@@ -6,10 +6,6 @@ Launch (single host; each pod host runs the same command — see README):
     python3 run_vit_training.py --fake_data ...
 """
 
-from vitax.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
-
 from vitax.config import parse_config
 from vitax.train.loop import train
 

@@ -13,11 +13,13 @@ if "--xla_force_host_platform_device_count" not in os.environ["XLA_FLAGS"]:
 os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax  # noqa: E402
-from vitax.platform import force_cpu_if_requested  # noqa: E402
-
-force_cpu_if_requested()
-
 import pytest  # noqa: E402
+
+# Entry points place a persistent compile cache (vitax/platform.py); this
+# process stays off it, as it always was: serializing executables after a few
+# hundred in-process tests has crashed the interpreter before. The cache's own
+# tests run the real CLIs in subprocesses.
+jax.config.update("jax_enable_compilation_cache", False)
 
 
 def pytest_configure(config):

@@ -1,0 +1,197 @@
+"""The main path's kernels, compiled for a DESCRIBED v5e chip with real Mosaic
+lowering — the third rehearsal of the `on-chip-measurement` guide (section
+2), kept as tests so every later PR is held to what the chip's compiler
+accepts at no chip time.
+
+Interpret mode (every other kernel test here) emulates the math but not the
+lowering: tiling legality, block shapes, VMEM budgets. This file caught the
+fused clip+AdamW kernel refusing every leaf wider than 8,192 (the 10B-width
+fc1 and qkv), which no interpret-mode test could see.
+
+A compile that passes is a compile: nothing runs, so nothing here says a
+result is right or a kernel is fast. Skipped where the topology cannot be
+described (no libtpu). The persistent compile cache is turned off around the
+file: a described-topology entry cannot be read back without a chip.
+"""
+
+import functools
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # keep the compiler's logs out of /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+L14_ATTN = (32, 256, 16, 64)     # (B, N, H, Dh): ViT-L/14 at batch 32
+TENB_ATTN = (8, 256, 32, 160)    # the 10B widths at the per-chip batch 8
+LONG_ATTN = (1, 4096, 16, 64)    # past MAX_SEQ_IN_VMEM: the streaming kernel
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """A SingleDeviceSharding on one chip of a described v5e:2x2, plus the
+    topology (its four devices build the fsdp=4 mesh)."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu here: nothing to compile for
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    return SingleDeviceSharding(topo.devices[0]), topo
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """The `_interpret()` seam (vitax/ops/attention.py): real Mosaic lowering
+    although the host backend is the CPU."""
+    monkeypatch.setenv("VITAX_FORCE_MOSAIC", "1")
+
+
+def _kernel_names(compiled):
+    """op_name of every tpu_custom_call in the compiled text."""
+    return [ln.split('op_name="', 1)[1].split('"', 1)[0]
+            for ln in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln]
+
+
+def _attention(family):
+    from vitax.ops.attention import flash_attention, flash_attention_4d
+    from vitax.ops.flash_blocked import blocked_flash_attention
+    return {"4d": (flash_attention_4d, "flash_4d"),
+            "bh": (flash_attention, "flash_bh"),
+            "streaming": (blocked_flash_attention, "flash_blocked")}[family]
+
+
+@pytest.mark.parametrize("family,shape", [
+    ("4d", L14_ATTN), ("4d", TENB_ATTN),
+    ("bh", L14_ATTN), ("bh", TENB_ATTN),
+    ("streaming", LONG_ATTN),
+], ids=lambda v: v if isinstance(v, str) else "x".join(map(str, v)))
+def test_attention_forward_and_vjp_compile(chip, mosaic, family, shape):
+    one_chip, _ = chip
+    fn, name = _attention(family)
+
+    def fwd_bwd(q, k, v):
+        o, vjp = jax.vjp(fn, q, k, v)
+        return o, vjp(o)
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(fwd_bwd).lower(x, x, x).compile()
+    kernels = _kernel_names(compiled)
+    assert any(f"{name}_fwd" in k for k in kernels), kernels
+    assert len(kernels) >= 2, kernels  # forward and at least one backward
+
+
+@pytest.mark.parametrize("mode", ["int8", "fp8", "int8_act"])
+@pytest.mark.parametrize("mkn", [
+    (8 * 257, 1024, 4096),       # ViT-L/14 fc1 at the largest serve bucket
+    (8 * 257, 5120, 20480),      # the 10B widths' fc1
+], ids=["l14_fc1", "10b_fc1"])
+def test_dequant_matmul_compiles(chip, mode, mkn):
+    from vitax.ops.dequant_matmul import DEQUANT_KERNEL_NAME, dequant_matmul
+    one_chip, _ = chip
+    m, k, n = mkn
+    # the export's own fp8: ml_dtypes' IEEE-style float8_e4m3, which Mosaic
+    # refused to load until the kernel reinterpreted it as e4m3fn
+    w_dtype = jnp.float8_e4m3 if mode == "fp8" else jnp.int8
+    x = jax.ShapeDtypeStruct((m, k), jnp.float32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((k, n), w_dtype, sharding=one_chip)
+    s = jax.ShapeDtypeStruct((1, n), jnp.float32, sharding=one_chip)
+    fn = functools.partial(dequant_matmul, act=mode == "int8_act",
+                           fused=True, interpret=False)
+    compiled = jax.jit(fn).lower(x, w, s).compile()
+    assert any(DEQUANT_KERNEL_NAME in k for k in _kernel_names(compiled))
+
+
+def _compile_leaf_update(shape, one_chip):
+    from vitax.ops.fused_optimizer import (FUSED_KERNEL_NAME,
+                                           _local_leaf_update)
+    from vitax.train.state import ADAMW_HPARAMS
+    hparams = (ADAMW_HPARAMS["b1"], ADAMW_HPARAMS["b2"],
+               ADAMW_HPARAMS["eps"], 0.1)
+    leaf = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    scal = jax.ShapeDtypeStruct((1, 4), jnp.float32, sharding=one_chip)
+    fn = functools.partial(_local_leaf_update, hparams=hparams,
+                           interpret=False)
+    compiled = jax.jit(fn, donate_argnums=(0, 2, 3)).lower(
+        leaf, leaf, leaf, leaf, scal).compile()
+    assert any(FUSED_KERNEL_NAME in k for k in _kernel_names(compiled))
+
+
+@pytest.mark.parametrize("shape", [
+    (1024, 4096), (24, 1024, 3072), (1024,), (1000,), (1, 257, 1024),
+    # last dimension over 8,192: the row block fell to 3 and 4 rows, which
+    # the TPU lowering refuses — `bench.py --preset 10b_slice` and the
+    # trainer at --embed_dim 5120 died in compilation before the fix
+    (5120, 20480), (2, 5120, 15360),
+    # the shapes tools/check_kernels_on_chip.py runs on the chip
+    (2, 37, 96), (70_000, 8), (),
+], ids=lambda s: "x".join(map(str, s)) or "scalar")
+def test_fused_adamw_leaf_update_compiles(chip, shape):
+    _compile_leaf_update(shape, chip[0])
+
+
+def _state_leaf_shapes(topo):
+    """Every distinct leaf shape of the ViT-L/14 and 10B-width parameter
+    trees — stacked (scan_blocks) and unstacked, whole and as the local shard
+    of an fsdp=4 mesh."""
+    from chip_smoke import MODELS
+    from vitax.config import Config
+    from vitax.models import build_model
+    from vitax.parallel.mesh import build_mesh
+    from vitax.parallel.sharding import param_specs
+
+    shapes = set()
+    for model in ("l14", "10b_width"):
+        for scan in (True, False):
+            cfg = Config(batch_size=4, scan_blocks=scan,
+                         **MODELS[model]).validate()
+            mesh = build_mesh(cfg, devices=list(topo.devices))
+            net = build_model(cfg)
+            x = jax.ShapeDtypeStruct(
+                (1, cfg.image_size, cfg.image_size, 3), jnp.float32)
+            params = jax.eval_shape(
+                lambda k, im: net.init(k, im, True), jax.random.key(0), x)
+            specs = param_specs(params, cfg, mesh)
+            for leaf, spec in zip(
+                    jax.tree.leaves(params),
+                    jax.tree.leaves(specs, is_leaf=lambda s: isinstance(
+                        s, jax.sharding.PartitionSpec))):
+                shapes.add(tuple(leaf.shape))
+                shapes.add(tuple(
+                    d // (mesh.shape[ax] if isinstance(ax, str) else 1)
+                    for d, ax in zip(leaf.shape,
+                                     tuple(spec) + (None,) * leaf.ndim)))
+    return sorted(shapes)
+
+
+def test_fused_adamw_every_state_leaf_compiles(chip):
+    """`--fused_optimizer auto` is on for every leaf on a TPU, so every leaf
+    must get a block the lowering accepts: checked on the block shape for
+    all of them, and by compiling each distinct 2-D view."""
+    from vitax.ops.fused_optimizer import _as_2d, _block_shape
+    one_chip, topo = chip
+    shapes = _state_leaf_shapes(topo)
+    # the wide leaves, stacked and unstacked, and an fsdp=4 shard of one
+    assert {(5120, 20480), (2, 5120, 15360), (2, 5120, 3840)} <= set(shapes)
+    views = sorted({_as_2d(s) for s in shapes})
+    for m, n in views:
+        bm, bn = _block_shape(m, n)
+        assert bm == m or bm % 8 == 0, (m, n, bm, bn)
+        assert bn == n or bn % 128 == 0, (m, n, bm, bn)
+        assert bm * (-(-bn // 128) * 128) <= 64 * 1024, (m, n, bm, bn)
+    for view in views:
+        _compile_leaf_update(view, one_chip)

@@ -840,7 +840,7 @@ def _drill_tiny_cfg(**kw):
     return Config(**base).validate()
 
 
-def _drill_train_argv(ckpt_dir, peers, metrics_dir, arbiter_url, cache_dir):
+def _drill_train_argv(ckpt_dir, peers, metrics_dir, arbiter_url):
     return [
         sys.executable, os.path.join(REPO, "run_vit_training.py"),
         "--fake_data", "--image_size", "32", "--patch_size", "8",
@@ -852,7 +852,7 @@ def _drill_train_argv(ckpt_dir, peers, metrics_dir, arbiter_url, cache_dir):
         "--ckpt_epoch_interval", "99", "--ckpt_dir", str(ckpt_dir),
         "--zero_stall_ckpt", "--replicate_steps", "2",
         "--peer_dir", str(peers), "--metrics_dir", str(metrics_dir),
-        "--control_sync_steps", "2", "--compile_cache_dir", str(cache_dir),
+        "--control_sync_steps", "2",
         "--arbiter_url", arbiter_url,
     ]
 
@@ -914,9 +914,11 @@ def test_arbiter_borrow_return_drill(devices8, tmp_path_factory):
     # the live tenant: 2-process training, peer-replicated, heartbeating
     director = TrainDirector(
         _drill_train_argv(root / "train_ckpt", root / "peers", metrics_dir,
-                          arb_url, cache_dir),
+                          arb_url),
         term_grace_s=240.0, log_dir=str(root / "train_logs"),
+        # the compile cache is placed from outside (vitax/platform.py)
         env=dict(os.environ, JAX_PLATFORMS="cpu",
+                 JAX_COMPILATION_CACHE_DIR=str(cache_dir),
                  XLA_FLAGS="--xla_force_host_platform_device_count=4"))
 
     # the serving tenant: router + admission + maxed-out autoscaler

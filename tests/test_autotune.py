@@ -179,14 +179,34 @@ def test_validate_bench_payload_contract():
     assert validate_bench_payload({**good, "knobs": [1, 2]})
 
 
-def test_repo_bench_trajectory_validates():
-    """Every committed BENCH_r*.json must pass the schema validator (the
-    lint.sh / perf_gate --validate guard, run in-process)."""
+def test_bench_trajectory_files_validate(tmp_path):
+    """The driver-round wrapper files pass the schema validator (the
+    lint.sh / perf_gate --validate guard, run in-process) in each of the
+    three forms the record has held: a payload older than the knobs object,
+    a measured payload with knobs and the device block, an outage round —
+    and any still committed in the repo do too."""
     import glob
-    files = sorted(glob.glob(os.path.join(REPO, "BENCH_r*.json")))
-    assert files
+    rounds = [
+        _bench_round(2, 163.02),
+        _bench_round(4, 252.63, dict(_tiny_knobs(), batch_per_chip=32)),
+        _bench_round(5, 0.0, error="backend unavailable after 8 probes"),
+    ]
+    rounds[1]["parsed"].update(platform="tpu", device_kind="TPU v5 lite",
+                               n_devices=1)
+    for rec in rounds:
+        path = tmp_path / f"BENCH_r{rec['n']:02d}.json"
+        path.write_text(json.dumps(rec))
+    files = (sorted(glob.glob(str(tmp_path / "BENCH_r*.json")))
+             + sorted(glob.glob(os.path.join(REPO, "BENCH_r*.json"))))
+    assert len(files) >= 3
     for path in files:
         assert validate_bench_file(path) == [], path
+    # the device block is typed: a count that is not an integer is refused
+    bad = _bench_round(6, 1.0)
+    bad["parsed"].update(platform="tpu", device_kind="TPU v5 lite",
+                         n_devices="one")
+    (tmp_path / "bad.json").write_text(json.dumps(bad))
+    assert validate_bench_file(str(tmp_path / "bad.json"))
 
 
 # -------------------------------------------------------- candidate space

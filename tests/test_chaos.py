@@ -817,10 +817,10 @@ def test_no_fault_plan_request_path_unchanged(fleet_factory):
         stop_router(httpd)
 
 
-# --- serve_bench taxonomy (satellite) ----------------------------------------
+# --- serve_bench error classes (satellite) -----------------------------------
 
 
-def test_serve_bench_error_taxonomy_classifier():
+def test_serve_bench_error_class_classifier():
     serve_bench = _import_serve_bench()
     classify = serve_bench.classify_error
     assert classify(urllib.error.URLError(

@@ -70,6 +70,21 @@ def test_weight_only_fp8_matches_closed_form(m, k, n):
     assert _rel_err(fused, want) < 1e-5
 
 
+def test_fp8_export_bits_mean_the_same_as_e4m3fn():
+    """The fused path hands Mosaic the export's float8_e4m3 weights
+    reinterpreted as float8_e4m3fn (the chip's compiler loads only the
+    latter): every finite e4m3 bit pattern must mean the same number there,
+    and the quantizer's largest value must be finite."""
+    bits = np.arange(256, dtype=np.uint8)
+    ieee = bits.view(ml_dtypes.float8_e4m3).astype(np.float32)
+    fn = bits.view(ml_dtypes.float8_e4m3fn).astype(np.float32)
+    finite = np.isfinite(ieee)
+    assert finite.sum() == 256 - 16  # exponent all ones: inf / NaN
+    np.testing.assert_array_equal(ieee[finite], fn[finite])
+    assert np.abs(ieee[finite]).max() == 240.0 == float(
+        ml_dtypes.finfo(ml_dtypes.float8_e4m3).max)
+
+
 @pytest.mark.parametrize("m,k,n", SHAPES)
 def test_act_quant_fused_matches_unfused_bitwise(m, k, n):
     """Fused and unfused act-quant paths compute the SAME int32 sums and

@@ -1,8 +1,8 @@
 """Driver entry-point smoke tests: bench.py and __graft_entry__.py must keep
 working — the round's benchmark and compile checks run through them.
 
-Both run in subprocesses with JAX_PLATFORMS=cpu so the forced-platform guard
-(vitax/platform.py) is exercised exactly as the driver exercises it."""
+Both run in subprocesses with JAX_PLATFORMS=cpu, the explicit CPU pin that
+lets bench.py run without a TPU (without it a chipless run must fail)."""
 
 import json
 import os
@@ -30,12 +30,69 @@ def test_bench_prints_one_json_line():
     assert r.returncode == 0, r.stderr[-2000:]
     line = r.stdout.strip().splitlines()[-1]
     result = json.loads(line)
-    # the four contract keys must be present (extra fields — "knobs", and
-    # "error"/"last_measured" on failure paths — are part of the design)
-    assert {"metric", "value", "unit", "vs_baseline"} <= set(result)
+    # the four contract keys and the device block must be present
+    assert {"metric", "value", "unit", "vs_baseline",
+            "platform", "device_kind", "n_devices"} <= set(result)
     assert result["unit"] == "images/sec/chip"
     assert result["value"] > 0
+    assert (result["platform"], result["n_devices"]) == ("cpu", 8)
+    assert result["mfu"] is None  # a CPU run reports no MFU
     assert result["knobs"]["batch_per_chip"] == 1  # global 8 over 8 devices
+
+
+def test_bench_refuses_to_run_without_a_tpu():
+    """No TPU and no explicit JAX_PLATFORMS=cpu pin: JAX falls back to the
+    CPU silently, bench.py must not — non-zero exit, no result line."""
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    r = subprocess.run([sys.executable, "bench.py", "--preset", "tiny"],
+                       cwd=REPO, env=env, capture_output=True, text=True,
+                       timeout=300)
+    assert r.returncode != 0
+    assert "no TPU found" in r.stderr
+    assert r.stdout.strip() == ""
+
+
+def test_compile_cache_placed_from_outside(tmp_path):
+    """vitax.platform.setup_compile_cache: JAX_COMPILATION_CACHE_DIR wins and
+    nothing is set in code; without it the fixed in-checkout directory."""
+    code = ("import jax\n"
+            "before = jax.config.jax_compilation_cache_dir\n"
+            "from vitax.platform import setup_compile_cache\n"
+            "print(setup_compile_cache())\n"
+            "print(before == jax.config.jax_compilation_cache_dir)\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+    outside = str(tmp_path / "placed")
+    for env_dir, want in ((outside, outside),
+                          (None, os.path.join(REPO, ".jax_cache"))):
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        if env_dir:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+        r = subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
+                           capture_output=True, text=True, timeout=120)
+        assert r.returncode == 0, r.stderr[-2000:]
+        returned, untouched, configured = r.stdout.split()
+        assert returned == configured == want
+        assert (untouched == "True") == bool(env_dir)
+
+
+def test_spawning_parents_never_initialize_a_backend():
+    """A chip belongs to one process at a time: the supervisor and the fleet
+    replica manager start children that need it, so importing and using the
+    parents' own code paths must leave JAX's backends uninitialized."""
+    code = ("import tempfile\n"
+            "import vitax.supervise as sup\n"
+            "import vitax.serve.fleet.replica as rep\n"
+            "import vitax.serve.fleet.__main__\n"
+            "d = tempfile.mkdtemp()\n"
+            "sup.run_progress(d, ''); sup.checkpoint_topology(d)\n"
+            "sup.Supervisor(['true'], ckpt_dir=d)\n"
+            "rep.ReplicaManager()\n"
+            "from jax._src import xla_bridge\n"
+            "print('initialized', xla_bridge.backends_are_initialized())\n")
+    r = _run([sys.executable, "-c", code], timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "initialized False" in r.stdout
 
 
 @pytest.mark.slow
@@ -89,7 +146,7 @@ def test_apply_ladder_picks_measured_winners(tmp_path, monkeypatch):
          "result": {"value": 100.0,
                     "knobs": knobs(False, 1, 0, "dots_attn_saveable", 64)}},
         # 10b_slice: a policy-only win must flip the policy along (the
-        # family code default is window-2 — LADDER_r04 — so the default
+        # family code default is window-2 — the round-4 ladder — so the default
         # and alternative rows both carry it)
         {"args": "--preset 10b_slice --remat_policy dots_saveable",
          "result": {"value": 130.0,

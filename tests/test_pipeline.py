@@ -327,10 +327,6 @@ def test_pp_1f1b_validation():
         pp_cfg(moe_experts=4, ep_size=1, tp_size=2, dp_size=1)
 
 
-@pytest.mark.xfail(
-    strict=False, reason="pp-under-tp standing debt (ROADMAP): XLA rejects\n"
-    "PartitionId under SPMD partitioning in jax 0.4.x, so the tp GSPMD-auto\n"
-    "axis cannot coexist with the pipeline shard_map yet")
 def test_pp_tp_forward_and_grads_match_scan_path(devices8):
     """pp x tp (the round-3 v1 exclusion): the pipeline shard_map manualizes
     only (dp, fsdp, pp, ep) and leaves "tp" as a GSPMD-auto axis, so the
@@ -368,26 +364,14 @@ def test_pp_tp_forward_and_grads_match_scan_path(devices8):
             err_msg=f"grad mismatch at {jax.tree_util.keystr(ka)}")
 
 
-# the tp_size entries carry the pp-under-tp xfail (ROADMAP standing debt):
-# XLA rejects PartitionId under SPMD partitioning in jax 0.4.x, so the tp
-# GSPMD-auto axis cannot coexist with the pipeline shard_map yet
-_PP_TP_XFAIL = pytest.mark.xfail(
-    strict=False, reason="pp-under-tp: PartitionId unimplemented in jax "
-    "0.4.x SPMD partitioning (ROADMAP standing debt)")
-
-
 @pytest.mark.parametrize("mesh_kw", [
-    pytest.param(dict(pp_size=2, dp_size=2, tp_size=2),   # pp x tp
-                 marks=_PP_TP_XFAIL),
-    pytest.param(dict(pp_size=2, dp_size=1, tp_size=2,    # + ZeRO-3 gathers
-                      fsdp_size=2), marks=_PP_TP_XFAIL),
+    dict(pp_size=2, dp_size=2, tp_size=2),                # pp x tp
+    dict(pp_size=2, dp_size=1, tp_size=2, fsdp_size=2),   # + ZeRO-3 gathers
     dict(pp_size=2, dp_size=2, sp_size=2),                # pp x sp (ring)
     dict(pp_size=2, dp_size=2, sp_size=2, sp_impl="ulysses"),
-    pytest.param(dict(pp_size=2, tp_size=2, sp_size=2,    # pp x tp x sp
-                      dp_size=1), marks=_PP_TP_XFAIL),
+    dict(pp_size=2, tp_size=2, sp_size=2, dp_size=1),     # pp x tp x sp
     # ulysses' with_tp branch: dense inner under the GSPMD-auto head axis
-    pytest.param(dict(pp_size=2, tp_size=2, sp_size=2, dp_size=1,
-                      sp_impl="ulysses"), marks=_PP_TP_XFAIL),
+    dict(pp_size=2, tp_size=2, sp_size=2, dp_size=1, sp_impl="ulysses"),
 ])
 def test_pp_tp_sp_train_step_matches_fsdp(devices8, mesh_kw):
     """Full train step on pp x tp / pp x sp meshes must match the plain
@@ -402,10 +386,6 @@ def test_pp_tp_sp_train_step_matches_fsdp(devices8, mesh_kw):
     np.testing.assert_allclose(losses, fsdp8_reference_losses(), rtol=2e-4)
 
 
-@pytest.mark.xfail(
-    strict=False, reason="pp-under-tp standing debt (ROADMAP): XLA rejects\n"
-    "PartitionId under SPMD partitioning in jax 0.4.x, so the tp GSPMD-auto\n"
-    "axis cannot coexist with the pipeline shard_map yet")
 def test_pp_tp_forward_with_pallas_kernels(devices8):
     """Under pp x tp the Pallas kernel cannot ride into the pipeline body
     (tp is a GSPMD-auto axis there and a custom kernel cannot be

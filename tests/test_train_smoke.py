@@ -168,13 +168,15 @@ def test_full_loop_fake_data(devices8, tmp_path):
 
 
 def test_compile_cache_dir_populates(tmp_path):
-    """--compile_cache_dir persists compiled step programs so restarts
+    """The persistent compile cache, placed from outside through
+    JAX_COMPILATION_CACHE_DIR (vitax/platform.py setup_compile_cache sets
+    nothing in code then), keeps compiled step programs so restarts
     (launcher --restart, preemption resume) skip recompilation. Runs the
     REAL CLI in a subprocess: enabling the persistent cache mutates global
     jax.config and serializes executables, and doing that inside this
     process after ~200 suite tests aborted the interpreter twice (native
-    crash in the cache write path with accumulated XLA state) — subprocess
-    isolation matches how the flag is actually used (one cache per run)."""
+    crash in the cache write path with accumulated XLA state) — which is
+    also why tests/conftest.py keeps the cache off in-process."""
     import subprocess
     import sys
 
@@ -182,6 +184,7 @@ def test_compile_cache_dir_populates(tmp_path):
     cache = tmp_path / "xla_cache"
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
+               JAX_COMPILATION_CACHE_DIR=str(cache),
                JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
     r = subprocess.run(
         [sys.executable, "run_vit_training.py", "--fake_data",
@@ -189,8 +192,7 @@ def test_compile_cache_dir_populates(tmp_path):
          "--num_heads", "4", "--num_blocks", "2", "--batch_size", "16",
          "--num_epochs", "1", "--steps_per_epoch", "2",
          "--log_step_interval", "1", "--test_epoch_interval", "10",
-         "--num_workers", "1", "--ckpt_dir", str(tmp_path / "ckpt"),
-         "--compile_cache_dir", str(cache)],
+         "--num_workers", "1", "--ckpt_dir", str(tmp_path / "ckpt")],
         cwd=repo, env=env, capture_output=True, text=True, timeout=1500)
     assert r.returncode == 0, r.stderr[-2000:]
     assert cache.is_dir() and os.listdir(cache), (

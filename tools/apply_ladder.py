@@ -1,15 +1,14 @@
 #!/usr/bin/env python3
 """Pick per-preset scan/remat knob defaults from measured ladder results.
 
-Reads LADDER_r04.jsonl (appended by the chip watcher: one line per A/B run,
+Reads a ladder JSONL (one line per A/B run,
 {"args": "--preset l14 --scan_unroll 2", "result": {bench JSON}}) plus the
 default-config rows in BASELINE_MEASURED.json, and flips a preset's default
 knobs in TUNED.json ONLY when a ladder winner beats a MEASURED run of the
 current default by --min_gain. bench.py's default_scan_blocks /
 default_scan_unroll / default_remat_window / default_remat_policy consult
 TUNED.json first, so measured winners become the defaults WITHOUT a code
-edit — the chip watcher closes the measure->tune loop autonomously even
-when the chip returns after a build session ends (VERDICT r3 item 2).
+edit.
 
 Safety rules (reviewed in round 4):
 - never flip away from a default that has no measurement in the candidate
@@ -20,7 +19,7 @@ Safety rules (reviewed in round 4):
 - a row's knob set comes from the bench's OWN "knobs" field in the result
   JSON (ground truth); CLI-flag reconstruction is the legacy fallback.
 
-Usage: python tools/apply_ladder.py [--ladder LADDER_r04.jsonl]
+Usage: python tools/apply_ladder.py --ladder LADDER.jsonl
 """
 
 import argparse
@@ -105,7 +104,7 @@ def legacy_entry_knobs(knobs: dict) -> dict:
 
 def main():
     p = argparse.ArgumentParser()
-    p.add_argument("--ladder", default=os.path.join(REPO, "LADDER_r04.jsonl"))
+    p.add_argument("--ladder", required=True)
     p.add_argument("--out", default=os.path.join(REPO, "TUNED.json"))
     p.add_argument("--min_gain", type=float, default=1.02,
                    help="a ladder winner must beat the measured current "

@@ -67,7 +67,9 @@ def resolve_topology(name: str) -> dict:
         return {"topology": f"local-{len(devices)}x{kind}".replace(" ", ""),
                 "devices": list(devices), "n_dev": len(devices),
                 "device_kind": kind,
-                "peak_tflops": detect_peak_tflops(kind),
+                # a CPU has no peak; the analytic ranking only needs a
+                # constant (same as the cpu:N branch below)
+                "peak_tflops": detect_peak_tflops(kind) or 1.0,
                 "hbm_bound_bytes": 0.0,
                 "can_measure": platform == "tpu"}
     if name.startswith("cpu:"):
@@ -92,9 +94,6 @@ def resolve_topology(name: str) -> dict:
 
 
 def main(argv=None) -> int:
-    from vitax.platform import force_cpu_if_requested
-    force_cpu_if_requested()
-
     import bench
     from vitax.tune.driver import TrialLog, run_search
     from vitax.tune.preset import preset_path, save_preset
@@ -130,12 +129,17 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from vitax.platform import backend_platform
-    on_tpu = backend_platform() == "tpu"  # after force_cpu_if_requested
+    on_tpu = backend_platform() == "tpu"
     if not on_tpu and not args.compile_only:
         print("[autotune] no TPU backend — degrading to --compile_only "
               "(deterministic ranked shortlist; measured windows need a "
               "live chip)", flush=True)
         args.compile_only = True
+    if not args.compile_only:
+        # measured windows run on the local chip; compile-only probes stay
+        # off the cache (a described-topology entry cannot be read back)
+        from vitax.platform import setup_compile_cache
+        setup_compile_cache()
 
     preset_kw = bench.train_presets(1)[args.preset]
     log = TrialLog(args.trials)

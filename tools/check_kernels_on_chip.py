@@ -19,11 +19,14 @@ plus the streaming blocked kernel (vitax/ops/flash_blocked.py) at a
 sequence length past MAX_SEQ_IN_VMEM's block sizes.
 """
 
+import os
 import sys
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
 
 # bf16 has ~3 decimal digits; the fused kernels do softmax/accum in f32 so
 # outputs agree to bf16 resolution against the (also f32-accumulating) dense
@@ -55,6 +58,8 @@ def check(name, fn, ref, shape, dtype=jnp.bfloat16, seed=0):
 
 
 def main():
+    from vitax.platform import setup_compile_cache
+    setup_compile_cache()
     dev = jax.devices()[0]  # vtx: ignore[VTX104] CLI entry point: probes whatever backend the user launched on
     if dev.platform != "tpu":
         print(f"no TPU attached (found {dev.platform}); this tool checks "
@@ -137,12 +142,13 @@ def check_fused_optimizer() -> bool:
     so on-chip agreement is tight — 1e-5 relative, not the bf16 attention
     tolerance. States compare directly (no vjp: the optimizer sits outside
     autodiff). Shapes cover a ragged grid row count, a >1-block leaf, a
-    vector leaf, and a scalar leaf."""
+    vector leaf, a scalar leaf, and a leaf wider than 8,192 (the 10B-width
+    fc1's last dimension: the lane-tiled grid)."""
     from vitax.ops.fused_optimizer import fused_clip_adamw
     from vitax.train.state import ADAMW_HPARAMS
     b1, b2, eps = (ADAMW_HPARAMS[k] for k in ("b1", "b2", "eps"))
     wd, clip, lr = 0.05, 1.0, 3e-4
-    shapes = [(2, 37, 96), (70_000, 8), (128,), ()]
+    shapes = [(2, 37, 96), (70_000, 8), (128,), (), (40, 20480)]
     keys = jax.random.split(jax.random.key(7), 3 * len(shapes))
     params = {f"leaf{i}": jax.random.normal(keys[3 * i], s, jnp.float32)
               for i, s in enumerate(shapes)}
@@ -193,10 +199,14 @@ def check_dequant_matmul() -> bool:
     Three modes per the serve paths (vitax/ops/dequant_matmul.py): int8
     weight-only, int8 weights + int8 activations (the MXU i8xi8->i32 path),
     and fp8 weight-only. The kernel's k-loop accumulates in i32 (act) or
-    f32 (weight-only) with the scales applied once after — the closed form
-    reproduces that exactly, so agreement is tight (1e-5 relative), not an
-    accuracy-style tolerance. Shapes cover ragged m/k/n (block padding) and
-    an aligned case."""
+    f32 (weight-only) with the scales applied once after. The act-quant
+    path is integer arithmetic and must reproduce the closed form to 1e-5.
+    The weight-only path multiplies f32 operands, which the MXU does in bf16
+    passes at default precision — in the kernel exactly as in the XLA dot it
+    replaces (measured on a v5e, PR 21: 1.3e-3..2.3e-3 of the largest output;
+    1e-5 holds only in interpret mode). It is held to the serve path's 1e-2
+    acceptance bound, and the unfused XLA path's error is printed beside it.
+    Shapes cover ragged m/k/n (block padding) and an aligned case."""
     import ml_dtypes
 
     from vitax.ops.dequant_matmul import dequant_matmul, quantize_activations
@@ -218,9 +228,17 @@ def check_dequant_matmul() -> bool:
                 dequant_matmul(x, jnp.asarray(w_i8), jnp.asarray(scale),
                                act=False, fused=True, interpret=False),
                 x @ (w_i8.astype(np.float32) * scale)),
+            "int8 unfused (XLA)": (
+                dequant_matmul(x, jnp.asarray(w_i8), jnp.asarray(scale),
+                               act=False, fused=False),
+                x @ (w_i8.astype(np.float32) * scale)),
             "fp8 weight-only": (
                 dequant_matmul(x, jnp.asarray(w_fp8), jnp.asarray(s_fp8),
                                act=False, fused=True, interpret=False),
+                x @ (w_fp8.astype(np.float32) * s_fp8)),
+            "fp8 unfused (XLA)": (
+                dequant_matmul(x, jnp.asarray(w_fp8), jnp.asarray(s_fp8),
+                               act=False, fused=False),
                 x @ (w_fp8.astype(np.float32) * s_fp8)),
         }
         xq, sx = jax.device_get(quantize_activations(jnp.asarray(x)))
@@ -234,10 +252,11 @@ def check_dequant_matmul() -> bool:
             got = np.asarray(jax.device_get(got), np.float32)
             err = float(np.max(np.abs(got - want))
                         / max(1e-6, float(np.max(np.abs(want)))))
-            status = "ok" if err < 1e-5 else "FAIL"
+            tol = 1e-5 if name == "int8 act-quant" else 1e-2
+            status = "ok" if err < tol else "FAIL"
             print(f"  dequant matmul {name:18s} ({m}x{k}x{n}) rel-max-err "
                   f"{err:.2e} {status}")
-            if err >= 1e-5:
+            if err >= tol:
                 ok = False
     return ok
 

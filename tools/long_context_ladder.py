@@ -1,7 +1,7 @@
 """Streaming-kernel block-size ladder + long-N frontier, on chip.
 
 Round-4 measured the streaming kernel (vitax/ops/flash_blocked.py) only at
-its untuned DEFAULT_BLOCK_Q/K = 512 (BASELINE.md "Long-context on chip").
+its untuned DEFAULT_BLOCK_Q/K = 512.
 This ladder sweeps (block_q, block_k) over {256, 512, 1024}^2 at N = 4,096
 and N = 9,216, then pushes the max trainable N at ViT-L width with the
 winning blocks (16k+). Same end-to-end train-step methodology as round 4:
@@ -14,7 +14,7 @@ Usage:
 Each row: {"n": N, "block_q": bq, "block_k": bk, "ms_per_step": t | null,
            "error": ...}. The dense arm at N=4,096 re-verifies the round-4
 comparison point. tools/apply_ladder.py is NOT involved — the winner is
-applied by editing DEFAULT_BLOCK_Q/K with a BASELINE.md note.
+applied by editing DEFAULT_BLOCK_Q/K with a PERF.md finding.
 """
 
 from __future__ import annotations
@@ -36,6 +36,8 @@ def measure(n_tokens: int, block_q, block_k, steps: int, dense: bool = False):
     code = f"""
 import sys, time, json
 sys.path.insert(0, {os.path.dirname(os.path.dirname(os.path.abspath(__file__)))!r})
+from vitax.platform import setup_compile_cache
+setup_compile_cache()
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding
 from vitax.config import Config
@@ -82,6 +84,8 @@ assert np.isfinite(loss), loss
 print("RESULT " + json.dumps({{"ms_per_step": dt / {steps} * 1e3}}))
 """
     import subprocess
+    # the chip belongs to one process at a time: this parent never imports
+    # JAX, and the children run one after another
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=1200)
     for line in r.stdout.splitlines():

@@ -297,7 +297,7 @@ def summarize(path: str) -> dict:
 
     sec = sorted(r["sec_per_iter"] for r in steps if "sec_per_iter" in r)
     losses = [r["loss"] for r in steps]
-    mfus = [r["mfu"] for r in steps if "mfu" in r]
+    mfus = [r["mfu"] for r in steps if r.get("mfu") is not None]  # null: CPU run
     waits = [r.get("data_wait_s", 0.0) for r in steps]
     stalls = sorted(r["ckpt_stall_s"] for r in steps if "ckpt_stall_s" in r)
     opts = sorted(r["opt_update_s"] for r in steps

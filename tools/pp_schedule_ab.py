@@ -28,10 +28,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from vitax.platform import force_cpu_if_requested  # noqa: E402
-
-force_cpu_if_requested()
-
 
 def build(schedule: str, microbatches: int):
     from vitax.config import Config

@@ -6,8 +6,8 @@ Usage: python tools/profile_step.py --preset l14 [--steps 8] [--out /tmp/prof]
 
 Parses the xplane via xprof's framework_op_stats converter into a table of
 self-time by op category (fusion kinds, custom-call kernels, copies, infeed),
-printed as JSON + a human table. This is the measurement side of the
-BASELINE.md "where the step time goes" section.
+printed as JSON + a human table. This is the measurement side of PERF.md's
+"Where the time goes" section.
 """
 
 import argparse
@@ -33,12 +33,16 @@ def main():
     p.add_argument("--out", default="/tmp/vitax_profile")
     args = p.parse_args()
 
+    from vitax.platform import setup_compile_cache
+    setup_compile_cache()
+
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding
 
-    from bench import model_flops_per_image, detect_peak_tflops
+    from vitax.telemetry.flops import (detect_peak_tflops,
+                                       model_flops_per_image)
     from vitax.config import Config
     from vitax.models import build_model
     from vitax.ops.attention import make_attention_impl
@@ -48,6 +52,11 @@ def main():
 
     n_dev = jax.device_count()
     device_kind = jax.devices()[0].device_kind  # vtx: ignore[VTX104] CLI entry point: labels the backend being profiled
+    peak = detect_peak_tflops(device_kind)
+    if peak is None:
+        raise SystemExit(f"profile_step traces a chip; JAX reports "
+                         f"{device_kind!r} (a CPU trace says nothing about "
+                         f"the device)")
     # presets and remat defaults come FROM bench.py so traces explain exactly
     # the configs the bench measures
     from bench import apply_preset_file, resolve_bench_knobs, train_presets
@@ -99,7 +108,6 @@ def main():
 
     step_ms = dt / args.steps * 1e3
     flops = model_flops_per_image(cfg) * cfg.batch_size
-    peak = detect_peak_tflops(device_kind)
     mfu = flops / (dt / args.steps) / (peak * 1e12 * n_dev)
     print(f"\n== {args.preset} remat={args.remat_policy} "
           f"batch={cfg.batch_size}: "

@@ -32,7 +32,7 @@ Fleet mode (target = a vitax.serve.fleet router):
   a staged offered-load profile (each stage paces to its rps for its
   duration) — the autoscale acceptance drill's load shape. The summary
   gains a per-stage breakdown under "ramp";
-- errors carry a taxonomy: `errors_by_class` buckets connection_refused /
+- errors are classified: `errors_by_class` buckets connection_refused /
   reset_mid_body / timeout / http_5xx / other, so a drill can assert
   *which* failure mode leaked to clients, not just how many;
 - 503s that carry Retry-After are `unavailable`, not errors: like 429

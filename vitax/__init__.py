@@ -21,15 +21,7 @@ Package map:
 
 __version__ = "0.1.0"
 
-# Layout-invariant PRNG everywhere: newer jax defaults this on, 0.4.x does
-# not — and without it param init DRAWS (not just layouts) change with the
-# mesh shape, breaking the repo's core sharding-must-not-change-the-math
-# contract (tests/test_train_smoke.py::test_dp_fsdp_zero2_equivalence and
-# every sp/tp/pp equivalence test). No-op where it is already the default.
-import jax as _jax
-
-try:
-    _jax.config.update("jax_threefry_partitionable", True)
-except (AttributeError, ValueError):  # flag retired once always-on
-    pass
-del _jax
+# The sharding-must-not-change-the-math contract (param init DRAWS equal
+# under every mesh shape: tests/test_train_smoke.py::
+# test_dp_fsdp_zero2_equivalence and every sp/tp/pp equivalence test) rests
+# on jax_threefry_partitionable, which is jax's default.

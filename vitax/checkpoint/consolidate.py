@@ -295,9 +295,11 @@ def consolidate(ckpt_dir: str, epoch: int, out: str, params_only: bool = True,
     # by a multi-host run (its device ids don't exist here). Consolidation
     # must work from any single machine regardless of save topology.
     with ocp.PyTreeCheckpointer() as ckptr:
-        meta = ckptr.metadata(path)
+        # metadata() is one StepMetadata object; the saved tree's per-leaf
+        # view lives under item_metadata.tree
+        meta_tree = ckptr.metadata(path).item_metadata.tree
         restore_args = jax.tree.map(
-            lambda _: ocp.RestoreArgs(restore_type=np.ndarray), meta)
+            lambda _: ocp.RestoreArgs(restore_type=np.ndarray), meta_tree)
         state = ckptr.restore(path, restore_args=restore_args)
     tree = state["params"] if params_only and "params" in state else state
     flat = save_npz(out, flatten_tree(tree), dtype=dtype)

@@ -197,7 +197,6 @@ class Config:
     #   "training is actually progressing". Host-side reporter thread only
     #   (vitax/train/control.py ArbiterReporter) — the compiled step
     #   program is identical with or without it. "" = off
-    compile_cache_dir: str = ""         # persistent XLA compile cache (restarts skip recompiles)
     debug_nans: bool = False            # opt-in jax_debug_nans (SURVEY.md section 5, race-detection analog)
     log_memory: bool = True             # include HBM stats in step log
     steps_per_epoch: int = 0            # override (0 = derive from dataset length // batch_size)
@@ -810,7 +809,6 @@ def build_parser() -> argparse.ArgumentParser:
                           "write-error/loader-stall/SIGTERM drills for the "
                           "failure-reaction machinery (VITAX_FAULT_PLAN env "
                           "var is the flagless equivalent)")
-    ext.add_argument("--compile_cache_dir", type=str, default="")
     ext.add_argument("--debug_nans", action="store_true", dest="debug_nans")
     ext.add_argument("--no_log_memory", action="store_false", dest="log_memory")
     ext.add_argument("--steps_per_epoch", type=int, default=0)

@@ -30,7 +30,7 @@ Two dispatch/combine implementations (--moe_impl), MEASURED round 5:
 - "gather": integer scatter builds a per-slot source-token index (B, E*C),
   dispatch/combine are take_along_axis gathers, no (B, N, E, C) tensor
   exists. Measured b16_moe 477-527 img/s vs einsum's 617-650 across two
-  layouts (BASELINE.md round-5 MoE section) — kept as the A/B arm and
+  layouts (round 5; ROADMAP A3) — kept as the A/B arm and
   mutual oracle (tests/test_moe.py asserts gather == einsum on values and
   grads; trajectory tests pin both).
 """

@@ -497,7 +497,7 @@ def make_windowed_forward(cfg: Config, model: "VisionTransformer"):
     """Functional scan forward with remat around GROUPS of --remat_window
     blocks instead of per block.
 
-    The wgrad experiment for the profiled l14 ceiling (BASELINE.md): the
+    The wgrad experiment for the profiled l14 ceiling (ROADMAP A2): the
     per-block scan's saved residuals are written into (L, ...) stacked
     buffers by dynamic-update-slice each iteration, and the backward wgrad
     fusions co-writing those buffers run at 85-100 TF/s vs 164-182

@@ -166,6 +166,7 @@ def _fwd(q, k, v, scale):
             jax.ShapeDtypeStruct((bh, n, dh), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, n), jnp.float32),
         ],
+        name="flash_bh_fwd",
         interpret=_interpret(),
     )(q, k, v)
     return o, lse[:, 0, :]
@@ -227,6 +228,7 @@ def _bwd(scale, res, cts):
         in_specs=[spec, spec, spec, spec, lse_spec, spec, lse_spec],
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct((bh, n, dh), q.dtype)] * 3,
+        name="flash_bh_bwd",
         interpret=_interpret(),
     )(q, k, v, o, lse[:, None, :], do, dlse[:, None, :])
     return dq, dk, dv
@@ -416,6 +418,7 @@ def _fwd4(q, k, v, scale):
             jax.ShapeDtypeStruct((b, n, h * dh), q.dtype),
             jax.ShapeDtypeStruct(lse_shape, jnp.float32),
         ],
+        name="flash_4d_fwd",
         interpret=_interpret(),
     )(q3, k3, v3)
     if pad:
@@ -458,6 +461,7 @@ def _flash4_bwd(scale, res, cts):
         in_specs=[spec, spec, spec, spec, lse_spec, spec, lse_spec],
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct((b, n, h * dh), q.dtype)] * 3,
+        name="flash_4d_bwd",
         interpret=_interpret(),
     )(q3, k3, v3, o3, lse, do3, dlse)
     return tuple(x.reshape(b, n, h, dh) for x in (dq, dk, dv))
@@ -574,6 +578,7 @@ def _fwd_bh_drop(q, k, v, seedvec, scale, rate):
             jax.ShapeDtypeStruct((bh, n, dh), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, n), jnp.float32),
         ],
+        name="flash_bh_dropout_fwd",
         interpret=_interpret(),
     )(seedvec, q, k, v)
     return o, lse[:, 0, :]
@@ -607,6 +612,7 @@ def _flash_bh_drop_bwd(scale, rate, res, cts):
                   lse_spec],
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct((bh, n, dh), q.dtype)] * 3,
+        name="flash_bh_dropout_bwd",
         interpret=_interpret(),
     )(seedvec, q, k, v, o, lse[:, None, :], do, dlse[:, None, :])
     return dq, dk, dv, np.zeros(seedvec.shape, jax.dtypes.float0)
@@ -731,6 +737,7 @@ def _fwd4_drop(q, k, v, seedvec, scale, rate):
             jax.ShapeDtypeStruct((b, n, h * dh), q.dtype),
             jax.ShapeDtypeStruct(lse_shape, jnp.float32),
         ],
+        name="flash_4d_dropout_fwd",
         interpret=_interpret(),
     )(seedvec, q3, k3, v3)
     if pad:
@@ -777,6 +784,7 @@ def _flash4_drop_bwd(scale, rate, res, cts):
                   lse_spec],
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct((b, n, h * dh), q.dtype)] * 3,
+        name="flash_4d_dropout_bwd",
         interpret=_interpret(),
     )(seedvec, q3, k3, v3, o3, lse_in, do3, dlse_in)
     return (*(x.reshape(b, n, h, dh) for x in (dq, dk, dv)),
@@ -1143,7 +1151,7 @@ def make_attention_impl(cfg, mesh: Optional[Mesh] = None,
         # einsum path, which GSPMD partitions over the tp-global head dim.
         # MEASURED (round 5, v5e): at 10B dims the dense path costs ~1.9%
         # of step time (10b_slice 114.1 img/s dense vs 116.3 kernel at
-        # matching knobs — BASELINE.md), so the unfused body is cheap at
+        # matching knobs), so the unfused body is cheap at
         # flagship widths; the scan path keeps the kernel.
         wrapped.vitax_pp_impl = None
     else:

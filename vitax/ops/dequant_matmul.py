@@ -39,8 +39,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from vitax.ops.attention import _interpret
 
-# the jaxpr marker VTX-R009 greps for: pallas_call equations carry the
-# kernel function's name in their printed params (one occurrence per launch)
+# the pallas_call `name=`: the jaxpr marker VTX-R009 greps for (one
+# occurrence per launch) and the custom call's op_name in compiled HLO
 DEQUANT_KERNEL_NAME = "dequant_matmul_kernel"
 
 # block caps: x (bm, bk) + w (bk, bn) + acc/out (bm, bn) stay well under
@@ -139,6 +139,7 @@ def _pallas_matmul_call(m: int, k: int, n: int, act: bool, w_dtype: str,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
+        name=DEQUANT_KERNEL_NAME,
         interpret=interpret,
     )
 
@@ -148,6 +149,15 @@ def _fused_2d(x2d, w, scale, sx, act: bool, interpret: bool):
     x*0, padded m/n rows are sliced off) and launch the kernel."""
     m, k = x2d.shape
     n = w.shape[1]
+    if w.dtype == jnp.float8_e4m3:
+        # The export's fp8 is ml_dtypes' IEEE-style float8_e4m3, which Mosaic
+        # cannot load as a vector ("Invalid vector type for load" on a v5e);
+        # float8_e4m3fn it can. Every FINITE e4m3 value has the same bits in
+        # e4m3fn (same bias and subnormals; they differ only where the
+        # exponent is all ones: inf/NaN there, 256..448 here), and a
+        # quantized export holds finite values only (absmax maps to 240, the
+        # format's largest). So reinterpret the bits; nothing is converted.
+        w = jax.lax.bitcast_convert_type(w, jnp.float8_e4m3fn)
     mp = _round_up(m, min(_BM_CAP, _round_up(m, 32 if act else 8)))
     kp = _round_up(k, min(_BK_CAP, _round_up(k, 128)))
     np_ = _round_up(n, min(_BN_CAP, _round_up(n, 128)))

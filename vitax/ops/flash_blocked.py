@@ -34,10 +34,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from vitax.ops.attention import _interpret, dropout_keep_mask
 
-# jax < 0.5 names this TPUCompilerParams; same fields, renamed at 0.5
-if not hasattr(pltpu, "CompilerParams"):
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 NEG_INF = -1e30  # large-but-finite: avoids inf-inf=nan in max/exp chains
 
 """Measured block defaults (round-5 ladder, tools/long_context_ladder.py ->
@@ -140,6 +136,7 @@ def blocked_fwd_padded(q, k, v, n_valid, scale, bq, bk, seed=None,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_blocked_fwd",
         interpret=_interpret(),
     )(n_valid, seed, q, k, v)
     return o, lse[:, 0, :]
@@ -268,6 +265,7 @@ def blocked_bwd_padded(q, k, v, o, lse, do, dlse, n_valid, scale, bq, bk,
                         pltpu.VMEM((bk, dh), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_blocked_dkv",
         interpret=_interpret(),
     )(n_valid, seed, q, k, v, do, lse3, delta, dlse3)
 
@@ -285,6 +283,7 @@ def blocked_bwd_padded(q, k, v, o, lse, do, dlse, n_valid, scale, bq, bk,
         scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_blocked_dq",
         interpret=_interpret(),
     )(n_valid, seed, q, k, v, do, lse3, delta, dlse3)
     return dq, dk, dv

@@ -38,45 +38,12 @@ MESH_AXES = ("dp", "fsdp", "tp", "sp", "pp", "ep")
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma=False, axis_names=None):
-    """jax.shard_map across jax versions — the single spelling every vitax
-    shard_map site goes through. jax >= 0.5 exposes the public jax.shard_map
-    (replication checking under `check_vma`, manual axes under `axis_names`);
-    on 0.4.x the same transform is jax.experimental.shard_map.shard_map with
-    `check_rep` and the complementary `auto` set instead."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": axis_names}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, **kw)
-
-
-def axis_size(axis_name: str) -> int:
-    """jax.lax.axis_size across versions: 0.4.x has no axis_size, but
-    psum(1, axis) of a Python int constant-folds to the bound axis size."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-@jax.custom_jvp
-def optimization_barrier(x):
-    """Differentiable jax.lax.optimization_barrier: 0.4.x defines no
-    differentiation rule for the primitive, so route autodiff around it —
-    the primal is barriered, tangents/cotangents flow through as identity
-    (the barrier IS the identity; only XLA scheduling sees it, and the
-    primal-side barrier is what pins the gather hoisting)."""
-    return jax.lax.optimization_barrier(x)
-
-
-@optimization_barrier.defjvp
-def _optimization_barrier_jvp(primals, tangents):
-    (x,), (t,) = primals, tangents
-    return jax.lax.optimization_barrier(x), t
+    """jax.shard_map with vitax's default: replication (vma) checking off
+    unless a site asks for it (the pipeline's partial-manual tp path does).
+    `axis_names` names the manual axes; None = every mesh axis."""
+    kw = {} if axis_names is None else {"axis_names": axis_names}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
 def resolve_mesh_shape(cfg: Config, n_devices: Optional[int] = None) -> Tuple[int, ...]:

@@ -43,7 +43,7 @@ placements on their weight dims in addition to "pp" on the layer dim, and
   Attention under tp uses the dense einsum path (GSPMD shards it over the
   tp-global head dim; a Pallas kernel cannot be auto-partitioned — at ViT
   sequence lengths the dense path measured ~1.9% of step time at 10B
-  dims on v5e — BASELINE.md round-5 attention A/B).
+  dims on v5e — the round-5 attention A/B).
 Inside the pipeline body each block's leaves are all-gathered
 over "fsdp" right before use — the manual form of the per-block gather
 GSPMD emits on the scan path — and autodiff's transpose of that gather is
@@ -82,7 +82,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from vitax.config import Config
-from vitax.parallel.mesh import BATCH_AXES, optimization_barrier, shard_map
+from vitax.parallel.mesh import BATCH_AXES, shard_map
 from vitax.platform import backend_platform
 
 
@@ -217,7 +217,7 @@ def make_pp_forward(cfg: Config, model, mesh: Mesh, block_specs=None):
                 # once (28.7 GB vs 10.1 GB temps at the 10B flagship shape —
                 # caught by test_10b_shape_lowers_under_pipeline_fsdp). The
                 # barrier makes the gather input depend on the loop carry.
-                layer_params, carry = optimization_barrier(
+                layer_params, carry = jax.lax.optimization_barrier(
                     (layer_params, carry))
                 # ZeRO-3 inside the pipeline: gather this block's shards over
                 # "fsdp" just-in-time (under remat this sits inside the
@@ -317,12 +317,10 @@ def make_pp_forward(cfg: Config, model, mesh: Mesh, block_specs=None):
             acc0 = (jnp.zeros((Lps, cfg.moe_experts), jnp.float32),) * 2 \
                 if collect_aux else (jnp.float32(0.0),) * 2
             buf0 = jnp.zeros_like(mbs[0])
-            if tp_auto and hasattr(jax.lax, "pcast"):
+            if tp_auto:
                 # under vma tracking (the partial-manual tp path) the
                 # carry's type must declare it varies over pp — the tick
-                # output does (each stage holds a different activation).
-                # jax 0.4.x has no vma tracking (check_rep=False on the
-                # partial-auto path), so there is nothing to cast there.
+                # output does (each stage holds a different activation)
                 buf0 = jax.lax.pcast(buf0, ("pp",), to="varying")
             (_, acc_f, acc_p), ys = jax.lax.scan(
                 tick, (buf0, *acc0),

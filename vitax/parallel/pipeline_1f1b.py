@@ -70,7 +70,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from vitax.config import Config
-from vitax.parallel.mesh import BATCH_AXES, optimization_barrier, shard_map
+from vitax.parallel.mesh import BATCH_AXES, shard_map
 from vitax.parallel.pipeline import _gather_over
 
 import optax
@@ -126,7 +126,7 @@ def make_1f1b_value_and_grad(cfg: Config, model, mesh: Mesh, state_specs):
                 # LICM otherwise hoists loop-invariant all-gathers out of
                 # the loop, materializing every layer's gathered weights at
                 # once (the GPipe body's idiom, vitax/parallel/pipeline.py)
-                layer_params, carry = optimization_barrier(
+                layer_params, carry = jax.lax.optimization_barrier(
                     (layer_params, carry))
                 layer_params = jax.tree.map(
                     lambda s, p: _gather_over(p, s, "fsdp"),

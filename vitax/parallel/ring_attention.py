@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from vitax.parallel.mesh import BATCH_AXES, axis_size, shard_map
+from vitax.parallel.mesh import BATCH_AXES, shard_map
 from vitax.platform import backend_platform
 
 
@@ -89,7 +89,7 @@ def _ring_attention_local(q, k, v, *, axis_name: str, scale: float,
     (q, k, v, scale) products. ONE copy of the ring machinery — the
     prefetch-before-compute ordering below is load-bearing for the
     latency hiding described in the module docstring."""
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % sp) for i in range(sp)]
 
     # K and V ride ONE stacked (2, B, N_loc, H, Dh) buffer so each ring step
@@ -186,7 +186,7 @@ def _ring_attention_local_drop(q, k, v, seed, *, axis_name: str,
     per-step seedvec differs."""
     from vitax.ops.attention import _seedvec
 
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     n_loc = q.shape[1]
     q0 = idx.astype(jnp.int32) * n_loc

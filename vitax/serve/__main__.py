@@ -28,6 +28,8 @@ def main(argv=None) -> int:
                           "--ckpt_dir)")
     ns = parser.parse_args(argv)
     cfg = Config(**config_fields_from_namespace(ns)).validate()
+    from vitax.platform import setup_compile_cache
+    setup_compile_cache()  # restarts re-use the AOT buckets' compiles
 
     # the registry's engine constructor (vitax/programs/builder.py):
     # scenario-checked, then npz export or Orbax checkpoint exactly as the

@@ -149,6 +149,9 @@ class ReplicaManager:
         self.restart_total = 0
         self.started = time.time()
         self._lock = threading.Lock()
+        # a chip belongs to one process at a time: this parent imports JAX
+        # only transitively and never initializes a backend, so each replica
+        # child gets its chip (tests/test_entrypoints.py pins it)
         self._spawn = spawn or (lambda argv: subprocess.Popen(argv))
         self._http_get = http_get or http_get_json
         self._sleep = sleep

@@ -276,6 +276,10 @@ class Supervisor:
         self.crash_loop_tolerance = crash_loop_tolerance
         self.term_grace_s = term_grace_s
         self.poll_interval_s = poll_interval_s
+        # a chip belongs to one process at a time: the supervisor never
+        # initializes a JAX backend (its checkpoint probes read directory
+        # listings and sidecars only), so the training child gets the chip
+        # (tests/test_entrypoints.py pins it)
         self._spawn = spawn or (lambda argv: subprocess.Popen(argv))
         # peer-replicated progress counts too: a child surviving on peer
         # restores (no Orbax commit between deaths) is not a crash loop

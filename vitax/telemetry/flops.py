@@ -21,30 +21,38 @@ every MFU the repo reports is the same number.
 
 from __future__ import annotations
 
-# bf16 peak TFLOP/s per chip by TPU generation (public figures). "cpu" keeps
-# CPU smoke runs' MFU finite and self-consistent rather than meaningless.
+from typing import Optional
+
+# bf16 peak TFLOP/s per chip by TPU generation (Google Cloud TPU
+# documentation, the per-generation system-architecture pages), keyed by a
+# substring of the PJRT device_kind. A device that is not here is an error,
+# not a default; a CPU has no entry because a CPU run has no MFU.
 PEAK_TFLOPS = {
     "v4": 275.0,
     "v5 lite": 197.0, "v5e": 197.0,
     "v5p": 459.0,
     "v6e": 918.0, "v6 lite": 918.0,
-    "cpu": 1.0,
 }
 
-DEFAULT_PEAK_TFLOPS = 197.0  # conservative fallback for unknown device kinds
 
-
-def detect_peak_tflops(device_kind: str, override: float = 0.0) -> float:
-    """Per-chip peak TFLOP/s for a PJRT device_kind string; `override` > 0
-    (--peak_tflops) wins unconditionally — the escape hatch for new hardware
-    the table has not met."""
+def detect_peak_tflops(device_kind: str,
+                       override: float = 0.0) -> Optional[float]:
+    """Per-chip peak TFLOP/s for a PJRT device_kind string, or None for the
+    host CPU (its runs report `mfu: null`). `override` > 0 (--peak_tflops)
+    wins unconditionally — the way to name new hardware the table has not
+    met; without it an unknown accelerator kind raises."""
     if override and override > 0:
         return float(override)
     kind = (device_kind or "").lower()
+    if kind == "cpu":
+        return None
     for key, val in PEAK_TFLOPS.items():
         if key in kind:
             return val
-    return DEFAULT_PEAK_TFLOPS
+    raise ValueError(
+        f"no peak TFLOP/s known for device kind {device_kind!r}: add it to "
+        f"vitax/telemetry/flops.py PEAK_TFLOPS with its source, or pass "
+        f"--peak_tflops")
 
 
 def model_flops_per_image(cfg) -> float:
@@ -81,8 +89,11 @@ def model_flops_per_step(cfg) -> float:
 
 
 def mfu(cfg, sec_per_iter: float, n_devices: int,
-        peak_tflops_per_chip: float) -> float:
-    """MFU in [0, 1]: achieved useful FLOP/s over aggregate peak FLOP/s."""
+        peak_tflops_per_chip: Optional[float]) -> Optional[float]:
+    """MFU in [0, 1]: achieved useful FLOP/s over aggregate peak FLOP/s.
+    None where there is no peak to be a share of (a CPU run)."""
+    if peak_tflops_per_chip is None:
+        return None
     if sec_per_iter <= 0 or n_devices <= 0 or peak_tflops_per_chip <= 0:
         return 0.0
     achieved = model_flops_per_step(cfg) / sec_per_iter

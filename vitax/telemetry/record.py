@@ -38,7 +38,9 @@ REQUIRED_STEP_KEYS = (
 
 
 class Recorder:
-    """Fan structured records out to sinks; owns the run's MFU constants."""
+    """Fan structured records out to sinks; owns the run's MFU constants.
+    `peak_tflops` is None on the host CPU, and every step record then
+    carries `mfu: null` (vitax/telemetry/flops.py)."""
 
     def __init__(self, cfg, sinks, n_devices: int, device_kind: str,
                  rank: int = 0):

@@ -7,11 +7,12 @@ raising, so tools/perf_gate.py --validate and tools/lint.sh can report every
 problem in one pass. The contracts guarded here:
 
   - bench payload: the ONE JSON line bench.py prints — metric/value/unit/
-    vs_baseline always present; a measured (non-error) payload must carry
+    vs_baseline always present, with the device block (platform,
+    device_kind, n_devices) typed where present; a measured payload carries
     the full resolved `knobs` object (KNOB_PAYLOAD_KEYS) so the trajectory
     can tell whether two numbers are comparable. Historical payloads
-    (BENCH_r02 and earlier) predate the knobs object; absence is legal,
-    a *malformed* knobs object is not.
+    predate the knobs object; absence is legal, a *malformed* knobs
+    object is not.
   - autotune trial: schema 1, monotone trial ids within a file, phase and
     pruned_by drawn from closed vocabularies, knobs complete.
 """
@@ -92,6 +93,14 @@ def validate_bench_payload(payload, where: str = "bench") -> List[str]:
     if isinstance(payload.get("value"), _NUM) and payload["value"] < 0:
         errs.append(f"{where}: 'value' must be >= 0")
     _typecheck(errs, where, payload, "error", str, required=False)
+    # the device the number came from, as JAX reported it (every payload
+    # bench.py prints carries the block; older records predate it). A CPU
+    # run's mfu is null.
+    _typecheck(errs, where, payload, "platform", str, required=False)
+    _typecheck(errs, where, payload, "device_kind", str, required=False)
+    _typecheck(errs, where, payload, "n_devices", int, required=False)
+    _typecheck(errs, where, payload, "mfu", (*_NUM, type(None)),
+               required=False)
     if "knobs" in payload:
         errs.extend(validate_knobs(payload["knobs"], f"{where}.knobs",
                                    require_all=False))

@@ -3,16 +3,10 @@
 Identical surface to run_vit_training.py (parse_config's full flag set,
 --preset_file included, so a committed autotune winner drives a real run:
 `python -m vitax.train --fake_data --preset_file presets/l14_v5e-1.json`).
-Backend pinning must happen before anything touches jax.devices(), hence
-the force_cpu_if_requested() call ahead of the train import.
 """
 
-from vitax.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
-
-from vitax.config import parse_config  # noqa: E402
-from vitax.train.loop import train  # noqa: E402
+from vitax.config import parse_config
+from vitax.train.loop import train
 
 
 def main(argv=None):

@@ -2,7 +2,7 @@
 resolved-knob payload.
 
 Historically bench.py, tools/profile_step.py and tools/aot_topology.py each
-carried their own copy of the knob flags, and BENCH_r04's `knobs` payload
+carried their own copy of the knob flags, and the round-4 `knobs` payload
 predates the gather-overlap / fused-optimizer / comm-dtype knobs entirely —
 so a trajectory entry could not say what actually ran. This module is the
 single definition all of them (and tools/autotune.py) import:
