@@ -410,8 +410,13 @@ def test_serve_jsonl_contract(served):
     post_bytes(url + "/predict", png_bytes(seed=9))  # at least one record
     path = os.path.join(metrics_dir, "serve.jsonl")
     assert os.path.exists(path)
-    records = [json.loads(line) for line in open(path) if line.strip()]
-    kinds = {r["kind"] for r in records}
+    deadline = time.time() + 5.0    # a record is written after its reply
+    while True:
+        records = [json.loads(line) for line in open(path) if line.strip()]
+        kinds = {r["kind"] for r in records}
+        if "serve_request" in kinds or time.time() >= deadline:
+            break
+        time.sleep(0.02)
     assert "serve_start" in kinds and "serve_request" in kinds
     reqs = [r for r in records if r["kind"] == "serve_request"]
     for rec in reqs:

@@ -516,7 +516,15 @@ def run_bench(url: str, concurrency: int, requests_per_worker: int,
     if chaos_installed is not None:
         summary["chaos"] = chaos_installed
     if serve_jsonl:
-        summary["server"] = summarize_serve_jsonl(serve_jsonl, since=t_start)
+        # the server writes a request's record after its reply: give the
+        # last handlers a moment to reach the file before counting
+        deadline = time.time() + 2.0
+        while True:
+            server = summarize_serve_jsonl(serve_jsonl, since=t_start)
+            if server["records"] >= len(lat) or time.time() >= deadline:
+                break
+            time.sleep(0.02)
+        summary["server"] = server
     return summary
 
 

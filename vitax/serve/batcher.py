@@ -10,6 +10,18 @@ at most the deadline (bounded tail latency).
 Thread-safe by construction: HTTP handler threads only append under the
 condition lock and block on their Future; all engine work happens on the
 one worker thread, so the engine needs no internal locking.
+
+The worker stamps its own timeline (`time.time()`, no holes: each phase
+lasts from its mark to the next, and `t_collect` of batch n+1 is `t_end` of
+batch n) and hands it to `on_batch` with a `batch_id` counting from 0:
+
+  t_collect  waiting for the bucket to fill or the deadline
+  t_stack    batch popped from the queue; `np.stack`
+  t_put      stacked; fault hook, then `predict_fn` (which may mark phases
+             of its own inside: the engine's `t_dispatch`, `t_wait`)
+  t_deliver  `predict_fn` returned; futures resolved
+  t_end      last future resolved (`on_batch` runs after it, so a telemetry
+             write of batch n falls into `collect` of batch n+1)
 """
 
 from __future__ import annotations
@@ -41,16 +53,18 @@ class BatchResult:
     (queue wait, engine latency, occupancy) for telemetry."""
 
     __slots__ = ("classes", "probs", "queue_wait_s", "infer_s",
-                 "batch_size", "bucket")
+                 "batch_size", "bucket", "batch_id", "t_deliver")
 
     def __init__(self, classes, probs, queue_wait_s, infer_s, batch_size,
-                 bucket):
+                 bucket, batch_id=0, t_deliver=0.0):
         self.classes = classes            # (k,) int32 class ids
         self.probs = probs                # (k,) float32 probabilities
         self.queue_wait_s = queue_wait_s  # this request's time in queue
         self.infer_s = infer_s            # engine latency of its batch
         self.batch_size = batch_size      # real requests in the batch
         self.bucket = bucket              # padded bucket it executed in
+        self.batch_id = batch_id          # names its batch's serve_batch span
+        self.t_deliver = t_deliver        # time.time() its results reached the host
 
 
 class DynamicBatcher:
@@ -125,6 +139,7 @@ class DynamicBatcher:
     # --- worker -----------------------------------------------------------
 
     def _run(self) -> None:
+        t_collect = time.time()
         while True:
             with self._cond:
                 while not self._pending and not self._closed:
@@ -144,11 +159,14 @@ class DynamicBatcher:
                 batch = [self._pending.popleft()
                          for _ in range(min(len(self._pending),
                                             self.max_batch))]
-            self._flush(batch)
+            t_collect = self._flush(batch, t_collect, time.time())
 
-    def _flush(self, batch) -> None:  # vtx: ignore[VTX103] predict_fn fences internally (np.asarray on outputs)
+    def _flush(self, batch, t_collect,  # vtx: ignore[VTX103] predict_fn fences internally (np.asarray on outputs)
+               t_stack) -> float:
+        """Run one popped batch; returns its `t_end`, which the worker takes
+        as the next batch's `t_collect`."""
         images = np.stack([img for img, _, _ in batch])
-        t_flush = time.time()
+        t_flush = time.time()   # the `t_put` mark
         try:
             # chaos hook on the worker thread: `hang` stalls the whole batch
             # (the predict-hang drill), `oserror` fails it — delivered to
@@ -159,21 +177,28 @@ class DynamicBatcher:
             for _, fut, _ in batch:
                 if not fut.cancelled():
                     fut.set_exception(e)
-            return
-        infer_s = time.time() - t_flush
+            return time.time()
+        t_deliver = time.time()
+        infer_s = t_deliver - t_flush
         n = len(batch)
         bucket = self.bucket_of(n)
+        batch_id = self.batches_flushed
         self.batches_flushed += 1
         for row, (_, fut, t_enq) in enumerate(batch):
             if not fut.cancelled():
                 fut.set_result(BatchResult(
                     classes=ids[row], probs=probs[row],
                     queue_wait_s=t_flush - t_enq, infer_s=infer_s,
-                    batch_size=n, bucket=bucket))
+                    batch_size=n, bucket=bucket, batch_id=batch_id,
+                    t_deliver=t_deliver))
+        t_end = time.time()
         if self.on_batch is not None:
             try:
-                self.on_batch({"batch_size": n, "bucket": bucket,
-                               "infer_s": infer_s,
-                               "queue_wait_s_max": t_flush - batch[0][2]})
+                self.on_batch({"batch_id": batch_id, "batch_size": n,
+                               "bucket": bucket, "infer_s": infer_s,
+                               "t_collect": t_collect, "t_stack": t_stack,
+                               "t_put": t_flush, "t_deliver": t_deliver,
+                               "t_end": t_end})
             except Exception:  # noqa: BLE001 # vtx: ignore[VTX106] telemetry must not kill serving
                 pass
+        return t_end

@@ -136,6 +136,11 @@ class InferenceEngine:
         self.topk = min(cfg.serve_topk, cfg.num_classes)
         self.buckets = bucket_sizes(cfg.serve_max_batch)
         self.compile_count = 0          # warmup compiles; pinned by tests
+        # (t_dispatch, t_wait) of the last `_run`, time.time(): device_put
+        # returned / the compiled call returned. One worker thread calls
+        # predict, so the batch hook reads its own batch's pair
+        # (vitax/serve/server.py serve_batch)
+        self.phase_marks = (0.0, 0.0)
         # readiness vs liveness: the HTTP server is LIVE as soon as it binds
         # (healthz answers), but READY only once every AOT bucket is compiled
         # and exercised — a fleet router must not dispatch to a warming
@@ -365,9 +370,13 @@ class InferenceEngine:
 
     def _run(self, bucket: int, images: np.ndarray):
         batch = jax.device_put(images, self._batch_shardings[bucket])
+        t_dispatch = time.time()
         if self.scales:
-            return self._compiled[bucket](self.params, self.scales, batch)
-        return self._compiled[bucket](self.params, batch)
+            out = self._compiled[bucket](self.params, self.scales, batch)
+        else:
+            out = self._compiled[bucket](self.params, batch)
+        self.phase_marks = (t_dispatch, time.time())
+        return out
 
     def predict(self, images: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """(n, H, W, 3) uint8 -> (top-k class ids (n, k) int32,

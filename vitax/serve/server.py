@@ -40,10 +40,20 @@ Chaos: --fault_plan (or VITAX_FAULT_PLAN) arms the serve fault sites
 (tools/serve_bench.py --chaos drives this). Fired faults surface as
 kind:"serve_fault" telemetry events.
 
-Observability rides the existing vitax.telemetry Recorder/sinks: one
-schema-versioned JSONL record per request (kind "serve_request") plus
-lifecycle events land in <metrics_dir>/serve.jsonl, summarized by
-tools/serve_bench.py --json for CI.
+Observability rides the existing vitax.telemetry Recorder/sinks: with
+--metrics_dir set, schema-versioned JSONL records land in
+<metrics_dir>/serve.jsonl (summarized by tools/serve_bench.py --json for
+CI); unset, there is no recorder and nothing is built or written. Every
+stamp is time.time() seconds, the clock a device trace shares:
+- kind "serve_request", one per answered request, written after its reply:
+  batch_id, t_start, read_s, decode_s, latency_s, queue_wait_s, infer_s,
+  wake_s, reply_s, batch_size, bucket, topk[, batched]
+  (latency_s = enqueue - t_start + queue_wait_s + infer_s + wake_s, stamped
+  when the handler runs again after its future; reply_s comes after it);
+- kind "serve_batch", one per engine batch, the worker thread's timeline
+  (vitax/serve/batcher.py): batch_id, batch_size, bucket, infer_s,
+  t_collect, t_stack, t_put, t_dispatch, t_wait, t_deliver, t_end;
+- lifecycle events: serve_start, serve_drain, brownout, serve_fault, ...
 """
 
 from __future__ import annotations
@@ -301,7 +311,8 @@ class ServeContext:
             engine.predict, max_batch=cfg.serve_max_batch,
             max_wait_ms=cfg.max_batch_wait_ms,
             bucket_of=lambda n: next_bucket(n, engine.buckets),
-            on_batch=self._record_batch,
+            # no recorder: no hook, and the batcher builds no stats
+            on_batch=self._record_batch if recorder is not None else None,
             queue_max=getattr(cfg, "serve_queue_max", 0))
         # brownout: shed optional work under sustained queue pressure
         # instead of tipping into queue-full sheds (degraded != unready:
@@ -371,8 +382,37 @@ class ServeContext:
             return True
 
     def _record_batch(self, stats: dict) -> None:
-        if self.recorder is not None:
-            self.recorder.event("serve_batch", **stats)
+        """The batcher's five marks plus the engine's two, as one
+        `serve_batch` span record. An engine stand-in that marks nothing
+        gets an empty `put` and `dispatch`: all of predict is `wait`. Only
+        hooked up where there is a recorder."""
+        t_put = stats["t_put"]
+        t_dispatch, t_wait = getattr(self.engine, "phase_marks",
+                                     (t_put, t_put))
+        stats.update(t_dispatch=t_dispatch, t_wait=t_wait)
+        self.recorder.event("serve_batch", **{
+            k: round(v, 6) if isinstance(v, float) else v
+            for k, v in stats.items()})
+
+    def record_request(self, result, topk: int, t_start: float,
+                       read_s: float, decode_s: float, t_woke: float,
+                       t_replied: float, **extra) -> None:
+        """One `serve_request` record, written after the reply; `/predict`
+        and `/predict_batch` both come through here, so it has one shape.
+        `t_woke` is the handler's stamp right after `fut.result()`."""
+        if self.recorder is None:
+            return
+        self.recorder.event(
+            "serve_request", batch_id=result.batch_id,
+            t_start=round(t_start, 6), read_s=round(read_s, 6),
+            decode_s=round(decode_s, 6),
+            latency_s=round(t_woke - t_start, 6),
+            queue_wait_s=round(result.queue_wait_s, 6),
+            infer_s=round(result.infer_s, 6),
+            wake_s=round(t_woke - result.t_deliver, 6),
+            reply_s=round(t_replied - t_woke, 6),
+            batch_size=result.batch_size, bucket=result.bucket,
+            topk=topk, **extra)
 
     def decode(self, body: bytes, content_type: str):
         """(uint8 HWC image, requested topk) from a /predict body."""
@@ -518,8 +558,10 @@ def _make_handler(ctx: ServeContext):
             try:
                 length = int(self.headers.get("Content-Length", 0))
                 body = self.rfile.read(length)
+                t_read = time.time()
                 image, topk = ctx.decode(
                     body, self.headers.get("Content-Type", ""))
+                t_decoded = time.time()
             except Exception as e:  # noqa: BLE001 — client error, not ours
                 ctx.metrics.error()
                 self._reply(400, {"error": f"bad request: {e}"})
@@ -544,21 +586,17 @@ def _make_handler(ctx: ServeContext):
                 ctx.metrics.error()
                 self._reply(503, {"error": f"inference failed: {e}"})
                 return
-            latency_s = time.time() - t0
+            t_woke = time.time()
+            latency_s = t_woke - t0
             ctx.metrics.observe(latency_s, result.queue_wait_s,
                                 result.batch_size, result.bucket)
-            if ctx.recorder is not None:
-                ctx.recorder.event(
-                    "serve_request", latency_s=round(latency_s, 6),
-                    queue_wait_s=round(result.queue_wait_s, 6),
-                    infer_s=round(result.infer_s, 6),
-                    batch_size=result.batch_size, bucket=result.bucket,
-                    topk=topk)
             self._reply(200, {
                 "classes": [int(c) for c in result.classes[:topk]],
                 "probs": [float(p) for p in result.probs[:topk]],
                 "latency_ms": round(latency_s * 1000.0, 3),
             })
+            ctx.record_request(result, topk, t0, t_read - t0,
+                               t_decoded - t_read, t_woke, time.time())
 
         def _predict_batch(self) -> None:
             """Composed dispatch from the fleet router (BatchComposer in
@@ -584,11 +622,15 @@ def _make_handler(ctx: ServeContext):
                 ctx.metrics.error()
                 self._reply(400, {"error": f"bad batch request: {e}"})
                 return
+            read_s = time.time() - t0   # the envelope: shared by its items
             results = [None] * len(bodies)
-            waiting = []  # (index, topk, future)
+            waiting = []  # (index, topk, future, decode_s)
+            answered = []  # (result, topk, decode_s, t_woke)
             for i, (body, ctype) in enumerate(zip(bodies, ctypes)):
                 try:
+                    t_decode = time.time()
                     image, topk = ctx.decode(body, ctype)
+                    decode_s = time.time() - t_decode
                 except Exception as e:  # noqa: BLE001 — client error
                     ctx.metrics.error()
                     results[i] = {"status": 400, "body": json.dumps(
@@ -605,8 +647,8 @@ def _make_handler(ctx: ServeContext):
                                       {"error": f"overloaded: {e}",
                                        "reason": "queue_full"})}
                     continue
-                waiting.append((i, topk, fut))
-            for i, topk, fut in waiting:
+                waiting.append((i, topk, fut, decode_s))
+            for i, topk, fut, decode_s in waiting:
                 try:
                     result = fut.result(timeout=ctx.request_timeout_s)
                 except Exception as e:  # noqa: BLE001
@@ -614,22 +656,22 @@ def _make_handler(ctx: ServeContext):
                     results[i] = {"status": 503, "body": json.dumps(
                         {"error": f"inference failed: {e}"})}
                     continue
-                latency_s = time.time() - t0
+                t_woke = time.time()
+                latency_s = t_woke - t0
                 ctx.metrics.observe(latency_s, result.queue_wait_s,
                                     result.batch_size, result.bucket)
                 if ctx.recorder is not None:
-                    ctx.recorder.event(
-                        "serve_request", latency_s=round(latency_s, 6),
-                        queue_wait_s=round(result.queue_wait_s, 6),
-                        infer_s=round(result.infer_s, 6),
-                        batch_size=result.batch_size, bucket=result.bucket,
-                        topk=topk, batched=True)
+                    answered.append((result, topk, decode_s, t_woke))
                 results[i] = {"status": 200, "body": json.dumps({
                     "classes": [int(c) for c in result.classes[:topk]],
                     "probs": [float(p) for p in result.probs[:topk]],
                     "latency_ms": round(latency_s * 1000.0, 3),
                 })}
             self._reply(200, {"results": results})
+            t_replied = time.time()
+            for result, topk, decode_s, t_woke in answered:
+                ctx.record_request(result, topk, t0, read_s, decode_s,
+                                   t_woke, t_replied, batched=True)
 
     return Handler
 
