@@ -316,7 +316,7 @@ def test_r006_serve_negative(serve_program):
         """compile_count drifted past the bucket set: recompiles happened."""
         buckets = (1,)
         compile_count = 3
-        params = None
+        params = compute_params = None
         _compiled = {1: lambda *a, **k: None}  # accepts anything: also bad
         _batch_shardings = {1: None}
 

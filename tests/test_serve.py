@@ -165,6 +165,297 @@ def test_engine_npz_round_trip_matches_checkpoint(served, tmp_path):
     np.testing.assert_allclose(probs_a, probs_b, rtol=1e-6)
 
 
+# --- the compute tree: weights cast once at warm-up (engine.py) -------------
+
+# every leaf of the tiny bf16 trees below, by what the forward does with it
+# first (vitax/models/vit.py cast_before_use): CAST leaves reach the bucket
+# programs already in bf16, KEPT leaves are read in float32 and must stay so
+CAST = {"patch_embed/proj/kernel", "patch_embed/proj/bias", "pos_embed",
+        "attn/qkv/kernel", "attn/qkv/bias", "attn/proj/kernel",
+        "attn/proj/bias", "mlp/fc1/kernel", "mlp/fc1/bias", "mlp/fc2/kernel",
+        "mlp/fc2/bias", "moe/w1", "moe/b1", "moe/w2", "moe/b2"}
+KEPT = {"norm1/scale", "norm1/bias", "norm2/scale", "norm2/bias",
+        "norm/scale", "norm/bias", "head/kernel", "head/bias",
+        "moe/router/kernel", "moe/router/bias"}
+VARIANTS = {"scan": {}, "unrolled": {"scan_blocks": False},
+            "moe": {"moe_experts": 4}}
+
+
+def perturbed_engine(**kw):
+    """A tiny engine over the trainer's initialisation with EVERY leaf moved
+    off it (LayerNorm scales != 1, biases != 0), sharded as the engine
+    shards: a leaf cast that the forward reads in float32 changes outputs."""
+    import jax.numpy as jnp
+    from vitax.parallel.mesh import build_mesh
+    from vitax.parallel.sharding import param_specs, shardings_of
+    from vitax.serve import engine as serve_engine
+    cfg = tiny_cfg(**kw)
+    mesh = build_mesh(cfg)
+    model = serve_engine._build_model(cfg, mesh, quantized=False)
+    sample = jnp.zeros((mesh.shape["dp"] * mesh.shape["fsdp"],
+                        cfg.image_size, cfg.image_size, 3), jnp.float32)
+
+    def init(rng):
+        params = model.init(rng, sample, True)
+        leaves, treedef = jax.tree.flatten(params)
+        keys = jax.random.split(jax.random.key(7), len(leaves))
+        return treedef.unflatten(
+            [v + 0.3 * jax.random.normal(k, v.shape, v.dtype)
+             for v, k in zip(leaves, keys)])
+
+    abstract = jax.eval_shape(init, jax.random.key(cfg.seed))
+    shardings = shardings_of(mesh, param_specs(abstract, cfg, mesh))
+    params = jax.jit(init, out_shardings=shardings)(jax.random.key(cfg.seed))
+    return serve_engine.InferenceEngine(cfg, mesh, model, params), abstract
+
+
+def leaf_roles(engine):
+    """({cast leaf names}, {kept leaf names}) read off the compute tree
+    itself, block prefix dropped: a kept leaf is the very array `params`
+    holds, a cast one the engine's own bf16 copy."""
+    own = jax.tree_util.tree_flatten_with_path(engine.compute_params)[0]
+    cast, kept = set(), set()
+    for (path, leaf), shared in zip(own, jax.tree.leaves(engine.params)):
+        parts = [k.key for k in path
+                 if k.key != "params" and not k.key.startswith("blocks")]
+        (kept if leaf is shared else cast).add("/".join(parts))
+    return cast, kept
+
+
+def parent_formulation(engine, images):
+    """What the engine served before it kept a compute tree: the same
+    forward jitted over the float32 tree, every cast inside the program."""
+    top_i, top_p = jax.jit(engine._predict_fn())(engine.params, images)
+    return np.asarray(top_i), np.asarray(top_p)
+
+
+@pytest.fixture(scope="module")
+def bf16_engines(devices8):
+    engines = {}
+    for name, kw in VARIANTS.items():
+        engines[name], _ = perturbed_engine(dtype="bfloat16", **kw)
+        engines[name].warmup()
+    return engines
+
+
+@pytest.mark.parametrize("variant", list(VARIANTS))
+def test_compute_tree_bit_identical_to_f32_forward(bf16_engines, variant):
+    engine = bf16_engines[variant]
+    cast, kept = leaf_roles(engine)
+    assert cast == CAST - ({"mlp/fc1/kernel", "mlp/fc1/bias",
+                            "mlp/fc2/kernel", "mlp/fc2/bias"}
+                           if variant == "moe" else
+                           {"moe/w1", "moe/b1", "moe/w2", "moe/b2"})
+    assert kept == KEPT - (set() if variant == "moe" else
+                           {"moe/router/kernel", "moe/router/bias"})
+    assert engine.precast_leaves == sum(
+        a is not b for a, b in zip(jax.tree.leaves(engine.compute_params),
+                                   jax.tree.leaves(engine.params)))
+    s = engine.cfg.image_size
+    images = np.random.default_rng(3).integers(
+        0, 256, (4, s, s, 3), dtype=np.uint8)
+    ids, probs = engine.predict(images)
+    want_ids, want_probs = parent_formulation(engine, images)
+    assert np.array_equal(ids, want_ids)
+    assert np.array_equal(probs, want_probs)     # ==, not allclose
+    # params is what the caller handed in: still float32, every leaf
+    assert {str(v.dtype) for v in jax.tree.leaves(engine.params)} == {
+        "float32"}
+    assert engine.weights_dtype == "float32"
+
+
+@pytest.mark.parametrize("wrong", ["norm1/scale", "norm/bias", "head/kernel",
+                                   "moe/router/kernel"])
+def test_leaf_wrongly_cast_breaks_identity(devices8, monkeypatch, wrong):
+    """The identity test can fail: cast one leaf the forward reads in
+    float32 and the served probabilities move."""
+    from vitax.serve import engine as serve_engine
+    right = serve_engine.cast_before_use
+
+    def too_eager(path):
+        names = [getattr(k, "key", k) for k in path]
+        return right(path) or "/".join(names).endswith(wrong)
+
+    monkeypatch.setattr(serve_engine, "cast_before_use", too_eager)
+    engine, _ = perturbed_engine(
+        dtype="bfloat16", **(VARIANTS["moe"] if "moe" in wrong else {}))
+    engine.warmup()
+    assert wrong in leaf_roles(engine)[0]
+    s = engine.cfg.image_size
+    images = np.random.default_rng(3).integers(
+        0, 256, (4, s, s, 3), dtype=np.uint8)
+    _, probs = engine.predict(images)
+    assert not np.array_equal(probs, parent_formulation(engine, images)[1])
+
+
+def weight_converts(mlir: str, engine, threshold: int):
+    """`stablehlo.convert` ops from float32 whose operand has the shape of a
+    parameter leaf of at least `threshold` bytes (or of its per-layer
+    slice): a weight cast inside the program."""
+    import re
+    shapes = set()
+    for v in jax.tree.leaves(engine.params):
+        if v.size * 4 >= threshold:
+            shapes.add("x".join(map(str, v.shape)))
+            shapes.add("x".join(map(str, v.shape[1:])))
+    found = re.findall(
+        r"stablehlo\.convert [^\n]*\(tensor<([0-9x]+)xf32>\) -> "
+        r"tensor<[0-9x]+xbf16>", mlir)
+    return [shape for shape in found if shape in shapes]
+
+
+def test_bucket_program_has_no_weight_cast(bf16_engines):
+    from vitax.analysis import hlo
+    from vitax.analysis.rules import large_param_threshold_bytes
+    engine = bf16_engines["scan"]
+    threshold = large_param_threshold_bytes(engine.cfg)
+    bucket = engine.buckets[-1]
+    mlir = engine.lower_bucket_mlir(bucket)
+    big_f32 = [a for a in hlo.mlir_main_args(mlir)
+               if a["dtype"] == "f32" and a["bytes"] >= threshold]
+    assert big_f32 == []
+    assert weight_converts(mlir, engine, threshold) == []
+    # the detector sees the parent's program: four stacked block kernels
+    # and the patchify kernel, each cast inside it
+    s = engine.cfg.image_size
+    parent = jax.jit(engine._predict_fn()).lower(
+        engine.params, jax.ShapeDtypeStruct((bucket, s, s, 3), np.uint8))
+    assert len(weight_converts(parent.as_text(), engine, threshold)) == 5
+    assert engine.compile_count == len(engine.buckets)
+
+
+def test_bucket_lowers_over_abstract_params(bf16_engines):
+    """The analysis and AOT arms build the engine over jax.eval_shape
+    params: the compute tree is then eval_shape of the same cast, and the
+    bucket lowers to the very program the live engine compiled."""
+    from vitax.serve.engine import InferenceEngine
+    live = bf16_engines["scan"]
+    _, abstract = perturbed_engine(dtype="bfloat16")
+    engine = InferenceEngine(live.cfg, live.mesh, live.model, abstract)
+    assert all(isinstance(v, jax.ShapeDtypeStruct)
+               for v in jax.tree.leaves(engine.compute_params))
+    bucket = engine.buckets[-1]
+    assert engine.lower_bucket_mlir(bucket) == live.lower_bucket_mlir(bucket)
+    assert engine.compile_count == 0
+    assert engine.precast_leaves == live.precast_leaves
+
+
+def test_precast_stops_at_the_memory_share(devices8, monkeypatch):
+    """Copies are taken largest leaf first, only while the weights on one
+    device stay within WEIGHTS_MEMORY_SHARE of its memory; a leaf left out
+    is cast inside the program as before, and outputs stay bit-identical."""
+    from vitax.analysis import hlo
+    from vitax.analysis.rules import large_param_threshold_bytes
+    from vitax.serve import engine as serve_engine
+    # replicated weights (dp only), so a leaf's shard is the leaf
+    kw = dict(dtype="bfloat16", run_without_fsdp=True)
+    free, _ = perturbed_engine(**kw)               # CPU: no limit reported
+    assert serve_engine.device_memory_limit(free.mesh) is None
+    assert leaf_roles(free)[0] == CAST - {"moe/w1", "moe/b1", "moe/w2",
+                                          "moe/b2"}
+    # room for the two largest copies (the fc1 and fc2 kernels) and a byte
+    # short of the proj kernel's: qkv, proj and the patchify kernel stay out
+    resident = tree_bytes(free.params)
+    copies = sorted((v.size * 2 for v in jax.tree.leaves(free.params)),
+                    reverse=True)
+    proj = 2 * 32 * 32 * 2
+    assert copies[0] == copies[1] > copies[2] > proj
+    room = copies[0] + copies[1] + proj - 1
+    limit = (resident + room) / serve_engine.WEIGHTS_MEMORY_SHARE
+    monkeypatch.setattr(serve_engine, "device_memory_limit",
+                        lambda mesh: limit)
+    engine, _ = perturbed_engine(**kw)
+    cast, kept = leaf_roles(engine)
+    assert {"mlp/fc1/kernel", "mlp/fc2/kernel", "pos_embed"} <= cast
+    assert {"attn/qkv/kernel", "attn/proj/kernel",
+            "patch_embed/proj/kernel"} <= kept
+    assert engine.precast_leaves == free.precast_leaves - 3
+    assert copies[0] + copies[1] < engine.precast_bytes <= room
+    assert engine.param_bytes() == resident + engine.precast_bytes
+    engine.warmup()
+    threshold = large_param_threshold_bytes(engine.cfg)
+    mlir = engine.lower_bucket_mlir(engine.buckets[-1])
+    assert sorted(weight_converts(mlir, engine, threshold)) == [
+        "32x32", "32x96", "8x8x3x32"]   # proj, qkv (a block's slice), patchify
+    assert {a["dtype"] for a in hlo.mlir_main_args(mlir)} == {
+        "bf16", "f32", "ui8"}
+    s = engine.cfg.image_size
+    images = np.random.default_rng(5).integers(
+        0, 256, (3, s, s, 3), dtype=np.uint8)
+    ids, probs = engine.predict(images)
+    want_ids, want_probs = parent_formulation(engine, images)
+    assert np.array_equal(ids, want_ids)
+    assert np.array_equal(probs, want_probs)
+    # no room at all: nothing is pre-cast and the compute tree is params
+    monkeypatch.setattr(serve_engine, "device_memory_limit",
+                        lambda mesh: resident)
+    full, _ = perturbed_engine(**kw)
+    assert full.precast_leaves == 0 and full.compute_params is full.params
+
+
+def tree_bytes(tree) -> int:
+    return sum(int(v.nbytes) for v in jax.tree.leaves(tree))
+
+
+@pytest.fixture(scope="module")
+def engines_by_weights(devices8, bf16_engines):
+    from vitax.analysis.rules import arm_config, build_serve_program
+    f32, _ = perturbed_engine(dtype="float32")
+    int8 = build_serve_program(arm_config("serve_quant"),
+                               arm="serve_quant").engine
+    return {"bfloat16": bf16_engines["scan"], "float32": f32, "int8": int8}
+
+
+@pytest.mark.parametrize("weights", ["bfloat16", "float32", "int8"])
+def test_precast_counters_on_metrics_and_serve_start(
+        engines_by_weights, tmp_path, weights):
+    import dataclasses
+    from vitax.serve import start_server, stop_server
+    engine = engines_by_weights[weights]
+    cfg = dataclasses.replace(engine.cfg, metrics_dir=str(tmp_path))
+    httpd, ctx = start_server(cfg, engine, port=0)
+    try:
+        snap = get_json(
+            f"http://127.0.0.1:{httpd.server_address[1]}/metrics")
+    finally:
+        stop_server(httpd, ctx)
+    with open(tmp_path / "serve.jsonl", encoding="utf-8") as f:
+        start = [json.loads(line) for line in f if line.strip()][0]
+    assert start["kind"] == "serve_start"
+    own = [a for a, b in zip(jax.tree.leaves(engine.compute_params),
+                             jax.tree.leaves(engine.params)) if a is not b]
+    for rec in (snap, start):
+        assert rec["precast_leaves"] == engine.precast_leaves == len(own)
+        assert rec["precast_bytes"] == engine.precast_bytes == tree_bytes(own)
+    if weights == "bfloat16":
+        assert engine.precast_leaves == 11 and engine.precast_bytes > 0
+        assert {str(a.dtype) for a in own} == {"bfloat16"}
+    else:   # nothing to cast: the compute tree IS params, no copy
+        assert engine.compute_params is engine.params
+        assert engine.precast_leaves == engine.precast_bytes == 0
+    # both trees, a shared leaf once, plus the quant scales
+    assert snap["param_bytes"] == engine.param_bytes() == (
+        tree_bytes(engine.params) + tree_bytes(own)
+        + tree_bytes(engine.scales))
+    assert snap["weights_dtype"] == engine.weights_dtype == (
+        "float32" if weights == "bfloat16" else weights)
+
+
+def test_quantized_engine_program_unchanged(engines_by_weights):
+    """A quantized engine takes its int8 leaves as it always did: VTX-R007
+    and VTX-R006 hold on it, and no argument of its program is bf16."""
+    from vitax.analysis import hlo
+    from vitax.analysis.rules import (QUANT_WEIGHTS_RESIDENT,
+                                      SERVE_NO_RECOMPILE, Program)
+    engine = engines_by_weights["int8"]
+    prog = Program(kind="serve", arm="serve_quant", config=engine.cfg,
+                   engine=engine)
+    assert QUANT_WEIGHTS_RESIDENT.check(prog, engine.cfg) == []
+    assert SERVE_NO_RECOMPILE.check(prog, engine.cfg) == []
+    args = hlo.mlir_main_args(engine.lower_bucket_mlir(engine.buckets[-1]))
+    assert {a["dtype"] for a in args} == {"i8", "f32", "ui8"}
+
+
 # --- consolidation round-trip (satellite) -----------------------------------
 
 

@@ -309,8 +309,10 @@ def check_serve_no_recompile(program: Program, cfg: Config) -> List[Finding]:
     try:
         import jax
         bad = np.zeros((b0, s + 1, s + 1, 3), np.uint8)
+        # the tree the bucket was compiled for, so that the shape alone is
+        # what it can reject (a quantized engine's call raises either way)
         eng._compiled[b0](
-            eng.params, jax.device_put(bad, eng._batch_shardings[b0]))
+            eng.compute_params, jax.device_put(bad, eng._batch_shardings[b0]))
         out.append(_finding(
             r, program,
             f"bucket-{b0} executable accepted an unseen input shape "

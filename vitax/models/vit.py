@@ -103,6 +103,35 @@ def _dense(quant_matmul: Optional[Callable], act: bool, features: int, *,
                       use_bias=use_bias, dtype=dtype, name=name)
 
 
+# The sites built with `dtype=self.dtype`: every `_dense` call below except
+# `head` (dtype=float32), and PatchEmbed's conv (`proj` too). Flax's
+# promote_dtype casts their kernel and bias to the compute dtype before the
+# matmul. A new site built that way is added here, beside its constructor.
+_COMPUTE_DTYPE_SITES = ("qkv", "proj", "fc1", "fc2")
+# MoeMlp's expert weights (vitax/models/moe.py: `w1.astype(self.dtype)` at
+# every use); its `router` is a float32 Dense and is not a site above
+_COMPUTE_DTYPE_MOE_PARAMS = ("w1", "b1", "w2", "b2")
+
+
+def cast_before_use(path) -> bool:
+    """Whether the forward casts the param leaf at `path` (a jax key path, or
+    its names) to the model's compute dtype before its first use — so a
+    caller whose weights never change (vitax/serve/engine.py) may hand the
+    forward that leaf already cast and get the same values: promote_dtype
+    and `.astype` are no-ops on a leaf that has the dtype. False for what the
+    forward reads in float32: LayerNorm scale/bias (Flax normalizes and
+    scales in f32), `head`, the MoE router. Decided from the leaf's role in
+    the tree alone — scan-stacked and unrolled block trees answer alike."""
+    names = tuple(getattr(k, "key", k) for k in path)
+    leaf = names[-1]
+    site = names[-2] if len(names) > 1 else ""
+    if leaf == "pos_embed":
+        return True
+    if site == "moe":
+        return leaf in _COMPUTE_DTYPE_MOE_PARAMS
+    return leaf in ("kernel", "bias") and site in _COMPUTE_DTYPE_SITES
+
+
 class PatchEmbed(nn.Module):
     """Conv patchify: (B, H, W, 3) -> (B, N, D). timm PatchEmbed equivalent
     (reference run_vit_training.py:124)."""

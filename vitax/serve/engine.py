@@ -20,10 +20,37 @@ executes precompiled programs only: `compile_count` is exactly
 len(bucket_sizes) after warmup and never moves again — recompiles are
 structurally impossible because `predict` calls AOT executables, which
 reject any shape they were not compiled for (tests/test_serve.py pins this).
+
+The bucket programs do not take `engine.params`: they take the engine's
+COMPUTE TREE (`compute_params`), the same tree with float32 leaves cast to
+the model's compute dtype that the forward would cast before their first use
+(vitax/models/vit.py `cast_before_use`: Dense/Conv kernels and biases of the
+`dtype=self.dtype` sites, MoE expert weights, pos_embed). Served weights
+never change, so that cast is loop-invariant over requests: `warmup` runs it
+once, before the first bucket compiles, and a pre-cast leaf costs the
+per-batch program neither a weight-sized `convert` nor a weight-sized
+temporary. The outputs are bit-identical to the forward on `engine.params`.
+`params` stays what the caller handed in, resident and untouched: the served
+model's source of truth. So a pre-cast leaf is resident twice, and the engine
+takes copies, largest leaf first, only while `params`, the scales and the
+copies together stay within WEIGHTS_MEMORY_SHARE of a device's memory; a
+leaf left out is cast inside the program, as all were before. On a float32
+config, and on a quantized engine (int8/fp8 leaves enter the program as they
+are), the compute tree IS `params`: no copy, no extra program. Accounting,
+also on /metrics and the `serve_start` event:
+
+  precast_leaves   leaves the compute tree holds as its own cast copy
+                   (0 on the float32 and quantized paths)
+  precast_bytes    logical bytes of those copies
+  param_bytes()    everything a warmed engine keeps resident: `params`, the
+                   quant scales, and the pre-cast copies
+  weights_dtype    dtype of `params` (the quant dtype when quantized), not
+                   of the compute tree
 """
 
 from __future__ import annotations
 
+import math
 import time
 from typing import Dict, Optional, Tuple
 
@@ -35,6 +62,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from vitax import faults
 from vitax.config import Config
+from vitax.models.vit import cast_before_use
 from vitax.parallel.mesh import BATCH_AXES, Mesh, batch_pspec, build_mesh
 from vitax.utils.logging import master_print
 
@@ -58,6 +86,30 @@ def next_bucket(n: int, buckets: Tuple[int, ...]) -> int:
     raise ValueError(
         f"batch of {n} exceeds the largest bucket {buckets[-1]} "
         f"(--serve_max_batch); the batcher never emits this")
+
+
+# The share of a device's memory the engine will fill with weights: `params`,
+# the quant scales and the compute tree's pre-cast copies. The rest stays free
+# for the bucket programs' temporaries, for what else the process runs on the
+# device beside serving (an eval-mode forward over `params`, as chip_smoke.py
+# and the benchmark's reference check run, wants a layer or two of float32
+# weights at a time) and for fragmentation.
+WEIGHTS_MEMORY_SHARE = 0.8
+
+
+def device_memory_limit(mesh: Mesh) -> Optional[int]:
+    """`bytes_limit` of the smallest device of the mesh, or None where a
+    device reports none: the CPU backend, a described (not attached) chip."""
+    limits = []
+    for device in mesh.devices.flat:
+        try:
+            stats = device.memory_stats() or {}
+        except jax.errors.JaxRuntimeError:  # "only supported for addressable"
+            stats = {}
+        if "bytes_limit" not in stats:
+            return None
+        limits.append(int(stats["bytes_limit"]))
+    return min(limits)
 
 
 def _quant_model_mode(cfg: Config) -> bool:
@@ -153,6 +205,48 @@ class InferenceEngine:
         self._batch_devices = 1
         for ax in BATCH_AXES:
             self._batch_devices *= mesh.shape.get(ax, 1)
+        from vitax.parallel.sharding import param_specs, shardings_of
+        self._param_shardings = shardings_of(
+            mesh, param_specs(params, cfg, mesh))
+        # the compute tree's plan, from what is in sight here (the model's
+        # compute dtype, the scales, each leaf's role, dtype and shard, the
+        # devices' memory). Nothing is cast yet: `compute_params` runs the
+        # one program, at warmup
+        self._precast = self._plan_precast()
+        picked = [v for v, m in zip(jax.tree.leaves(params), self._precast)
+                  if m]
+        self.precast_leaves = len(picked)
+        self.precast_bytes = sum(
+            int(v.size) * jnp.dtype(model.dtype).itemsize for v in picked)
+        self._compute_params = None if picked else params
+
+    def _plan_precast(self):
+        """One bool a leaf of `params`, in `jax.tree.leaves` order: whether
+        the compute tree holds that leaf as its own cast copy. Candidates
+        are the float32 leaves the forward casts before use; none on a
+        float32 config or a quantized engine. Largest first, a candidate is
+        taken while the weights resident on one device stay within
+        WEIGHTS_MEMORY_SHARE of the device's memory; where no limit is
+        known, all are."""
+        flat = jax.tree_util.tree_flatten_with_path(self.params)[0]
+        take = [False] * len(flat)
+        dtype = jnp.dtype(self.model.dtype)
+        if self.scales or dtype == jnp.float32:
+            return take
+        shard_elems = [math.prod(sh.shard_shape(v.shape)) for (_, v), sh in
+                       zip(flat, jax.tree.leaves(self._param_shardings))]
+        limit = device_memory_limit(self.mesh)
+        room = math.inf if limit is None else (
+            WEIGHTS_MEMORY_SHARE * limit
+            - sum(n * v.dtype.itemsize for n, (_, v) in zip(shard_elems, flat)))
+        candidates = [i for i, (path, v) in enumerate(flat)
+                      if v.dtype == jnp.float32 and cast_before_use(path)]
+        for i in sorted(candidates, key=lambda i: -shard_elems[i]):
+            cost = shard_elems[i] * dtype.itemsize
+            if cost <= room:
+                take[i] = True
+                room -= cost
+        return take
 
     # --- accounting (reported on /metrics and by serve_bench) -------------
 
@@ -171,12 +265,39 @@ class InferenceEngine:
         return str(largest.dtype)
 
     def param_bytes(self) -> int:
-        """Device-resident parameter footprint: weight leaves plus the
-        quant scale side table, logical (unsharded) bytes — the per-replica
-        HBM number the fleet density math runs on."""
+        """Device-resident parameter footprint of the warmed engine: weight
+        leaves, the quant scale side table and the compute tree's pre-cast
+        copies, logical (unsharded) bytes — the per-replica HBM number the
+        fleet density math runs on."""
         total = sum(int(v.nbytes) for v in jax.tree.leaves(self.params))
         total += sum(int(v.nbytes) for v in self.scales.values())
-        return total
+        return total + self.precast_bytes
+
+    @property
+    def compute_params(self):
+        """The tree every bucket program is lowered against and called with:
+        `params` with the planned leaves cast to the model's compute dtype,
+        in the same param_specs layout (a sharded serve mesh keeps its
+        shards); every other leaf is the very array `params` holds. Made on
+        first use — `warmup` asks before the first bucket compiles — and
+        kept. Over abstract params (ShapeDtypeStruct leaves: the analysis
+        and AOT arms) it is `jax.eval_shape` of the same cast, nothing runs.
+        `params` itself when there is nothing to cast."""
+        if self._compute_params is None:
+            dtype = self.model.dtype
+            leaves, treedef = jax.tree.flatten(self.params)
+            mask = self._precast
+            shardings = jax.tree.leaves(self._param_shardings)
+            cast = jax.jit(
+                lambda xs: [x.astype(dtype) for x in xs],
+                out_shardings=[s for s, m in zip(shardings, mask) if m])
+            picked = [x for x, m in zip(leaves, mask) if m]
+            abstract = isinstance(picked[0], jax.ShapeDtypeStruct)
+            done = iter(jax.eval_shape(cast, picked) if abstract
+                        else cast(picked))
+            self._compute_params = treedef.unflatten(
+                [next(done) if m else x for x, m in zip(leaves, mask)])
+        return self._compute_params
 
     # --- constructors -----------------------------------------------------
 
@@ -299,11 +420,10 @@ class InferenceEngine:
     def _lower_bucket(self, bucket: int):
         """Lower (but do not compile) the predict program for one bucket —
         shared by warmup compilation and the analysis rules, which inspect
-        the StableHLO without disturbing compile_count."""
-        from vitax.parallel.sharding import param_specs, shardings_of
+        the StableHLO without disturbing compile_count. Lowered against the
+        compute tree, in the layout of `params`."""
         batch_sh = self._batch_sharding(bucket)
-        param_sh = shardings_of(
-            self.mesh, param_specs(self.params, self.cfg, self.mesh))
+        params, param_sh = self.compute_params, self._param_shardings
         s = self.cfg.image_size
         images = jax.ShapeDtypeStruct((bucket, s, s, 3), jnp.uint8,
                                       sharding=batch_sh)
@@ -313,12 +433,12 @@ class InferenceEngine:
             fn = jax.jit(self._predict_fn(),
                          in_shardings=(param_sh, scale_sh, batch_sh),
                          out_shardings=None)
-            lowered = fn.lower(self.params, self.scales, images)
+            lowered = fn.lower(params, self.scales, images)
         else:
             fn = jax.jit(self._predict_fn(),
                          in_shardings=(param_sh, batch_sh),
                          out_shardings=None)
-            lowered = fn.lower(self.params, images)
+            lowered = fn.lower(params, images)
         return lowered, batch_sh
 
     def lower_bucket_mlir(self, bucket: int) -> str:
@@ -339,7 +459,7 @@ class InferenceEngine:
         if self.scales:
             jaxpr = jax.make_jaxpr(fn)(self.params, self.scales, images)
         else:
-            jaxpr = jax.make_jaxpr(fn)(self.params, images)
+            jaxpr = jax.make_jaxpr(fn)(self.compute_params, images)
         return str(jaxpr)
 
     def _compile_bucket(self, bucket: int) -> jax.stages.Compiled:
@@ -350,8 +470,12 @@ class InferenceEngine:
         return compiled
 
     def warmup(self) -> Dict[int, float]:
-        """AOT-compile every bucket and run each once (first execution pays
-        allocator/transfer setup). Returns {bucket: seconds} for the log."""
+        """Make the compute tree, then AOT-compile every bucket and run each
+        once (first execution pays allocator/transfer setup). Returns
+        {bucket: seconds} for the log."""
+        t0 = time.time()
+        jax.block_until_ready(self.compute_params)
+        cast_s = time.time() - t0
         timings = {}
         s = self.cfg.image_size
         for b in self.buckets:
@@ -362,8 +486,11 @@ class InferenceEngine:
             jax.block_until_ready((idx, probs))
             timings[b] = time.time() - t0
         self.ready = True
-        master_print("serve: warmup compiled buckets "
-                     + ", ".join(f"{b}:{t:.2f}s" for b, t in timings.items()))
+        master_print(
+            "serve: warmup compiled buckets "
+            + ", ".join(f"{b}:{t:.2f}s" for b, t in timings.items())
+            + f"; pre-cast {self.precast_leaves} leaves "
+              f"({self.precast_bytes:,} B) in {cast_s:.2f}s")
         return timings
 
     # --- inference --------------------------------------------------------
@@ -374,7 +501,7 @@ class InferenceEngine:
         if self.scales:
             out = self._compiled[bucket](self.params, self.scales, batch)
         else:
-            out = self._compiled[bucket](self.params, batch)
+            out = self._compiled[bucket](self.compute_params, batch)
         self.phase_marks = (t_dispatch, time.time())
         return out
 

@@ -488,6 +488,12 @@ def _make_handler(ctx: ServeContext):
                     snap["weights_dtype"] = ctx.engine.weights_dtype
                 if hasattr(ctx.engine, "param_bytes"):
                     snap["param_bytes"] = ctx.engine.param_bytes()
+                # how much of param_bytes is the engine's own copy of leaves
+                # cast to the compute dtype once, at warm-up (engine.py
+                # `compute_params`); 0 on float32 and quantized engines
+                if hasattr(ctx.engine, "precast_leaves"):
+                    snap["precast_leaves"] = ctx.engine.precast_leaves
+                    snap["precast_bytes"] = ctx.engine.precast_bytes
                 # tier-2 quant mode flags (PR 16): which activation-quant
                 # and fused-dequant policy this replica's program compiled
                 # with — the fleet router surfaces mixed values during a
@@ -705,7 +711,9 @@ def start_server(cfg: Config, engine: InferenceEngine,
         recorder.event("serve_start", port=httpd.server_address[1],
                        buckets=list(engine.buckets), topk=engine.topk,
                        max_batch_wait_ms=cfg.max_batch_wait_ms,
-                       compile_count=engine.compile_count)
+                       compile_count=engine.compile_count,
+                       precast_leaves=getattr(engine, "precast_leaves", 0),
+                       precast_bytes=getattr(engine, "precast_bytes", 0))
     master_print(f"serve: listening on :{httpd.server_address[1]} "
                  f"(buckets {list(engine.buckets)}, "
                  f"wait {cfg.max_batch_wait_ms}ms, top-{engine.topk})")
