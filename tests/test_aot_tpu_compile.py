@@ -27,6 +27,7 @@ from jax.sharding import SingleDeviceSharding
 L14_ATTN = (32, 256, 16, 64)     # (B, N, H, Dh): ViT-L/14 at batch 32
 TENB_ATTN = (8, 256, 32, 160)    # the 10B widths at the per-chip batch 8
 LONG_ATTN = (1, 4096, 16, 64)    # past MAX_SEQ_IN_VMEM: the streaming kernel
+PACKED_ATTN = (2, 8192, 16, 72)  # MoonViT-SO400M: 2 packed rows, head dim 72
 
 
 @pytest.fixture(scope="module")
@@ -93,6 +94,26 @@ def test_attention_forward_and_vjp_compile(chip, mosaic, family, shape):
     kernels = _kernel_names(compiled)
     assert any(f"{name}_fwd" in k for k in kernels), kernels
     assert len(kernels) >= 2, kernels  # forward and at least one backward
+
+
+def test_packed_attention_forward_and_vjp_compile(chip, mosaic):
+    """The segment-masked streaming kernels at the MoonViT cell's shape: a
+    72-wide block, scalar-prefetched block tables, 8 heads a grid step."""
+    from vitax.ops.flash_blocked import packed_flash_attention
+    one_chip, _ = chip
+
+    def fwd_bwd(q, k, v, segment_ids):
+        o, vjp = jax.vjp(
+            lambda q, k, v: packed_flash_attention(q, k, v, segment_ids),
+            q, k, v)
+        return o, vjp(o)
+
+    x = jax.ShapeDtypeStruct(PACKED_ATTN, jnp.bfloat16, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct(PACKED_ATTN[:2], jnp.int32, sharding=one_chip)
+    compiled = jax.jit(fwd_bwd).lower(x, x, x, seg).compile()
+    kernels = _kernel_names(compiled)
+    for name in ("flash_packed_fwd", "flash_packed_dkv", "flash_packed_dq"):
+        assert any(name in k for k in kernels), kernels
 
 
 @pytest.mark.parametrize("mode", ["int8", "fp8", "int8_act"])

@@ -60,6 +60,21 @@ class Config:
     num_heads: int = 32
     num_blocks: int = 32
     mlp_ratio: float = 4.0
+    mlp_dim: int = 0                    # MLP width as a number; 0 = int(embed_dim * mlp_ratio).
+    #   For a published width that is no ratio of the model's (MoonViT: 4304
+    #   at 1152 wide; 1152 * 3.7361 is 4303)
+    # Native-resolution packed model (MoonViT; vitax/models/vit.py,
+    # vitax/data/packing.py): pack_tokens > 0 selects it. A batch is then
+    # `batch_size` ROWS of pack_tokens pre-cut patches, each row holding up to
+    # pack_images whole images of different grids back to back, attended
+    # within each image only (vitax/ops/flash_blocked.py). These are the
+    # model's shape; none chooses a kernel, a block size or a layout.
+    pack_tokens: int = 0                # tokens a packed row (T); 0 = the fixed-size dense model
+    pack_images: int = 0                # images a packed row can hold (S)
+    max_image_tokens: int = 0           # per-image token limit (MoonViT's in_token_limit: 4096)
+    pos_grid: int = 0                   # side of the learned position table, resized bicubically
+    #   to each image's grid (MoonViT: 64)
+    rope_base: float = 10000.0          # base of the 2D rotary embedding on q and k
     pos_dropout: float = 0.0
     # NOTE: att_dropout > 0 stays on the fused kernels — every attention path
     # (whole-N, streamed, ring/ulysses sp, and their pipeline bodies at tp=1)
@@ -293,18 +308,63 @@ class Config:
         return self.dtype == "bfloat16" and self.resolved_param_gather_dtype == "bfloat16"
 
     @property
+    def packed(self) -> bool:
+        """The native-resolution packed model (pack_tokens > 0)."""
+        return self.pack_tokens > 0
+
+    @property
     def num_patches(self) -> int:
+        """Tokens a sequence: a packed row's length, or the image's patches."""
+        if self.packed:
+            return self.pack_tokens
         return (self.image_size // self.patch_size) ** 2
 
     @property
     def mlp_hidden_dim(self) -> int:
-        return int(self.embed_dim * self.mlp_ratio)
+        return self.mlp_dim or int(self.embed_dim * self.mlp_ratio)
+
+    def _validate_packed(self) -> None:
+        """Every rule of the packed model's shape, in one place."""
+        assert self.pack_images >= 1, (
+            f"--pack_tokens {self.pack_tokens} needs --pack_images >= 1 (the "
+            f"images a row can hold), got {self.pack_images}")
+        assert 0 < self.max_image_tokens <= self.pack_tokens, (
+            f"--max_image_tokens must be in (0, pack_tokens={self.pack_tokens}]"
+            f", got {self.max_image_tokens}: an image is never split over rows")
+        assert self.pos_grid >= 2, (
+            f"--pos_grid must be >= 2 (the learned table resized to each "
+            f"image's grid), got {self.pos_grid}")
+        assert (self.embed_dim // self.num_heads) % 4 == 0, (
+            f"2D RoPE rotates pairs by column and row in turn: the head dim "
+            f"{self.embed_dim // self.num_heads} must be a multiple of 4")
+        assert self.rope_base > 1.0, f"--rope_base must be > 1, got {self.rope_base}"
+        assert self.task == "train", (
+            f"--pack_tokens trains (--task train); --task {self.task} over "
+            f"packed rows is not built")
+        assert (self.tp_size == self.sp_size == self.pp_size == self.ep_size
+                == 1 and self.moe_experts == 0), (
+            "--pack_tokens composes with dp/fsdp only: the packed attention "
+            "kernel has no tp/sp/pp path and no MoE block was run packed")
+        assert self.pos_dropout == self.att_dropout == self.mlp_dropout == 0.0, (
+            "--pack_tokens with dropout is not built (no dropout arm of the "
+            "packed kernel)")
+        assert self.grad_accum_steps == 1 and self.remat_window <= 1, (
+            "--pack_tokens with --grad_accum_steps / --remat_window is not "
+            "built: rows hold different numbers of images, so the loss is a "
+            "mean over the batch's images, not over microbatches")
+        assert self.gather_overlap != "on", (
+            "--gather_overlap on is not built for packed rows")
 
     def validate(self) -> "Config":
         assert self.image_size % self.patch_size == 0, (
             f"image_size {self.image_size} not divisible by patch_size {self.patch_size}")
         assert self.embed_dim % self.num_heads == 0, (
             f"embed_dim {self.embed_dim} not divisible by num_heads {self.num_heads}")
+        assert self.mlp_dim >= 0 and self.pack_tokens >= 0, (
+            f"--mlp_dim {self.mlp_dim} and --pack_tokens {self.pack_tokens} "
+            f"must be >= 0 (0 = mlp_ratio / the fixed-size model)")
+        if self.packed:
+            self._validate_packed()
         assert self.sp_impl in ("ring", "ulysses"), (
             f"unknown sp_impl {self.sp_impl!r} (expected 'ring' or 'ulysses')")
         for name in ("pos_dropout", "att_dropout", "mlp_dropout"):
@@ -650,6 +710,23 @@ def build_parser() -> argparse.ArgumentParser:
     parser.add_argument("--num_heads", type=int, default=32)
     parser.add_argument("--num_blocks", type=int, default=32)
     parser.add_argument("--mlp_ratio", type=float, default=4.0)
+    parser.add_argument("--mlp_dim", type=int, default=0,
+                        help="MLP width as a number (0 = embed_dim * "
+                             "mlp_ratio)")
+    parser.add_argument("--pack_tokens", type=int, default=0,
+                        help=">0: the native-resolution packed model "
+                             "(MoonViT): --batch_size rows of this many "
+                             "pre-cut patches, images of different grids "
+                             "back to back, attended within each image")
+    parser.add_argument("--pack_images", type=int, default=0,
+                        help="images a packed row can hold")
+    parser.add_argument("--max_image_tokens", type=int, default=0,
+                        help="per-image token limit of the packed model")
+    parser.add_argument("--pos_grid", type=int, default=0,
+                        help="side of the learned position table the packed "
+                             "model resizes to each image's grid")
+    parser.add_argument("--rope_base", type=float, default=10000.0,
+                        help="base of the packed model's 2D RoPE")
     parser.add_argument("--pos_dropout", type=float, default=0.0)
     parser.add_argument("--att_dropout", type=float, default=0.0)
     parser.add_argument("--mlp_dropout", type=float, default=0.0)

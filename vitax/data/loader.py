@@ -234,6 +234,17 @@ def build_datasets(cfg: Config, mesh: Mesh):
         from vitax.data.stream import build_stream_datasets
         return build_stream_datasets(cfg, mesh)
 
+    if cfg.packed:
+        # packed native-resolution batches: fake data only so far (packing in
+        # the ImageFolder and stream loaders is a later issue, PERF.md s.7)
+        assert cfg.fake_data, (
+            "--pack_tokens trains on --fake_data only: packing real images "
+            "in the ImageFolder / stream loaders is not built yet")
+        from vitax.data.fake import FakePackedLoader
+        train_loader = FakePackedLoader(cfg, mesh, TRAIN_SPLIT_LEN)
+        val_loader = FakePackedLoader(cfg, mesh, VAL_SPLIT_LEN)
+        return train_loader, train_loader, val_loader, val_loader
+
     if cfg.fake_data:
         train_ds = FakeImageNetDataset(cfg.image_size, TRAIN_SPLIT_LEN)
         val_ds = FakeImageNetDataset(cfg.image_size, VAL_SPLIT_LEN)

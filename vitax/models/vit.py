@@ -26,6 +26,17 @@ Architecture parity notes (verified against the reference by param-count closed 
   (reference run_vit_training.py:155-162)
 - init: trunc-normal(std=0.02) weights, zero biases, LN ones/zeros (timm
   _init_vit_weights semantics, reference run_vit_training.py:125,142,152,128)
+
+The native-resolution packed model (MoonViT; `pack_tokens > 0`) is the same
+`VisionTransformer` / `Block` / scan / remat over another input: rows of
+pre-cut patches holding several images of different grids (vitax/data/
+packing.py). What differs, all decided by the shape: a linear patch map (the
+conv's equal on cut patches), a learned (G, G, D) position table resized
+bicubically to each image's grid (`pos_interp`), 2D RoPE on q and k
+(`rope2d`), attention within each image only (vitax/ops/flash_blocked.py
+packed kernels, or the dense masked fallback below), tanh-GELU, an MLP width
+given as a number, LayerNorm eps 1e-5 throughout, and a mean over each
+image's tokens (`segment_pool`) into per-image logits (R, S, classes).
 """
 
 from __future__ import annotations
@@ -132,6 +143,120 @@ def cast_before_use(path) -> bool:
     return leaf in ("kernel", "bias") and site in _COMPUTE_DTYPE_SITES
 
 
+# --- the packed native-resolution model's pieces (pure functions) ----------
+
+BICUBIC_A = -0.75  # PyTorch's cubic coefficient (jax.image.resize uses -0.5)
+
+
+def _cubic_taps(index: Array, out_size: Array, in_size: int):
+    """PyTorch `F.interpolate(mode="bicubic", align_corners=False)` along one
+    axis: for output `index` of an axis resized from `in_size` to `out_size`,
+    the four source indices (clamped at the border) and their weights.
+    Source coordinate (i + 0.5) * in/out - 0.5, no antialiasing."""
+    src = ((index.astype(jnp.float32) + 0.5)
+           * (in_size / out_size.astype(jnp.float32)) - 0.5)
+    base = jnp.floor(src)
+    t = src - base
+    a = BICUBIC_A
+
+    def near(x):   # |x| <= 1
+        return ((a + 2.0) * x - (a + 3.0)) * x * x + 1.0
+
+    def far(x):    # 1 < |x| < 2
+        return ((a * x - 5.0 * a) * x + 8.0 * a) * x - 4.0 * a
+
+    weights = jnp.stack([far(t + 1.0), near(t), near(1.0 - t), far(2.0 - t)],
+                        axis=-1)
+    taps = base[..., None].astype(jnp.int32) + jnp.arange(-1, 3)
+    return jnp.clip(taps, 0, in_size - 1), weights
+
+
+def pos_interp(table: Array, positions: Array, token_hw: Array,
+               dtype) -> Array:
+    """The learned (G, G, D) table resized to each token's image grid and
+    read at the token's place: per token a 16-tap weighted gather (4 rows x
+    4 columns), run on the MXU as one matmul of a (tokens, G*G) weight
+    matrix with 16 non-zeros a row against the flat table — no gather, and
+    no scatter in the backward. At (h, w) = (G, G) the weights are exactly
+    one-hot: the table itself. positions / token_hw: (R, T, 2) int32 as
+    (row, column) / (h, w) -> (R, T, D) float32."""
+    g = table.shape[0]
+    side = jnp.arange(g)
+
+    def axis_weights(index, size):          # -> (R, T, G), 4 non-zeros
+        taps, w = _cubic_taps(index, size, g)
+        return jnp.sum(w[..., None] * (taps[..., None] == side), axis=-2)
+
+    size = jnp.maximum(token_hw, 1)         # padding tokens: any finite row
+    wy = axis_weights(positions[..., 0], size[..., 0])
+    wx = axis_weights(positions[..., 1], size[..., 1])
+    w = (wy[..., :, None] * wx[..., None, :]).reshape(*wy.shape[:-1], g * g)
+    return jnp.einsum("rtk,kd->rtd", w.astype(dtype),
+                      table.reshape(g * g, -1).astype(dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def rope2d_tables(positions: Array, head_dim: int, base: float):
+    """cos / sin (R, T, head_dim/2) float32 of MoonViT's 2D RoPE: head_dim/4
+    frequencies theta_i = base^(-4i/head_dim); the head vector's adjacent
+    pair j turns by column * theta_{j/2} for even j, by row *
+    theta_{(j-1)/2} for odd j."""
+    theta = base ** (-4.0 * jnp.arange(head_dim // 4, dtype=jnp.float32)
+                     / head_dim)
+    pos = positions.astype(jnp.float32)
+    angles = jnp.stack([pos[..., 1:2] * theta, pos[..., 0:1] * theta],
+                       axis=-1).reshape(*positions.shape[:-1], head_dim // 2)
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_rope2d(x: Array, cos: Array, sin: Array) -> Array:
+    """Rotate the adjacent pairs of (R, T, H, Dh) by the tables, in float32."""
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    c, s = cos[:, :, None, :], sin[:, :, None, :]
+    out = jnp.stack([a * c - b * s, a * s + b * c], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def segment_pool(x: Array, segment_ids: Array, images: int) -> Array:
+    """Mean over each image's tokens: (R, T, D), (R, T) -> (R, S, D) float32
+    (zeros where a row has no such image)."""
+    member = (segment_ids[..., None]
+              == jnp.arange(1, images + 1)).astype(x.dtype)       # (R, T, S)
+    sums = jnp.einsum("rts,rtd->rsd", member, x,
+                      preferred_element_type=jnp.float32)
+    count = jnp.sum(member.astype(jnp.float32), axis=1)           # (R, S)
+    return sums / jnp.maximum(count, 1.0)[..., None]
+
+
+def masked_attention(q: Array, k: Array, v: Array, segment_ids: Array,
+                     dtype) -> Array:
+    """Dense attention within each image of a packed row (the no-kernel
+    path, off the TPU): O(T^2) scores, padding rows come back zero."""
+    scale = q.shape[-1] ** -0.5
+    same = ((segment_ids[:, :, None] == segment_ids[:, None, :])
+            & (segment_ids[:, :, None] > 0))[:, None]             # (R,1,T,T)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(same, s, -1e30), axis=-1)
+    p = jnp.where(same, p, 0.0).astype(dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+def sample_input(cfg: Config, batch: int):
+    """Zeros shaped like the model's input, for `model.init`: an image
+    batch, or the packed model's dict of a packed batch's arrays."""
+    if not cfg.packed:
+        return jnp.zeros((batch, cfg.image_size, cfg.image_size, 3),
+                         jnp.float32)
+    t, s = cfg.pack_tokens, cfg.pack_images
+    return {"patches": jnp.zeros((batch, t, 3 * cfg.patch_size ** 2),
+                                 jnp.float32),
+            "segment_ids": jnp.zeros((batch, t), jnp.int32),
+            "positions": jnp.zeros((batch, t, 2), jnp.int32),
+            "grid_hw": jnp.zeros((batch, s, 2), jnp.int32)}
+
+
 class PatchEmbed(nn.Module):
     """Conv patchify: (B, H, W, 3) -> (B, N, D). timm PatchEmbed equivalent
     (reference run_vit_training.py:124)."""
@@ -156,6 +281,22 @@ class PatchEmbed(nn.Module):
         )(x)
         b, h, w, d = x.shape
         return x.reshape(b, h * w, d)
+
+
+class PatchProj(nn.Module):
+    """PatchEmbed on pre-cut patches: the linear map that equals the p x p
+    stride-p convolution, (R, T, p*p*3) -> (R, T, D) (its kernel is the
+    conv's, reshaped; vitax/data/packing.py:cut_patches gives the order)."""
+
+    embed_dim: int
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, patches: Array) -> Array:
+        return nn.Dense(
+            self.embed_dim, dtype=self.dtype, param_dtype=jnp.float32,
+            kernel_init=default_init, bias_init=nn.initializers.zeros,
+            name="proj")(patches)
 
 
 class Attention(nn.Module):
@@ -183,7 +324,12 @@ class Attention(nn.Module):
     quant_matmul: Optional[Callable] = None
 
     @nn.compact
-    def __call__(self, x: Array, deterministic: bool = True) -> Array:
+    def __call__(self, x: Array, deterministic: bool = True,
+                 segment_ids: Optional[Array] = None,
+                 rope: Optional[Any] = None) -> Array:
+        """`segment_ids` (R, T) and `rope` (cos, sin): the packed model's
+        row context — RoPE on q and k, attention within each image only,
+        through `attention_impl(q, k, v, segment_ids)`."""
         b, n, d = x.shape
         head_dim = d // self.num_heads
 
@@ -198,12 +344,21 @@ class Attention(nn.Module):
         qkv = qkv.reshape(b, n, 3, self.num_heads, head_dim)
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # each (B, N, H, Dh)
 
+        if segment_ids is not None:
+            with jax.named_scope("rope2d"):
+                q = apply_rope2d(q, *rope)
+                k = apply_rope2d(k, *rope)
+
         use_kernel = (
             self.attention_impl is not None
             and (self.att_dropout == 0.0 or deterministic)
         )
         drop_impl = getattr(self.attention_impl, "vitax_dropout", None)
-        if use_kernel:
+        if segment_ids is not None:
+            out = (masked_attention(q, k, v, segment_ids, self.dtype)
+                   if self.attention_impl is None
+                   else self.attention_impl(q, k, v, segment_ids))
+        elif use_kernel:
             out = self.attention_impl(q, k, v)  # (B, N, H, Dh)
         elif drop_impl is not None:
             # in-kernel attention dropout (vitax/ops/attention.py): the fused
@@ -239,6 +394,7 @@ class Mlp(nn.Module):
     dropout: float = 0.0
     dtype: Dtype = jnp.bfloat16
     quant_matmul: Optional[Callable] = None
+    gelu_tanh: bool = False   # MoonViT's gelu_pytorch_tanh; timm's is exact
 
     @nn.compact
     def __call__(self, x: Array, deterministic: bool = True) -> Array:
@@ -247,7 +403,7 @@ class Mlp(nn.Module):
             dtype=self.dtype,
             name="fc1",
         )(x)
-        x = nn.gelu(x, approximate=False)
+        x = nn.gelu(x, approximate=self.gelu_tanh)
         x = nn.Dropout(rate=self.dropout)(x, deterministic=deterministic)
         x = _dense(
             self.quant_matmul, True, self.out_dim,
@@ -280,9 +436,13 @@ class Block(nn.Module):
     moe_dispatch_sharding: Optional[Any] = None
     token_sharding: Optional[Any] = None
     quant_matmul: Optional[Callable] = None
+    mlp_dim: int = 0          # MLP width as a number; 0 = dim * mlp_ratio
+    gelu_tanh: bool = False
 
     @nn.compact
-    def __call__(self, x: Array, deterministic: bool = True) -> Array:
+    def __call__(self, x: Array, deterministic: bool = True,
+                 segment_ids: Optional[Array] = None,
+                 rope: Optional[Any] = None) -> Array:
         d = x.shape[-1]
         if self.token_sharding is not None:
             # re-anchor the carry at every block entry: under the ep mesh the
@@ -311,7 +471,7 @@ class Block(nn.Module):
             qkv_sharding=qkv_sharding,
             quant_matmul=self.quant_matmul,
             name="attn",
-        )(y, deterministic=deterministic)
+        )(y, deterministic, segment_ids, rope)
         x = x + y
         y = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, param_dtype=jnp.float32, name="norm2")(x)
         if self.moe_experts > 0:
@@ -332,11 +492,12 @@ class Block(nn.Module):
             )(y, deterministic=deterministic)
         else:
             y = Mlp(
-                hidden_dim=int(d * self.mlp_ratio),
+                hidden_dim=self.mlp_dim or int(d * self.mlp_ratio),
                 out_dim=d,
                 dropout=self.mlp_dropout,
                 dtype=self.dtype,
                 quant_matmul=self.quant_matmul,
+                gelu_tanh=self.gelu_tanh,
                 name="mlp",
             )(y, deterministic=deterministic)
         return x + y
@@ -403,6 +564,13 @@ class VisionTransformer(nn.Module):
     # the quantized path (vitax/ops/dequant_matmul.make_quant_matmul); None
     # keeps the exact nn.Dense program (training, full-precision serving)
     quant_matmul: Optional[Callable] = None
+    # the native-resolution packed model's shape (Config's fields of the
+    # same names); pack_tokens > 0 selects it
+    mlp_dim: int = 0
+    pack_tokens: int = 0
+    pack_images: int = 0
+    pos_grid: int = 0
+    rope_base: float = 10000.0
 
     def block_kwargs(self) -> dict:
         """Constructor kwargs for one transformer Block — shared between the
@@ -425,29 +593,60 @@ class VisionTransformer(nn.Module):
             moe_dispatch_sharding=self.moe_dispatch_sharding,
             token_sharding=self.token_sharding,
             quant_matmul=self.quant_matmul,
+            mlp_dim=self.mlp_dim,
+            gelu_tanh=self.pack_tokens > 0,
         )
 
+    def _embed_packed(self, batch):
+        """The packed model's embedding: (x (R, T, D), the row context the
+        blocks take: segment ids and the RoPE tables)."""
+        seg, positions = batch["segment_ids"], batch["positions"]
+        x = PatchProj(embed_dim=self.embed_dim, dtype=self.dtype,
+                      name="patch_embed")(batch["patches"].astype(self.dtype))
+        table = self.param("pos_embed", default_init,
+                           (self.pos_grid, self.pos_grid, self.embed_dim),
+                           jnp.float32)
+        with jax.named_scope("pos_interp"):
+            token_hw = jnp.take_along_axis(
+                batch["grid_hw"], jnp.maximum(seg - 1, 0)[..., None], axis=1)
+            x = x + pos_interp(table, positions, token_hw,
+                               self.dtype).astype(self.dtype)
+        # a padding token carries nothing (and never meets a real one)
+        x = jnp.where((seg > 0)[..., None], x, jnp.zeros((), self.dtype))
+        with jax.named_scope("rope2d"):
+            rope = rope2d_tables(positions, self.embed_dim // self.num_heads,
+                                 self.rope_base)
+        return x, (seg, rope)
+
     @nn.compact
-    def __call__(self, images: Array, deterministic: bool = True) -> Array:
-        """images: (B, H, W, 3) float -> logits (B, num_classes) float32."""
-        num_patches = (self.image_size // self.patch_size) ** 2
+    def __call__(self, images, deterministic: bool = True) -> Array:
+        """images: (B, H, W, 3) float -> logits (B, num_classes) float32.
+        Packed model: a dict of a packed batch's `patches` (float),
+        `segment_ids`, `positions`, `grid_hw` (vitax/data/packing.py) ->
+        per-image logits (R, S, num_classes) float32."""
+        packed = self.pack_tokens > 0
+        ctx = ()  # what every block takes beside the carry: the row context
+        if packed:
+            x, ctx = self._embed_packed(images)
+        else:
+            num_patches = (self.image_size // self.patch_size) ** 2
 
-        x = PatchEmbed(
-            patch_size=self.patch_size, embed_dim=self.embed_dim, dtype=self.dtype,
-            name="patch_embed",
-        )(images.astype(self.dtype))
+            x = PatchEmbed(
+                patch_size=self.patch_size, embed_dim=self.embed_dim, dtype=self.dtype,
+                name="patch_embed",
+            )(images.astype(self.dtype))
 
-        pos_embed = self.param(
-            "pos_embed", default_init, (1, num_patches, self.embed_dim), jnp.float32)
-        x = x + pos_embed.astype(self.dtype)
+            pos_embed = self.param(
+                "pos_embed", default_init, (1, num_patches, self.embed_dim), jnp.float32)
+            x = x + pos_embed.astype(self.dtype)
         x = nn.Dropout(rate=self.pos_dropout)(x, deterministic=deterministic)
         if self.token_sharding is not None:
             x = jax.lax.with_sharding_constraint(x, self.token_sharding)
 
         block_kwargs = self.block_kwargs()
 
-        def body(block: Block, carry: Array, det: bool):
-            return block(carry, det), None
+        def body(block: Block, carry: Array, det: bool, *ctx):
+            return block(carry, det, *ctx), None
 
         if self.grad_ckpt:
             policy = _REMAT_POLICIES[self.remat_policy]  # KeyError on unknown names
@@ -470,15 +669,26 @@ class VisionTransformer(nn.Module):
                 variable_axes={"params": 0, "intermediates": 0},
                 split_rngs={"params": True, "dropout": True},
                 length=self.num_blocks,
-                in_axes=(nn.broadcast,),
+                in_axes=(nn.broadcast,) * (1 + len(ctx)),
                 metadata_params={nn.meta.PARTITION_NAME: "layers"},
                 unroll=min(self.scan_unroll, self.num_blocks),
             )
-            x, _ = scan(Block(name="blocks", **block_kwargs), x, deterministic)
+            x, _ = scan(Block(name="blocks", **block_kwargs), x, deterministic,
+                        *ctx)
         else:
             for i in range(self.num_blocks):
-                x, _ = body(Block(name=f"blocks_{i}", **block_kwargs), x, deterministic)
+                x, _ = body(Block(name=f"blocks_{i}", **block_kwargs), x,
+                            deterministic, *ctx)
 
+        if packed:
+            # PyTorch's LayerNorm default, as in the blocks (assumed); then
+            # one mean per image, and the float32 head on (R, S, D)
+            x = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype,
+                             param_dtype=jnp.float32, name="norm")(x)
+            with jax.named_scope("segment_pool"):
+                x = segment_pool(x, ctx[0], self.pack_images)
+            return _dense(self.quant_matmul, False, self.num_classes,
+                          dtype=jnp.float32, name="head")(x)
         x = nn.LayerNorm(epsilon=1e-6, dtype=self.dtype, param_dtype=jnp.float32, name="norm")(x)
         x = jnp.mean(x, axis=1)  # mean-pool over sequence (arXiv:2106.04560)
         if self.token_sharding is not None:
@@ -809,6 +1019,11 @@ def build_model(cfg: Config, attention_impl: Optional[Callable] = None,
         moe_dispatch_sharding=moe_dispatch_sharding,
         token_sharding=token_sharding,
         quant_matmul=quant_matmul,
+        mlp_dim=cfg.mlp_dim,
+        pack_tokens=cfg.pack_tokens,
+        pack_images=cfg.pack_images,
+        pos_grid=cfg.pos_grid,
+        rope_base=cfg.rope_base,
     )
 
 
@@ -830,7 +1045,7 @@ def expected_param_count(cfg: Config) -> int:
         + 2 * (2 * d)          # two LayerNorms
     )
     patch = 3 * cfg.patch_size * cfg.patch_size * d + d
-    pos = n * d
+    pos = (cfg.pos_grid ** 2 if cfg.packed else n) * d
     final_ln = 2 * d
     head = d * cfg.num_classes + cfg.num_classes
     return per_block * cfg.num_blocks + patch + pos + final_ln + head

@@ -973,6 +973,31 @@ def _tpu_kernel(cfg, n: int, force: bool = False, local_heads: int = 0):
     return flash_attention, "pallas fused (whole-N, BH relayout)"
 
 
+def _packed_impl(cfg, mesh: Optional[Mesh], force: bool):
+    """The packed model's core, `impl(q, k, v, segment_ids)`: the
+    segment-masked streaming kernels (vitax/ops/flash_blocked.py) wherever
+    kernels run, shard_map-wrapped over the batch axes on a mesh (rows are
+    independent; Config.validate admits dp/fsdp only). None -> the model's
+    dense masked path."""
+    if not cfg.use_flash_attention or not (
+            force or backend_platform() == "tpu"):
+        return None
+    from vitax.ops.flash_blocked import packed_flash_attention as kernel
+
+    name = "pallas streaming, segment-masked (packed rows)"
+    if mesh is not None and mesh.size > 1:
+        spec = P(BATCH_AXES, None, None, None)
+        kernel = shard_map(
+            kernel, mesh=mesh, in_specs=(spec, spec, spec, P(BATCH_AXES, None)),
+            out_specs=spec, check_vma=False)
+        name += " + shard_map"
+
+    def impl(q, k, v, segment_ids):  # a fresh callable to carry the name
+        return kernel(q, k, v, segment_ids)
+    impl.vitax_name = name
+    return impl
+
+
 def make_attention_impl(cfg, mesh: Optional[Mesh] = None,
                         force_tpu_kernels: bool = False):
     """Choose the attention core for this config/mesh:
@@ -996,6 +1021,8 @@ def make_attention_impl(cfg, mesh: Optional[Mesh] = None,
     pipeline body. The sole dense-under-dropout surface is pp-under-tp
     (structural — warned below).
     """
+    if getattr(cfg, "packed", False):
+        return _packed_impl(cfg, mesh, force_tpu_kernels)
     n = cfg.num_patches
 
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1

@@ -32,7 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from vitax.ops.attention import _interpret, dropout_keep_mask
+from vitax.ops.attention import (_from_bh, _interpret, _to_bh,
+                                 dropout_keep_mask)
 
 NEG_INF = -1e30  # large-but-finite: avoids inf-inf=nan in max/exp chains
 
@@ -433,3 +434,379 @@ def blocked_dropout_attention(q, k, v, seed, rate: float,
     o = blocked_bh_dropout(_to_bh(q), _to_bh(k), _to_bh(v), seed, scale,
                            rate, bq, bk)
     return _from_bh(o, q.shape)
+
+
+# ---------------------------------------------------------------------------
+# packed rows: segment-masked streaming attention that skips dead block pairs
+# ---------------------------------------------------------------------------
+# A packed row holds several images back to back (vitax/data/packing.py):
+# `segment_ids` (R, T) int32 names each token's image (1, 2, ...) and 0 marks
+# padding. Attention is block-diagonal: a token sees the tokens of its own
+# image only, padding sees nothing and nothing sees it (its output and its
+# gradients are zero). The three kernels are the streaming ones above with
+# - the mask `segment_q == segment_k != 0` inside a block, in place of the
+#   count of valid columns;
+# - a table of live (q-block, k-block) pairs, from each block's smallest and
+#   largest segment id, passed as scalar prefetch: a dead pair runs no
+#   matmul, and its index map names the block the pipeline already holds, so
+#   it moves no bytes either. The work follows sum(n_i^2), not T^2;
+# - several heads of one row a grid step (they share the row's mask and its
+#   table), so that what a dead step still costs is paid once for all of them.
+# Matmul operands stay in the input dtype with float32 accumulation, as in
+# the whole-N 4D kernels; softmax and score math is float32.
+
+MASKED = 2 * NEG_INF  # a masked score: exp(MASKED - m) is 0 even while the
+#   running max m is still NEG_INF (a row that has met no key of its own yet)
+
+"""Measured block defaults (my chip runs, PR 26: v5e, 32 x 8,192 x 72 bf16,
+the MoonViT cell's nine images, kernels alone): forward 9.07 ms at (512, 512)
+with 4 heads a step, 5.91 at (512, 1024), 5.67 with 8 heads, 5.55 with 16;
+(512, 2048) 5.45-5.64; (1024, 4096) 6.80. Backward (dK/dV + dQ) 13.73 ms at
+(512, 512) x 4, 12.45 at (512, 1024) x 8, 12.15 x 16, 13.5-14.7 at 1,024 or
+2,048 rows of q. The curve is flat past (512, 1024) x 8, which leaves VMEM
+to spare; with every pair live the same kernels take 2.1 times as long."""
+PACKED_BLOCK_Q = 512
+PACKED_BLOCK_K = 1024
+PACKED_HEADS_PER_STEP = 8
+PACKED_VMEM_LIMIT = 64 * 1024 * 1024  # of the v5e's 128 MiB
+
+
+def packed_block_tables(segment_ids, bq: int, bk: int, skip: bool = True):
+    """What the kernels' grids read as scalar prefetch, from a row's
+    `segment_ids` (R, T), T a multiple of both blocks. A (q-block, k-block)
+    pair is live when the blocks' ranges of non-zero segment ids meet. For
+    the grids that stream k blocks (forward, dQ): `live_qk`, and `kidx`, the
+    k block to hold at each step (the latest live one, so a dead step
+    fetches nothing); for the grid that streams q blocks (dK/dV): `live_kq`
+    and `qidx`. All flat int32. `skip=False` calls every pair live (the
+    tests' and the measurements' comparison arm)."""
+    r, t = segment_ids.shape
+    nq, nk = t // bq, t // bk
+    big = jnp.iinfo(jnp.int32).max
+
+    def ranges(block):
+        s = segment_ids.reshape(r, t // block, block)
+        return jnp.min(jnp.where(s > 0, s, big), axis=-1), jnp.max(s, axis=-1)
+
+    qlo, qhi = ranges(bq)
+    klo, khi = ranges(bk)
+    live = ((qlo[:, :, None] <= khi[:, None, :])
+            & (klo[:, None, :] <= qhi[:, :, None]))          # (R, nq, nk)
+    if not skip:
+        live = jnp.ones_like(live)
+
+    def held(live):  # along the streamed (last) axis
+        n = live.shape[-1]
+        latest = jax.lax.cummax(
+            jnp.where(live, jnp.arange(n, dtype=jnp.int32), -1),
+            axis=live.ndim - 1)
+        first = jnp.argmax(live, axis=-1).astype(jnp.int32)[..., None]
+        return jnp.where(latest >= 0, latest, first)
+
+    live_kq = live.transpose(0, 2, 1)
+    flat = lambda x: x.astype(jnp.int32).reshape(-1)  # noqa: E731
+    return flat(live), flat(held(live)), flat(live_kq), flat(held(live_kq))
+
+
+def _segment_mask(qseg_ref, kseg_ref):
+    """(BQ, BK) bool from the q block's ids, broadcast over lanes
+    (1, BQ, 128), and the k block's, broadcast over sublanes (1, 8, BK)."""
+    seg_q = qseg_ref[0][:, :1]
+    seg_k = kseg_ref[0][:1, :]
+    return (seg_q == seg_k) & (seg_q > 0)
+
+
+def _packed_fwd_kernel(live_ref, kidx_ref, q_ref, k_ref, v_ref, qseg_ref,
+                       kseg_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
+                       scale: float, hb: int, gpr: int, nq: int, nk: int):
+    del kidx_ref  # read by the index maps
+    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(live_ref[((b // gpr) * nq + i) * nk + j] != 0)
+    def _():
+        mask = _segment_mask(qseg_ref, kseg_ref)
+
+        def head(h, carry):
+            q, k, v = q_ref[h], k_ref[h], v_ref[h]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(mask, s, MASKED)
+            m_prev, l_prev = m_ref[h], l_ref[h]      # (BQ, 128), col 0 live
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev[:, :1] - m_new[:, :1])
+            p = jnp.exp(s - m_new[:, :1])
+            l_new = alpha * l_prev[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = jnp.broadcast_to(m_new[:, :1], m_prev.shape)
+            l_ref[h] = jnp.broadcast_to(l_new, l_prev.shape)
+            return carry
+
+        jax.lax.fori_loop(0, hb, head, 0)
+
+    @pl.when(j == nk - 1)
+    def _():
+        def head(h, carry):
+            # a row with no key (padding) has l == 0: its output is 0 and its
+            # lse stays near NEG_INF, where the backward's exp(s - lse) is 0
+            l = jnp.maximum(l_ref[h][:, :1], 1e-30)
+            o_ref[h] = (acc_ref[h] / l).astype(o_ref.dtype)
+            lse_ref[h] = (m_ref[h][:, :1] + jnp.log(l))[:, 0][None, :]
+            return carry
+
+        jax.lax.fori_loop(0, hb, head, 0)
+
+
+def _packed_p_ds(q, k, v, do, lse_row, delta_row, mask, scale):
+    """What both backward kernels recompute for one head's block pair: the
+    probabilities P = exp(S - lse) and dS = P * (dO V^T - delta) * scale,
+    both (BQ, BK) in the input dtype (the MXU's operands). lse / delta
+    arrive as (1, BQ) rows."""
+    lse = lse_row[0][:, None]                         # (BQ, 1)
+    delta = delta_row[0][:, None]
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    p = jnp.exp(jnp.where(mask, s, MASKED) - lse)
+    dp = jax.lax.dot_general(                         # dO V^T
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    ds = p * (dp - delta) * scale
+    return p.astype(q.dtype), ds.astype(q.dtype)
+
+
+def _packed_dkv_kernel(live_ref, qidx_ref, q_ref, k_ref, v_ref, do_ref,
+                       lse_ref, delta_ref, qseg_ref, kseg_ref, dk_ref, dv_ref,
+                       dk_acc, dv_acc, *, scale: float, hb: int, gpr: int,
+                       nq: int, nk: int):
+    del qidx_ref
+    b, jk, jq = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when(jq == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(live_ref[((b // gpr) * nk + jk) * nq + jq] != 0)
+    def _():
+        mask = _segment_mask(qseg_ref, kseg_ref)
+
+        def head(h, carry):
+            p, ds = _packed_p_ds(q_ref[h], k_ref[h], v_ref[h], do_ref[h],
+                                 lse_ref[h], delta_ref[h], mask, scale)
+            dv_acc[h] += jax.lax.dot_general(         # P^T dO
+                p, do_ref[h], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_acc[h] += jax.lax.dot_general(         # dS^T Q
+                ds, q_ref[h], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, hb, head, 0)
+
+    @pl.when(jq == nq - 1)
+    def _():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _packed_dq_kernel(live_ref, kidx_ref, q_ref, k_ref, v_ref, do_ref,
+                      lse_ref, delta_ref, qseg_ref, kseg_ref, dq_ref, dq_acc,
+                      *, scale: float, hb: int, gpr: int, nq: int, nk: int):
+    del kidx_ref
+    b, jq, jk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when(jk == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(live_ref[((b // gpr) * nq + jq) * nk + jk] != 0)
+    def _():
+        mask = _segment_mask(qseg_ref, kseg_ref)
+
+        def head(h, carry):
+            _, ds = _packed_p_ds(q_ref[h], k_ref[h], v_ref[h], do_ref[h],
+                                 lse_ref[h], delta_ref[h], mask, scale)
+            dq_acc[h] += jax.lax.dot_general(         # dS K
+                ds, k_ref[h], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, hb, head, 0)
+
+    @pl.when(jk == nk - 1)
+    def _():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+
+def _packed_params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=PACKED_VMEM_LIMIT)
+
+
+def _segment_tiles(segment_ids):
+    """The ids as the kernels read them: (R, T, 128) for a q block's column,
+    (R, 8, T) for a k block's row (the layouts of jax's own TPU flash
+    attention: no relayout of an integer vector inside the kernel)."""
+    r, t = segment_ids.shape
+    return (jnp.broadcast_to(segment_ids[:, :, None], (r, t, 128)),
+            jnp.broadcast_to(segment_ids[:, None, :], (r, 8, t)))
+
+
+def _packed_fwd(q, k, v, segment_ids, scale, bq, bk, hb, heads, skip):
+    bh, t, dh = q.shape
+    nq, nk, gpr = t // bq, t // bk, heads // hb
+    live, kidx, _, _ = packed_block_tables(segment_ids, bq, bk, skip)
+    qseg, kseg = _segment_tiles(segment_ids)
+
+    def at_k(b, i, j, live, kidx):
+        return kidx[((b // gpr) * nq + i) * nk + j]
+
+    qspec = pl.BlockSpec((hb, bq, dh), lambda b, i, j, *_: (b, i, 0))
+    kspec = pl.BlockSpec((hb, bk, dh), lambda b, i, j, *t: (b, at_k(b, i, j, *t), 0))
+    o, lse = pl.pallas_call(
+        functools.partial(_packed_fwd_kernel, scale=scale, hb=hb, gpr=gpr,
+                          nq=nq, nk=nk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bh // hb, nq, nk),
+            in_specs=[
+                qspec, kspec, kspec,
+                pl.BlockSpec((1, bq, 128), lambda b, i, j, *_: (b // gpr, i, 0)),
+                pl.BlockSpec((1, 8, bk),
+                             lambda b, i, j, *t: (b // gpr, 0, at_k(b, i, j, *t))),
+            ],
+            out_specs=[
+                qspec,
+                pl.BlockSpec((hb, 1, bq), lambda b, i, j, *_: (b, 0, i)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((hb, bq, dh), jnp.float32),
+                pltpu.VMEM((hb, bq, 128), jnp.float32),
+                pltpu.VMEM((hb, bq, 128), jnp.float32),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, t, dh), q.dtype),
+            jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
+        ],
+        compiler_params=_packed_params(),
+        name="flash_packed_fwd",
+        interpret=_interpret(),
+    )(live, kidx, q, k, v, qseg, kseg)
+    return o, lse
+
+
+def _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk, hb, heads,
+                skip):
+    bh, t, dh = q.shape
+    nq, nk, gpr = t // bq, t // bk, heads // hb
+    live_qk, kidx, live_kq, qidx = packed_block_tables(segment_ids, bq, bk,
+                                                       skip)
+    qseg, kseg = _segment_tiles(segment_ids)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1)[:, None, :]                       # (BH, 1, T)
+
+    def at_q(b, jk, jq, live, qidx):
+        return qidx[((b // gpr) * nk + jk) * nq + jq]
+
+    qspec = pl.BlockSpec((hb, bq, dh), lambda b, jk, jq, *t: (b, at_q(b, jk, jq, *t), 0))
+    kspec = pl.BlockSpec((hb, bk, dh), lambda b, jk, jq, *_: (b, jk, 0))
+    row = pl.BlockSpec((hb, 1, bq), lambda b, jk, jq, *t: (b, 0, at_q(b, jk, jq, *t)))
+    dk, dv = pl.pallas_call(
+        functools.partial(_packed_dkv_kernel, scale=scale, hb=hb, gpr=gpr,
+                          nq=nq, nk=nk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bh // hb, nk, nq),
+            in_specs=[
+                qspec, kspec, kspec, qspec, row, row,
+                pl.BlockSpec((1, bq, 128),
+                             lambda b, jk, jq, *t: (b // gpr, at_q(b, jk, jq, *t), 0)),
+                pl.BlockSpec((1, 8, bk), lambda b, jk, jq, *_: (b // gpr, 0, jk)),
+            ],
+            out_specs=[kspec, kspec],
+            scratch_shapes=[pltpu.VMEM((hb, bk, dh), jnp.float32),
+                            pltpu.VMEM((hb, bk, dh), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((bh, t, dh), q.dtype)] * 2,
+        compiler_params=_packed_params(),
+        name="flash_packed_dkv",
+        interpret=_interpret(),
+    )(live_kq, qidx, q, k, v, do, lse, delta, qseg, kseg)
+
+    def at_k(b, jq, jk, live, kidx):
+        return kidx[((b // gpr) * nq + jq) * nk + jk]
+
+    qspec = pl.BlockSpec((hb, bq, dh), lambda b, jq, jk, *_: (b, jq, 0))
+    kspec = pl.BlockSpec((hb, bk, dh), lambda b, jq, jk, *t: (b, at_k(b, jq, jk, *t), 0))
+    row = pl.BlockSpec((hb, 1, bq), lambda b, jq, jk, *_: (b, 0, jq))
+    dq = pl.pallas_call(
+        functools.partial(_packed_dq_kernel, scale=scale, hb=hb, gpr=gpr,
+                          nq=nq, nk=nk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bh // hb, nq, nk),
+            in_specs=[
+                qspec, kspec, kspec, qspec, row, row,
+                pl.BlockSpec((1, bq, 128), lambda b, jq, jk, *_: (b // gpr, jq, 0)),
+                pl.BlockSpec((1, 8, bk),
+                             lambda b, jq, jk, *t: (b // gpr, 0, at_k(b, jq, jk, *t))),
+            ],
+            out_specs=qspec,
+            scratch_shapes=[pltpu.VMEM((hb, bq, dh), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((bh, t, dh), q.dtype),
+        compiler_params=_packed_params(),
+        name="flash_packed_dq",
+        interpret=_interpret(),
+    )(live_qk, kidx, q, k, v, do, lse, delta, qseg, kseg)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _packed_bh(q, k, v, segment_ids, scale, bq, bk, hb, heads, skip):
+    return _packed_fwd(q, k, v, segment_ids, scale, bq, bk, hb, heads,
+                       skip)[0]
+
+
+def _packed_bh_fwd(q, k, v, segment_ids, scale, bq, bk, hb, heads, skip):
+    o, lse = _packed_fwd(q, k, v, segment_ids, scale, bq, bk, hb, heads, skip)
+    return o, (q, k, v, o, lse, segment_ids)
+
+
+def _packed_bh_bwd(scale, bq, bk, hb, heads, skip, res, do):
+    import numpy as np
+    q, k, v, o, lse, segment_ids = res
+    dq, dk, dv = _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk,
+                             hb, heads, skip)
+    return dq, dk, dv, np.zeros(segment_ids.shape, jax.dtypes.float0)
+
+
+_packed_bh.defvjp(_packed_bh_fwd, _packed_bh_bwd)
+
+
+def packed_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                           segment_ids: jax.Array,
+                           block_q: int = PACKED_BLOCK_Q,
+                           block_k: int = PACKED_BLOCK_K,
+                           skip: bool = True) -> jax.Array:
+    """Attention within each image of a packed row: (R, T, H, Dh) q/k/v and
+    (R, T) int32 segment ids (0 = padding) -> (R, T, H, Dh), differentiable
+    in q/k/v. Padding rows come back zero. `block_q`/`block_k`/`skip` exist
+    for the tests (small rows that still span blocks; the every-pair arm)."""
+    r, t, h, dh = q.shape
+    bq = min(block_q, _pad_len(t, 128))
+    bk = min(block_k, _pad_len(t, 128))
+    t_pad = _pad_len(t, math.lcm(bq, bk))
+    hb = math.gcd(h, PACKED_HEADS_PER_STEP)
+    seg = jnp.pad(segment_ids.astype(jnp.int32), ((0, 0), (0, t_pad - t)))
+    qb, kb, vb = (_pad_seq(_to_bh(x), t_pad) for x in (q, k, v))
+    o = _packed_bh(qb, kb, vb, seg, dh ** -0.5, bq, bk, hb, h, skip)
+    return _from_bh(o[:, :t], q.shape)

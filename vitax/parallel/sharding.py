@@ -235,6 +235,7 @@ def gather_overlap_active(cfg: Config, mesh: Mesh) -> bool:
             and cfg.grad_ckpt
             and cfg.remat_policy == "none_saveable"
             and getattr(cfg, "pp_size", 1) == 1
+            and not getattr(cfg, "packed", False)  # no packed arm of the schedule
             and mesh.shape.get("fsdp", 1) > 1)
 
 

@@ -81,6 +81,23 @@ def model_flops_per_image(cfg) -> float:
     return 3.0 * fwd
 
 
+def packed_flops_per_step(cfg, tokens: float, token_pairs: float,
+                          images: float) -> float:
+    """Useful matmul FLOPs of one step of the packed native-resolution
+    model, fwd+bwd (3x forward), from what the step's batch held: `tokens`
+    valid tokens (qkv, proj, fc1, fc2 and the patch map), `token_pairs` =
+    the sum over images of n_i^2 (QK^T and AV within each image) and
+    `images` (the head). Padding, the masked part of a block and the
+    position table's resize are not useful work and are not counted."""
+    d, L = cfg.embed_dim, cfg.num_blocks
+    h = cfg.mlp_hidden_dim
+    per_token = L * (2 * (3 * d * d + d * d) + 2 * (d * h + h * d))
+    per_token += 2 * (3 * cfg.patch_size ** 2) * d             # patch map
+    fwd = per_token * tokens + L * 2 * 2 * token_pairs * d     # QK^T and AV
+    fwd += 2 * d * cfg.num_classes * images                    # head
+    return 3.0 * fwd
+
+
 def model_flops_per_step(cfg) -> float:
     """Useful FLOPs of one optimizer step = per-image x global batch.
     Invariant under --grad_accum_steps and --pp_microbatches (see module
@@ -89,12 +106,17 @@ def model_flops_per_step(cfg) -> float:
 
 
 def mfu(cfg, sec_per_iter: float, n_devices: int,
-        peak_tflops_per_chip: Optional[float]) -> Optional[float]:
+        peak_tflops_per_chip: Optional[float],
+        flops_per_step: Optional[float] = None) -> Optional[float]:
     """MFU in [0, 1]: achieved useful FLOP/s over aggregate peak FLOP/s.
-    None where there is no peak to be a share of (a CPU run)."""
+    None where there is no peak to be a share of (a CPU run).
+    `flops_per_step`: the step's own count (a packed batch's, which no
+    config gives); default: the closed form of the fixed-size model."""
     if peak_tflops_per_chip is None:
         return None
     if sec_per_iter <= 0 or n_devices <= 0 or peak_tflops_per_chip <= 0:
         return 0.0
-    achieved = model_flops_per_step(cfg) / sec_per_iter
+    if flops_per_step is None:
+        flops_per_step = model_flops_per_step(cfg)
+    achieved = flops_per_step / sec_per_iter
     return achieved / (peak_tflops_per_chip * 1e12 * n_devices)

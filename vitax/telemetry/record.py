@@ -6,6 +6,7 @@ versioned, machine-readable record (schema 1):
     schema, time, step, epoch, step_in_epoch, loss, lr, grad_norm,
     sec_per_iter, images_per_sec, tokens_per_sec, data_wait_s, ckpt_stall_s,
     opt_update_s, mfu, mem_used_bytes, mem_peak_bytes[, mem_limit_bytes]
+    [, padding_frac]   (packed batches: from the step's own counters)
 
 MFU comes from the analytic FLOPs model (telemetry/flops.py) over the
 measured sec/iter — no device work, no tracing. `event()` appends
@@ -25,7 +26,7 @@ import time
 from typing import Optional
 
 from vitax.telemetry.flops import (
-    detect_peak_tflops, mfu, model_flops_per_step)
+    detect_peak_tflops, mfu, model_flops_per_step, packed_flops_per_step)
 
 SCHEMA_VERSION = 1
 
@@ -66,7 +67,7 @@ class Recorder:
                     loss: float, lr: float, sec_per_iter: float,
                     data_wait_s: float, grad_norm: Optional[float] = None,
                     ckpt_stall_s: float = 0.0, opt_update_s: float = 0.0,
-                    ) -> dict:
+                    packed_counts: Optional[dict] = None) -> dict:
         """One record per log step. `sec_per_iter` / `data_wait_s` /
         `ckpt_stall_s` are the per-step averages since the previous record;
         `step` is the global optimizer-step count (monotonically increasing
@@ -75,7 +76,17 @@ class Recorder:
         snapshot.py) — the acceptance pin keeps it ~0 on non-final saves.
         `opt_update_s` is the fenced wall time of the optimizer-phase probe
         (vitax/train/step.py make_opt_probe), measured at log steps only —
-        the fused-optimizer win as a number, not an assertion."""
+        the fused-optimizer win as a number, not an assertion.
+        `packed_counts`: a packed step's own counters (`tokens`,
+        `padding_tokens`, `images`, `token_pairs`; vitax/train/step.py) —
+        throughput, MFU and `padding_frac` then come from what the batch
+        held, not from `batch_size x num_patches`."""
+        images, tokens = self.cfg.batch_size, self.tokens_per_step
+        flops_per_step = self.flops_per_step
+        if packed_counts is not None:
+            images, tokens = packed_counts["images"], packed_counts["tokens"]
+            flops_per_step = packed_flops_per_step(
+                self.cfg, tokens, packed_counts["token_pairs"], images)
         record = {
             "schema": SCHEMA_VERSION,
             "time": time.time(),
@@ -85,16 +96,20 @@ class Recorder:
             "loss": float(loss),
             "lr": float(lr),
             "sec_per_iter": float(sec_per_iter),
-            "images_per_sec": (self.cfg.batch_size / sec_per_iter
+            "images_per_sec": (images / sec_per_iter
                                if sec_per_iter > 0 else 0.0),
-            "tokens_per_sec": (self.tokens_per_step / sec_per_iter
+            "tokens_per_sec": (tokens / sec_per_iter
                                if sec_per_iter > 0 else 0.0),
             "data_wait_s": float(data_wait_s),
             "ckpt_stall_s": float(ckpt_stall_s),
             "opt_update_s": float(opt_update_s),
             "mfu": mfu(self.cfg, sec_per_iter, self.n_devices,
-                       self.peak_tflops),
+                       self.peak_tflops, flops_per_step),
         }
+        if packed_counts is not None:
+            slots = tokens + packed_counts["padding_tokens"]
+            record["padding_frac"] = (packed_counts["padding_tokens"] / slots
+                                      if slots else 0.0)
         if grad_norm is not None:
             record["grad_norm"] = float(grad_norm)
         record.update(memory_stats_bytes())

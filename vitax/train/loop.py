@@ -654,7 +654,11 @@ def _run_epochs(cfg, state, train_step, train_loader, val_loader, eval_step,
                                        / max(steps_since_record, 1))
                                       if snap_pipe is not None else 0.0),
                         opt_update_s=opt_update_s,
-                        grad_norm=float(jax.device_get(metrics["grad_norm"])))
+                        grad_norm=float(jax.device_get(metrics["grad_norm"])),
+                        packed_counts=(
+                            {k: float(jax.device_get(metrics[k])) for k in
+                             ("tokens", "padding_tokens", "images",
+                              "token_pairs")} if cfg.packed else None))
                     if "kl" in metrics:
                         # distill step (vitax/programs/workloads.py): the
                         # extra metrics ride the log-step fence the record
@@ -773,7 +777,9 @@ def _run_epochs(cfg, state, train_step, train_loader, val_loader, eval_step,
             # after a staged memcpy (vitax/checkpoint/snapshot.py).
             _save_ckpt(cfg, state, epoch, wait=epoch == cfg.num_epochs,
                        snap_pipe=snap_pipe, replicator=replicator)
-        if epoch % cfg.test_epoch_interval == 0 or epoch == cfg.num_epochs:
+        # evaluation over packed rows is not built (PERF.md section 7)
+        if not cfg.packed and (epoch % cfg.test_epoch_interval == 0
+                               or epoch == cfg.num_epochs):
             top1, top5, _, _ = eval_on_val(cfg, val_loader, eval_step, state,
                                            recorder=recorder, epoch=epoch)
             master_print(f"accuracy on val: {top1:.4f} (top-5 {top5:.4f})")

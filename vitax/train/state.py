@@ -86,7 +86,8 @@ def make_train_state(
     # sample batch must divide evenly over the (dp, fsdp) batch axes — the
     # attention shard_map paths trace through init
     sample_b = mesh.shape["dp"] * mesh.shape["fsdp"]
-    sample = jnp.zeros((sample_b, cfg.image_size, cfg.image_size, 3), jnp.float32)
+    from vitax.models.vit import sample_input
+    sample = sample_input(cfg, sample_b)
 
     def init_fn(rng):
         params = model.init(rng, sample, True)

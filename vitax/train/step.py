@@ -125,6 +125,32 @@ def prepare_images(images: jax.Array) -> jax.Array:
     return (images.astype(jnp.float32) / 255.0 - mean) / std
 
 
+def prepare_patches(patches: jax.Array) -> jax.Array:
+    """`prepare_images` for pre-cut patches (R, T, p*p*3), flattened as
+    (row, column, channel): uint8 -> normalised float32 per channel."""
+    if patches.dtype != jnp.uint8:
+        return patches
+    pixels = patches.reshape(*patches.shape[:-1], -1, 3)
+    return prepare_images(pixels).reshape(patches.shape)
+
+
+def packed_inputs(batch: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+    """The packed model's input (vitax/models/vit.py) from a packed batch
+    (vitax/data/packing.py): its patches normalised, labels left out."""
+    return {"patches": prepare_patches(batch["patches"]),
+            "segment_ids": batch["segment_ids"],
+            "positions": batch["positions"], "grid_hw": batch["grid_hw"]}
+
+
+def packed_loss(logits: jax.Array, batch: Dict[str, jax.Array]) -> jax.Array:
+    """Mean cross-entropy over the IMAGES of a packed batch (rows hold
+    different numbers of them), from per-image logits (R, S, classes)."""
+    ce = optax.softmax_cross_entropy_with_integer_labels(logits,
+                                                         batch["label"])
+    mask = batch["label_mask"].astype(jnp.float32)
+    return jnp.sum(ce * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+
 def _microbatch_split(batch: PyTree, k_steps: int, mesh: Mesh) -> PyTree:
     """Reshape every (B, ...) leaf to (K, B/K, ...) with a STRIDED sample
     assignment: reshape to (B/K, K, ...) then swap the leading axes, so
@@ -281,6 +307,9 @@ def make_train_step(
             # idempotent: leaves the ZeRO-2 path pre-cast (already bf16)
             # untouched; elsewhere the convert-vjp rides the backward
             params = comm.cast(params)
+        if cfg.packed:  # no dropout arm (Config.validate): rng unused
+            return packed_loss(forward(params, packed_inputs(batch), True),
+                               batch)
         images = prepare_images(batch["image"])
         det = not dropout
         r = rng if dropout else None
@@ -447,6 +476,19 @@ def make_train_step(
             # the value via the pure schedule fn
             "lr_step": new_state.step,
         }
+        if cfg.packed:
+            # what a packed step did is in its batch, not in the config:
+            # counted on the device from the segment ids and the label mask
+            seg = batch["segment_ids"]
+            per_image = jnp.sum(
+                seg[..., None] == jnp.arange(1, cfg.pack_images + 1),
+                axis=1, dtype=jnp.int32)                       # (R, S)
+            valid = jnp.sum(per_image)
+            metrics.update(
+                tokens=valid, padding_tokens=seg.size - valid,
+                images=jnp.sum(batch["label_mask"] > 0, dtype=jnp.int32),
+                # sum of n_i^2: the attention's useful work (telemetry MFU)
+                token_pairs=jnp.sum(jnp.square(per_image.astype(jnp.float32))))
         return new_state, metrics
 
     jitted = jax.jit(
@@ -466,8 +508,9 @@ def make_train_step(
 
     def step_with_counts(state, batch, rng):
         new_state, metrics = jitted(state, batch, rng)
-        metrics = dict(metrics, images=images_per_step,
-                       tokens=tokens_per_step)
+        if not cfg.packed:  # a packed step counted its own, on the device
+            metrics = dict(metrics, images=images_per_step,
+                           tokens=tokens_per_step)
         return new_state, metrics
 
     step_with_counts.lower = jitted.lower  # AOT surface (tools/, tests/)
