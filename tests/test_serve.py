@@ -612,6 +612,428 @@ def test_batcher_error_propagates_to_futures():
         b.close()
 
 
+# --- batcher over a two-phase engine (one batch queued behind the running one)
+
+
+class TwoPhaseEngine:
+    """`dispatch(images) -> handle` stand-in whose batches finish when the
+    test says so. Batch k (in order of dispatch) answers row r with class ids
+    `[k, pixel of its image, r]`, so a reply names its batch, its request and
+    its row. `log` is the order of what the worker did."""
+
+    def __init__(self, hold=True, fail_dispatch=(), fail_result=()):
+        self.hold = hold
+        self.fail_dispatch, self.fail_result = set(fail_dispatch), set(fail_result)
+        self.handles, self.log = [], []
+        self.gate = None            # set: dispatch blocks until it is released
+        self.in_dispatch = threading.Event()
+
+    def dispatch(self, images):
+        k = len(self.handles)
+        self.in_dispatch.set()
+        if self.gate is not None:
+            assert self.gate.wait(timeout=30)
+        handle = TwoPhaseHandle(self, k, images)
+        self.handles.append(handle)
+        if k in self.fail_dispatch:
+            self.log.append(("dispatch_failed", k))
+            raise RuntimeError(f"dispatch {k} fell over")
+        self.log.append(("dispatch", k))
+        return handle
+
+    def release(self, k):
+        wait_until(lambda: len(self.handles) > k)
+        self.handles[k].finished.set()
+
+    def order(self, *events):
+        """Positions of `events` in the log, which must hold each once."""
+        assert all(self.log.count(e) == 1 for e in events), self.log
+        return [self.log.index(e) for e in events]
+
+
+class TwoPhaseHandle:
+    def __init__(self, engine, k, images):
+        self.engine, self.k, self.images = engine, k, images
+        self.finished = threading.Event()
+        if not engine.hold:
+            self.finished.set()
+        self.t_dispatch = time.time()
+        self.t_wait = None
+
+    def done(self):
+        return self.finished.is_set()
+
+    def result(self):
+        self.t_wait = time.time()
+        self.engine.log.append(("result_begin", self.k))
+        assert self.finished.wait(timeout=30)
+        self.engine.log.append(("result_end", self.k))
+        if self.k in self.engine.fail_result:
+            raise RuntimeError(f"result {self.k} fell over")
+        n = self.images.shape[0]
+        ids = np.stack([np.array([self.k, self.images[r, 0, 0, 0], r],
+                                 np.int32) for r in range(n)])
+        return ids, np.tile(np.array([0.5, 0.3, 0.2], np.float32), (n, 1))
+
+
+def wait_until(cond, timeout=30.0):
+    deadline = time.time() + timeout
+    while not cond():
+        assert time.time() < deadline, "timed out"
+        time.sleep(0.001)
+
+
+def image(pixel):
+    return np.full((4, 4, 3), pixel, np.uint8)
+
+
+def two_phase_batcher(engine, events=None, **kw):
+    from vitax.serve import DynamicBatcher
+    kw.setdefault("max_batch", 2)
+    kw.setdefault("max_wait_ms", 60_000.0)
+    return DynamicBatcher(None, dispatch_fn=engine.dispatch,
+                          on_batch=None if events is None else events.append,
+                          **kw)
+
+
+BATCH_MARKS = ("t_collect", "t_stack", "t_put", "t_dispatch", "t_wait",
+               "t_deliver", "t_end")
+
+
+def test_full_bucket_is_dispatched_before_the_running_batch_is_fetched():
+    """(a) Three full buckets queued: batch 1 goes to the device before
+    batch 0's `result()` returns, batch 2 only after batch 0 is delivered
+    (one queued batch, never two); every reply reaches its own future, in
+    the order of dispatch."""
+    engine, events = TwoPhaseEngine(), []
+    engine.gate = threading.Event()     # hold batch 0 inside its dispatch ...
+    b = two_phase_batcher(engine, events)
+    try:
+        futs = [b.submit(image(10 + i)) for i in range(6)]
+        assert engine.in_dispatch.wait(timeout=30)
+        engine.gate.set()               # ... until all three buckets wait
+        wait_until(lambda: ("result_begin", 0) in engine.log)
+        assert engine.log == [("dispatch", 0), ("dispatch", 1),
+                              ("result_begin", 0)]
+        time.sleep(0.05)                # the third bucket stays in the queue
+        assert b.queue_depth() == 2 and len(engine.handles) == 2
+        done_order = []
+        for f in futs:
+            f.add_done_callback(lambda f: done_order.append(
+                int(f.result().classes[1])))
+        engine.release(0)       # batch 2 goes out while batch 1 still runs
+        wait_until(lambda: ("result_begin", 1) in engine.log)
+        engine.release(1)
+        engine.release(2)
+        results = [f.result(timeout=30) for f in futs]
+        d1, r0, d2, b1 = engine.order(("dispatch", 1), ("result_end", 0),
+                                      ("dispatch", 2), ("result_begin", 1))
+        assert d1 < r0 < d2 < b1
+        for i, r in enumerate(results):
+            assert list(r.classes) == [i // 2, 10 + i, i % 2]
+            assert (r.batch_id, r.batch_size) == (i // 2, 2)
+        assert done_order == [10, 11, 12, 13, 14, 15]
+        wait_until(lambda: len(events) == 3)
+        assert [e["overlapped"] for e in events] == [0, 1, 1]
+        assert (b.batches_total, b.batches_overlapped) == (3, 2)
+    finally:
+        b.close()
+
+
+@pytest.mark.parametrize("ending", ["bucket_fills", "batch_in_flight_done"])
+def test_partial_bucket_is_not_queued_behind_a_running_batch(ending):
+    """(b) A batch in flight and less than a full bucket pending: the
+    deadline (5 ms) alone flushes nothing. The bucket filling does, at once
+    and ahead of the fetch; failing that, the running batch's delivery."""
+    engine, events = TwoPhaseEngine(), []
+    b = two_phase_batcher(engine, events, max_batch=4, max_wait_ms=5.0)
+    try:
+        first = [b.submit(image(i)) for i in range(4)]
+        wait_until(lambda: ("dispatch", 0) in engine.log)
+        late = [b.submit(image(20 + i)) for i in range(2)]
+        time.sleep(0.1)                 # twenty deadlines
+        assert engine.log == [("dispatch", 0)] and b.queue_depth() == 2
+        if ending == "bucket_fills":
+            late += [b.submit(image(22 + i)) for i in range(2)]
+            wait_until(lambda: ("result_begin", 0) in engine.log)
+            assert engine.log == [("dispatch", 0), ("dispatch", 1),
+                                  ("result_begin", 0)]
+        engine.release(0)
+        assert [int(f.result(timeout=30).classes[1]) for f in first] == [
+            0, 1, 2, 3]
+        engine.release(1)
+        results = [f.result(timeout=30) for f in late]
+        assert [int(r.classes[1]) for r in results] == [
+            20 + i for i in range(len(late))]
+        assert {r.batch_size for r in results} == {len(late)}
+        wait_until(lambda: len(events) == 2)
+        assert [e["overlapped"] for e in events] == [
+            0, int(ending == "bucket_fills")]
+        if ending == "batch_in_flight_done":
+            d1, r0 = engine.order(("dispatch", 1), ("result_end", 0))
+            assert r0 < d1
+            # a batch that did not overlap begins where the last one ended
+            assert events[1]["t_collect"] == events[0]["t_end"]
+    finally:
+        b.close()
+
+
+def test_nothing_in_flight_keeps_the_deadline_rule():
+    """(c) A lone request waits for company until its deadline, then goes
+    alone; a full bucket goes at once."""
+    engine = TwoPhaseEngine(hold=False)
+    b = two_phase_batcher(engine, max_batch=4, max_wait_ms=50.0)
+    try:
+        t0 = time.time()
+        r = b.submit(image(7)).result(timeout=30)
+        assert time.time() - t0 >= 0.04 and r.batch_size == 1
+        t0 = time.time()
+        full = [b.submit(image(i)) for i in range(4)]
+        assert {f.result(timeout=30).batch_size for f in full} == {4}
+        assert time.time() - t0 < 0.04
+    finally:
+        b.close()
+
+
+@pytest.mark.parametrize("failing", ["dispatch", "result"])
+@pytest.mark.parametrize("overlap", [False, True])
+def test_a_failure_reaches_its_own_batch_only(failing, overlap):
+    """(d) Batch 1 fails, in its dispatch or in its `result()`, alone or
+    queued behind batch 0: its futures get the exception, batches 0 and 2
+    their answers, and the worker lives."""
+    engine = TwoPhaseEngine(hold=overlap, **{f"fail_{failing}": [1]})
+    b = two_phase_batcher(engine)
+    try:
+        if overlap:
+            engine.gate = threading.Event()
+        futs = [b.submit(image(i)) for i in range(4)]
+        if overlap:
+            assert engine.in_dispatch.wait(timeout=30)
+            engine.gate.set()
+            wait_until(lambda: ("result_begin", 0) in engine.log)
+            for k in range(2):
+                engine.release(k)
+        for f in futs[:2]:
+            assert int(f.result(timeout=30).classes[0]) == 0
+        for f in futs[2:]:
+            with pytest.raises(RuntimeError, match=f"{failing} 1 fell over"):
+                f.result(timeout=30)
+        engine.hold = False
+        again = [b.submit(image(30 + i)) for i in range(2)]
+        assert [int(f.result(timeout=30).classes[1]) for f in again] == [
+            30, 31]
+    finally:
+        b.close()
+
+
+def test_close_delivers_the_batch_in_flight_and_the_queue():
+    """(e) `close()` with one batch running and a partial bucket queued:
+    both are answered before the worker is joined."""
+    engine = TwoPhaseEngine()
+    b = two_phase_batcher(engine)
+    futs = [b.submit(image(i)) for i in range(2)]
+    wait_until(lambda: ("dispatch", 0) in engine.log)
+    futs.append(b.submit(image(2)))
+    closer = threading.Thread(target=b.close)
+    closer.start()
+    try:
+        time.sleep(0.02)
+        assert closer.is_alive() and not futs[0].done()
+        engine.hold = False
+        engine.release(0)
+        assert [int(f.result(timeout=30).classes[1]) for f in futs] == [
+            0, 1, 2]
+    finally:
+        closer.join(timeout=30)
+    assert not closer.is_alive() and not b._worker.is_alive()
+    with pytest.raises(RuntimeError, match="closed"):
+        b.submit(image(3))
+
+
+def test_many_submitters_against_an_overlapping_worker():
+    """Stress: 16 threads submit 40 requests each while the worker runs one
+    batch behind another, on a 10 us switch interval. Every reply is its own
+    request's, batches come back in the order of dispatch, and the counters
+    add up."""
+    engine, events = TwoPhaseEngine(hold=False), []
+    slow = engine.dispatch
+
+    def dispatch(images):       # a batch is done 2 ms after its dispatch
+        handle = slow(images)
+        handle.finished.clear()
+        threading.Timer(0.002, handle.finished.set).start()
+        return handle
+
+    engine.dispatch = dispatch
+    b = two_phase_batcher(engine, events, max_batch=4, max_wait_ms=1.0)
+    wrong, delivered = [], []
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        def submitter(i):
+            for k in range(40):
+                pixel = (7 * i + k) % 256
+                r = b.submit(image(pixel)).result(timeout=60)
+                if int(r.classes[1]) != pixel:
+                    wrong.append((i, k, r.classes))
+                delivered.append(r.batch_id)
+
+        threads = [threading.Thread(target=submitter, args=(i,))
+                   for i in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+        b.close()
+    assert not wrong and len(delivered) == 640
+    assert [e["batch_id"] for e in events] == list(range(len(events)))
+    assert sum(e["batch_size"] for e in events) == 640
+    assert b.batches_total == len(events) == len(engine.handles)
+    assert b.batches_overlapped == sum(e["overlapped"] for e in events) > 0
+    ends = [e["t_end"] for e in events]
+    assert ends == sorted(ends)         # delivered in the order of dispatch
+
+
+def overlapped_records():
+    """Records of four full buckets through a two-phase engine, queued
+    before the first is fetched; each batch runs 20 ms."""
+    engine, events = TwoPhaseEngine(), []
+    engine.gate = threading.Event()
+    b = two_phase_batcher(engine, events)
+    try:
+        futs = [b.submit(image(i)) for i in range(8)]
+        assert engine.in_dispatch.wait(timeout=30)
+        engine.gate.set()
+        for k in range(4):
+            time.sleep(0.02)
+            engine.release(k)
+        for f in futs:
+            f.result(timeout=30)
+    finally:
+        b.close()
+    return engine, events
+
+
+def plain_records():
+    """The same four buckets through a `predict_fn` that blocks 20 ms."""
+    from vitax.serve import DynamicBatcher
+    events, started = [], threading.Event()
+    gate = threading.Event()
+
+    def predict(images):
+        started.set()
+        assert gate.wait(timeout=30)
+        return _fake_predict([], delay_s=0.02)(images)
+
+    b = DynamicBatcher(predict, max_batch=2, max_wait_ms=60_000.0,
+                       on_batch=events.append)
+    try:
+        futs = [b.submit(image(i)) for i in range(8)]
+        assert started.wait(timeout=30)
+        gate.set()
+        for f in futs:
+            f.result(timeout=30)
+    finally:
+        b.close()
+    return None, events
+
+
+def check_marks_in_order(engine, events):
+    assert [e["batch_id"] for e in events] == [0, 1, 2, 3]
+    for e in events:
+        marks = [e[m] for m in BATCH_MARKS]
+        assert marks == sorted(marks), e
+        assert e["infer_s"] == e["t_deliver"] - e["t_put"]
+        assert set(e) == {"batch_id", "batch_size", "bucket", "infer_s",
+                          "overlapped", *BATCH_MARKS}
+
+
+def check_marks_are_the_batchs_own(engine, events):
+    """`t_dispatch` and `t_wait` come off the batch's handle: with two in
+    flight, the engine's last marks would be the neighbour's."""
+    assert [e["overlapped"] for e in events] == [0, 1, 1, 1]
+    for e, handle in zip(events, engine.handles):
+        assert (e["t_dispatch"], e["t_wait"]) == (handle.t_dispatch,
+                                                  handle.t_wait)
+    for prev, nxt in zip(events, events[1:]):
+        # batch n+1 was on the device before batch n's fetch began, and its
+        # own fetch began after batch n's futures were resolved
+        assert nxt["t_dispatch"] <= prev["t_wait"]
+        assert nxt["t_wait"] >= prev["t_end"]
+
+
+def check_overlapped_timelines(engine, events):
+    """A batch dispatched behind a running one has its collect, stack, put
+    and dispatch marks inside that batch's device time."""
+    for prev, nxt in zip(events, events[1:]):
+        assert nxt["t_collect"] < prev["t_deliver"]
+        assert prev["t_dispatch"] <= nxt["t_dispatch"] <= prev["t_deliver"]
+    # after the first pair: collect of n+1 begins at the end of n-1
+    for before, nxt in zip(events[1:], events[3:]):
+        assert nxt["t_collect"] == before["t_end"]
+
+
+def check_plain_predict_never_overlaps(engine, events):
+    """(g) A `predict_fn` that blocks gives the parent's records: no hole
+    between two batches, and a handle that marks nothing leaves `put` and
+    `dispatch` empty."""
+    assert [e["overlapped"] for e in events] == [0, 0, 0, 0]
+    for prev, nxt in zip(events, events[1:]):
+        assert nxt["t_collect"] == prev["t_end"]
+    for e in events:
+        assert e["t_put"] == e["t_dispatch"] == e["t_wait"]
+        assert e["t_deliver"] - e["t_wait"] >= 0.02
+
+
+RECORD_STATEMENTS = {
+    "two_phase-marks_in_order": (overlapped_records, check_marks_in_order),
+    "two_phase-own_marks": (overlapped_records, check_marks_are_the_batchs_own),
+    "two_phase-timelines_overlap": (overlapped_records,
+                                    check_overlapped_timelines),
+    "plain-marks_in_order": (plain_records, check_marks_in_order),
+    "plain-never_overlaps": (plain_records,
+                             check_plain_predict_never_overlaps),
+}
+
+
+@pytest.mark.parametrize("statement", sorted(RECORD_STATEMENTS))
+def test_serve_batch_records_of_overlapped_batches(statement):
+    """(f), (g)"""
+    records, check = RECORD_STATEMENTS[statement]
+    check(*records())
+
+
+@pytest.mark.parametrize("weights", ["bfloat16", "float32", "int8"])
+def test_predict_is_dispatch_then_result(engines_by_weights, weights):
+    """`predict` against `dispatch().result()`, bitwise, with a second batch
+    queued behind the first and the answers fetched in order; the marks are
+    each handle's own."""
+    engine = engines_by_weights[weights]
+    if not engine.ready:
+        engine.warmup()
+    s = engine.cfg.image_size
+    rng = np.random.default_rng(11)
+    batches = [rng.integers(0, 256, (n, s, s, 3), dtype=np.uint8)
+               for n in (3, 4)]
+    want = [engine.predict(images) for images in batches]
+    compiles = engine.compile_count
+    handles = [engine.dispatch(images) for images in batches]
+    assert all(h.t_wait is None for h in handles)
+    got = [h.result() for h in handles]
+    for (ids, probs), (want_ids, want_probs) in zip(got, want):
+        assert ids.dtype == np.int32 and ids.shape == want_ids.shape
+        assert np.array_equal(ids, want_ids)
+        assert np.array_equal(probs, want_probs)    # ==, not allclose
+    wait_until(lambda: all(h.done() for h in handles))
+    first, second = handles
+    assert first.t_dispatch <= second.t_dispatch <= first.t_wait <= second.t_wait
+    assert engine.compile_count == compiles
+    assert not hasattr(engine, "phase_marks")   # the marks live on the handle
+
+
 # --- HTTP -------------------------------------------------------------------
 
 

@@ -21,6 +21,19 @@ len(bucket_sizes) after warmup and never moves again — recompiles are
 structurally impossible because `predict` calls AOT executables, which
 reject any shape they were not compiled for (tests/test_serve.py pins this).
 
+A batch goes through the engine in two phases, split where the host would
+block. `dispatch(images)` does all the host's work — fault hook, bucket
+choice, padding, `device_put`, the compiled call — and returns a `Dispatched`
+handle at once: the device runs the batch (behind the one before it, if that
+is still running) while the caller does something else. `handle.result()`
+blocks on the outputs, fetches the top-k and cuts the padding off;
+`handle.done()` says without blocking whether they are ready. The handle
+carries its own batch's two marks, `t_dispatch` (`device_put` returned) and
+`t_wait` (`result()` began to block): with two batches in flight, marks kept
+on the engine would be the other batch's. `predict(images)` is
+`dispatch(images).result()`. The batcher (vitax/serve/batcher.py) keeps at
+most one batch queued on the device behind the one that runs.
+
 The bucket programs do not take `engine.params`: they take the engine's
 COMPUTE TREE (`compute_params`), the same tree with float32 leaves cast to
 the model's compute dtype that the forward would cast before their first use
@@ -149,12 +162,38 @@ def _build_model(cfg: Config, mesh: Mesh, quantized: bool = True):
         quant_matmul=quant_matmul)
 
 
+class Dispatched:
+    """One batch between `InferenceEngine.dispatch` and its answers: the
+    compiled call's outputs, still on the device, and the batch's own marks
+    (`time.time()`): `t_dispatch` when `device_put` returned, `t_wait` when
+    `result()` began to block (None until then)."""
+
+    __slots__ = ("_outputs", "_n", "t_dispatch", "t_wait")
+
+    def __init__(self, outputs, n: int, t_dispatch: float):
+        self._outputs = outputs       # (top_i, top_p) of the padded bucket
+        self._n = n                   # real rows
+        self.t_dispatch = t_dispatch
+        self.t_wait = None
+
+    def done(self) -> bool:
+        """Whether `result()` would return without waiting for the device."""
+        return all(out.is_ready() for out in self._outputs)
+
+    def result(self) -> Tuple[np.ndarray, np.ndarray]:
+        """Block on the outputs; (top-k ids (n, k) int32, probs (n, k)
+        float32) of the real rows."""
+        self.t_wait = time.time()
+        top_i, top_p = self._outputs
+        return np.asarray(top_i)[:self._n], np.asarray(top_p)[:self._n]
+
+
 class InferenceEngine:
     """Bucketed eval-mode forward: uint8 (B, H, W, 3) images -> top-k.
 
-    Thread-compatible by design: `predict` is called from the batcher's
-    single worker thread; construction/warmup happen before the server
-    accepts traffic.
+    Thread-compatible by design: `dispatch`, the handles' `result` and
+    `predict` are called from the batcher's single worker thread;
+    construction/warmup happen before the server accepts traffic.
     """
 
     def __init__(self, cfg: Config, mesh: Mesh, model, params,
@@ -188,11 +227,6 @@ class InferenceEngine:
         self.topk = min(cfg.serve_topk, cfg.num_classes)
         self.buckets = bucket_sizes(cfg.serve_max_batch)
         self.compile_count = 0          # warmup compiles; pinned by tests
-        # (t_dispatch, t_wait) of the last `_run`, time.time(): device_put
-        # returned / the compiled call returned. One worker thread calls
-        # predict, so the batch hook reads its own batch's pair
-        # (vitax/serve/server.py serve_batch)
-        self.phase_marks = (0.0, 0.0)
         # readiness vs liveness: the HTTP server is LIVE as soon as it binds
         # (healthz answers), but READY only once every AOT bucket is compiled
         # and exercised — a fleet router must not dispatch to a warming
@@ -481,9 +515,7 @@ class InferenceEngine:
         for b in self.buckets:
             t0 = time.time()
             self._compiled[b] = self._compile_bucket(b)
-            zeros = np.zeros((b, s, s, 3), np.uint8)
-            idx, probs = self._run(b, zeros)
-            jax.block_until_ready((idx, probs))
+            self._run(b, np.zeros((b, s, s, 3), np.uint8), b).result()
             timings[b] = time.time() - t0
         self.ready = True
         master_print(
@@ -495,21 +527,23 @@ class InferenceEngine:
 
     # --- inference --------------------------------------------------------
 
-    def _run(self, bucket: int, images: np.ndarray):
+    def _run(self, bucket: int, images: np.ndarray, n: int) -> Dispatched:
+        """Put one padded batch on the device and call its bucket's program;
+        returns without waiting for either."""
         batch = jax.device_put(images, self._batch_shardings[bucket])
         t_dispatch = time.time()
         if self.scales:
             out = self._compiled[bucket](self.params, self.scales, batch)
         else:
             out = self._compiled[bucket](self.compute_params, batch)
-        self.phase_marks = (t_dispatch, time.time())
-        return out
+        return Dispatched(out, n, t_dispatch)
 
-    def predict(self, images: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-        """(n, H, W, 3) uint8 -> (top-k class ids (n, k) int32,
-        top-k probs (n, k) float32). Pads to the next bucket; the padded
-        rows' outputs are discarded. Only precompiled buckets execute —
-        an unseen shape raises instead of silently recompiling."""
+    def dispatch(self, images: np.ndarray) -> Dispatched:
+        """(n, H, W, 3) uint8 -> handle of the batch, which the device now
+        runs or holds queued; nothing here waits for it. Pads to the next
+        bucket; the padded rows' outputs are discarded by `result()`. Only
+        precompiled buckets execute — an unseen shape raises instead of
+        silently recompiling."""
         faults.fire("engine_predict")
         n = images.shape[0]
         bucket = next_bucket(n, self.buckets)
@@ -519,5 +553,9 @@ class InferenceEngine:
             padded = np.zeros((bucket,) + images.shape[1:], images.dtype)
             padded[:n] = images
             images = padded
-        top_i, top_p = self._run(bucket, images)
-        return np.asarray(top_i)[:n], np.asarray(top_p)[:n]
+        return self._run(bucket, images, n)
+
+    def predict(self, images: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """(n, H, W, 3) uint8 -> (top-k class ids (n, k) int32,
+        top-k probs (n, k) float32): `dispatch`, then wait for the answers."""
+        return self.dispatch(images).result()

@@ -52,7 +52,9 @@ stamp is time.time() seconds, the clock a device trace shares:
   when the handler runs again after its future; reply_s comes after it);
 - kind "serve_batch", one per engine batch, the worker thread's timeline
   (vitax/serve/batcher.py): batch_id, batch_size, bucket, infer_s,
-  t_collect, t_stack, t_put, t_dispatch, t_wait, t_deliver, t_end;
+  overlapped (1: dispatched while the batch before it was still in flight,
+  so the two timelines overlap), t_collect, t_stack, t_put, t_dispatch,
+  t_wait, t_deliver, t_end;
 - lifecycle events: serve_start, serve_drain, brownout, serve_fault, ...
 """
 
@@ -311,9 +313,13 @@ class ServeContext:
             engine.predict, max_batch=cfg.serve_max_batch,
             max_wait_ms=cfg.max_batch_wait_ms,
             bucket_of=lambda n: next_bucket(n, engine.buckets),
-            # no recorder: no hook, and the batcher builds no stats
+            # no recorder: no hook, and the batcher's record goes nowhere
             on_batch=self._record_batch if recorder is not None else None,
-            queue_max=getattr(cfg, "serve_queue_max", 0))
+            queue_max=getattr(cfg, "serve_queue_max", 0),
+            # the engine's two-phase call lets the batcher queue one batch
+            # behind the one that runs; a stand-in with `predict` alone is
+            # wrapped by the batcher and never overlaps
+            dispatch_fn=getattr(engine, "dispatch", None))
         # brownout: shed optional work under sustained queue pressure
         # instead of tipping into queue-full sheds (degraded != unready:
         # a browned-out replica still serves)
@@ -382,14 +388,9 @@ class ServeContext:
             return True
 
     def _record_batch(self, stats: dict) -> None:
-        """The batcher's five marks plus the engine's two, as one
-        `serve_batch` span record. An engine stand-in that marks nothing
-        gets an empty `put` and `dispatch`: all of predict is `wait`. Only
-        hooked up where there is a recorder."""
-        t_put = stats["t_put"]
-        t_dispatch, t_wait = getattr(self.engine, "phase_marks",
-                                     (t_put, t_put))
-        stats.update(t_dispatch=t_dispatch, t_wait=t_wait)
+        """The batcher's record of one batch (its seven marks, the engine's
+        two among them, and `overlapped`) as one `serve_batch` span record.
+        Only hooked up where there is a recorder."""
         self.recorder.event("serve_batch", **{
             k: round(v, 6) if isinstance(v, float) else v
             for k, v in stats.items()})
@@ -471,7 +472,10 @@ def _make_handler(ctx: ServeContext):
                 snap = ctx.metrics.snapshot()
                 snap["queue_depth"] = ctx.batcher.queue_depth()
                 snap["queue_max"] = ctx.batcher.queue_max
-                snap["batches_flushed"] = ctx.batcher.batches_flushed
+                # batches dispatched, and how many of them went to the device
+                # while the batch before was still in flight
+                snap["batches_total"] = ctx.batcher.batches_total
+                snap["batches_overlapped"] = ctx.batcher.batches_overlapped
                 snap["compile_count"] = ctx.engine.compile_count
                 snap["request_timeout_s"] = ctx.request_timeout_s
                 snap["ready"] = ctx.is_ready()
