@@ -438,12 +438,7 @@ def phase_fsdp4(args, model: str, batch: int) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from vitax.models import build_model
-    from vitax.ops.attention import make_attention_impl
-    from vitax.parallel.mesh import build_mesh
     from vitax.programs.builder import Geometry, build_program
-    from vitax.programs.registry import get_scenario
-    from vitax.train.state import make_train_state
 
     n_dev = jax.device_count()
     # trainer defaults: fsdp_size -1 puts all devices on the ZeRO-3 axis
@@ -481,14 +476,10 @@ def phase_fsdp4(args, model: str, batch: int) -> dict:
     # the comparison arm: one device, the builder's train program fed the
     # fake dataset's batch (zero images, label 0), same seed and schedule
     cfg1 = dataclasses.replace(cfg, fsdp_size=1)
-    mesh1 = build_mesh(cfg1, devices=jax.devices()[:1])
-    model1 = build_model(cfg1, attention_impl=make_attention_impl(cfg1, mesh1))
-    tx, schedule = get_scenario(cfg1.task).make_optimizer(cfg1, TRAIN_STEPS)
-    state1, specs1, _ = make_train_state(cfg1, model1, tx, mesh1,
-                                         jax.random.key(cfg1.seed))
-    step1 = build_program("train", Geometry(
-        cfg=cfg1, mesh=mesh1, model=model1, tx=tx, schedule=schedule,
-        state_specs=specs1))
+    geom1 = Geometry.assemble(cfg1, TRAIN_STEPS, devices=jax.devices()[:1],
+                              materialize=True)
+    state1 = geom1.state
+    step1 = build_program("train", geom1)
     device0 = jax.devices()[0]
     fake = {"image": jax.device_put(jnp.zeros(
                 (batch, cfg1.image_size, cfg1.image_size, 3), jnp.float32),

@@ -155,8 +155,8 @@ def _compile_leaf_update(shape, one_chip):
 @pytest.mark.parametrize("shape", [
     (1024, 4096), (24, 1024, 3072), (1024,), (1000,), (1, 257, 1024),
     # last dimension over 8,192: the row block fell to 3 and 4 rows, which
-    # the TPU lowering refuses — `bench.py --preset 10b_slice` and the
-    # trainer at --embed_dim 5120 died in compilation before the fix
+    # the TPU lowering refuses — the trainer at --embed_dim 5120 died in
+    # compilation before the fix
     (5120, 20480), (2, 5120, 15360),
     # the shapes tools/check_kernels_on_chip.py runs on the chip
     (2, 37, 96), (70_000, 8), (),

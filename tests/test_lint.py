@@ -1,7 +1,7 @@
-"""Tier-1 lint guard: flake8 over vitax/ tests/ tools/ bench.py with the
+"""Tier-1 lint guard: flake8 over vitax/ tests/ tools/ chip_smoke.py with the
 repo's .flake8 settings (max-line-length 120), plus firing/silent fixtures
 for VTX109 (network calls without an explicit timeout=). Skips the flake8
-arm cleanly when flake8 is not installed (the bench/CI images don't ship
+arm cleanly when flake8 is not installed (the CI images don't ship
 it); tools/lint.sh is the equivalent shell entry point.
 """
 
@@ -21,7 +21,7 @@ def test_flake8_clean():
     pytest.importorskip("flake8")
     r = subprocess.run(
         [sys.executable, "-m", "flake8", "vitax/", "tests/", "tools/",
-         "bench.py"],
+         "chip_smoke.py"],
         cwd=REPO, capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, f"flake8 findings:\n{r.stdout}\n{r.stderr}"
 
@@ -31,7 +31,7 @@ def test_max_line_length_120():
     rule cheap enough to check directly, so the guard still bites on images
     where test_flake8_clean skips."""
     bad = []
-    targets = [os.path.join(REPO, "bench.py")]
+    targets = [os.path.join(REPO, "chip_smoke.py")]
     for sub in ("vitax", "tests", "tools"):
         for dirpath, _, files in os.walk(os.path.join(REPO, sub)):
             targets += [os.path.join(dirpath, f) for f in files

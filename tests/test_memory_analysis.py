@@ -335,9 +335,10 @@ def test_60b_shape_readiness(devices8):
 
 @pytest.mark.slow
 def test_10b_slice_fits_single_chip_hbm(devices8):
-    """The 10b_slice bench preset's claim — "params+moments+activations stay
-    under 16 GB HBM" on one v5e chip (bench.py train_presets) — asserted from
-    the compiled single-device step's memory analysis instead of a comment.
+    """The 10B block at depth 2 (the shape of the `vit10b_d2` benchmark
+    configuration) keeps "params+moments+activations under 16 GB HBM" on one
+    v5e chip — asserted from the compiled single-device step's memory
+    analysis instead of a comment.
 
     Resident bytes = arguments (params + mu + nu + batch) + temps
     (activations, grads, stacking buffers) + any output bytes NOT aliased
@@ -346,30 +347,19 @@ def test_10b_slice_fits_single_chip_hbm(devices8):
 
     Caveat: this compiles on the CPU test backend with the dense jnp
     attention; TPU layout padding and Pallas scratch can shift temps by some
-    margin — the on-chip bench run is the ground truth, this test is the
-    regression guard (it caught the depth-4 preset overflowing by 9+ GB).
-    The dense-attention divergence is why the batch is pinned to the
-    flagship's pod operating point (8/chip, the reference's per-core batch)
-    rather than the preset's default: the preset ships the measured
-    single-chip throughput frontier (64/chip, fused kernel), which fits and
-    runs on the real chip but whose dense-path CPU estimate inflates to
-    ~29 GB of score tensors the Pallas kernel never materializes."""
-    from bench import default_remat_policy, train_presets
-
-    # the preset's own batch is chip-proven, not CPU-estimable: pin it here
-    # so a future bump past the measured OOM frontier (96/chip OOMs on v5e)
-    # forces an on-chip re-measurement instead of silently shipping
-    assert train_presets(1)["10b_slice"]["batch_size"] == 64, (
-        "10b_slice preset batch changed — re-run bench.py --preset 10b_slice "
-        "on the TPU to re-prove the HBM fit, then update this pin")
-    kw = train_presets(1)["10b_slice"] | dict(batch_size=8)
-    cfg = Config(num_classes=1000, warmup_steps=0,
-                 # allow_tuned=False: the HBM byte thresholds below were
-                 # measured under the pinned reference policy — a TUNED.json
-                 # policy flip must not silently change what this guard pins
-                 remat_policy=default_remat_policy("10b_slice",
-                                                   allow_tuned=False),
-                 fsdp_size=1, **kw).validate()
+    margin — the compile for a described chip (benchmark/size_cells.py) and
+    the cell's run are the ground truth, this test is the regression guard
+    (it caught a depth-4 shape overflowing by 9+ GB). The dense-attention
+    divergence is why the batch is the flagship's pod operating point
+    (8/chip, the reference's per-core batch) rather than the cell's 64,
+    whose dense-path CPU estimate inflates to ~29 GB of score tensors the
+    Pallas kernel never materializes."""
+    cfg = Config(image_size=224, patch_size=14, embed_dim=5120, num_heads=32,
+                 num_blocks=2, batch_size=8, num_classes=1000, warmup_steps=0,
+                 # the HBM byte thresholds below were measured under this
+                 # policy: pinned, so a change of default cannot silently
+                 # change what this guard holds
+                 remat_policy="none_saveable", fsdp_size=1).validate()
     state, lowered = _lower_train_step(cfg, n_devices=1)
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
@@ -444,30 +434,18 @@ import os, sys
 sys.path.insert(0, '.')
 import jax, jax.numpy as jnp
 from jax.experimental import topologies
-from jax.sharding import NamedSharding
 from vitax.config import Config
-from vitax.models import build_model
-from vitax.ops.attention import make_attention_impl
-from vitax.parallel.mesh import batch_pspec, build_mesh
-from vitax.train.state import build_optimizer, make_train_state
-from vitax.train.step import make_train_step
+from vitax.programs.builder import Geometry, abstract_batch, build_program
 
 td = topologies.get_topology_desc('v5e:2x4', 'tpu')
 cfg = Config(image_size=224, patch_size=16, embed_dim=128, num_heads=2,
              num_blocks=2, num_classes=16, batch_size=16,
              fsdp_size=-1).validate()
-mesh = build_mesh(cfg, devices=list(td.devices))
-impl = make_attention_impl(cfg, mesh, force_tpu_kernels=True)
-assert impl is not None, 'kernel selection bailed'
-model = build_model(cfg, attention_impl=impl)
-tx, _ = build_optimizer(cfg, max_iteration=10)
-state, sspecs, _ = make_train_state(cfg, model, tx, mesh,
-                                    jax.random.key(0), materialize=False)
-step = make_train_step(cfg, model, tx, mesh, sspecs)
-sh = NamedSharding(mesh, batch_pspec())
-batch = {'image': jax.ShapeDtypeStruct((16, 224, 224, 3), jnp.float32,
-                                       sharding=sh),
-         'label': jax.ShapeDtypeStruct((16,), jnp.int32, sharding=sh)}
+geom = Geometry.assemble(cfg, max_iteration=10, devices=list(td.devices),
+                         force_tpu_kernels=True)
+assert geom.model.attention_impl is not None, 'kernel selection bailed'
+step = build_program('train', geom)
+state, batch = geom.abstract_state, abstract_batch(cfg, geom.mesh)
 key = jax.eval_shape(lambda: jax.random.key(0))
 compiled = step.lower(state, batch, key).compile()
 ma = compiled.memory_analysis()

@@ -177,12 +177,31 @@ def test_decode_releases_gil(tmp_path):
     the loader's in-process thread pool a valid substitute for the
     reference's DataLoader worker processes (run_vit_training.py:65-73).
     Even on one core, OS timeslicing keeps the counter at a healthy fraction
-    of its idle rate (~0.5 measured); a GIL-holding decode pins it near 0.
-    The measurement harness is bench.py's counter_rate (one implementation,
-    bench --preset data_scaling records the same ratios)."""
+    of its idle rate (~0.5 measured); a GIL-holding decode pins it near 0."""
+    import threading
     import time
 
-    from bench import counter_rate
+    def counter_rate(work, min_time: float = 0.5) -> float:
+        """Counts/sec of a pure-Python spin thread while `work()` runs
+        repeatedly on the calling thread for >= min_time."""
+        box = {"n": 0, "stop": False}
+
+        def spin():
+            n = 0
+            while not box["stop"]:
+                n += 1
+            box["n"] = n
+
+        t = threading.Thread(target=spin, daemon=True)
+        t.start()
+        t0 = time.perf_counter()
+        while time.perf_counter() - t0 < min_time:
+            work()
+        dt = time.perf_counter() - t0
+        box["stop"] = True
+        t.join(timeout=10)
+        assert not t.is_alive()
+        return box["n"] / dt
 
     paths, params = [], []
     tt = train_transform(224, seed=0)

@@ -138,7 +138,7 @@ def test_ulysses_dropout_matches_masked_dense(devices8):
 
 def test_ulysses_dropout_dense_inner_off_tpu(devices8):
     """Off-TPU without forced kernels the ulysses flavor now carries a DENSE
-    dropout inner (PR 1 satellite, ADVICE r5) — the two sp flavors behave
+    dropout inner (PR 1 satellite) — the two sp flavors behave
     consistently anywhere ring's _dense_block_drop runs, including the
     pipeline body at tp=1. The dense inner makes the same counter-hash mask
     decisions at the same local coordinates as the kernel inner, so its

@@ -18,6 +18,8 @@ Usage:
     JAX_PLATFORMS=cpu python tools/aot_topology.py [--configs 10b 60b]
     JAX_PLATFORMS=cpu python tools/aot_topology.py --configs smoke_l14 \
         smoke_10b_width smoke_10b_width_fsdp4 smoke_serve_l14
+    JAX_PLATFORMS=cpu python tools/aot_topology.py --configs 10b \
+        --remat_policy dots_saveable      # any trainer flag, on every config
 
 Writes one JSON object per config with the compiled per-device argument /
 temp / output bytes and the HBM bound checked. A compile that passes is a
@@ -42,115 +44,59 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 HBM_BY_PREFIX = {"v5p": 95e9, "v5e": 16e9}
 
 
-def _abstract_key():
-    import jax
-    return jax.eval_shape(lambda: jax.random.key(0))
+def config_for(cfg_kw: dict, flags) -> "Config":
+    """A config entry as a `Config`, with the trainer's own flags
+    (vitax/config.py) applied on top: the entry becomes the parser's
+    defaults, so an explicit flag wins, as on the trainer's command line."""
+    import dataclasses
 
-
-def knob_overrides(args) -> dict:
-    """Config-kwarg overrides from the shared knob group (+ --preset_file).
-
-    A committed autotune preset applies first (its knobs become the config
-    baseline for every topology compiled); explicit CLI knobs override on
-    top — the same explicit-wins rule as bench.py. The preset's batch is
-    per-chip, so it travels as the special "_batch_per_chip" key and is
-    translated once the topology's device count is known."""
-    from vitax.tune.knobs import knobs_from_args
-    out = {}
-    if getattr(args, "preset_file", ""):
-        from vitax.tune.preset import config_defaults_from_preset, load_preset
-        preset = load_preset(args.preset_file)
-        out.update(config_defaults_from_preset(preset))
-        out["_batch_per_chip"] = int(preset["knobs"]["batch_per_chip"])
-    kn = knobs_from_args(args)
-    kn.apply_to_preset_kw(out)  # explicit non-scan knobs (incl. batch_size)
-    if kn.batch_size:
-        out.pop("_batch_per_chip", None)  # explicit global batch wins
-    if args.remat_policy is not None:
-        out["remat_policy"] = args.remat_policy
-    if args.scan_blocks is not None:
-        out["scan_blocks"] = args.scan_blocks
-    if args.scan_unroll:
-        out["scan_unroll"] = args.scan_unroll
-    if args.remat_window >= 0:
-        out["remat_window"] = args.remat_window
-    if not args.grad_ckpt:
-        out["grad_ckpt"] = False
-    if not args.use_flash_attention:
-        out["use_flash_attention"] = False
-    return out
+    from vitax.config import (Config, build_parser,
+                              config_fields_from_namespace)
+    parser = build_parser()
+    parser.set_defaults(**dataclasses.asdict(Config(
+        **{"num_classes": 1000, "warmup_steps": 0, **cfg_kw})))
+    return Config(**config_fields_from_namespace(
+        parser.parse_args(flags))).validate()
 
 
 def compile_for_topology(tag: str, topo_name: str, cfg_kw: dict,
-                         kernels: bool = False,
-                         overrides: dict = None,
+                         kernels: bool = False, flags=(),
                          n_devices: int = 0,
                          serve_bucket: int = 0) -> dict:
     """Compile one whole program for `topo_name` (its first `n_devices`
     devices; 0 = all): the train step, or with `serve_bucket` the serving
     engine's predict program for that batch bucket."""
     import jax
-    import jax.numpy as jnp
     from jax.experimental import topologies
-    from jax.sharding import NamedSharding
 
     from chip_smoke import program_facts
-    from vitax.config import Config
-    from vitax.models import build_model, count_params
-    from vitax.parallel.mesh import batch_pspec, build_mesh
-    from vitax.train.loop import _moe_dispatch_sharding, _token_sharding
-    from vitax.train.state import build_optimizer, make_train_state
-    from vitax.train.step import make_train_step
+    from vitax.models import count_params
+    from vitax.programs.builder import (Geometry, abstract_batch,
+                                        build_program)
 
     td = topologies.get_topology_desc(topo_name, "tpu")
     devices = list(td.devices)[:n_devices or None]
     n_dev = len(devices)
-    cfg_kw = dict(cfg_kw)
-    if overrides:
-        ov = dict(overrides)
-        bpc = ov.pop("_batch_per_chip", None)
-        if bpc:
-            cfg_kw["batch_size"] = bpc * n_dev
-        cfg_kw.update(ov)
-    cfg = Config(**{"num_classes": 1000, "warmup_steps": 0,
-                    **cfg_kw}).validate()
-    mesh = build_mesh(cfg, devices=devices)
-    attention_impl = None
-    if kernels:
-        # compile the PRODUCTION program: real Mosaic kernels against the
-        # TPU target (VITAX_FORCE_MOSAIC set in main; force_tpu_kernels
-        # runs the selection logic despite the CPU host backend)
-        from vitax.ops.attention import make_attention_impl
-        attention_impl = make_attention_impl(cfg, mesh,
-                                             force_tpu_kernels=True)
-    # the trainer's own model construction (vitax/train/loop.py)
-    model = build_model(cfg, attention_impl=attention_impl,
-                        token_sharding=_token_sharding(cfg, mesh),
-                        moe_dispatch_sharding=_moe_dispatch_sharding(cfg, mesh))
-    tx, schedule = build_optimizer(cfg, max_iteration=10_000)
-    state, sspecs, _ = make_train_state(
-        cfg, model, tx, mesh, jax.random.key(0), materialize=False)
+    cfg = config_for(cfg_kw, flags)
+    # the trainer's own assembly, on the described devices; `kernels`
+    # compiles the PRODUCTION program: real Mosaic kernels against the TPU
+    # target (VITAX_FORCE_MOSAIC set in main; force_tpu_kernels runs the
+    # selection logic despite the CPU host backend)
+    geom = Geometry.assemble(cfg, devices=devices, force_tpu_kernels=kernels)
+    state = geom.abstract_state
     n_params = count_params(state.params)
     t0 = time.perf_counter()
     if serve_bucket:
         # the engine over ABSTRACT params: _lower_bucket needs only shapes
         from vitax.serve.engine import InferenceEngine
-        engine = InferenceEngine(cfg, mesh, model, state.params)
+        engine = InferenceEngine(cfg, geom.mesh, geom.model, state.params)
         lowered, _ = engine._lower_bucket(serve_bucket)
         state_bytes = sum(x.size * x.dtype.itemsize
                           for x in jax.tree.leaves(state.params))
     else:
-        step = make_train_step(cfg, model, tx, mesh, sspecs,
-                               schedule=schedule)
-        sh = NamedSharding(mesh, batch_pspec())
-        batch = {
-            "image": jax.ShapeDtypeStruct(
-                (cfg.batch_size, cfg.image_size, cfg.image_size, 3),
-                jnp.float32, sharding=sh),
-            "label": jax.ShapeDtypeStruct((cfg.batch_size,), jnp.int32,
-                                          sharding=sh),
-        }
-        lowered = step.lower(state, batch, _abstract_key())
+        lowered = build_program("train", geom).lower(
+            state, abstract_batch(cfg, geom.mesh),
+            jax.eval_shape(lambda: jax.random.key(0)))
         state_bytes = sum(x.size * x.dtype.itemsize
                           for x in jax.tree.leaves(state))
     t_lower = time.perf_counter() - t0
@@ -293,21 +239,17 @@ SMOKE_CONFIGS = {
 
 
 def main():
-    ap = argparse.ArgumentParser()
+    ap = argparse.ArgumentParser(
+        description="Flags this parser does not know are the trainer's "
+                    "(vitax/config.py) and override every config compiled.")
     ap.add_argument("--configs", nargs="+", default=["10b", "60b"],
                     choices=[*CONFIGS, *SMOKE_CONFIGS])
-    # shared knob group (vitax/tune/knobs.py): A/B a knob or replay a
-    # committed autotune preset against a pod topology without editing
-    # CONFIGS — explicit flags override each config entry
-    from vitax.tune.knobs import add_knob_args
-    add_knob_args(ap)
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "AOT_TOPOLOGY.json"))
-    args = ap.parse_args()
-    overrides = knob_overrides(args)
-    if overrides:
-        print(f"[aot_topology] knob overrides: {overrides}", flush=True)
+    args, flags = ap.parse_known_args()
+    if flags:
+        print(f"[aot_topology] trainer flags: {flags}", flush=True)
 
     results = []
     for tag in args.configs:
@@ -323,7 +265,7 @@ def main():
         print(f"[aot_topology] compiling {tag} for {topo} "
               f"(kernels={kernels}) ...", flush=True)
         rec = compile_for_topology(tag, topo, kw, kernels=kernels,
-                                   overrides=overrides, n_devices=n_devices,
+                                   flags=flags, n_devices=n_devices,
                                    serve_bucket=serve_bucket)
         os.environ.pop("VITAX_FORCE_MOSAIC", None)
         print(json.dumps(rec), flush=True)

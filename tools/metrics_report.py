@@ -258,40 +258,6 @@ def summarize(path: str) -> dict:
                       "act_quant", "fused_dequant",
                       "top1_f32", "top1_quant", "top5_f32", "top5_quant",
                       "delta_top1", "delta_top5", "n")}
-    # knob autotuner (tools/autotune.py trial JSONL, vitax/tune/driver.py):
-    # point this report at AUTOTUNE_TRIALS.jsonl for the search story —
-    # trials by phase, prune reasons, and the measured best/worst spread
-    trials = [e for e in events if e.get("kind") == "autotune_trial"]
-    if trials:
-        pruned = {}
-        for t in trials:
-            if t.get("pruned_by"):
-                pruned[t["pruned_by"]] = pruned.get(t["pruned_by"], 0) + 1
-        measured = [t for t in trials if t.get("phase") == "measure"
-                    and not t.get("pruned_by")
-                    and isinstance(t.get("images_per_sec_chip"),
-                                   (int, float))]
-        at = {
-            "trials": len(trials),
-            "analytic": sum(1 for t in trials
-                            if t.get("phase") == "analytic"),
-            "compiled": sum(1 for t in trials
-                            if t.get("phase") == "compile"),
-            "measured": len(measured),
-            "pruned": pruned,
-        }
-        if measured:
-            best = max(measured, key=lambda t: t["images_per_sec_chip"])
-            worst = min(measured, key=lambda t: t["images_per_sec_chip"])
-            at["best_images_per_sec_chip"] = round(
-                best["images_per_sec_chip"], 2)
-            at["worst_images_per_sec_chip"] = round(
-                worst["images_per_sec_chip"], 2)
-            at["best_mfu"] = (round(best["mfu"], 4)
-                              if isinstance(best.get("mfu"), (int, float))
-                              else None)
-            at["winning_knobs"] = best.get("knobs")
-        summary["autotune"] = at
     if not steps:
         return summary
 
@@ -460,22 +426,6 @@ def print_human(summary: dict) -> None:
               f"(delta {qg['delta_top1']:+.2f} pts)  "
               f"top5 {qg['top5_quant']:.4f} "
               f"(delta {qg['delta_top5']:+.2f} pts)  (n={qg['n']})")
-    at = summary.get("autotune")
-    if at:
-        pr = ", ".join(f"{k}:{v}" for k, v in sorted(at["pruned"].items()))
-        print(f"  autotune: {at['trials']} trials "
-              f"({at['analytic']} analytic, {at['compiled']} compiled, "
-              f"{at['measured']} measured"
-              + (f"; pruned {pr}" if pr else "") + ")")
-        if at.get("best_images_per_sec_chip") is not None:
-            print(f"    measured spread: best "
-                  f"{at['best_images_per_sec_chip']:.1f} / worst "
-                  f"{at['worst_images_per_sec_chip']:.1f} img/s/chip"
-                  + (f", best MFU {at['best_mfu']:.3f}"
-                     if at.get("best_mfu") is not None else ""))
-        if at.get("winning_knobs"):
-            print(f"    winning knobs: "
-                  f"{json.dumps(at['winning_knobs'], sort_keys=True)}")
     if not summary["records"]:
         print("  no step records — nothing to summarize")
         return

@@ -465,55 +465,17 @@ def capture_partitioned(lowered, module_hint: str = "train_step") -> str:
         shutil.rmtree(dump_dir, ignore_errors=True)
 
 
-def _build_train_step(cfg, max_iteration: int, donate: bool):
-    """Shared builder for the AOT surfaces: returns
-    (step, (state, batch, rng) abstract args, n_state_leaves)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding
-
-    from vitax.models import build_model
-    from vitax.ops.attention import make_attention_impl
-    from vitax.parallel.mesh import batch_pspec, build_mesh
-    from vitax.train.loop import _token_sharding
-    from vitax.train.state import build_optimizer, make_train_state
-    from vitax.train.step import make_train_step
-
-    mesh = build_mesh(cfg)
-    model = build_model(cfg, attention_impl=make_attention_impl(cfg, mesh),
-                        token_sharding=_token_sharding(cfg, mesh))
-    tx, schedule = build_optimizer(cfg, max_iteration=max_iteration)
-    state, sspecs, _ = make_train_state(cfg, model, tx, mesh,
-                                        jax.random.key(cfg.seed),
-                                        materialize=False)
-    step = make_train_step(cfg, model, tx, mesh, sspecs, donate=donate,
-                           schedule=schedule)
-    sh = NamedSharding(mesh, batch_pspec())
-    batch = {
-        "image": jax.ShapeDtypeStruct(
-            (cfg.batch_size, cfg.image_size, cfg.image_size, 3),
-            jnp.float32, sharding=sh),
-        "label": jax.ShapeDtypeStruct((cfg.batch_size,), jnp.int32,
-                                      sharding=sh),
-    }
-    args = (state, batch, jax.random.key(cfg.seed + 1))
-    return step, args, len(jax.tree_util.tree_leaves(state))
-
-
 def lower_train_step(cfg, max_iteration: int = 10_000, donate: bool = True):
-    """AOT-lower the train step for `cfg` on the current backend.
-
-    Returns (lowered, n_state_leaves): the `jax.stages.Lowered` step and the
-    number of TrainState leaves (the donation rule's expected aliased-buffer
-    count). `donate=False` builds the same program without donate_argnums —
-    the deliberately-broken arm the donation rule's negative test compiles.
-    """
-    step, args, n_state_leaves = _build_train_step(cfg, max_iteration, donate)
-    return step.lower(*args), n_state_leaves
+    """AOT-lower the step program for `cfg` on the current backend: the
+    builder's `lower_step` (vitax/programs/builder.py), i.e. the program the
+    trainer runs. Returns (lowered, n_state_leaves)."""
+    from vitax.programs.builder import lower_step
+    return lower_step(cfg, max_iteration, donate)
 
 
 def train_step_jaxpr(cfg, max_iteration: int = 10_000) -> str:
-    """Trace the train step for `cfg` and return its closed jaxpr as text.
+    """Trace the step program for `cfg` and return its closed jaxpr as text
+    (the builder's `step_jaxpr`).
 
     The jaxpr — not StableHLO — is the artifact the fused-optimizer rule
     (VTX-R008) reads: Pallas interpret mode (the only lowering available
@@ -521,8 +483,8 @@ def train_step_jaxpr(cfg, max_iteration: int = 10_000) -> str:
     `pallas_call` jaxpr equation prints the kernel function's name, and the
     surrounding equations still show any param-sized post-clip temporaries
     the fusion was supposed to eliminate."""
-    step, args, _ = _build_train_step(cfg, max_iteration, donate=True)
-    return str(step.trace(*args).jaxpr)
+    from vitax.programs.builder import step_jaxpr
+    return step_jaxpr(cfg, max_iteration)
 
 
 # `c:f32[256,96] = sqrt b` — binder dtype/shape and primitive name of a jaxpr

@@ -635,32 +635,23 @@ def arm_config(arm: str, **overrides) -> Config:
 
 def build_train_program(cfg: Config, arm: str = "custom",
                         donate: bool = True) -> Program:
-    """Lower the scenario's step program for `cfg` and capture the rule
-    artifacts. --task train takes the historical hlo.lower_train_step path
-    byte-for-byte (its identity is pinned by tests); other scenarios lower
-    through the unified builder, which additionally captures the
-    freeze-report evidence VTX-R010 reads."""
-    from vitax.parallel.mesh import build_mesh
-    task = getattr(cfg, "task", "train")
-    frozen_paths: Tuple[str, ...] = ()
-    opt_moment_paths: Tuple[str, ...] = ()
-    if task == "train":
-        lowered, n_state_leaves = hlo.lower_train_step(cfg, donate=donate)
-        # the traced-jaxpr artifact only exists where a rule reads it
-        jaxpr = hlo.train_step_jaxpr(cfg) if _fused_active(cfg) else ""
-    else:
-        from vitax.programs import builder as B
-        lowered, n_state_leaves = B.lower_step(cfg, donate=donate)
-        frozen_paths, opt_moment_paths = B.freeze_report(cfg)
-        jaxpr = (B.step_jaxpr(cfg)
-                 if (_fused_active(cfg) or task == "distill") else "")
-    mesh = build_mesh(cfg)
+    """Lower the scenario's step program for `cfg` through the builder
+    (vitax/programs/builder.py: the program the trainer runs) and capture
+    the rule artifacts, among them the freeze-report evidence VTX-R010
+    reads on the arms that freeze parameters."""
+    from vitax.programs import builder as B
+    lowered, n_state_leaves = B.lower_step(cfg, donate=donate)
+    frozen_paths, opt_moment_paths = (B.freeze_report(cfg)
+                                      if _frozen_task(cfg) else ((), ()))
+    # the traced-jaxpr artifact only exists where a rule reads it
+    jaxpr = (B.step_jaxpr(cfg)
+             if (_fused_active(cfg) or cfg.task == "distill") else "")
     return Program(
         kind="train", arm=arm, config=cfg,
         mlir=lowered.as_text(),
         partitioned_hlo=hlo.capture_partitioned(lowered),
         jaxpr=jaxpr,
-        mesh_shape=dict(mesh.shape),
+        mesh_shape=dict(B.Geometry.from_config(cfg).mesh.shape),
         n_state_leaves=n_state_leaves,
         frozen_paths=frozen_paths,
         opt_moment_paths=opt_moment_paths,

@@ -165,9 +165,9 @@ class Config:
     device_normalize: bool = True       # ship uint8 batches; normalize on-device (4x less host->device traffic)
     # none_saveable = the reference's checkpoint_module semantics (recompute
     # everything) and the least HBM — the right default for the 10B+ flagship.
-    # Measured on v5e l14 (BASELINE_MEASURED.json): dots_attn_saveable 192.9 >
-    # dots_saveable 190.2 > none_saveable ~183 img/s/chip — bench selects
-    # dots_attn_saveable where activations fit.
+    # Seen on v5e l14 before the ledger (a hand-built program at batch 32):
+    # dots_attn_saveable 192.9 > dots_saveable 190.2 > none_saveable ~183
+    # img/s/chip. A prior for ROADMAP A2, not a ledger number.
     remat_policy: str = "none_saveable" # none_saveable | dots_saveable | dots_attn_saveable (only if grad_ckpt)
     profile_dir: str = ""               # if set, capture a jax.profiler trace of a few steps
     profile_start_step: int = 2         # global step the profiler window opens after (with --profile_dir)
@@ -1020,20 +1020,6 @@ def config_fields_from_namespace(ns: argparse.Namespace) -> dict:
 
 
 def parse_config(argv: Optional[Tuple[str, ...]] = None) -> Config:
-    """Two-phase parse so --preset_file (a committed autotune winner,
-    presets/<model>_<topology>.json) becomes the DEFAULTS layer: the preset's
-    resolved knobs are installed via parser.set_defaults() and the command
-    line is re-parsed, so an explicit CLI flag still wins over the preset.
-    batch_size stays at the trainer's own default/flag — the preset stores
-    per-chip batch and the device count is unknown at parse time."""
-    parser = build_parser()
-    parser.add_argument("--preset_file", default="",
-                        help="autotune preset JSON whose knobs become the "
-                             "parser defaults (explicit flags win)")
-    ns = parser.parse_args(argv)
-    if ns.preset_file:
-        from vitax.tune.preset import config_defaults_from_preset, load_preset
-        parser.set_defaults(**config_defaults_from_preset(
-            load_preset(ns.preset_file)))
-        ns = parser.parse_args(argv)
+    """The validated `Config` of a command line (default: sys.argv)."""
+    ns = build_parser().parse_args(argv)
     return Config(**config_fields_from_namespace(ns)).validate()

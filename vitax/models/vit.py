@@ -318,7 +318,7 @@ class Attention(nn.Module):
     # it, a batch spanning 3 mesh axes (dp x fsdp x ep — the MoE meshes)
     # makes GSPMD keep the qkv weight fsdp-sharded instead of all-gathering
     # it (ZeRO-3), and the feature-sharded dot output then triggers
-    # "involuntary full rematerialization" at this add (MULTICHIP_r03 tail).
+    # "involuntary full rematerialization" at this add (a pre-ledger dry run).
     # Feature axis carries "tp" under tensor parallelism (Megatron layout).
     qkv_sharding: Optional[Any] = None
     quant_matmul: Optional[Callable] = None
@@ -448,7 +448,7 @@ class Block(nn.Module):
             # re-anchor the carry at every block entry: under the ep mesh the
             # MoE combine einsum hands the next block a partially-sharded
             # layout and the partitioner falls back to involuntary full
-            # rematerialization at the qkv projection (MULTICHIP_r03 tail)
+            # rematerialization at the qkv projection (a pre-ledger dry run)
             x = jax.lax.with_sharding_constraint(x, self.token_sharding)
         qkv_sharding = None
         if self.token_sharding is not None:

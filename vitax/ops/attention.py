@@ -838,7 +838,7 @@ def make_dense_dropout(rate: float):
     mask: (q, k, v, seed) -> o on (B, N, H, Dh). The off-TPU/kernels-disabled
     analog of _tpu_dropout_kernel — ring sp keeps a dense block product for
     the same purpose (_dense_block_drop); this gives the ulysses flavor the
-    same anywhere-runnable dropout inner (ADVICE r5), with the same mask
+    same anywhere-runnable dropout inner, with the same mask
     decisions at the same local (b*H + h, q, k) coordinates as the kernels
     (timm semantics: mask the softmax probabilities, rescale by 1/(1-rate))."""
     def dense_drop(q, k, v, seed):
@@ -1066,7 +1066,7 @@ def make_attention_impl(cfg, mesh: Optional[Mesh] = None,
                     # off-TPU / kernels disabled: dense inner with the same
                     # counter-hash mask, so BOTH sp flavors carry a dropout
                     # impl everywhere ring does — incl. the pp body at tp=1
-                    # (ADVICE r5; ring's _dense_block_drop counterpart)
+                    # (ring's _dense_block_drop counterpart)
                     drop_inner = make_dense_dropout(float(cfg.att_dropout))
                 if drop_inner is not None:
                     # sp with fused dropout (round 5): the resharded inner

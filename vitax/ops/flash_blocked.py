@@ -37,9 +37,9 @@ from vitax.ops.attention import (_from_bh, _interpret, _to_bh,
 
 NEG_INF = -1e30  # large-but-finite: avoids inf-inf=nan in max/exp chains
 
-"""Measured block defaults (round-5 ladder, tools/long_context_ladder.py ->
-LADDER_LONGCTX.jsonl, v5e, ViT-L width train steps): the (512, 1024) pair
-wins at N=4,096 (79.3 ms vs 102.8 at the untuned (512, 512)) and is within
+"""Block defaults from a sweep on the v5e that predates the ledger (ViT-L
+width train steps, a hand-built program): the (512, 1024) pair
+won at N=4,096 (79.3 ms vs 102.8 at the untuned (512, 512)) and was within
 5% of best at N=9,216 (295.9 vs 280.2 at (1024, 1024)). A taller K block
 amortizes the online-softmax rescale chain over more of the KV stream."""
 DEFAULT_BLOCK_Q = 512

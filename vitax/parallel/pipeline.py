@@ -138,7 +138,7 @@ def make_pp_forward(cfg: Config, model, mesh: Mesh, block_specs=None):
     if (tp_auto and cfg.dtype == "bfloat16"
             and backend_platform() == "cpu"):
         # a warning here would be followed by a native XLA abort the user
-        # can't connect back to it (ADVICE r4) — fail loudly instead
+        # can't connect back to it — fail loudly instead
         raise ValueError(
             "pp x tp with bf16 on the CPU backend crashes XLA's "
             "operand_upcaster pass (CPU bf16-dot emulation mishandles "

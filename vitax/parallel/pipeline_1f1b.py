@@ -6,7 +6,7 @@ Megatron-LM) interleaves: once stage s has run its warmup forwards, each
 tick performs ONE forward and ONE backward, bounding in-flight microbatch
 activations at ~2(S-s) per stage instead of the full M+S-1 tick carries.
 
-MEASURED VERDICT (tools/pp_schedule_ab.py, PP_AB.json, 8-device CPU mesh):
+VERDICT (a pre-ledger A/B on an 8-device CPU mesh: bytes and CPU times):
 in THIS framework the classic 1F1B memory win does not materialize, and
 GPipe stays the default. Two reasons, both structural: (1) the pipeline
 always runs recompute-everything remat, so GPipe's saved state is already

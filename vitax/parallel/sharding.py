@@ -37,6 +37,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from vitax.config import Config
+from vitax.parallel.mesh import BATCH_AXES
 
 PyTree = Any
 
@@ -202,6 +203,29 @@ def shardings_of(mesh: Mesh, specs: PyTree) -> PyTree:
     return jax.tree.map(
         lambda s: NamedSharding(mesh, s), specs,
         is_leaf=lambda x: isinstance(x, P))
+
+
+def token_sharding(cfg: Config, mesh: Mesh) -> Optional[NamedSharding]:
+    """(B, N, D) activation sharding: batch over (dp, fsdp, ep), tokens over
+    sp. Anchors GSPMD propagation; None on single-device meshes."""
+    if mesh.size == 1:
+        return None
+    sp = mesh.shape.get("sp", 1)
+    token_axis = "sp" if (sp > 1 and cfg.num_patches % sp == 0) else None
+    return NamedSharding(mesh, P(BATCH_AXES, token_axis, None))
+
+
+def moe_dispatch_sharding(cfg: Config,
+                          mesh: Mesh) -> Optional[NamedSharding]:
+    """(E, B, C, D) dispatched-tensor sharding for the MoE einsums: experts
+    over "ep", batch over the data axes. The explicit anchor makes GSPMD
+    lower dispatch/combine to all-to-alls instead of the partitioner's
+    involuntary full rematerialization. None when dense or single-device."""
+    if cfg.moe_experts == 0 or mesh.size == 1:
+        return None
+    ep = mesh.shape.get("ep", 1)
+    return NamedSharding(
+        mesh, P("ep" if ep > 1 else None, ("dp", "fsdp"), None, None))
 
 
 def gather_over_fsdp(specs: PyTree) -> PyTree:

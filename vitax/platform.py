@@ -3,8 +3,9 @@
 Backend selection is JAX's own: `JAX_PLATFORMS=cpu` in the environment pins
 the process to the host CPU (the test suite, CPU rehearsals); with the
 variable unset JAX takes the TPU where it finds one and otherwise falls back
-to the CPU without a word — so the entry points that measure (bench.py,
-chip_smoke.py) check `backend_platform()` themselves. Nothing here re-pins.
+to the CPU without a word — so the entry points that measure
+(chip_smoke.py, benchmark/run.py) check the platform themselves. Nothing
+here re-pins.
 """
 
 import os
@@ -22,7 +23,7 @@ def setup_compile_cache() -> str:
     """Place JAX's persistent compilation cache; returns the directory.
 
     Called first thing by every entry point that compiles (trainer, server,
-    bench.py, chip_smoke.py, the tools), so restarts and the processes of one
+    chip_smoke.py, the benchmark, the tools), so restarts and the processes of one
     chip call share compiled programs. Where JAX_COMPILATION_CACHE_DIR is set
     the cache is placed from outside and nothing is set in code (JAX reads
     the variable itself); otherwise the fixed in-checkout directory is used.

@@ -1,8 +1,9 @@
-"""Scenario registry + unified program builder (ROADMAP item 3).
+"""Scenario registry + the one program builder.
 
 `registry` maps --task names to Scenario declarations (programs, optimizer,
-validator, sharding rules); `builder` turns (task, geometry) into jitted/AOT
-programs through a shared compile cache; `workloads` holds the finetune /
+validator, sharding rules); `builder` assembles a Config's stack once
+(`Geometry.assemble`) and turns (task, geometry) into jitted/AOT programs,
+cached on the geometry; `workloads` holds the finetune /
 linear-probe / distillation ingredients the scenarios are spent on.
 """
 
@@ -14,7 +15,8 @@ __all__ = [
     "Scenario",
     "get_scenario",
     # heavy (jax-importing) surfaces are reached via their modules:
-    #   vitax.programs.builder   Geometry, build_program, build_engine,
+    #   vitax.programs.builder   Geometry (.assemble, .from_config),
+    #                            build_model_for, build_program, build_engine,
     #                            lower_step, step_jaxpr, freeze_report
     #   vitax.programs.workloads masks, optimizers, warm starts, distill step
 ]

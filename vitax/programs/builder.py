@@ -1,37 +1,55 @@
-"""Unified program builder: one `build_program(task, geometry)` entry.
+"""The program builder: one way to assemble a program from a `Config`.
 
-ROADMAP item 3's first half. The three independent assembly paths —
-train/loop.py hand-wiring mesh+model+optimizer+step, analysis/hlo.py
-rebuilding the same stack for the AOT surfaces, serve/engine.py assembling
-its own forward — converge here:
+`Geometry.assemble(cfg, ...)` is the only place that writes out
 
-- `Geometry` is the shared substrate (cfg, mesh, model, optimizer, schedule,
-  state specs) every program is built against. The training loop constructs
-  its geometry from live objects (non-owned: nothing cached, programs bound
-  to the loop's exact model/optimizer — the lowered bytes are pinned
-  identical to the pre-builder direct calls); analysis/tools call
-  `Geometry.from_config(cfg)`, which memoizes (owned) so an arm's lower +
-  jaxpr + freeze-report probes share one traced stack instead of three.
-- `build_program(task, geom)` dispatches to the per-task constructors
-  (train/train/step.py, eval, opt_probe, distill in programs/workloads.py,
-  serve buckets on an InferenceEngine) and caches built programs per owned
-  geometry — the shared compile cache.
-- `build_engine(cfg, ...)` is the registry's engine constructor: every CLI
-  that boots a serving engine (vitax.serve.__main__, arbiter-provisioned
-  replicas) routes through it, so scenario validation runs before any
-  checkpoint IO.
+    mesh -> attention core -> model (both activation anchors) ->
+    the scenario's optimizer -> train state (abstract or live) -> specs
 
-The scenario registry (programs/registry.py) names which tasks each --task
-may build; unknown combinations fail here with the scenario's program set.
+and `build_program(task, geom)` the only place that turns such a geometry
+into a jitted program. Everything that needs a program goes through the two:
+
+- the training loop (vitax/train/loop.py) assembles with the live loader's
+  `max_iteration` and `materialize=resume_epoch <= 0`, then builds its step,
+  eval and optimizer-probe programs;
+- the analysis arms and AOT surfaces (vitax/analysis/hlo.py, rules.py,
+  `lower_step` / `step_jaxpr` / `freeze_report` below, chip_smoke.py) use
+  `Geometry.from_config(cfg)`: the memoized `assemble(materialize=False)`,
+  so an arm's lower + jaxpr + freeze-report probes share one traced stack;
+- tools that compile for a described topology (tools/aot_topology.py) pass
+  `devices=` and `force_tpu_kernels=`;
+- the serve engine (vitax/serve/engine.py) takes the model half alone,
+  `build_model_for(cfg, mesh, ...)`, which `assemble` itself calls: the one
+  door for `quant_matmul` and the anchors; `build_engine(cfg, ...)` is the
+  registry's engine constructor (scenario validation before checkpoint IO).
+
+The activation anchors are sharding rules and live with them
+(vitax/parallel/sharding.py). The scenario registry (programs/registry.py)
+names which tasks each --task may build; unknown combinations fail here with
+the scenario's program set. `benchmark/harness.py` still writes the assembly
+out by hand (ROADMAP C13); tests/test_assembly.py holds this module to the
+written-out form, text for text.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding
 
 from vitax.config import Config
+from vitax.models import build_model
+from vitax.ops.attention import make_attention_impl
+from vitax.parallel.mesh import Mesh, batch_pspec, build_mesh
+from vitax.parallel.rules import _leaf_path_names
+from vitax.parallel.sharding import (moe_dispatch_sharding, shardings_of,
+                                     token_sharding)
 from vitax.programs.registry import Scenario, get_scenario
+from vitax.programs.workloads import load_teacher_params, make_distill_step
+from vitax.train.state import make_train_state
+from vitax.train.step import make_eval_step, make_opt_probe, make_train_step
 
 PyTree = Any
 
@@ -39,13 +57,28 @@ PyTree = Any
 PROGRAM_KINDS = ("train", "eval", "opt_probe", "distill", "serve_bucket")
 
 
+def build_model_for(cfg: Config, mesh: Mesh, force_tpu_kernels: bool = False,
+                    quant_matmul: Optional[Callable] = None):
+    """The model every program runs: the attention core chosen for this
+    config and mesh, and BOTH activation anchors (token sharding on any
+    multi-device mesh, the MoE dispatch sharding iff the model has experts).
+    `force_tpu_kernels` selects the TPU kernels off the TPU (a compile for a
+    described topology; interpret mode on the CPU); `quant_matmul` (serving
+    only) swaps every Dense site for QuantDense."""
+    return build_model(
+        cfg,
+        attention_impl=make_attention_impl(
+            cfg, mesh, force_tpu_kernels=force_tpu_kernels),
+        token_sharding=token_sharding(cfg, mesh),
+        moe_dispatch_sharding=moe_dispatch_sharding(cfg, mesh),
+        quant_matmul=quant_matmul)
+
+
 @dataclasses.dataclass
 class Geometry:
-    """Everything a program is built against: the resolved mesh/model/
-    optimizer/spec stack for one Config. `owned=True` (Geometry.from_config)
-    marks a geometry the builder materialized itself — those carry the
-    abstract state for AOT lowering and participate in the program cache.
-    Loop-constructed geometries wrap live objects and cache nothing."""
+    """Everything a program is built against: the resolved mesh / model /
+    optimizer / spec stack for one Config. Made by `Geometry.assemble`;
+    built programs are cached on the geometry they were built against."""
     cfg: Config
     mesh: Any
     model: Any
@@ -53,8 +86,11 @@ class Geometry:
     schedule: Any
     state_specs: PyTree
     abstract_state: Optional[PyTree] = None   # ShapeDtypeStruct TrainState
+    # what assemble made: the live TrainState under materialize=True, else
+    # `abstract_state` itself. A caller that keeps the geometry takes it
+    # out (a train step donates the state it is given)
+    state: Optional[PyTree] = None
     max_iteration: int = 10_000
-    owned: bool = False
     _programs: Dict[Tuple, Any] = dataclasses.field(default_factory=dict)
 
     @property
@@ -62,48 +98,59 @@ class Geometry:
         return get_scenario(self.cfg.task)
 
     @classmethod
-    def from_config(cls, cfg: Config, max_iteration: int = 10_000) -> "Geometry":
-        """Materialize the full (abstract) stack for one Config — the exact
-        assembly the training loop performs (train/loop.py:166-182), shared
-        by the analysis arms and AOT tools. Memoized per (cfg, max_iteration)
-        so one arm's multiple probes trace the stack once."""
-        key = (dataclasses.astuple(cfg), max_iteration)
-        hit = _GEOMETRY_CACHE.get(key)
-        if hit is not None:
-            return hit
+    def assemble(cls, cfg: Config, max_iteration: int = 10_000,
+                 devices: Optional[Sequence[jax.Device]] = None,
+                 materialize: bool = False,
+                 rng: Optional[jax.Array] = None,
+                 force_tpu_kernels: bool = False) -> "Geometry":
+        """The one assembly of a program's stack from a `Config`.
 
-        import jax
-        from vitax.models import build_model
-        from vitax.ops.attention import make_attention_impl
-        from vitax.parallel.mesh import build_mesh
-        from vitax.train.loop import _moe_dispatch_sharding, _token_sharding
-        from vitax.train.state import make_train_state
+        max_iteration      length of the lr schedule (the loop: from the
+                           live loader)
+        devices            the mesh's devices (default: all attached; a
+                           described topology's, or a subset)
+        materialize, rng   True: `state` is born sharded on the devices from
+                           `rng` (default `key(cfg.seed)`); False: `state` is
+                           the abstract state, costing no device memory (a
+                           restore target, an AOT lowering)
+        force_tpu_kernels  see `build_model_for`
 
-        mesh = build_mesh(cfg)
-        model = build_model(
-            cfg, attention_impl=make_attention_impl(cfg, mesh),
-            token_sharding=_token_sharding(cfg, mesh),
-            moe_dispatch_sharding=_moe_dispatch_sharding(cfg, mesh))
+        The model is traced once for the abstract state; a live state's
+        abstract twin is read off its arrays, not traced again."""
+        mesh = build_mesh(cfg, devices)
+        model = build_model_for(cfg, mesh, force_tpu_kernels)
         tx, schedule = get_scenario(cfg.task).make_optimizer(
             cfg, max_iteration)
-        abstract, sspecs, _ = make_train_state(
-            cfg, model, tx, mesh, jax.random.key(cfg.seed),
-            materialize=False)
-        geom = cls(cfg=cfg, mesh=mesh, model=model, tx=tx, schedule=schedule,
-                   state_specs=sspecs, abstract_state=abstract,
-                   max_iteration=max_iteration, owned=True)
-        _GEOMETRY_CACHE[key] = geom
-        return geom
+        state, sspecs, _ = make_train_state(
+            cfg, model, tx, mesh,
+            jax.random.key(cfg.seed) if rng is None else rng,
+            materialize=materialize)
+        abstract = state if not materialize else jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            state, shardings_of(mesh, sspecs))
+        return cls(cfg=cfg, mesh=mesh, model=model, tx=tx, schedule=schedule,
+                   state_specs=sspecs, abstract_state=abstract, state=state,
+                   max_iteration=max_iteration)
+
+    @classmethod
+    def from_config(cls, cfg: Config, max_iteration: int = 10_000) -> "Geometry":
+        """`assemble(cfg, max_iteration)` (abstract state), memoized per
+        (cfg, max_iteration) so one arm's several probes trace the stack
+        once and share its built programs."""
+        key = (dataclasses.astuple(cfg), max_iteration)
+        if key not in _GEOMETRY_CACHE:
+            _GEOMETRY_CACHE[key] = cls.assemble(cfg, max_iteration)
+        return _GEOMETRY_CACHE[key]
 
 
-# owned geometries, memoized by (cfg fields, max_iteration) — Config is a
-# flat dataclass of scalars/strings, so astuple is hashable
+# from_config's geometries — Config is a flat dataclass of scalars/strings,
+# so astuple is hashable
 _GEOMETRY_CACHE: Dict[Tuple, Geometry] = {}
 
 
 def build_program(task: str, geom: Geometry, donate: bool = True,
                   bucket: Optional[int] = None, engine=None):
-    """Build (or fetch from the owned-geometry cache) one program.
+    """Build (or fetch from the geometry's cache) one program.
 
     task        one of PROGRAM_KINDS, and a member of the scenario's declared
                 program set (registry.py) — the registry is the contract for
@@ -113,7 +160,7 @@ def build_program(task: str, geom: Geometry, donate: bool = True,
     bucket      serve_bucket only: the batch bucket to lower
     engine      serve_bucket only: the InferenceEngine holding the params
                 (serve programs are bound to concrete weights, not abstract
-                geometry — build one with build_engine)
+                geometry — build one with build_engine; never cached here)
     """
     scenario = geom.scenario
     if task not in PROGRAM_KINDS:
@@ -124,47 +171,42 @@ def build_program(task: str, geom: Geometry, donate: bool = True,
             f"--task {scenario.name} does not build {task!r} programs "
             f"(declared set: {scenario.programs}; vitax/programs/registry.py)")
 
-    key = (task, donate, bucket)
-    if geom.owned and key in geom._programs:
-        return geom._programs[key]
-
-    cfg, mesh, model = geom.cfg, geom.mesh, geom.model
-    if task == "train":
-        from vitax.train.step import make_train_step
-        program = make_train_step(cfg, model, geom.tx, mesh,
-                                  geom.state_specs, donate=donate,
-                                  schedule=geom.schedule)
-    elif task == "eval":
-        from vitax.train.step import make_eval_step
-        program = make_eval_step(cfg, model, mesh, geom.state_specs)
-    elif task == "opt_probe":
-        from vitax.train.step import make_opt_probe
-        program = make_opt_probe(cfg, geom.tx, mesh, geom.state_specs,
-                                 schedule=geom.schedule)
-    elif task == "distill":
-        from vitax.programs.workloads import (load_teacher_params,
-                                              make_distill_step)
-        if cfg.teacher_npz:
-            teacher = load_teacher_params(cfg, mesh)
-        else:
-            # no file: lower against the ABSTRACT teacher (analysis arms,
-            # AOT probes) — requires an owned geometry's abstract state
-            assert geom.abstract_state is not None, (
-                "--task distill needs --teacher_npz to build a runnable "
-                "program (abstract lowering needs Geometry.from_config)")
-            teacher = geom.abstract_state.params
-        program = make_distill_step(cfg, model, geom.tx, mesh,
-                                    geom.state_specs, teacher,
-                                    donate=donate, schedule=geom.schedule)
-    else:  # serve_bucket
+    if task == "serve_bucket":
         assert engine is not None and bucket is not None, (
             "serve_bucket programs are built on an InferenceEngine: pass "
             "engine=build_engine(cfg, ...) and bucket=<batch size>")
         lowered, _ = engine._lower_bucket(bucket)
-        program = lowered
+        return lowered
 
-    if geom.owned:
-        geom._programs[key] = program
+    key = (task, donate)
+    if key in geom._programs:
+        return geom._programs[key]
+
+    cfg, mesh, model = geom.cfg, geom.mesh, geom.model
+    if task == "train":
+        program = make_train_step(cfg, model, geom.tx, mesh,
+                                  geom.state_specs, donate=donate,
+                                  schedule=geom.schedule)
+    elif task == "eval":
+        program = make_eval_step(cfg, model, mesh, geom.state_specs)
+    elif task == "opt_probe":
+        program = make_opt_probe(cfg, geom.tx, mesh, geom.state_specs,
+                                 schedule=geom.schedule)
+    else:  # distill
+        if cfg.teacher_npz:
+            teacher = load_teacher_params(cfg, mesh)
+        else:
+            # no file: lower against the ABSTRACT teacher (analysis arms,
+            # AOT probes)
+            assert geom.abstract_state is not None, (
+                "--task distill needs --teacher_npz to build a runnable "
+                "program (abstract lowering needs Geometry.assemble)")
+            teacher = geom.abstract_state.params
+        program = make_distill_step(cfg, model, geom.tx, mesh,
+                                    geom.state_specs, teacher,
+                                    donate=donate, schedule=geom.schedule)
+
+    geom._programs[key] = program
     return program
 
 
@@ -185,37 +227,39 @@ def build_engine(cfg: Config, npz: str = "", epoch: Optional[int] = None):
 
 
 # --- AOT / analysis surfaces -------------------------------------------------
-# Scenario-aware mirrors of analysis/hlo.py's lower_train_step family: the
-# invariant arms for --task probe/distill lower through these. hlo.py's own
-# builders are untouched — the train-task identity pins compare against them.
+# The scenario's step program lowered against abstract arguments: what the
+# invariant arms (vitax/analysis/), tools/comm_audit.py and chip_smoke.py read.
 
 
-def _build_step(cfg: Config, max_iteration: int, donate: bool):
-    """(step, (state, batch, rng) abstract args, n_state_leaves) for the
-    scenario's step program — the same return contract as
-    analysis/hlo.py:_build_train_step, for any --task."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding
-    from vitax.parallel.mesh import batch_pspec
-
-    geom = Geometry.from_config(cfg, max_iteration=max_iteration)
-    step = build_program(geom.scenario.step_program, geom, donate=donate)
-    sh = NamedSharding(geom.mesh, batch_pspec())
-    batch = {
+def abstract_batch(cfg: Config, mesh: Mesh) -> Dict[str, jax.ShapeDtypeStruct]:
+    """The dense step's batch as shapes, sharded as the loader shards it."""
+    sh = NamedSharding(mesh, batch_pspec())
+    return {
         "image": jax.ShapeDtypeStruct(
             (cfg.batch_size, cfg.image_size, cfg.image_size, 3),
             jnp.float32, sharding=sh),
         "label": jax.ShapeDtypeStruct((cfg.batch_size,), jnp.int32,
                                       sharding=sh),
     }
-    args = (geom.abstract_state, batch, jax.random.key(cfg.seed + 1))
+
+
+def _build_step(cfg: Config, max_iteration: int, donate: bool):
+    """(step, (state, batch, rng) abstract args, n_state_leaves) for the
+    scenario's step program, for any --task."""
+    geom = Geometry.from_config(cfg, max_iteration=max_iteration)
+    step = build_program(geom.scenario.step_program, geom, donate=donate)
+    args = (geom.abstract_state, abstract_batch(cfg, geom.mesh),
+            jax.random.key(cfg.seed + 1))
     return step, args, len(jax.tree_util.tree_leaves(geom.abstract_state))
 
 
 def lower_step(cfg: Config, max_iteration: int = 10_000, donate: bool = True):
-    """AOT-lower the scenario's step program; returns
-    (lowered, n_state_leaves) like hlo.lower_train_step."""
+    """AOT-lower the scenario's step program on the current backend.
+
+    Returns (lowered, n_state_leaves): the `jax.stages.Lowered` step and the
+    number of TrainState leaves (the donation rule's expected aliased-buffer
+    count). `donate=False` builds the same program without donate_argnums —
+    the deliberately-broken arm the donation rule's negative test compiles."""
     step, args, n_state_leaves = _build_step(cfg, max_iteration, donate)
     return step.lower(*args), n_state_leaves
 
@@ -240,9 +284,6 @@ def freeze_report(cfg: Config,
     EXISTS in the optimizer state — optax.masked replaces masked-out
     positions with leafless MaskedNodes, so a frozen leaf acquiring moments
     shows up here as a path collision."""
-    import jax
-    from vitax.parallel.rules import _leaf_path_names
-
     geom = Geometry.from_config(cfg, max_iteration=max_iteration)
     param_paths = [
         "/".join(_leaf_path_names(path))

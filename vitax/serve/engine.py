@@ -77,6 +77,7 @@ from vitax import faults
 from vitax.config import Config
 from vitax.models.vit import cast_before_use
 from vitax.parallel.mesh import BATCH_AXES, Mesh, batch_pspec, build_mesh
+from vitax.programs.builder import Geometry, build_model_for
 from vitax.utils.logging import master_print
 
 
@@ -141,25 +142,18 @@ def _quant_model_mode(cfg: Config) -> bool:
 
 
 def _build_model(cfg: Config, mesh: Mesh, quantized: bool = True):
-    """The same model construction the training loop performs (attention
-    impl + activation-sharding anchors included), so serving runs the
-    identical forward graph eval ran — except under quant-model mode
+    """The model the training loop builds (vitax/programs/builder.py
+    `build_model_for`: attention core and activation anchors included), so
+    serving runs the forward graph eval ran — except under quant-model mode
     (quantized=True and _quant_model_mode), where every Dense site becomes
     QuantDense consuming the quantized kernel + merged qscale directly.
     quantized=False forces the plain model (full-precision param sources:
     from_checkpoint, param init in the invariant arms)."""
-    from vitax.models import build_model
-    from vitax.ops.attention import make_attention_impl
-    from vitax.train.loop import _moe_dispatch_sharding, _token_sharding
     quant_matmul = None
     if quantized and _quant_model_mode(cfg):
         from vitax.ops.dequant_matmul import make_quant_matmul
         quant_matmul = make_quant_matmul(cfg)
-    return build_model(
-        cfg, attention_impl=make_attention_impl(cfg, mesh),
-        token_sharding=_token_sharding(cfg, mesh),
-        moe_dispatch_sharding=_moe_dispatch_sharding(cfg, mesh),
-        quant_matmul=quant_matmul)
+    return build_model_for(cfg, mesh, quant_matmul=quant_matmul)
 
 
 class Dispatched:
@@ -341,20 +335,16 @@ class InferenceEngine:
         """Restore params from a sharded Orbax epoch checkpoint (epoch None =
         latest) directly into the serving mesh layout."""
         from vitax.checkpoint.orbax_io import latest_epoch, restore_state
-        from vitax.train.state import build_optimizer, make_train_state
         ckpt_dir = ckpt_dir or cfg.ckpt_dir
         if epoch is None:
             epoch = latest_epoch(ckpt_dir)
             assert epoch is not None, f"no epoch checkpoint under {ckpt_dir}"
-        mesh = build_mesh(cfg)
-        model = _build_model(cfg, mesh, quantized=False)
         # the abstract TrainState is the restore target (no device
         # materialization); the optimizer exists only to shape it — its
         # restored moments are dropped immediately below
-        tx, _ = build_optimizer(cfg, max_iteration=1)
-        abstract, _, _ = make_train_state(
-            cfg, model, tx, mesh, jax.random.key(cfg.seed), materialize=False)
-        state = restore_state(ckpt_dir, epoch, abstract)
+        geom = Geometry.assemble(cfg, max_iteration=1)
+        mesh, model = geom.mesh, geom.model
+        state = restore_state(ckpt_dir, epoch, geom.abstract_state)
         engine = cls(cfg, mesh, model, state.params)
         del state  # opt_state/step freed: serving holds params only
         master_print(f"serve: params from Orbax checkpoint "

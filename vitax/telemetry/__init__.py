@@ -4,8 +4,6 @@ Subsystem map:
   flops      analytic model-FLOPs accounting + TPU peak table -> MFU
   sinks      JSONL event log (always-on) + optional TensorBoard mirror
   record     Recorder: versioned per-step records fanned out to sinks
-  schema     validators for the perf-data files CI folds into a trajectory
-             (bench payloads, BENCH_r*.json, autotune trial JSONL)
   watchdog   heartbeat hang detector: all-thread stack + memory dumps
   threads    thread-crash excepthook (kind:"thread_crash" events) and
              bounded shutdown joins with leaked-thread warnings
@@ -22,9 +20,6 @@ from vitax.telemetry.flops import (  # noqa: F401
     model_flops_per_step)
 from vitax.telemetry.record import (  # noqa: F401
     REQUIRED_STEP_KEYS, SCHEMA_VERSION, Recorder, build_recorder)
-from vitax.telemetry.schema import (  # noqa: F401
-    validate_autotune_trial, validate_bench_file, validate_bench_payload,
-    validate_trials_file)
 from vitax.telemetry.sinks import (  # noqa: F401
     JsonlSink, TensorBoardSink, make_tensorboard_sink)
 from vitax.telemetry.threads import (  # noqa: F401

@@ -15,8 +15,8 @@ batch's samples flow, not how many matmul FLOPs the optimizer step performs,
 so per-step FLOPs are `per_image x batch_size` for every (K, pp_microbatches)
 setting — the model is accumulation/pipeline aware by construction.
 
-Shared by bench.py, tools/profile_step.py and the training-loop Recorder so
-every MFU the repo reports is the same number.
+What the training loop's Recorder reports as MFU. The benchmark keeps its
+own copy (benchmark/flops.py); benchmark/tests hold the two equal.
 """
 
 from __future__ import annotations
@@ -61,9 +61,7 @@ def model_flops_per_image(cfg) -> float:
     Dense blocks count qkv/proj/fc1/fc2; MoE blocks count the router matmul
     plus top_k expert MLPs per token (capacity-dropped tokens still occupy
     their expert slot in the einsum impl, but dropped work is not useful —
-    top_k per token is the honest number). The dense path is term-for-term
-    the historical bench.py accounting, so measured baselines stay
-    comparable."""
+    top_k per token is the honest number)."""
     d, L = cfg.embed_dim, cfg.num_blocks
     n = cfg.num_patches
     h = cfg.mlp_hidden_dim

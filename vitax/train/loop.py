@@ -24,12 +24,16 @@ from vitax.checkpoint import (restore_state, restore_state_with_fallback,
                               save_state)
 from vitax.config import Config
 from vitax.data import build_datasets
-from vitax.models import build_model, count_params
-from vitax.parallel.mesh import BATCH_AXES, build_mesh
+from vitax.models import count_params
+from vitax.parallel.mesh import build_mesh
+# benchmark/harness.py imports these two names from here; a `benchmark`
+# issue re-points it at vitax/parallel/sharding.py (ROADMAP C13)
+from vitax.parallel.sharding import (  # noqa: F401
+    moe_dispatch_sharding as _moe_dispatch_sharding,
+    token_sharding as _token_sharding)
 from vitax.train.control import ArbiterReporter, ControlPlane
 from vitax.programs.builder import Geometry, build_program
-from vitax.programs.registry import get_scenario
-from vitax.train.state import TrainState, make_train_state
+from vitax.train.state import TrainState
 from vitax.telemetry import (Watchdog, build_recorder,
                              install_thread_excepthook)
 from vitax.telemetry.watchdog import EXIT_HANG
@@ -75,7 +79,6 @@ def train(cfg: Config) -> TrainState:
     mesh = build_mesh(cfg)
     master_print(f"mesh: {dict(mesh.shape)} over {jax.device_count()} devices "
                  f"({jax.process_count()} host(s))")
-    attention_impl = _select_attention(cfg, mesh)
 
     # --- datasets (reference :223-225) ---
     train_ds, train_loader, _, val_loader = build_datasets(cfg, mesh)
@@ -160,9 +163,6 @@ def train(cfg: Config) -> TrainState:
     elif cfg.resume_epoch > 0:
         resume_step, topology_change, resume_rounded = _elastic_resume(
             cfg, cfg.resume_epoch)
-    model = build_model(cfg, attention_impl=attention_impl,
-                        token_sharding=_token_sharding(cfg, mesh),
-                        moe_dispatch_sharding=_moe_dispatch_sharding(cfg, mesh))
     # re-derived from the LIVE loader each run (not a checkpointed value):
     # an elastic restart under a different topology gets the cadence its
     # CURRENT shard assignment supports (the stream sampler's steps_per_epoch
@@ -171,17 +171,21 @@ def train(cfg: Config) -> TrainState:
                        or getattr(train_loader, "steps_per_epoch", 0)
                        or (len(train_ds) // cfg.batch_size))
     max_iteration = steps_per_epoch * cfg.num_epochs
-    # the scenario registry (vitax/programs/registry.py) owns the optimizer
-    # assembly: --task train/distill get the reference AdamW chain verbatim,
-    # finetune appends the masked backbone-lr scale, probe masks the
-    # backbone frozen with head-only moments
-    scenario = get_scenario(cfg.task)
-    tx, schedule = scenario.make_optimizer(cfg, max_iteration)
-    # On resume, build only the ABSTRACT state (no device materialization — the
-    # checkpoint supplies the values; reference :246-248) and restore into it.
-    state, state_specs, _ = make_train_state(
-        cfg, model, tx, mesh, jax.random.key(cfg.seed),
-        materialize=cfg.resume_epoch <= 0)
+    # the one assembly (vitax/programs/builder.py): attention core, model
+    # with its activation anchors, the scenario's optimizer (registry.py:
+    # --task train/distill get the reference AdamW chain verbatim, finetune
+    # appends the masked backbone-lr scale, probe masks the backbone frozen
+    # with head-only moments) and the state born sharded. On resume only
+    # the ABSTRACT state is built (no device materialization — the
+    # checkpoint supplies the values; reference :246-248) and restored into.
+    geom = Geometry.assemble(cfg, max_iteration,
+                             materialize=cfg.resume_epoch <= 0)
+    model, schedule = geom.model, geom.schedule
+    master_print("attention core: "
+                 + getattr(model.attention_impl, "vitax_name", "dense jnp"))
+    # the loop owns the state: a restore or a warm start replaces it, every
+    # step donates it
+    state, geom.state = geom.state, None
     restore_info = None  # {"path": "peer"|"orbax", "epoch": N} for telemetry
     from vitax.checkpoint.orbax_io import restore_read_count
     reads_before_restore = restore_read_count()  # delta = THIS run's reads
@@ -242,15 +246,9 @@ def train(cfg: Config) -> TrainState:
             f"grad accumulation: {cfg.grad_accum_steps} microbatches of "
             f"{cfg.batch_size // cfg.grad_accum_steps} inside the jitted "
             f"step (one optimizer step per loader batch)")
-    # one build_program(task, geometry) entry for every jitted program the
-    # loop runs (vitax/programs/builder.py). The geometry wraps the loop's
-    # LIVE objects (non-owned), so the built programs are the exact
-    # constructors' outputs — the lowered bytes are pinned identical to the
-    # former direct make_train_step/make_eval_step calls
-    # (tests/test_programs.py).
-    geom = Geometry(cfg=cfg, mesh=mesh, model=model, tx=tx,
-                    schedule=schedule, state_specs=state_specs)
-    train_step = build_program(scenario.step_program, geom)
+    # every jitted program the loop runs comes from build_program on that
+    # one geometry
+    train_step = build_program(geom.scenario.step_program, geom)
     eval_step = build_program("eval", geom)
 
     smoothed_loss = SmoothedValue(window_size=5)
@@ -787,41 +785,6 @@ def _run_epochs(cfg, state, train_step, train_loader, val_loader, eval_step,
             break
 
     return state
-
-
-def _token_sharding(cfg: Config, mesh):
-    """(B, N, D) activation sharding: batch over (dp, fsdp), tokens over sp.
-    Anchors GSPMD propagation; None on single-device meshes."""
-    if mesh.size == 1:
-        return None
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    sp = mesh.shape.get("sp", 1)
-    token_axis = "sp" if (sp > 1 and cfg.num_patches % sp == 0) else None
-    return NamedSharding(mesh, P(BATCH_AXES, token_axis, None))
-
-
-def _moe_dispatch_sharding(cfg: Config, mesh):
-    """(E, B, C, D) dispatched-tensor sharding for the MoE einsums: experts
-    over "ep", batch over the data axes. The explicit anchor makes GSPMD
-    lower dispatch/combine to all-to-alls instead of the partitioner's
-    involuntary full rematerialization. None when dense or single-device."""
-    if cfg.moe_experts == 0 or mesh.size == 1:
-        return None
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    ep = mesh.shape.get("ep", 1)
-    return NamedSharding(
-        mesh, P("ep" if ep > 1 else None, ("dp", "fsdp"), None, None))
-
-
-def _select_attention(cfg: Config, mesh):
-    """Pick the attention core (vitax.ops.attention.make_attention_impl):
-    ring attention under sp, whole-N or streaming Pallas kernel on TPU,
-    dense jnp elsewhere."""
-    from vitax.ops.attention import make_attention_impl
-    impl = make_attention_impl(cfg, mesh)
-    master_print("attention core: "
-                 + getattr(impl, "vitax_name", "dense jnp"))
-    return impl
 
 
 def _run_logging(cfg, epoch, step, loss, lr, smoothed_loss, smoothed_time):

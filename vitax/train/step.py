@@ -46,7 +46,7 @@ def _make_logits_anchor(mesh: Mesh):
 def _select_by_name(cols, name: str):
     """Leaves of the 'intermediates' collection whose path contains `name` —
     sown values are selected BY NAME so any future sow (e.g. a debug metric)
-    cannot silently join the training objective (ADVICE r3)."""
+    cannot silently join the training objective."""
     return [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(cols)
             if any(getattr(k, "key", None) == name for k in path)]
 
