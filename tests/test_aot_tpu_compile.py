@@ -216,3 +216,40 @@ def test_fused_adamw_every_state_leaf_compiles(chip):
         assert bm * (-(-bn // 128) * 128) <= 64 * 1024, (m, n, bm, bn)
     for view in views:
         _compile_leaf_update(view, one_chip)
+
+
+@pytest.mark.parametrize("tokens,forward_sites", [(512, 2), (1024, 1)])
+def test_packed_step_forward_kernel_call_sites(chip, mosaic, tokens,
+                                               forward_sites):
+    """The lowered value_and_grad of a small packed model, kernels as real
+    Mosaic custom calls: from ATTN_KEEP_MIN_SPAN tokens of row on the per-block
+    remat keeps the forward kernel's outputs and the whole program holds one
+    `flash_packed_fwd` call site (the forward scan's); below it the backward
+    scan's body holds a second. The chip's compiler takes both."""
+    from vitax.config import Config
+    from vitax.models.vit import (ATTN_KEEP_MIN_SPAN, build_model,
+                                  sample_input)
+    from vitax.ops.attention import make_attention_impl
+    one_chip, _ = chip
+    assert 512 < ATTN_KEEP_MIN_SPAN <= 1024
+    cfg = Config(embed_dim=64, num_heads=4, num_blocks=2, mlp_dim=100,
+                 patch_size=4, num_classes=10, pack_tokens=tokens,
+                 pack_images=4, max_image_tokens=64, pos_grid=8, batch_size=2,
+                 fsdp_size=1, fake_data=True).validate()
+    model = build_model(cfg, attention_impl=make_attention_impl(
+        cfg, None, force_tpu_kernels=True))
+    x = sample_input(cfg, 2)
+    params = jax.eval_shape(lambda k: model.init(k, x, True),
+                            jax.random.key(0))
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip),
+        tree)
+    lowered = jax.jit(jax.value_and_grad(
+        lambda p, b: jnp.sum(model.apply(p, b, True) ** 2))).lower(
+            on_chip(params), on_chip(x))
+    text = lowered.as_text()
+    assert text.count('kernel_name = "flash_packed_fwd"') == forward_sites
+    assert text.count('kernel_name = "flash_packed_dkv"') == 1
+    assert text.count('kernel_name = "flash_packed_dq"') == 1
+    kernels = _kernel_names(lowered.compile())
+    assert sum("flash_packed_fwd" in k for k in kernels) == forward_sites

@@ -258,3 +258,133 @@ def test_windowed_remat_v2_moe_and_dropout(devices8, variant):
         np.testing.assert_array_equal(l1, l2)  # deterministic given seed
         _, l0 = run_steps(Config(remat_window=2, **kw).validate(), n_steps=3)
         assert l1 != l0, "dropout had no effect under the windowed scan"
+
+
+# --- what per-block remat keeps of the attention kernel ----------------------
+
+def kernel_model(**kw):
+    """A tiny dense model through the Pallas kernels (interpret mode), its
+    parameters and a batch of two images."""
+    from vitax.ops.attention import make_attention_impl
+    cfg = tiny_cfg(embed_dim=32, num_classes=4, batch_size=2, **kw)
+    impl = make_attention_impl(cfg, None, force_tpu_kernels=True)
+    model = build_model(cfg, attention_impl=impl)
+    x = jax.random.normal(jax.random.key(1),
+                          (2, cfg.image_size, cfg.image_size, 3), jnp.float32)
+    params = model.init(jax.random.key(0), x, True)
+    return model, params, x, impl.vitax_name
+
+
+def loss_and_grads(model):
+    return jax.value_and_grad(
+        lambda p, x: jnp.sum(model.apply(p, x, True) ** 2))
+
+
+@pytest.mark.parametrize("image,patch,family", [
+    (128, 4, "whole-N"),         # 1,024 tokens: the span where keeping starts
+    (192, 4, "streaming"),       # 2,304 tokens: past the whole-N kernels
+])
+def test_dense_keeping_program_equals_the_recomputing_one(monkeypatch, image,
+                                                          patch, family):
+    """A dense ViT at a long sequence gets what the packed model gets: the
+    kernel's o and lse are kept, and loss and every gradient leaf equal the
+    recomputing program's bit for bit."""
+    from vitax.models import vit
+    model, params, x, name = kernel_model(image_size=image, patch_size=patch)
+    assert family in name
+    assert vit.attention_span(model) >= vit.ATTN_KEEP_MIN_SPAN
+    assert vit.block_remat_policy(model) is vit._attention_kernel_saveable
+    kept = jax.jit(loss_and_grads(model))(params, x)
+    monkeypatch.setattr(vit, "ATTN_KEEP_MIN_SPAN", 1 << 30)
+    assert vit.block_remat_policy(model) is None
+    again = jax.jit(loss_and_grads(model))(params, x)
+    assert np.isfinite(float(kept[0])) and float(kept[0]) > 0.0
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(again)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_rule_does_not_engage_at_256_tokens(monkeypatch):
+    """The benchmark's dense cells (256 tokens): the model takes
+    `_REMAT_POLICIES` as it always did, and its lowered value_and_grad is the
+    same text with this PR's policy function in place and without it."""
+    from vitax.models import vit
+    model, params, x, _ = kernel_model(image_size=64, patch_size=4)
+    assert vit.attention_span(model) == 256
+    assert not vit.keeps_attention_residuals(model)
+    assert vit.block_remat_policy(model) is vit._REMAT_POLICIES["none_saveable"]
+    from vitax.parallel.mesh import build_mesh
+    from vitax.train.loop import _attention_remat_note
+    cfg = tiny_cfg(image_size=64, patch_size=4, fsdp_size=1, dp_size=8)
+    assert "runs its forward again (span 256 < 1024" in _attention_remat_note(
+        cfg, model, build_mesh(cfg))
+    with_rule = jax.jit(loss_and_grads(model)).lower(params, x).as_text()
+    monkeypatch.setattr(vit, "block_remat_policy",
+                        lambda m: vit._REMAT_POLICIES[m.remat_policy])
+    without = jax.jit(loss_and_grads(model)).lower(params, x).as_text()
+    assert with_rule == without
+
+
+@pytest.mark.parametrize("policy,grad_ckpt,keeps", [
+    ("none_saveable", True, True),
+    ("dots_saveable", True, False), ("dots_attn_saveable", True, False),
+    ("none_saveable", False, False),
+])
+def test_rule_leaves_the_other_policies_alone(policy, grad_ckpt, keeps):
+    from vitax.models import vit
+    cfg = tiny_cfg(image_size=128, patch_size=4, remat_policy=policy,
+                   grad_ckpt=grad_ckpt)
+    model = build_model(cfg, attention_impl=lambda q, k, v: q)
+    assert vit.keeps_attention_residuals(model) is keeps
+    if not keeps:
+        assert vit.block_remat_policy(model) is vit._REMAT_POLICIES[policy]
+    # no kernel, nothing to keep: the dense jnp core
+    assert not vit.keeps_attention_residuals(build_model(cfg))
+
+
+@pytest.mark.parametrize("arm", ["model", "overlap", "windowed", "pipeline"])
+def test_group_forwards_keep_their_own_policy(devices8, monkeypatch, arm):
+    """`make_overlap_forward`, `make_windowed_forward` and the pipeline body
+    recompute a group inside their own backward: at a span where the model's
+    own remat keeps the kernel's outputs they still consult
+    `_REMAT_POLICIES[cfg.remat_policy]` and never this PR's policy; and a
+    ZeRO-3 config at that span still arms `gather_overlap auto`."""
+    from vitax.models import vit
+    from vitax.parallel.sharding import gather_overlap_active
+    from vitax.programs.builder import Geometry
+    from vitax.train.loop import _attention_remat_note
+    from vitax.train.step import _forward_fn
+
+    asked = {"table": 0, "rule": 0}
+
+    def table_policy(prim, *_, **__):
+        asked["table"] += 1
+        return False
+
+    def rule_policy(prim, *_, **__):
+        asked["rule"] += 1
+        return False
+
+    monkeypatch.setitem(vit._REMAT_POLICIES, "none_saveable", table_policy)
+    monkeypatch.setattr(vit, "_attention_kernel_saveable", rule_policy)
+    kw = {"model": dict(fsdp_size=1, dp_size=8),        # plain data parallel
+          "overlap": dict(fsdp_size=-1),                # ZeRO-3, overlap auto
+          "windowed": dict(fsdp_size=1, dp_size=8, remat_window=2),
+          "pipeline": dict(pp_size=2, dp_size=4, fsdp_size=1)}[arm]
+    cfg = tiny_cfg(image_size=128, patch_size=4, embed_dim=32, num_classes=4,
+                   num_blocks=4, **kw)
+    geom = Geometry.assemble(cfg, force_tpu_kernels=True)
+    assert vit.keeps_attention_residuals(geom.model)
+    assert gather_overlap_active(cfg, geom.mesh) is (arm == "overlap")
+    forward = _forward_fn(cfg, geom.model, geom.mesh, geom.state_specs)
+    images = jax.ShapeDtypeStruct((8, 128, 128, 3), jnp.float32)
+    asked.update(table=0, rule=0)   # `model.init` went through the model's own
+    jax.eval_shape(jax.grad(lambda p, x: jnp.sum(forward(p, x) ** 2)),
+                   geom.abstract_state.params, images)
+    if arm == "model":
+        assert asked["rule"] > 0 and asked["table"] == 0
+    else:
+        assert asked["table"] > 0 and asked["rule"] == 0
+    # the trainer's start-up line says which
+    note = _attention_remat_note(cfg, geom.model, geom.mesh)
+    assert ("keeps its o and lse (span 1024 >= 1024" in note) is (arm == "model")
+    assert ("checkpoints groups of blocks itself" in note) is (arm != "model")

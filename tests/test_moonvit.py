@@ -374,6 +374,91 @@ def test_model_through_the_kernel_equals_the_dense_path(setup):
                                atol=2e-5)
 
 
+# --- what per-block remat keeps of the kernel --------------------------------
+
+def kernel_model(tokens):
+    """The small model through the segment-masked kernels (interpret mode) at
+    rows of `tokens`, with the batch of ROWS and perturbed parameters."""
+    from vitax.ops.attention import make_attention_impl
+    cfg = small_cfg(pack_tokens=tokens)
+    model = build_model(cfg, attention_impl=make_attention_impl(
+        cfg, None, force_tpu_kernels=True))
+    batch = {k: jnp.asarray(v) for k, v in make_batch(cfg, ROWS).items()}
+    return cfg, model, init_params(cfg, model), batch
+
+
+def loss_and_grads(model):
+    def loss(params, batch):
+        logits = model.apply(params, packed_inputs(batch), True)
+        return packed_loss(logits, batch)
+    return jax.value_and_grad(loss)
+
+
+def kernel_calls(jaxpr, name):
+    """`pallas_call`s named `name` anywhere in a jaxpr: its call sites."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "pallas_call"
+                and eqn.params["name"] == name):
+            count += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            count += kernel_calls(sub, name)
+    return count
+
+
+@pytest.mark.parametrize("tokens,keeps,forward_sites", [
+    (vit.ATTN_KEEP_MIN_SPAN // 2, False, 2),   # the backward scan re-runs it
+    (vit.ATTN_KEEP_MIN_SPAN, True, 1),         # the forward scan's, alone
+])
+def test_forward_kernel_call_sites_follow_the_span(tokens, keeps,
+                                                   forward_sites):
+    _, model, params, batch = kernel_model(tokens)
+    assert vit.keeps_attention_residuals(model) is keeps
+    jaxpr = jax.make_jaxpr(loss_and_grads(model))(params, batch).jaxpr
+    assert kernel_calls(jaxpr, "flash_packed_fwd") == forward_sites
+    assert kernel_calls(jaxpr, "flash_packed_dkv") == 1
+    assert kernel_calls(jaxpr, "flash_packed_dq") == 1
+
+
+def test_keeping_program_equals_the_recomputing_one_bit_for_bit(monkeypatch):
+    """What is kept is what the kernel would have produced again: loss and
+    every gradient leaf of the two programs are the same bits."""
+    _, model, params, batch = kernel_model(vit.ATTN_KEEP_MIN_SPAN)
+    kept = jax.jit(loss_and_grads(model))(params, batch)
+    monkeypatch.setattr(vit, "ATTN_KEEP_MIN_SPAN", 1 << 30)
+    assert not vit.keeps_attention_residuals(model)
+    again = jax.jit(loss_and_grads(model))(params, batch)
+    assert float(kept[0]) > 0.0
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(again)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("keeps", [True, False])
+def test_a_layer_keeps_its_input_o_and_lse_and_nothing_else(keeps):
+    """The residuals of one rematted block, beside its own arguments (the
+    block input, the layer's parameters, the row context): the kernel's o in
+    the BH layout and its lse as the backward kernels take them; nothing
+    under the span."""
+    from jax._src.ad_checkpoint import saved_residuals
+    tokens = vit.ATTN_KEEP_MIN_SPAN // (1 if keeps else 2)
+    cfg, model, params, batch = kernel_model(tokens)
+    block = vit.Block(**model.block_kwargs())
+    layer = jax.tree.map(lambda l: l[0], params["params"]["blocks"])
+    seg = batch["segment_ids"]
+    rope = vit.rope2d_tables(batch["positions"], 16, cfg.rope_base)
+    x = jnp.ones((2, tokens, cfg.embed_dim), jnp.float32)
+    body = jax.checkpoint(
+        lambda layer, x, seg, rope: block.apply({"params": layer}, x, True,
+                                                seg, rope),
+        policy=vit.block_remat_policy(model), prevent_cse=False)
+    kept = [aval for aval, why in saved_residuals(body, layer, x, seg, rope)
+            if "from the argument" not in why]
+    bh = 2 * cfg.num_heads
+    want = [((bh, tokens, 16), jnp.float32),
+            ((bh, 1, tokens), jnp.float32)] if keeps else []
+    assert sorted((a.shape, a.dtype) for a in kept) == sorted(want)
+
+
 # --- the packer -------------------------------------------------------------
 
 def test_packer_places_every_token_once_and_splits_no_image():

@@ -168,6 +168,10 @@ class Config:
     # Seen on v5e l14 before the ledger (a hand-built program at batch 32):
     # dots_attn_saveable 192.9 > dots_saveable 190.2 > none_saveable ~183
     # img/s/chip. A prior for ROADMAP A2, not a ledger number.
+    # From an attention span (patches, or a packed row) of 1,024 tokens on,
+    # none_saveable also keeps the attention kernel's o and lse (re-running
+    # it costs N_kv FLOPs a byte kept; 1,024 is 4x a v5e's ridge of 240
+    # FLOP/B: vitax/models/vit.py ATTN_KEEP_MIN_SPAN).
     remat_policy: str = "none_saveable" # none_saveable | dots_saveable | dots_attn_saveable (only if grad_ckpt)
     profile_dir: str = ""               # if set, capture a jax.profiler trace of a few steps
     profile_start_step: int = 2         # global step the profiler window opens after (with --profile_dir)

@@ -649,7 +649,7 @@ class VisionTransformer(nn.Module):
             return block(carry, det, *ctx), None
 
         if self.grad_ckpt:
-            policy = _REMAT_POLICIES[self.remat_policy]  # KeyError on unknown names
+            policy = block_remat_policy(self)  # at the end of this module
             # remat composed inside the scan body — per-block recompute, the
             # reference's checkpoint_module-then-FSDP order (run_vit_training.py:145).
             body = nn.remat(body, policy=policy, prevent_cse=False, static_argnums=(2,))
@@ -1049,3 +1049,56 @@ def expected_param_count(cfg: Config) -> int:
     final_ln = 2 * d
     head = d * cfg.num_classes + cfg.num_classes
     return per_block * cfg.num_blocks + patch + pos + final_ln + head
+
+
+# --- what per-block remat keeps of the attention kernel ---------------------
+# (Below everything a kernel's call stack passes through: the Mosaic payloads
+# in a lowered step embed those line numbers, and the cells this rule does
+# not reach lower to the text they had before it.)
+
+"""From which attention span `none_saveable` keeps the forward kernel's
+outputs. Running the kernel again costs 4 * N_kv * Dh FLOPs a query and head;
+keeping o costs one more write and one read of 2 * Dh bytes: N_kv FLOPs a
+byte kept. A v5e's ridge is 197 TFLOP/s / 819 GB/s = 240 FLOP/B, so at 256
+tokens the two cost the same (with everything kept the dense cells read
+-0.26% and +0.07%: ledger, PR 27), and from 4x the ridge on the re-run is the
+dearer one, on kernels that run well under their roofline at that."""
+ATTN_KEEP_MIN_SPAN = 1024
+
+
+def _attention_kernel_saveable(prim, *_, **__):
+    """`none_saveable` + the attention forward kernel's own outputs (o and
+    lse, in the layout the backward kernels read): the block keeps them beside
+    its input, and the rematted backward runs no second forward kernel. The
+    qkv matmul, RoPE, the relayout of q, k, v and everything else are
+    recomputed as before. The kernels are a block's only `pallas_call`s; a
+    shard_map around one applies the policy to its body."""
+    return getattr(prim, "name", "") == "pallas_call"
+
+
+def attention_span(model: VisionTransformer) -> int:
+    """The keys one query can meet, as the model's shape gives them: a packed
+    row, or the image's patches."""
+    return model.pack_tokens or (model.image_size // model.patch_size) ** 2
+
+
+def keeps_attention_residuals(model: VisionTransformer) -> bool:
+    """Whether `VisionTransformer.__call__`'s per-block remat keeps the
+    attention kernel's o and lse: only where `none_saveable` would run the
+    kernel twice and the span makes that the dearer choice. Not under
+    sequence parallelism: ring attention runs sp block products a layer and
+    would keep every one of them."""
+    ts = model.token_sharding
+    return (model.grad_ckpt and model.remat_policy == "none_saveable"
+            and model.attention_impl is not None
+            and (ts is None or ts.spec[1] is None)
+            and attention_span(model) >= ATTN_KEEP_MIN_SPAN)
+
+
+def block_remat_policy(model: VisionTransformer):
+    """The policy of the per-block remat in `VisionTransformer.__call__`. The
+    group forwards above and the pipeline body recompute a group inside their
+    own backward and take `_REMAT_POLICIES` as it is."""
+    if keeps_attention_residuals(model):
+        return _attention_kernel_saveable
+    return _REMAT_POLICIES[model.remat_policy]  # KeyError on unknown names

@@ -59,6 +59,33 @@ def _sharded_param_count(state: TrainState) -> int:
     return total
 
 
+def _attention_remat_note(cfg: Config, model, mesh) -> str:
+    """The `attention core:` line's second half: whether a rematted block
+    keeps the forward kernel's o and lse or runs the kernel again in its
+    backward, and why (vitax/models/vit.py: keeps_attention_residuals). The
+    pipeline body and the group forwards (vitax/train/step.py: _forward_fn)
+    checkpoint blocks themselves and always recompute."""
+    from vitax.models.vit import (ATTN_KEEP_MIN_SPAN, attention_span,
+                                  keeps_attention_residuals)
+    from vitax.parallel.sharding import gather_overlap_active
+    if model.attention_impl is None or not cfg.grad_ckpt:
+        return ""
+    span = attention_span(model)
+    if (mesh.shape.get("pp", 1) > 1 or gather_overlap_active(cfg, mesh)
+            or cfg.remat_window > 1):
+        why = "this forward checkpoints groups of blocks itself"
+    elif keeps_attention_residuals(model):
+        return (f"; remat keeps its o and lse (span {span} >= "
+                f"{ATTN_KEEP_MIN_SPAN} tokens)")
+    elif cfg.remat_policy != "none_saveable":
+        return f"; remat policy {cfg.remat_policy}"
+    elif span >= ATTN_KEEP_MIN_SPAN:
+        why = "sequence-parallel: one set of o and lse a ring step"
+    else:
+        why = f"span {span} < {ATTN_KEEP_MIN_SPAN} tokens"
+    return f"; remat runs its forward again ({why})"
+
+
 def train(cfg: Config) -> TrainState:
     # persistent XLA compilation cache, placed by JAX_COMPILATION_CACHE_DIR
     # or at the fixed in-checkout default: restarts (launcher --restart,
@@ -182,7 +209,8 @@ def train(cfg: Config) -> TrainState:
                              materialize=cfg.resume_epoch <= 0)
     model, schedule = geom.model, geom.schedule
     master_print("attention core: "
-                 + getattr(model.attention_impl, "vitax_name", "dense jnp"))
+                 + getattr(model.attention_impl, "vitax_name", "dense jnp")
+                 + _attention_remat_note(cfg, model, geom.mesh))
     # the loop owns the state: a restore or a warm start replaces it, every
     # step donates it
     state, geom.state = geom.state, None
