@@ -3,8 +3,10 @@
 A copy of the arithmetic of `vitax/telemetry/flops.py:model_flops_per_image`
 (PaLM appendix B convention: recomputation, padding and dropped work are not
 useful and are not counted), kept here so that no later PR can move the
-yardstick; `benchmark/tests` holds the two equal. Takes a configuration
-file's dict, not a `Config`.
+yardstick; `benchmark/tests` holds the two equal through
+`against_program`. Takes a configuration file's dict, not a `Config`. The
+arithmetic of the traffic kinds `train_resident` and `serve_closed`: a
+generator names its module as `arithmetic`.
 """
 
 from __future__ import annotations
@@ -42,3 +44,14 @@ def param_count(config: dict) -> int:
     embed = 3 * config["patch_size"] ** 2 * d + d + num_patches(config) * d
     head = 2 * d + d * config["num_classes"] + config["num_classes"]
     return depth * block + embed + head
+
+
+def against_program(config: dict, traffic: dict, cfg) -> list:
+    """[(what, this copy's value, the program's)] for the `Config` a
+    generator of this arithmetic built from `config`."""
+    from vitax.models.vit import expected_param_count
+    from vitax.telemetry import flops as programs
+    return [("FLOPs an image", model_flops_per_image(config),
+             programs.model_flops_per_image(cfg)),
+            ("patches", num_patches(config), cfg.num_patches),
+            ("parameters", param_count(config), expected_param_count(cfg))]

@@ -6,7 +6,8 @@ and `vitax/models/vit.py:expected_param_count` (PaLM appendix B convention:
 recomputation, padding, the masked part of an attention block and the
 position table's resize are not useful and are not counted), kept here so
 that no later PR can move the yardstick; `benchmark/tests` holds the copies
-equal. Takes a configuration file's dict and what a step's batch held.
+equal through `against_program`. Takes a configuration file's dict and what
+a step's batch held. The arithmetic of the traffic kind `train_packed`.
 """
 
 from __future__ import annotations
@@ -44,3 +45,15 @@ def param_count(config: dict) -> int:
              + config["native_res"]["pos_grid"] ** 2 * d)
     head = 2 * d + d * config["num_classes"] + config["num_classes"]
     return depth * block + embed + head
+
+
+def against_program(config: dict, traffic: dict, cfg) -> list:
+    """[(what, this copy's value, the program's)] for the `Config` the
+    packed generator built from `config`, on the traffic's own layout."""
+    from vitax.models.vit import expected_param_count
+    from vitax.telemetry.flops import packed_flops_per_step
+    counts = layout_counts(traffic["rows"])
+    return [("FLOPs a step", model_flops_per_step(config, **counts),
+             packed_flops_per_step(cfg, **counts)),
+            ("MLP width", mlp_dim(config), cfg.mlp_hidden_dim),
+            ("parameters", param_count(config), expected_param_count(cfg))]

@@ -17,10 +17,13 @@ order over all clients is fixed) as soon as its previous reply has arrived.
 A reply is counted (`attempted`, `failed`, latency) where it ARRIVES inside
 the window; its latency runs from just before the request is sent to the last
 byte of the reply. `arrivals` are the arrival times of the good replies, for
-the rate; `answered_work` counts them as work done inside the window, a
-request that straddles an edge by the share of its send-to-reply time inside
-(whole replies come a batch at a time, so their bare count over a fixed
-window moves in steps of a batch).
+the rate, and `arrival_after_close` that of the first good reply after the
+window's nominal close (requests in flight at the close are answered, so
+there is one): the rate's window runs from a reply to a reply, as a train
+cell's runs from a fence to a fence. `answered_work` counts replies as work
+done inside the window, a request that straddles an edge by the share of its
+send-to-reply time inside (whole replies come a batch at a time, so their
+bare count over a fixed window moves in steps of a batch).
 """
 
 import http.client
@@ -106,6 +109,8 @@ def main() -> int:
         "failed": sum(not r[4] for r in inside),
         "answered_work": work,
         "arrivals": sorted(r[1] for r in inside if r[4]),
+        "arrival_after_close": min(
+            (r[1] for r in rows if r[4] and r[1] > t_close), default=None),
         "latency_s": sorted(r[1] - r[0] for r in inside if r[4]),
         "statuses": sorted({r[3] for r in inside}),
         "kept": [k for k in kept if t_open < k[0] <= t_close],

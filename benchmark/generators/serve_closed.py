@@ -8,15 +8,20 @@ Parameters (the traffic mix's file):
                      seed (random content, `pool_side_min..max` px, quality
                      `pool_quality`: the recipe of `bench.py:_write_random_jpegs`)
   warm_seconds       closed-loop traffic before the window opens
-  rate_span          the throughput is the median rate at which this many
-                     consecutive replies arrive (metrics/serve_images_per_s_chip.py)
+  rate_span          the steady rate, a per-layer metric, is the median rate
+                     at which this many consecutive replies arrive
+                     (metrics/serve_steady_images_per_s_chip.py); the judged
+                     throughput is every reply over the whole window
+                     (metrics/serve_images_per_s_chip.py)
   reference_images   how many of the pool's first images the plain
                      reference answers; every reply to one of them, in set-up
                      and in the window, is held to it
 
 The server is `vitax.serve.server.start_server` on an `InferenceEngine`, as
 `python -m vitax.serve` runs it, with every serving knob at its default. The
-weights are the trainer's seeded initialisation, made on the device and
+model is the builder's (`build_model_for`, vitax/programs/builder.py: the
+model half of the program's one constructor, which the engine itself takes);
+the weights are the trainer's seeded initialisation, made on the device and
 handed to the engine the way `from_checkpoint` does after its read: no
 checkpoint IO in set-up. The parent holds the chip and runs the server; the
 load generator (`_loadgen.py`) never imports JAX.
@@ -39,8 +44,8 @@ import threading
 import time
 import urllib.request
 
+from benchmark import flops as arithmetic   # this kind's FLOPs and parameters
 from benchmark import harness
-from benchmark import manifest as mf
 from benchmark.reference import vit as reference
 
 # Served top-k probabilities against the float32 reference's softmax at the
@@ -52,6 +57,14 @@ from benchmark.reference import vit as reference
 # of 8 images x 5 classes through 8 blocks of 10B width (PERF.md, PR 22). A
 # format with 3 bits of mantissa rounds 32 times coarser: some 0.5.
 LOGP_ATOL = 8e-2
+
+
+def build_config(config_kwargs: dict, traffic: dict, n_devices: int,
+                 seed: int):
+    """The server's `Config`: the model's shape and the seed. Batching is
+    the server's own default, so traffic and chips set nothing."""
+    from vitax.config import Config
+    return Config(**config_kwargs, seed=seed).validate()
 
 
 def make_pool(run: harness.Run) -> list:
@@ -131,9 +144,9 @@ def setup(run: harness.Run) -> dict:
 
     import jax
     import numpy as np
-    from vitax.config import Config
     from vitax.parallel.mesh import build_mesh
-    from vitax.serve import engine as serve_engine
+    from vitax.programs.builder import build_model_for
+    from vitax.serve.engine import InferenceEngine
     from vitax.serve.server import decode_image_bytes, start_server
 
     t0 = time.time()
@@ -144,15 +157,16 @@ def setup(run: harness.Run) -> dict:
     run.records["pool_s"] = time.time() - t0
     run.records["pool_bytes"] = sum(len(b) for b in pool)
 
-    cfg = Config(**mf.config_kwargs(run.config), seed=run.seed).validate()
+    cfg = build_config(run.config_kwargs, run.traffic, jax.device_count(),
+                       run.seed)
     if run.trace_on:
         cfg = dataclasses.replace(
             cfg, metrics_dir=os.path.join(run.work_dir, "serve_metrics"))
     t0 = time.time()
     mesh = build_mesh(cfg)
-    model = serve_engine._build_model(cfg, mesh, quantized=False)
+    model = build_model_for(cfg, mesh)
     params = init_params(cfg, mesh, model)
-    engine = serve_engine.InferenceEngine(cfg, mesh, model, params)
+    engine = InferenceEngine(cfg, mesh, model, params)
     httpd, ctx = start_server(cfg, engine, port=0)
     url = f"http://127.0.0.1:{httpd.server_address[1]}/predict"
     child = start_loadgen(run, url, pool_path)
@@ -235,6 +249,7 @@ def window(run: harness.Run, live: dict, compiles: harness.CompileCounter) -> No
         "answered": result["attempted"] - result["failed"] - wrong,
         "answered_work": result["answered_work"],
         "arrivals": result["arrivals"],
+        "arrival_after_close": result["arrival_after_close"],
         "latency_s": result["latency_s"],
         "latency_quantiles_ms": {
             str(q): 1e3 * harness.percentile(result["latency_s"], q)
@@ -284,19 +299,16 @@ def finish(run: harness.Run, live: dict) -> None:
     live.clear()
 
 
-def lower_described(config: dict, traffic: dict, devices):
+def lower_described(config_kwargs: dict, traffic: dict, devices):
     """The largest bucket's program lowered for described devices over
     abstract parameters (benchmark/size_cells.py). Nothing runs."""
-    import jax
-    import jax.numpy as jnp
-    from vitax.config import Config
+    from vitax.programs.builder import Geometry, build_program
     from vitax.serve.engine import InferenceEngine
-    cfg = Config(**mf.config_kwargs(config)).validate()
-    mesh, model = harness.assemble(cfg, devices, force_kernels=True)
-    sample = jnp.zeros((1, cfg.image_size, cfg.image_size, 3), jnp.float32)
-    params = jax.eval_shape(lambda k: model.init(k, sample, True),
-                            jax.random.key(0))
-    engine = InferenceEngine(cfg, mesh, model, params)
+    cfg = build_config(config_kwargs, traffic, len(devices), 0)
+    geom = Geometry.assemble(cfg, devices=devices, force_tpu_kernels=True)
+    engine = InferenceEngine(cfg, geom.mesh, geom.model,
+                             geom.abstract_state.params)
     bucket = engine.buckets[-1]
-    lowered, _ = engine._lower_bucket(bucket)
+    lowered = build_program("serve_bucket", geom, bucket=bucket,
+                            engine=engine)
     return lowered, f"serve bucket {bucket}"

@@ -14,15 +14,15 @@ Parameters (the traffic mix's file):
                      plain reference is run on, image by image
   run_ahead, warm_steps, expect_decreasing, reference_grad_norm
                      as in `train_resident`
-  rehearse           overrides of the keys above and of the configuration's
-                     `native_res` block for `--rehearse` (off the TPU):
-                     tiny shapes, control flow only
+  rehearse           overrides of the keys above and, under `config`, of
+                     the `native_res` block for `--rehearse` (run.py, over
+                     the family's own `rehearse` block): control flow only
 
 The program is what `python -m vitax.train --pack_tokens ...` builds for a
 `Config` that names only the model's shape (the configuration file's
-`Config` fields, its `native_res` block, and the row shape above): mesh,
-model, optimizer and `make_train_state` as the loop assembles them, wrapped
-in a `Geometry` -> `build_program("train", ...)`, lowered once. The layout
+`Config` fields, its `native_res` block, and the row shape above):
+`Geometry.assemble` (vitax/programs/builder.py, the program's one
+constructor) -> `build_program("train", ...)`, lowered once. The layout
 arrays come from the trainer's own packer (`vitax/data/packing.py:
 row_layout`); pixels and labels are made on the device from the seed.
 `images` is what the step itself counted (its `images` metric) x steps.
@@ -34,9 +34,8 @@ import time
 
 import numpy as np
 
-from benchmark import flops_packed
+from benchmark import flops_packed as arithmetic   # this kind's FLOPs
 from benchmark import harness
-from benchmark import manifest as mf
 from benchmark.generators import train_resident
 from benchmark.reference import moonvit as reference
 
@@ -61,21 +60,10 @@ LOGITS_RTOL = 3e-2
 MAX_ITERATION = train_resident.MAX_ITERATION
 
 
-def shapes(run_config: dict, run_traffic: dict, on_chip: bool):
-    """(configuration, traffic) as they are run: off the TPU the traffic
-    file's `rehearse` block overrides both (benchmark/rehearse.json knows
-    no kind added after it and shrinks only the top-level shape keys)."""
-    config, traffic = dict(run_config), dict(run_traffic)
-    tiny = {} if on_chip else traffic.get("rehearse", {})
-    config["native_res"] = {**config["native_res"],
-                            **tiny.get("native_res", {})}
-    traffic.update({k: v for k, v in tiny.items() if k != "native_res"})
-    return config, traffic
-
-
-def build_config(config: dict, traffic: dict, n_devices: int, seed: int):
+def build_config(config_kwargs: dict, traffic: dict, n_devices: int,
+                 seed: int):
     from vitax.config import Config
-    return Config(**mf.config_kwargs(config), **config["native_res"],
+    return Config(**config_kwargs,
                   pack_tokens=int(traffic["row_tokens"]),
                   pack_images=int(traffic["images_per_row"]),
                   batch_size=int(traffic["rows_per_chip"]) * n_devices,
@@ -126,21 +114,15 @@ def setup(run: harness.Run) -> dict:
     import jax
     import jax.numpy as jnp
     from vitax.programs.builder import Geometry, build_program
-    from vitax.programs.registry import get_scenario
-    from vitax.train.state import make_train_state
     from vitax.train.step import packed_inputs
 
     n_dev = jax.device_count()
-    config, traffic = shapes(run.config, run.traffic,
-                             run.device.get("platform") == "tpu")
-    cfg = build_config(config, traffic, n_dev, run.seed)
+    config, traffic = run.config, run.traffic
+    cfg = build_config(run.config_kwargs, traffic, n_dev, run.seed)
     t0 = time.time()
-    mesh, model = harness.assemble(cfg)
-    tx, schedule = get_scenario(cfg.task).make_optimizer(cfg, MAX_ITERATION)
-    state, specs, _ = make_train_state(cfg, model, tx, mesh,
-                                       jax.random.key(cfg.seed))
-    geom = Geometry(cfg=cfg, mesh=mesh, model=model, tx=tx,
-                    schedule=schedule, state_specs=specs)
+    geom = Geometry.assemble(cfg, MAX_ITERATION, materialize=True)
+    state, geom.state = geom.state, None    # the step donates it
+    mesh, model = geom.mesh, geom.model
     step = build_program("train", geom)
     inputs = make_inputs(cfg, mesh, run.seed, {
         "batch": layout(cfg, traffic["rows"], n_dev),
@@ -156,7 +138,7 @@ def setup(run: harness.Run) -> dict:
     run.program["packed_attention_kernels"] = sum(
         "flash_packed_" in ln for ln in compiled.as_text().splitlines()
         if 'custom_call_target="tpu_custom_call"' in ln)
-    run.program["params"] = flops_packed.param_count(config)
+    run.program["params"] = arithmetic.param_count(config)
 
     # the reference first (the step donates the state it is given): each
     # image of the check batch alone, unpacked, in float32
@@ -219,7 +201,7 @@ def setup(run: harness.Run) -> dict:
     # step), held against the layout the traffic file gives
     counts = {k: float(metrics[k]) for k in
               ("tokens", "padding_tokens", "images", "token_pairs")}
-    want = flops_packed.layout_counts(traffic["rows"])
+    want = arithmetic.layout_counts(traffic["rows"])
     run.records["packed_counts"] = counts
     run.check(all(counts[k] == want[k] * n_dev for k in want),
               f"the step counted {counts}, the layout holds {want} a chip")
@@ -246,24 +228,19 @@ def finish(run: harness.Run, live: dict) -> None:
     live.clear()
 
 
-def lower_described(config: dict, traffic: dict, devices):
+def lower_described(config_kwargs: dict, traffic: dict, devices):
     """The cell's step lowered for described devices, from abstract shapes
     (benchmark/size_cells.py). Nothing runs."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
     from vitax.parallel.mesh import batch_pspec
-    from vitax.programs.registry import get_scenario
-    from vitax.train.state import make_train_state
-    from vitax.train.step import make_train_step
-    config, traffic = shapes(config, traffic, on_chip=True)
-    cfg = build_config(config, traffic, len(devices), 0)
-    mesh, model = harness.assemble(cfg, devices, force_kernels=True)
-    tx, schedule = get_scenario(cfg.task).make_optimizer(cfg, MAX_ITERATION)
-    state, specs, _ = make_train_state(cfg, model, tx, mesh,
-                                       jax.random.key(0), materialize=False)
-    step = make_train_step(cfg, model, tx, mesh, specs, schedule=schedule)
-    sh = NamedSharding(mesh, batch_pspec())
+    from vitax.programs.builder import Geometry, build_program
+    cfg = build_config(config_kwargs, traffic, len(devices), 0)
+    geom = Geometry.assemble(cfg, MAX_ITERATION, devices=devices,
+                             force_tpu_kernels=True)
+    step, state = build_program("train", geom), geom.abstract_state
+    sh = NamedSharding(geom.mesh, batch_pspec())
     lay = layout(cfg, traffic["rows"], len(devices))
     batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=sh)
              for k, v in lay.items()}

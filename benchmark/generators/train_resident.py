@@ -14,10 +14,12 @@ Parameters (the traffic mix's file):
                      first (the same batch every step: the optimizer moves)
 
 The program is what `python -m vitax.train` builds for a `Config` that names
-only the model: mesh, model, optimizer and `make_train_state` as the loop
-assembles them, wrapped in a `Geometry` -> `build_program("train", ...)`. It is lowered once for the cell's shapes and
-the compiled executable is what runs in the window, so a second shape cannot
-compile there. Data loading, the train loop and checkpoints are bypassed.
+only the model: the program's one constructor, `Geometry.assemble`
+(vitax/programs/builder.py: mesh, model, optimizer and a live train state
+from the seed), then `build_program("train", ...)`. It is lowered once for
+the cell's shapes and the compiled executable is what runs in the window, so
+a second shape cannot compile there. Data loading, the train loop and
+checkpoints are bypassed; the trainer's loop is not even imported.
 """
 
 from __future__ import annotations
@@ -26,9 +28,8 @@ import collections
 import math
 import time
 
-from benchmark import flops as bench_flops
+from benchmark import flops as arithmetic   # this kind's FLOPs and parameters
 from benchmark import harness
-from benchmark import manifest as mf
 from benchmark.reference import vit as reference
 
 # Step-0 loss against the float32 reference on the same weights and images.
@@ -46,11 +47,12 @@ MIN_STEPS = 3       # whatever --seconds says, the window holds this many
 MAX_ITERATION = 10_000   # the schedule's length: `Geometry.from_config`'s default
 
 
-def build_config(run: harness.Run, n_devices: int):
+def build_config(config_kwargs: dict, traffic: dict, n_devices: int,
+                 seed: int):
     from vitax.config import Config
-    per_chip = int(run.traffic["per_chip_batch"])
-    return Config(**mf.config_kwargs(run.config),
-                  batch_size=per_chip * n_devices, seed=run.seed).validate()
+    return Config(**config_kwargs,
+                  batch_size=int(traffic["per_chip_batch"]) * n_devices,
+                  seed=seed).validate()
 
 
 def make_inputs(cfg, mesh, seed: int, sample: int):
@@ -94,25 +96,15 @@ def make_inputs(cfg, mesh, seed: int, sample: int):
 def setup(run: harness.Run) -> dict:
     import jax
     from vitax.programs.builder import Geometry, build_program
-    from vitax.programs.registry import get_scenario
-    from vitax.train.state import make_train_state
 
-    n_dev = jax.device_count()
-    cfg = build_config(run, n_dev)
+    cfg = build_config(run.config_kwargs, run.traffic, jax.device_count(),
+                       run.seed)
     t0 = time.time()
-    # the loop's own assembly (vitax/train/loop.py): live objects wrapped
-    # in a Geometry. `Geometry.from_config` builds the same stack but traces
-    # the model once more for an abstract state nobody here needs: seconds
-    # of set-up in every run.
-    mesh, model = harness.assemble(cfg)
-    tx, schedule = get_scenario(cfg.task).make_optimizer(cfg, MAX_ITERATION)
-    state, specs, _ = make_train_state(cfg, model, tx, mesh,
-                                       jax.random.key(cfg.seed))
-    geom = Geometry(cfg=cfg, mesh=mesh, model=model, tx=tx,
-                    schedule=schedule, state_specs=specs)
+    geom = Geometry.assemble(cfg, MAX_ITERATION, materialize=True)
+    state, geom.state = geom.state, None    # the step donates it
     step = build_program("train", geom)
     sample = int(run.traffic["reference_sample"])
-    inputs = make_inputs(cfg, mesh, run.seed, sample)
+    inputs = make_inputs(cfg, geom.mesh, run.seed, sample)
     rng = jax.random.key(cfg.seed + 1)
     jax.block_until_ready((state, inputs))
     run.records["state_s"] = time.time() - t0
@@ -121,7 +113,7 @@ def setup(run: harness.Run) -> dict:
     compiled = step.lower(state, inputs["batch"], rng).compile()
     run.records["compile_or_cache_s"] = time.time() - t0
     run.program.update(harness.program_facts(compiled))
-    run.program["params"] = bench_flops.param_count(run.config)
+    run.program["params"] = arithmetic.param_count(run.config)
 
     # the reference first: the step donates the state it is given
     t0 = time.time()
@@ -245,26 +237,20 @@ def finish(run: harness.Run, live: dict) -> None:
     live.clear()
 
 
-def lower_described(config: dict, traffic: dict, devices):
-    """The cell's step lowered for described devices, from abstract shapes
+def lower_described(config_kwargs: dict, traffic: dict, devices):
+    """The cell's step lowered for described devices (not attached, the
+    production kernels forced), from abstract shapes
     (benchmark/size_cells.py). Nothing runs."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
-    from vitax.config import Config
     from vitax.parallel.mesh import batch_pspec
-    from vitax.programs.registry import get_scenario
-    from vitax.train.state import make_train_state
-    from vitax.train.step import make_train_step
-    cfg = Config(**mf.config_kwargs(config),
-                 batch_size=int(traffic["per_chip_batch"]) * len(devices)
-                 ).validate()
-    mesh, model = harness.assemble(cfg, devices, force_kernels=True)
-    tx, schedule = get_scenario(cfg.task).make_optimizer(cfg, MAX_ITERATION)
-    state, specs, _ = make_train_state(cfg, model, tx, mesh,
-                                       jax.random.key(0), materialize=False)
-    step = make_train_step(cfg, model, tx, mesh, specs, schedule=schedule)
-    sh = NamedSharding(mesh, batch_pspec())
+    from vitax.programs.builder import Geometry, build_program
+    cfg = build_config(config_kwargs, traffic, len(devices), 0)
+    geom = Geometry.assemble(cfg, MAX_ITERATION, devices=devices,
+                             force_tpu_kernels=True)
+    step, state = build_program("train", geom), geom.abstract_state
+    sh = NamedSharding(geom.mesh, batch_pspec())
     s = cfg.image_size
     batch = {"image": jax.ShapeDtypeStruct((cfg.batch_size, s, s, 3),
                                            jnp.uint8, sharding=sh),

@@ -27,6 +27,7 @@ class Run:
     """One run of one cell: what generators fill and metric readers read."""
     cell: dict
     config: dict
+    config_kwargs: dict          # the `Config` fields the configuration sets
     traffic: dict
     seed: int
     seconds: float
@@ -109,25 +110,6 @@ def program_facts(compiled) -> dict:
         "step_bytes": (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                        + max(mem.output_size_in_bytes - alias, 0)),
     }
-
-
-def assemble(cfg, devices=None, force_kernels: bool = False):
-    """(mesh, model) for a `Config`, line for line as the trainer's loop
-    assembles them (vitax/train/loop.py). `devices` and `force_kernels` are
-    for a DESCRIBED topology (benchmark/size_cells.py): devices that are not
-    attached, with the production kernels forced."""
-    from vitax.models import build_model
-    from vitax.ops.attention import make_attention_impl
-    from vitax.parallel.mesh import build_mesh
-    from vitax.train.loop import _moe_dispatch_sharding, _token_sharding
-    mesh = build_mesh(cfg) if devices is None else build_mesh(cfg, devices=devices)
-    attention = (make_attention_impl(cfg, mesh, force_tpu_kernels=True)
-                 if force_kernels else make_attention_impl(cfg, mesh))
-    model = build_model(
-        cfg, attention_impl=attention,
-        token_sharding=_token_sharding(cfg, mesh),
-        moe_dispatch_sharding=_moe_dispatch_sharding(cfg, mesh))
-    return mesh, model
 
 
 def span(name: str):

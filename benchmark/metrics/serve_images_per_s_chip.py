@@ -1,24 +1,21 @@
 """Requests answered 200 with a well-formed, right answer, per second and
-chip, on the client's clock: the MEDIAN, over every run of `rate_span`
-consecutive replies that arrived inside the window, of `rate_span` / the time
-from the first of them to the last.
+chip, on the client's clock, over ALL the work and ALL the time of the
+window: every good reply from the first that arrived inside the window up to
+(not with) the first that arrived after its nominal close, divided by the
+time between those two arrivals.
 
-Why not replies / seconds: whole replies arrive a batch at a time, so their
-count over a fixed window moves in steps of a batch (1.1% at 10 s); and the
-host's cores are shared, so in one run in six a stall of a few hundred
-milliseconds took 2% off the whole-window rate (PERF.md, PR 22). A span of 64
-replies is 8 batches of 8, under a second: its ends fall on the same place
-in a batch, and a stall touches a tenth of the spans, not their median."""
-
-import statistics
+The window runs from a reply to a reply, as a train cell's runs from a fence
+to a fence: whole replies arrive a batch at a time, so their count over a
+fixed ten seconds moves in steps of a batch (0.85%), while the time from the
+first reply of one batch to the first of a later one holds whole batches
+only. A stall, a slow batch or a tail anywhere in the window, across its
+close too, takes its full share off this rate; the pace between stalls is
+the per-layer `serve_steady_images_per_s_chip`."""
 
 
 def read(run):
     arrivals = run.records.get("arrivals")
-    span = int(run.traffic.get("rate_span", 0))
-    if not arrivals or span < 1 or len(arrivals) <= span:
+    closes = run.records.get("arrival_after_close")
+    if not arrivals or closes is None or run.records["failed"]:
         return None
-    if run.records["failed"]:
-        return None
-    rates = [span / (b - a) for a, b in zip(arrivals, arrivals[span:]) if b > a]
-    return statistics.median(rates) / run.chips
+    return len(arrivals) / (closes - arrivals[0]) / run.chips

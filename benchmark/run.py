@@ -12,9 +12,9 @@ the device's busy seconds and a breakdown of the device trace.
 There is no CPU fallback: without a TPU, or with another number of chips than
 the cell asks for, the process exits non-zero and prints no result.
 `--rehearse` is the only way to run off the chip: the same code at the tiny
-shapes of `benchmark/rehearse.json`, pinned to the CPU (virtual devices for a
-cell on several chips); its line says `"platform": "cpu"`, carries every
-metric's name with the value null, and is no result.
+shapes of the family's and the traffic file's `rehearse` blocks, pinned to the CPU (virtual
+devices for a cell on several chips); its line says `"platform": "cpu"`,
+carries every metric's name with the value null, and is no result.
 """
 
 import time
@@ -53,13 +53,6 @@ def parse_args():
     return ap.parse_args()
 
 
-def apply_rehearsal(config: dict, traffic: dict) -> None:
-    with open(os.path.join(mf.BENCH_DIR, "rehearse.json"), encoding="utf-8") as f:
-        tiny = json.load(f)
-    config.update(tiny["config"])
-    traffic.update(tiny["traffic"].get(traffic["kind"], {}))
-
-
 def main() -> int:
     args = parse_args()
     man = mf.Manifest(args.manifest)
@@ -68,7 +61,7 @@ def main() -> int:
     traffic = man.traffic(cell["traffic"])
     seconds = args.seconds or float(man.data["run_seconds"])
     if args.rehearse:
-        apply_rehearsal(config, traffic)
+        mf.apply_rehearsal(config, traffic, man.family(config["family"]))
         os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_"
@@ -103,7 +96,8 @@ def main() -> int:
     work_dir = os.path.join(harness.WORK_DIR, cell["name"])
     shutil.rmtree(work_dir, ignore_errors=True)
     os.makedirs(work_dir)
-    run = harness.Run(cell=cell, config=config, traffic=traffic,
+    run = harness.Run(cell=cell, config=config,
+                      config_kwargs=man.config_kwargs(config), traffic=traffic,
                       seed=args.seed, seconds=seconds,
                       trace_on=bool(args.trace),
                       process_start=PROCESS_START, work_dir=work_dir,
@@ -153,6 +147,12 @@ def main() -> int:
     shutil.rmtree(work_dir, ignore_errors=True)
     out.write(json.dumps(line) + "\n")
     out.flush()
+    # each number compared beside its limit, as the last lines of stderr too:
+    # where a run is not correct, the end of stderr is what the record keeps
+    for what in run.failures:
+        print(f"benchmark: NOT CORRECT: {what}", file=sys.stderr)
+    print(f"benchmark: correct={not run.failures} checks "
+          f"{json.dumps(run.checks)}", file=sys.stderr)
     return 0
 
 
@@ -163,9 +163,12 @@ def write_record(args, run, line: dict) -> None:
         args.out_dir, f"{run.cell['name']}.trace{int(run.trace_on)}."
                       f"seed{run.seed}")
     small = {k: v for k, v in run.records.items()
-             if k not in ("serve_events", "arrivals")}
+             if k != "serve_events"}
     record = {"line": line, "records": small, "program": run.program,
-              "n_latencies": len(run.records.get("latency_s", []))}
+              "n_latencies": len(run.records.get("latency_s", [])),
+              # what of the program this process loaded: set-up pays for it
+              "program_modules": sorted(m for m in sys.modules
+                                        if m.split(".")[0] == "vitax")}
     if run.trace is not None:
         record["category_seconds"] = run.trace.category_seconds()
         record["top_ops_30"] = run.trace.top_ops(30)

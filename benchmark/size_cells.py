@@ -12,7 +12,10 @@ compile only (how the batch of the ViT-L/14 cell and the depth of the
 four-chip cell were chosen; the sizes chosen are in the files and PERF.md).
 
 Each generator lowers its own program for the described devices
-(`lower_described(config, traffic, devices)` in `generators/<kind>.py`).
+(`lower_described(config_kwargs, traffic, devices)` in
+`generators/<kind>.py`), through the program's own constructor. The depth is
+printed under the key the configuration's family names for it
+(`roles.depth` of `shapes/<family>.json`).
 The production Pallas kernels are compiled with real Mosaic lowering
 (`VITAX_FORCE_MOSAIC=1`, `force_tpu_kernels`), as `tools/aot_topology.py`
 does for `chip_smoke.py`'s programs. Keep the persistent compile cache off:
@@ -61,15 +64,16 @@ def main() -> int:
             target = traffic if key in traffic else config
             target[key] = type(target.get(key, 0))(value)
         devices = list(topo.devices)[:cell["chips"]]
+        depth_key = man.family(config["family"])["roles"]["depth"]
         t0 = time.time()
         lowered, what = mf.generator(traffic["kind"]).lower_described(
-            config, traffic, devices)
+            man.config_kwargs(config), traffic, devices)
         compiled = lowered.compile()
         facts = harness.program_facts(compiled)
         print(json.dumps({
             "workload": name, "program": what, "topology": TOPOLOGY,
             "chips": cell["chips"], "overrides": args.set,
-            "num_blocks": config["num_blocks"], **facts,
+            depth_key: config[depth_key], **facts,
             "step_gb": round(facts["step_bytes"] / 1e9, 3),
             "spare_gb": round((hbm - facts["step_bytes"]) / 1e9, 3),
             "compile_s": round(time.time() - t0, 1)}), flush=True)

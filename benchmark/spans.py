@@ -73,6 +73,23 @@ def idle_pct(run, *phases: str) -> Optional[float]:
     return sum(split[p] for p in phases) / (1e7 * run.trace.window_s)
 
 
+def batches_with_the_one_ahead(run) -> List[tuple]:
+    """[(batch, the batch dispatched before it)] over the window's
+    `serve_batch` events that carry the seven marks, paired by `batch_id`;
+    a batch whose predecessor lies outside the window is left out. The one
+    worker thread runs `dispatch(n)` and then `deliver(n - 1)`, so some of
+    what a batch's own marks leave open is closed by its predecessor's."""
+    marked = {e["batch_id"]: e for e in events(run, "serve_batch")
+              if "batch_id" in e and all(m in e for m in MARKS)}
+    return [(e, marked[i - 1]) for i, e in sorted(marked.items())
+            if i - 1 in marked]
+
+
+def median_ms_of(values) -> Optional[float]:
+    value = percentile(sorted(values), 0.5)
+    return None if value is None else 1e3 * value
+
+
 def median_ms(run, kind: str, seconds_of) -> Optional[float]:
     """Median over the window's `kind` events of `seconds_of(event)`, in ms;
     events that lack a field it reads (an older program's) are left out."""
@@ -82,5 +99,4 @@ def median_ms(run, kind: str, seconds_of) -> Optional[float]:
             vals.append(seconds_of(e))
         except KeyError:
             pass
-    value = percentile(sorted(vals), 0.5)
-    return None if value is None else 1e3 * value
+    return median_ms_of(vals)

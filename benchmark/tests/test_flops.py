@@ -1,25 +1,29 @@
-"""The benchmark's copy of the FLOPs arithmetic equals the program's, for
-every configuration the manifest names."""
+"""The benchmark's copies of the FLOPs and parameter arithmetic equal the
+program's, for every cell: each through the module its own generator names
+(`arithmetic`), on the `Config` that generator builds."""
 
 import pytest
 
-from benchmark import flops
 from benchmark import manifest as mf
 
 MANIFEST = mf.Manifest()
-CONFIGS = [c["name"] for c in MANIFEST.data["configs"]]
+CELLS = [w["name"] for w in MANIFEST.data["workloads"]]
 
 
-@pytest.mark.parametrize("name", CONFIGS)
+@pytest.mark.parametrize("name", CELLS)
 def test_flops_equal_the_programs(name):
-    from vitax.config import Config
-    from vitax.models.vit import expected_param_count
-    from vitax.telemetry.flops import model_flops_per_image
-    config = MANIFEST.config(name)
-    cfg = Config(**mf.config_kwargs(config)).validate()
-    assert flops.model_flops_per_image(config) == model_flops_per_image(cfg)
-    assert flops.num_patches(config) == cfg.num_patches
-    assert flops.param_count(config) == expected_param_count(cfg)
+    cell = MANIFEST.cell(name)
+    config = MANIFEST.config(cell["config"])
+    traffic = MANIFEST.traffic(cell["traffic"])
+    gen = mf.generator(traffic["kind"])
+    cfg = gen.build_config(MANIFEST.config_kwargs(config), traffic,
+                           cell["chips"], 0)
+    pairs = gen.arithmetic.against_program(config, traffic, cfg)
+    assert len(pairs) >= 2
+    for what, ours, programs in pairs:
+        assert ours == programs, what
+    if "parameters" in config:      # where the file states them
+        assert gen.arithmetic.param_count(config) == config["parameters"]
 
 
 def test_peaks_table():

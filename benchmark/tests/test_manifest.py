@@ -2,12 +2,10 @@
 unit uses the allowed characters, no file sets a performance knob, and
 `run.py` names no cell, configuration or metric."""
 
-import dataclasses
 import os
 import re
 
-import pytest
-
+from benchmark import forms
 from benchmark import manifest as mf
 
 MANIFEST = mf.Manifest()
@@ -68,12 +66,20 @@ def test_names_units_and_keys():
 
 
 def test_every_name_resolves_to_a_file():
+    from vitax.config import Config
     for w in DATA["workloads"]:
         config = MANIFEST.config(w["config"])
         traffic = MANIFEST.traffic(w["traffic"])
         gen = mf.generator(traffic["kind"])
-        assert all(hasattr(gen, f) for f in ("setup", "window", "finish"))
-        assert mf.config_kwargs(config)["embed_dim"] > 0
+        assert all(hasattr(gen, f) for f in (
+            "setup", "window", "finish", "build_config", "lower_described",
+            "arithmetic"))
+        # whether a configuration builds is its generator's to say: it
+        # hands back a `Config` that passed `validate()`, of any family
+        cfg = gen.build_config(MANIFEST.config_kwargs(config), traffic,
+                               w["chips"], 0)
+        assert isinstance(cfg, Config) and cfg.validate() == cfg
+        assert gen.arithmetic.param_count(config) > 0
     for m in ALL_METRICS:
         assert callable(mf.metric_reader(m["name"]).read), m["name"]
     readers = {f[:-3] for f in os.listdir(os.path.join(mf.BENCH_DIR, "metrics"))
@@ -82,21 +88,39 @@ def test_every_name_resolves_to_a_file():
 
 
 def test_configs_state_their_cut_and_set_no_knob():
+    """Every configuration keeps the rules of form (benchmark/forms.py): a
+    count held here may be reduced, with its source value; a width never;
+    a share of a deployment keeps the floors; only what the family declares
+    reaches `Config`, inside nested blocks too."""
+    import dataclasses
+
     from vitax.config import Config
     fields = {f.name for f in dataclasses.fields(Config)}
-    allowed = set(mf.SHAPE_KEYS + mf.MESH_KEYS)
-    for c in DATA["configs"]:
-        config = MANIFEST.config(c["name"])
-        assert config["reduced"] == c["reduced"]
-        assert config["source"] == c["source"]
-        for key in c["reduced"]:
-            assert key == "num_blocks"          # depth only; never a width
-            assert config[key] < config["source_values"][key]
-        knobs = (set(config) & fields) - allowed
-        assert not knobs, f"{c['name']} sets {knobs}"
+    assert forms.manifest_problems(MANIFEST) == {}
     for w in DATA["workloads"]:
         traffic = MANIFEST.traffic(w["traffic"])
         assert not (set(traffic) & fields), w["traffic"]
+
+
+def test_the_first_family_holds_the_keys_the_tuple_held():
+    """`shapes/vit.json` is the tuple `manifest.py` held until PR 31, key
+    for key; no family declares a performance knob."""
+    vit = MANIFEST.family("vit")
+    assert vit["shape_keys"] == [
+        "image_size", "patch_size", "embed_dim", "num_heads", "num_blocks",
+        "mlp_ratio", "num_classes", "moe_experts", "moe_top_k",
+        "moe_capacity_factor"]
+    assert vit["mesh_keys"] == ["dp_size", "fsdp_size", "tp_size", "sp_size",
+                                "pp_size", "ep_size"]
+    knobs = set(forms.rules()["knobs"]["keys"])
+    assert {"scan_blocks", "remat_policy", "fused_optimizer",
+            "serve_max_batch", "batch_size", "seed"} <= knobs
+    shapes_dir = os.path.join(mf.BENCH_DIR, "shapes")
+    for name in os.listdir(shapes_dir):
+        family = MANIFEST.family(name[:-len(".json")])
+        declared = family["shape_keys"] + family["mesh_keys"] + [
+            k for keys in family.get("nested", {}).values() for k in keys]
+        assert not set(declared) & knobs, name
 
 
 def test_metric_coverage_of_each_cell():

@@ -86,11 +86,42 @@ def test_idle_under_two_timelines_is_counted_in_both_phases():
 
 def test_duration_readers_on_overlapped_batches():
     run = run_of(three_batches(), [(8, 95)])
-    # t_put -> t_deliver of a batch queued behind another holds that wait:
-    # 35, 59, 52
-    assert engine_infer_ms_p50.read(run) == pytest.approx(52.0)
+    # the device has a batch from its call, or from the delivery of the one
+    # ahead if that is later, to its own delivery: batch 1 70 - max(13, 40),
+    # batch 2 96 - max(46, 70); batch 0 has none ahead in the window
+    # (t_put -> t_deliver would read 35, 59, 52: the wait behind the batch
+    # ahead inside it)
+    assert engine_infer_ms_p50.read(run) == pytest.approx(28.0)   # 30, 26
     # t_wait -> t_deliver is the batch's own fetch: 26, 23, 0.5
     assert engine_wait_ms_p50.read(run) == pytest.approx(23.0)
-    # `dispatch` stretches until the worker turns to the batch's answers:
-    # 1 + 7 + 2, 1 + 34 + 2, 1 + 49.5 + 2
-    assert batch_handoff_ms_p50.read(run) == pytest.approx(37.0)
+    # the worker's own Python: stack, the call (it ends where the worker
+    # turns to the batch AHEAD, that batch's t_wait), deliver:
+    # batch 1: 1 + (14 - 13) + 2, batch 2: 1 + (47 - 46) + 2; batch 0 was
+    # not dispatched behind another. (`dispatch` as a phase would read
+    # 1 + 34 + 2 and 1 + 49.5 + 2: a period, not the worker's time)
+    assert batch_handoff_ms_p50.read(run) == pytest.approx(4.0)
+    # a device that idles between two batches: batch 2's call comes after
+    # batch 1 has left it, and counts from the call
+    late = three_batches()
+    late[2].update(batch(2, 72, 73, 74, 76, 95.5, 96, 98), overlapped=1)
+    assert engine_infer_ms_p50.read(run_of(late, [(8, 95)])) \
+        == pytest.approx(25.0)                                    # 30, 20
+
+
+def test_the_two_readers_leave_out_what_the_marks_do_not_close():
+    # no batch ahead inside the window: nothing to read for either
+    alone = with_overlapped([batch(5, 0, 4, 5, 7, 14, 40, 42)], [1])
+    run = run_of(alone, [(8, 40)])
+    assert engine_infer_ms_p50.read(run) is None
+    assert batch_handoff_ms_p50.read(run) is None
+    # batches not dispatched behind one in flight (or from a program whose
+    # events lack the flag): the worker collects the next batch before it
+    # turns to any answer, so no mark ends the call; the device time is
+    # still read
+    for flag in (0, None):
+        events = three_batches()
+        for e in events:
+            e.pop("overlapped")
+        run = run_of(with_overlapped(events, [flag] * 3), [(8, 95)])
+        assert batch_handoff_ms_p50.read(run) is None
+        assert engine_infer_ms_p50.read(run) == pytest.approx(28.0)

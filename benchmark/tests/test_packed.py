@@ -24,8 +24,9 @@ PEAKS = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
 def test_flops_and_parameters_equal_the_programs():
     from vitax.models.vit import expected_param_count
     from vitax.telemetry.flops import packed_flops_per_step
-    config, traffic = train_packed.shapes(CONFIG, TRAFFIC, on_chip=True)
-    cfg = train_packed.build_config(config, traffic, 1, 0)
+    config, traffic = CONFIG, TRAFFIC
+    cfg = train_packed.build_config(MANIFEST.config_kwargs(config), traffic,
+                                    1, 0)
     assert cfg.packed and cfg.mlp_hidden_dim == 4304
     assert (cfg.embed_dim, cfg.num_heads, cfg.num_blocks, cfg.patch_size,
             cfg.pos_grid) == (1152, 16, 27, 14, 64)
@@ -59,19 +60,38 @@ def test_the_layout_is_the_issues():
     assert all(h * w <= limit and h % 2 == 0 and w % 2 == 0
                for row in TRAFFIC["rows"] for h, w in row)
     # the seed never reaches the layout
-    cfg = train_packed.build_config(
-        *train_packed.shapes(CONFIG, TRAFFIC, True), 1, 12345)
+    cfg = train_packed.build_config(MANIFEST.config_kwargs(CONFIG), TRAFFIC,
+                                    1, 12345)
     a = train_packed.layout(cfg, TRAFFIC["rows"], 1)
     assert int((a["segment_ids"] > 0).sum()) == 15284
     assert int(a["label_mask"].sum()) == 9
 
 
-def test_rehearsal_shapes_are_the_traffic_files_own():
-    config, traffic = train_packed.shapes(CONFIG, TRAFFIC, on_chip=False)
+def test_rehearsal_shapes_come_from_the_family_and_the_traffic_file():
+    """`--rehearse` shrinks a configuration by its family's `rehearse` block
+    (the one place a family's tiny shapes are written) and by what the
+    traffic file's block adds for its own kind: traffic keys, configuration
+    keys, a nested block key by key."""
+    import copy
+    import os
+    config, traffic = copy.deepcopy(CONFIG), copy.deepcopy(TRAFFIC)
+    family = MANIFEST.family(config["family"])
+    mf.apply_rehearsal(config, traffic, family)
     assert traffic["row_tokens"] == 128 and traffic["images_per_row"] == 4
+    assert config["embed_dim"] == 64 and config["num_blocks"] == 2
     assert config["native_res"]["pos_grid"] == 8
     assert config["native_res"]["rope_base"] == CONFIG["native_res"]["rope_base"]
-    assert TRAFFIC["row_tokens"] == 8192            # the file's dict untouched
+    kwargs = MANIFEST.config_kwargs(config)
+    assert kwargs["mlp_dim"] == 96 and kwargs["max_image_tokens"] == 64
+    assert "vocab_size" not in kwargs and "hidden_size" not in kwargs
+    # a family's tiny shapes are keys it declares, written once: no traffic
+    # file repeats one, and there is no other source
+    declared = set(family["shape_keys"]) | set(family["nested"])
+    assert set(family["rehearse"]) <= declared
+    for w in MANIFEST.data["workloads"]:
+        own = MANIFEST.traffic(w["traffic"])["rehearse"].get("config", {})
+        assert not set(own) & set(family["rehearse"]), w["traffic"]
+    assert not os.path.exists(os.path.join(mf.BENCH_DIR, "rehearse.json"))
 
 
 class FakeTrace:

@@ -136,9 +136,9 @@ def test_duration_readers_take_the_median_of_their_marks():
     assert engine_put_ms_p50.read(run) == pytest.approx(4.5)       # 4, 5
     assert engine_wait_ms_p50.read(run) == pytest.approx(30.5)     # 31, 30
     assert batch_collect_ms_p50.read(run) == pytest.approx(6.0)    # 10, 2
-    # stack + dispatch + deliver, the phases of serve_idle_handoff_pct:
-    # 2+1+2, 1+2+5
-    assert batch_handoff_ms_p50.read(run) == pytest.approx(6.5)
+    # neither batch was dispatched behind one in flight: the call's end is
+    # not marked (benchmark/tests/test_overlap.py has the overlapped case)
+    assert batch_handoff_ms_p50.read(run) is None
     assert request_decode_ms_p50.read(run) == pytest.approx(5.0)
     assert request_wake_ms_p50.read(run) == pytest.approx(2.0)
     assert request_reply_ms_p50.read(run) == pytest.approx(1.0)
