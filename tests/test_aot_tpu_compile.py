@@ -116,6 +116,35 @@ def test_packed_attention_forward_and_vjp_compile(chip, mosaic):
         assert any(name in k for k in kernels), kernels
 
 
+@pytest.mark.parametrize("heads,window", [(48, 0), (64, 512)],
+                         ids=["full_48q_8kv", "window512_64q_8kv"])
+def test_document_attention_forward_and_vjp_compile(chip, mosaic, heads,
+                                                    window):
+    """The decoder's kernels at the Laguna-XS.2 cell's shapes: one row of
+    8,192 tokens, head dim 128, 6 or 8 query heads a key/value head read
+    inside the kernel, causal and window terms in mask and block table."""
+    from vitax.ops.flash_blocked import document_flash_attention
+    one_chip, _ = chip
+
+    def fwd_bwd(q, k, v, segment_ids):
+        o, vjp = jax.vjp(
+            lambda q, k, v: document_flash_attention(q, k, v, segment_ids,
+                                                     window), q, k, v)
+        return o, vjp(o)
+
+    q = jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(fwd_bwd).lower(q, kv, kv, seg).compile()
+    kernels = _kernel_names(compiled)
+    name = "flash_window" if window else "flash_causal"
+    for part in ("fwd", "dkv", "dq"):
+        assert any(f"{name}_{part}" in k for k in kernels), kernels
+    assert not any("flash_packed" in k for k in kernels), kernels
+
+
 @pytest.mark.parametrize("mode", ["int8", "fp8", "int8_act"])
 @pytest.mark.parametrize("mkn", [
     (8 * 257, 1024, 4096),       # ViT-L/14 fc1 at the largest serve bucket

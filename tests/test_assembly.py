@@ -274,6 +274,40 @@ def test_layering(package):
     assert not bad, "\n".join(bad)
 
 
+def test_the_loops_sharding_aliases_are_gone():
+    """`vitax/train/loop.py` re-exported the two anchors for a harness that
+    assembled by hand (PERF.md section 7 (n)); nothing imports them now."""
+    from vitax.train import loop
+    assert not hasattr(loop, "_token_sharding")
+    assert not hasattr(loop, "_moe_dispatch_sharding")
+    with open(loop.__file__, encoding="utf-8") as f:
+        source = f.read()
+    assert "token_sharding" not in source
+    assert "moe_dispatch_sharding" not in source
+
+
+BENCH = os.path.join(REPO, "benchmark")
+ASSEMBLERS = sorted(
+    os.path.join("generators", name)
+    for name in os.listdir(os.path.join(BENCH, "generators"))
+    if name.startswith(("train_", "serve_")))
+
+
+@pytest.mark.parametrize("path", ASSEMBLERS + ["harness.py"])
+def test_the_benchmark_builds_through_the_one_assembly(path):
+    """The benchmark's generators are callers of `Geometry.assemble` like
+    the loop (section 7 (o)): each builds its program there, and neither they
+    nor the harness write the assembly out by hand."""
+    with open(os.path.join(BENCH, path), encoding="utf-8") as f:
+        source = f.read()
+    for by_hand in ("build_model(", "build_decoder(", "make_train_state(",
+                    "make_attention_impl("):
+        assert by_hand not in source, (path, by_hand)
+    if path != "harness.py":
+        assert "Geometry.assemble(" in source, path
+        assert "vitax.train.loop" not in source, path
+
+
 # --- one knob mechanism: Config defaults --------------------------------------
 
 

@@ -78,7 +78,7 @@ def test_off_traces_identical_program(devices8, arm_kw):
     from vitax.models import build_model
     from vitax.ops.attention import make_attention_impl
     from vitax.parallel.mesh import build_mesh
-    from vitax.train.loop import _token_sharding
+    from vitax.parallel.sharding import token_sharding as _token_sharding
     from vitax.train.state import build_optimizer, make_train_state
     from vitax.train.step import _forward_fn
 

@@ -386,7 +386,9 @@ class TestWorkloadsE2E:
         under stop_gradient."""
         from vitax.programs.registry import get_scenario as scen
         from vitax.ops.attention import make_attention_impl
-        from vitax.train.loop import _moe_dispatch_sharding, _token_sharding
+        from vitax.parallel.sharding import (
+            moe_dispatch_sharding as _moe_dispatch_sharding,
+            token_sharding as _token_sharding)
         npz = str(tmp_path / "teacher.npz")
         export_params_npz(tiny_cfg(), npz, seed=42)
         cfg = tiny_cfg(task="distill", teacher_npz=npz, lr=1e-2,

@@ -43,7 +43,7 @@ def build_train_objects(cfg, max_iteration=100):
     """Build the full sharded training machinery exactly as the training loop
     does (attention impl + token sharding selection included)."""
     from vitax.ops.attention import make_attention_impl
-    from vitax.train.loop import _token_sharding
+    from vitax.parallel.sharding import token_sharding as _token_sharding
     mesh = build_mesh(cfg)
     model = build_model(cfg, attention_impl=make_attention_impl(cfg, mesh),
                         token_sharding=_token_sharding(cfg, mesh))

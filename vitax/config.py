@@ -75,6 +75,41 @@ class Config:
     pos_grid: int = 0                   # side of the learned position table, resized bicubically
     #   to each image's grid (MoonViT: 64)
     rope_base: float = 10000.0          # base of the 2D rotary embedding on q and k
+    # The token decoder family (vitax/models/decoder.py): model_family
+    # "decoder" selects it. A batch is `batch_size` rows of pack_tokens token
+    # ids holding up to pack_images whole DOCUMENTS back to back
+    # (vitax/data/packing.py: document_layout); embed_dim, num_blocks and
+    # the fields below are the model's shape under the program's own names.
+    # One chip may hold a share of a deployment in which several chips share
+    # each layer: `experts_held` of `experts_routed` experts from
+    # `expert_first` on, and `vocab_rows` rows of the vocabulary.
+    model_family: str = "vit"           # "vit" | "decoder"
+    vocab_rows: int = 0                 # rows of the embedding and of the untied head held here
+    kv_heads: int = 0                   # key/value heads, each serving layer_heads[i] / kv_heads query heads
+    head_size: int = 0                  # width of one head (not embed_dim / heads: q is heads * head_size wide)
+    layer_kinds: Tuple[str, ...] = ()   # per layer: "full_attention" | "sliding_attention"
+    layer_heads: Tuple[int, ...] = ()   # per layer: query heads
+    layer_mlps: Tuple[str, ...] = ()    # per layer: "dense" | "sparse"
+    window_tokens: int = 0              # keys a sliding layer's query sees, its own position included
+    ffn_dim: int = 0                    # width of a dense layer's SwiGLU
+    expert_dim: int = 0                 # width of one routed expert's SwiGLU
+    shared_expert_dim: int = 0          # width of the shared expert every token takes (0 = none)
+    experts_routed: int = 0             # experts the router scores (the deployment's count)
+    experts_held: int = 0               # experts this chip holds and computes
+    expert_first: int = 0               # index of the first held expert among the routed ones
+    experts_per_token: int = 0          # experts a token is sent to, chosen over ALL routed ones
+    routed_scale: float = 1.0           # factor on the normalised routed weights
+    head_gate: bool = False             # sigmoid gate on the attention output, one scalar a head
+    norm_eps: float = 1e-6              # RMSNorm epsilon
+    rope_theta_full: float = 10000.0    # full layers: RoPE base ...
+    rope_fraction_full: float = 1.0     # ... and the leading share of a head it rotates
+    yarn_factor: float = 1.0            # full layers: YaRN context extension (1 = plain RoPE) ...
+    yarn_orig_len: int = 0              # ... from this many positions ...
+    yarn_beta_fast: float = 32.0        # ... between these two rotation counts ...
+    yarn_beta_slow: float = 1.0
+    yarn_attn_factor: float = 1.0       # ... with this factor on cos and sin
+    rope_theta_window: float = 10000.0  # sliding layers: plain RoPE base ...
+    rope_fraction_window: float = 1.0   # ... and rotated share
     pos_dropout: float = 0.0
     # NOTE: att_dropout > 0 stays on the fused kernels — every attention path
     # (whole-N, streamed, ring/ulysses sp, and their pipeline bodies at tp=1)
@@ -301,6 +336,16 @@ class Config:
     distill_alpha: float = 0.5          # distill loss mix: (1-alpha)*CE(labels) + alpha*KL(teacher)
     distill_temp: float = 2.0           # distill softmax temperature T (KL term scaled by T^2)
 
+    def __post_init__(self) -> None:
+        # per-layer lists arrive as lists (a JSON file) or comma-separated
+        # strings (a flag); held as tuples, so the dataclass stays hashable
+        for name, kind in (("layer_kinds", str), ("layer_heads", int),
+                           ("layer_mlps", str)):
+            value = getattr(self, name)
+            if isinstance(value, str):
+                value = [v for v in value.split(",") if v]
+            setattr(self, name, tuple(kind(v) for v in value))
+
     @property
     def resolved_param_gather_dtype(self) -> str:
         """Gather-dtype policy after None -> --dtype resolution."""
@@ -313,8 +358,14 @@ class Config:
 
     @property
     def packed(self) -> bool:
-        """The native-resolution packed model (pack_tokens > 0)."""
+        """A model over packed rows (pack_tokens > 0): the native-resolution
+        ViT, or the token decoder."""
         return self.pack_tokens > 0
+
+    @property
+    def decoder(self) -> bool:
+        """The token decoder family (vitax/models/decoder.py)."""
+        return self.model_family == "decoder"
 
     @property
     def num_patches(self) -> int:
@@ -359,6 +410,76 @@ class Config:
         assert self.gather_overlap != "on", (
             "--gather_overlap on is not built for packed rows")
 
+    def _validate_decoder(self) -> None:
+        """Every rule of the token decoder's shape, and a sentence for each
+        arm of the program it is not built for."""
+        n = self.num_blocks
+        assert self.pack_tokens > 0 and self.pack_images >= 1, (
+            "--model_family decoder trains on packed rows: --pack_tokens (a "
+            "row's tokens) and --pack_images (the documents a row can hold) "
+            "must be set")
+        assert (len(self.layer_kinds) == len(self.layer_heads)
+                == len(self.layer_mlps) == n), (
+            f"--layer_kinds, --layer_heads and --layer_mlps need one entry "
+            f"for each of the {n} layers, got {len(self.layer_kinds)}, "
+            f"{len(self.layer_heads)} and {len(self.layer_mlps)}")
+        assert set(self.layer_kinds) <= {"full_attention",
+                                         "sliding_attention"}, self.layer_kinds
+        assert set(self.layer_mlps) <= {"dense", "sparse"}, self.layer_mlps
+        assert self.vocab_rows >= 2 and self.head_size >= 2, (
+            f"--vocab_rows {self.vocab_rows} and --head_size "
+            f"{self.head_size} must be set")
+        assert self.kv_heads >= 1 and all(
+            h >= self.kv_heads and h % self.kv_heads == 0
+            for h in self.layer_heads), (
+            f"every layer's query heads {self.layer_heads} must be a "
+            f"multiple of --kv_heads {self.kv_heads}")
+        for name in ("rope_fraction_full", "rope_fraction_window"):
+            rot = self.head_size * getattr(self, name)
+            assert 0 < rot <= self.head_size and rot == int(rot) \
+                and int(rot) % 2 == 0, (
+                f"--{name} {getattr(self, name)} must rotate an even number "
+                f"of a head's {self.head_size} dimensions")
+        assert self.rope_theta_full > 1 and self.rope_theta_window > 1
+        assert self.yarn_factor >= 1 and (
+            self.yarn_factor == 1 or self.yarn_orig_len > 0), (
+            "--yarn_factor > 1 needs --yarn_orig_len, the positions the "
+            "model was first trained on")
+        if "sliding_attention" in self.layer_kinds:
+            assert self.window_tokens >= 1, (
+                "a sliding_attention layer needs --window_tokens >= 1")
+        if "dense" in self.layer_mlps:
+            assert self.ffn_dim >= 1, "a dense layer needs --ffn_dim"
+        if "sparse" in self.layer_mlps:
+            assert self.expert_dim >= 1, "a sparse layer needs --expert_dim"
+            assert 1 <= self.experts_per_token <= self.experts_routed, (
+                f"--experts_per_token {self.experts_per_token} must be in "
+                f"[1, experts_routed={self.experts_routed}]")
+            assert (self.experts_held >= 1 and self.expert_first >= 0
+                    and self.expert_first + self.experts_held
+                    <= self.experts_routed), (
+                f"the held experts [{self.expert_first}, {self.expert_first} "
+                f"+ {self.experts_held}) must lie within the "
+                f"{self.experts_routed} routed ones")
+        assert self.task == "train", (
+            f"the decoder trains (--task train); --task {self.task} and "
+            f"serving a decoder are not built: the serve path answers images")
+        assert self.tp_size == self.sp_size == self.pp_size == 1, (
+            "the decoder composes with dp/fsdp only: its attention kernels "
+            "have no tensor-, sequence- or pipeline-parallel arm")
+        assert self.ep_size == 1 and self.moe_experts == 0, (
+            "--ep_size > 1 is not built for the decoder: a chip holds its "
+            "share of the experts (--experts_held of --experts_routed) and "
+            "runs without the exchange; --moe_experts is the ViT's Switch MLP")
+        assert self.pos_dropout == self.att_dropout == self.mlp_dropout == 0.0, (
+            "the decoder has no dropout arm")
+        assert self.grad_accum_steps == 1 and self.remat_window <= 1, (
+            "the decoder with --grad_accum_steps / --remat_window is not "
+            "built: rows hold different numbers of targets, so the loss is a "
+            "mean over the batch's targets, not over microbatches")
+        assert self.gather_overlap != "on", (
+            "--gather_overlap on is not built for the decoder")
+
     def validate(self) -> "Config":
         assert self.image_size % self.patch_size == 0, (
             f"image_size {self.image_size} not divisible by patch_size {self.patch_size}")
@@ -367,7 +488,12 @@ class Config:
         assert self.mlp_dim >= 0 and self.pack_tokens >= 0, (
             f"--mlp_dim {self.mlp_dim} and --pack_tokens {self.pack_tokens} "
             f"must be >= 0 (0 = mlp_ratio / the fixed-size model)")
-        if self.packed:
+        assert self.model_family in ("vit", "decoder"), (
+            f"unknown --model_family {self.model_family!r} (expected 'vit' or "
+            f"'decoder')")
+        if self.decoder:
+            self._validate_decoder()
+        elif self.packed:
             self._validate_packed()
         assert self.sp_impl in ("ring", "ulysses"), (
             f"unknown sp_impl {self.sp_impl!r} (expected 'ring' or 'ulysses')")
@@ -731,6 +857,40 @@ def build_parser() -> argparse.ArgumentParser:
                              "model resizes to each image's grid")
     parser.add_argument("--rope_base", type=float, default=10000.0,
                         help="base of the packed model's 2D RoPE")
+    dec = parser.add_argument_group(
+        "decoder", "the token decoder family (vitax/models/decoder.py); "
+        "per-layer lists are comma-separated")
+    dec.add_argument("--model_family", type=str, default="vit",
+                     choices=("vit", "decoder"))
+    for name, kind, default, text in (
+            ("vocab_rows", int, 0, "vocabulary rows held here"),
+            ("kv_heads", int, 0, "key/value heads"),
+            ("head_size", int, 0, "width of one head"),
+            ("layer_kinds", str, "", "full_attention|sliding_attention a layer"),
+            ("layer_heads", str, "", "query heads a layer"),
+            ("layer_mlps", str, "", "dense|sparse a layer"),
+            ("window_tokens", int, 0, "keys a sliding layer's query sees"),
+            ("ffn_dim", int, 0, "dense SwiGLU width"),
+            ("expert_dim", int, 0, "routed expert SwiGLU width"),
+            ("shared_expert_dim", int, 0, "shared expert SwiGLU width"),
+            ("experts_routed", int, 0, "experts the router scores"),
+            ("experts_held", int, 0, "experts this chip holds"),
+            ("expert_first", int, 0, "first held expert's index"),
+            ("experts_per_token", int, 0, "experts a token is sent to"),
+            ("routed_scale", float, 1.0, "factor on the routed weights"),
+            ("norm_eps", float, 1e-6, "RMSNorm epsilon"),
+            ("rope_theta_full", float, 10000.0, "full layers' RoPE base"),
+            ("rope_fraction_full", float, 1.0, "rotated share of a head"),
+            ("yarn_factor", float, 1.0, "YaRN factor (1 = plain RoPE)"),
+            ("yarn_orig_len", int, 0, "YaRN original positions"),
+            ("yarn_beta_fast", float, 32.0, "YaRN fast rotation count"),
+            ("yarn_beta_slow", float, 1.0, "YaRN slow rotation count"),
+            ("yarn_attn_factor", float, 1.0, "YaRN factor on cos and sin"),
+            ("rope_theta_window", float, 10000.0, "sliding layers' RoPE base"),
+            ("rope_fraction_window", float, 1.0, "rotated share of a head")):
+        dec.add_argument(f"--{name}", type=kind, default=default, help=text)
+    dec.add_argument("--head_gate", action="store_true", dest="head_gate",
+                     help="sigmoid gate on the attention output, a head")
     parser.add_argument("--pos_dropout", type=float, default=0.0)
     parser.add_argument("--att_dropout", type=float, default=0.0)
     parser.add_argument("--mlp_dropout", type=float, default=0.0)

@@ -94,3 +94,38 @@ class FakePackedLoader:
         return (f"FakePackedLoader(rows={c.batch_size}, row_tokens="
                 f"{c.pack_tokens}, images_per_row={c.pack_images}, "
                 f"steps_per_epoch={self.steps_per_epoch})")
+
+
+class FakeDocumentLoader(FakePackedLoader):
+    """Packed batches of random token documents for `--fake_data
+    --model_family decoder`: the same loader surface. Each step draws
+    document lengths (a long one with probability 1/4, uniform up to the row;
+    else uniform up to an eighth of it) and ids uniform over the vocabulary
+    rows held, from (seed, epoch, step, process), until a document fits no
+    row, and first-fit packs them."""
+
+    def draw(self, epoch: int, step: int):
+        from vitax.data.packing import first_fit, pack_documents
+        cfg = self.cfg
+        rng = np.random.default_rng(np.random.SeedSequence(
+            [cfg.seed, epoch, step, self.process_index]))
+        shape = dict(rows=self.local_rows, row_tokens=cfg.pack_tokens,
+                     docs_per_row=cfg.pack_images)
+        lengths = []
+        while True:
+            top = cfg.pack_tokens if rng.random() < 0.25 \
+                else max(cfg.pack_tokens // 8, 2)
+            n = int(rng.integers(2, top + 1))
+            if first_fit([(m, 1) for m in lengths + [n]], shape["rows"],
+                         cfg.pack_tokens, cfg.pack_images)[1]:
+                break
+            lengths.append(n)
+        docs = [rng.integers(0, cfg.vocab_rows, n, dtype=np.int32)
+                for n in lengths]
+        return pack_documents(docs, **shape)
+
+    def __repr__(self) -> str:
+        c = self.cfg
+        return (f"FakeDocumentLoader(rows={c.batch_size}, row_tokens="
+                f"{c.pack_tokens}, docs_per_row={c.pack_images}, "
+                f"steps_per_epoch={self.steps_per_epoch})")

@@ -234,6 +234,15 @@ def build_datasets(cfg: Config, mesh: Mesh):
         from vitax.data.stream import build_stream_datasets
         return build_stream_datasets(cfg, mesh)
 
+    if cfg.decoder:
+        assert cfg.fake_data, (
+            "--model_family decoder trains on --fake_data documents only: no "
+            "text loader is built")
+        from vitax.data.fake import FakeDocumentLoader
+        train_loader = FakeDocumentLoader(cfg, mesh, TRAIN_SPLIT_LEN)
+        val_loader = FakeDocumentLoader(cfg, mesh, VAL_SPLIT_LEN)
+        return train_loader, train_loader, val_loader, val_loader
+
     if cfg.packed:
         # packed native-resolution batches: fake data only so far (packing in
         # the ImageFolder and stream loaders is a later issue, PERF.md s.7)

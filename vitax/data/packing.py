@@ -11,6 +11,13 @@ The arrays (all static shapes; the model's input, vitax/models/vit.py):
   label        int32 [R, S]
   label_mask   float32 [R, S]       1 where an image exists
 
+A packed batch of token DOCUMENTS (the decoder family, vitax/models/
+decoder.py) is the same in one dimension: `document_layout` / `pack_documents`
+
+  tokens       int32 [R, T]         token ids; 0 at padding
+  segment_ids  int32 [R, T]         0 = padding, 1.. = the document in its row
+  positions    int32 [R, T]         the token's position in its own document
+
 NumPy only: this runs in a loader's host thread.
 """
 
@@ -103,3 +110,44 @@ def pack_batch(grids: Sequence[Grid], labels: Sequence[int],
     return batch, {"tokens": tokens,
                    "padding_tokens": rows * row_tokens - tokens,
                    "images": sum(len(row) for row in placed), "left": left}
+
+
+def document_layout(rows_of_lengths: Sequence[Sequence[int]], row_tokens: int,
+                    docs_per_row: int) -> Dict[str, np.ndarray]:
+    """segment_ids and positions for rows whose documents (their lengths, in
+    packing order) are already chosen. No document is ever split."""
+    r = len(rows_of_lengths)
+    seg = np.zeros((r, row_tokens), np.int32)
+    pos = np.zeros((r, row_tokens), np.int32)
+    for i, lengths in enumerate(rows_of_lengths):
+        assert len(lengths) <= docs_per_row, (len(lengths), docs_per_row)
+        at = 0
+        for s, n in enumerate(lengths):
+            assert n >= 1 and at + n <= row_tokens, (
+                f"row {i}: {at + n} tokens exceed the row's {row_tokens}")
+            seg[i, at:at + n] = s + 1
+            pos[i, at:at + n] = np.arange(n)
+            at += n
+    return {"segment_ids": seg, "positions": pos}
+
+
+def pack_documents(docs: Sequence[np.ndarray], *, rows: int, row_tokens: int,
+                   docs_per_row: int
+                   ) -> Tuple[Dict[str, np.ndarray], Dict[str, object]]:
+    """First-fit pack documents (arrays of token ids) into a batch. Returns
+    (the arrays above, counts: `tokens` valid, `padding_tokens`, `documents`
+    placed, `left` unplaced)."""
+    placed, left = first_fit([(len(d), 1) for d in docs], rows, row_tokens,
+                             docs_per_row)
+    batch = document_layout([[len(docs[i]) for i in row] for row in placed],
+                            row_tokens, docs_per_row)
+    batch["tokens"] = np.zeros((rows, row_tokens), np.int32)
+    for r, row in enumerate(placed):
+        at = 0
+        for i in row:
+            batch["tokens"][r, at:at + len(docs[i])] = docs[i]
+            at += len(docs[i])
+    tokens = int((batch["segment_ids"] > 0).sum())
+    return batch, {"tokens": tokens,
+                   "padding_tokens": rows * row_tokens - tokens,
+                   "documents": sum(len(row) for row in placed), "left": left}

@@ -1,0 +1,442 @@
+"""A packed token decoder in Flax: layers of unequal shapes in one stack.
+
+The second model family (`Config.model_family == "decoder"`), built by
+`build_decoder` through the same door as the ViT (vitax/programs/builder.py:
+`build_model_for`). Its input is a packed batch of token documents
+(vitax/data/packing.py: `document_layout`): `tokens`, `segment_ids` and
+`positions`, all (R, T) int32; a row holds whole documents back to back, 0 in
+`segment_ids` is padding and `positions` counts from 0 inside each document.
+
+    h = embedding[tokens]
+    each layer:  h += W_o[ g * Attn(RMSNorm(h)) ];  h += F(RMSNorm(h))
+    logits = RMSNorm(h) @ head            (untied; float32 logits)
+
+- Attn: `layer_heads[i]` query heads and `kv_heads` key/value heads of
+  `head_size`, each key/value head serving heads / kv_heads query heads; RoPE
+  in the rotate-half convention on the leading `rope_fraction_*` of a head, by
+  layer kind: plain on sliding layers, YaRN-scaled (`yarn_*`) on full ones;
+  scores in float32; a key is visible when it is not after the query, in the
+  same document and, in a `sliding_attention` layer, fewer than
+  `window_tokens` positions back. `g = sigmoid(W_g x)`, one scalar a head
+  (`head_gate`). No biases, no q/k normalisation.
+- F: a SwiGLU of `ffn_dim` in a `dense` layer, the routed and shared experts
+  of vitax/models/experts.py in a `sparse` one.
+
+Layers differ in shape (heads by kind, dense or sparse), so one stacked
+`lax.scan` cannot hold them: consecutive layers of one shape form a RUN, each
+run is one `nn.scan` over its stacked parameters with per-block remat inside
+(as in vitax/models/vit.py), and the runs follow one another. The attention
+core comes from `make_attention_impl` (vitax/ops/attention.py) as
+`impl(q, k, v, segment_ids, window)`; None selects the dense masked path
+below.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Callable, List, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from vitax.config import Config
+from vitax.models.experts import SharedRoutedExperts, SwiGLU, Table
+from vitax.models.vit import Array, Dtype, default_init
+
+SLIDING = "sliding_attention"
+
+
+# --- rotary position embedding (pure functions) -----------------------------
+
+def rope_inv_freq(rot: int, theta: float) -> np.ndarray:
+    """The rot / 2 inverse frequencies theta^(-2i / rot) of plain RoPE."""
+    return 1.0 / theta ** (np.arange(0, rot, 2, dtype=np.float64) / rot)
+
+
+def yarn_inv_freq(rot: int, theta: float, factor: float, orig_len: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's frequencies (arXiv:2309.00071, as the published
+    `_compute_yarn_parameters` blends them): a frequency that turns more than
+    `beta_fast` times over the original context is kept, one that turns
+    fewer than `beta_slow` times is divided by `factor`, and between the two
+    (the dimensions where the turn counts fall, rounded outwards) the blend is
+    linear in the dimension's index."""
+    def dim_of(turns):
+        return rot * math.log(orig_len / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), rot - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(rot // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    plain = rope_inv_freq(rot, theta)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def rope_tables(positions: Array, inv_freq: np.ndarray,
+                attn_factor: float = 1.0) -> Tuple[Array, Array]:
+    """cos and sin (R, T, 1, rot / 2), float32, times `attn_factor`."""
+    angle = positions.astype(jnp.float32)[..., None, None] \
+        * jnp.asarray(inv_freq, jnp.float32)
+    return jnp.cos(angle) * attn_factor, jnp.sin(angle) * attn_factor
+
+
+def apply_rope(x: Array, cos: Array, sin: Array) -> Array:
+    """Rotate-half RoPE on the leading 2 * cos.shape[-1] dimensions of each
+    head of x (R, T, H, Dh); the rest passes through."""
+    half = cos.shape[-1]
+    x1, x2, rest = (x[..., :half].astype(jnp.float32),
+                    x[..., half:2 * half].astype(jnp.float32),
+                    x[..., 2 * half:])
+    return jnp.concatenate(
+        [(x1 * cos - x2 * sin).astype(x.dtype),
+         (x2 * cos + x1 * sin).astype(x.dtype), rest], axis=-1)
+
+
+def causal_masked_attention(q: Array, k: Array, v: Array, segment_ids: Array,
+                            window: int, dtype: Dtype) -> Array:
+    """The dense fallback: q (R, T, H, Dh), k and v (R, T, KV, Dh), each
+    key/value head serving H / KV query heads; a key is visible from a query
+    of its own document, not before it and (window > 0) fewer than `window`
+    positions back. Padding comes back zero."""
+    r, t, h, dh = q.shape
+    kv = k.shape[2]
+    qg = q.reshape(r, t, kv, h // kv, dh)
+    s = jnp.einsum("rtkgd,rskd->rkgts", qg, k,
+                   preferred_element_type=jnp.float32) * dh ** -0.5
+    at = jnp.arange(t)
+    back = at[:, None] - at[None, :]                       # query - key
+    seg = segment_ids
+    see = ((seg[:, :, None] == seg[:, None, :]) & (seg[:, :, None] > 0)
+           & (back >= 0)[None])
+    if window > 0:
+        see = see & (back < window)[None]
+    see = see[:, None, None]
+    p = jax.nn.softmax(jnp.where(see, s, -1e30), axis=-1)
+    p = jnp.where(see, p, 0.0).astype(dtype)
+    return jnp.einsum("rkgts,rskd->rtkgd", p, v).reshape(r, t, h, dh)
+
+
+def layer_runs(kinds, heads, mlps) -> List[Tuple[Tuple[str, int, str], int]]:
+    """[(shape, length)]: consecutive layers of one (kind, heads, mlp), the
+    units the stack is scanned by (`run<i>` in the parameter tree)."""
+    out: List[List] = []
+    for shape in zip(kinds, heads, mlps):
+        if out and out[-1][0] == shape:
+            out[-1][1] += 1
+        else:
+            out.append([shape, 1])
+    return [(shape, n) for shape, n in out]
+
+
+# --- modules ----------------------------------------------------------------
+
+class RMSNorm(nn.Module):
+    """x / sqrt(mean(x^2) + eps) * scale, normalised in float32."""
+
+    eps: float
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: Array) -> Array:
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
+        return (y * scale).astype(self.dtype)
+
+
+def _linear(features: int, dtype: Dtype, name: str) -> nn.Dense:
+    return nn.Dense(features, use_bias=False, dtype=dtype,
+                    param_dtype=jnp.float32, kernel_init=default_init,
+                    name=name)
+
+
+class DecoderAttention(nn.Module):
+    heads: int
+    kv_heads: int
+    head_size: int
+    window: int                     # 0 = every earlier key of the document
+    head_gate: bool
+    dtype: Dtype = jnp.bfloat16
+    attention_impl: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x: Array, segment_ids: Array,
+                 rope: Tuple[Array, Array]) -> Array:
+        r, t, d = x.shape
+        h, kv, dh = self.heads, self.kv_heads, self.head_size
+        q = _linear(h * dh, self.dtype, "wq")(x).reshape(r, t, h, dh)
+        k = _linear(kv * dh, self.dtype, "wk")(x).reshape(r, t, kv, dh)
+        v = _linear(kv * dh, self.dtype, "wv")(x).reshape(r, t, kv, dh)
+        with jax.named_scope("rope1d"):
+            q, k = apply_rope(q, *rope), apply_rope(k, *rope)
+        if self.attention_impl is None:
+            out = causal_masked_attention(q, k, v, segment_ids, self.window,
+                                          self.dtype)
+        else:
+            out = self.attention_impl(q, k, v, segment_ids, self.window)
+        if self.head_gate:
+            with jax.named_scope("head_gate"):
+                gate = jax.nn.sigmoid(_linear(h, self.dtype, "head_gate")(
+                    x).astype(jnp.float32))
+                out = (out * gate[..., None]).astype(self.dtype)
+        return _linear(d, self.dtype, "wo")(out.reshape(r, t, h * dh))
+
+
+class DecoderBlock(nn.Module):
+    """One layer; `shape` = (kind, heads, mlp) is what a run's layers share."""
+
+    shape: Tuple[str, int, str]
+    kv_heads: int
+    head_size: int
+    window_tokens: int
+    head_gate: bool
+    norm_eps: float
+    ffn_dim: int
+    expert_dim: int
+    shared_expert_dim: int
+    experts_routed: int
+    experts_held: int
+    expert_first: int
+    experts_per_token: int
+    routed_scale: float
+    dtype: Dtype = jnp.bfloat16
+    attention_impl: Optional[Callable] = None
+    token_sharding: Optional[Any] = None
+
+    @nn.compact
+    def __call__(self, x: Array, segment_ids: Array, rope_full, rope_window):
+        kind, heads, mlp = self.shape
+        sliding = kind == SLIDING
+        if self.token_sharding is not None:
+            x = jax.lax.with_sharding_constraint(x, self.token_sharding)
+        y = RMSNorm(self.norm_eps, self.dtype, name="norm1")(x)
+        y = DecoderAttention(
+            heads=heads, kv_heads=self.kv_heads, head_size=self.head_size,
+            window=self.window_tokens if sliding else 0,
+            head_gate=self.head_gate, dtype=self.dtype,
+            attention_impl=self.attention_impl, name="attn",
+        )(y, segment_ids, rope_window if sliding else rope_full)
+        x = x + y
+        y = RMSNorm(self.norm_eps, self.dtype, name="norm2")(x)
+        if mlp == "dense":
+            y = SwiGLU(self.ffn_dim, x.shape[-1], dtype=self.dtype,
+                       name="mlp")(y)
+        else:
+            y = SharedRoutedExperts(
+                experts_routed=self.experts_routed,
+                experts_held=self.experts_held,
+                expert_first=self.expert_first,
+                experts_per_token=self.experts_per_token,
+                expert_dim=self.expert_dim, shared_dim=self.shared_expert_dim,
+                routed_scale=self.routed_scale, dtype=self.dtype, name="moe",
+            )(y, segment_ids > 0)
+        return x + y
+
+
+class Run(nn.Module):
+    """Consecutive layers of one shape: scanned over stacked parameters
+    (`blocks`, leading axis = the run's length) or unrolled, with per-block
+    remat inside, as `VisionTransformer` runs its blocks."""
+
+    length: int
+    block_kwargs: Any               # a tuple of (name, value) pairs: hashable
+    scan_blocks: bool
+    scan_unroll: int
+    remat: bool
+    policy: Any
+
+    @nn.compact
+    def __call__(self, x: Array, *ctx) -> Array:
+        kwargs = dict(self.block_kwargs)
+
+        def body(block: DecoderBlock, carry: Array, *ctx):
+            return block(carry, *ctx), None
+
+        if self.remat:
+            body = nn.remat(body, policy=self.policy, prevent_cse=False)
+        if self.scan_blocks:
+            scan = nn.scan(
+                body,
+                variable_axes={"params": 0, "intermediates": 0},
+                split_rngs={"params": True},
+                length=self.length,
+                in_axes=(nn.broadcast,) * len(ctx),
+                metadata_params={nn.meta.PARTITION_NAME: "layers"},
+                unroll=min(self.scan_unroll, self.length))
+            x, _ = scan(DecoderBlock(name="blocks", **kwargs), x, *ctx)
+        else:
+            for i in range(self.length):
+                x, _ = body(DecoderBlock(name=f"blocks_{i}", **kwargs), x,
+                            *ctx)
+        return x
+
+
+class Decoder(nn.Module):
+    embed_dim: int
+    vocab_rows: int
+    layer_kinds: Tuple[str, ...]
+    layer_heads: Tuple[int, ...]
+    layer_mlps: Tuple[str, ...]
+    kv_heads: int
+    head_size: int
+    window_tokens: int
+    head_gate: bool
+    norm_eps: float
+    ffn_dim: int
+    expert_dim: int
+    shared_expert_dim: int
+    experts_routed: int
+    experts_held: int
+    expert_first: int
+    experts_per_token: int
+    routed_scale: float
+    rope_full: Tuple[float, ...]    # theta, fraction, yarn factor, original
+    #   length, beta fast, beta slow, factor on cos and sin
+    rope_window: Tuple[float, ...]  # theta, fraction
+    pack_tokens: int
+    dtype: Dtype = jnp.bfloat16
+    scan_blocks: bool = True
+    scan_unroll: int = 1
+    grad_ckpt: bool = True
+    remat_policy: str = "none_saveable"
+    attention_impl: Optional[Callable] = None
+    token_sharding: Optional[Any] = None
+
+    def runs(self) -> List[Tuple[Tuple[str, int, str], int]]:
+        return layer_runs(self.layer_kinds, self.layer_heads, self.layer_mlps)
+
+    def span(self, kind: str) -> int:
+        """The keys one query of a layer of `kind` can meet."""
+        return (min(self.window_tokens, self.pack_tokens) if kind == SLIDING
+                else self.pack_tokens)
+
+    def _rope(self, positions: Array):
+        theta, frac, factor, orig, fast, slow, attn = self.rope_full
+        rot = int(self.head_size * frac)
+        full = (rope_inv_freq(rot, theta) if factor == 1.0 else
+                yarn_inv_freq(rot, theta, factor, int(orig), fast, slow))
+        theta_w, frac_w = self.rope_window
+        return (rope_tables(positions, full, attn),
+                rope_tables(positions, rope_inv_freq(
+                    int(self.head_size * frac_w), theta_w)))
+
+    @nn.compact
+    def __call__(self, batch, deterministic: bool = True) -> Array:
+        """A packed batch's `tokens`, `segment_ids`, `positions` (R, T) ->
+        next-token logits (R, T, vocab_rows) float32."""
+        del deterministic            # no dropout arm (Config.validate)
+        seg = batch["segment_ids"]
+        table = Table((self.vocab_rows, self.embed_dim), "embedding",
+                      name="embed")()
+        x = jnp.take(table.astype(self.dtype), batch["tokens"], axis=0)
+        # a padding token carries nothing (and never meets a real one)
+        x = jnp.where((seg > 0)[..., None], x, jnp.zeros((), self.dtype))
+        if self.token_sharding is not None:
+            x = jax.lax.with_sharding_constraint(x, self.token_sharding)
+        with jax.named_scope("rope1d"):
+            rope_full, rope_window = self._rope(batch["positions"])
+
+        block_kwargs = dict(
+            kv_heads=self.kv_heads, head_size=self.head_size,
+            window_tokens=self.window_tokens, head_gate=self.head_gate,
+            norm_eps=self.norm_eps, ffn_dim=self.ffn_dim,
+            expert_dim=self.expert_dim,
+            shared_expert_dim=self.shared_expert_dim,
+            experts_routed=self.experts_routed,
+            experts_held=self.experts_held, expert_first=self.expert_first,
+            experts_per_token=self.experts_per_token,
+            routed_scale=self.routed_scale, dtype=self.dtype,
+            attention_impl=self.attention_impl,
+            token_sharding=self.token_sharding)
+        for i, (shape, length) in enumerate(self.runs()):
+            x = Run(length=length,
+                    block_kwargs=tuple({**block_kwargs,
+                                        "shape": shape}.items()),
+                    scan_blocks=self.scan_blocks,
+                    scan_unroll=self.scan_unroll, remat=self.grad_ckpt,
+                    policy=run_remat_policy(self, shape[0]),
+                    name=f"run{i}")(x, seg, rope_full, rope_window)
+
+        x = RMSNorm(self.norm_eps, self.dtype, name="norm")(x)
+        with jax.named_scope("lm_head_loss"):
+            head = Table((self.embed_dim, self.vocab_rows), name="lm_head")()
+            return jnp.einsum("rtd,dv->rtv", x, head.astype(self.dtype),
+                              preferred_element_type=jnp.float32)
+
+
+# --- what per-block remat keeps of the attention kernels --------------------
+
+def _decoder_attention_saveable(prim, *_, **params):
+    """`none_saveable` + the attention forward kernel's own outputs (o and
+    lse), as vitax/models/vit.py keeps them; by the kernels' names, so that a
+    block that gains another `pallas_call` does not keep that one's too."""
+    name = getattr(params.get("name_and_src_info"), "name", "") or ""
+    return getattr(prim, "name", "") == "pallas_call" and name.startswith(
+        "flash_")
+
+
+def keeps_attention_residuals(model: Decoder, kind: str) -> bool:
+    """PR 30's rule (vitax/models/vit.py: keeps_attention_residuals) by the
+    span of a run's layers: a full layer's query meets a whole row, a sliding
+    layer's at most `window_tokens` keys."""
+    from vitax.models.vit import keeps_attention_residuals as rule
+    return rule(model, span=model.span(kind))
+
+
+def run_remat_policy(model: Decoder, kind: str):
+    from vitax.models.vit import _REMAT_POLICIES
+    if keeps_attention_residuals(model, kind):
+        return _decoder_attention_saveable
+    return _REMAT_POLICIES[model.remat_policy]
+
+
+def build_decoder(cfg: Config, attention_impl: Optional[Callable] = None,
+                  token_sharding=None) -> Decoder:
+    return Decoder(
+        embed_dim=cfg.embed_dim, vocab_rows=cfg.vocab_rows,
+        layer_kinds=cfg.layer_kinds, layer_heads=cfg.layer_heads,
+        layer_mlps=cfg.layer_mlps, kv_heads=cfg.kv_heads,
+        head_size=cfg.head_size, window_tokens=cfg.window_tokens,
+        head_gate=cfg.head_gate, norm_eps=cfg.norm_eps, ffn_dim=cfg.ffn_dim,
+        expert_dim=cfg.expert_dim, shared_expert_dim=cfg.shared_expert_dim,
+        experts_routed=cfg.experts_routed, experts_held=cfg.experts_held,
+        expert_first=cfg.expert_first,
+        experts_per_token=cfg.experts_per_token,
+        routed_scale=cfg.routed_scale,
+        rope_full=(cfg.rope_theta_full, cfg.rope_fraction_full,
+                   cfg.yarn_factor, cfg.yarn_orig_len, cfg.yarn_beta_fast,
+                   cfg.yarn_beta_slow, cfg.yarn_attn_factor),
+        rope_window=(cfg.rope_theta_window, cfg.rope_fraction_window),
+        pack_tokens=cfg.pack_tokens,
+        dtype=jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32,
+        scan_blocks=cfg.scan_blocks, scan_unroll=cfg.scan_unroll,
+        grad_ckpt=cfg.grad_ckpt, remat_policy=cfg.remat_policy,
+        attention_impl=attention_impl, token_sharding=token_sharding)
+
+
+def sample_documents(cfg: Config, batch: int):
+    """Zeros shaped like the decoder's input, for `model.init`."""
+    zeros = jnp.zeros((batch, cfg.pack_tokens), jnp.int32)
+    return {"tokens": zeros, "segment_ids": zeros, "positions": zeros}
+
+
+def expected_param_count(cfg: Config) -> int:
+    """Closed-form parameter count of what this chip holds."""
+    d, dh = cfg.embed_dim, cfg.head_size
+    total = 2 * cfg.vocab_rows * d + d           # embedding, head, final norm
+    for heads, mlp in zip(cfg.layer_heads, cfg.layer_mlps):
+        total += 2 * d + 2 * d * heads * dh + 2 * d * cfg.kv_heads * dh
+        total += d * heads if cfg.head_gate else 0
+        if mlp == "dense":
+            total += 3 * d * cfg.ffn_dim
+        else:
+            total += (d * cfg.experts_routed
+                      + 3 * d * cfg.expert_dim * cfg.experts_held
+                      + 3 * d * cfg.shared_expert_dim)
+    return total

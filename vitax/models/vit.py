@@ -1082,17 +1082,19 @@ def attention_span(model: VisionTransformer) -> int:
     return model.pack_tokens or (model.image_size // model.patch_size) ** 2
 
 
-def keeps_attention_residuals(model: VisionTransformer) -> bool:
+def keeps_attention_residuals(model, span: Optional[int] = None) -> bool:
     """Whether `VisionTransformer.__call__`'s per-block remat keeps the
     attention kernel's o and lse: only where `none_saveable` would run the
     kernel twice and the span makes that the dearer choice. Not under
     sequence parallelism: ring attention runs sp block products a layer and
-    would keep every one of them."""
+    would keep every one of them. `span`: the keys a query of THESE layers
+    can meet, where a model's layers differ (vitax/models/decoder.py)."""
     ts = model.token_sharding
+    span = attention_span(model) if span is None else span
     return (model.grad_ckpt and model.remat_policy == "none_saveable"
             and model.attention_impl is not None
             and (ts is None or ts.spec[1] is None)
-            and attention_span(model) >= ATTN_KEEP_MIN_SPAN)
+            and span >= ATTN_KEEP_MIN_SPAN)
 
 
 def block_remat_policy(model: VisionTransformer):

@@ -998,6 +998,35 @@ def _packed_impl(cfg, mesh: Optional[Mesh], force: bool):
     return impl
 
 
+def _decoder_impl(cfg, mesh: Optional[Mesh], force: bool):
+    """The token decoder's core, `impl(q, k, v, segment_ids, window)` with a
+    static `window` (0 = a full layer): the packed kernels with their causal,
+    window and grouped-KV terms (vitax/ops/flash_blocked.py:
+    document_flash_attention), chosen and wrapped as `_packed_impl` does.
+    None -> the model's dense masked path."""
+    if not cfg.use_flash_attention or not (
+            force or backend_platform() == "tpu"):
+        return None
+    from vitax.ops.flash_blocked import document_flash_attention
+
+    name = "pallas streaming, causal / window, grouped KV (packed documents)"
+    sharded = mesh is not None and mesh.size > 1
+    if sharded:
+        name += " + shard_map"
+
+    def impl(q, k, v, segment_ids, window):
+        kernel = functools.partial(document_flash_attention, window=window)
+        if sharded:
+            spec = P(BATCH_AXES, None, None, None)
+            kernel = shard_map(
+                kernel, mesh=mesh,
+                in_specs=(spec, spec, spec, P(BATCH_AXES, None)),
+                out_specs=spec, check_vma=False)
+        return kernel(q, k, v, segment_ids)
+    impl.vitax_name = name
+    return impl
+
+
 def make_attention_impl(cfg, mesh: Optional[Mesh] = None,
                         force_tpu_kernels: bool = False):
     """Choose the attention core for this config/mesh:
@@ -1021,6 +1050,8 @@ def make_attention_impl(cfg, mesh: Optional[Mesh] = None,
     pipeline body. The sole dense-under-dropout surface is pp-under-tp
     (structural — warned below).
     """
+    if getattr(cfg, "decoder", False):
+        return _decoder_impl(cfg, mesh, force_tpu_kernels)
     if getattr(cfg, "packed", False):
         return _packed_impl(cfg, mesh, force_tpu_kernels)
     n = cfg.num_patches

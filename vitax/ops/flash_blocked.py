@@ -471,7 +471,8 @@ PACKED_HEADS_PER_STEP = 8
 PACKED_VMEM_LIMIT = 64 * 1024 * 1024  # of the v5e's 128 MiB
 
 
-def packed_block_tables(segment_ids, bq: int, bk: int, skip: bool = True):
+def packed_block_tables(segment_ids, bq: int, bk: int, skip: bool = True,
+                        causal: bool = False, window: int = 0):
     """What the kernels' grids read as scalar prefetch, from a row's
     `segment_ids` (R, T), T a multiple of both blocks. A (q-block, k-block)
     pair is live when the blocks' ranges of non-zero segment ids meet. For
@@ -479,7 +480,10 @@ def packed_block_tables(segment_ids, bq: int, bk: int, skip: bool = True):
     k block to hold at each step (the latest live one, so a dead step
     fetches nothing); for the grid that streams q blocks (dK/dV): `live_kq`
     and `qidx`. All flat int32. `skip=False` calls every pair live (the
-    tests' and the measurements' comparison arm)."""
+    tests' and the measurements' comparison arm). `causal`: a pair whose
+    every key lies after its every query is dead too, and with `window` > 0
+    one whose every key lies `window` or more positions back (a document is
+    contiguous in its row, so positions in the row serve)."""
     r, t = segment_ids.shape
     nq, nk = t // bq, t // bk
     big = jnp.iinfo(jnp.int32).max
@@ -492,6 +496,13 @@ def packed_block_tables(segment_ids, bq: int, bk: int, skip: bool = True):
     klo, khi = ranges(bk)
     live = ((qlo[:, :, None] <= khi[:, None, :])
             & (klo[:, None, :] <= qhi[:, :, None]))          # (R, nq, nk)
+    if causal:
+        q0 = jnp.arange(nq, dtype=jnp.int32)[:, None] * bq   # a block's first
+        k0 = jnp.arange(nk, dtype=jnp.int32)[None, :] * bk
+        near = k0 <= q0 + bq - 1
+        if window > 0:
+            near = near & (k0 + bk - 1 > q0 - window)
+        live = live & near[None]
     if not skip:
         live = jnp.ones_like(live)
 
@@ -516,11 +527,28 @@ def _segment_mask(qseg_ref, kseg_ref):
     return (seg_q == seg_k) & (seg_q > 0)
 
 
+def _block_mask(qseg_ref, kseg_ref, i, j, causal: bool, window: int):
+    """The (BQ, BK) mask of block pair (i, j): the segment mask and, for a
+    decoder (`causal`), a key not after its query and, with `window` > 0,
+    fewer than `window` positions back."""
+    mask = _segment_mask(qseg_ref, kseg_ref)
+    if not causal:
+        return mask
+    bq, bk = qseg_ref.shape[1], kseg_ref.shape[2]
+    back = (i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            - j * bk - jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
+    mask = mask & (back >= 0)
+    return mask & (back < window) if window > 0 else mask
+
+
 def _packed_fwd_kernel(live_ref, kidx_ref, q_ref, k_ref, v_ref, qseg_ref,
                        kseg_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                       scale: float, hb: int, gpr: int, nq: int, nk: int):
+                       scale: float, hb: int, gpr: int, nq: int, nk: int,
+                       causal: bool = False, window: int = 0,
+                       grouped: bool = False):
     del kidx_ref  # read by the index maps
     b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    kv = (lambda h: 0) if grouped else (lambda h: h)  # the k/v head q head h reads
 
     @pl.when(j == 0)
     def _():
@@ -530,10 +558,10 @@ def _packed_fwd_kernel(live_ref, kidx_ref, q_ref, k_ref, v_ref, qseg_ref,
 
     @pl.when(live_ref[((b // gpr) * nq + i) * nk + j] != 0)
     def _():
-        mask = _segment_mask(qseg_ref, kseg_ref)
+        mask = _block_mask(qseg_ref, kseg_ref, i, j, causal, window)
 
         def head(h, carry):
-            q, k, v = q_ref[h], k_ref[h], v_ref[h]
+            q, k, v = q_ref[h], k_ref[kv(h)], v_ref[kv(h)]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
@@ -586,9 +614,11 @@ def _packed_p_ds(q, k, v, do, lse_row, delta_row, mask, scale):
 def _packed_dkv_kernel(live_ref, qidx_ref, q_ref, k_ref, v_ref, do_ref,
                        lse_ref, delta_ref, qseg_ref, kseg_ref, dk_ref, dv_ref,
                        dk_acc, dv_acc, *, scale: float, hb: int, gpr: int,
-                       nq: int, nk: int):
+                       nq: int, nk: int, causal: bool = False,
+                       window: int = 0, grouped: bool = False):
     del qidx_ref
     b, jk, jq = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    kv = (lambda h: 0) if grouped else (lambda h: h)
 
     @pl.when(jq == 0)
     def _():
@@ -597,15 +627,16 @@ def _packed_dkv_kernel(live_ref, qidx_ref, q_ref, k_ref, v_ref, do_ref,
 
     @pl.when(live_ref[((b // gpr) * nk + jk) * nq + jq] != 0)
     def _():
-        mask = _segment_mask(qseg_ref, kseg_ref)
+        mask = _block_mask(qseg_ref, kseg_ref, jq, jk, causal, window)
 
         def head(h, carry):
-            p, ds = _packed_p_ds(q_ref[h], k_ref[h], v_ref[h], do_ref[h],
-                                 lse_ref[h], delta_ref[h], mask, scale)
-            dv_acc[h] += jax.lax.dot_general(         # P^T dO
+            p, ds = _packed_p_ds(q_ref[h], k_ref[kv(h)], v_ref[kv(h)],
+                                 do_ref[h], lse_ref[h], delta_ref[h], mask,
+                                 scale)
+            dv_acc[kv(h)] += jax.lax.dot_general(     # P^T dO
                 p, do_ref[h], (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            dk_acc[h] += jax.lax.dot_general(         # dS^T Q
+            dk_acc[kv(h)] += jax.lax.dot_general(     # dS^T Q
                 ds, q_ref[h], (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             return carry
@@ -620,9 +651,12 @@ def _packed_dkv_kernel(live_ref, qidx_ref, q_ref, k_ref, v_ref, do_ref,
 
 def _packed_dq_kernel(live_ref, kidx_ref, q_ref, k_ref, v_ref, do_ref,
                       lse_ref, delta_ref, qseg_ref, kseg_ref, dq_ref, dq_acc,
-                      *, scale: float, hb: int, gpr: int, nq: int, nk: int):
+                      *, scale: float, hb: int, gpr: int, nq: int, nk: int,
+                      causal: bool = False, window: int = 0,
+                      grouped: bool = False):
     del kidx_ref
     b, jq, jk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    kv = (lambda h: 0) if grouped else (lambda h: h)
 
     @pl.when(jk == 0)
     def _():
@@ -630,13 +664,14 @@ def _packed_dq_kernel(live_ref, kidx_ref, q_ref, k_ref, v_ref, do_ref,
 
     @pl.when(live_ref[((b // gpr) * nq + jq) * nk + jk] != 0)
     def _():
-        mask = _segment_mask(qseg_ref, kseg_ref)
+        mask = _block_mask(qseg_ref, kseg_ref, jq, jk, causal, window)
 
         def head(h, carry):
-            _, ds = _packed_p_ds(q_ref[h], k_ref[h], v_ref[h], do_ref[h],
-                                 lse_ref[h], delta_ref[h], mask, scale)
+            _, ds = _packed_p_ds(q_ref[h], k_ref[kv(h)], v_ref[kv(h)],
+                                 do_ref[h], lse_ref[h], delta_ref[h], mask,
+                                 scale)
             dq_acc[h] += jax.lax.dot_general(         # dS K
-                ds, k_ref[h], (((1,), (0,)), ((), ())),
+                ds, k_ref[kv(h)], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             return carry
 
@@ -653,6 +688,14 @@ def _packed_params():
         vmem_limit_bytes=PACKED_VMEM_LIMIT)
 
 
+def _decoder_terms(causal, window, grouped) -> dict:
+    """The kernels' keywords beyond the packed ViT's: none where all are off,
+    so that model's kernels are traced as they were."""
+    if not (causal or window or grouped):
+        return {}
+    return dict(causal=causal, window=window, grouped=grouped)
+
+
 def _segment_tiles(segment_ids):
     """The ids as the kernels read them: (R, T, 128) for a q block's column,
     (R, 8, T) for a k block's row (the layouts of jax's own TPU flash
@@ -662,20 +705,27 @@ def _segment_tiles(segment_ids):
             jnp.broadcast_to(segment_ids[:, None, :], (r, 8, t)))
 
 
-def _packed_fwd(q, k, v, segment_ids, scale, bq, bk, hb, heads, skip):
+def _packed_fwd(q, k, v, segment_ids, scale, bq, bk, hb, heads, skip,
+                causal=False, window=0, grouped=False, name="flash_packed"):
+    """`grouped`: k and v hold ONE head for each grid step's `hb` query
+    heads (grouped-query attention: (R * KV, T, Dh) beside q's (R * H, T,
+    Dh), hb = H / KV), read inside the kernel: no repeated K or V exists."""
     bh, t, dh = q.shape
     nq, nk, gpr = t // bq, t // bk, heads // hb
-    live, kidx, _, _ = packed_block_tables(segment_ids, bq, bk, skip)
+    hk = 1 if grouped else hb
+    live, kidx, _, _ = packed_block_tables(segment_ids, bq, bk, skip, causal,
+                                           window)
     qseg, kseg = _segment_tiles(segment_ids)
 
     def at_k(b, i, j, live, kidx):
         return kidx[((b // gpr) * nq + i) * nk + j]
 
     qspec = pl.BlockSpec((hb, bq, dh), lambda b, i, j, *_: (b, i, 0))
-    kspec = pl.BlockSpec((hb, bk, dh), lambda b, i, j, *t: (b, at_k(b, i, j, *t), 0))
+    kspec = pl.BlockSpec((hk, bk, dh), lambda b, i, j, *t: (b, at_k(b, i, j, *t), 0))
     o, lse = pl.pallas_call(
         functools.partial(_packed_fwd_kernel, scale=scale, hb=hb, gpr=gpr,
-                          nq=nq, nk=nk),
+                          nq=nq, nk=nk, **_decoder_terms(causal, window,
+                                                         grouped)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh // hb, nq, nk),
@@ -699,18 +749,21 @@ def _packed_fwd(q, k, v, segment_ids, scale, bq, bk, hb, heads, skip):
             jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
         ],
         compiler_params=_packed_params(),
-        name="flash_packed_fwd",
+        name=f"{name}_fwd",
         interpret=_interpret(),
     )(live, kidx, q, k, v, qseg, kseg)
     return o, lse
 
 
 def _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk, hb, heads,
-                skip):
+                skip, causal=False, window=0, grouped=False,
+                name="flash_packed"):
     bh, t, dh = q.shape
     nq, nk, gpr = t // bq, t // bk, heads // hb
+    hk = 1 if grouped else hb
+    terms = _decoder_terms(causal, window, grouped)
     live_qk, kidx, live_kq, qidx = packed_block_tables(segment_ids, bq, bk,
-                                                       skip)
+                                                       skip, causal, window)
     qseg, kseg = _segment_tiles(segment_ids)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None, :]                       # (BH, 1, T)
@@ -719,11 +772,11 @@ def _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk, hb, heads,
         return qidx[((b // gpr) * nk + jk) * nq + jq]
 
     qspec = pl.BlockSpec((hb, bq, dh), lambda b, jk, jq, *t: (b, at_q(b, jk, jq, *t), 0))
-    kspec = pl.BlockSpec((hb, bk, dh), lambda b, jk, jq, *_: (b, jk, 0))
+    kspec = pl.BlockSpec((hk, bk, dh), lambda b, jk, jq, *_: (b, jk, 0))
     row = pl.BlockSpec((hb, 1, bq), lambda b, jk, jq, *t: (b, 0, at_q(b, jk, jq, *t)))
     dk, dv = pl.pallas_call(
         functools.partial(_packed_dkv_kernel, scale=scale, hb=hb, gpr=gpr,
-                          nq=nq, nk=nk),
+                          nq=nq, nk=nk, **terms),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh // hb, nk, nq),
@@ -734,11 +787,11 @@ def _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk, hb, heads,
                 pl.BlockSpec((1, 8, bk), lambda b, jk, jq, *_: (b // gpr, 0, jk)),
             ],
             out_specs=[kspec, kspec],
-            scratch_shapes=[pltpu.VMEM((hb, bk, dh), jnp.float32),
-                            pltpu.VMEM((hb, bk, dh), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((bh, t, dh), q.dtype)] * 2,
+            scratch_shapes=[pltpu.VMEM((hk, bk, dh), jnp.float32),
+                            pltpu.VMEM((hk, bk, dh), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(k.shape, q.dtype)] * 2,
         compiler_params=_packed_params(),
-        name="flash_packed_dkv",
+        name=f"{name}_dkv",
         interpret=_interpret(),
     )(live_kq, qidx, q, k, v, do, lse, delta, qseg, kseg)
 
@@ -746,11 +799,11 @@ def _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk, hb, heads,
         return kidx[((b // gpr) * nq + jq) * nk + jk]
 
     qspec = pl.BlockSpec((hb, bq, dh), lambda b, jq, jk, *_: (b, jq, 0))
-    kspec = pl.BlockSpec((hb, bk, dh), lambda b, jq, jk, *t: (b, at_k(b, jq, jk, *t), 0))
+    kspec = pl.BlockSpec((hk, bk, dh), lambda b, jq, jk, *t: (b, at_k(b, jq, jk, *t), 0))
     row = pl.BlockSpec((hb, 1, bq), lambda b, jq, jk, *_: (b, 0, jq))
     dq = pl.pallas_call(
         functools.partial(_packed_dq_kernel, scale=scale, hb=hb, gpr=gpr,
-                          nq=nq, nk=nk),
+                          nq=nq, nk=nk, **terms),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh // hb, nq, nk),
@@ -764,7 +817,7 @@ def _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk, hb, heads,
             scratch_shapes=[pltpu.VMEM((hb, bq, dh), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((bh, t, dh), q.dtype),
         compiler_params=_packed_params(),
-        name="flash_packed_dq",
+        name=f"{name}_dq",
         interpret=_interpret(),
     )(live_qk, kidx, q, k, v, do, lse, delta, qseg, kseg)
     return dq, dk, dv
@@ -809,4 +862,79 @@ def packed_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     seg = jnp.pad(segment_ids.astype(jnp.int32), ((0, 0), (0, t_pad - t)))
     qb, kb, vb = (_pad_seq(_to_bh(x), t_pad) for x in (q, k, v))
     o = _packed_bh(qb, kb, vb, seg, dh ** -0.5, bq, bk, hb, h, skip)
+    return _from_bh(o[:, :t], q.shape)
+
+
+# ---------------------------------------------------------------------------
+# packed token documents: the same kernels with causal, window and KV groups
+# ---------------------------------------------------------------------------
+# A decoder's packed row holds documents back to back (vitax/data/packing.py:
+# document_layout). The packed kernels above gain three mask terms (a key in
+# the query's own document, not after it, and in a sliding layer fewer than
+# `window` positions back), the block table the same terms by block (so a
+# sliding layer runs only the block pairs inside its window), and grouped
+# key/value heads: the H / KV query heads that share a key/value head are one
+# grid step's heads and read that one head's block (`grouped`), so K and V
+# are never repeated in memory. They carry names of their own,
+# `flash_causal_*` and `flash_window_*`: one body, told apart in a trace.
+
+"""Block defaults, not yet swept on the chip: a full layer takes the packed
+kernels' (512, 1024); a sliding layer of window 512 takes (512, 512), where
+a q block meets two k blocks (its own and the one before) and half of what
+they compute is inside the window."""
+CAUSAL_BLOCKS = (512, 1024)
+WINDOW_BLOCKS = (512, 512)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _documents_bh(q, k, v, segment_ids, scale, bq, bk, group, heads, skip,
+                  window):
+    return _packed_fwd(q, k, v, segment_ids, scale, bq, bk, group, heads, skip,
+                       **_documents_terms(window))[0]
+
+
+def _documents_terms(window: int) -> dict:
+    return dict(causal=True, window=window, grouped=True,
+                name="flash_window" if window > 0 else "flash_causal")
+
+
+def _documents_bh_fwd(q, k, v, segment_ids, scale, bq, bk, group, heads, skip,
+                      window):
+    o, lse = _packed_fwd(q, k, v, segment_ids, scale, bq, bk, group, heads,
+                         skip, **_documents_terms(window))
+    return o, (q, k, v, o, lse, segment_ids)
+
+
+def _documents_bh_bwd(scale, bq, bk, group, heads, skip, window, res, do):
+    import numpy as np
+    q, k, v, o, lse, segment_ids = res
+    dq, dk, dv = _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk,
+                             group, heads, skip, **_documents_terms(window))
+    return dq, dk, dv, np.zeros(segment_ids.shape, jax.dtypes.float0)
+
+
+_documents_bh.defvjp(_documents_bh_fwd, _documents_bh_bwd)
+
+
+def document_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                             segment_ids: jax.Array, window: int = 0,
+                             block_q: int = 0, block_k: int = 0,
+                             skip: bool = True) -> jax.Array:
+    """Causal attention within each document of a packed row: q (R, T, H, Dh),
+    k and v (R, T, KV, Dh) with H a multiple of KV, (R, T) int32 segment ids
+    (0 = padding) -> (R, T, H, Dh), differentiable in q/k/v. `window` > 0: a
+    query sees the `window` latest keys of its document, itself included.
+    Padding rows come back zero. `block_q`/`block_k`/`skip` exist for the
+    tests."""
+    r, t, h, dh = q.shape
+    kv = k.shape[2]
+    assert h % kv == 0, (h, kv)
+    dq, dk = WINDOW_BLOCKS if window > 0 else CAUSAL_BLOCKS
+    bq = min(block_q or dq, _pad_len(t, 128))
+    bk = min(block_k or dk, _pad_len(t, 128))
+    t_pad = _pad_len(t, math.lcm(bq, bk))
+    seg = jnp.pad(segment_ids.astype(jnp.int32), ((0, 0), (0, t_pad - t)))
+    qb, kb, vb = (_pad_seq(_to_bh(x), t_pad) for x in (q, k, v))
+    o = _documents_bh(qb, kb, vb, seg, dh ** -0.5, bq, bk, h // kv, h, skip,
+                      int(window))
     return _from_bh(o[:, :t], q.shape)

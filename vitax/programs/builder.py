@@ -25,9 +25,9 @@ into a jitted program. Everything that needs a program goes through the two:
 The activation anchors are sharding rules and live with them
 (vitax/parallel/sharding.py). The scenario registry (programs/registry.py)
 names which tasks each --task may build; unknown combinations fail here with
-the scenario's program set. `benchmark/harness.py` still writes the assembly
-out by hand (ROADMAP C13); tests/test_assembly.py holds this module to the
-written-out form, text for text.
+the scenario's program set. The benchmark's generators assemble here too
+(since PR 31); tests/test_assembly.py holds this module to the written-out
+form, text for text, and the generators to this module.
 """
 
 from __future__ import annotations
@@ -41,6 +41,7 @@ from jax.sharding import NamedSharding
 
 from vitax.config import Config
 from vitax.models import build_model
+from vitax.models.decoder import build_decoder
 from vitax.ops.attention import make_attention_impl
 from vitax.parallel.mesh import Mesh, batch_pspec, build_mesh
 from vitax.parallel.rules import _leaf_path_names
@@ -64,11 +65,17 @@ def build_model_for(cfg: Config, mesh: Mesh, force_tpu_kernels: bool = False,
     multi-device mesh, the MoE dispatch sharding iff the model has experts).
     `force_tpu_kernels` selects the TPU kernels off the TPU (a compile for a
     described topology; interpret mode on the CPU); `quant_matmul` (serving
-    only) swaps every Dense site for QuantDense."""
+    only) swaps every Dense site for QuantDense. `cfg.model_family` picks
+    the module: the ViT, or the token decoder (vitax/models/decoder.py)."""
+    attention_impl = make_attention_impl(
+        cfg, mesh, force_tpu_kernels=force_tpu_kernels)
+    if cfg.decoder:
+        assert quant_matmul is None, "the decoder has no quantized arm"
+        return build_decoder(cfg, attention_impl=attention_impl,
+                             token_sharding=token_sharding(cfg, mesh))
     return build_model(
         cfg,
-        attention_impl=make_attention_impl(
-            cfg, mesh, force_tpu_kernels=force_tpu_kernels),
+        attention_impl=attention_impl,
         token_sharding=token_sharding(cfg, mesh),
         moe_dispatch_sharding=moe_dispatch_sharding(cfg, mesh),
         quant_matmul=quant_matmul)
@@ -232,8 +239,13 @@ def build_engine(cfg: Config, npz: str = "", epoch: Optional[int] = None):
 
 
 def abstract_batch(cfg: Config, mesh: Mesh) -> Dict[str, jax.ShapeDtypeStruct]:
-    """The dense step's batch as shapes, sharded as the loader shards it."""
+    """The step's batch as shapes, sharded as the loader shards it: the
+    dense model's images, or the decoder's packed documents."""
     sh = NamedSharding(mesh, batch_pspec())
+    if cfg.decoder:
+        rows = jax.ShapeDtypeStruct((cfg.batch_size, cfg.pack_tokens),
+                                    jnp.int32, sharding=sh)
+        return {"tokens": rows, "segment_ids": rows, "positions": rows}
     return {
         "image": jax.ShapeDtypeStruct(
             (cfg.batch_size, cfg.image_size, cfg.image_size, 3),

@@ -26,7 +26,8 @@ import time
 from typing import Optional
 
 from vitax.telemetry.flops import (
-    detect_peak_tflops, mfu, model_flops_per_step, packed_flops_per_step)
+    decoder_flops_per_step, detect_peak_tflops, mfu, model_flops_per_step,
+    packed_flops_per_step)
 
 SCHEMA_VERSION = 1
 
@@ -67,7 +68,8 @@ class Recorder:
                     loss: float, lr: float, sec_per_iter: float,
                     data_wait_s: float, grad_norm: Optional[float] = None,
                     ckpt_stall_s: float = 0.0, opt_update_s: float = 0.0,
-                    packed_counts: Optional[dict] = None) -> dict:
+                    packed_counts: Optional[dict] = None,
+                    expert_load: Optional[list] = None) -> dict:
         """One record per log step. `sec_per_iter` / `data_wait_s` /
         `ckpt_stall_s` are the per-step averages since the previous record;
         `step` is the global optimizer-step count (monotonically increasing
@@ -80,13 +82,24 @@ class Recorder:
         `packed_counts`: a packed step's own counters (`tokens`,
         `padding_tokens`, `images`, `token_pairs`; vitax/train/step.py) —
         throughput, MFU and `padding_frac` then come from what the batch
-        held, not from `batch_size x num_patches`."""
+        held, not from `batch_size x num_patches`. A decoder step's
+        (`targets`, `causal_pairs`, `window_pairs`, `expert_slots_here` in
+        place of `token_pairs`; `images` are documents) are written into the
+        record as they are, with `expert_load`, its per-layer per-expert
+        load."""
         images, tokens = self.cfg.batch_size, self.tokens_per_step
         flops_per_step = self.flops_per_step
         if packed_counts is not None:
             images, tokens = packed_counts["images"], packed_counts["tokens"]
-            flops_per_step = packed_flops_per_step(
-                self.cfg, tokens, packed_counts["token_pairs"], images)
+            if self.cfg.decoder:
+                flops_per_step = decoder_flops_per_step(
+                    self.cfg, tokens, packed_counts["targets"],
+                    packed_counts["causal_pairs"],
+                    packed_counts["window_pairs"],
+                    packed_counts["expert_slots_here"])
+            else:
+                flops_per_step = packed_flops_per_step(
+                    self.cfg, tokens, packed_counts["token_pairs"], images)
         record = {
             "schema": SCHEMA_VERSION,
             "time": time.time(),
@@ -110,6 +123,10 @@ class Recorder:
             slots = tokens + packed_counts["padding_tokens"]
             record["padding_frac"] = (packed_counts["padding_tokens"] / slots
                                       if slots else 0.0)
+        if packed_counts is not None and self.cfg.decoder:
+            record.update({k: packed_counts[k] for k in (
+                "targets", "causal_pairs", "window_pairs",
+                "expert_slots_here")}, expert_load=expert_load)
         if grad_norm is not None:
             record["grad_norm"] = float(grad_norm)
         record.update(memory_stats_bytes())

@@ -26,11 +26,6 @@ from vitax.config import Config
 from vitax.data import build_datasets
 from vitax.models import count_params
 from vitax.parallel.mesh import build_mesh
-# benchmark/harness.py imports these two names from here; a `benchmark`
-# issue re-points it at vitax/parallel/sharding.py (ROADMAP C13)
-from vitax.parallel.sharding import (  # noqa: F401
-    moe_dispatch_sharding as _moe_dispatch_sharding,
-    token_sharding as _token_sharding)
 from vitax.train.control import ArbiterReporter, ControlPlane
 from vitax.programs.builder import Geometry, build_program
 from vitax.train.state import TrainState
@@ -70,6 +65,12 @@ def _attention_remat_note(cfg: Config, model, mesh) -> str:
     from vitax.parallel.sharding import gather_overlap_active
     if model.attention_impl is None or not cfg.grad_ckpt:
         return ""
+    if cfg.decoder:   # by the span of each kind of layer
+        from vitax.models.decoder import keeps_attention_residuals as keeps
+        return "; remat " + ", ".join(
+            f"{'keeps o and lse' if keeps(model, kind) else 'runs the forward again'}"
+            f" in {kind} layers (span {model.span(kind)})"
+            for kind in sorted(set(cfg.layer_kinds)))
     span = attention_span(model)
     if (mesh.shape.get("pp", 1) > 1 or gather_overlap_active(cfg, mesh)
             or cfg.remat_window > 1):
@@ -84,6 +85,12 @@ def _attention_remat_note(cfg: Config, model, mesh) -> str:
     else:
         why = f"span {span} < {ATTN_KEEP_MIN_SPAN} tokens"
     return f"; remat runs its forward again ({why})"
+
+
+# a decoder step's own counters (vitax/train/step.py: decoder_counts), as its
+# step record carries them
+DECODER_COUNTERS = ("tokens", "padding_tokens", "images", "targets",
+                    "causal_pairs", "window_pairs", "expert_slots_here")
 
 
 def train(cfg: Config) -> TrainState:
@@ -683,8 +690,13 @@ def _run_epochs(cfg, state, train_step, train_loader, val_loader, eval_step,
                         grad_norm=float(jax.device_get(metrics["grad_norm"])),
                         packed_counts=(
                             {k: float(jax.device_get(metrics[k])) for k in
-                             ("tokens", "padding_tokens", "images",
-                              "token_pairs")} if cfg.packed else None))
+                             (DECODER_COUNTERS if cfg.decoder else
+                              ("tokens", "padding_tokens", "images",
+                               "token_pairs"))} if cfg.packed else None),
+                        expert_load=(
+                            jax.device_get(
+                                metrics["expert_load"]).tolist()
+                            if cfg.decoder else None))
                     if "kl" in metrics:
                         # distill step (vitax/programs/workloads.py): the
                         # extra metrics ride the log-step fence the record

@@ -86,8 +86,12 @@ def make_train_state(
     # sample batch must divide evenly over the (dp, fsdp) batch axes — the
     # attention shard_map paths trace through init
     sample_b = mesh.shape["dp"] * mesh.shape["fsdp"]
-    from vitax.models.vit import sample_input
-    sample = sample_input(cfg, sample_b)
+    if cfg.decoder:
+        from vitax.models.decoder import sample_documents
+        sample = sample_documents(cfg, sample_b)
+    else:
+        from vitax.models.vit import sample_input
+        sample = sample_input(cfg, sample_b)
 
     def init_fn(rng):
         params = model.init(rng, sample, True)

@@ -151,6 +151,52 @@ def packed_loss(logits: jax.Array, batch: Dict[str, jax.Array]) -> jax.Array:
     return jnp.sum(ce * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
+def decoder_inputs(batch: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+    """The decoder's input (vitax/models/decoder.py) from a packed batch of
+    documents (vitax/data/packing.py: document_layout)."""
+    return {k: batch[k] for k in ("tokens", "segment_ids", "positions")}
+
+
+def next_token_targets(batch: Dict[str, jax.Array]):
+    """(labels (R, T), mask (R, T) float32): position t's target is token
+    t + 1 where that belongs to the same document; a document's last token
+    and padding have none."""
+    seg, tokens = batch["segment_ids"], batch["tokens"]
+    following = jnp.pad(seg[:, 1:], ((0, 0), (0, 1)))
+    mask = (seg > 0) & (following == seg)
+    labels = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)))
+    return jnp.where(mask, labels, 0), mask.astype(jnp.float32)
+
+
+def decoder_loss(logits: jax.Array, batch: Dict[str, jax.Array]) -> jax.Array:
+    """Mean next-token cross-entropy over the TARGETS of a packed batch
+    (within each document), from logits (R, T, vocabulary rows)."""
+    with jax.named_scope("lm_head_loss"):
+        labels, mask = next_token_targets(batch)
+        ce = optax.softmax_cross_entropy_with_integer_labels(logits, labels)
+        return jnp.sum(ce * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+def decoder_counts(cfg: Config, batch: Dict[str, jax.Array]
+                   ) -> Dict[str, jax.Array]:
+    """What a decoder step's batch held, counted on the device from the
+    segment ids: `tokens` valid, `padding_tokens`, `images` (documents: the
+    step's samples), `targets`, and the attention's useful work as (query,
+    key) pairs a layer: `causal_pairs` = sum n (n + 1) / 2 over documents,
+    `window_pairs` = the same with at most `window_tokens` keys a query."""
+    seg = batch["segment_ids"]
+    n = jnp.sum(seg[..., None] == jnp.arange(1, cfg.pack_images + 1),
+                axis=1, dtype=jnp.int32).astype(jnp.float32)      # (R, S)
+    w = jnp.minimum(n, float(max(cfg.window_tokens, 1)))
+    valid = jnp.sum(seg > 0, dtype=jnp.int32)
+    documents = jnp.sum(n > 0, dtype=jnp.int32)
+    return dict(
+        tokens=valid, padding_tokens=seg.size - valid, images=documents,
+        targets=valid - documents,
+        causal_pairs=jnp.sum(n * (n + 1) / 2),
+        window_pairs=jnp.sum(w * (w + 1) / 2 + (n - w) * w))
+
+
 def _microbatch_split(batch: PyTree, k_steps: int, mesh: Mesh) -> PyTree:
     """Reshape every (B, ...) leaf to (K, B/K, ...) with a STRIDED sample
     assignment: reshape to (B/K, K, ...) then swap the leading axes, so
@@ -301,6 +347,19 @@ def make_train_step(
 
     moe = cfg.moe_experts > 0
     anchor_logits = _make_logits_anchor(mesh)
+
+    def decoder_loss_fn(params, batch, rng):
+        """(loss, per-layer per-expert load (sparse layers, held experts)):
+        the expert layers sow their load (vitax/models/experts.py)."""
+        del rng                      # no dropout arm (Config.validate)
+        if comm is not None:
+            params = comm.cast(params)
+        logits, cols = model.apply(params, decoder_inputs(batch), True,
+                                   mutable=["intermediates"])
+        loads = _select_by_name(cols, "expert_load")
+        load = (jnp.concatenate([x.reshape(-1, x.shape[-1]) for x in loads])
+                if loads else jnp.zeros((0, 0), jnp.int32))
+        return decoder_loss(logits, batch), load
 
     def loss_fn(params, batch, rng):
         if comm is not None:
@@ -454,7 +513,11 @@ def make_train_step(
             params = comm.cast(state.params)
         else:
             params = state.params
-        if use_1f1b:
+        expert_load = None
+        if cfg.decoder:
+            (loss, expert_load), grads = jax.value_and_grad(
+                decoder_loss_fn, has_aux=True)(params, batch, step_rng)
+        elif use_1f1b:
             loss, grads = vag_1f1b(params, prepare_images(batch["image"]),
                                    batch["label"])
         elif k_steps > 1:
@@ -476,7 +539,11 @@ def make_train_step(
             # the value via the pure schedule fn
             "lr_step": new_state.step,
         }
-        if cfg.packed:
+        if cfg.decoder:
+            metrics.update(decoder_counts(cfg, batch))
+            metrics.update(expert_load=expert_load,
+                           expert_slots_here=jnp.sum(expert_load))
+        elif cfg.packed:
             # what a packed step did is in its batch, not in the config:
             # counted on the device from the segment ids and the label mask
             seg = batch["segment_ids"]
