@@ -376,6 +376,14 @@ def test_train_step_counters_against_a_layout_counted_by_hand():
         "window_pairs")}
     assert got == dict(tokens=111, padding_tokens=17, images=5, targets=106,
                        causal_pairs=1618, window_pairs=748)
+    # beside them what the kernels compute: the live sub-tiles of the tables
+    # the kernels read (rows of 64 pad to one block of 128 each)
+    from vitax.ops.flash_blocked import packed_block_tables
+    seg = jnp.pad(batch["segment_ids"], ((0, 0), (0, 64)))
+    for kind, window in (("causal", 0), ("window", cfg.window_tokens)):
+        live = np.asarray(packed_block_tables(seg, 128, 128, True, True,
+                                              window)[0])
+        assert float(m[f"{kind}_computed_pairs"]) == live.sum() * 128 * 128
     load = np.asarray(m["expert_load"])
     assert load.shape == (4, 4)           # sparse layers x held experts
     assert int(m["expert_slots_here"]) == load.sum() <= 111 * 4 * 4

@@ -158,6 +158,12 @@ def test_train_step_loss_grad_norm_and_counters(setup):
     for key in ("tokens", "padding_tokens", "images"):
         assert int(metrics[key]) == info[key], key
     assert int(metrics["token_pairs"]) == sum((h * w) ** 2 for h, w in grids)
+    # what the kernels compute for them: the live sub-tiles of the table the
+    # kernels read (here one tile of 128 x 128 a row)
+    from vitax.ops.flash_blocked import packed_block_tables
+    live = np.asarray(packed_block_tables(
+        jnp.asarray(batch["segment_ids"]), 128, 128)[0])
+    assert float(metrics["computed_pairs"]) == live.sum() * 128 * 128 == 32768
 
 
 def test_lower_precision_fails(setup):

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -451,7 +452,15 @@ def blocked_dropout_attention(q, k, v, seed, rate: float,
 #   matmul, and its index map names the block the pipeline already holds, so
 #   it moves no bytes either. The work follows sum(n_i^2), not T^2;
 # - several heads of one row a grid step (they share the row's mask and its
-#   table), so that what a dead step still costs is paid once for all of them.
+#   table), so that what a dead step still costs is paid once for all of them;
+# - a second level of liveness inside a live pair (PR 33): the table holds a
+#   bit for each (sq, sk) sub-tile of the pair, by the same rule one level
+#   down, and the body runs the sub-tiles alone whose bit is set; one scalar
+#   branch a sub-tile, shared by the step's heads, which are unrolled inside
+#   it. What made small tiles pay is in the bodies: the forward keeps its
+#   running max and sum in all 128 lanes and never narrows them to one
+#   (`_lanes`); dK/dV computes its scores keys down, queries across, so that
+#   no score tile is transposed and lse / delta are used as the rows they are.
 # Matmul operands stay in the input dtype with float32 accumulation, as in
 # the whole-N 4D kernels; softmax and score math is float32.
 
@@ -470,20 +479,79 @@ PACKED_BLOCK_K = 1024
 PACKED_HEADS_PER_STEP = 8
 PACKED_VMEM_LIMIT = 64 * 1024 * 1024  # of the v5e's 128 MiB
 
+"""Sub-tile shapes (sq, sk) of each kernel: inside a live block pair the body
+walks tiles of this shape and runs those alone that hold a pair some query
+may see (`packed_block_tables`, `sub`). Chosen by kernel and by `window` > 0
+or not, which is all the code sees; `tools/sweep_packed_tiles.py` measures
+them. My chip runs, PR 33 (v5e, bf16, kernels alone, ms a call; "whole" is
+one sub-tile a pair; in brackets the area computed over the pairs needed).
 
-def packed_block_tables(segment_ids, bq: int, bk: int, skip: bool = True,
-                        causal: bool = False, window: int = 0):
-    """What the kernels' grids read as scalar prefetch, from a row's
-    `segment_ids` (R, T), T a multiple of both blocks. A (q-block, k-block)
-    pair is live when the blocks' ranges of non-zero segment ids meet. For
-    the grids that stream k blocks (forward, dQ): `live_qk`, and `kidx`, the
-    k block to hold at each step (the latest live one, so a dead step
-    fetches nothing); for the grid that streams q blocks (dK/dV): `live_kq`
-    and `qidx`. All flat int32. `skip=False` calls every pair live (the
-    tests' and the measurements' comparison arm). `causal`: a pair whose
-    every key lies after its every query is dead too, and with `window` > 0
-    one whose every key lies `window` or more positions back (a document is
-    contiguous in its row, so positions in the row serve)."""
+The MoonViT cell's rows (32 x 8,192 x 72, nine images, blocks (512, 1024)).
+PR 26's bodies: forward 5.62, dK/dV 7.26, dQ 5.84, and sub-tiles in them
+LOST: forward 8.53 at (512, 512) and 14.96 at (256, 256), dK/dV 6.83 and
+10.23, dQ 5.55 and 7.35. An update's fixed costs hid the area: narrowing the
+running max and sum to one lane and broadcasting them again (forward), the
+transposes of P and dS (dK/dV), a loop over heads that ran one head's
+matmuls and softmax in turn. With the statistics held in all lanes, scores
+keys-down in dK/dV and the heads unrolled:
+  forward  whole 4.32 [1.55] | (256, 512) 3.99 [1.28] | (256, 1024) 4.11 |
+           (512, 512) 4.19 | (256, 256) 4.36 [1.19] | (128, 256) 4.85 |
+           (128, 128) 6.59 [1.09]
+  dK/dV    whole 6.91 | (256, 256) 5.72 | (512, 128) 5.90 | (256, 512) 5.93 |
+           (512, 512) 6.32 | (128, 256) 6.29 | (128, 128) 6.99
+  dQ       whole 5.72 | (256, 512) 4.83 | (128, 512) 4.91 | (256, 256) 4.92 |
+           (512, 512) 5.06 | (128, 256) 6.00 | sk = 128: 8.2-8.5
+18.72 ms a layer before, 14.55 now. Blocks of (1024, 1024) with the same
+tiles: forward 3.90, dQ 4.71, dK/dV 7.55: not taken.
+
+The Laguna cell's row, full layers (48 x 8,192 x 128 over 8 key/value heads,
+causal, blocks (512, 1024)); before: 5.28 / 6.25 / 5.35.
+  forward  whole 3.98 [1.49] | (512, 512) 3.85 [1.33] | (256, 512) 3.92 |
+           (256, 256) 4.36 [1.18] | (128, 128) 6.98
+  dK/dV    whole 5.82 | (256, 256) 4.79 | (512, 128) 5.06 | (256, 512) 5.17 |
+           (512, 512) 5.30 | (128, 128) 6.58
+  dQ       whole 4.81 | (512, 512) 4.43 | (256, 256) 4.44 | (256, 512) 4.50 |
+           (128, 256) 5.58
+(512, 512) would suit its forward and dQ 2% better; one table serves both
+models, and the MoonViT rows lose 5% there.
+
+Sliding layers (64 x 8,192 x 128 over 8, window 512): see `WINDOW_BLOCKS`.
+(128, x) tiles lose everywhere: a 128-row left operand pays an MXU weight
+load for every 128 rows it streams."""
+
+
+class Tiles(NamedTuple):
+    """One value a kernel: forward, dK/dV, dQ."""
+    fwd: tuple
+    dkv: tuple
+    dq: tuple
+
+
+PACKED_TILES = Tiles(fwd=(256, 512), dkv=(256, 256), dq=(256, 512))
+WINDOW_TILES = Tiles(fwd=(256, 256), dkv=(256, 256), dq=(256, 256))
+# the matmuls a kernel runs on every score tile it computes (QK^T and PV;
+# QK^T, dO V^T, P^T dO, dS^T Q; QK^T, dO V^T, dS K): the weights of
+# `computed_pairs`
+TILE_MATMULS = Tiles(fwd=2, dkv=4, dq=3)
+
+
+def _sub_tiles(window: int, bq: int, bk: int) -> Tiles:
+    """Each kernel's sub-tile shape for blocks (bq, bk): the constants above,
+    cut to what divides the blocks."""
+    return Tiles(*((math.gcd(sq, bq), math.gcd(sk, bk))
+                   for sq, sk in (WINDOW_TILES if window > 0 else PACKED_TILES)))
+
+
+def _blocks_for(t: int, block_q: int, block_k: int):
+    """(bq, bk, padded T) for rows of `t` tokens."""
+    bq = min(block_q, _pad_len(t, 128))
+    bk = min(block_k, _pad_len(t, 128))
+    return bq, bk, _pad_len(t, math.lcm(bq, bk))
+
+
+def _pairs_live(segment_ids, bq: int, bk: int, causal: bool, window: int):
+    """(R, T / bq, T / bk) bool: which (bq, bk) tiles of a row's score
+    matrix hold a pair some query may see, by the tiles' ranges alone."""
     r, t = segment_ids.shape
     nq, nk = t // bq, t // bk
     big = jnp.iinfo(jnp.int32).max
@@ -497,12 +565,39 @@ def packed_block_tables(segment_ids, bq: int, bk: int, skip: bool = True,
     live = ((qlo[:, :, None] <= khi[:, None, :])
             & (klo[:, None, :] <= qhi[:, :, None]))          # (R, nq, nk)
     if causal:
-        q0 = jnp.arange(nq, dtype=jnp.int32)[:, None] * bq   # a block's first
+        q0 = jnp.arange(nq, dtype=jnp.int32)[:, None] * bq   # a tile's first
         k0 = jnp.arange(nk, dtype=jnp.int32)[None, :] * bk
         near = k0 <= q0 + bq - 1
         if window > 0:
             near = near & (k0 + bk - 1 > q0 - window)
         live = live & near[None]
+    return live
+
+
+def packed_block_tables(segment_ids, bq: int, bk: int, skip: bool = True,
+                        causal: bool = False, window: int = 0, sub=None):
+    """What the kernels' grids read as scalar prefetch, from a row's
+    `segment_ids` (R, T), T a multiple of both blocks. A (q-block, k-block)
+    pair is live when the blocks' ranges of non-zero segment ids meet. For
+    the grids that stream k blocks (forward, dQ): `live_qk`, and `kidx`, the
+    k block to hold at each step (the latest live one, so a dead step
+    fetches nothing); for the grid that streams q blocks (dK/dV): `live_kq`
+    and `qidx`. All flat int32. `skip=False` calls every pair live (the
+    tests' and the measurements' comparison arm). `causal`: a pair whose
+    every key lies after its every query is dead too, and with `window` > 0
+    one whose every key lies `window` or more positions back (a document is
+    contiguous in its row, so positions in the row serve).
+
+    `sub` = (sq, sk), dividing the blocks into at most 32 sub-tiles a pair:
+    the same rule one level down. `live_qk` / `live_kq` then hold, for each
+    pair, a bit for each of its sub-tiles (row-major: bit a * (bk / sk) + c
+    for rows a * sq and columns c * sk on), set where the sub-tile holds a
+    pair some query may see. A sub-tile is live only inside a live pair, and
+    non-zero still reads "live"; `kidx` / `qidx` keep following the blocks'
+    own ranges. Without `sub` a pair is its one sub-tile: 0 or 1."""
+    r, t = segment_ids.shape
+    nq, nk = t // bq, t // bk
+    live = _pairs_live(segment_ids, bq, bk, causal, window)
     if not skip:
         live = jnp.ones_like(live)
 
@@ -514,41 +609,80 @@ def packed_block_tables(segment_ids, bq: int, bk: int, skip: bool = True,
         first = jnp.argmax(live, axis=-1).astype(jnp.int32)[..., None]
         return jnp.where(latest >= 0, latest, first)
 
+    bits = live.astype(jnp.int32)
+    if sub is not None and sub != (bq, bk):
+        sq, sk = sub
+        na, nc = bq // sq, bk // sk
+        assert bq % sq == 0 and bk % sk == 0 and na * nc <= 32, (bq, bk, sub)
+        tiles = (_pairs_live(segment_ids, sq, sk, causal, window)
+                 if skip else jnp.ones((r, t // sq, t // sk), bool))
+        tiles = tiles.reshape(r, nq, na, nk, nc).transpose(0, 1, 3, 2, 4)
+        bits = jax.lax.bitcast_convert_type(jnp.sum(
+            tiles.reshape(r, nq, nk, na * nc).astype(jnp.uint32)
+            << jnp.arange(na * nc, dtype=jnp.uint32), axis=-1,
+            dtype=jnp.uint32), jnp.int32)
     live_kq = live.transpose(0, 2, 1)
     flat = lambda x: x.astype(jnp.int32).reshape(-1)  # noqa: E731
-    return flat(live), flat(held(live)), flat(live_kq), flat(held(live_kq))
+    return (flat(bits), flat(held(live)), flat(bits.transpose(0, 2, 1)),
+            flat(held(live_kq)))
 
 
-def _segment_mask(qseg_ref, kseg_ref):
-    """(BQ, BK) bool from the q block's ids, broadcast over lanes
-    (1, BQ, 128), and the k block's, broadcast over sublanes (1, 8, BK)."""
-    seg_q = qseg_ref[0][:, :1]
-    seg_k = kseg_ref[0][:1, :]
-    return (seg_q == seg_k) & (seg_q > 0)
-
-
-def _block_mask(qseg_ref, kseg_ref, i, j, causal: bool, window: int):
-    """The (BQ, BK) mask of block pair (i, j): the segment mask and, for a
-    decoder (`causal`), a key not after its query and, with `window` > 0,
-    fewer than `window` positions back."""
-    mask = _segment_mask(qseg_ref, kseg_ref)
+def _tile_mask(qseg_ref, kseg_ref, i, j, r0: int, c0: int, sq: int, sk: int,
+               causal: bool, window: int, keys_down: bool = False):
+    """The mask of the (sq, sk) sub-tile at rows `r0`, columns `c0` of block
+    pair (i, j): the segment mask and, for a decoder (`causal`), a key not
+    after its query and, with `window` > 0, fewer than `window` positions
+    back. Queries down and keys across, (sq, sk), from the q block's ids
+    broadcast over lanes (1, BQ, 128) and the k block's over sublanes
+    (1, 8, BK); `keys_down`: the transpose, (sk, sq), from the q block's ids
+    as (1, 8, BQ) and the k block's as (1, BK, 128)."""
+    if keys_down:
+        seg_q, seg_k = qseg_ref[0, :1, r0:r0 + sq], kseg_ref[0, c0:c0 + sk, :1]
+        shape, bq, bk = (sk, sq), qseg_ref.shape[2], kseg_ref.shape[1]
+    else:
+        seg_q, seg_k = qseg_ref[0, r0:r0 + sq, :1], kseg_ref[0, :1, c0:c0 + sk]
+        shape, bq, bk = (sq, sk), qseg_ref.shape[1], kseg_ref.shape[2]
+    mask = (seg_q == seg_k) & (seg_q > 0)
     if not causal:
         return mask
-    bq, bk = qseg_ref.shape[1], kseg_ref.shape[2]
-    back = (i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            - j * bk - jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
+    back = (i * bq + r0 - j * bk - c0
+            + jax.lax.broadcasted_iota(jnp.int32, shape, int(keys_down))
+            - jax.lax.broadcasted_iota(jnp.int32, shape, int(not keys_down)))
     mask = mask & (back >= 0)
     return mask & (back < window) if window > 0 else mask
+
+
+def _each_live_tile(bits, bq: int, bk: int, sub, tile):
+    """Inside a live block pair: `tile(r0, c0)` for every (sq, sk) sub-tile
+    whose bit of `bits` (packed_block_tables) is set, rows before columns.
+    The branch is a scalar's, taken once for all heads of the step."""
+    sq, sk = sub
+    if (sq, sk) == (bq, bk):
+        return tile(0, 0)
+    starts = [(r0, c0) for r0 in range(0, bq, sq) for c0 in range(0, bk, sk)]
+    for n, (r0, c0) in enumerate(starts):
+        pl.when(((bits >> n) & 1) != 0)(functools.partial(tile, r0, c0))
+
+
+def _lanes(x, width: int):
+    """A statistic held equal in all 128 lanes, (rows, 128), as (rows,
+    width): a slice or whole copies, never a broadcast out of one lane."""
+    if width <= 128:
+        return x[:, :width]
+    assert width % 128 == 0, width
+    return pltpu.repeat(x, width // 128, axis=1)
 
 
 def _packed_fwd_kernel(live_ref, kidx_ref, q_ref, k_ref, v_ref, qseg_ref,
                        kseg_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                        scale: float, hb: int, gpr: int, nq: int, nk: int,
-                       causal: bool = False, window: int = 0,
+                       sub, causal: bool = False, window: int = 0,
                        grouped: bool = False):
     del kidx_ref  # read by the index maps
     b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     kv = (lambda h: 0) if grouped else (lambda h: h)  # the k/v head q head h reads
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    sq, sk = sub
 
     @pl.when(j == 0)
     def _():
@@ -556,29 +690,41 @@ def _packed_fwd_kernel(live_ref, kidx_ref, q_ref, k_ref, v_ref, qseg_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(live_ref[((b // gpr) * nq + i) * nk + j] != 0)
+    bits = live_ref[((b // gpr) * nq + i) * nk + j]
+
+    @pl.when(bits != 0)
     def _():
-        mask = _block_mask(qseg_ref, kseg_ref, i, j, causal, window)
+        def tile(r0, c0):
+            # rows are independent: one online-softmax update of the `sq`
+            # rows at r0 with the `sk` keys at c0. The running max and sum
+            # live in all 128 lanes of m_ref / l_ref and are used that way
+            # (`_lanes`): picking one lane out and broadcasting it again,
+            # three times an update, cost more than the scores of 512 keys.
+            # The heads are unrolled, not looped over: one head's matmuls
+            # run beside another's softmax.
+            rows, cols = slice(r0, r0 + sq), slice(c0, c0 + sk)
+            mask = _tile_mask(qseg_ref, kseg_ref, i, j, r0, c0, sq, sk,
+                              causal, window)
+            for h in range(hb):
+                q, k, v = q_ref[h, rows], k_ref[kv(h), cols], v_ref[kv(h), cols]
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                s = jnp.where(mask, s, MASKED)
+                m_prev, l_prev = m_ref[h, rows], l_ref[h, rows]    # (sq, 128)
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - _lanes(m_new, sk))
+                l_ref[h, rows] = alpha * l_prev + jnp.sum(p, axis=-1,
+                                                          keepdims=True)
+                m_ref[h, rows] = m_new
+                acc_ref[h, rows] = (
+                    acc_ref[h, rows] * _lanes(alpha, v.shape[-1])
+                    + jax.lax.dot_general(
+                        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32))
 
-        def head(h, carry):
-            q, k, v = q_ref[h], k_ref[kv(h)], v_ref[kv(h)]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            s = jnp.where(mask, s, MASKED)
-            m_prev, l_prev = m_ref[h], l_ref[h]      # (BQ, 128), col 0 live
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev[:, :1] - m_new[:, :1])
-            p = jnp.exp(s - m_new[:, :1])
-            l_new = alpha * l_prev[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[h] = jnp.broadcast_to(m_new[:, :1], m_prev.shape)
-            l_ref[h] = jnp.broadcast_to(l_new, l_prev.shape)
-            return carry
-
-        jax.lax.fori_loop(0, hb, head, 0)
+        _each_live_tile(bits, bq, bk, sub, tile)
 
     @pl.when(j == nk - 1)
     def _():
@@ -593,55 +739,54 @@ def _packed_fwd_kernel(live_ref, kidx_ref, q_ref, k_ref, v_ref, qseg_ref,
         jax.lax.fori_loop(0, hb, head, 0)
 
 
-def _packed_p_ds(q, k, v, do, lse_row, delta_row, mask, scale):
-    """What both backward kernels recompute for one head's block pair: the
-    probabilities P = exp(S - lse) and dS = P * (dO V^T - delta) * scale,
-    both (BQ, BK) in the input dtype (the MXU's operands). lse / delta
-    arrive as (1, BQ) rows."""
-    lse = lse_row[0][:, None]                         # (BQ, 1)
-    delta = delta_row[0][:, None]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    p = jnp.exp(jnp.where(mask, s, MASKED) - lse)
-    dp = jax.lax.dot_general(                         # dO V^T
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    ds = p * (dp - delta) * scale
-    return p.astype(q.dtype), ds.astype(q.dtype)
-
-
 def _packed_dkv_kernel(live_ref, qidx_ref, q_ref, k_ref, v_ref, do_ref,
                        lse_ref, delta_ref, qseg_ref, kseg_ref, dk_ref, dv_ref,
                        dk_acc, dv_acc, *, scale: float, hb: int, gpr: int,
-                       nq: int, nk: int, causal: bool = False,
+                       nq: int, nk: int, sub, causal: bool = False,
                        window: int = 0, grouped: bool = False):
+    """Scores with keys down and queries across, (sk, sq): P^T and dS^T come
+    out as the two products into dV and dK take them (no (sq, sk) tile is
+    ever transposed), and lse / delta stay the (1, sq) rows they arrive as.
+    `qseg_ref` holds the q block's ids as a row, `kseg_ref` the k block's as
+    a column (`_tile_mask`, `keys_down`)."""
     del qidx_ref
     b, jk, jq = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     kv = (lambda h: 0) if grouped else (lambda h: h)
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    sq, sk = sub
 
     @pl.when(jq == 0)
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(live_ref[((b // gpr) * nk + jk) * nq + jq] != 0)
+    bits = live_ref[((b // gpr) * nk + jk) * nq + jq]
+
+    @pl.when(bits != 0)
     def _():
-        mask = _block_mask(qseg_ref, kseg_ref, jq, jk, causal, window)
+        def tile(r0, c0):
+            rows, cols = slice(r0, r0 + sq), slice(c0, c0 + sk)
+            mask = _tile_mask(qseg_ref, kseg_ref, jq, jk, r0, c0, sq, sk,
+                              causal, window, keys_down=True)
+            for h in range(hb):                       # unrolled, as forward
+                q, do = q_ref[h, rows], do_ref[h, rows]
+                k, v = k_ref[kv(h), cols], v_ref[kv(h), cols]
+                s = jax.lax.dot_general(                        # K Q^T
+                    k, q, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                p = jnp.exp(jnp.where(mask, s, MASKED) - lse_ref[h, :, rows])
+                dp = jax.lax.dot_general(                       # V dO^T
+                    v, do, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                ds = p * (dp - delta_ref[h, :, rows]) * scale
+                dv_acc[kv(h), cols] += jax.lax.dot_general(     # P^T dO
+                    p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dk_acc[kv(h), cols] += jax.lax.dot_general(     # dS^T Q
+                    ds.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
 
-        def head(h, carry):
-            p, ds = _packed_p_ds(q_ref[h], k_ref[kv(h)], v_ref[kv(h)],
-                                 do_ref[h], lse_ref[h], delta_ref[h], mask,
-                                 scale)
-            dv_acc[kv(h)] += jax.lax.dot_general(     # P^T dO
-                p, do_ref[h], (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dk_acc[kv(h)] += jax.lax.dot_general(     # dS^T Q
-                ds, q_ref[h], (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return carry
-
-        jax.lax.fori_loop(0, hb, head, 0)
+        _each_live_tile(bits, bq, bk, sub, tile)
 
     @pl.when(jq == nq - 1)
     def _():
@@ -652,30 +797,43 @@ def _packed_dkv_kernel(live_ref, qidx_ref, q_ref, k_ref, v_ref, do_ref,
 def _packed_dq_kernel(live_ref, kidx_ref, q_ref, k_ref, v_ref, do_ref,
                       lse_ref, delta_ref, qseg_ref, kseg_ref, dq_ref, dq_acc,
                       *, scale: float, hb: int, gpr: int, nq: int, nk: int,
-                      causal: bool = False, window: int = 0,
+                      sub, causal: bool = False, window: int = 0,
                       grouped: bool = False):
     del kidx_ref
     b, jq, jk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     kv = (lambda h: 0) if grouped else (lambda h: h)
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    sq, sk = sub
 
     @pl.when(jk == 0)
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    @pl.when(live_ref[((b // gpr) * nq + jq) * nk + jk] != 0)
+    bits = live_ref[((b // gpr) * nq + jq) * nk + jk]
+
+    @pl.when(bits != 0)
     def _():
-        mask = _block_mask(qseg_ref, kseg_ref, jq, jk, causal, window)
+        def tile(r0, c0):
+            rows, cols = slice(r0, r0 + sq), slice(c0, c0 + sk)
+            mask = _tile_mask(qseg_ref, kseg_ref, jq, jk, r0, c0, sq, sk,
+                              causal, window)
+            for h in range(hb):                       # unrolled, as forward
+                k, v = k_ref[kv(h), cols], v_ref[kv(h), cols]
+                lse = lse_ref[h, :, rows][0][:, None]               # (sq, 1)
+                delta = delta_ref[h, :, rows][0][:, None]
+                s = jax.lax.dot_general(
+                    q_ref[h, rows], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                p = jnp.exp(jnp.where(mask, s, MASKED) - lse)
+                dp = jax.lax.dot_general(                       # dO V^T
+                    do_ref[h, rows], v, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                ds = p * (dp - delta) * scale
+                dq_acc[h, rows] += jax.lax.dot_general(         # dS K
+                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
 
-        def head(h, carry):
-            _, ds = _packed_p_ds(q_ref[h], k_ref[kv(h)], v_ref[kv(h)],
-                                 do_ref[h], lse_ref[h], delta_ref[h], mask,
-                                 scale)
-            dq_acc[h] += jax.lax.dot_general(         # dS K
-                ds, k_ref[kv(h)], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return carry
-
-        jax.lax.fori_loop(0, hb, head, 0)
+        _each_live_tile(bits, bq, bk, sub, tile)
 
     @pl.when(jk == nk - 1)
     def _():
@@ -705,16 +863,38 @@ def _segment_tiles(segment_ids):
             jnp.broadcast_to(segment_ids[:, None, :], (r, 8, t)))
 
 
+def _traced_once(fn):
+    """`fn` jitted, every argument but the arrays static. A step traces a
+    layer's forward five times over (the scan's, the custom_vjp's, the
+    remat's), and the bodies, unrolled over sub-tiles and heads, are the dear
+    part of a trace: the jit's cache hands the later traces the first one's
+    jaxpr, and layers of one shape lower to one function. Whether the
+    kernels run interpreted is part of the key."""
+    jitted = jax.jit(fn, static_argnames=(
+        "scale", "bq", "bk", "hb", "heads", "skip", "causal", "window",
+        "grouped", "name", "tiles", "interpret"))
+
+    @functools.wraps(fn)
+    def call(*args, **kwargs):
+        return jitted(*args, interpret=_interpret(), **kwargs)
+    return call
+
+
+@_traced_once
 def _packed_fwd(q, k, v, segment_ids, scale, bq, bk, hb, heads, skip,
-                causal=False, window=0, grouped=False, name="flash_packed"):
+                causal=False, window=0, grouped=False, name="flash_packed",
+                tiles=None, interpret=False):
     """`grouped`: k and v hold ONE head for each grid step's `hb` query
     heads (grouped-query attention: (R * KV, T, Dh) beside q's (R * H, T,
-    Dh), hb = H / KV), read inside the kernel: no repeated K or V exists."""
+    Dh), hb = H / KV), read inside the kernel: no repeated K or V exists.
+    `tiles`: the kernels' sub-tile shapes, a `Tiles`, where not `_sub_tiles`'
+    (the tests and the sweep)."""
     bh, t, dh = q.shape
     nq, nk, gpr = t // bq, t // bk, heads // hb
     hk = 1 if grouped else hb
+    sub = (tiles or _sub_tiles(window, bq, bk)).fwd
     live, kidx, _, _ = packed_block_tables(segment_ids, bq, bk, skip, causal,
-                                           window)
+                                           window, sub)
     qseg, kseg = _segment_tiles(segment_ids)
 
     def at_k(b, i, j, live, kidx):
@@ -724,8 +904,8 @@ def _packed_fwd(q, k, v, segment_ids, scale, bq, bk, hb, heads, skip,
     kspec = pl.BlockSpec((hk, bk, dh), lambda b, i, j, *t: (b, at_k(b, i, j, *t), 0))
     o, lse = pl.pallas_call(
         functools.partial(_packed_fwd_kernel, scale=scale, hb=hb, gpr=gpr,
-                          nq=nq, nk=nk, **_decoder_terms(causal, window,
-                                                         grouped)),
+                          nq=nq, nk=nk, sub=sub,
+                          **_decoder_terms(causal, window, grouped)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh // hb, nq, nk),
@@ -750,21 +930,25 @@ def _packed_fwd(q, k, v, segment_ids, scale, bq, bk, hb, heads, skip,
         ],
         compiler_params=_packed_params(),
         name=f"{name}_fwd",
-        interpret=_interpret(),
+        interpret=interpret,
     )(live, kidx, q, k, v, qseg, kseg)
     return o, lse
 
 
+@_traced_once
 def _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk, hb, heads,
                 skip, causal=False, window=0, grouped=False,
-                name="flash_packed"):
+                name="flash_packed", tiles=None, interpret=False):
     bh, t, dh = q.shape
     nq, nk, gpr = t // bq, t // bk, heads // hb
     hk = 1 if grouped else hb
     terms = _decoder_terms(causal, window, grouped)
-    live_qk, kidx, live_kq, qidx = packed_block_tables(segment_ids, bq, bk,
-                                                       skip, causal, window)
-    qseg, kseg = _segment_tiles(segment_ids)
+    tiles = tiles or _sub_tiles(window, bq, bk)
+    _, _, live_kq, qidx = packed_block_tables(
+        segment_ids, bq, bk, skip, causal, window, tiles.dkv)
+    live_qk, kidx, _, _ = packed_block_tables(
+        segment_ids, bq, bk, skip, causal, window, tiles.dq)
+    seg_cols, seg_rows = _segment_tiles(segment_ids)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None, :]                       # (BH, 1, T)
 
@@ -776,15 +960,16 @@ def _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk, hb, heads,
     row = pl.BlockSpec((hb, 1, bq), lambda b, jk, jq, *t: (b, 0, at_q(b, jk, jq, *t)))
     dk, dv = pl.pallas_call(
         functools.partial(_packed_dkv_kernel, scale=scale, hb=hb, gpr=gpr,
-                          nq=nq, nk=nk, **terms),
+                          nq=nq, nk=nk, sub=tiles.dkv, **terms),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh // hb, nk, nq),
             in_specs=[
                 qspec, kspec, kspec, qspec, row, row,
-                pl.BlockSpec((1, bq, 128),
-                             lambda b, jk, jq, *t: (b // gpr, at_q(b, jk, jq, *t), 0)),
-                pl.BlockSpec((1, 8, bk), lambda b, jk, jq, *_: (b // gpr, 0, jk)),
+                # keys down, queries across: the q ids a row, the k ids a column
+                pl.BlockSpec((1, 8, bq),
+                             lambda b, jk, jq, *t: (b // gpr, 0, at_q(b, jk, jq, *t))),
+                pl.BlockSpec((1, bk, 128), lambda b, jk, jq, *_: (b // gpr, jk, 0)),
             ],
             out_specs=[kspec, kspec],
             scratch_shapes=[pltpu.VMEM((hk, bk, dh), jnp.float32),
@@ -792,8 +977,8 @@ def _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk, hb, heads,
         out_shape=[jax.ShapeDtypeStruct(k.shape, q.dtype)] * 2,
         compiler_params=_packed_params(),
         name=f"{name}_dkv",
-        interpret=_interpret(),
-    )(live_kq, qidx, q, k, v, do, lse, delta, qseg, kseg)
+        interpret=interpret,
+    )(live_kq, qidx, q, k, v, do, lse, delta, seg_rows, seg_cols)
 
     def at_k(b, jq, jk, live, kidx):
         return kidx[((b // gpr) * nq + jq) * nk + jk]
@@ -803,7 +988,7 @@ def _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk, hb, heads,
     row = pl.BlockSpec((hb, 1, bq), lambda b, jq, jk, *_: (b, 0, jq))
     dq = pl.pallas_call(
         functools.partial(_packed_dq_kernel, scale=scale, hb=hb, gpr=gpr,
-                          nq=nq, nk=nk, **terms),
+                          nq=nq, nk=nk, sub=tiles.dq, **terms),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh // hb, nq, nk),
@@ -818,8 +1003,8 @@ def _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk, hb, heads,
         out_shape=jax.ShapeDtypeStruct((bh, t, dh), q.dtype),
         compiler_params=_packed_params(),
         name=f"{name}_dq",
-        interpret=_interpret(),
-    )(live_qk, kidx, q, k, v, do, lse, delta, qseg, kseg)
+        interpret=interpret,
+    )(live_qk, kidx, q, k, v, do, lse, delta, seg_cols, seg_rows)
     return dq, dk, dv
 
 
@@ -855,9 +1040,7 @@ def packed_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     in q/k/v. Padding rows come back zero. `block_q`/`block_k`/`skip` exist
     for the tests (small rows that still span blocks; the every-pair arm)."""
     r, t, h, dh = q.shape
-    bq = min(block_q, _pad_len(t, 128))
-    bk = min(block_k, _pad_len(t, 128))
-    t_pad = _pad_len(t, math.lcm(bq, bk))
+    bq, bk, t_pad = _blocks_for(t, block_q, block_k)
     hb = math.gcd(h, PACKED_HEADS_PER_STEP)
     seg = jnp.pad(segment_ids.astype(jnp.int32), ((0, 0), (0, t_pad - t)))
     qb, kb, vb = (_pad_seq(_to_bh(x), t_pad) for x in (q, k, v))
@@ -878,12 +1061,20 @@ def packed_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # are never repeated in memory. They carry names of their own,
 # `flash_causal_*` and `flash_window_*`: one body, told apart in a trace.
 
-"""Block defaults, not yet swept on the chip: a full layer takes the packed
-kernels' (512, 1024); a sliding layer of window 512 takes (512, 512), where
-a q block meets two k blocks (its own and the one before) and half of what
-they compute is inside the window."""
+"""Block defaults from the sweep that chose the sub-tiles (my chip runs, PR
+33: the Laguna cell's row, kernels alone, ms a call, forward / dK/dV / dQ;
+`tools/sweep_packed_tiles.py`). Full layers: (512, 1024) with `PACKED_TILES`
+3.92 / 4.79 / 4.50; (512, 512) blocks 4.03 / 5.13 / 4.55 at their best tiles;
+(1024, 1024) and (256, 1024) lost in PR 26's bodies already (5.36 / 7.26 /
+6.23 and 6.38 / 6.71 / 5.76 whole). Sliding layers of window 512: until PR
+33 (512, 512) blocks, whole, 5.82 / 4.07 / 3.82 (half of each pair outside
+the window: 2.28 times the pairs needed). With sub-tiles the block is only
+what the pipeline fetches and the tile decides the area, so the wider block
+with fewer steps wins: (512, 1024) blocks with (256, 256) tiles 2.85 / 2.75 /
+2.90 [1.66], with (512, 512) tiles 3.05 / 3.52 / 3.32, whole 3.72 / 4.88 /
+4.38 [3.39]; (512, 512) blocks with (256, 256) tiles 3.02 / 2.95 / 3.04."""
 CAUSAL_BLOCKS = (512, 1024)
-WINDOW_BLOCKS = (512, 512)
+WINDOW_BLOCKS = (512, 1024)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
@@ -930,11 +1121,31 @@ def document_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     kv = k.shape[2]
     assert h % kv == 0, (h, kv)
     dq, dk = WINDOW_BLOCKS if window > 0 else CAUSAL_BLOCKS
-    bq = min(block_q or dq, _pad_len(t, 128))
-    bk = min(block_k or dk, _pad_len(t, 128))
-    t_pad = _pad_len(t, math.lcm(bq, bk))
+    bq, bk, t_pad = _blocks_for(t, block_q or dq, block_k or dk)
     seg = jnp.pad(segment_ids.astype(jnp.int32), ((0, 0), (0, t_pad - t)))
     qb, kb, vb = (_pad_seq(_to_bh(x), t_pad) for x in (q, k, v))
     o = _documents_bh(qb, kb, vb, seg, dh ** -0.5, bq, bk, h // kv, h, skip,
                       int(window))
     return _from_bh(o[:, :t], q.shape)
+
+
+def computed_pairs(segment_ids: jax.Array, causal: bool = False,
+                   window: int = 0) -> jax.Array:
+    """The (query, key) pairs a head that one layer's kernels compute on
+    these rows (R, T), one run of each: the area of the live sub-tiles in
+    the tables the kernels themselves read, a mean over the three kernels
+    weighted by their matmuls (`TILE_MATMULS`). Over the pairs the mask
+    lets through (sum n^2, or a decoder's causal / window pairs) it says how
+    much of what the kernels compute some query sees. float32."""
+    blocks = ((WINDOW_BLOCKS if window > 0 else CAUSAL_BLOCKS) if causal
+              else (PACKED_BLOCK_Q, PACKED_BLOCK_K))
+    bq, bk, t_pad = _blocks_for(segment_ids.shape[1], *blocks)
+    seg = jnp.pad(segment_ids.astype(jnp.int32),
+                  ((0, 0), (0, t_pad - segment_ids.shape[1])))
+    total = 0.0
+    for (sq, sk), matmuls in zip(_sub_tiles(window, bq, bk), TILE_MATMULS):
+        bits = packed_block_tables(seg, bq, bk, True, causal, window,
+                                   (sq, sk))[0]
+        live = jnp.sum(jax.lax.population_count(bits)).astype(jnp.float32)
+        total += matmuls * sq * sk * live
+    return total / sum(TILE_MATMULS)

@@ -38,6 +38,15 @@ REQUIRED_STEP_KEYS = (
     "mem_used_bytes",
 )
 
+# (record key, counter of pairs the mask lets through, counter of pairs the
+# attention kernels compute for them): how much of the kernels' work some
+# query sees, 1.0 at best (vitax/ops/flash_blocked.py: computed_pairs)
+COMPUTED_OVER_NEEDED = (
+    ("attn_computed_over_needed", "token_pairs", "computed_pairs"),
+    ("causal_computed_over_needed", "causal_pairs", "causal_computed_pairs"),
+    ("window_computed_over_needed", "window_pairs", "window_computed_pairs"),
+)
+
 
 class Recorder:
     """Fan structured records out to sinks; owns the run's MFU constants.
@@ -82,7 +91,11 @@ class Recorder:
         `packed_counts`: a packed step's own counters (`tokens`,
         `padding_tokens`, `images`, `token_pairs`; vitax/train/step.py) —
         throughput, MFU and `padding_frac` then come from what the batch
-        held, not from `batch_size x num_patches`. A decoder step's
+        held, not from `batch_size x num_patches`. Where the step also
+        counted the score pairs its attention kernels compute
+        (`computed_pairs`, or a decoder's `causal_` / `window_computed_pairs`),
+        the record holds computed / needed: `attn_computed_over_needed`, or
+        `causal_` / `window_computed_over_needed`. A decoder step's
         (`targets`, `causal_pairs`, `window_pairs`, `expert_slots_here` in
         place of `token_pairs`; `images` are documents) are written into the
         record as they are, with `expert_load`, its per-layer per-expert
@@ -123,6 +136,10 @@ class Recorder:
             slots = tokens + packed_counts["padding_tokens"]
             record["padding_frac"] = (packed_counts["padding_tokens"] / slots
                                       if slots else 0.0)
+        for key, needed, computed in COMPUTED_OVER_NEEDED:
+            if packed_counts and packed_counts.get(needed) and (
+                    computed in packed_counts):
+                record[key] = packed_counts[computed] / packed_counts[needed]
         if packed_counts is not None and self.cfg.decoder:
             record.update({k: packed_counts[k] for k in (
                 "targets", "causal_pairs", "window_pairs",

@@ -90,7 +90,10 @@ def _attention_remat_note(cfg: Config, model, mesh) -> str:
 # a decoder step's own counters (vitax/train/step.py: decoder_counts), as its
 # step record carries them
 DECODER_COUNTERS = ("tokens", "padding_tokens", "images", "targets",
-                    "causal_pairs", "window_pairs", "expert_slots_here")
+                    "causal_pairs", "window_pairs", "causal_computed_pairs",
+                    "window_computed_pairs", "expert_slots_here")
+PACKED_COUNTERS = ("tokens", "padding_tokens", "images", "token_pairs",
+                   "computed_pairs")
 
 
 def train(cfg: Config) -> TrainState:
@@ -691,8 +694,7 @@ def _run_epochs(cfg, state, train_step, train_loader, val_loader, eval_step,
                         packed_counts=(
                             {k: float(jax.device_get(metrics[k])) for k in
                              (DECODER_COUNTERS if cfg.decoder else
-                              ("tokens", "padding_tokens", "images",
-                               "token_pairs"))} if cfg.packed else None),
+                              PACKED_COUNTERS)} if cfg.packed else None),
                         expert_load=(
                             jax.device_get(
                                 metrics["expert_load"]).tolist()

@@ -18,6 +18,7 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from vitax.config import Config
+from vitax.ops.flash_blocked import computed_pairs
 from vitax.ops.fused_optimizer import fused_clip_adamw, fused_optimizer_active
 from vitax.parallel.mesh import BATCH_AXES, Mesh, batch_pspec
 from vitax.parallel.sharding import (
@@ -183,7 +184,9 @@ def decoder_counts(cfg: Config, batch: Dict[str, jax.Array]
     segment ids: `tokens` valid, `padding_tokens`, `images` (documents: the
     step's samples), `targets`, and the attention's useful work as (query,
     key) pairs a layer: `causal_pairs` = sum n (n + 1) / 2 over documents,
-    `window_pairs` = the same with at most `window_tokens` keys a query."""
+    `window_pairs` = the same with at most `window_tokens` keys a query;
+    and beside each what the kernels compute for it, `causal_computed_pairs`
+    / `window_computed_pairs` (vitax/ops/flash_blocked.py: computed_pairs)."""
     seg = batch["segment_ids"]
     n = jnp.sum(seg[..., None] == jnp.arange(1, cfg.pack_images + 1),
                 axis=1, dtype=jnp.int32).astype(jnp.float32)      # (R, S)
@@ -194,7 +197,10 @@ def decoder_counts(cfg: Config, batch: Dict[str, jax.Array]
         tokens=valid, padding_tokens=seg.size - valid, images=documents,
         targets=valid - documents,
         causal_pairs=jnp.sum(n * (n + 1) / 2),
-        window_pairs=jnp.sum(w * (w + 1) / 2 + (n - w) * w))
+        window_pairs=jnp.sum(w * (w + 1) / 2 + (n - w) * w),
+        causal_computed_pairs=computed_pairs(seg, causal=True),
+        window_computed_pairs=computed_pairs(seg, causal=True,
+                                             window=cfg.window_tokens))
 
 
 def _microbatch_split(batch: PyTree, k_steps: int, mesh: Mesh) -> PyTree:
@@ -554,8 +560,10 @@ def make_train_step(
             metrics.update(
                 tokens=valid, padding_tokens=seg.size - valid,
                 images=jnp.sum(batch["label_mask"] > 0, dtype=jnp.int32),
-                # sum of n_i^2: the attention's useful work (telemetry MFU)
-                token_pairs=jnp.sum(jnp.square(per_image.astype(jnp.float32))))
+                # sum of n_i^2: the attention's useful work (telemetry MFU),
+                # and the score pairs the packed kernels compute for it
+                token_pairs=jnp.sum(jnp.square(per_image.astype(jnp.float32))),
+                computed_pairs=computed_pairs(seg))
         return new_state, metrics
 
     jitted = jax.jit(
