@@ -116,27 +116,33 @@ def test_packed_attention_forward_and_vjp_compile(chip, mosaic):
         assert any(name in k for k in kernels), kernels
 
 
-@pytest.mark.parametrize("heads,window", [(48, 0), (64, 512)],
-                         ids=["full_48q_8kv", "window512_64q_8kv"])
-def test_document_attention_forward_and_vjp_compile(chip, mosaic, heads,
-                                                    window):
-    """The decoder's kernels at the Laguna-XS.2 cell's shapes: one row of
+@pytest.mark.parametrize(
+    "tokens,heads,head_size,window,scale",
+    [(8192, 48, 128, 0, 0.0), (8192, 64, 128, 512, 0.0),
+     (4096, 32, 64, 0, 0.015625)],
+    ids=["full_48q_8kv", "window512_64q_8kv", "nope_32q_8kv_dh64_scale"])
+def test_document_attention_forward_and_vjp_compile(chip, mosaic, tokens,
+                                                    heads, head_size, window,
+                                                    scale):
+    """The decoder's kernels at the Laguna-XS.2 cell's shapes (one row of
     8,192 tokens, head dim 128, 6 or 8 query heads a key/value head read
-    inside the kernel, causal and window terms in mask and block table."""
+    inside the kernel, causal and window terms in mask and block table) and
+    at the hybrid cell's (4,096 tokens, 4 query heads a key/value head of
+    64, a score scale of its own)."""
     from vitax.ops.flash_blocked import document_flash_attention
     one_chip, _ = chip
 
     def fwd_bwd(q, k, v, segment_ids):
         o, vjp = jax.vjp(
-            lambda q, k, v: document_flash_attention(q, k, v, segment_ids,
-                                                     window), q, k, v)
+            lambda q, k, v: document_flash_attention(
+                q, k, v, segment_ids, window, scale=scale), q, k, v)
         return o, vjp(o)
 
-    q = jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.bfloat16,
+    q = jax.ShapeDtypeStruct((1, tokens, heads, head_size), jnp.bfloat16,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16,
+    kv = jax.ShapeDtypeStruct((1, tokens, 8, head_size), jnp.bfloat16,
                               sharding=one_chip)
-    seg = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((1, tokens), jnp.int32, sharding=one_chip)
     compiled = jax.jit(fwd_bwd).lower(q, kv, kv, seg).compile()
     kernels = _kernel_names(compiled)
     name = "flash_window" if window else "flash_causal"

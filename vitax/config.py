@@ -87,8 +87,8 @@ class Config:
     vocab_rows: int = 0                 # rows of the embedding and of the untied head held here
     kv_heads: int = 0                   # key/value heads, each serving layer_heads[i] / kv_heads query heads
     head_size: int = 0                  # width of one head (not embed_dim / heads: q is heads * head_size wide)
-    layer_kinds: Tuple[str, ...] = ()   # per layer: "full_attention" | "sliding_attention"
-    layer_heads: Tuple[int, ...] = ()   # per layer: query heads
+    layer_kinds: Tuple[str, ...] = ()   # per layer: "full_attention" (or "attention") | "sliding_attention" | "mamba"
+    layer_heads: Tuple[int, ...] = ()   # per layer: query heads (0 in a mamba layer, which has none)
     layer_mlps: Tuple[str, ...] = ()    # per layer: "dense" | "sparse"
     window_tokens: int = 0              # keys a sliding layer's query sees, its own position included
     ffn_dim: int = 0                    # width of a dense layer's SwiGLU
@@ -110,6 +110,22 @@ class Config:
     yarn_attn_factor: float = 1.0       # ... with this factor on cos and sin
     rope_theta_window: float = 10000.0  # sliding layers: plain RoPE base ...
     rope_fraction_window: float = 1.0   # ... and rotated share
+    position_embedding: str = "rope"    # "rope" | "nope": nope rotates nothing, in any layer
+    tie_embeddings: bool = False        # the head is the embedding table itself (no lm_head leaf)
+    embedding_multiplier: float = 1.0   # factor on the embedded tokens
+    residual_multiplier: float = 1.0    # factor on what each half of a layer adds to the stream
+    attention_multiplier: float = 0.0   # factor on the attention scores; 0 = head_size ** -0.5
+    logits_scaling: float = 1.0         # the logits are divided by this
+    # A "mamba" layer's mixer (Mamba-2 in its chunked dual form, SSD;
+    # vitax/models/ssm.py): ssm_heads heads of ssm_head_size carry a state of
+    # ssm_state_size each, B and C shared by the heads of one of ssm_groups
+    # groups, behind a depthwise causal convolution of ssm_conv_width taps
+    ssm_heads: int = 0
+    ssm_head_size: int = 0
+    ssm_state_size: int = 0
+    ssm_conv_width: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 0                  # tokens a chunk of the scan; pack_tokens is a multiple of it
     pos_dropout: float = 0.0
     # NOTE: att_dropout > 0 stays on the fused kernels — every attention path
     # (whole-N, streamed, ring/ulysses sp, and their pipeline bodies at tp=1)
@@ -423,17 +439,43 @@ class Config:
             f"--layer_kinds, --layer_heads and --layer_mlps need one entry "
             f"for each of the {n} layers, got {len(self.layer_kinds)}, "
             f"{len(self.layer_heads)} and {len(self.layer_mlps)}")
-        assert set(self.layer_kinds) <= {"full_attention",
-                                         "sliding_attention"}, self.layer_kinds
+        assert set(self.layer_kinds) <= {
+            "full_attention", "attention", "sliding_attention",
+            "mamba"}, self.layer_kinds
         assert set(self.layer_mlps) <= {"dense", "sparse"}, self.layer_mlps
         assert self.vocab_rows >= 2 and self.head_size >= 2, (
             f"--vocab_rows {self.vocab_rows} and --head_size "
             f"{self.head_size} must be set")
         assert self.kv_heads >= 1 and all(
             h >= self.kv_heads and h % self.kv_heads == 0
-            for h in self.layer_heads), (
+            for h, kind in zip(self.layer_heads, self.layer_kinds)
+            if kind != "mamba"), (
             f"every layer's query heads {self.layer_heads} must be a "
             f"multiple of --kv_heads {self.kv_heads}")
+        assert self.position_embedding in ("rope", "nope"), (
+            f"unknown --position_embedding {self.position_embedding!r} "
+            f"(expected 'rope' or 'nope')")
+        assert (self.embedding_multiplier > 0 and self.residual_multiplier > 0
+                and self.attention_multiplier >= 0
+                and self.logits_scaling > 0), (
+            "--embedding_multiplier, --residual_multiplier and "
+            "--logits_scaling must be > 0, --attention_multiplier >= 0 (0 = "
+            "head_size ** -0.5)")
+        if "mamba" in self.layer_kinds:
+            assert (self.ssm_heads >= 1 and self.ssm_head_size >= 1
+                    and self.ssm_state_size >= 1
+                    and self.ssm_conv_width >= 1), (
+                "a mamba layer needs --ssm_heads, --ssm_head_size, "
+                "--ssm_state_size and --ssm_conv_width")
+            assert (self.ssm_groups >= 1
+                    and self.ssm_heads % self.ssm_groups == 0), (
+                f"--ssm_heads {self.ssm_heads} must be a multiple of "
+                f"--ssm_groups {self.ssm_groups}")
+            assert (self.ssm_chunk >= 1
+                    and self.pack_tokens % self.ssm_chunk == 0), (
+                f"--pack_tokens {self.pack_tokens} must be a multiple of "
+                f"--ssm_chunk {self.ssm_chunk}, the tokens a chunk of the "
+                f"scan")
         for name in ("rope_fraction_full", "rope_fraction_window"):
             rot = self.head_size * getattr(self, name)
             assert 0 < rot <= self.head_size and rot == int(rot) \
@@ -866,8 +908,9 @@ def build_parser() -> argparse.ArgumentParser:
             ("vocab_rows", int, 0, "vocabulary rows held here"),
             ("kv_heads", int, 0, "key/value heads"),
             ("head_size", int, 0, "width of one head"),
-            ("layer_kinds", str, "", "full_attention|sliding_attention a layer"),
-            ("layer_heads", str, "", "query heads a layer"),
+            ("layer_kinds", str, "",
+             "full_attention (or attention)|sliding_attention|mamba a layer"),
+            ("layer_heads", str, "", "query heads a layer (0 in a mamba one)"),
             ("layer_mlps", str, "", "dense|sparse a layer"),
             ("window_tokens", int, 0, "keys a sliding layer's query sees"),
             ("ffn_dim", int, 0, "dense SwiGLU width"),
@@ -887,10 +930,28 @@ def build_parser() -> argparse.ArgumentParser:
             ("yarn_beta_slow", float, 1.0, "YaRN slow rotation count"),
             ("yarn_attn_factor", float, 1.0, "YaRN factor on cos and sin"),
             ("rope_theta_window", float, 10000.0, "sliding layers' RoPE base"),
-            ("rope_fraction_window", float, 1.0, "rotated share of a head")):
+            ("rope_fraction_window", float, 1.0, "rotated share of a head"),
+            ("embedding_multiplier", float, 1.0, "factor on the embedded tokens"),
+            ("residual_multiplier", float, 1.0,
+             "factor on what each half of a layer adds to the stream"),
+            ("attention_multiplier", float, 0.0,
+             "factor on the attention scores (0 = head_size ** -0.5)"),
+            ("logits_scaling", float, 1.0, "the logits are divided by this"),
+            ("ssm_heads", int, 0, "heads of a mamba layer's mixer"),
+            ("ssm_head_size", int, 0, "width of one of them"),
+            ("ssm_state_size", int, 0, "state a head carries, a channel"),
+            ("ssm_conv_width", int, 0, "taps of the mixer's causal convolution"),
+            ("ssm_groups", int, 1, "groups of heads that share B and C"),
+            ("ssm_chunk", int, 0, "tokens a chunk of the mixer's scan")):
         dec.add_argument(f"--{name}", type=kind, default=default, help=text)
+    dec.add_argument("--position_embedding", type=str, default="rope",
+                     choices=("rope", "nope"),
+                     help="nope: attention rotates nothing, in any layer")
     dec.add_argument("--head_gate", action="store_true", dest="head_gate",
                      help="sigmoid gate on the attention output, a head")
+    dec.add_argument("--tie_embeddings", action="store_true",
+                     dest="tie_embeddings",
+                     help="the head is the embedding table itself")
     parser.add_argument("--pos_dropout", type=float, default=0.0)
     parser.add_argument("--att_dropout", type=float, default=0.0)
     parser.add_argument("--mlp_dropout", type=float, default=0.0)

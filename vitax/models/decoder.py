@@ -9,7 +9,7 @@ The second model family (`Config.model_family == "decoder"`), built by
 
     h = embedding[tokens]
     each layer:  h += W_o[ g * Attn(RMSNorm(h)) ];  h += F(RMSNorm(h))
-    logits = RMSNorm(h) @ head            (untied; float32 logits)
+    logits = RMSNorm(h) @ head            (float32 logits)
 
 - Attn: `layer_heads[i]` query heads and `kv_heads` key/value heads of
   `head_size`, each key/value head serving heads / kv_heads query heads; RoPE
@@ -21,6 +21,13 @@ The second model family (`Config.model_family == "decoder"`), built by
   (`head_gate`). No biases, no q/k normalisation.
 - F: a SwiGLU of `ffn_dim` in a `dense` layer, the routed and shared experts
   of vitax/models/experts.py in a `sparse` one.
+- A `mamba` layer has the state-space mixer of vitax/models/ssm.py in place
+  of W_o[g * Attn]. `attention` is another word for `full_attention`.
+- What a model may state beside its layers: `position_embedding` nope (no
+  layer rotates anything), `attention_multiplier` on the scores in place of
+  head_size ** -0.5, `embedding_multiplier` on the embedded tokens,
+  `residual_multiplier` on what each half of a layer adds, logits divided by
+  `logits_scaling`, and `tie_embeddings` (the head is the embedding table).
 
 Layers differ in shape (heads by kind, dense or sparse), so one stacked
 `lax.scan` cannot hold them: consecutive layers of one shape form a RUN, each
@@ -43,9 +50,11 @@ import numpy as np
 
 from vitax.config import Config
 from vitax.models.experts import SharedRoutedExperts, SwiGLU, Table
+from vitax.models.ssm import MixerShape, SSDMixer, mixer_param_count
 from vitax.models.vit import Array, Dtype, default_init
 
 SLIDING = "sliding_attention"
+MAMBA = "mamba"
 
 
 # --- rotary position embedding (pure functions) -----------------------------
@@ -97,16 +106,18 @@ def apply_rope(x: Array, cos: Array, sin: Array) -> Array:
 
 
 def causal_masked_attention(q: Array, k: Array, v: Array, segment_ids: Array,
-                            window: int, dtype: Dtype) -> Array:
+                            window: int, dtype: Dtype,
+                            scale: float = 0.0) -> Array:
     """The dense fallback: q (R, T, H, Dh), k and v (R, T, KV, Dh), each
     key/value head serving H / KV query heads; a key is visible from a query
     of its own document, not before it and (window > 0) fewer than `window`
-    positions back. Padding comes back zero."""
+    positions back; scores times `scale` (0 = Dh ** -0.5). Padding comes back
+    zero."""
     r, t, h, dh = q.shape
     kv = k.shape[2]
     qg = q.reshape(r, t, kv, h // kv, dh)
     s = jnp.einsum("rtkgd,rskd->rkgts", qg, k,
-                   preferred_element_type=jnp.float32) * dh ** -0.5
+                   preferred_element_type=jnp.float32) * (scale or dh ** -0.5)
     at = jnp.arange(t)
     back = at[:, None] - at[None, :]                       # query - key
     seg = segment_ids
@@ -164,22 +175,25 @@ class DecoderAttention(nn.Module):
     head_gate: bool
     dtype: Dtype = jnp.bfloat16
     attention_impl: Optional[Callable] = None
+    scale: float = 0.0              # on the scores; 0 = head_size ** -0.5
 
     @nn.compact
     def __call__(self, x: Array, segment_ids: Array,
-                 rope: Tuple[Array, Array]) -> Array:
+                 rope: Optional[Tuple[Array, Array]]) -> Array:
         r, t, d = x.shape
         h, kv, dh = self.heads, self.kv_heads, self.head_size
         q = _linear(h * dh, self.dtype, "wq")(x).reshape(r, t, h, dh)
         k = _linear(kv * dh, self.dtype, "wk")(x).reshape(r, t, kv, dh)
         v = _linear(kv * dh, self.dtype, "wv")(x).reshape(r, t, kv, dh)
-        with jax.named_scope("rope1d"):
-            q, k = apply_rope(q, *rope), apply_rope(k, *rope)
+        if rope is not None:
+            with jax.named_scope("rope1d"):
+                q, k = apply_rope(q, *rope), apply_rope(k, *rope)
         if self.attention_impl is None:
             out = causal_masked_attention(q, k, v, segment_ids, self.window,
-                                          self.dtype)
+                                          self.dtype, self.scale)
         else:
-            out = self.attention_impl(q, k, v, segment_ids, self.window)
+            out = self.attention_impl(q, k, v, segment_ids, self.window,
+                                      self.scale)
         if self.head_gate:
             with jax.named_scope("head_gate"):
                 gate = jax.nn.sigmoid(_linear(h, self.dtype, "head_gate")(
@@ -208,21 +222,35 @@ class DecoderBlock(nn.Module):
     dtype: Dtype = jnp.bfloat16
     attention_impl: Optional[Callable] = None
     token_sharding: Optional[Any] = None
+    attention_scale: float = 0.0
+    residual_multiplier: float = 1.0
+    mixer: Optional[MixerShape] = None      # a mamba layer's
+
+    def _added(self, y: Array) -> Array:
+        if self.residual_multiplier == 1.0:
+            return y
+        return y * jnp.asarray(self.residual_multiplier, y.dtype)
 
     @nn.compact
-    def __call__(self, x: Array, segment_ids: Array, rope_full, rope_window):
+    def __call__(self, x: Array, segment_ids: Array, rope_full=None,
+                 rope_window=None):
         kind, heads, mlp = self.shape
         sliding = kind == SLIDING
         if self.token_sharding is not None:
             x = jax.lax.with_sharding_constraint(x, self.token_sharding)
         y = RMSNorm(self.norm_eps, self.dtype, name="norm1")(x)
-        y = DecoderAttention(
-            heads=heads, kv_heads=self.kv_heads, head_size=self.head_size,
-            window=self.window_tokens if sliding else 0,
-            head_gate=self.head_gate, dtype=self.dtype,
-            attention_impl=self.attention_impl, name="attn",
-        )(y, segment_ids, rope_window if sliding else rope_full)
-        x = x + y
+        if kind == MAMBA:
+            y = SSDMixer(self.mixer, self.norm_eps, self.dtype,
+                         name="mixer")(y, segment_ids)
+        else:
+            y = DecoderAttention(
+                heads=heads, kv_heads=self.kv_heads, head_size=self.head_size,
+                window=self.window_tokens if sliding else 0,
+                head_gate=self.head_gate, dtype=self.dtype,
+                attention_impl=self.attention_impl,
+                scale=self.attention_scale, name="attn",
+            )(y, segment_ids, rope_window if sliding else rope_full)
+        x = x + self._added(y)
         y = RMSNorm(self.norm_eps, self.dtype, name="norm2")(x)
         if mlp == "dense":
             y = SwiGLU(self.ffn_dim, x.shape[-1], dtype=self.dtype,
@@ -236,7 +264,7 @@ class DecoderBlock(nn.Module):
                 expert_dim=self.expert_dim, shared_dim=self.shared_expert_dim,
                 routed_scale=self.routed_scale, dtype=self.dtype, name="moe",
             )(y, segment_ids > 0)
-        return x + y
+        return x + self._added(y)
 
 
 class Run(nn.Module):
@@ -307,6 +335,13 @@ class Decoder(nn.Module):
     remat_policy: str = "none_saveable"
     attention_impl: Optional[Callable] = None
     token_sharding: Optional[Any] = None
+    rope: bool = True               # False: no layer rotates anything (NoPE)
+    tie_embeddings: bool = False
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_scale: float = 0.0    # 0 = head_size ** -0.5
+    logits_scaling: float = 1.0
+    mixer: Optional[MixerShape] = None
 
     def runs(self) -> List[Tuple[Tuple[str, int, str], int]]:
         return layer_runs(self.layer_kinds, self.layer_heads, self.layer_mlps)
@@ -335,12 +370,16 @@ class Decoder(nn.Module):
         table = Table((self.vocab_rows, self.embed_dim), "embedding",
                       name="embed")()
         x = jnp.take(table.astype(self.dtype), batch["tokens"], axis=0)
+        if self.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(self.embedding_multiplier, self.dtype)
         # a padding token carries nothing (and never meets a real one)
         x = jnp.where((seg > 0)[..., None], x, jnp.zeros((), self.dtype))
         if self.token_sharding is not None:
             x = jax.lax.with_sharding_constraint(x, self.token_sharding)
-        with jax.named_scope("rope1d"):
-            rope_full, rope_window = self._rope(batch["positions"])
+        ropes = ()
+        if self.rope:
+            with jax.named_scope("rope1d"):
+                ropes = self._rope(batch["positions"])
 
         block_kwargs = dict(
             kv_heads=self.kv_heads, head_size=self.head_size,
@@ -353,7 +392,9 @@ class Decoder(nn.Module):
             experts_per_token=self.experts_per_token,
             routed_scale=self.routed_scale, dtype=self.dtype,
             attention_impl=self.attention_impl,
-            token_sharding=self.token_sharding)
+            token_sharding=self.token_sharding,
+            attention_scale=self.attention_scale,
+            residual_multiplier=self.residual_multiplier, mixer=self.mixer)
         for i, (shape, length) in enumerate(self.runs()):
             x = Run(length=length,
                     block_kwargs=tuple({**block_kwargs,
@@ -361,13 +402,21 @@ class Decoder(nn.Module):
                     scan_blocks=self.scan_blocks,
                     scan_unroll=self.scan_unroll, remat=self.grad_ckpt,
                     policy=run_remat_policy(self, shape[0]),
-                    name=f"run{i}")(x, seg, rope_full, rope_window)
+                    name=f"run{i}")(x, seg, *ropes)
 
         x = RMSNorm(self.norm_eps, self.dtype, name="norm")(x)
         with jax.named_scope("lm_head_loss"):
-            head = Table((self.embed_dim, self.vocab_rows), name="lm_head")()
-            return jnp.einsum("rtd,dv->rtv", x, head.astype(self.dtype),
-                              preferred_element_type=jnp.float32)
+            if self.tie_embeddings:
+                logits = jnp.einsum("rtd,vd->rtv", x, table.astype(self.dtype),
+                                    preferred_element_type=jnp.float32)
+            else:
+                head = Table((self.embed_dim, self.vocab_rows),
+                             name="lm_head")()
+                logits = jnp.einsum("rtd,dv->rtv", x, head.astype(self.dtype),
+                                    preferred_element_type=jnp.float32)
+            if self.logits_scaling != 1.0:
+                logits = logits / self.logits_scaling
+            return logits
 
 
 # --- what per-block remat keeps of the attention kernels --------------------
@@ -384,9 +433,10 @@ def _decoder_attention_saveable(prim, *_, **params):
 def keeps_attention_residuals(model: Decoder, kind: str) -> bool:
     """PR 30's rule (vitax/models/vit.py: keeps_attention_residuals) by the
     span of a run's layers: a full layer's query meets a whole row, a sliding
-    layer's at most `window_tokens` keys."""
+    layer's at most `window_tokens` keys. A mamba layer has no attention
+    kernel to keep anything of."""
     from vitax.models.vit import keeps_attention_residuals as rule
-    return rule(model, span=model.span(kind))
+    return kind != MAMBA and rule(model, span=model.span(kind))
 
 
 def run_remat_policy(model: Decoder, kind: str):
@@ -417,7 +467,23 @@ def build_decoder(cfg: Config, attention_impl: Optional[Callable] = None,
         dtype=jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32,
         scan_blocks=cfg.scan_blocks, scan_unroll=cfg.scan_unroll,
         grad_ckpt=cfg.grad_ckpt, remat_policy=cfg.remat_policy,
-        attention_impl=attention_impl, token_sharding=token_sharding)
+        attention_impl=attention_impl, token_sharding=token_sharding,
+        rope=cfg.position_embedding == "rope",
+        tie_embeddings=cfg.tie_embeddings,
+        embedding_multiplier=cfg.embedding_multiplier,
+        residual_multiplier=cfg.residual_multiplier,
+        attention_scale=cfg.attention_multiplier,
+        logits_scaling=cfg.logits_scaling, mixer=mixer_shape(cfg))
+
+
+def mixer_shape(cfg: Config) -> Optional[MixerShape]:
+    """The shape of the mamba layers' mixer, None in a model that has none."""
+    if MAMBA not in cfg.layer_kinds:
+        return None
+    return MixerShape(
+        heads=cfg.ssm_heads, head_size=cfg.ssm_head_size,
+        state_size=cfg.ssm_state_size, conv_width=cfg.ssm_conv_width,
+        groups=cfg.ssm_groups, chunk=cfg.ssm_chunk)
 
 
 def sample_documents(cfg: Config, batch: int):
@@ -429,10 +495,16 @@ def sample_documents(cfg: Config, batch: int):
 def expected_param_count(cfg: Config) -> int:
     """Closed-form parameter count of what this chip holds."""
     d, dh = cfg.embed_dim, cfg.head_size
-    total = 2 * cfg.vocab_rows * d + d           # embedding, head, final norm
-    for heads, mlp in zip(cfg.layer_heads, cfg.layer_mlps):
-        total += 2 * d + 2 * d * heads * dh + 2 * d * cfg.kv_heads * dh
-        total += d * heads if cfg.head_gate else 0
+    # embedding, head (the same table when tied), final norm
+    total = (1 if cfg.tie_embeddings else 2) * cfg.vocab_rows * d + d
+    for kind, heads, mlp in zip(cfg.layer_kinds, cfg.layer_heads,
+                                cfg.layer_mlps):
+        total += 2 * d                                      # the two norms
+        if kind == MAMBA:
+            total += mixer_param_count(mixer_shape(cfg), d)
+        else:
+            total += 2 * d * heads * dh + 2 * d * cfg.kv_heads * dh
+            total += d * heads if cfg.head_gate else 0
         if mlp == "dense":
             total += 3 * d * cfg.ffn_dim
         else:

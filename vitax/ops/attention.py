@@ -999,8 +999,9 @@ def _packed_impl(cfg, mesh: Optional[Mesh], force: bool):
 
 
 def _decoder_impl(cfg, mesh: Optional[Mesh], force: bool):
-    """The token decoder's core, `impl(q, k, v, segment_ids, window)` with a
-    static `window` (0 = a full layer): the packed kernels with their causal,
+    """The token decoder's core, `impl(q, k, v, segment_ids, window, scale)`,
+    `window` (0 = a full layer) and `scale` (on the scores; 0 = Dh ** -0.5)
+    static: the packed kernels with their causal,
     window and grouped-KV terms (vitax/ops/flash_blocked.py:
     document_flash_attention), chosen and wrapped as `_packed_impl` does.
     None -> the model's dense masked path."""
@@ -1014,8 +1015,9 @@ def _decoder_impl(cfg, mesh: Optional[Mesh], force: bool):
     if sharded:
         name += " + shard_map"
 
-    def impl(q, k, v, segment_ids, window):
-        kernel = functools.partial(document_flash_attention, window=window)
+    def impl(q, k, v, segment_ids, window, scale=0.0):
+        kernel = functools.partial(document_flash_attention, window=window,
+                                   scale=scale)
         if sharded:
             spec = P(BATCH_AXES, None, None, None)
             kernel = shard_map(

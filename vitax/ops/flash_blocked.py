@@ -1110,13 +1110,13 @@ _documents_bh.defvjp(_documents_bh_fwd, _documents_bh_bwd)
 def document_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                              segment_ids: jax.Array, window: int = 0,
                              block_q: int = 0, block_k: int = 0,
-                             skip: bool = True) -> jax.Array:
+                             skip: bool = True, scale: float = 0.) -> jax.Array:
     """Causal attention within each document of a packed row: q (R, T, H, Dh),
     k and v (R, T, KV, Dh) with H a multiple of KV, (R, T) int32 segment ids
     (0 = padding) -> (R, T, H, Dh), differentiable in q/k/v. `window` > 0: a
     query sees the `window` latest keys of its document, itself included.
-    Padding rows come back zero. `block_q`/`block_k`/`skip` exist for the
-    tests."""
+    Scores times `scale` (0 = Dh ** -0.5). Padding rows come back zero.
+    `block_q`/`block_k`/`skip` exist for the tests."""
     r, t, h, dh = q.shape
     kv = k.shape[2]
     assert h % kv == 0, (h, kv)
@@ -1124,8 +1124,8 @@ def document_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     bq, bk, t_pad = _blocks_for(t, block_q or dq, block_k or dk)
     seg = jnp.pad(segment_ids.astype(jnp.int32), ((0, 0), (0, t_pad - t)))
     qb, kb, vb = (_pad_seq(_to_bh(x), t_pad) for x in (q, k, v))
-    o = _documents_bh(qb, kb, vb, seg, dh ** -0.5, bq, bk, h // kv, h, skip,
-                      int(window))
+    o = _documents_bh(qb, kb, vb, seg, float(scale) or dh ** -0.5, bq, bk,
+                      h // kv, h, skip, int(window))
     return _from_bh(o[:, :t], q.shape)
 
 

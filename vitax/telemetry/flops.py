@@ -98,7 +98,8 @@ def packed_flops_per_step(cfg, tokens: float, token_pairs: float,
 
 def decoder_flops_per_step(cfg, tokens: float, targets: float,
                            causal_pairs: float, window_pairs: float,
-                           expert_slots: float) -> float:
+                           expert_slots: float,
+                           ssd_pairs: float = 0.0) -> float:
     """Useful matmul FLOPs of one step of the token decoder
     (vitax/models/decoder.py), fwd+bwd (3x forward), from the step's own
     counters (vitax/train/step.py: decoder_counts): `tokens` valid (the
@@ -106,21 +107,35 @@ def decoder_flops_per_step(cfg, tokens: float, targets: float,
     and shared expert), the (query, key) pairs a full or a sliding layer's
     mask leaves (QK^T and PV), `expert_slots` = the (token, choice) slots
     routed to an expert held here, over all sparse layers (the routed
-    experts), and `targets` (the head). Padding, the masked part of a block
-    and sorted rows no held expert owns are not counted."""
+    experts), and `targets` (the head). A mamba layer (vitax/models/ssm.py):
+    its two projections by `tokens`, its scan by `ssd_pairs` (C.B and the
+    masked product over x, a pair of one chunk and one document) and by
+    `tokens` (the state a chunk leaves and the state a token reads). Padding,
+    the masked part of a block and sorted rows no held expert owns are not
+    counted."""
     d, dh = cfg.embed_dim, cfg.head_size
+    inner, gn = cfg.ssm_heads * cfg.ssm_head_size, \
+        cfg.ssm_groups * cfg.ssm_state_size
     fwd = 0.0
     for kind, heads, mlp in zip(cfg.layer_kinds, cfg.layer_heads,
                                 cfg.layer_mlps):
-        per_token = 2 * (2 * d * heads * dh + 2 * d * cfg.kv_heads * dh)
-        per_token += 2 * d * heads if cfg.head_gate else 0
+        if kind == "mamba":
+            per_token = 2 * d * (2 * inner + 2 * gn + cfg.ssm_heads)
+            per_token += 2 * inner * d
+            per_token += 4 * inner * cfg.ssm_state_size
+            fwd += 2 * (gn + inner) * ssd_pairs
+        else:
+            per_token = 2 * (2 * d * heads * dh + 2 * d * cfg.kv_heads * dh)
+            per_token += 2 * d * heads if cfg.head_gate else 0
+            pairs = (window_pairs if kind == "sliding_attention"
+                     else causal_pairs)
+            fwd += 2 * 2 * pairs * heads * dh
         if mlp == "dense":
             per_token += 2 * 3 * d * cfg.ffn_dim
         else:
             per_token += 2 * d * cfg.experts_routed
             per_token += 2 * 3 * d * cfg.shared_expert_dim
-        pairs = window_pairs if kind == "sliding_attention" else causal_pairs
-        fwd += per_token * tokens + 2 * 2 * pairs * heads * dh
+        fwd += per_token * tokens
     fwd += 2 * 3 * d * cfg.expert_dim * expert_slots
     fwd += 2 * d * cfg.vocab_rows * targets
     return 3.0 * fwd

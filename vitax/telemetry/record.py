@@ -109,7 +109,8 @@ class Recorder:
                     self.cfg, tokens, packed_counts["targets"],
                     packed_counts["causal_pairs"],
                     packed_counts["window_pairs"],
-                    packed_counts["expert_slots_here"])
+                    packed_counts["expert_slots_here"],
+                    packed_counts.get("ssd_pairs", 0.0))
             else:
                 flops_per_step = packed_flops_per_step(
                     self.cfg, tokens, packed_counts["token_pairs"], images)
@@ -143,7 +144,8 @@ class Recorder:
         if packed_counts is not None and self.cfg.decoder:
             record.update({k: packed_counts[k] for k in (
                 "targets", "causal_pairs", "window_pairs",
-                "expert_slots_here")}, expert_load=expert_load)
+                "expert_slots_here", "ssd_pairs", "ssd_live_chunks")
+                if k in packed_counts}, expert_load=expert_load)
         if grad_norm is not None:
             record["grad_norm"] = float(grad_norm)
         record.update(memory_stats_bytes())

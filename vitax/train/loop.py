@@ -70,7 +70,7 @@ def _attention_remat_note(cfg: Config, model, mesh) -> str:
         return "; remat " + ", ".join(
             f"{'keeps o and lse' if keeps(model, kind) else 'runs the forward again'}"
             f" in {kind} layers (span {model.span(kind)})"
-            for kind in sorted(set(cfg.layer_kinds)))
+            for kind in sorted(set(cfg.layer_kinds) - {"mamba"}))
     span = attention_span(model)
     if (mesh.shape.get("pp", 1) > 1 or gather_overlap_active(cfg, mesh)
             or cfg.remat_window > 1):
@@ -91,7 +91,9 @@ def _attention_remat_note(cfg: Config, model, mesh) -> str:
 # step record carries them
 DECODER_COUNTERS = ("tokens", "padding_tokens", "images", "targets",
                     "causal_pairs", "window_pairs", "causal_computed_pairs",
-                    "window_computed_pairs", "expert_slots_here")
+                    "window_computed_pairs", "expert_slots_here",
+                    # a model with mamba layers only:
+                    "ssd_pairs", "ssd_live_chunks")
 PACKED_COUNTERS = ("tokens", "padding_tokens", "images", "token_pairs",
                    "computed_pairs")
 
@@ -694,7 +696,8 @@ def _run_epochs(cfg, state, train_step, train_loader, val_loader, eval_step,
                         packed_counts=(
                             {k: float(jax.device_get(metrics[k])) for k in
                              (DECODER_COUNTERS if cfg.decoder else
-                              PACKED_COUNTERS)} if cfg.packed else None),
+                              PACKED_COUNTERS) if k in metrics}
+                            if cfg.packed else None),
                         expert_load=(
                             jax.device_get(
                                 metrics["expert_load"]).tolist()

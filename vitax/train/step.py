@@ -186,14 +186,27 @@ def decoder_counts(cfg: Config, batch: Dict[str, jax.Array]
     key) pairs a layer: `causal_pairs` = sum n (n + 1) / 2 over documents,
     `window_pairs` = the same with at most `window_tokens` keys a query;
     and beside each what the kernels compute for it, `causal_computed_pairs`
-    / `window_computed_pairs` (vitax/ops/flash_blocked.py: computed_pairs)."""
+    / `window_computed_pairs` (vitax/ops/flash_blocked.py: computed_pairs).
+    A model with mamba layers (vitax/models/ssm.py) also counts its scan's
+    work on the grid of `ssm_chunk` tokens: `ssd_pairs`, the pairs of a
+    query and a key not after it in one chunk and one document, and
+    `ssd_live_chunks`, the chunks that hold a valid token."""
     seg = batch["segment_ids"]
     n = jnp.sum(seg[..., None] == jnp.arange(1, cfg.pack_images + 1),
                 axis=1, dtype=jnp.int32).astype(jnp.float32)      # (R, S)
     w = jnp.minimum(n, float(max(cfg.window_tokens, 1)))
     valid = jnp.sum(seg > 0, dtype=jnp.int32)
     documents = jnp.sum(n > 0, dtype=jnp.int32)
+    ssd = {}
+    if "mamba" in cfg.layer_kinds:
+        chunks = seg.reshape(seg.shape[0], -1, cfg.ssm_chunk)
+        m = jnp.sum(chunks[..., None] == jnp.arange(1, cfg.pack_images + 1),
+                    axis=2, dtype=jnp.int32).astype(jnp.float32)  # (R, C, S)
+        ssd = dict(ssd_pairs=jnp.sum(m * (m + 1) / 2),
+                   ssd_live_chunks=jnp.sum(jnp.any(chunks > 0, axis=-1),
+                                           dtype=jnp.int32))
     return dict(
+        ssd,
         tokens=valid, padding_tokens=seg.size - valid, images=documents,
         targets=valid - documents,
         causal_pairs=jnp.sum(n * (n + 1) / 2),
