@@ -72,6 +72,7 @@ def _attention(family):
     from vitax.ops.attention import flash_attention, flash_attention_4d
     from vitax.ops.flash_blocked import blocked_flash_attention
     return {"4d": (flash_attention_4d, "flash_4d"),
+            "4d_qkv": (None, "flash_4d"),
             "bh": (flash_attention, "flash_bh"),
             "streaming": (blocked_flash_attention, "flash_blocked")}[family]
 
@@ -80,17 +81,35 @@ def _attention(family):
     ("4d", L14_ATTN), ("4d", TENB_ATTN),
     ("bh", L14_ATTN), ("bh", TENB_ATTN),
     ("streaming", LONG_ATTN),
+    ("4d_qkv", L14_ATTN), ("4d_qkv", TENB_ATTN),
 ], ids=lambda v: v if isinstance(v, str) else "x".join(map(str, v)))
 def test_attention_forward_and_vjp_compile(chip, mosaic, family, shape):
+    """`4d_qkv` is the fused-qkv entry of the 4D kernels: one (B, N, 3D)
+    operand, one (B, N, 3D) cotangent; at L14_ATTN one head group (a
+    (1, N, 3D) block out), at TENB_ATTN eight (the cotangent written from
+    the kernel's own VMEM slots)."""
     one_chip, _ = chip
     fn, name = _attention(family)
+    b, n, h, dh = shape
 
     def fwd_bwd(q, k, v):
         o, vjp = jax.vjp(fn, q, k, v)
         return o, vjp(o)
 
+    def fwd_bwd_qkv(qkv):
+        from vitax.ops.attention import flash_attention_qkv
+        o, vjp = jax.vjp(lambda x: flash_attention_qkv(x, h), qkv)
+        return o, vjp(o)
+
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
-    compiled = jax.jit(fwd_bwd).lower(x, x, x).compile()
+    if family == "4d_qkv":
+        qkv = jax.ShapeDtypeStruct((b, n, 3 * h * dh), jnp.bfloat16,
+                                   sharding=one_chip)
+        compiled = jax.jit(fwd_bwd_qkv).lower(qkv).compile()
+        # nothing but the two kernels touches an activation
+        assert " copy(" not in compiled.as_text()
+    else:
+        compiled = jax.jit(fwd_bwd).lower(x, x, x).compile()
     kernels = _kernel_names(compiled)
     assert any(f"{name}_fwd" in k for k in kernels), kernels
     assert len(kernels) >= 2, kernels  # forward and at least one backward

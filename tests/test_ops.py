@@ -395,3 +395,243 @@ def test_tpu_kernel_selection_uses_local_heads(devices8):
                                 local_heads=12)
     assert k_global is flash_attention_4d
     assert k_local is flash_attention and "BH relayout" in name
+
+
+# --- the fused-qkv entry of the 4D kernels (PR 36) --------------------------
+
+def _split_qkv(qkv, heads):
+    b, n, d3 = qkv.shape
+    x = qkv.reshape(b, n, 3, heads, d3 // (3 * heads))
+    return x[:, :, 0], x[:, :, 1], x[:, :, 2]
+
+
+@pytest.mark.parametrize("shape,hb", [
+    ((2, 64, 16, 64), 16),    # ViT-L/14's heads: one head group, a
+                              # (1, N, 3D) block out
+    ((1, 128, 32, 160), 4),   # the 10B widths: 8 groups, the grouped-padded
+                              # lse, the cotangent written by the kernel
+    ((2, 64, 3, 128), 3),     # an odd head count
+    ((1, 256, 16, 64), 8),    # two groups on the plain (B, H, N) lse
+], ids=["l14_one_group", "tenb_hb4_padded_lse", "odd_heads", "two_groups"])
+def test_flash_qkv_matches_reference_fwd_and_grad(devices8, shape, hb):
+    """`flash_attention_qkv` against `reference_attention` on the split q, k,
+    v: the output, and the gradient compared on the (B, N, 3D) cotangent
+    itself, under a cotangent that differs by position and column."""
+    from vitax.ops.attention import (_heads_per_program, flash4_qkv_supported,
+                                     flash_attention_qkv)
+    b, n, h, dh = shape
+    assert _heads_per_program(n, h, dh, 4) == hb
+    assert flash4_qkv_supported(n, h, dh, 4)
+    qkv = jax.random.normal(jax.random.key(11), (b, n, 3 * h * dh),
+                            jnp.float32)
+    weight = jax.random.normal(jax.random.key(12), (b, n, h * dh),
+                               jnp.float32)
+
+    def reference(x):
+        return reference_attention(*_split_qkv(x, h)).reshape(b, n, h * dh)
+
+    def fused(x):
+        return flash_attention_qkv(x, h)
+
+    np.testing.assert_allclose(np.asarray(fused(qkv)),
+                               np.asarray(reference(qkv)),
+                               rtol=2e-4, atol=2e-4)
+    got = jax.grad(lambda x: jnp.sum(fused(x) * weight))(qkv)
+    want = jax.grad(lambda x: jnp.sum(reference(x) * weight))(qkv)
+    assert got.shape == qkv.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-3, atol=1e-3)
+
+
+def test_flash_qkv_refuses_a_window_off_the_lane_tile(devices8):
+    """h=6, dh=16: the 4D kernel's grouping (hb = h, 96 lanes) is legal only
+    as a full-array block, which a window into a 3D-wide buffer never is:
+    the entry is not offered and says so when called anyway."""
+    from vitax.ops.attention import (flash4_qkv_supported, flash4_supported,
+                                     flash_attention_qkv)
+    assert flash4_supported(64, 6, 16, 4)
+    assert not flash4_qkv_supported(64, 6, 16, 4)
+    with pytest.raises(AssertionError, match="flash4_qkv_supported"):
+        flash_attention_qkv(jnp.zeros((1, 64, 3 * 6 * 16), jnp.float32), 6)
+
+
+def _attention_config(**over):
+    from vitax.config import Config
+    base = dict(image_size=64, patch_size=8, embed_dim=256, num_heads=4,
+                num_blocks=1, dtype="float32")
+    return Config(**{**base, **over}).validate()
+
+
+def _equations(jaxpr):
+    """Every equation of `jaxpr` and of the jaxprs its equations hold, but
+    not the kernels' bodies."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _equations(sub)
+
+
+def _activation_relayouts(jaxpr, b, n):
+    """Names of the slice / squeeze / transpose / pad / concatenate
+    equations that read or write a (b, n, ...) activation of three or more
+    dimensions."""
+    relayout = {"slice", "squeeze", "transpose", "pad", "concatenate",
+                "dynamic_slice", "gather"}
+    return [eqn.primitive.name for eqn in _equations(jaxpr)
+            if eqn.primitive.name in relayout and any(
+                len(getattr(v.aval, "shape", ())) >= 3
+                and v.aval.shape[:2] == (b, n)
+                for v in (*eqn.invars, *eqn.outvars))]
+
+
+def test_attention_with_the_fused_entry_moves_no_activation(devices8):
+    """Jaxpr equations (not compiled text): with the fused entry nothing
+    slices, squeezes, transposes, pads or concatenates a (B, N, .) activation
+    between the qkv `dot_general` and the `pallas_call`, forward or backward;
+    two kernel calls in all. The same walk over the split entry finds the
+    slices and the pads, so it can see them."""
+    from vitax.models.vit import Attention
+    from vitax.ops.attention import flash_attention_4d, make_attention_impl
+    cfg = _attention_config()
+    b, n, d = 2, cfg.num_patches, cfg.embed_dim
+    x = jax.random.normal(jax.random.key(0), (b, n, d), jnp.float32)
+    impl = make_attention_impl(cfg, None, force_tpu_kernels=True)
+    assert impl.vitax_fused_qkv is not None
+
+    def jaxpr_of(attention_impl):
+        model = Attention(num_heads=cfg.num_heads, dtype=jnp.float32,
+                          attention_impl=attention_impl)
+        params = model.init(jax.random.key(1), x)
+
+        def loss(params, x):
+            return jnp.sum(model.apply(params, x) ** 2)
+        return params, model, jax.make_jaxpr(
+            jax.grad(loss, argnums=(0, 1)))(params, x).jaxpr
+
+    params, fused_model, fused = jaxpr_of(impl)
+    assert _activation_relayouts(fused, b, n) == []
+    assert sum(eqn.primitive.name == "pallas_call"
+               for eqn in _equations(fused)) == 2
+    _, split_model, split = jaxpr_of(flash_attention_4d)
+    moved = _activation_relayouts(split, b, n)
+    assert moved.count("slice") >= 3 and moved.count("pad") >= 3, moved
+    # one set of weights, one function
+    np.testing.assert_allclose(
+        np.asarray(fused_model.apply(params, x)),
+        np.asarray(split_model.apply(params, x)), rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("name,over,fused", [
+    ("l14", dict(image_size=224, patch_size=14, embed_dim=1024,
+                 num_heads=16, dtype="bfloat16"), True),
+    ("tenb_one_chip", dict(image_size=224, patch_size=14, embed_dim=5120,
+                           num_heads=32, dtype="bfloat16"), True),
+    ("att_dropout_eval_only", dict(att_dropout=0.1), True),
+    ("bh_fallback", dict(image_size=144, patch_size=8, embed_dim=960,
+                         num_heads=12, dtype="bfloat16"), False),
+    ("lane_tile_off", dict(embed_dim=96, num_heads=6), False),
+    ("streaming", dict(image_size=384, patch_size=8, embed_dim=256), False),
+])
+def test_fused_entry_is_offered_by_what_the_shape_allows(devices8, name,
+                                                         over, fused):
+    """One chip: the entry is advertised where `_select_path` says 4d and the
+    head group's lanes tile; the impl's name says so; the BH fallback, a
+    96-lane group and the streaming kernel keep today's entry."""
+    from vitax.ops.attention import make_attention_impl
+    impl = make_attention_impl(_attention_config(**over), None,
+                               force_tpu_kernels=True)
+    assert (getattr(impl, "vitax_fused_qkv", None) is not None) == fused
+    assert ("fused qkv" in impl.vitax_name) == fused
+
+
+@pytest.mark.parametrize("name,over,fused", [
+    ("fsdp8", dict(fsdp_size=8), True),
+    ("tenb_fsdp4_dp2", dict(image_size=224, patch_size=14, embed_dim=5120,
+                            num_heads=32, dtype="bfloat16", fsdp_size=4,
+                            dp_size=2), True),
+    ("tp2", dict(fsdp_size=4, tp_size=2), False),
+    ("sp2_ring", dict(fsdp_size=4, sp_size=2), False),
+    ("sp2_ulysses", dict(fsdp_size=4, sp_size=2, sp_impl="ulysses"), False),
+])
+def test_fused_entry_on_a_mesh_needs_the_head_axis_whole(devices8, name,
+                                                         over, fused):
+    """On a mesh the entry rides the same shard_map over the batch axes;
+    tp > 1 and sp > 1 keep today's entry, and the pipeline body's impls
+    never carry it."""
+    from vitax.ops.attention import make_attention_impl
+    from vitax.parallel.mesh import build_mesh
+    cfg = _attention_config(**over)
+    impl = make_attention_impl(cfg, build_mesh(cfg), force_tpu_kernels=True)
+    assert (getattr(impl, "vitax_fused_qkv", None) is not None) == fused
+    assert ("fused qkv" in impl.vitax_name) == fused
+    for body in ("vitax_local_impl", "vitax_pp_impl"):
+        assert getattr(getattr(impl, body, None), "vitax_fused_qkv",
+                       None) is None
+
+
+def test_fused_entry_under_shard_map_matches_the_split_entry(devices8):
+    """fsdp = 8 on the virtual mesh: same values and gradients through the
+    (B, N, 3D) spec as through today's three (B, N, H, Dh) operands."""
+    from vitax.models.vit import Attention
+    from vitax.ops.attention import make_attention_impl
+    from vitax.parallel.mesh import build_mesh
+    cfg = _attention_config(fsdp_size=8)
+    mesh = build_mesh(cfg)
+    impl = make_attention_impl(cfg, mesh, force_tpu_kernels=True)
+    x = jax.random.normal(jax.random.key(0), (8, cfg.num_patches, 256),
+                          jnp.float32)
+
+    def split(q, k, v):   # the impl without what it advertises
+        return impl(q, k, v)
+    fused_model = Attention(num_heads=4, dtype=jnp.float32,
+                            attention_impl=impl)
+    split_model = Attention(num_heads=4, dtype=jnp.float32,
+                            attention_impl=split)
+    params = fused_model.init(jax.random.key(1), x)
+
+    def grads(model):
+        return jax.jit(jax.value_and_grad(
+            lambda p: jnp.sum(model.apply(p, x) ** 2)))(params)
+    with mesh:
+        (lf, gf), (ls, gs) = grads(fused_model), grads(split_model)
+    np.testing.assert_allclose(float(lf), float(ls), rtol=1e-5)
+    for a, c in zip(jax.tree_util.tree_leaves(gf),
+                    jax.tree_util.tree_leaves(gs)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   rtol=1e-3, atol=1e-4)
+
+
+@pytest.mark.parametrize("case", ["train_dropout", "packed"])
+def test_calls_that_put_something_before_the_kernel_keep_the_split_entry(
+        devices8, case):
+    """Active attention dropout and the packed model (RoPE, `segment_ids`)
+    keep q, k and v apart: the fused entry is there (or not offered at all)
+    and is not called."""
+    from vitax.models.vit import Attention
+    from vitax.ops.attention import make_attention_impl
+    called = []
+    if case == "train_dropout":
+        cfg = _attention_config(att_dropout=0.1)
+        impl = make_attention_impl(cfg, None, force_tpu_kernels=True)
+        assert impl.vitax_fused_qkv is not None
+        real = impl.vitax_fused_qkv
+        impl.vitax_fused_qkv = lambda *a: called.append(1) or real(*a)
+        model = Attention(num_heads=4, dtype=jnp.float32, att_dropout=0.1,
+                          attention_impl=impl)
+        x = jnp.ones((2, cfg.num_patches, 256), jnp.float32)
+        params = model.init(jax.random.key(0), x)
+        called.clear()               # init is an evaluation call
+        model.apply(params, x, False, rngs={"dropout": jax.random.key(1)})
+        assert called == []          # training: the in-kernel dropout entry
+        model.apply(params, x, True)
+        assert called == [1]         # evaluation: nothing in the way
+    else:
+        from vitax.config import Config
+        cfg = Config(pack_tokens=128, pack_images=4, max_image_tokens=64,
+                     pos_grid=8, patch_size=4, embed_dim=64, num_heads=4,
+                     num_blocks=1, mlp_dim=100, num_classes=10,
+                     dtype="float32").validate()
+        impl = make_attention_impl(cfg, None, force_tpu_kernels=True)
+        assert getattr(impl, "vitax_fused_qkv", None) is None
+        assert "fused qkv" not in impl.vitax_name

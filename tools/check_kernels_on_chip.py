@@ -14,6 +14,7 @@ Usage: python tools/check_kernels_on_chip.py   (needs a TPU; ~1 min)
 Shapes cover the three dispatch paths of vitax/ops/attention.py:
 - 4D whole-N kernel, full-array head blocks (l14/b16 geometry)
 - 4D whole-N kernel, grouped-padded lse (10B-family geometry, hb=4)
+- the fused-qkv entry of the 4D kernel at both geometries
 - BH relayout kernel (forced)
 plus the streaming blocked kernel (vitax/ops/flash_blocked.py) at a
 sequence length past MAX_SEQ_IN_VMEM's block sizes.
@@ -67,7 +68,8 @@ def main():
         return 2
 
     from vitax.ops.attention import (_heads_per_program, flash_attention,
-                                     flash_attention_4d, reference_attention)
+                                     flash_attention_4d, flash_attention_qkv,
+                                     reference_attention)
     from vitax.ops.flash_blocked import blocked_flash_attention
 
     print(f"device: {dev.device_kind}")
@@ -88,6 +90,21 @@ def main():
                 reference_attention, (4, 256, 16, 64))
     # 10B-family geometry: grouped-padded lse (hb=4, P=8)
     ok &= check("4D padded-lse (10B: h32 dh160)", flash_attention_4d,
+                reference_attention, (8, 256, 32, 160))
+    # the same two geometries through the fused-qkv entry: one (B, N, 3D)
+    # operand read through three block windows, one (B, N, 3D) cotangent —
+    # a (1, N, 3D) block out at l14 (one head group), written by the
+    # kernel's own DMAs from two VMEM slots at the 10B widths (8 groups)
+
+    def fused_qkv(q, k, v):
+        b, n, h, dh = q.shape
+        qkv = jnp.concatenate(
+            [x.reshape(b, n, h * dh) for x in (q, k, v)], axis=-1)
+        return flash_attention_qkv(qkv, h).reshape(q.shape)
+
+    ok &= check("4D fused qkv (l14: 1 head group)", fused_qkv,
+                reference_attention, (4, 256, 16, 64))
+    ok &= check("4D fused qkv (10B: 8 head groups)", fused_qkv,
                 reference_attention, (8, 256, 32, 160))
     # BH relayout kernel, forced (the fallback dispatch path)
     ok &= check("BH relayout (h8 dh64)", flash_attention,

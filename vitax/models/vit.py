@@ -341,6 +341,14 @@ class Attention(nn.Module):
         )(x)
         if self.qkv_sharding is not None:
             qkv = jax.lax.with_sharding_constraint(qkv, self.qkv_sharding)
+        fused_qkv = getattr(self.attention_impl, "vitax_fused_qkv", None)
+        if (fused_qkv is not None and segment_ids is None
+                and (self.att_dropout == 0.0 or deterministic)):
+            # the kernel reads q, k and v where the projection wrote them
+            # and its backward hands back the qkv cotangent whole: no slice
+            # or copy on either side (vitax/ops/attention.py)
+            return self._project(fused_qkv(qkv, self.num_heads),
+                                 deterministic)
         qkv = qkv.reshape(b, n, 3, self.num_heads, head_dim)
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # each (B, N, H, Dh)
 
@@ -376,9 +384,11 @@ class Attention(nn.Module):
             attn = nn.Dropout(rate=self.att_dropout)(attn, deterministic=deterministic)
             out = jnp.einsum("bhqk,bkhd->bqhd", attn, v)
 
-        out = out.reshape(b, n, d)
+        return self._project(out.reshape(b, n, d), deterministic)
+
+    def _project(self, out: Array, deterministic: bool) -> Array:
         out = _dense(
-            self.quant_matmul, True, d,
+            self.quant_matmul, True, out.shape[-1],
             dtype=self.dtype,
             name="proj",
         )(out)

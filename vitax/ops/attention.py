@@ -11,7 +11,10 @@ Design notes (see /opt/skills/guides/pallas_guide.md):
 - Two whole-N kernel families: the 4D-native kernel (default — operands
   viewed as (B, N, H*Dh), grid over (batch, head-groups), per-head lane
   slices, no HBM relayouts; measured +13% step throughput on ViT-L/14 v5e
-  over the BH layout) and the BH kernel ((B*H, N, Dh), one head per program
+  over the BH layout; where nothing sits between the qkv projection and
+  the kernel it is entered through `flash_attention_qkv`, which reads the
+  projection's (B, N, 3D) output in place and hands back one (B, N, 3D)
+  gradient) and the BH kernel ((B*H, N, Dh), one head per program
   — the fallback when no head grouping fits VMEM, and the building block of
   ring attention's local products). ViT sequence lengths are short (256
   tokens at 224^2/patch 14), so whole-N blocks fit comfortably; beyond
@@ -33,6 +36,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
 from vitax.parallel.mesh import BATCH_AXES, shard_map
@@ -272,6 +276,21 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
 # logsumexp is a (1, N) row — every slice/store stays a legal Mosaic layout
 # (no vector transposes, no mid-tensor unit reshapes; probed 13% faster than
 # the BH path forward on v5e).
+#
+# "No HBM transposes" holds INSIDE the kernel. Its hand-off is another
+# matter: q, k and v are the three strided slices qkv[:, :, 0..2] of the
+# fused projection's (B, N, 3, H, Dh) output, and a pallas_call operand must
+# be dense, so XLA copies the projection's output into a layout that makes
+# the slices cheap and copies each slice back, forward and rematted forward,
+# and copies dq, dk, dv on their way into the padded sum that is the qkv
+# cotangent: 2 x (B, N, 3D) + 9 x (B, N, D) copies a layer, a tenth of
+# ViT-L/14's step on a v5e (ledger, PR 35). `flash_attention_qkv` below is
+# the entry without that hand-off: it reads q, k and v where the projection
+# wrote them — three block windows over the one (B, N, 3D) buffer, at
+# column-block offsets 0, H/hb and 2H/hb — and its backward writes the
+# (B, N, 3D) cotangent itself. Same kernel bodies, same names, same lse
+# layouts; `flash_attention_4d` stays for callers that hold q, k and v apart
+# (RoPE or dropout between projection and kernel, tp, ring attention).
 
 
 def _fwd4_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, heads, scale,
@@ -313,9 +332,7 @@ def _bwd4_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dlse_ref,
         o = o_ref[0][:, sl].astype(jnp.float32)
         do = do_ref[0][:, sl].astype(jnp.float32)
         lse_blk = lse_ref[0, 0] if pad_rows else lse_ref[0]
-        dlse_blk = dlse_ref[0, 0] if pad_rows else dlse_ref[0]
         lse_row = lse_blk[i:i + 1, :]                # (1, Nq) f32
-        dlse_row = dlse_blk[i:i + 1, :]
 
         sT = jax.lax.dot_general(                    # (Nk, Nq)
             k, q, (((1,), (1,)), ((), ())),
@@ -337,7 +354,11 @@ def _bwd4_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dlse_ref,
         delta_row = jax.lax.dot_general(             # sum(dO*O, -1) as a row
             ones_row, do * o, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)      # (1, Nq)
-        dsT = (pT * (dpT - delta_row + dlse_row) * scale).astype(q_ref.dtype)
+        inner = dpT - delta_row
+        if dlse_ref is not None:  # None: the caller handed out no lse
+            dlse_blk = dlse_ref[0, 0] if pad_rows else dlse_ref[0]
+            inner = inner + dlse_blk[i:i + 1, :]     # (1, Nq) f32
+        dsT = (pT * inner * scale).astype(q_ref.dtype)
 
         dq = jax.lax.dot_general(                    # dS K: contract Nk
             dsT, k, (((0,), (0,)), ((), ())),
@@ -386,6 +407,26 @@ def _lse_pad_rows(hb: int, h: int) -> int:
     return -(-hb // 8) * 8  # round up to the f32 sublane tile
 
 
+def _lse_layout4(b: int, n: int, h: int, hb: int):
+    """(pad, BlockSpec, array shape) of the 4D kernels' lse for an hb-head
+    grouping on a (batch, head-groups) grid: the grouped-padded
+    (B, H/hb, P, N) with full-tile blocks where _lse_pad_rows says so, the
+    plain (B, H, N) otherwise."""
+    pad = _lse_pad_rows(hb, h)
+    if pad:
+        return (pad, pl.BlockSpec((1, 1, pad, n), lambda i, j: (i, j, 0, 0)),
+                (b, h // hb, pad, n))
+    return pad, pl.BlockSpec((1, hb, n), lambda i, j: (i, j, 0)), (b, h, n)
+
+
+def _regroup_lse(x, hb: int, pad: int):
+    """(B, H, N) -> the grouped-padded (B, H/hb, P, N) the kernel blocks
+    need."""
+    b, h, n = x.shape
+    g = x.reshape(b, h // hb, hb, n)
+    return jnp.pad(g, ((0, 0), (0, 0), (0, pad - hb), (0, 0)))
+
+
 def flash4_supported(n: int, h: int, dh: int, itemsize: int) -> bool:
     """Whether the 4D-native kernel has a legal, VMEM-fitting head grouping
     for this shape — checked by _tpu_kernel before selecting it; the BH
@@ -400,15 +441,9 @@ def _fwd4(q, k, v, scale):
     assert hb is not None, (
         f"flash_attention_4d has no VMEM-fitting head grouping for "
         f"(n={n}, h={h}, dh={dh}) — gate on flash4_supported() first")
-    pad = _lse_pad_rows(hb, h)
+    pad, lse_spec, lse_shape = _lse_layout4(b, n, h, hb)
     q3, k3, v3 = (x.reshape(b, n, h * dh) for x in (q, k, v))  # free bitcasts
     spec = pl.BlockSpec((1, n, hb * dh), lambda i, j: (i, 0, j))
-    if pad:  # grouped-padded lse: (B, H/hb, P, N) with full-tile blocks
-        lse_spec = pl.BlockSpec((1, 1, pad, n), lambda i, j: (i, j, 0, 0))
-        lse_shape = (b, h // hb, pad, n)
-    else:
-        lse_spec = pl.BlockSpec((1, hb, n), lambda i, j: (i, j, 0))
-        lse_shape = (b, h, n)
     o, lse = pl.pallas_call(
         functools.partial(_fwd4_kernel, heads=hb, scale=scale, pad_rows=pad),
         grid=(b, h // hb),
@@ -443,18 +478,12 @@ def _flash4_bwd(scale, res, cts):
     do, dlse = cts
     b, n, h, dh = q.shape
     hb = _heads_per_program(n, h, dh, q.dtype.itemsize)
-    pad = _lse_pad_rows(hb, h)
+    pad, lse_spec, _ = _lse_layout4(b, n, h, hb)
     flat = (x.reshape(b, n, h * dh) for x in (q, k, v, o, do))
     q3, k3, v3, o3, do3 = flat
     spec = pl.BlockSpec((1, n, hb * dh), lambda i, j: (i, 0, j))
-    if pad:  # re-pad (B, H, N) to the grouped layout the kernel blocks need
-        def regroup(x):
-            g = x.reshape(b, h // hb, hb, n)
-            return jnp.pad(g, ((0, 0), (0, 0), (0, pad - hb), (0, 0)))
-        lse, dlse = regroup(lse), regroup(dlse)
-        lse_spec = pl.BlockSpec((1, 1, pad, n), lambda i, j: (i, j, 0, 0))
-    else:
-        lse_spec = pl.BlockSpec((1, hb, n), lambda i, j: (i, j, 0))
+    if pad:
+        lse, dlse = _regroup_lse(lse, hb, pad), _regroup_lse(dlse, hb, pad)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd4_kernel, heads=hb, scale=scale, pad_rows=pad),
         grid=(b, h // hb),
@@ -473,6 +502,183 @@ flash4_with_lse.defvjp(_flash4_fwd, _flash4_bwd)
 def flash_attention_4d(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
     """Fused attention on native (B, N, H, Dh) layout — no HBM relayouts."""
     return flash4_with_lse(q, k, v, q.shape[-1] ** -0.5)[0]
+
+
+# -- the fused-qkv entry: (B, N, 3*H*Dh) in, (B, N, H*Dh) out ----------------
+
+def flash4_qkv_supported(n: int, h: int, dh: int, itemsize: int) -> bool:
+    """Whether the fused-qkv entry applies: the 4D kernel's head grouping
+    exists and its block's lane dim hb*Dh is a multiple of 128. A window of
+    hb*Dh columns into a 3D-wide buffer is never a full-array block, so the
+    grouping that is legal only as one (hb == h with D off the lane tile)
+    keeps `flash_attention_4d`."""
+    hb = _heads_per_program(n, h, dh, itemsize)
+    return hb is not None and (hb * dh) % 128 == 0
+
+
+def _qkv_geometry(qkv, heads: int):
+    b, n, d3 = qkv.shape
+    assert d3 % (3 * heads) == 0, (qkv.shape, heads)
+    dh = d3 // (3 * heads)
+    hb = _heads_per_program(n, heads, dh, qkv.dtype.itemsize)
+    assert hb is not None and (hb * dh) % 128 == 0, (
+        f"flash_attention_qkv has no legal head grouping for (n={n}, "
+        f"h={heads}, dh={dh}) — gate on flash4_qkv_supported() first")
+    return b, n, dh, hb, heads // hb
+
+
+def _qkv_windows(n: int, width: int, groups: int):
+    """q's, k's and v's block windows over the one (B, N, 3D) buffer: head
+    group j of q sits at column block j, of k at groups + j, of v at
+    2 * groups + j."""
+    return [pl.BlockSpec((1, n, width),
+                         lambda i, j, c=c: (i, 0, c * groups + j))
+            for c in range(3)]
+
+
+def _fwd4_qkv(qkv, heads):
+    """-> (o (B, N, D), lse in the kernel's own layout, _lse_layout4)."""
+    b, n, dh, hb, groups = _qkv_geometry(qkv, heads)
+    pad, lse_spec, lse_shape = _lse_layout4(b, n, heads, hb)
+    return pl.pallas_call(
+        functools.partial(_fwd4_kernel, heads=hb, scale=dh ** -0.5,
+                          pad_rows=pad),
+        grid=(b, groups),
+        in_specs=_qkv_windows(n, hb * dh, groups),
+        out_specs=[pl.BlockSpec((1, n, hb * dh), lambda i, j: (i, 0, j)),
+                   lse_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, n, heads * dh), qkv.dtype),
+            jax.ShapeDtypeStruct(lse_shape, jnp.float32),
+        ],
+        name="flash_4d_fwd",
+        interpret=_interpret(),
+    )(qkv, qkv, qkv)
+
+
+def _second_result(ref):
+    """What the fused backward hands out beside the cotangent: one zeroed
+    (8, 128) tile, so that its custom call is a tuple. The TPU profiler
+    names an op event by the op's whole HLO text, operand names included,
+    and whoever sums the events that hold `flash_` (attention_roofline)
+    would count every op that reads a one-result `%flash_4d_bwd` directly —
+    the projection's dW and dx products — as attention time; a tuple's
+    readers name a `get-tuple-element`."""
+    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
+    def _():
+        ref[...] = jnp.zeros_like(ref)
+
+
+def _bwd4_qkv_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dqkv_ref,
+                     second_ref, *, heads, scale, pad_rows):
+    """One head group covers all heads: the block out is the whole
+    (1, N, 3D) row of the cotangent, dq | dk | dv side by side."""
+    _second_result(second_ref)
+    d = q_ref.shape[-1]
+    _bwd4_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, None,
+                 dqkv_ref.at[:, :, 0:d], dqkv_ref.at[:, :, d:2 * d],
+                 dqkv_ref.at[:, :, 2 * d:3 * d],
+                 heads=heads, scale=scale, pad_rows=pad_rows)
+
+
+def _bwd4_qkv_grouped_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
+                             dqkv_hbm, second_ref, buf, sem, *, heads,
+                             groups, scale, pad_rows):
+    """Several head groups: a group's dq, dk, dv are three column windows
+    of the (B, N, 3D) cotangent, `groups` blocks apart, which no one output
+    BlockSpec describes. The cotangent stays in HBM and each step sends its
+    three (N, hb*Dh) pieces there itself, from one of two VMEM slots, and
+    waits for a slot's writes only when it needs the slot again (two steps
+    on), so a step's write-back runs under the next step's matmuls as the
+    pipeline's own would."""
+    _second_result(second_ref)
+    i, j = pl.program_id(0), pl.program_id(1)
+    step = i * groups + j
+    last = pl.num_programs(0) * groups - 1
+    slot = step % 2
+    width = q_ref.shape[-1]
+
+    def writes(slot):
+        return [pltpu.make_async_copy(
+            buf.at[slot, c],
+            dqkv_hbm.at[pl.ds(i, 1), :,
+                        pl.ds(pl.multiple_of((c * groups + j) * width, 128),
+                              width)],
+            sem.at[slot, c]) for c in range(3)]
+
+    # a wait needs the semaphore and the size, not the place: these stand
+    # for the writes this slot started two steps ago
+    @pl.when(step >= 2)
+    def _():
+        for w in writes(slot):
+            w.wait()
+
+    _bwd4_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, None,
+                 buf.at[slot, 0], buf.at[slot, 1], buf.at[slot, 2],
+                 heads=heads, scale=scale, pad_rows=pad_rows)
+    for w in writes(slot):
+        w.start()
+
+    @pl.when(step == last)
+    def _():
+        for w in writes(1 - slot) + writes(slot):  # groups > 1: last >= 1
+            w.wait()
+
+
+def _bwd4_qkv(qkv, heads, o, lse, do):
+    b, n, dh, hb, groups = _qkv_geometry(qkv, heads)
+    pad, lse_spec, _ = _lse_layout4(b, n, heads, hb)
+    width = hb * dh
+    spec = pl.BlockSpec((1, n, width), lambda i, j: (i, 0, j))
+    kwargs = dict(heads=hb, scale=dh ** -0.5, pad_rows=pad)
+    if groups == 1:
+        kernel = functools.partial(_bwd4_qkv_kernel, **kwargs)
+        out_spec = pl.BlockSpec((1, n, 3 * width), lambda i, j: (i, 0, 0))
+        extra = {}
+    else:
+        kernel = functools.partial(_bwd4_qkv_grouped_kernel, groups=groups,
+                                   **kwargs)
+        out_spec = pl.BlockSpec(memory_space=pl.ANY)
+        extra = dict(
+            scratch_shapes=[pltpu.VMEM((2, 3, 1, n, width), qkv.dtype),
+                            pltpu.SemaphoreType.DMA((2, 3))],
+            # the slots' hand-over from step to step needs the grid in order
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")))
+    return pl.pallas_call(
+        kernel,
+        grid=(b, groups),
+        in_specs=[*_qkv_windows(n, width, groups), spec, lse_spec, spec],
+        out_specs=[out_spec, pl.BlockSpec((8, 128), lambda i, j: (0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(qkv.shape, qkv.dtype),
+                   jax.ShapeDtypeStruct((8, 128), jnp.float32)],
+        name="flash_4d_bwd",
+        interpret=_interpret(),
+        **extra,
+    )(qkv, qkv, qkv, o, lse, do)[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def flash_attention_qkv(qkv: jax.Array, heads: int) -> jax.Array:
+    """Fused attention straight off the fused projection: qkv (B, N, 3*H*Dh)
+    laid out [q | k | v], each H heads of Dh -> o (B, N, H*Dh). The
+    cotangent is the (B, N, 3*H*Dh) gradient itself: nothing is sliced,
+    copied, padded or concatenated between the projection and the kernel,
+    forward or backward."""
+    return _fwd4_qkv(qkv, heads)[0]
+
+
+def _flash_qkv_fwd(qkv, heads):
+    o, lse = _fwd4_qkv(qkv, heads)
+    return o, (qkv, o, lse)
+
+
+def _flash_qkv_bwd(heads, res, do):
+    qkv, o, lse = res
+    return (_bwd4_qkv(qkv, heads, o, lse, do),)
+
+
+flash_attention_qkv.defvjp(_flash_qkv_fwd, _flash_qkv_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -553,7 +759,6 @@ def _bwd_kernel_drop(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
 
 
 def _seed_spec():
-    from jax.experimental.pallas import tpu as pltpu
     return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
@@ -718,15 +923,9 @@ def _fwd4_drop(q, k, v, seedvec, scale, rate):
     b, n, h, dh = q.shape
     hb = _heads_per_program(n, h, dh, q.dtype.itemsize)
     assert hb is not None, (n, h, dh)
-    pad = _lse_pad_rows(hb, h)
+    pad, lse_spec, lse_shape = _lse_layout4(b, n, h, hb)
     q3, k3, v3 = (x.reshape(b, n, h * dh) for x in (q, k, v))
     spec = pl.BlockSpec((1, n, hb * dh), lambda i, j: (i, 0, j))
-    if pad:
-        lse_spec = pl.BlockSpec((1, 1, pad, n), lambda i, j: (i, j, 0, 0))
-        lse_shape = (b, h // hb, pad, n)
-    else:
-        lse_spec = pl.BlockSpec((1, hb, n), lambda i, j: (i, j, 0))
-        lse_shape = (b, h, n)
     o, lse = pl.pallas_call(
         functools.partial(_fwd4_kernel_drop, heads=hb, heads_total=h,
                           scale=scale, rate=rate, pad_rows=pad),
@@ -763,19 +962,14 @@ def _flash4_drop_bwd(scale, rate, res, cts):
     do, dlse = cts
     b, n, h, dh = q.shape
     hb = _heads_per_program(n, h, dh, q.dtype.itemsize)
-    pad = _lse_pad_rows(hb, h)
+    pad, lse_spec, _ = _lse_layout4(b, n, h, hb)
     flat = (x.reshape(b, n, h * dh) for x in (q, k, v, o, do))
     q3, k3, v3, o3, do3 = flat
     spec = pl.BlockSpec((1, n, hb * dh), lambda i, j: (i, 0, j))
-    if pad:  # re-pad (B, H, N) to the grouped layout the kernel blocks need
-        def regroup(x):
-            g = x.reshape(b, h // hb, hb, n)
-            return jnp.pad(g, ((0, 0), (0, 0), (0, pad - hb), (0, 0)))
-        lse_in, dlse_in = regroup(lse), regroup(dlse)
-        lse_spec = pl.BlockSpec((1, 1, pad, n), lambda i, j: (i, j, 0, 0))
-    else:
-        lse_in, dlse_in = lse, dlse
-        lse_spec = pl.BlockSpec((1, hb, n), lambda i, j: (i, j, 0))
+    lse_in, dlse_in = lse, dlse
+    if pad:
+        lse_in, dlse_in = (_regroup_lse(lse, hb, pad),
+                           _regroup_lse(dlse, hb, pad))
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd4_kernel_drop, heads=hb, heads_total=h,
                           scale=scale, rate=rate, pad_rows=pad),
@@ -1029,6 +1223,30 @@ def _decoder_impl(cfg, mesh: Optional[Mesh], force: bool):
     return impl
 
 
+def _fused_qkv_entry(cfg, mesh: Optional[Mesh], n: int):
+    """`fused(qkv, heads)`, the hand-off-free entry of the 4D kernels that a
+    whole-N impl advertises as `vitax_fused_qkv` (Attention.__call__ takes
+    it when nothing sits between its projection and the kernel), or None
+    where the shape keeps today's entry (flash4_qkv_supported). Only for a
+    head axis that is whole on a shard: the caller checks tp == 1 and has
+    left sp > 1 behind. On a mesh, the same shard_map over the batch axes
+    with a (B, N, 3D) spec."""
+    itemsize = 2 if cfg.dtype == "bfloat16" else 4
+    if not flash4_qkv_supported(n, cfg.num_heads,
+                                cfg.embed_dim // cfg.num_heads, itemsize):
+        return None
+    sharded = mesh is not None and mesh.size > 1
+
+    def fused(qkv, heads):
+        kernel = functools.partial(flash_attention_qkv, heads=heads)
+        if sharded:
+            spec = P(BATCH_AXES, None, None)
+            kernel = shard_map(kernel, mesh=mesh, in_specs=(spec,),
+                               out_specs=spec, check_vma=False)
+        return kernel(qkv)
+    return fused
+
+
 def make_attention_impl(cfg, mesh: Optional[Mesh] = None,
                         force_tpu_kernels: bool = False):
     """Choose the attention core for this config/mesh:
@@ -1042,6 +1260,13 @@ def make_attention_impl(cfg, mesh: Optional[Mesh] = None,
 
     force_tpu_kernels=True makes the same selections off-TPU with the Pallas
     kernels in interpret mode (the multichip dryrun's production-path sweep).
+
+    Where `_select_path` says "4d" and the head axis is whole on a shard
+    (tp = 1, sp = 1) the impl also advertises `vitax_fused_qkv(qkv, heads)`,
+    the 4D kernels' entry that reads the fused projection's (B, N, 3D)
+    output as it stands and hands back one (B, N, 3D) gradient
+    (flash_attention_qkv), and its `vitax_name` says so. The pipeline
+    body's impls do not carry it.
 
     Attention dropout: every path that can run kernels runs dropout
     IN-KERNEL (exposed as impl.vitax_dropout, taking (q, k, v, seed)) — the
@@ -1162,9 +1387,15 @@ def make_attention_impl(cfg, mesh: Optional[Mesh] = None,
         return None
     drop_kernel = _tpu_dropout_kernel(cfg, n, force=force_tpu_kernels,
                                       local_heads=cfg.num_heads // tp)
+    fused_qkv = None
+    if kernel is flash_attention_4d and tp == 1:
+        fused_qkv = _fused_qkv_entry(cfg, mesh, n)
+    if fused_qkv is not None:
+        name += ", fused qkv"
 
     if mesh is None or mesh.size == 1:
         impl = _named(kernel, name)
+        impl.vitax_fused_qkv = fused_qkv
         if drop_kernel is not None:
             impl.vitax_dropout = drop_kernel
             # single-device impls also serve as the pipeline BODY impl
@@ -1178,6 +1409,7 @@ def make_attention_impl(cfg, mesh: Optional[Mesh] = None,
         in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     ), name + " + shard_map")
+    wrapped.vitax_fused_qkv = fused_qkv
     if drop_kernel is not None:
         shard_axes = tuple(a for a in (*BATCH_AXES, "tp")
                            if mesh.shape.get(a, 1) > 1)
