@@ -1,0 +1,9 @@
+"""Share of the window in which chip 0 was idle while the loop thread was
+inside the `train_step` call (`dispatch` of benchmark/loop_spans.py). The
+six `loop_idle_*` shares add up to `device_idle_pct`."""
+
+from benchmark import loop_spans
+
+
+def read(run):
+    return loop_spans.idle_pct(run, "dispatch")

@@ -350,32 +350,3 @@ def test_fused_requires_schedule():
                                     materialize=False)
     with pytest.raises(ValueError, match="schedule"):
         make_train_step(cfg, model, tx, mesh, sspecs)
-
-
-def test_opt_probe_runs():
-    """make_opt_probe (the opt_update_s telemetry program): zero grads ->
-    zero grad_norm, finite state outputs, params stepped by decay only —
-    and it is a separate non-donating program, so the input state's buffers
-    survive the call."""
-    from vitax.parallel.mesh import build_mesh
-    from vitax.train.state import build_optimizer, make_train_state
-    from vitax.train.step import make_opt_probe
-    from vitax.models import build_model
-    from vitax.ops.attention import make_attention_impl
-
-    cfg = Config(**dict(GEOMETRY, gather_overlap="off")).validate()
-    mesh = build_mesh(cfg)
-    model = build_model(cfg, attention_impl=make_attention_impl(cfg, mesh))
-    tx, schedule = build_optimizer(cfg, max_iteration=100)
-    state, sspecs, _ = make_train_state(cfg, model, tx, mesh,
-                                        jax.random.key(0))
-    probe = make_opt_probe(cfg, tx, mesh, sspecs, schedule=schedule)
-    new_params, new_opt_state, grad_norm = jax.block_until_ready(
-        probe(state))
-    assert float(grad_norm) == 0.0
-    for leaf in jax.tree.leaves(new_params):
-        assert np.all(np.isfinite(leaf))
-    # non-donating: the live state is still usable afterwards
-    assert np.all(np.isfinite(jax.tree.leaves(state.params)[0]))
-    assert (jax.tree_util.tree_structure(new_opt_state)
-            == jax.tree_util.tree_structure(state.opt_state))

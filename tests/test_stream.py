@@ -507,6 +507,25 @@ def test_metrics_report_input_bound(tmp_path, capsys):
     metrics_report.print_human(summary)
     out = capsys.readouterr().out
     assert "input-bound steps" in out and "30.0%" in out
+    # with the loop's marks, a wait that the fence after it outlasts is time
+    # the run-ahead hid from the device (two steps a record: waits of 0.3 s
+    # against fences of 1.0 s in all): only a wait with no backlog behind it
+    # counts (records 4 and 5)
+    with open(path, "w") as f:
+        for i in range(1, 6):
+            fence = 0.0 if i >= 4 else 1.0
+            f.write(json.dumps({
+                "schema": 1, "time": 1000.0 + 2 * i, "step": 2 * i,
+                "epoch": 1, "step_in_epoch": 2 * i, "loss": 2.0, "lr": 1e-3,
+                "sec_per_iter": 1.0, "data_wait_s": 0.3,
+                "loop_marks": [
+                    [2 * i - 1] + [1000.0 + 2 * i + t
+                                   for t in (0.0, 0.3, 0.35, 0.4, 0.4)],
+                    [2 * i] + [1001.0 + 2 * i + t
+                               for t in (0.0, 0.3, 0.35, 0.4, 0.4 + fence)]],
+            }) + "\n")
+    assert metrics_report.summarize(str(path))["input_bound"] \
+        == pytest.approx(0.4)
     # a healthy run reports 0.0, and human mode drops the (!!) flag
     with open(path, "w") as f:
         f.write(json.dumps({

@@ -204,21 +204,45 @@ def test_watchdog_silent_on_healthy_loop(capsys):
 
 # --- step program identity + host-side work counts ---
 
+def _lowered_step(cfg):
+    from tests.test_train_smoke import build_train_objects, random_batch
+    mesh, state, step_fn, _ = build_train_objects(cfg)
+    batch = random_batch(cfg, mesh)
+    return step_fn.lower(state, batch, jax.random.key(0)).as_text()
+
+
 def test_telemetry_off_traces_identical_step_program(devices8):
     """--metrics_dir / --hang_timeout_s / --peak_tflops are host-side only:
     the lowered step program must be bit-identical with telemetry on or off
     (the acceptance pin against new device ops / extra syncs)."""
-    from tests.test_train_smoke import build_train_objects, random_batch
-
-    def lowered(cfg):
-        mesh, state, step_fn, _ = build_train_objects(cfg)
-        batch = random_batch(cfg, mesh)
-        return step_fn.lower(state, batch, jax.random.key(0)).as_text()
-
-    off = lowered(tiny_cfg())
-    on = lowered(tiny_cfg(metrics_dir="/tmp/vitax_metrics_identity_test",
-                          hang_timeout_s=300.0, peak_tflops=197.0))
+    off = _lowered_step(tiny_cfg())
+    on = _lowered_step(tiny_cfg(metrics_dir="/tmp/vitax_metrics_identity_test",
+                                hang_timeout_s=300.0, peak_tflops=197.0))
     assert off == on
+
+
+def test_a_live_recorder_counts_compiles_and_changes_no_program(
+        tmp_path, devices8):
+    """The recorder's one `jax.monitoring` listener (the step records'
+    `compiles`) sees the lowering and moves nothing in it: the same text
+    with a recorder alive as with none, and no listener left after close."""
+    from jax._src import monitoring
+    without = _lowered_step(tiny_cfg())
+    listeners = len(monitoring.get_event_duration_listeners())
+    cfg = tiny_cfg(metrics_dir=str(tmp_path / "m"))
+    rec = build_recorder(cfg, n_devices=8, device_kind="cpu", rank=0)
+    assert rec.compiles == 0
+    assert _lowered_step(cfg) == without
+    traced = rec.compiles
+    assert traced > 0                       # the step's trace, at the least
+    r = rec.record_step(step=1, epoch=1, step_in_epoch=1, loss=1.0, lr=1e-3,
+                        sec_per_iter=0.5, data_wait_s=0.0,
+                        loop_marks=[[1, 10.0, 10.1, 10.2, 10.3, 10.4]])
+    assert r["compiles"] == traced
+    assert r["loop_marks"] == [[1, 10.0, 10.1, 10.2, 10.3, 10.4]]
+    rec.close()
+    rec.close()                             # twice is harmless
+    assert len(monitoring.get_event_duration_listeners()) == listeners
 
 
 def test_step_metrics_carry_work_counts(devices8):
@@ -308,7 +332,14 @@ def test_metrics_report_synthetic(tmp_path):
                 "sec_per_iter": 0.5 + (0.5 if i == 20 else 0.0),
                 "images_per_sec": 32.0, "tokens_per_sec": 8192.0,
                 "data_wait_s": 0.05, "mfu": 0.4, "mem_used_bytes": 123456,
-                "mem_peak_bytes": 234567}) + "\n")
+                "mem_peak_bytes": 234567,
+                # a second of wall time a step: wait 0.05, put 0.1, dispatch
+                # 0.05, fence 0.7 (1.2 at step 20), host 0.1 to the next row;
+                # something compiled before steps 1 and 12
+                "compiles": 100 + (7 if i >= 12 else 0),
+                "loop_marks": [[i] + [1000.0 + i + t for t in (
+                    0.0, 0.05, 0.15, 0.2, 1.4 if i == 20 else 0.9)]],
+                }) + "\n")
         f.write(json.dumps({"schema": 1, "kind": "hang", "rank": 0,
                             "stalled_s": 99.0, "stacks": "..."}) + "\n")
         f.write("{corrupt json\n")
@@ -329,6 +360,17 @@ def test_metrics_report_synthetic(tmp_path):
     assert summary["loss_first"] == pytest.approx(2.9)
     assert summary["loss_last"] == pytest.approx(1.0)
     assert summary["mem_peak_bytes"] == 234567
+    phases = summary["loop_phases"]
+    assert list(phases) == ["wait", "put", "dispatch", "fence", "host"]
+    assert phases["wait"]["p50"] == pytest.approx(0.05)
+    assert phases["fence"]["p50"] == pytest.approx(0.7)
+    assert phases["fence"]["p95"] > 0.7         # step 20's long fence
+    assert phases["host"]["p50"] == pytest.approx(0.1)
+    # 19 closed rows of 1.0 s and the last, open after its fence, of 1.4
+    assert phases["put"]["share"] == pytest.approx(20 * 0.1 / 20.4, abs=1e-5)
+    assert sum(v["share"] for v in phases.values()) == pytest.approx(
+        1.0, abs=1e-5)
+    assert summary["compile_steps"] == [1, 12]
 
     # human mode renders without crashing and flags the hang
     r = subprocess.run(
@@ -336,6 +378,8 @@ def test_metrics_report_synthetic(tmp_path):
          str(path)], capture_output=True, text=True, timeout=120)
     assert r.returncode == 0, r.stderr
     assert "watchdog hang events: 1" in r.stdout
+    assert "loop fence: p50 0.7000s" in r.stdout
+    assert "compiled before the records of steps: [1, 12]" in r.stdout
 
     # empty file -> exit 2 (CI must notice a run that recorded nothing)
     empty = tmp_path / "empty.jsonl"

@@ -2,7 +2,9 @@
 """Summarize a vitax telemetry JSONL run (vitax/telemetry/, schema 1).
 
 Human mode prints the run at a glance — step range, p50/p95 sec/iter, MFU,
-data-wait fraction, checkpoint-stall percentiles, peer-replication volume
+data-wait fraction, the loop thread's phases (p50/p95 and share of the run,
+from `loop_marks`), the steps at which something compiled,
+checkpoint-stall percentiles, peer-replication volume
 and restore path, throughput, a loss sparkline, memory peak, watchdog
 events; `--json` emits the same summary as one JSON object for CI.
 
@@ -52,6 +54,40 @@ def sparkline(vals, width: int = 40) -> str:
         SPARK_CHARS[min(int((v - lo) / span * (len(SPARK_CHARS) - 1)),
                         len(SPARK_CHARS) - 1)]
         for v in vals)
+
+
+# a phase of the loop thread lasts from its mark to the next one, `host` to
+# the next iteration's t_next (vitax/train/loop.py, module docstring; a row
+# of `loop_marks` is [step, t_next, t_got, t_batch, t_dispatch, t_fence])
+LOOP_PHASES = ("wait", "put", "dispatch", "fence", "host")
+
+
+def loop_phase_seconds(steps) -> dict:
+    """{phase: [seconds, one an iteration]} over the records' `loop_marks`.
+    `host` closes on the row of the next step, across records; the run's
+    last row, and one followed by another run's rows, leaves it open."""
+    rows = [row for r in steps for row in r.get("loop_marks") or []]
+    out = {phase: [] for phase in LOOP_PHASES}
+    for row, after in zip(rows, rows[1:] + [None]):
+        ends = list(row[2:])
+        if after is not None and after[0] == row[0] + 1 and after[1] >= row[5]:
+            ends.append(after[1])
+        for phase, a, b in zip(LOOP_PHASES, row[1:], ends):
+            out[phase].append(b - a)
+    return out
+
+
+def run_ahead_spent(record) -> bool:
+    """Whether a record's queue wait can be starvation. The loop dispatches
+    up to a log interval of steps ahead of the device, so while the rows'
+    `fence` (the device's backlog at the log step) outlasts their `wait`,
+    the device never saw the wait. A record without marks cannot say, and
+    counts by its wait alone."""
+    rows = record.get("loop_marks")
+    if not rows:
+        return True
+    return (sum(row[5] - row[4] for row in rows)
+            < sum(row[2] - row[1] for row in rows))
 
 
 def load_records(path: str):
@@ -266,12 +302,19 @@ def summarize(path: str) -> dict:
     mfus = [r["mfu"] for r in steps if r.get("mfu") is not None]  # null: CPU run
     waits = [r.get("data_wait_s", 0.0) for r in steps]
     stalls = sorted(r["ckpt_stall_s"] for r in steps if "ckpt_stall_s" in r)
-    opts = sorted(r["opt_update_s"] for r in steps
-                  if r.get("opt_update_s", 0.0) > 0.0)
+    phases = loop_phase_seconds(steps)
+    in_phases = sum(sum(v) for v in phases.values())
+    compiles_before = 0
+    compile_steps = []   # records whose cumulative `compiles` rose
+    for r in steps:
+        if r.get("compiles", 0) > compiles_before:
+            compile_steps.append(r["step"])
+        compiles_before = r.get("compiles", compiles_before)
     # fraction of each recorded step spent waiting on host data (both sides
     # are per-step averages over the same record interval)
-    wait_fracs = [r["data_wait_s"] / r["sec_per_iter"] for r in steps
-                  if r.get("sec_per_iter") and "data_wait_s" in r]
+    timed = [r for r in steps
+             if r.get("sec_per_iter") and "data_wait_s" in r]
+    wait_fracs = [r["data_wait_s"] / r["sec_per_iter"] for r in timed]
     summary.update({
         "first_step": steps[0]["step"],
         "last_step": steps[-1]["step"],
@@ -286,19 +329,23 @@ def summarize(path: str) -> dict:
                              if stalls else None),
         "ckpt_stall_s_p95": (round(percentile(stalls, 0.95), 6)
                              if stalls else None),
-        # fused-optimizer acceptance metric: fenced wall time of the
-        # optimizer-phase probe (records with the probe disabled carry 0
-        # and are excluded)
-        "opt_update_s_p50": (round(percentile(opts, 0.50), 6)
-                             if opts else None),
-        "opt_update_s_p95": (round(percentile(opts, 0.95), 6)
-                             if opts else None),
+        # where the loop thread's time went: per-iteration seconds of each
+        # phase and its share of all the marked time (records without
+        # `loop_marks` give none)
+        "loop_phases": ({
+            phase: {"p50": round(percentile(sorted(v), 0.50), 6),
+                    "p95": round(percentile(sorted(v), 0.95), 6),
+                    "share": round(sum(v) / in_phases, 6)}
+            for phase, v in phases.items() if v} if in_phases > 0 else None),
+        "compile_steps": compile_steps,
         "data_wait_fraction": (round(sum(wait_fracs) / len(wait_fracs), 6)
                                if wait_fracs else None),
         # the streaming data plane's acceptance metric (ROADMAP item 3):
         # fraction of recorded steps that were input-bound — data wait over
-        # 10% of the step. A healthy pipeline holds this at ~0.
-        "input_bound": (round(sum(1 for w in wait_fracs if w > 0.1)
+        # 10% of the step, and longer than the fence that followed it where
+        # the record has marks. A healthy pipeline holds this at ~0.
+        "input_bound": (round(sum(1 for r, w in zip(timed, wait_fracs)
+                                  if w > 0.1 and run_ahead_spent(r))
                               / len(wait_fracs), 6)
                         if wait_fracs else None),
         "loss_first": round(losses[0], 6),
@@ -436,19 +483,23 @@ def print_human(summary: dict) -> None:
     if mfu_last is not None:
         print(f"  MFU: last {mfu_last:.4f}  max {summary['mfu_max']:.4f}")
     if summary["data_wait_fraction"] is not None:
-        starved = " (input-bound!)" if summary["data_wait_fraction"] > 0.3 else ""
+        # queue time, which the loop's run-ahead hides while the device has
+        # a backlog: the count of input-bound steps below says how much of
+        # it the device saw
         print(f"  data wait: {summary['data_wait_s_mean']:.4f}s/step, "
-              f"{100 * summary['data_wait_fraction']:.1f}% of step "
-              f"time{starved}")
+              f"{100 * summary['data_wait_fraction']:.1f}% of step time")
     if summary.get("ckpt_stall_s_p50") is not None:
         print(f"  ckpt stall: p50 {summary['ckpt_stall_s_p50']:.4f}s  "
               f"p95 {summary['ckpt_stall_s_p95']:.4f}s per step")
-    if summary.get("opt_update_s_p50") is not None:
-        print(f"  opt update: p50 {summary['opt_update_s_p50']:.4f}s  "
-              f"p95 {summary['opt_update_s_p95']:.4f}s per step")
+    for phase, v in (summary.get("loop_phases") or {}).items():
+        print(f"  loop {phase}: p50 {v['p50']:.4f}s  p95 {v['p95']:.4f}s "
+              f"per step, {100 * v['share']:.1f}% of the loop thread")
+    if summary.get("compile_steps"):
+        print(f"  compiled before the records of steps: "
+              f"{summary['compile_steps']}")
     if summary.get("input_bound") is not None:
         flag = " (!!)" if summary["input_bound"] > 0 else ""
-        print(f"  input-bound steps (wait > 10% of step): "
+        print(f"  input-bound steps (wait > 10% of step and > fence): "
               f"{100 * summary['input_bound']:.1f}%{flag}")
     print(f"  throughput: {summary['images_per_sec_last']:.1f} images/s, "
           f"{summary['tokens_per_sec_last']:.0f} tokens/s (last record)")

@@ -14,8 +14,8 @@ else:
              the only window where the live buffers are read: the moment
              stage() returns, the caller may dispatch step N+1 and donate
              the state. The copy time is the per-step `ckpt_stall_s`
-             telemetry (consume_stall_s, same consume contract as the
-             loader's data_wait_s) — the acceptance harness pins it ~0.
+             telemetry (consume_stall_s: read and reset by the loop at each
+             step record) — the acceptance harness pins it ~0.
 
   worker     one background thread owns EVERYTHING downstream: rebuilding
              device arrays from the staged copies and handing them to
@@ -307,7 +307,7 @@ class SnapshotPipeline:
 
     def consume_stall_s(self) -> float:
         """Accumulated staging stall since the last call (the loop divides
-        by its record window — same contract as loader.consume_wait_s)."""
+        by the steps of its record window)."""
         s, self._stall_s = self._stall_s, 0.0
         return s
 

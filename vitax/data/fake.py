@@ -39,11 +39,12 @@ class FakeImageNetDataset:
 
 class FakePackedLoader:
     """Packed batches of zero-pixel images for `--fake_data --pack_tokens`:
-    the loop's loader surface (`epoch`, `steps_per_epoch`, `consume_wait_s`,
-    `close`). Each step draws grids (even sides up to the position table's,
-    redrawn while over the per-image limit) from (seed, epoch, step, process)
-    until one fits no row, first-fit packs them, and hands the loop one
-    batch-sharded device batch."""
+    the loop's loader surface (`epoch`, `steps_per_epoch`, `close`; no
+    prefetch queue, so no `t_got`: all its time is the loop's `put`). Each
+    step draws grids (even sides up to the position table's, redrawn while
+    over the per-image limit) from (seed, epoch, step, process) until one
+    fits no row, first-fit packs them, and hands the loop one batch-sharded
+    device batch."""
 
     def __init__(self, cfg, mesh, length: int):
         from jax.sharding import NamedSharding
@@ -82,9 +83,6 @@ class FakePackedLoader:
             local, _ = self.draw(epoch, step)
             yield {k: jax.make_array_from_process_local_data(self.sharding, v)
                    for k, v in local.items()}
-
-    def consume_wait_s(self) -> float:
-        return 0.0
 
     def close(self) -> None:
         pass

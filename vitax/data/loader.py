@@ -103,22 +103,17 @@ class ShardedLoader:
         self.num_workers = max(num_workers, 1)
         self.prefetch = max(prefetch, 1)
         self.steps_per_epoch = sampler.steps_per_epoch
-        self._wait_s = 0.0  # host time the consumer spent blocked on q.get
+        # time.time() at which the prefetch queue last handed a host batch
+        # to the TRAINING THREAD (stamped in epoch(), on that thread): the
+        # loop's `t_got` mark. Before it the thread was blocked on the queue
+        # (`wait`: queue time, which the loop's run-ahead hides from the
+        # device while the log step's `fence` outlasts it; a run whose wait
+        # tracks sec/iter with no fence left is input-bound), after it it
+        # hands the batch to the device (`put`) — vitax/train/loop.py,
+        # module docstring.
+        self.t_got = 0.0
         self._pool = ThreadPoolExecutor(max_workers=self.num_workers,
                                         thread_name_prefix="vitax-data")
-
-    def consume_wait_s(self) -> float:
-        """Seconds the TRAINING THREAD spent blocked waiting for a decoded
-        batch since the last call (accumulated around the prefetch-queue get
-        in epoch()), then reset. This is the data-starvation signal: device
-        step time hides inside JAX's async dispatch, so a loop whose
-        sec/iter grows while data_wait_s stays ~0 is compute/comm-bound; one
-        whose data_wait_s tracks sec/iter is input-bound. Read by the
-        telemetry Recorder once per log step — single-threaded with the
-        accumulation (both happen on the consumer thread), so no lock."""
-        w = self._wait_s
-        self._wait_s = 0.0
-        return w
 
     def _load_local(self, indices: Sequence[int]) -> Dict[str, np.ndarray]:
         if getattr(self.dataset, "use_native", False):
@@ -175,9 +170,8 @@ class ShardedLoader:
         t.start()
         try:
             while True:
-                t_wait = time.monotonic()
                 item = q.get()
-                self._wait_s += time.monotonic() - t_wait
+                self.t_got = time.time()
                 if item is None:
                     return
                 if isinstance(item, _ProducerFailure):

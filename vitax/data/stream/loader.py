@@ -2,7 +2,7 @@
 
 Mirrors ShardedLoader's interface (vitax/data/loader.py) so train/loop.py
 consumes either transparently — `epoch(epoch, start_step)`, `steps_per_epoch`,
-`consume_wait_s()`, `close()` — with three streaming-specific upgrades:
+`t_got`, `close()` — with three streaming-specific upgrades:
 
 - records arrive as in-memory bytes from the shard reader (ONE open handle,
   sequential shard consumption) and decode through the native memory-source
@@ -153,16 +153,7 @@ class StreamLoader:
         self.num_workers = max(num_workers, 1)
         self.prefetch = max(prefetch, 1)
         self.steps_per_epoch = sampler.steps_per_epoch
-        self._wait_s = 0.0
-
-    def consume_wait_s(self) -> float:
-        """Seconds the training thread spent blocked on the prefetch queue
-        since the last call, then reset — flows into the data_wait_s
-        telemetry field exactly like ShardedLoader.consume_wait_s (the
-        input-bound signal tools/metrics_report.py aggregates)."""
-        w = self._wait_s
-        self._wait_s = 0.0
-        return w
+        self.t_got = 0.0   # the loop's `t_got` mark, as ShardedLoader's
 
     def cursor_for_step(self, epoch: int, step: int) -> Dict:
         """Resume cursor after `step` consumed batches — what train/loop.py
@@ -222,9 +213,8 @@ class StreamLoader:
         pending: Optional[Dict[str, jax.Array]] = None
         try:
             while True:
-                t_wait = time.monotonic()
                 item = q.get()
-                self._wait_s += time.monotonic() - t_wait
+                self.t_got = time.time()
                 if item is None:
                     break
                 if isinstance(item, _ProducerFailure):

@@ -50,12 +50,12 @@ from vitax.parallel.sharding import (moe_dispatch_sharding, shardings_of,
 from vitax.programs.registry import Scenario, get_scenario
 from vitax.programs.workloads import load_teacher_params, make_distill_step
 from vitax.train.state import make_train_state
-from vitax.train.step import make_eval_step, make_opt_probe, make_train_step
+from vitax.train.step import make_eval_step, make_train_step
 
 PyTree = Any
 
 # program kinds build_program understands (each scenario declares a subset)
-PROGRAM_KINDS = ("train", "eval", "opt_probe", "distill", "serve_bucket")
+PROGRAM_KINDS = ("train", "eval", "distill", "serve_bucket")
 
 
 def build_model_for(cfg: Config, mesh: Mesh, force_tpu_kernels: bool = False,
@@ -196,9 +196,6 @@ def build_program(task: str, geom: Geometry, donate: bool = True,
                                   schedule=geom.schedule)
     elif task == "eval":
         program = make_eval_step(cfg, model, mesh, geom.state_specs)
-    elif task == "opt_probe":
-        program = make_opt_probe(cfg, geom.tx, mesh, geom.state_specs,
-                                 schedule=geom.schedule)
     else:  # distill
         if cfg.teacher_npz:
             teacher = load_teacher_params(cfg, mesh)

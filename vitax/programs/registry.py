@@ -162,7 +162,7 @@ SCENARIOS = {
         name="train",
         description="reference pretraining loop (CE over labels)",
         step_program="train",
-        programs=("train", "eval", "opt_probe", "serve_bucket"),
+        programs=("train", "eval", "serve_bucket"),
         make_optimizer=_train_optimizer,
         validate=_validate_train,
     ),
@@ -171,7 +171,7 @@ SCENARIOS = {
         description="fine-tune from a consolidated npz export "
                     "(--init_npz; head re-init, --backbone_lr_mult)",
         step_program="train",
-        programs=("train", "eval", "opt_probe", "serve_bucket"),
+        programs=("train", "eval", "serve_bucket"),
         make_optimizer=_finetune_optimizer,
         validate=_validate_finetune,
     ),
@@ -180,7 +180,7 @@ SCENARIOS = {
         description="linear probe: frozen backbone (optax-masked), "
                     "head-only optimizer state",
         step_program="train",
-        programs=("train", "eval", "opt_probe", "serve_bucket"),
+        programs=("train", "eval", "serve_bucket"),
         make_optimizer=_probe_optimizer,
         validate=_validate_probe,
     ),
@@ -189,7 +189,7 @@ SCENARIOS = {
         description="knowledge distillation: frozen teacher "
                     "(--teacher_npz) + student in one jitted program",
         step_program="distill",
-        programs=("distill", "eval", "opt_probe", "serve_bucket"),
+        programs=("distill", "eval", "serve_bucket"),
         make_optimizer=_train_optimizer,  # plain AdamW over the student
         validate=_validate_distill,
     ),

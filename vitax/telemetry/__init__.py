@@ -9,8 +9,9 @@ Subsystem map:
              bounded shutdown joins with leaked-thread warnings
 
 Wired through the training stack by vitax/train/loop.py (Recorder lifecycle,
-per-log-step records, watchdog pets), vitax/data/loader.py (host batch-wait
-accounting) and vitax/config.py (--metrics_dir, --tensorboard,
+per-log-step records with the loop thread's timeline, watchdog pets),
+vitax/data/loader.py (the `t_got` mark, where the prefetch queue hands a host
+batch over) and vitax/config.py (--metrics_dir, --tensorboard,
 --peak_tflops, --hang_timeout_s). Everything is host-side: telemetry on or
 off, the compiled step program is identical.
 """
@@ -19,7 +20,8 @@ from vitax.telemetry.flops import (  # noqa: F401
     PEAK_TFLOPS, detect_peak_tflops, mfu, model_flops_per_image,
     model_flops_per_step)
 from vitax.telemetry.record import (  # noqa: F401
-    REQUIRED_STEP_KEYS, SCHEMA_VERSION, Recorder, build_recorder)
+    LOOP_MARKS, LOOP_PHASES, REQUIRED_STEP_KEYS, SCHEMA_VERSION, Recorder,
+    build_recorder, phase_intervals)
 from vitax.telemetry.sinks import (  # noqa: F401
     JsonlSink, TensorBoardSink, make_tensorboard_sink)
 from vitax.telemetry.threads import (  # noqa: F401

@@ -5,7 +5,8 @@ versioned, machine-readable record (schema 1):
 
     schema, time, step, epoch, step_in_epoch, loss, lr, grad_norm,
     sec_per_iter, images_per_sec, tokens_per_sec, data_wait_s, ckpt_stall_s,
-    opt_update_s, mfu, mem_used_bytes, mem_peak_bytes[, mem_limit_bytes]
+    compiles, mfu, mem_used_bytes, mem_peak_bytes[, mem_limit_bytes]
+    [, loop_marks]     (the loop's timeline: one row of LOOP_MARKS a step)
     [, padding_frac]   (packed batches: from the step's own counters)
 
 MFU comes from the analytic FLOPs model (telemetry/flops.py) over the
@@ -30,6 +31,31 @@ from vitax.telemetry.flops import (
     packed_flops_per_step)
 
 SCHEMA_VERSION = 1
+
+# one row of a step record's `loop_marks`: the global step and the five
+# `time.time()` marks its iteration stamped (vitax/train/loop.py, module
+# docstring: each opens a phase that lasts to the next mark)
+LOOP_MARKS = ("step", "t_next", "t_got", "t_batch", "t_dispatch", "t_fence")
+# the phase each of the five marks opens, in the marks' order
+LOOP_PHASES = ("wait", "put", "dispatch", "fence", "host")
+# what `compiles` counts: every jaxpr trace and every backend compile of the
+# process, cached or not, through `jax.monitoring`
+COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
+                  "/jax/core/compile/jaxpr_trace_duration")
+
+def phase_intervals(rows) -> list:
+    """[(step, phase, start, end)] of consecutive rows of LOOP_MARKS: a phase
+    lasts from its mark to the row's next one, `host` to the next row's
+    `t_next`, so the last row has no `host`. The one place the rule is
+    written for the program's readers (tools/metrics_report.py, which
+    imports nothing, keeps a copy)."""
+    out = []
+    for row, after in zip(rows, list(rows[1:]) + [None]):
+        ends = list(row[2:]) + ([after[1]] if after is not None else [])
+        out += [(row[0], phase, a, b)
+                for phase, a, b in zip(LOOP_PHASES, row[1:], ends)]
+    return out
+
 
 # acceptance contract of a step record: tools/metrics_report.py and the
 # tier-1 round-trip test key off this exact set
@@ -64,6 +90,15 @@ class Recorder:
             device_kind, getattr(cfg, "peak_tflops", 0.0))
         self.flops_per_step = model_flops_per_step(cfg)
         self.tokens_per_step = cfg.batch_size * cfg.num_patches
+        # cumulative, from the recorder's birth: the record after a
+        # recompile says so (a week-long run has no other witness)
+        self.compiles = 0
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+
+    def _on_duration(self, name, duration, **kwargs):
+        if name in COMPILE_EVENTS:
+            self.compiles += 1
 
     def _write(self, record: dict) -> None:
         for sink in self.sinks:
@@ -76,7 +111,8 @@ class Recorder:
     def record_step(self, *, step: int, epoch: int, step_in_epoch: int,
                     loss: float, lr: float, sec_per_iter: float,
                     data_wait_s: float, grad_norm: Optional[float] = None,
-                    ckpt_stall_s: float = 0.0, opt_update_s: float = 0.0,
+                    ckpt_stall_s: float = 0.0,
+                    loop_marks: Optional[list] = None,
                     packed_counts: Optional[dict] = None,
                     expert_load: Optional[list] = None) -> dict:
         """One record per log step. `sec_per_iter` / `data_wait_s` /
@@ -85,9 +121,9 @@ class Recorder:
         across epochs). `ckpt_stall_s` is the zero-stall snapshot pipeline's
         staging time charged to the loop thread (vitax/checkpoint/
         snapshot.py) — the acceptance pin keeps it ~0 on non-final saves.
-        `opt_update_s` is the fenced wall time of the optimizer-phase probe
-        (vitax/train/step.py make_opt_probe), measured at log steps only —
-        the fused-optimizer win as a number, not an assertion.
+        `loop_marks`: the loop's rows of LOOP_MARKS since the previous
+        record, written as they are; `data_wait_s` is their mean `wait`
+        (t_got - t_next). `compiles` is the recorder's own cumulative count.
         `packed_counts`: a packed step's own counters (`tokens`,
         `padding_tokens`, `images`, `token_pairs`; vitax/train/step.py) —
         throughput, MFU and `padding_frac` then come from what the batch
@@ -129,7 +165,7 @@ class Recorder:
                                if sec_per_iter > 0 else 0.0),
             "data_wait_s": float(data_wait_s),
             "ckpt_stall_s": float(ckpt_stall_s),
-            "opt_update_s": float(opt_update_s),
+            "compiles": self.compiles,
             "mfu": mfu(self.cfg, sec_per_iter, self.n_devices,
                        self.peak_tflops, flops_per_step),
         }
@@ -148,6 +184,8 @@ class Recorder:
                 if k in packed_counts}, expert_load=expert_load)
         if grad_norm is not None:
             record["grad_norm"] = float(grad_norm)
+        if loop_marks is not None:
+            record["loop_marks"] = loop_marks
         record.update(memory_stats_bytes())
         self._write(record)
         return record
@@ -161,6 +199,11 @@ class Recorder:
         return record
 
     def close(self) -> None:
+        from jax import monitoring
+        try:
+            monitoring.unregister_event_duration_listener(self._on_duration)
+        except (AssertionError, ValueError):   # closed twice, or cleared
+            pass
         for sink in self.sinks:
             try:
                 sink.close()

@@ -6,11 +6,43 @@ compiled train_step per iteration. Device->host syncs happen only at log steps
 (the role of the reference's xm.add_step_closure throttling, run_vit_training.py:289):
 JAX's async dispatch returns futures, so we hold the metrics of the most recent
 step and fetch them when logging.
+
+The loop thread's timeline. Every iteration stamps five marks on `time.time()`
+(the clock of a `--profile_dir` device trace and of the server's marks), and a
+phase lasts from its mark to the next one:
+
+  t_next      the loop asks the loader for a batch    wait: blocked on the
+              (the end of the iteration before)       prefetch queue
+  t_got       the loader's queue handed a host batch  put: the host-to-device
+              over (stamped by the loader; one        hand-off, up to the batch
+              without a queue gives t_next)           reaching the loop
+  t_batch     the loop has the device batch           dispatch: the train_step
+                                                      call
+  t_dispatch  train_step returned                     fence: blocked on the loss
+                                                      (log steps, the step that
+                                                      arms the watchdog or
+                                                      closes a trace; else zero)
+  t_fence     the loss arrived (t_dispatch where no   host: all the rest, up to
+              fence was taken)                        the next t_next
+
+so the phases of consecutive iterations tile the thread's time with no hole:
+fault hook, watchdog, logging, the step record's fetches and write, snapshot
+submit and control poll are `host`, and so is what an epoch's end does (its
+fence, save and evaluation) before the next epoch's first t_next. A step
+record carries the rows since the record before as `loop_marks`
+(vitax/telemetry/record.py: LOOP_MARKS), its own last; `data_wait_s` is their
+mean `wait`. `wait` is queue time, not starvation by itself: the loop runs up
+to a log interval of steps ahead of the device, and a loader slower than the
+dispatch but faster than the device fills `wait` with time the device never
+sees (`fence` shrinks by as much); a run is input-bound where `fence` has gone
+to 0. Cost: five clock reads and a list append a step, taken always,
+written where a recorder is.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 import pprint
 import time
@@ -306,26 +338,6 @@ def train(cfg: Config) -> TrainState:
     # rank-tagged stderr tracebacks + kind:"thread_crash" events instead
     # of silent thread deaths (recorder=None still tags stderr)
     install_thread_excepthook(recorder, rank=jax.process_index())
-    # opt_update_s probe: a separate non-donating compile of the optimizer
-    # phase (vitax/train/step.py make_opt_probe), run at log steps only — the
-    # train step's program and the non-log-step cadence are untouched. The
-    # first probe call warms the compile; timing starts at the second.
-    # Built from cfg.metrics_dir (rank-uniform argv), NOT from the recorder:
-    # the recorder lives on rank 0 only, but the probe is a global-mesh
-    # program — every process must execute it at the same log steps or
-    # rank 0 blocks forever in a collective its peers never enter.
-    opt_probe = (build_program("opt_probe", geom)
-                 if (getattr(cfg, "metrics_dir", "") or "") else None)
-    opt_probe_warm = [False]
-
-    def _time_opt_update(cur_state) -> float:
-        if not opt_probe_warm[0]:
-            jax.block_until_ready(opt_probe(cur_state))
-            opt_probe_warm[0] = True
-        t0 = time.perf_counter()
-        jax.block_until_ready(opt_probe(cur_state))
-        return time.perf_counter() - t0
-
     if recorder is not None:
         master_print(f"telemetry: JSONL step records -> {cfg.metrics_dir} ("
                      + (f"MFU vs {recorder.peak_tflops:.0f} TF/s/chip peak"
@@ -437,7 +449,6 @@ def train(cfg: Config) -> TrainState:
             resume_step=resume_step, resume_rounded=resume_rounded,
             recorder=recorder, watchdog=watchdog, control=control,
             snap_pipe=snap_pipe, replicator=replicator,
-            opt_timer=_time_opt_update if opt_probe is not None else None,
             arbiter_reporter=arbiter_reporter)
     except Exception as e:  # noqa: BLE001 — classify, then exit coordinated or re-raise
         # A dead peer shows up two ways: ICI collectives BLOCK on it (the
@@ -574,18 +585,20 @@ def _save_ckpt(cfg, state, epoch, *, wait, step_in_epoch=None,
                    keep=cfg.keep_checkpoints, extra_meta=extra)
 
 
-def _run_epochs(cfg, state, train_step, train_loader, val_loader, eval_step,
+def _run_epochs(  # vtx: ignore[VTX103] `dispatch` IS the submission's time; the fence is a phase of its own
+                cfg, state, train_step, train_loader, val_loader, eval_step,
                 schedule, smoothed_loss, smoothed_time, prof,
                 resume_step: int = 0, resume_rounded: bool = False,
                 recorder=None, watchdog=None, control=None,
-                snap_pipe=None, replicator=None, opt_timer=None,
-                arbiter_reporter=None):
+                snap_pipe=None, replicator=None, arbiter_reporter=None):
     if control is None:  # direct callers (tests): a local, collective-free plane
         control = ControlPlane(sync_steps=cfg.control_sync_steps,
                                watchdog=watchdog)
     data_rng = jax.random.key(cfg.seed + 1)
     total_steps = 0
-    steps_since_record = 0  # averaging window for the per-record data wait
+    # the loop thread's timeline (module docstring): one row of LOOP_MARKS
+    # an iteration since the last step record
+    marks = []
     # profiler window (historical default: steps 3..7 — start after 2
     # completed steps so the compile step stays out of the trace)
     prof_start = cfg.profile_start_step
@@ -613,71 +626,80 @@ def _run_epochs(cfg, state, train_step, train_loader, val_loader, eval_step,
         time_epoch_b = time_step_b = time.time()
         metrics = None
         start_step = resume_step if epoch == start_epoch else 0
-        for step, batch in enumerate(
-                train_loader.epoch(epoch, start_step=start_step),
-                start=start_step):
-            if cfg.steps_per_epoch and step >= cfg.steps_per_epoch:
-                break
+        batches = train_loader.epoch(epoch, start_step=start_step)
+        for step in itertools.count(start_step):
             if cfg.profile_dir and total_steps == prof_start and not prof["on"]:
+                # before t_next: the profiler's start is the last of the
+                # iteration before's `host` phase, and the first traced
+                # step's annotation opens inside the trace
                 jax.profiler.start_trace(cfg.profile_dir)
                 prof["on"] = True
-            state, metrics = train_step(state, batch, data_rng)
+            t_next = time.time()
+            # the profiler's step view (--profile_dir): the batch fetch and
+            # the dispatch of global step `total_steps + 1`, the number its
+            # step record carries; a flag test when no trace runs
+            with jax.profiler.StepTraceAnnotation("train",
+                                                  step_num=total_steps + 1):
+                batch = next(batches, None)
+                if batch is None or (cfg.steps_per_epoch
+                                     and step >= cfg.steps_per_epoch):
+                    break
+                t_batch = time.time()
+                state, metrics = train_step(state, batch, data_rng)
+            t_dispatch = time.time()
             total_steps += 1
+            # first step of THIS RUN (fresh start, epoch-granular resume, or
+            # mid-epoch resume alike): always log it — it carries the compile
+            will_log = (total_steps == 1
+                        or (step + 1) % cfg.log_step_interval == 0)
+            # The FIRST step arms the watchdog — after its results
+            # MATERIALIZE, not at dispatch return: the first execution
+            # covers XLA compile and, multi-host, collective-transport
+            # bring-up + peer compile skew. None of that is a hang, and
+            # --hang_timeout_s stays independent of all of it.
+            arming = watchdog is not None and not watchdog.running
+            closing_trace = prof["on"] and total_steps == prof_stop
+            host_loss = None
+            t_fence = t_dispatch
+            if will_log or arming or closing_trace:
+                # the iteration's one fence. At a log step it comes before
+                # the clock is read: train_step returns at dispatch, so an
+                # unfenced delta times the async enqueue, not device
+                # execution — the logged sec/iter would converge to dispatch
+                # latency while the devices fall arbitrarily far behind.
+                # Fetched ONCE here and passed through as a host value
+                # (_run_logging and the telemetry record reuse it); every
+                # other step stays fence-free so the pipeline keeps its
+                # device/host overlap.
+                host_loss = float(jax.device_get(metrics["loss"]))
+                t_fence = time.time()
+            # the loader stamps t_got where its queue hands a host batch
+            # over; one without a queue never does, and `put` is all its time
+            marks.append([total_steps, t_next,
+                          max(getattr(train_loader, "t_got", 0.0), t_next),
+                          t_batch, t_dispatch, t_fence])
             # fault drill point (no-op without a plan): fires BEFORE the pet
             # so an injected hang starves the watchdog exactly like a real
             # wedged step; index = the global step count, so plans are
             # deterministic across restarts of the same config
             faults.fire("step", index=total_steps)
-            steps_since_record += 1
-            if watchdog is not None:
+            if arming:
+                watchdog.start()
+            elif watchdog is not None:
                 # pet on dispatch, not completion: the loop is alive; a wedged
                 # DEVICE stalls the next log step's fence, which stops pets
-                # within log_step_interval dispatches (async dispatch depth).
-                # The FIRST step arms the watchdog instead — after its results
-                # MATERIALIZE, not at dispatch return: the first execution
-                # covers XLA compile and, multi-host, collective-transport
-                # bring-up + peer compile skew. None of that is a hang, and
-                # --hang_timeout_s stays independent of all of it.
-                if watchdog.running:
-                    watchdog.pet()
-                else:
-                    jax.device_get(metrics["loss"])  # fence: bring-up done
-                    watchdog.start()
-            if prof["on"] and total_steps == prof_stop:
-                jax.device_get(metrics["loss"])  # fence: traced steps done
+                # within log_step_interval dispatches (async dispatch depth)
+                watchdog.pet()
+            if closing_trace:   # fenced above: the traced steps are done
                 jax.profiler.stop_trace()
                 prof["on"] = False
                 master_print(f"profile trace written to {cfg.profile_dir}")
-
-            # first step of THIS RUN (fresh start, epoch-granular resume, or
-            # mid-epoch resume alike): always log it — it carries the compile
-            is_first_iter = total_steps == 1
-            will_log = is_first_iter or (step + 1) % cfg.log_step_interval == 0
-            host_loss = None
-            if will_log:
-                # fence before reading the clock: train_step returns at
-                # dispatch, so an unfenced delta times the async enqueue,
-                # not device execution — the logged sec/iter would converge
-                # to dispatch latency while the devices fall arbitrarily
-                # far behind. Fetched ONCE here and passed through as a host
-                # value (_run_logging and the telemetry record reuse it);
-                # non-log steps stay fence-free so the pipeline keeps its
-                # device/host overlap.
-                host_loss = float(jax.device_get(metrics["loss"]))
-            t_new = time.time()
-            smoothed_time.update(t_new - time_step_b, batch_size=1)
-            time_step_b = t_new
+            smoothed_time.update(t_fence - time_step_b, batch_size=1)
+            time_step_b = t_fence
             if will_log:
                 lr = float(schedule(int(jax.device_get(metrics["lr_step"]))))
                 _run_logging(cfg, epoch, step, host_loss, lr, smoothed_loss,
                              smoothed_time)
-                # fenced re-run of the optimizer phase in isolation (probe
-                # program, not the train step) — the cost rides a log step
-                # that just fenced anyway. Runs on EVERY rank (the probe is
-                # a global-mesh program; its collectives must line up), even
-                # though only rank 0 records the number.
-                opt_update_s = (opt_timer(state)
-                                if opt_timer is not None else 0.0)
                 if recorder is not None:
                     # all inputs are already host values; the one extra
                     # device->host fetch (grad_norm) rides a log step that
@@ -686,12 +708,11 @@ def _run_epochs(cfg, state, train_step, train_loader, val_loader, eval_step,
                         step=total_steps, epoch=epoch, step_in_epoch=step + 1,
                         loss=host_loss, lr=lr,
                         sec_per_iter=smoothed_time.avg,
-                        data_wait_s=(train_loader.consume_wait_s()
-                                     / max(steps_since_record, 1)),
-                        ckpt_stall_s=((snap_pipe.consume_stall_s()
-                                       / max(steps_since_record, 1))
+                        data_wait_s=(sum(m[2] - m[1] for m in marks)
+                                     / len(marks)),
+                        loop_marks=marks,
+                        ckpt_stall_s=(snap_pipe.consume_stall_s() / len(marks)
                                       if snap_pipe is not None else 0.0),
-                        opt_update_s=opt_update_s,
                         grad_norm=float(jax.device_get(metrics["grad_norm"])),
                         packed_counts=(
                             {k: float(jax.device_get(metrics[k])) for k in
@@ -715,7 +736,7 @@ def _run_epochs(cfg, state, train_step, train_loader, val_loader, eval_step,
                             student_top1=float(
                                 jax.device_get(metrics["student_top1"])),
                             alpha=cfg.distill_alpha, temp=cfg.distill_temp)
-                steps_since_record = 0
+                marks = []   # taken always, written where a recorder is
             if arbiter_reporter is not None:
                 # a lock + three assignments; the reporter thread posts
                 arbiter_reporter.update(total_steps, epoch)
@@ -783,6 +804,7 @@ def _run_epochs(cfg, state, train_step, train_loader, val_loader, eval_step,
                 return state
             if cfg.max_steps and total_steps >= cfg.max_steps:
                 break
+        batches.close()   # a loader left early stops its producer here
 
         if metrics is not None:
             jax.device_get(metrics["loss"])  # fence: honest epoch wall time

@@ -238,8 +238,7 @@ def _microbatch_split(batch: PyTree, k_steps: int, mesh: Mesh) -> PyTree:
 
 def _make_update_fn(cfg: Config, tx, mesh: Mesh, state_specs, schedule):
     """The optimizer phase: update(grads, opt_state, params) ->
-    (new_params, new_opt_state, grad_norm). Shared by the train step and the
-    opt_update_s telemetry probe (make_opt_probe).
+    (new_params, new_opt_state, grad_norm), the train step's own.
 
     ONE global-norm reduction per step feeds both the clip and the grad_norm
     metric (the old step re-reduced the tree optax's clip_by_global_norm had
@@ -281,38 +280,6 @@ def _make_update_fn(cfg: Config, tx, mesh: Mesh, state_specs, schedule):
         return optax.apply_updates(params, updates), new_opt_state, grad_norm
 
     return update
-
-
-def make_opt_probe(
-    cfg: Config,
-    tx: optax.GradientTransformation,
-    mesh: Mesh,
-    state_specs: PyTree,
-    schedule=None,
-):
-    """Jitted optimizer-phase probe for the opt_update_s telemetry:
-    (state) -> (new_params, new_opt_state, grad_norm) over all-zero grads at
-    the state shardings — the same update program the train step runs, timed
-    in isolation. A SEPARATE, non-donating compile: the train step's program
-    is untouched (tests/test_telemetry.py pins its identity), the probe's
-    outputs are discarded, and the loop invokes it at log steps only."""
-    state_shardings = shardings_of(mesh, state_specs)
-    update_fn = _make_update_fn(cfg, tx, mesh, state_specs, schedule)
-
-    def probe(state: TrainState):
-        grads = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
-                             state.params)
-        if mesh.size > 1:
-            grads = jax.lax.with_sharding_constraint(
-                grads, shardings_of(mesh, state_specs.params))
-        return update_fn(grads, state.opt_state, state.params)
-
-    return jax.jit(
-        probe,
-        in_shardings=(state_shardings,),
-        out_shardings=(state_shardings.params, state_shardings.opt_state,
-                       None),
-    )
 
 
 def make_train_step(
