@@ -307,3 +307,46 @@ def test_packed_step_forward_kernel_call_sites(chip, mosaic, tokens,
     assert text.count('kernel_name = "flash_packed_dq"') == 1
     kernels = _kernel_names(lowered.compile())
     assert sum("flash_packed_fwd" in k for k in kernels) == forward_sites
+
+
+def test_state_space_scan_compiles_and_lies_under_its_scopes(chip, mosaic):
+    """The fused scan (vitax/ops/ssd.py) through the mixer, at the hybrid
+    cell's shape (1 x 4,096 tokens, 64 heads of 64, one group, state 128,
+    chunk 256): real Mosaic lowering and compile of forward and backward, and
+    every `ssd_*` custom call of the compiled text has `ssd_chunk` or
+    `ssd_state` in its `op_name` path: the join `benchmark/scopes.py:index`
+    makes for `ssd_roofline` and `ssm_mixer_busy_pct`."""
+    import re
+
+    from vitax.config import Config
+    from vitax.models.ssm import MixerShape, SSDMixer
+    from vitax.ops.ssd import make_scan_impl
+    one_chip, _ = chip
+    cfg = Config(
+        model_family="decoder", embed_dim=2048, num_blocks=1, vocab_rows=128,
+        kv_heads=8, head_size=64, layer_kinds=["mamba"], layer_heads=[0],
+        layer_mlps=["dense"], ffn_dim=128, ssm_heads=64, ssm_head_size=64,
+        ssm_state_size=128, ssm_conv_width=4, ssm_groups=1, ssm_chunk=256,
+        pack_tokens=4096, pack_images=4, batch_size=1).validate()
+    scan = make_scan_impl(cfg, None, force_tpu_kernels=True)
+    assert scan.vitax_name == "fused kernel (chunk 256, 16 heads a grid step)"
+    mixer = SSDMixer(MixerShape(64, 64, 128, 4, 1, 256), 1e-5, jnp.bfloat16,
+                     scan=scan)
+    u = jax.ShapeDtypeStruct((1, 4096, 2048), jnp.bfloat16, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip),
+        jax.eval_shape(mixer.init, jax.random.key(0), u, seg))
+    compiled = jax.jit(jax.grad(lambda p, u, seg: jnp.sum(
+        mixer.apply(p, u, seg).astype(jnp.float32)), argnums=(0, 1))).lower(
+            params, u, seg).compile()
+    kernels = [k for k in _kernel_names(compiled) if "/ssd_" in k]
+    assert sorted(k.rsplit("/", 2)[-2] for k in kernels) == \
+        ["ssd_bwd", "ssd_fwd"], kernels
+    from benchmark import scopes
+    text = compiled.as_text()
+    found = scopes.index(text, ("ssd_chunk", "ssd_state"))
+    calls = [re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=", ln).group(1)
+             for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln and "/ssd_" in ln]
+    assert len(calls) == 2 and all(c in found for c in calls), (calls, kernels)

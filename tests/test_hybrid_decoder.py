@@ -317,7 +317,7 @@ def test_the_family_declares_the_new_shape_fields():
     assert not granite & forms.knob_keys(forms.rules())
 
 
-def test_training_through_the_cli_path(tmp_path):
+def test_training_through_the_cli_path(tmp_path, capsys):
     """`python -m vitax.train --fake_data --model_family decoder` with mamba
     layers (the flags through `parse_config`, then the loop the entry point
     calls): a falling loss and the scan's counters on the step records.
@@ -342,6 +342,8 @@ def test_training_through_the_cli_path(tmp_path):
         "--metrics_dir", str(tmp_path / "metrics")))
     assert cfg.tie_embeddings and cfg.ssm_groups == 1
     train(cfg)
+    # which form of the scan runs, beside the attention core's line
+    assert "state-space scan: plain (no TPU)" in capsys.readouterr().out
     with open(tmp_path / "metrics" / "metrics.jsonl") as f:
         steps = [r for r in map(json.loads, f) if "kind" not in r]
     losses = [r["loss"] for r in steps]

@@ -225,6 +225,7 @@ class DecoderBlock(nn.Module):
     attention_scale: float = 0.0
     residual_multiplier: float = 1.0
     mixer: Optional[MixerShape] = None      # a mamba layer's
+    scan_impl: Optional[Callable] = None    # ... and its scan (None: plain)
 
     def _added(self, y: Array) -> Array:
         if self.residual_multiplier == 1.0:
@@ -241,7 +242,7 @@ class DecoderBlock(nn.Module):
         y = RMSNorm(self.norm_eps, self.dtype, name="norm1")(x)
         if kind == MAMBA:
             y = SSDMixer(self.mixer, self.norm_eps, self.dtype,
-                         name="mixer")(y, segment_ids)
+                         scan=self.scan_impl, name="mixer")(y, segment_ids)
         else:
             y = DecoderAttention(
                 heads=heads, kv_heads=self.kv_heads, head_size=self.head_size,
@@ -342,6 +343,7 @@ class Decoder(nn.Module):
     attention_scale: float = 0.0    # 0 = head_size ** -0.5
     logits_scaling: float = 1.0
     mixer: Optional[MixerShape] = None
+    scan_impl: Optional[Callable] = None
 
     def runs(self) -> List[Tuple[Tuple[str, int, str], int]]:
         return layer_runs(self.layer_kinds, self.layer_heads, self.layer_mlps)
@@ -394,7 +396,8 @@ class Decoder(nn.Module):
             attention_impl=self.attention_impl,
             token_sharding=self.token_sharding,
             attention_scale=self.attention_scale,
-            residual_multiplier=self.residual_multiplier, mixer=self.mixer)
+            residual_multiplier=self.residual_multiplier, mixer=self.mixer,
+            scan_impl=self.scan_impl)
         for i, (shape, length) in enumerate(self.runs()):
             x = Run(length=length,
                     block_kwargs=tuple({**block_kwargs,
@@ -447,7 +450,8 @@ def run_remat_policy(model: Decoder, kind: str):
 
 
 def build_decoder(cfg: Config, attention_impl: Optional[Callable] = None,
-                  token_sharding=None) -> Decoder:
+                  token_sharding=None,
+                  scan_impl: Optional[Callable] = None) -> Decoder:
     return Decoder(
         embed_dim=cfg.embed_dim, vocab_rows=cfg.vocab_rows,
         layer_kinds=cfg.layer_kinds, layer_heads=cfg.layer_heads,
@@ -473,7 +477,8 @@ def build_decoder(cfg: Config, attention_impl: Optional[Callable] = None,
         embedding_multiplier=cfg.embedding_multiplier,
         residual_multiplier=cfg.residual_multiplier,
         attention_scale=cfg.attention_multiplier,
-        logits_scaling=cfg.logits_scaling, mixer=mixer_shape(cfg))
+        logits_scaling=cfg.logits_scaling, mixer=mixer_shape(cfg),
+        scan_impl=scan_impl)
 
 
 def mixer_shape(cfg: Config) -> Optional[MixerShape]:

@@ -29,17 +29,29 @@ belongs to, it passes through a later chunk only if that chunk lies wholly
 inside the same document, and only tokens of that document read it.
 
 delta, A, the running sums and every state are float32; the products over x,
-B and C take operands of the model's dtype and accumulate in float32. The
-(chunk, head, `chunk`, `chunk`) products of a whole row would not fit beside
-a full chip's train state, so chunks are taken `SSD_BLOCK_BYTES` worth at a
-time (`jax.lax.map`) and each block's intermediates are made again in the
-backward (`jax.checkpoint`).
+B and C take operands of the model's dtype and accumulate in float32.
+
+Two forms of the scan, one algorithm, chosen by what the code can see
+(vitax/ops/ssd.py: `make_scan_impl`, through `build_model_for` as the
+attention core is; `SSDMixer.scan`):
+
+- the fused kernels `ssd_fwd` / `ssd_bwd` (vitax/ops/ssd.py: `ssd_fused`) on
+  a TPU (or forced, in interpret mode) where the shapes tile: chunk and state
+  size multiples of 128, head size a divisor or a multiple of 128. A chunk's
+  mask, decay and scores stay in VMEM; HBM sees the inputs, y and one float32
+  state a chunk, forward and backward; the forward runs twice under the
+  layer's remat;
+- the plain `ssd` below everywhere else, and as the oracle of the tests. The
+  (chunk, head, `chunk`, `chunk`) products of a whole row would not fit
+  beside a full chip's train state, so IT takes chunks `SSD_BLOCK_BYTES`
+  worth at a time (`jax.lax.map`) and makes each block's intermediates again
+  in the backward (`jax.checkpoint`): the constant bounds this form only.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Callable, NamedTuple
+from typing import Callable, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -236,6 +248,7 @@ class SSDMixer(nn.Module):
     shape: MixerShape
     norm_eps: float
     dtype: Dtype = jnp.bfloat16
+    scan: Optional[Callable] = None     # `ssd`'s arguments; None: `ssd`
 
     @nn.compact
     def __call__(self, u: Array, segment_ids: Array) -> Array:
@@ -264,10 +277,11 @@ class SSDMixer(nn.Module):
         with jax.named_scope("ssd_chunk"):
             delta = jax.nn.softplus(dt.astype(f32) + dt_bias)
             a_head = -jnp.exp(a_log)
-        y = ssd(x.reshape(r, t, s.heads, s.head_size), delta, a_head,
-                b.reshape(r, t, s.groups, s.state_size),
-                c.reshape(r, t, s.groups, s.state_size), d_skip, segment_ids,
-                s.chunk, self.dtype)
+        y = (self.scan or ssd)(
+            x.reshape(r, t, s.heads, s.head_size), delta, a_head,
+            b.reshape(r, t, s.groups, s.state_size),
+            c.reshape(r, t, s.groups, s.state_size), d_skip, segment_ids,
+            s.chunk, self.dtype)
 
         with jax.named_scope("ssm_gate_norm"):
             scale = Leaf((s.inner,), nn.initializers.ones,

@@ -255,6 +255,10 @@ def train(cfg: Config) -> TrainState:
     master_print("attention core: "
                  + getattr(model.attention_impl, "vitax_name", "dense jnp")
                  + _attention_remat_note(cfg, model, geom.mesh))
+    if cfg.decoder and "mamba" in cfg.layer_kinds:
+        from vitax.ops.ssd import scan_choice
+        master_print("state-space scan: " + (
+            getattr(model.scan_impl, "vitax_name", "") or scan_choice(cfg)[1]))
     # the loop owns the state: a restore or a warm start replaces it, every
     # step donates it
     state, geom.state = geom.state, None
