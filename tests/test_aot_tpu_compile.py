@@ -350,3 +350,59 @@ def test_state_space_scan_compiles_and_lies_under_its_scopes(chip, mosaic):
              for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln and "/ssd_" in ln]
     assert len(calls) == 2 and all(c in found for c in calls), (calls, kernels)
+
+
+def test_latent_attention_forward_and_vjp_compile(chip, mosaic):
+    """The packed causal kernels at the Ling cell's latent layer: one row of
+    4,096 tokens, 16 heads each with a key of its own, q and k 192 wide
+    (128 + the shared rotated 64) and v 128: real Mosaic lowering of
+    `flash_latent_*`, the output and dV at v's width."""
+    from vitax.ops.flash_blocked import document_flash_attention
+    one_chip, _ = chip
+
+    def fwd_bwd(q, k, v, segment_ids):
+        o, vjp = jax.vjp(lambda q, k, v: document_flash_attention(
+            q, k, v, segment_ids), q, k, v)
+        return o, vjp(o)
+
+    qk = jax.ShapeDtypeStruct((1, 4096, 16, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 4096, 16, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(fwd_bwd).lower(qk, qk, v, seg).compile()
+    kernels = _kernel_names(compiled)
+    for part in ("fwd", "dkv", "dq"):
+        assert any(f"flash_latent_{part}" in k for k in kernels), kernels
+    o, (dq, dk, dv) = jax.eval_shape(fwd_bwd, qk, qk, v, seg)
+    assert o.shape == dv.shape == v.shape and dq.shape == dk.shape == qk.shape
+
+
+def test_the_ling_cells_step_compiles_and_fits(chip, mosaic):
+    """The whole train step of `ling3_flash_vl_ep64tp2_train_packed4k` at the
+    published widths (seven layers, 648.9M parameters with Adam's state, one
+    row of 4,096 tokens), through the cell's own `lower_described`: the
+    chip's compiler takes it, it fits the 15.75 GB the compiler allows, the
+    latent layer's three kernels and the fused optimizer are in it, and the
+    delta rule's products lie under the scopes `kda_roofline` reads."""
+    from benchmark import harness, scopes
+    from benchmark import manifest as mf
+    _, topo = chip
+    man = mf.Manifest()
+    cell = man.cell("ling3_flash_vl_ep64tp2_train_packed4k")
+    config, traffic = man.config(cell["config"]), man.traffic(cell["traffic"])
+    gen = mf.generator(traffic["kind"])
+    lowered, what = gen.lower_described(man.config_kwargs(config), traffic,
+                                        list(topo.devices)[:1])
+    assert what == "decoder train step, 1 rows of 4096 tokens"
+    compiled = lowered.compile()
+    step_bytes = harness.program_facts(compiled)["step_bytes"]
+    assert 0.25 * 16.909e9 < step_bytes <= 15.75e9, step_bytes
+    kernels = _kernel_names(compiled)
+    for part in ("fwd", "dkv", "dq"):
+        assert any(f"flash_latent_{part}" in k for k in kernels), kernels
+    assert any("fused_adamw" in k for k in kernels)
+    found = set(scopes.index(compiled.as_text(), gen.SCOPES).values())
+    assert {"kda_conv", "kda_gate", "kda_chunk", "kda_state", "kda_out_norm",
+            "mla_latent", "moe_route", "moe_dispatch", "expert_ffn",
+            "moe_combine", "shared_expert"} <= found, found

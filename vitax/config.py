@@ -87,8 +87,10 @@ class Config:
     vocab_rows: int = 0                 # rows of the embedding and of the untied head held here
     kv_heads: int = 0                   # key/value heads, each serving layer_heads[i] / kv_heads query heads
     head_size: int = 0                  # width of one head (not embed_dim / heads: q is heads * head_size wide)
-    layer_kinds: Tuple[str, ...] = ()   # per layer: "full_attention" (or "attention") | "sliding_attention" | "mamba"
-    layer_heads: Tuple[int, ...] = ()   # per layer: query heads (0 in a mamba layer, which has none)
+    # per layer: "full_attention" (or "attention") | "sliding_attention" |
+    # "mamba" | "kda" | "latent_attention"
+    layer_kinds: Tuple[str, ...] = ()
+    layer_heads: Tuple[int, ...] = ()   # per layer: query heads, a kda layer's heads (0 in a mamba layer)
     layer_mlps: Tuple[str, ...] = ()    # per layer: "dense" | "sparse"
     window_tokens: int = 0              # keys a sliding layer's query sees, its own position included
     ffn_dim: int = 0                    # width of a dense layer's SwiGLU
@@ -126,6 +128,29 @@ class Config:
     ssm_conv_width: int = 0
     ssm_groups: int = 1
     ssm_chunk: int = 0                  # tokens a chunk of the scan; pack_tokens is a multiple of it
+    # A "kda" layer's mixer (Kimi Delta Attention, a delta rule with a decay
+    # per channel, in chunked form; vitax/models/kda.py): layer_heads[i] heads
+    # of head_size behind depthwise causal convolutions of kda_conv_width
+    # taps, the log-decay held inside (kda_gate_bound, 0)
+    kda_conv_width: int = 0
+    kda_gate_bound: float = 0.0         # < 0 in a model with kda layers
+    # A "latent_attention" layer (MLA): keys and values come up from a normed
+    # latent of latent_rank; a head's query and key are qk_nope_size +
+    # qk_rope_size wide, the rotated part of the key one for all heads; its
+    # value is v_head_size wide
+    latent_rank: int = 0
+    qk_nope_size: int = 0
+    qk_rope_size: int = 0
+    v_head_size: int = 0
+    # The router of a sparse layer may choose inside groups: the experts in
+    # route_groups equal groups, a group scored by the sum of its two best
+    # (biased) scores, groups_per_token groups kept, the experts_per_token
+    # best experts inside them; route_bias adds a float32 bias a routed
+    # expert to the scores for CHOOSING only (0 groups, no bias: a plain
+    # top-K over all experts)
+    route_groups: int = 0
+    groups_per_token: int = 0
+    route_bias: bool = False
     pos_dropout: float = 0.0
     # NOTE: att_dropout > 0 stays on the fused kernels — every attention path
     # (whole-N, streamed, ring/ulysses sp, and their pipeline bodies at tp=1)
@@ -441,7 +466,7 @@ class Config:
             f"{len(self.layer_heads)} and {len(self.layer_mlps)}")
         assert set(self.layer_kinds) <= {
             "full_attention", "attention", "sliding_attention",
-            "mamba"}, self.layer_kinds
+            "mamba", "kda", "latent_attention"}, self.layer_kinds
         assert set(self.layer_mlps) <= {"dense", "sparse"}, self.layer_mlps
         assert self.vocab_rows >= 2 and self.head_size >= 2, (
             f"--vocab_rows {self.vocab_rows} and --head_size "
@@ -476,6 +501,21 @@ class Config:
                 f"--pack_tokens {self.pack_tokens} must be a multiple of "
                 f"--ssm_chunk {self.ssm_chunk}, the tokens a chunk of the "
                 f"scan")
+        if "kda" in self.layer_kinds:
+            assert self.kda_conv_width >= 1 and self.kda_gate_bound < 0, (
+                "a kda layer needs --kda_conv_width >= 1 and "
+                "--kda_gate_bound < 0, the lower bound of its log-decay")
+        if "latent_attention" in self.layer_kinds:
+            assert (self.latent_rank >= 1 and self.qk_nope_size >= 1
+                    and self.qk_rope_size >= 2 and self.v_head_size >= 1), (
+                "a latent_attention layer needs --latent_rank, "
+                "--qk_nope_size, --qk_rope_size and --v_head_size")
+            assert (self.position_embedding == "rope" and self.qk_rope_size
+                    == int(self.head_size * self.rope_fraction_full)), (
+                f"a latent_attention layer rotates its --qk_rope_size "
+                f"{self.qk_rope_size} dimensions with the full layers' "
+                f"table: --head_size {self.head_size} x --rope_fraction_full "
+                f"{self.rope_fraction_full} must equal it")
         for name in ("rope_fraction_full", "rope_fraction_window"):
             rot = self.head_size * getattr(self, name)
             assert 0 < rot <= self.head_size and rot == int(rot) \
@@ -503,6 +543,18 @@ class Config:
                 f"the held experts [{self.expert_first}, {self.expert_first} "
                 f"+ {self.experts_held}) must lie within the "
                 f"{self.experts_routed} routed ones")
+            if self.route_groups:
+                per = self.experts_routed // self.route_groups
+                assert (self.experts_routed % self.route_groups == 0
+                        and per >= 2
+                        and 1 <= self.groups_per_token <= self.route_groups
+                        and self.experts_per_token
+                        <= self.groups_per_token * per), (
+                    f"--route_groups {self.route_groups} must divide "
+                    f"--experts_routed {self.experts_routed} into groups of "
+                    f"at least 2, and --groups_per_token "
+                    f"{self.groups_per_token} of them must hold "
+                    f"--experts_per_token {self.experts_per_token} experts")
         assert self.task == "train", (
             f"the decoder trains (--task train); --task {self.task} and "
             f"serving a decoder are not built: the serve path answers images")
@@ -909,7 +961,8 @@ def build_parser() -> argparse.ArgumentParser:
             ("kv_heads", int, 0, "key/value heads"),
             ("head_size", int, 0, "width of one head"),
             ("layer_kinds", str, "",
-             "full_attention (or attention)|sliding_attention|mamba a layer"),
+             "full_attention (or attention)|sliding_attention|mamba|kda|"
+             "latent_attention a layer"),
             ("layer_heads", str, "", "query heads a layer (0 in a mamba one)"),
             ("layer_mlps", str, "", "dense|sparse a layer"),
             ("window_tokens", int, 0, "keys a sliding layer's query sees"),
@@ -942,13 +995,23 @@ def build_parser() -> argparse.ArgumentParser:
             ("ssm_state_size", int, 0, "state a head carries, a channel"),
             ("ssm_conv_width", int, 0, "taps of the mixer's causal convolution"),
             ("ssm_groups", int, 1, "groups of heads that share B and C"),
-            ("ssm_chunk", int, 0, "tokens a chunk of the mixer's scan")):
+            ("ssm_chunk", int, 0, "tokens a chunk of the mixer's scan"),
+            ("kda_conv_width", int, 0, "taps of a kda layer's convolutions"),
+            ("kda_gate_bound", float, 0.0, "lower bound of a kda layer's log-decay"),
+            ("latent_rank", int, 0, "width of a latent_attention layer's latent"),
+            ("qk_nope_size", int, 0, "unrotated part of its query and key heads"),
+            ("qk_rope_size", int, 0, "rotated part (the key's shared by all heads)"),
+            ("v_head_size", int, 0, "width of its value heads"),
+            ("route_groups", int, 0, "groups the router chooses inside (0 = none)"),
+            ("groups_per_token", int, 0, "groups a token's experts come from")):
         dec.add_argument(f"--{name}", type=kind, default=default, help=text)
     dec.add_argument("--position_embedding", type=str, default="rope",
                      choices=("rope", "nope"),
                      help="nope: attention rotates nothing, in any layer")
     dec.add_argument("--head_gate", action="store_true", dest="head_gate",
                      help="sigmoid gate on the attention output, a head")
+    dec.add_argument("--route_bias", action="store_true", dest="route_bias",
+                     help="a bias a routed expert on the scores that choose")
     dec.add_argument("--tie_embeddings", action="store_true",
                      dest="tie_embeddings",
                      help="the head is the embedding table itself")

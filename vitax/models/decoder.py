@@ -22,7 +22,14 @@ The second model family (`Config.model_family == "decoder"`), built by
 - F: a SwiGLU of `ffn_dim` in a `dense` layer, the routed and shared experts
   of vitax/models/experts.py in a `sparse` one.
 - A `mamba` layer has the state-space mixer of vitax/models/ssm.py in place
-  of W_o[g * Attn]. `attention` is another word for `full_attention`.
+  of W_o[g * Attn], a `kda` layer the delta-rule mixer of
+  vitax/models/kda.py (`layer_heads[i]` heads of `head_size`; it rotates
+  nothing). `attention` is another word for `full_attention`.
+- A `latent_attention` layer (MLA, `LatentAttention`): keys and values come
+  up from a normed latent of `latent_rank`; a head's query and key are
+  `qk_nope_size` + `qk_rope_size` wide, the rotated `qk_rope_size` of the
+  key ONE for all heads (the full layers' RoPE table), its value
+  `v_head_size`; every earlier key of the document is visible.
 - What a model may state beside its layers: `position_embedding` nope (no
   layer rotates anything), `attention_multiplier` on the scores in place of
   head_size ** -0.5, `embedding_multiplier` on the embedded tokens,
@@ -41,7 +48,7 @@ below.
 from __future__ import annotations
 
 import math
-from typing import Any, Callable, List, Optional, Tuple
+from typing import Any, Callable, List, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -50,11 +57,14 @@ import numpy as np
 
 from vitax.config import Config
 from vitax.models.experts import SharedRoutedExperts, SwiGLU, Table
+from vitax.models.kda import KDAMixer, KDAShape, kda_param_count
 from vitax.models.ssm import MixerShape, SSDMixer, mixer_param_count
 from vitax.models.vit import Array, Dtype, default_init
 
 SLIDING = "sliding_attention"
 MAMBA = "mamba"
+KDA = "kda"
+LATENT = "latent_attention"
 
 
 # --- rotary position embedding (pure functions) -----------------------------
@@ -108,11 +118,11 @@ def apply_rope(x: Array, cos: Array, sin: Array) -> Array:
 def causal_masked_attention(q: Array, k: Array, v: Array, segment_ids: Array,
                             window: int, dtype: Dtype,
                             scale: float = 0.0) -> Array:
-    """The dense fallback: q (R, T, H, Dh), k and v (R, T, KV, Dh), each
+    """The dense fallback: q and k (R, T, H | KV, Dh), v (R, T, KV, Dv), each
     key/value head serving H / KV query heads; a key is visible from a query
     of its own document, not before it and (window > 0) fewer than `window`
     positions back; scores times `scale` (0 = Dh ** -0.5). Padding comes back
-    zero."""
+    zero, (R, T, H, Dv)."""
     r, t, h, dh = q.shape
     kv = k.shape[2]
     qg = q.reshape(r, t, kv, h // kv, dh)
@@ -128,7 +138,7 @@ def causal_masked_attention(q: Array, k: Array, v: Array, segment_ids: Array,
     see = see[:, None, None]
     p = jax.nn.softmax(jnp.where(see, s, -1e30), axis=-1)
     p = jnp.where(see, p, 0.0).astype(dtype)
-    return jnp.einsum("rkgts,rskd->rtkgd", p, v).reshape(r, t, h, dh)
+    return jnp.einsum("rkgts,rskd->rtkgd", p, v).reshape(r, t, h, v.shape[-1])
 
 
 def layer_runs(kinds, heads, mlps) -> List[Tuple[Tuple[str, int, str], int]]:
@@ -202,6 +212,60 @@ class DecoderAttention(nn.Module):
         return _linear(d, self.dtype, "wo")(out.reshape(r, t, h * dh))
 
 
+class LatentShape(NamedTuple):
+    rank: int
+    nope: int
+    rope: int
+    value: int
+
+
+class LatentAttention(nn.Module):
+    """Latent attention (MLA, without a query latent): `rope` is the (cos,
+    sin) of the `shape.rope` rotated dimensions."""
+
+    heads: int
+    shape: LatentShape
+    head_gate: bool
+    norm_eps: float
+    dtype: Dtype = jnp.bfloat16
+    attention_impl: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x: Array, segment_ids: Array,
+                 rope: Tuple[Array, Array]) -> Array:
+        r, t, d = x.shape
+        h, s = self.heads, self.shape
+        q = _linear(h * (s.nope + s.rope), self.dtype, "wq")(x).reshape(
+            r, t, h, s.nope + s.rope)
+        with jax.named_scope("mla_latent"):
+            latent, k_rope = jnp.split(_linear(
+                s.rank + s.rope, self.dtype, "wkva")(x), [s.rank], axis=-1)
+            latent = RMSNorm(self.norm_eps, self.dtype,
+                             name="latent_norm")(latent)
+            k_nope, v = jnp.split(_linear(
+                h * (s.nope + s.value), self.dtype, "wkvb")(latent).reshape(
+                    r, t, h, s.nope + s.value), [s.nope], axis=-1)
+            # the rotated key is one for all heads; the kernels' operand
+            # holds a head's whole key, so it is laid beside each head's own
+            # part here, once
+            k_rope = apply_rope(k_rope[:, :, None, :], *rope)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope, (r, t, h, s.rope))], axis=-1)
+        with jax.named_scope("rope1d"):
+            q = jnp.concatenate(
+                [q[..., :s.nope], apply_rope(q[..., s.nope:], *rope)], axis=-1)
+        if self.attention_impl is None:
+            out = causal_masked_attention(q, k, v, segment_ids, 0, self.dtype)
+        else:
+            out = self.attention_impl(q, k, v, segment_ids, 0, 0.0)
+        if self.head_gate:
+            with jax.named_scope("head_gate"):
+                gate = jax.nn.sigmoid(_linear(h, self.dtype, "head_gate")(
+                    x).astype(jnp.float32))
+                out = (out * gate[..., None]).astype(self.dtype)
+        return _linear(d, self.dtype, "wo")(out.reshape(r, t, h * s.value))
+
+
 class DecoderBlock(nn.Module):
     """One layer; `shape` = (kind, heads, mlp) is what a run's layers share."""
 
@@ -226,6 +290,9 @@ class DecoderBlock(nn.Module):
     residual_multiplier: float = 1.0
     mixer: Optional[MixerShape] = None      # a mamba layer's
     scan_impl: Optional[Callable] = None    # ... and its scan (None: plain)
+    kda: Optional[Tuple[int, float]] = None     # a kda layer's taps and bound
+    latent: Optional[LatentShape] = None    # a latent_attention layer's
+    route: Tuple[int, int, bool] = (0, 0, False)    # groups, kept, bias
 
     def _added(self, y: Array) -> Array:
         if self.residual_multiplier == 1.0:
@@ -243,6 +310,16 @@ class DecoderBlock(nn.Module):
         if kind == MAMBA:
             y = SSDMixer(self.mixer, self.norm_eps, self.dtype,
                          scan=self.scan_impl, name="mixer")(y, segment_ids)
+        elif kind == KDA:
+            y = KDAMixer(KDAShape(heads, self.head_size, *self.kda),
+                         self.norm_eps, self.dtype, name="mixer")(
+                y, segment_ids)
+        elif kind == LATENT:
+            y = LatentAttention(
+                heads=heads, shape=self.latent, head_gate=self.head_gate,
+                norm_eps=self.norm_eps, dtype=self.dtype,
+                attention_impl=self.attention_impl, name="attn",
+            )(y, segment_ids, rope_full)
         else:
             y = DecoderAttention(
                 heads=heads, kv_heads=self.kv_heads, head_size=self.head_size,
@@ -263,7 +340,9 @@ class DecoderBlock(nn.Module):
                 expert_first=self.expert_first,
                 experts_per_token=self.experts_per_token,
                 expert_dim=self.expert_dim, shared_dim=self.shared_expert_dim,
-                routed_scale=self.routed_scale, dtype=self.dtype, name="moe",
+                routed_scale=self.routed_scale, dtype=self.dtype,
+                route_groups=self.route[0], groups_per_token=self.route[1],
+                route_bias=self.route[2], name="moe",
             )(y, segment_ids > 0)
         return x + self._added(y)
 
@@ -344,6 +423,9 @@ class Decoder(nn.Module):
     logits_scaling: float = 1.0
     mixer: Optional[MixerShape] = None
     scan_impl: Optional[Callable] = None
+    kda: Optional[Tuple[int, float]] = None
+    latent: Optional[LatentShape] = None
+    route: Tuple[int, int, bool] = (0, 0, False)
 
     def runs(self) -> List[Tuple[Tuple[str, int, str], int]]:
         return layer_runs(self.layer_kinds, self.layer_heads, self.layer_mlps)
@@ -397,7 +479,8 @@ class Decoder(nn.Module):
             token_sharding=self.token_sharding,
             attention_scale=self.attention_scale,
             residual_multiplier=self.residual_multiplier, mixer=self.mixer,
-            scan_impl=self.scan_impl)
+            scan_impl=self.scan_impl, kda=self.kda, latent=self.latent,
+            route=self.route)
         for i, (shape, length) in enumerate(self.runs()):
             x = Run(length=length,
                     block_kwargs=tuple({**block_kwargs,
@@ -436,10 +519,10 @@ def _decoder_attention_saveable(prim, *_, **params):
 def keeps_attention_residuals(model: Decoder, kind: str) -> bool:
     """PR 30's rule (vitax/models/vit.py: keeps_attention_residuals) by the
     span of a run's layers: a full layer's query meets a whole row, a sliding
-    layer's at most `window_tokens` keys. A mamba layer has no attention
-    kernel to keep anything of."""
+    layer's at most `window_tokens` keys. A mamba or kda layer has no
+    attention kernel to keep anything of."""
     from vitax.models.vit import keeps_attention_residuals as rule
-    return kind != MAMBA and rule(model, span=model.span(kind))
+    return kind not in (MAMBA, KDA) and rule(model, span=model.span(kind))
 
 
 def run_remat_policy(model: Decoder, kind: str):
@@ -478,7 +561,11 @@ def build_decoder(cfg: Config, attention_impl: Optional[Callable] = None,
         residual_multiplier=cfg.residual_multiplier,
         attention_scale=cfg.attention_multiplier,
         logits_scaling=cfg.logits_scaling, mixer=mixer_shape(cfg),
-        scan_impl=scan_impl)
+        scan_impl=scan_impl,
+        kda=((cfg.kda_conv_width, cfg.kda_gate_bound)
+             if KDA in cfg.layer_kinds else None),
+        latent=latent_shape(cfg),
+        route=(cfg.route_groups, cfg.groups_per_token, cfg.route_bias))
 
 
 def mixer_shape(cfg: Config) -> Optional[MixerShape]:
@@ -489,6 +576,14 @@ def mixer_shape(cfg: Config) -> Optional[MixerShape]:
         heads=cfg.ssm_heads, head_size=cfg.ssm_head_size,
         state_size=cfg.ssm_state_size, conv_width=cfg.ssm_conv_width,
         groups=cfg.ssm_groups, chunk=cfg.ssm_chunk)
+
+
+def latent_shape(cfg: Config) -> Optional[LatentShape]:
+    """The shape of the latent_attention layers, None in a model without."""
+    if LATENT not in cfg.layer_kinds:
+        return None
+    return LatentShape(rank=cfg.latent_rank, nope=cfg.qk_nope_size,
+                       rope=cfg.qk_rope_size, value=cfg.v_head_size)
 
 
 def sample_documents(cfg: Config, batch: int):
@@ -507,6 +602,15 @@ def expected_param_count(cfg: Config) -> int:
         total += 2 * d                                      # the two norms
         if kind == MAMBA:
             total += mixer_param_count(mixer_shape(cfg), d)
+        elif kind == KDA:
+            total += kda_param_count(KDAShape(
+                heads, dh, cfg.kda_conv_width, cfg.kda_gate_bound), d)
+        elif kind == LATENT:
+            s = latent_shape(cfg)
+            total += (d * heads * (s.nope + s.rope) + d * (s.rank + s.rope)
+                      + s.rank + s.rank * heads * (s.nope + s.value)
+                      + heads * s.value * d)
+            total += d * heads if cfg.head_gate else 0
         else:
             total += 2 * d * heads * dh + 2 * d * cfg.kv_heads * dh
             total += d * heads if cfg.head_gate else 0
@@ -514,6 +618,7 @@ def expected_param_count(cfg: Config) -> int:
             total += 3 * d * cfg.ffn_dim
         else:
             total += (d * cfg.experts_routed
+                      + (cfg.experts_routed if cfg.route_bias else 0)
                       + 3 * d * cfg.expert_dim * cfg.experts_held
                       + 3 * d * cfg.shared_expert_dim)
     return total

@@ -5,15 +5,27 @@ The feed-forward of a sparse decoder layer (vitax/models/decoder.py):
     y = sum_{k in top-K} w_k E_k(x)  +  S(x)
 
 `s = sigmoid(W_r x)` scores ALL `experts_routed` experts in float32, the K
-best are chosen over all of them, `w = routed_scale * s_k / sum_topK s`; every
-`E_k` and the shared `S` is a SwiGLU. The layer is told which experts it
-holds (`experts_held` from `expert_first` on: one chip's share of a
-deployment in which several chips share each layer) and adds the terms whose
-expert it holds, and the shared expert; what the absent experts would add is
-left out. With `experts_held == experts_routed` it is the whole layer. There
-is no exchange and nothing stands in for the absent chips: expert
-parallelism over a mesh axis would put the all-to-all pair around
-`expert_ffn` and is not built.
+best are chosen over all of them (the plain path: `route_groups` 0, no
+bias), `w = routed_scale * s_k / sum_topK s`; every `E_k` and the shared `S`
+is a SwiGLU. The layer is told which experts it holds (`experts_held` from
+`expert_first` on: one chip's share of a deployment in which several chips
+share each layer) and adds the terms whose expert it holds, and the shared
+expert; what the absent experts would add is left out. With `experts_held
+== experts_routed` it is the whole layer. There is no exchange and nothing
+stands in for the absent chips: expert parallelism over a mesh axis would
+put the all-to-all pair around `expert_ffn` and is not built.
+
+What groups and a bias change is the CHOICE alone (`choose`, the DeepSeek-V3
+router): with `route_bias` the experts are ranked by s' = s + bias (a
+float32 leaf an expert, which receives no gradient; the balance update that
+moves it in a real job is the trainer's and is not built); with
+`route_groups` G the experts form G equal groups in index order, a group
+scores the sum of its two best s', the `groups_per_token` best groups are
+kept and the K best s' are taken inside them. The weights come from the
+unbiased s of the chosen, as on the plain path. The layer still scores all
+experts, keeps all groups and all K a token; `tokens_choosing_held_group`
+(sown beside `expert_load`) counts the tokens that kept the group of the
+first held expert.
 
 No token is dropped: the (token, choice) slots whose expert is held are
 sorted by expert and the held experts' three products run as grouped matrix
@@ -33,6 +45,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from vitax.models.ssm import Leaf
 from vitax.models.vit import Array, Dtype, default_init
 
 
@@ -118,6 +131,25 @@ def _combine_bwd(res, dy):
 combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def choose(scores: Array, bias, k: int, groups: int, groups_kept: int):
+    """The `k` experts a token is sent to and their scores: (top (N, K) taken
+    from `scores` (N, E), chosen (N, K), kept (N, groups) bool or None). The
+    ranking is by `scores + bias` (`bias` (E,) or None, no gradient), inside
+    the `groups_kept` best of `groups` equal groups, a group scored by the
+    sum of its two best ranked experts (0 groups: over all experts)."""
+    ranked = scores if bias is None else scores + jax.lax.stop_gradient(bias)
+    kept = None
+    if groups:
+        n, e = scores.shape
+        best_two, _ = jax.lax.top_k(ranked.reshape(n, groups, e // groups), 2)
+        _, best = jax.lax.top_k(jnp.sum(best_two, axis=-1), groups_kept)
+        kept = jnp.any(best[:, :, None] == jnp.arange(groups), axis=1)
+        ranked = jnp.where(jnp.repeat(kept, e // groups, axis=1), ranked,
+                           -jnp.inf)
+    _, chosen = jax.lax.top_k(ranked, k)
+    return jnp.take_along_axis(scores, chosen, axis=-1), chosen, kept
+
+
 class SharedRoutedExperts(nn.Module):
     """(R, T, D) -> (R, T, D); `valid` (R, T) bool marks real tokens (padding
     is routed nowhere and counted in no expert's load)."""
@@ -130,6 +162,9 @@ class SharedRoutedExperts(nn.Module):
     shared_dim: int
     routed_scale: float = 1.0
     dtype: Dtype = jnp.bfloat16
+    route_groups: int = 0           # 0: the K best over all experts
+    groups_per_token: int = 0
+    route_bias: bool = False
 
     @nn.compact
     def __call__(self, x: Array, valid: Array) -> Array:
@@ -142,7 +177,20 @@ class SharedRoutedExperts(nn.Module):
                 self.experts_routed, use_bias=False, dtype=jnp.float32,
                 param_dtype=jnp.float32, kernel_init=default_init,
                 name="router")(xf.astype(jnp.float32)))           # (N, E)
-            top, chosen = jax.lax.top_k(scores, k)                # (N, K)
+            if self.route_groups or self.route_bias:
+                bias = Leaf((self.experts_routed,), nn.initializers.zeros,
+                            "bias", name="router_bias")() \
+                    if self.route_bias else None
+                top, chosen, kept = choose(scores, bias, k, self.route_groups,
+                                           self.groups_per_token)
+                if kept is not None:
+                    mine = self.expert_first // (
+                        self.experts_routed // self.route_groups)
+                    self.sow("intermediates", "tokens_choosing_held_group",
+                             jnp.sum(kept[:, mine] & valid.reshape(n),
+                                     dtype=jnp.int32))
+            else:
+                top, chosen = jax.lax.top_k(scores, k)            # (N, K)
             weights = self.routed_scale * top / jnp.sum(
                 top, axis=-1, keepdims=True)
             local = chosen - self.expert_first

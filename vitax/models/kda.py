@@ -1,0 +1,301 @@
+"""Kimi Delta Attention over packed documents: a delta rule in chunked form.
+
+The mixer of a `kda` layer of the token decoder (vitax/models/decoder.py), in
+place of attention (Kimi Linear, arXiv:2510.26692, with a lower-bounded
+gate). With `u` the normed input of a token and H heads of `head_size`
+channels:
+
+    q, k, v = silu(conv(W_q u)), silu(conv(W_k u)), silu(conv(W_v u))
+                  depthwise, causal, `conv_width` taps, no bias
+    q = l2norm(q) * head_size ** -0.5,   k = l2norm(k)             a head
+    g = L * sigmoid(exp(A_log) * (W_f u + dt_bias))    in (L, 0), a head AND channel
+    b = sigmoid(W_b u)                                 a head
+    S_t = (I - b_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + b_t k_t v_t^T
+    o_t = S_t^T q_t                                    S (head_size, head_size), float32
+    out = W_o[ RMSNorm(o_t) * w * sigmoid(W_g u_t) ]   normed a head, gated a head
+
+A document starts from S = 0 and its convolution sees no token of another
+document; padding (`segment_ids` 0) gives zeros and receives nothing
+(vitax/models/ssm.py's conventions, and its `causal_conv`).
+
+The recurrence runs in chunks of `chunk` tokens (`kda`). With G the running
+sum of g inside a chunk, u_t = b_t (v_t - k_t^T Diag(exp(g_t)) S_{t-1}) the
+corrected value of a token and S_0 the state the chunk begins with,
+
+    (I + A) U = Diag(b) (V - (K e^G) S_0),   A_ts = b_t (k_t e^{G_t}) . (k_s e^{-G_s}),  s < t
+    o_t = (q_t e^{G_t}) S_0 + sum_{s <= t} (q_t e^{G_t}) . (k_s e^{-G_s}) u_s
+    S_end = Diag(e^{G_end}) S_0 + sum_s (k_s e^{G_end - G_s}) u_s^T
+
+The decay is per channel, so it does not factor out of a (query, key)
+product as SSD's scalar does, and e^{-G} alone overflows: a product over
+channels takes e^{G_t - m} on the query's side and e^{m - G_s} on the key's,
+with m the running sum at the middle of the query's SUB-chunk of `sub`
+tokens; both exponents then stay within |L| * sub / 2 (a key of an earlier
+sub-chunk has a negative one). That is why the gate is bounded, and `sub`
+follows from the bound (`tiling`). The unit lower-triangular (I + A) is
+inverted as the nilpotent product (I - A)(I + A^2)(I + A^4)... in float32:
+matmuls, no scalar loop (scope `kda_chunk`). One float32 state a head goes
+from chunk to chunk in a `lax.scan` that solves U for the chunk, reads S_0
+for the chunk's queries and adds the intra-chunk term (scope `kda_state`).
+
+Document boundaries fall anywhere: a pair passes only within one document,
+so the triangular system decouples by document; only tokens of the document
+that owns the incoming state read it; the state a chunk ends with is made of
+the tokens of its last token's document and passes through a chunk only if
+the whole chunk lies in that document.
+
+g, G, every exp and every state are float32; the products take operands of
+the model's dtype and accumulate in float32, as `ssd` does. This plain
+`jax.numpy` form is the only one: the CPU's path, the tests' and the TPU's.
+The per-chunk part is made `KDA_BLOCK_BYTES` worth of chunks at a time and
+again in the backward (`jax.checkpoint`), as `ssd`'s is.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import NamedTuple, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from vitax.models.ssm import (Leaf, a_log_init, causal_conv, conv_init,
+                              dt_bias_init)
+from vitax.models.vit import Array, Dtype, default_init
+
+KDA_CHUNK = 64          # tokens a chunk, where the row's length allows
+# The grid the step's counters `kda_pairs` and `kda_live_chunks` count on
+# (vitax/train/step.py: decoder_counts), and so the grid of the delta rule's
+# useful FLOPs (vitax/telemetry/flops.py). A constant of its own and NOT
+# `tiling`'s choice: a change of KDA_CHUNK shows in the step's time, not in
+# what the step is said to need.
+KDA_COUNT_CHUNK = 64
+KDA_EXP_RANGE = 40.0    # the largest |exponent| a product's operand may take
+L2_EPS = 1e-6           # q and k: x * rsqrt(sum x^2 + eps)
+# float32 bytes of the (rows, chunks, sub-chunks, chunk, heads, head_size)
+# key-side decay that a block of chunks may hold
+KDA_BLOCK_BYTES = 256 * 2 ** 20
+
+
+class KDAShape(NamedTuple):
+    heads: int
+    head_size: int
+    conv_width: int
+    gate_bound: float       # L < 0: g lies in (L, 0)
+
+    @property
+    def inner(self) -> int:
+        return self.heads * self.head_size
+
+
+def kda_param_count(shape: KDAShape, embed_dim: int) -> int:
+    """W_q, W_k, W_v, W_f and W_o; the three convolutions; A_log, dt_bias;
+    W_b and W_g; the output norm's weight."""
+    return (5 * embed_dim * shape.inner + 3 * shape.conv_width * shape.inner
+            + shape.heads + shape.inner + 2 * embed_dim * shape.heads
+            + shape.head_size)
+
+
+def tiling(tokens: int, gate_bound: float) -> Tuple[int, int]:
+    """(chunk, sub) for rows of `tokens`: the longest chunk up to KDA_CHUNK
+    that divides the row, and the longest power-of-two sub-chunk over which
+    |gate_bound| * sub / 2 stays within KDA_EXP_RANGE."""
+    chunk = math.gcd(tokens, KDA_CHUNK)
+    sub = 1
+    while (sub * 2 <= chunk and chunk % (sub * 2) == 0
+           and abs(gate_bound) * sub <= KDA_EXP_RANGE):
+        sub *= 2
+    return chunk, sub
+
+
+def count_chunk(tokens: int) -> int:
+    """The chunk of the counters' grid for rows of `tokens`."""
+    return math.gcd(tokens, KDA_COUNT_CHUNK)
+
+
+def unit_lower_inverse(a: Array) -> Array:
+    """(I + a)^-1 for `a` (..., c, c) strictly lower triangular, float32:
+    with n = -a nilpotent, (I - n)^-1 = (I + n)(I + n^2)(I + n^4)..."""
+    c = a.shape[-1]
+    hi = jax.lax.Precision.HIGHEST
+    power = -a
+    out = jnp.eye(c, dtype=a.dtype) + power
+    for _ in range(max(math.ceil(math.log2(c)) - 1, 0)):
+        power = jnp.matmul(power, power, precision=hi)
+        out = out + jnp.matmul(out, power, precision=hi)
+    return out
+
+
+def _chunk_block(per_chunk_bytes: int, chunks: int) -> int:
+    """Chunks a block: the most that divide `chunks` within the budget."""
+    most = max(KDA_BLOCK_BYTES // per_chunk_bytes, 1)
+    return max(b for b in range(1, chunks + 1)
+               if chunks % b == 0 and b <= most)
+
+
+def kda(q: Array, k: Array, v: Array, g: Array, beta: Array,
+        segment_ids: Array, chunk: int, sub: int, dtype: Dtype) -> Array:
+    """The delta rule: q and k (R, T, H, K), v (R, T, H, V), g (R, T, H, K)
+    float32 and <= 0, beta (R, T, H) float32 in [0, 1], segment_ids (R, T)
+    with T a multiple of `chunk` and `chunk` of `sub` -> o (R, T, H, V)
+    float32. q, k, v, g and beta are zero at padding, and so is o."""
+    r, t, h, dk = q.shape
+    dv = v.shape[-1]
+    c, nc, a = chunk, t // chunk, chunk // sub
+    f32 = jnp.float32
+    seg = segment_ids.reshape(r, nc, c)
+    last = seg[:, :, -1]                            # who owns what a chunk leaves
+    owner = jnp.pad(last, ((0, 0), (1, 0)))[:, :nc]     # ... and what it is given
+    at = jnp.arange(c)
+    not_after = at[:, None] >= at[None, :]          # key not after query
+    # a key meets the queries of its own and of later sub-chunks
+    upto = (at[None, :] // sub) <= jnp.arange(a)[:, None]       # (a, c)
+
+    @jax.checkpoint
+    def block(args):
+        seg, owner, q, k, v, g, beta = args         # (R, chunks a block, c, ...)
+        n = seg.shape[1]
+        with jax.named_scope("kda_chunk"):
+            q32, k32 = q.astype(f32), k.astype(f32)
+            run = jnp.cumsum(g, axis=2)                             # R n l h k
+            by_sub = run.reshape(r, n, a, sub, h, dk)
+            mid = by_sub[:, :, :, sub // 2]                         # R n a h k
+            row = jnp.exp(by_sub - mid[:, :, :, None])              # R n a s h k
+            col = jnp.exp(jnp.where(
+                upto[None, None, :, :, None, None],
+                mid[:, :, :, None] - run[:, :, None], -jnp.inf))    # R n a j h k
+            keys = (k32[:, :, None] * col).astype(dtype)
+
+            def scores(x32):        # (x_l e^{G_l}) . (k_j e^{-G_j}): R n h l j
+                rows = (x32.reshape(r, n, a, sub, h, dk) * row).astype(dtype)
+                return jnp.einsum("rnashk,rnajhk->rnhasj", rows, keys,
+                                  preferred_element_type=f32).reshape(
+                                      r, n, h, c, c)
+
+            see = ((seg[:, :, :, None] == seg[:, :, None, :])
+                   & (seg[:, :, :, None] > 0))[:, :, None]          # R n 1 l j
+            bh = beta.transpose(0, 1, 3, 2)                         # R n h l
+            qk = jnp.where(see & not_after, scores(q32), 0.0)
+            kk = jnp.where(see & (not_after & ~not_after.T), scores(k32),
+                           0.0) * bh[..., None]
+            solve = unit_lower_inverse(kk).astype(dtype)            # R n h l s
+            reads = ((seg == owner[..., None]) & (seg > 0))[..., None, None]
+            from_start = jnp.where(reads, jnp.exp(run), 0.0)        # R n l h k
+            b4 = beta[..., None]
+            w = jnp.einsum("rnhls,rnshk->rnhlk", solve,
+                           (k32 * from_start * b4).astype(dtype),
+                           preferred_element_type=f32)
+            u0 = jnp.einsum("rnhls,rnshv->rnhlv", solve,
+                            (v.astype(f32) * b4).astype(dtype),
+                            preferred_element_type=f32)
+            mine = ((seg == seg[:, :, -1:]) & (seg > 0))[..., None, None]
+            to_end = jnp.exp(jnp.where(mine, run[:, :, -1:] - run, -jnp.inf))
+            return (qk.astype(dtype), w.astype(dtype), u0,
+                    (q32 * from_start).astype(dtype).transpose(0, 1, 3, 2, 4),
+                    (k32 * to_end).astype(dtype).transpose(0, 1, 3, 2, 4),
+                    jnp.exp(run[:, :, -1]))                         # R n h k
+
+    def chunks(x):      # (R, T, ...) -> (R, nc, c, ...)
+        return x.reshape(r, nc, c, *x.shape[2:])
+
+    cb = _chunk_block(4 * r * a * c * h * dk, nc)
+
+    def blocked(x):     # (R, nc, ...) -> (nc / cb, R, cb, ...)
+        return jnp.moveaxis(x.reshape(r, nc // cb, cb, *x.shape[2:]), 1, 0)
+
+    def whole(x):       # and back
+        x = jnp.moveaxis(x, 0, 1)
+        return x.reshape(r, nc, *x.shape[3:])
+
+    args = (seg, owner, chunks(q), chunks(k), chunks(v), chunks(g),
+            chunks(beta))
+    if cb == nc:
+        parts = block(args)
+    else:
+        parts = tuple(map(whole, jax.lax.map(block,
+                                             tuple(map(blocked, args)))))
+    qk, w, u0, q_start, k_end, decay_end = parts
+
+    with jax.named_scope("kda_state"):
+        through = jnp.where(((last == owner) & (last > 0))[..., None, None],
+                            decay_end, 0.0)                         # R nc h k
+
+        @jax.checkpoint
+        def carry(state, inputs):
+            qk, w, u0, q_start, k_end, through = inputs
+            given = state.astype(dtype)
+            u = (u0 - jnp.einsum("rhlk,rhkv->rhlv", w, given,
+                                 preferred_element_type=f32)).astype(dtype)
+            o = (jnp.einsum("rhlk,rhkv->rhlv", q_start, given,
+                            preferred_element_type=f32)
+                 + jnp.einsum("rhls,rhsv->rhlv", qk, u,
+                              preferred_element_type=f32))
+            state = state * through[..., None] + jnp.einsum(
+                "rhlk,rhlv->rhkv", k_end, u, preferred_element_type=f32)
+            return state, o
+
+        _, o = jax.lax.scan(
+            carry, jnp.zeros((r, h, dk, dv), f32),
+            tuple(jnp.moveaxis(x, 1, 0)
+                  for x in (qk, w, u0, q_start, k_end, through)))
+        # (nc, R, h, c, v) -> (R, T, h, v)
+        return o.transpose(1, 0, 3, 2, 4).reshape(r, t, h, dv)
+
+
+class KDAMixer(nn.Module):
+    shape: KDAShape
+    norm_eps: float
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u: Array, segment_ids: Array) -> Array:
+        s = self.shape
+        r, t, d = u.shape
+        h, dh = s.heads, s.head_size
+        f32 = jnp.float32
+
+        def linear(features, name, dtype=self.dtype):
+            return nn.Dense(features, use_bias=False, dtype=dtype,
+                            param_dtype=f32, kernel_init=default_init,
+                            name=name)
+
+        valid = (segment_ids > 0)[..., None]
+        qkv = jnp.concatenate([linear(s.inner, n)(u)
+                               for n in ("wq", "wk", "wv")], axis=-1)
+        with jax.named_scope("kda_conv"):
+            taps = Leaf((s.conv_width, 3 * s.inner), conv_init, "kernel",
+                        name="conv")()
+            qkv = causal_conv(qkv, segment_ids, taps, 0.0)
+            qkv = jnp.where(valid, jax.nn.silu(qkv), 0.0)
+            q, k, v = (x.reshape(r, t, h, dh)
+                       for x in jnp.split(qkv, 3, axis=-1))
+
+            def l2norm(x):
+                return x * jax.lax.rsqrt(
+                    jnp.sum(jnp.square(x), axis=-1, keepdims=True) + L2_EPS)
+
+            q = (l2norm(q) * dh ** -0.5).astype(self.dtype)
+            k, v = l2norm(k).astype(self.dtype), v.astype(self.dtype)
+
+        with jax.named_scope("kda_gate"):
+            a_log = Leaf((h,), a_log_init, name="A_log")()
+            dt_bias = Leaf((s.inner,), dt_bias_init, "bias", name="dt_bias")()
+            f = linear(s.inner, "wf")(u).astype(f32) + dt_bias
+            g = s.gate_bound * jax.nn.sigmoid(
+                jnp.exp(a_log)[:, None] * f.reshape(r, t, h, dh))
+            g = jnp.where(valid[..., None], g, 0.0)
+            beta = jnp.where(valid, jax.nn.sigmoid(
+                linear(h, "wb")(u).astype(f32)), 0.0)
+
+        o = kda(q, k, v, g, beta, segment_ids,
+                *tiling(t, s.gate_bound), self.dtype)
+
+        with jax.named_scope("kda_out_norm"):
+            scale = Leaf((dh,), nn.initializers.ones, name="out_norm")()
+            gate = jax.nn.sigmoid(linear(h, "head_gate")(u).astype(f32))
+            o = o * jax.lax.rsqrt(
+                jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+                + self.norm_eps)
+            o = (o * scale * gate[..., None]).astype(self.dtype)
+        return linear(d, "wo")(o.reshape(r, t, s.inner))

@@ -888,8 +888,10 @@ def _packed_fwd(q, k, v, segment_ids, scale, bq, bk, hb, heads, skip,
     heads (grouped-query attention: (R * KV, T, Dh) beside q's (R * H, T,
     Dh), hb = H / KV), read inside the kernel: no repeated K or V exists.
     `tiles`: the kernels' sub-tile shapes, a `Tiles`, where not `_sub_tiles`'
-    (the tests and the sweep)."""
+    (the tests and the sweep). v may be narrower than q and k (a latent
+    layer's): the output has v's width."""
     bh, t, dh = q.shape
+    dv = v.shape[-1]
     nq, nk, gpr = t // bq, t // bk, heads // hb
     hk = 1 if grouped else hb
     sub = (tiles or _sub_tiles(window, bq, bk)).fwd
@@ -902,6 +904,10 @@ def _packed_fwd(q, k, v, segment_ids, scale, bq, bk, hb, heads, skip,
 
     qspec = pl.BlockSpec((hb, bq, dh), lambda b, i, j, *_: (b, i, 0))
     kspec = pl.BlockSpec((hk, bk, dh), lambda b, i, j, *t: (b, at_k(b, i, j, *t), 0))
+    ospec, vspec = qspec, kspec
+    if dv != dh:
+        ospec = pl.BlockSpec((hb, bq, dv), lambda b, i, j, *_: (b, i, 0))
+        vspec = pl.BlockSpec((hk, bk, dv), lambda b, i, j, *t: (b, at_k(b, i, j, *t), 0))
     o, lse = pl.pallas_call(
         functools.partial(_packed_fwd_kernel, scale=scale, hb=hb, gpr=gpr,
                           nq=nq, nk=nk, sub=sub,
@@ -910,22 +916,22 @@ def _packed_fwd(q, k, v, segment_ids, scale, bq, bk, hb, heads, skip,
             num_scalar_prefetch=2,
             grid=(bh // hb, nq, nk),
             in_specs=[
-                qspec, kspec, kspec,
+                qspec, kspec, vspec,
                 pl.BlockSpec((1, bq, 128), lambda b, i, j, *_: (b // gpr, i, 0)),
                 pl.BlockSpec((1, 8, bk),
                              lambda b, i, j, *t: (b // gpr, 0, at_k(b, i, j, *t))),
             ],
             out_specs=[
-                qspec,
+                ospec,
                 pl.BlockSpec((hb, 1, bq), lambda b, i, j, *_: (b, 0, i)),
             ],
             scratch_shapes=[
-                pltpu.VMEM((hb, bq, dh), jnp.float32),
+                pltpu.VMEM((hb, bq, dv), jnp.float32),
                 pltpu.VMEM((hb, bq, 128), jnp.float32),
                 pltpu.VMEM((hb, bq, 128), jnp.float32),
             ]),
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, dh), q.dtype),
+            jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
         ],
         compiler_params=_packed_params(),
@@ -940,6 +946,7 @@ def _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk, hb, heads,
                 skip, causal=False, window=0, grouped=False,
                 name="flash_packed", tiles=None, interpret=False):
     bh, t, dh = q.shape
+    dv = v.shape[-1]
     nq, nk, gpr = t // bq, t // bk, heads // hb
     hk = 1 if grouped else hb
     terms = _decoder_terms(causal, window, grouped)
@@ -957,24 +964,29 @@ def _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk, hb, heads,
 
     qspec = pl.BlockSpec((hb, bq, dh), lambda b, jk, jq, *t: (b, at_q(b, jk, jq, *t), 0))
     kspec = pl.BlockSpec((hk, bk, dh), lambda b, jk, jq, *_: (b, jk, 0))
+    dospec, vspec = qspec, kspec
+    if dv != dh:
+        dospec = pl.BlockSpec((hb, bq, dv), lambda b, jk, jq, *t: (b, at_q(b, jk, jq, *t), 0))
+        vspec = pl.BlockSpec((hk, bk, dv), lambda b, jk, jq, *_: (b, jk, 0))
     row = pl.BlockSpec((hb, 1, bq), lambda b, jk, jq, *t: (b, 0, at_q(b, jk, jq, *t)))
-    dk, dv = pl.pallas_call(
+    dk, dv_ = pl.pallas_call(
         functools.partial(_packed_dkv_kernel, scale=scale, hb=hb, gpr=gpr,
                           nq=nq, nk=nk, sub=tiles.dkv, **terms),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh // hb, nk, nq),
             in_specs=[
-                qspec, kspec, kspec, qspec, row, row,
+                qspec, kspec, vspec, dospec, row, row,
                 # keys down, queries across: the q ids a row, the k ids a column
                 pl.BlockSpec((1, 8, bq),
                              lambda b, jk, jq, *t: (b // gpr, 0, at_q(b, jk, jq, *t))),
                 pl.BlockSpec((1, bk, 128), lambda b, jk, jq, *_: (b // gpr, jk, 0)),
             ],
-            out_specs=[kspec, kspec],
+            out_specs=[kspec, vspec],
             scratch_shapes=[pltpu.VMEM((hk, bk, dh), jnp.float32),
-                            pltpu.VMEM((hk, bk, dh), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct(k.shape, q.dtype)] * 2,
+                            pltpu.VMEM((hk, bk, dv), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(k.shape, q.dtype),
+                   jax.ShapeDtypeStruct(v.shape, q.dtype)],
         compiler_params=_packed_params(),
         name=f"{name}_dkv",
         interpret=interpret,
@@ -985,6 +997,10 @@ def _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk, hb, heads,
 
     qspec = pl.BlockSpec((hb, bq, dh), lambda b, jq, jk, *_: (b, jq, 0))
     kspec = pl.BlockSpec((hk, bk, dh), lambda b, jq, jk, *t: (b, at_k(b, jq, jk, *t), 0))
+    dospec, vspec = qspec, kspec
+    if dv != dh:
+        dospec = pl.BlockSpec((hb, bq, dv), lambda b, jq, jk, *_: (b, jq, 0))
+        vspec = pl.BlockSpec((hk, bk, dv), lambda b, jq, jk, *t: (b, at_k(b, jq, jk, *t), 0))
     row = pl.BlockSpec((hb, 1, bq), lambda b, jq, jk, *_: (b, 0, jq))
     dq = pl.pallas_call(
         functools.partial(_packed_dq_kernel, scale=scale, hb=hb, gpr=gpr,
@@ -993,7 +1009,7 @@ def _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk, hb, heads,
             num_scalar_prefetch=2,
             grid=(bh // hb, nq, nk),
             in_specs=[
-                qspec, kspec, kspec, qspec, row, row,
+                qspec, kspec, vspec, dospec, row, row,
                 pl.BlockSpec((1, bq, 128), lambda b, jq, jk, *_: (b // gpr, jq, 0)),
                 pl.BlockSpec((1, 8, bk),
                              lambda b, jq, jk, *t: (b // gpr, 0, at_k(b, jq, jk, *t))),
@@ -1005,7 +1021,7 @@ def _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk, hb, heads,
         name=f"{name}_dq",
         interpret=interpret,
     )(live_qk, kidx, q, k, v, do, lse, delta, seg_cols, seg_rows)
-    return dq, dk, dv
+    return dq, dk, dv_
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
@@ -1107,6 +1123,36 @@ def _documents_bh_bwd(scale, bq, bk, group, heads, skip, window, res, do):
 _documents_bh.defvjp(_documents_bh_fwd, _documents_bh_bwd)
 
 
+# A latent layer (MLA, vitax/models/decoder.py: LatentAttention): every head
+# has a key of its own and q and k are wider than v. The same bodies, causal
+# and ungrouped (several heads a grid step, each reading its own key, as the
+# packed ViT's), under the names `flash_latent_*`.
+_LATENT_TERMS = dict(causal=True, name="flash_latent")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _latent_bh(q, k, v, segment_ids, scale, bq, bk, hb, heads, skip):
+    return _packed_fwd(q, k, v, segment_ids, scale, bq, bk, hb, heads, skip,
+                       **_LATENT_TERMS)[0]
+
+
+def _latent_bh_fwd(q, k, v, segment_ids, scale, bq, bk, hb, heads, skip):
+    o, lse = _packed_fwd(q, k, v, segment_ids, scale, bq, bk, hb, heads, skip,
+                         **_LATENT_TERMS)
+    return o, (q, k, v, o, lse, segment_ids)
+
+
+def _latent_bh_bwd(scale, bq, bk, hb, heads, skip, res, do):
+    import numpy as np
+    q, k, v, o, lse, segment_ids = res
+    dq, dk, dv = _packed_bwd(q, k, v, o, lse, do, segment_ids, scale, bq, bk,
+                             hb, heads, skip, **_LATENT_TERMS)
+    return dq, dk, dv, np.zeros(segment_ids.shape, jax.dtypes.float0)
+
+
+_latent_bh.defvjp(_latent_bh_fwd, _latent_bh_bwd)
+
+
 def document_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                              segment_ids: jax.Array, window: int = 0,
                              block_q: int = 0, block_k: int = 0,
@@ -1116,14 +1162,21 @@ def document_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     (0 = padding) -> (R, T, H, Dh), differentiable in q/k/v. `window` > 0: a
     query sees the `window` latest keys of its document, itself included.
     Scores times `scale` (0 = Dh ** -0.5). Padding rows come back zero.
-    `block_q`/`block_k`/`skip` exist for the tests."""
+    `block_q`/`block_k`/`skip` exist for the tests. Where v's width differs
+    from q's and k's (a latent layer: KV == H, no window) the kernels are
+    `flash_latent_*` and the output (R, T, H, v's width)."""
     r, t, h, dh = q.shape
-    kv = k.shape[2]
+    kv, dv = k.shape[2], v.shape[3]
     assert h % kv == 0, (h, kv)
     dq, dk = WINDOW_BLOCKS if window > 0 else CAUSAL_BLOCKS
     bq, bk, t_pad = _blocks_for(t, block_q or dq, block_k or dk)
     seg = jnp.pad(segment_ids.astype(jnp.int32), ((0, 0), (0, t_pad - t)))
     qb, kb, vb = (_pad_seq(_to_bh(x), t_pad) for x in (q, k, v))
+    if dv != dh:
+        assert kv == h and window == 0, (kv, h, window)
+        o = _latent_bh(qb, kb, vb, seg, float(scale) or dh ** -0.5, bq, bk,
+                       math.gcd(h, PACKED_HEADS_PER_STEP), h, skip)
+        return _from_bh(o[:, :t], (r, t, h, dv))
     o = _documents_bh(qb, kb, vb, seg, float(scale) or dh ** -0.5, bq, bk,
                       h // kv, h, skip, int(window))
     return _from_bh(o[:, :t], q.shape)

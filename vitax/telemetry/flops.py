@@ -99,7 +99,8 @@ def packed_flops_per_step(cfg, tokens: float, token_pairs: float,
 def decoder_flops_per_step(cfg, tokens: float, targets: float,
                            causal_pairs: float, window_pairs: float,
                            expert_slots: float,
-                           ssd_pairs: float = 0.0) -> float:
+                           ssd_pairs: float = 0.0,
+                           kda_pairs: float = 0.0) -> float:
     """Useful matmul FLOPs of one step of the token decoder
     (vitax/models/decoder.py), fwd+bwd (3x forward), from the step's own
     counters (vitax/train/step.py: decoder_counts): `tokens` valid (the
@@ -110,9 +111,15 @@ def decoder_flops_per_step(cfg, tokens: float, targets: float,
     experts), and `targets` (the head). A mamba layer (vitax/models/ssm.py):
     its two projections by `tokens`, its scan by `ssd_pairs` (C.B and the
     masked product over x, a pair of one chunk and one document) and by
-    `tokens` (the state a chunk leaves and the state a token reads). Padding,
-    the masked part of a block and sorted rows no held expert owns are not
-    counted."""
+    `tokens` (the state a chunk leaves and the state a token reads). A kda
+    layer (vitax/models/kda.py): its five projections and two head-wise
+    ones by `tokens`, its delta rule by `kda_pairs` (the two score products,
+    the triangular solve of the corrected keys and values, and the
+    intra-chunk output) and by `tokens` (the three products with the
+    state). A latent_attention layer: its projections by `tokens`, QK^T at
+    qk_nope_size + qk_rope_size and PV at v_head_size by `causal_pairs`.
+    Padding, the masked part of a block and sorted rows no held expert owns
+    are not counted."""
     d, dh = cfg.embed_dim, cfg.head_size
     inner, gn = cfg.ssm_heads * cfg.ssm_head_size, \
         cfg.ssm_groups * cfg.ssm_state_size
@@ -124,6 +131,17 @@ def decoder_flops_per_step(cfg, tokens: float, targets: float,
             per_token += 2 * inner * d
             per_token += 4 * inner * cfg.ssm_state_size
             fwd += 2 * (gn + inner) * ssd_pairs
+        elif kind == "kda":
+            per_token = 2 * d * heads * (5 * dh + 2)
+            per_token += 6 * heads * dh * dh
+            fwd += 10 * heads * dh * kda_pairs
+        elif kind == "latent_attention":
+            qk, dv = cfg.qk_nope_size + cfg.qk_rope_size, cfg.v_head_size
+            per_token = 2 * d * (heads * qk + cfg.latent_rank
+                                 + cfg.qk_rope_size + heads * dv)
+            per_token += 2 * cfg.latent_rank * heads * (cfg.qk_nope_size + dv)
+            per_token += 2 * d * heads if cfg.head_gate else 0
+            fwd += 2 * causal_pairs * heads * (qk + dv)
         else:
             per_token = 2 * (2 * d * heads * dh + 2 * d * cfg.kv_heads * dh)
             per_token += 2 * d * heads if cfg.head_gate else 0

@@ -133,9 +133,11 @@ class Recorder:
         the record holds computed / needed: `attn_computed_over_needed`, or
         `causal_` / `window_computed_over_needed`. A decoder step's
         (`targets`, `causal_pairs`, `window_pairs`, `expert_slots_here` in
-        place of `token_pairs`; `images` are documents) are written into the
-        record as they are, with `expert_load`, its per-layer per-expert
-        load."""
+        place of `token_pairs`; `images` are documents; a scan's `ssd_pairs`
+        and `ssd_live_chunks`, a delta rule's `kda_pairs` and
+        `kda_live_chunks`, a grouped router's `tokens_choosing_held_group`)
+        are written into the record as they are, with `expert_load`, its
+        per-layer per-expert load."""
         images, tokens = self.cfg.batch_size, self.tokens_per_step
         flops_per_step = self.flops_per_step
         if packed_counts is not None:
@@ -146,7 +148,8 @@ class Recorder:
                     packed_counts["causal_pairs"],
                     packed_counts["window_pairs"],
                     packed_counts["expert_slots_here"],
-                    packed_counts.get("ssd_pairs", 0.0))
+                    packed_counts.get("ssd_pairs", 0.0),
+                    packed_counts.get("kda_pairs", 0.0))
             else:
                 flops_per_step = packed_flops_per_step(
                     self.cfg, tokens, packed_counts["token_pairs"], images)
@@ -180,7 +183,8 @@ class Recorder:
         if packed_counts is not None and self.cfg.decoder:
             record.update({k: packed_counts[k] for k in (
                 "targets", "causal_pairs", "window_pairs",
-                "expert_slots_here", "ssd_pairs", "ssd_live_chunks")
+                "expert_slots_here", "ssd_pairs", "ssd_live_chunks",
+                "kda_pairs", "kda_live_chunks", "tokens_choosing_held_group")
                 if k in packed_counts}, expert_load=expert_load)
         if grad_norm is not None:
             record["grad_norm"] = float(grad_norm)

@@ -102,7 +102,7 @@ def _attention_remat_note(cfg: Config, model, mesh) -> str:
         return "; remat " + ", ".join(
             f"{'keeps o and lse' if keeps(model, kind) else 'runs the forward again'}"
             f" in {kind} layers (span {model.span(kind)})"
-            for kind in sorted(set(cfg.layer_kinds) - {"mamba"}))
+            for kind in sorted(set(cfg.layer_kinds) - {"mamba", "kda"}))
     span = attention_span(model)
     if (mesh.shape.get("pp", 1) > 1 or gather_overlap_active(cfg, mesh)
             or cfg.remat_window > 1):
@@ -125,7 +125,10 @@ DECODER_COUNTERS = ("tokens", "padding_tokens", "images", "targets",
                     "causal_pairs", "window_pairs", "causal_computed_pairs",
                     "window_computed_pairs", "expert_slots_here",
                     # a model with mamba layers only:
-                    "ssd_pairs", "ssd_live_chunks")
+                    "ssd_pairs", "ssd_live_chunks",
+                    # ... with kda layers, with a router that has groups:
+                    "kda_pairs", "kda_live_chunks",
+                    "tokens_choosing_held_group")
 PACKED_COUNTERS = ("tokens", "padding_tokens", "images", "token_pairs",
                    "computed_pairs")
 

@@ -190,7 +190,9 @@ def decoder_counts(cfg: Config, batch: Dict[str, jax.Array]
     A model with mamba layers (vitax/models/ssm.py) also counts its scan's
     work on the grid of `ssm_chunk` tokens: `ssd_pairs`, the pairs of a
     query and a key not after it in one chunk and one document, and
-    `ssd_live_chunks`, the chunks that hold a valid token."""
+    `ssd_live_chunks`, the chunks that hold a valid token; a model with kda
+    layers (vitax/models/kda.py) the same two on a grid of 64 tokens fixed
+    for counting (`count_chunk`): `kda_pairs` and `kda_live_chunks`."""
     seg = batch["segment_ids"]
     n = jnp.sum(seg[..., None] == jnp.arange(1, cfg.pack_images + 1),
                 axis=1, dtype=jnp.int32).astype(jnp.float32)      # (R, S)
@@ -198,13 +200,20 @@ def decoder_counts(cfg: Config, batch: Dict[str, jax.Array]
     valid = jnp.sum(seg > 0, dtype=jnp.int32)
     documents = jnp.sum(n > 0, dtype=jnp.int32)
     ssd = {}
-    if "mamba" in cfg.layer_kinds:
-        chunks = seg.reshape(seg.shape[0], -1, cfg.ssm_chunk)
+
+    def on_grid(chunk: int):    # (pairs, live chunks) of a scan in chunks
+        chunks = seg.reshape(seg.shape[0], -1, chunk)
         m = jnp.sum(chunks[..., None] == jnp.arange(1, cfg.pack_images + 1),
                     axis=2, dtype=jnp.int32).astype(jnp.float32)  # (R, C, S)
-        ssd = dict(ssd_pairs=jnp.sum(m * (m + 1) / 2),
-                   ssd_live_chunks=jnp.sum(jnp.any(chunks > 0, axis=-1),
-                                           dtype=jnp.int32))
+        return (jnp.sum(m * (m + 1) / 2),
+                jnp.sum(jnp.any(chunks > 0, axis=-1), dtype=jnp.int32))
+
+    if "mamba" in cfg.layer_kinds:
+        ssd["ssd_pairs"], ssd["ssd_live_chunks"] = on_grid(cfg.ssm_chunk)
+    if "kda" in cfg.layer_kinds:
+        from vitax.models.kda import count_chunk
+        ssd["kda_pairs"], ssd["kda_live_chunks"] = on_grid(
+            count_chunk(cfg.pack_tokens))
     return dict(
         ssd,
         tokens=valid, padding_tokens=seg.size - valid, images=documents,
@@ -335,8 +344,10 @@ def make_train_step(
     anchor_logits = _make_logits_anchor(mesh)
 
     def decoder_loss_fn(params, batch, rng):
-        """(loss, per-layer per-expert load (sparse layers, held experts)):
-        the expert layers sow their load (vitax/models/experts.py)."""
+        """(loss, (per-layer per-expert load (sparse layers, held experts),
+        the tokens that kept the held experts' group, over the sparse layers;
+        None where the router has no groups)): the expert layers sow both
+        (vitax/models/experts.py)."""
         del rng                      # no dropout arm (Config.validate)
         if comm is not None:
             params = comm.cast(params)
@@ -345,7 +356,9 @@ def make_train_step(
         loads = _select_by_name(cols, "expert_load")
         load = (jnp.concatenate([x.reshape(-1, x.shape[-1]) for x in loads])
                 if loads else jnp.zeros((0, 0), jnp.int32))
-        return decoder_loss(logits, batch), load
+        kept = _select_by_name(cols, "tokens_choosing_held_group")
+        kept = sum(jnp.sum(x) for x in kept) if kept else None
+        return decoder_loss(logits, batch), (load, kept)
 
     def loss_fn(params, batch, rng):
         if comm is not None:
@@ -501,7 +514,7 @@ def make_train_step(
             params = state.params
         expert_load = None
         if cfg.decoder:
-            (loss, expert_load), grads = jax.value_and_grad(
+            (loss, (expert_load, kept_group)), grads = jax.value_and_grad(
                 decoder_loss_fn, has_aux=True)(params, batch, step_rng)
         elif use_1f1b:
             loss, grads = vag_1f1b(params, prepare_images(batch["image"]),
@@ -529,6 +542,8 @@ def make_train_step(
             metrics.update(decoder_counts(cfg, batch))
             metrics.update(expert_load=expert_load,
                            expert_slots_here=jnp.sum(expert_load))
+            if kept_group is not None:
+                metrics.update(tokens_choosing_held_group=kept_group)
         elif cfg.packed:
             # what a packed step did is in its batch, not in the config:
             # counted on the device from the segment ids and the label mask
