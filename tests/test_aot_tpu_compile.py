@@ -352,6 +352,51 @@ def test_state_space_scan_compiles_and_lies_under_its_scopes(chip, mosaic):
     assert len(calls) == 2 and all(c in found for c in calls), (calls, kernels)
 
 
+def test_delta_rule_compiles_and_lies_under_its_scope(chip, mosaic):
+    """The fused delta rule (vitax/ops/kda.py) through the mixer, at the Ling
+    cell's shape (1 x 4,096 tokens, 16 heads of 128, chunks of 64 in
+    sub-chunks of 16): real Mosaic lowering and compile of forward and
+    backward, and every `kda_*` custom call of the compiled text has
+    `kda_chunk` in its `op_name` path: the join `benchmark/scopes.py:index`
+    makes for `kda_roofline` and `kda_mixer_busy_pct`."""
+    import re
+
+    from vitax.config import Config
+    from vitax.models.kda import KDAMixer, KDAShape
+    from vitax.ops.kda import HEADS_PER_STEP, make_kda_impl
+    one_chip, _ = chip
+    cfg = Config(
+        model_family="decoder", embed_dim=2048, num_blocks=1, vocab_rows=128,
+        kv_heads=8, head_size=128, layer_kinds=["kda"], layer_heads=[16],
+        layer_mlps=["dense"], ffn_dim=128, kda_conv_width=4,
+        kda_gate_bound=-5.0, pack_tokens=4096, pack_images=4,
+        batch_size=1).validate()
+    rule = make_kda_impl(cfg, None, force_tpu_kernels=True)
+    assert rule.vitax_name == (f"fused kernel (chunk 64, sub-chunks of 16, "
+                               f"{HEADS_PER_STEP} heads a grid step)")
+    mixer = KDAMixer(KDAShape(16, 128, 4, -5.0), 1e-6, jnp.bfloat16,
+                     rule=rule)
+    u = jax.ShapeDtypeStruct((1, 4096, 2048), jnp.bfloat16, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip),
+        jax.eval_shape(mixer.init, jax.random.key(0), u, seg))
+    compiled = jax.jit(jax.grad(lambda p, u, seg: jnp.sum(
+        mixer.apply(p, u, seg).astype(jnp.float32)), argnums=(0, 1))).lower(
+            params, u, seg).compile()
+    kernels = [k for k in _kernel_names(compiled) if "/kda_" in k]
+    assert sorted(k.rsplit("/", 2)[-2] for k in kernels) == \
+        ["kda_bwd", "kda_fwd"], kernels
+    from benchmark import scopes
+    text = compiled.as_text()
+    found = scopes.index(text, ("kda_chunk", "kda_state"))
+    calls = [re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=", ln).group(1)
+             for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln and "/kda_" in ln]
+    assert len(calls) == 2 and all(found.get(c) == "kda_chunk"
+                                   for c in calls), (calls, kernels)
+
+
 def test_latent_attention_forward_and_vjp_compile(chip, mosaic):
     """The packed causal kernels at the Ling cell's latent layer: one row of
     4,096 tokens, 16 heads each with a key of its own, q and k 192 wide
@@ -383,8 +428,12 @@ def test_the_ling_cells_step_compiles_and_fits(chip, mosaic):
     published widths (seven layers, 648.9M parameters with Adam's state, one
     row of 4,096 tokens), through the cell's own `lower_described`: the
     chip's compiler takes it, it fits the 15.75 GB the compiler allows, the
-    latent layer's three kernels and the fused optimizer are in it, and the
-    delta rule's products lie under the scopes `kda_roofline` reads."""
+    latent layer's three kernels, the delta rule's two (a forward a layer's
+    forward and its remat, a backward) and the fused optimizer are in it, and
+    the delta rule lies under the scope `kda_roofline` reads (`kda_chunk`:
+    the fused form has no `kda_state` of its own)."""
+    import re
+
     from benchmark import harness, scopes
     from benchmark import manifest as mf
     _, topo = chip
@@ -395,6 +444,15 @@ def test_the_ling_cells_step_compiles_and_fits(chip, mosaic):
     lowered, what = gen.lower_described(man.config_kwargs(config), traffic,
                                         list(topo.devices)[:1])
     assert what == "decoder train step, 1 rows of 4096 tokens"
+    # three runs of kda layers, each with a forward, a remat's forward and a
+    # backward: nine sites, and the module holds the backward kernel once and
+    # the forward once a set of outputs (with the states the backward reads,
+    # and without), each behind a `func.call`: vitax/ops/kda.py's `jax.jit`s
+    text = lowered.as_text()
+    assert sorted(re.findall(r'kernel_name = "(kda_\w+)"', text)) == [
+        "kda_bwd", "kda_fwd", "kda_fwd"]
+    assert len(re.findall(r"call @_backward\w*\(", text)) == 3
+    del text
     compiled = lowered.compile()
     step_bytes = harness.program_facts(compiled)["step_bytes"]
     assert 0.25 * 16.909e9 < step_bytes <= 15.75e9, step_bytes
@@ -402,7 +460,10 @@ def test_the_ling_cells_step_compiles_and_fits(chip, mosaic):
     for part in ("fwd", "dkv", "dq"):
         assert any(f"flash_latent_{part}" in k for k in kernels), kernels
     assert any("fused_adamw" in k for k in kernels)
+    for part in ("kda_fwd", "kda_bwd"):
+        assert any("kda_chunk" in k and f"/{part}/" in k
+                   for k in kernels), kernels
     found = set(scopes.index(compiled.as_text(), gen.SCOPES).values())
-    assert {"kda_conv", "kda_gate", "kda_chunk", "kda_state", "kda_out_norm",
+    assert {"kda_conv", "kda_gate", "kda_chunk", "kda_out_norm",
             "mla_latent", "moe_route", "moe_dispatch", "expert_ffn",
             "moe_combine", "shared_expert"} <= found, found

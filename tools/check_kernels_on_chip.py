@@ -148,6 +148,7 @@ def main():
 
     ok &= check_fused_optimizer()
     ok &= check_dequant_matmul()
+    ok &= check_delta_rule()
     print("ON-CHIP KERNEL NUMERICS:", "OK" if ok else "FAILED")
     return 0 if ok else 1
 
@@ -276,6 +277,46 @@ def check_dequant_matmul() -> bool:
             if err >= tol:
                 ok = False
     return ok
+
+
+def check_delta_rule() -> bool:
+    """The fused delta rule (`kda_fwd` / `kda_bwd`, vitax/ops/kda.py) at the
+    Ling cell's shape and layout (one row of 4,096 tokens, 16 heads of 128,
+    `packed_1x4096_tracemix`: every later document starts inside a chunk)
+    against the plain `kda` compiled on the chip, o and the five gradients in
+    bfloat16; and the inverse alone, whose float32 products a single bf16
+    pass would leave 1e-3 from X (I + A) = I. Operands, distances and the
+    inverse's kernel are tools/bench_kda.py's."""
+    from tools import bench_kda as bench
+    from vitax.models.kda import kda, tiling
+    from vitax.ops.kda import kda_fused
+
+    seg, ops, weight = bench.operands()
+    chunk, sub = tiling(seg.shape[1], bench.GATE_BOUND)
+
+    def run(rule):
+        def total(*a):
+            o = rule(*a, seg, chunk, sub, jnp.bfloat16)
+            return jnp.sum(o * weight), o
+
+        (_, o), grads = jax.jit(jax.value_and_grad(
+            total, argnums=tuple(range(5)), has_aux=True))(*ops)
+        return (o, *grads)
+
+    ok = True
+    # the same roundings in the forward: o to float32's last bits; gradients
+    # to bf16's, g's through a cumsum of terms that cancel
+    for tag, got, want, tol in zip(("o",) + bench.NAMES, run(kda_fused),
+                                   run(kda), (1e-5, 1e-2, 1e-2, 1e-2, 5e-2,
+                                              1e-2)):
+        err = bench.gap(got, want)
+        print(f"  delta rule 1x4096x16x128 {tag:5s} rel-norm-err {err:.2e} "
+              f"{'ok' if err < tol else 'FAIL'}")
+        ok &= err < tol
+    residual = bench.inverse_residual()
+    print(f"  delta rule inverse max |X (I + A) - I| {residual:.2e} "
+          f"{'ok' if residual < 1e-5 else 'FAIL: not float32 products'}")
+    return ok and residual < 1e-5
 
 
 if __name__ == "__main__":

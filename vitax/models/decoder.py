@@ -291,6 +291,7 @@ class DecoderBlock(nn.Module):
     mixer: Optional[MixerShape] = None      # a mamba layer's
     scan_impl: Optional[Callable] = None    # ... and its scan (None: plain)
     kda: Optional[Tuple[int, float]] = None     # a kda layer's taps and bound
+    kda_impl: Optional[Callable] = None     # ... and its delta rule (None: plain)
     latent: Optional[LatentShape] = None    # a latent_attention layer's
     route: Tuple[int, int, bool] = (0, 0, False)    # groups, kept, bias
 
@@ -312,8 +313,8 @@ class DecoderBlock(nn.Module):
                          scan=self.scan_impl, name="mixer")(y, segment_ids)
         elif kind == KDA:
             y = KDAMixer(KDAShape(heads, self.head_size, *self.kda),
-                         self.norm_eps, self.dtype, name="mixer")(
-                y, segment_ids)
+                         self.norm_eps, self.dtype, rule=self.kda_impl,
+                         name="mixer")(y, segment_ids)
         elif kind == LATENT:
             y = LatentAttention(
                 heads=heads, shape=self.latent, head_gate=self.head_gate,
@@ -424,6 +425,7 @@ class Decoder(nn.Module):
     mixer: Optional[MixerShape] = None
     scan_impl: Optional[Callable] = None
     kda: Optional[Tuple[int, float]] = None
+    kda_impl: Optional[Callable] = None
     latent: Optional[LatentShape] = None
     route: Tuple[int, int, bool] = (0, 0, False)
 
@@ -479,8 +481,8 @@ class Decoder(nn.Module):
             token_sharding=self.token_sharding,
             attention_scale=self.attention_scale,
             residual_multiplier=self.residual_multiplier, mixer=self.mixer,
-            scan_impl=self.scan_impl, kda=self.kda, latent=self.latent,
-            route=self.route)
+            scan_impl=self.scan_impl, kda=self.kda, kda_impl=self.kda_impl,
+            latent=self.latent, route=self.route)
         for i, (shape, length) in enumerate(self.runs()):
             x = Run(length=length,
                     block_kwargs=tuple({**block_kwargs,
@@ -534,7 +536,8 @@ def run_remat_policy(model: Decoder, kind: str):
 
 def build_decoder(cfg: Config, attention_impl: Optional[Callable] = None,
                   token_sharding=None,
-                  scan_impl: Optional[Callable] = None) -> Decoder:
+                  scan_impl: Optional[Callable] = None,
+                  kda_impl: Optional[Callable] = None) -> Decoder:
     return Decoder(
         embed_dim=cfg.embed_dim, vocab_rows=cfg.vocab_rows,
         layer_kinds=cfg.layer_kinds, layer_heads=cfg.layer_heads,
@@ -564,6 +567,7 @@ def build_decoder(cfg: Config, attention_impl: Optional[Callable] = None,
         scan_impl=scan_impl,
         kda=((cfg.kda_conv_width, cfg.kda_gate_bound)
              if KDA in cfg.layer_kinds else None),
+        kda_impl=kda_impl,
         latent=latent_shape(cfg),
         route=(cfg.route_groups, cfg.groups_per_token, cfg.route_bias))
 

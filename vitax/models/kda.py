@@ -46,15 +46,18 @@ the whole chunk lies in that document.
 
 g, G, every exp and every state are float32; the products take operands of
 the model's dtype and accumulate in float32, as `ssd` does. This plain
-`jax.numpy` form is the only one: the CPU's path, the tests' and the TPU's.
-The per-chunk part is made `KDA_BLOCK_BYTES` worth of chunks at a time and
-again in the backward (`jax.checkpoint`), as `ssd`'s is.
+`jax.numpy` form is the CPU's path and the tests' oracle; on a TPU, where the
+shapes tile, `KDAMixer.rule` is the fused kernel pair of vitax/ops/kda.py
+(`make_kda_impl`, through `build_model_for` as the scan of a mamba layer is),
+which keeps a chunk's decayed keys, scores and inverse in VMEM. Here the
+per-chunk part is made `KDA_BLOCK_BYTES` worth of chunks at a time and again
+in the backward (`jax.checkpoint`), as `ssd`'s is.
 """
 
 from __future__ import annotations
 
 import math
-from typing import NamedTuple, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -247,6 +250,7 @@ class KDAMixer(nn.Module):
     shape: KDAShape
     norm_eps: float
     dtype: Dtype = jnp.bfloat16
+    rule: Optional[Callable] = None     # the delta rule (None: the plain `kda`)
 
     @nn.compact
     def __call__(self, u: Array, segment_ids: Array) -> Array:
@@ -288,8 +292,8 @@ class KDAMixer(nn.Module):
             beta = jnp.where(valid, jax.nn.sigmoid(
                 linear(h, "wb")(u).astype(f32)), 0.0)
 
-        o = kda(q, k, v, g, beta, segment_ids,
-                *tiling(t, s.gate_bound), self.dtype)
+        o = (self.rule or kda)(q, k, v, g, beta, segment_ids,
+                               *tiling(t, s.gate_bound), self.dtype)
 
         with jax.named_scope("kda_out_norm"):
             scale = Leaf((dh,), nn.initializers.ones, name="out_norm")()

@@ -262,6 +262,10 @@ def train(cfg: Config) -> TrainState:
         from vitax.ops.ssd import scan_choice
         master_print("state-space scan: " + (
             getattr(model.scan_impl, "vitax_name", "") or scan_choice(cfg)[1]))
+    if cfg.decoder and "kda" in cfg.layer_kinds:
+        from vitax.ops.kda import kda_choice
+        master_print("delta rule: " + (
+            getattr(model.kda_impl, "vitax_name", "") or kda_choice(cfg)[1]))
     # the loop owns the state: a restore or a warm start replaces it, every
     # step donates it
     state, geom.state = geom.state, None
