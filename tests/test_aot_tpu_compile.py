@@ -467,3 +467,39 @@ def test_the_ling_cells_step_compiles_and_fits(chip, mosaic):
     assert {"kda_conv", "kda_gate", "kda_chunk", "kda_out_norm",
             "mla_latent", "moe_route", "moe_dispatch", "expert_ffn",
             "moe_combine", "shared_expert"} <= found, found
+
+
+def test_the_olmo_hybrid_cells_step_compiles_and_fits(chip, mosaic):
+    """The whole train step of `olmo_hybrid_7b_tp2vp8_train_packed4k` at the
+    published widths (four layers, 766.2M parameters with Adam's state, one
+    row of 4,096 tokens), through the cell's own `lower_described`: the
+    chip's compiler takes it, it fits the 15.75 GB the compiler allows, the
+    attention layer's three `flash_causal_*` kernels and the fused optimizer
+    are in it and NO `kda_fwd` (a 96 x 192 state under one decay a head is
+    none the delta rule's kernels tile: the plain form runs), and the delta
+    rule, the norms after and the QK-norm lie under the scopes the cell's
+    readers read."""
+    from benchmark import harness, scopes
+    from benchmark import manifest as mf
+    _, topo = chip
+    man = mf.Manifest()
+    cell = man.cell("olmo_hybrid_7b_tp2vp8_train_packed4k")
+    config, traffic = man.config(cell["config"]), man.traffic(cell["traffic"])
+    gen = mf.generator(traffic["kind"])
+    lowered, what = gen.lower_described(man.config_kwargs(config), traffic,
+                                        list(topo.devices)[:1])
+    assert what == "decoder train step, 1 rows of 4096 tokens"
+    compiled = lowered.compile()
+    step_bytes = harness.program_facts(compiled)["step_bytes"]
+    assert 0.25 * 16.909e9 < step_bytes <= 15.75e9, step_bytes
+    kernels = _kernel_names(compiled)
+    # one attention layer, whose remat keeps o and lse: each kernel once
+    import re
+    assert sorted(re.search(r"flash_causal_\w+", k).group() for k in kernels
+                  if "flash_" in k) == [
+        "flash_causal_dkv", "flash_causal_dq", "flash_causal_fwd"], kernels
+    assert any("fused_adamw" in k for k in kernels)
+    assert not any("kda_fwd" in k or "kda_bwd" in k for k in kernels)
+    found = set(scopes.index(compiled.as_text(), gen.SCOPES).values())
+    assert {"kda_conv", "kda_gate", "kda_chunk", "kda_state", "kda_out_norm",
+            "post_norm", "qk_norm", "lm_head_loss"} <= found, found

@@ -1,0 +1,483 @@
+"""The fourth decoder shape (Olmo-Hybrid: Gated-DeltaNet layers to one
+full-attention layer in Olmo's norm-after block with QK-norm over the whole
+projected width; vitax/models/decoder.py, kda.py) at small sizes on the CPU,
+seeded weights: the program against the plain reference
+(benchmark/reference/olmo_hybrid.py) for the whole 4-layer model in float32
+and in bf16 beside a float8 control, the share of the heads tied to the
+uncut layer, the closed-form parameter count and the catalog's count a
+layer, the step's counters, the configuration's sentences, the flags and the
+loop. The delta rule itself: tests/test_gated_delta.py."""
+
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import olmo_hybrid as reference
+from tests.test_latent_decoder import LENGTHS, documents, make_batch, moved
+from vitax.config import Config, parse_config
+from vitax.models import decoder
+from vitax.models.kda import GatedDeltaMixer, GatedDeltaShape
+
+KINDS = ["linear_attention"] * 3 + ["full_attention"]
+TINY = dict(
+    model_family="decoder", embed_dim=32, num_blocks=4, vocab_rows=48,
+    kv_heads=2, head_size=8, layer_kinds=KINDS, layer_heads=[2] * 4,
+    layer_mlps=["dense"] * 4, ffn_dim=48, norm_eps=1e-6,
+    position_embedding="nope", gdn_key_size=6, gdn_value_size=12,
+    gdn_conv_width=4, norm_after=True, qk_norm=True, pack_tokens=32,
+    pack_images=4, batch_size=2, dtype="float32")
+# the configuration of the benchmark's cell under the program's names
+OLMO = dict(
+    model_family="decoder", embed_dim=3840, num_blocks=4, vocab_rows=12544,
+    kv_heads=15, head_size=128, layer_kinds=KINDS, layer_heads=[15] * 4,
+    layer_mlps=["dense"] * 4, ffn_dim=11008, norm_eps=1e-6,
+    position_embedding="nope", gdn_key_size=96, gdn_value_size=192,
+    gdn_conv_width=4, norm_after=True, qk_norm=True, pack_tokens=4096,
+    pack_images=5, batch_size=1)
+
+
+def reference_shape(cfg):
+    return dict(layer_types=list(cfg.layer_kinds), head_dim=cfg.head_size,
+                eps=cfg.norm_eps,
+                linear=dict(key_dim=cfg.gdn_key_size,
+                            value_dim=cfg.gdn_value_size,
+                            taps=cfg.gdn_conv_width))
+
+
+@pytest.fixture(scope="module")
+def setup():
+    cfg = Config(**TINY).validate()
+    model = decoder.build_decoder(cfg)
+    variables = moved(jax.jit(lambda: model.init(
+        jax.random.key(0), decoder.sample_documents(cfg, 1), True))())
+    return cfg, model, variables, make_batch(cfg)
+
+
+@pytest.fixture(scope="module")
+def plain(setup):
+    """The reference's loss, gradients and logits at each document's first
+    and last position, computed once for the tests that hold them."""
+    cfg, _, variables, batch = setup
+    docs = documents(batch)
+    ats = [jnp.asarray([0, len(d) - 1]) for d in docs]
+    with jax.default_matmul_precision("highest"):
+        return reference.loss_grads_and_logits(variables, docs, ats,
+                                               **reference_shape(cfg))
+
+
+# --- the whole model ----------------------------------------------------------
+
+def test_logits_match_the_reference(setup):
+    cfg, model, variables, batch = setup
+    got = np.asarray(jax.jit(lambda v: model.apply(v, batch, True))(variables))
+    seg = np.asarray(batch["segment_ids"])
+    assert np.abs(got).max() > 0.2
+
+    @jax.jit
+    def alone(ids):         # a document followed by zeros it cannot see
+        with jax.default_matmul_precision("highest"):
+            return reference.logits(variables, ids, **reference_shape(cfg))
+
+    for r in range(seg.shape[0]):
+        for s in range(1, seg[r].max() + 1):
+            at = np.where(seg[r] == s)[0]
+            want = alone(jnp.pad(batch["tokens"][r, at],
+                                 (0, seg.shape[1] - len(at))))[:len(at)]
+            np.testing.assert_allclose(got[r, at], want, rtol=2e-4,
+                                       atol=2e-5)
+    assert float(np.abs(got[seg == 0]).max()) < 10.0      # finite at padding
+
+
+def test_loss_and_every_gradient_leaf_match_the_reference(setup, plain):
+    from vitax.train.step import decoder_loss
+    cfg, model, variables, batch = setup
+    want_loss, want = jax.jit(jax.value_and_grad(lambda v: decoder_loss(
+        model.apply(v, batch, True), batch)))(variables)
+    loss, grads, rows = plain
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
+    flat = jax.tree_util.tree_leaves_with_path(want)
+    # embedding, head, final norm; the linear run's 11 mixer leaves, 3 of
+    # the MLP and 2 norms; the attention run's 6, 3 and 2
+    assert len(flat) == len(jax.tree.leaves(grads)) == 3 + 16 + 11
+    for (path, a), b in zip(flat, jax.tree.leaves(grads)):
+        assert float(jnp.max(jnp.abs(a))) > 0.0, path
+        assert reference.relative_gap(b, a) < 2e-3, jax.tree_util.keystr(path)
+    np.testing.assert_allclose(
+        reference.global_norm(reference.leaf_norms(grads)),
+        reference.global_norm(reference.leaf_norms(want)), rtol=1e-4)
+    logits = np.asarray(model.apply(variables, batch, True))
+    first = logits[0, [0, LENGTHS[0][0] - 1]]   # row 0's first document
+    np.testing.assert_allclose(rows[0], first, rtol=2e-4, atol=2e-5)
+
+
+def test_bfloat16_stays_inside_limits_that_a_float8_control_breaks(setup,
+                                                                   plain):
+    """The benchmark's control (weights rounded to float8_e4m3 for the
+    program, the reference on the seeded ones) against the program in the
+    precision the configuration states, gradient by gradient and on the
+    logits: one limit between the two, as the cell's `correct` has."""
+    from benchmark.generators.train_gated_delta_packed import (
+        round_to_float8, watched_leaves)
+    from vitax.train.step import decoder_loss
+    cfg, _, variables, batch = setup
+    model = decoder.build_decoder(Config(**{**TINY, "dtype": "bfloat16"}))
+
+    @jax.jit
+    def grads_and_logits(v):
+        grads = jax.grad(lambda v: decoder_loss(
+            model.apply(v, batch, True), batch))(v)
+        return watched_leaves(grads, cfg), model.apply(v, batch, True)
+
+    want = watched_leaves(plain[1], cfg)
+    assert sorted(want) == [
+        "attention.q_norm", "attention.wq", "first.conv", "first.post_norm",
+        "first.wa", "first.wb", "first.wq", "first.wz", "last.conv",
+        "last.wa", "last.wb", "last.wq", "last.wz", "linear.A_log",
+        "linear.dt_bias"]
+    # A_log and dt_bias of the three linear layers together
+    assert want["linear.A_log"].shape == want["linear.dt_bias"].shape == (6,)
+    assert want["first.conv"].shape == (4, 2 * (6 + 6 + 12))
+    assert want["attention.q_norm"].shape == (16,)
+    (sound, logits), (control, off) = (
+        grads_and_logits(variables),
+        grads_and_logits(round_to_float8(variables)))
+    rows = plain[2]
+    at = [0, LENGTHS[0][0] - 1]
+    # at 32 wide with every leaf moved by 0.05 bf16 reads 0.07 on the logits
+    # and 0.18-0.53 on the leaves, the control 0.60 and 0.94-5.8
+    assert reference.relative_gap(logits[0, at], rows[0]) < 0.2
+    assert reference.relative_gap(off[0, at], rows[0]) > 0.2
+    for name in want:
+        assert reference.relative_gap(sound[name], want[name]) < 0.7, name
+        assert reference.relative_gap(control[name], want[name]) > 0.7, name
+
+
+def test_the_layer_pattern_and_its_runs(setup):
+    cfg, model, variables, _ = setup
+    assert decoder.layer_runs(cfg.layer_kinds, cfg.layer_heads,
+                              cfg.layer_mlps) == [
+        (("linear_attention", 2, "dense"), 3),
+        (("full_attention", 2, "dense"), 1)]
+    mixer = variables["params"]["run0"]["blocks"]["mixer"]
+    assert sorted(mixer) == ["A_log", "conv", "dt_bias", "out_norm", "wa",
+                             "wb", "wk", "wo", "wq", "wv", "wz"]
+    assert mixer["conv"]["kernel"].shape == (3, 4, 2 * (6 + 6 + 12))
+    assert mixer["wa"]["kernel"].shape == (3, 32, 2)      # one decay a head
+    assert mixer["A_log"]["scale"].shape == (3, 2)
+    assert mixer["dt_bias"]["bias"].shape == (3, 2)
+    assert mixer["wz"]["kernel"].shape == (3, 32, 24)     # a head AND channel
+    assert mixer["out_norm"]["scale"].shape == (3, 12)
+    attn = variables["params"]["run1"]["blocks"]["attn"]
+    assert sorted(attn) == ["k_norm", "q_norm", "wk", "wo", "wq", "wv"]
+    assert attn["q_norm"]["scale"].shape == (1, 16)       # the whole width
+
+
+def test_the_scopes_a_metric_reads_are_in_the_lowered_program(setup):
+    cfg, model, variables, batch = setup
+    text = jax.jit(lambda v: model.apply(v, batch, True)).lower(
+        variables).as_text(debug_info=True)
+    for scope in ("kda_conv", "kda_gate", "kda_chunk", "kda_state",
+                  "kda_out_norm", "post_norm", "qk_norm"):
+        assert f"{scope}/" in text, scope
+    # a pre-norm model has neither of the two new ones
+    plain_cfg = Config(**{**TINY, "norm_after": False, "qk_norm": False})
+    plain_model = decoder.build_decoder(plain_cfg)
+    shapes = jax.eval_shape(lambda: plain_model.init(
+        jax.random.key(0), decoder.sample_documents(plain_cfg, 1), True))
+    text = jax.jit(lambda v: plain_model.apply(v, batch, True)).lower(
+        shapes).as_text(debug_info=True)
+    assert "post_norm/" not in text and "qk_norm/" not in text
+
+
+def test_remat_keeps_o_and_lse_of_the_attention_layer_only():
+    cfg = Config(**{**TINY, "pack_tokens": 2048,
+                    "dtype": "bfloat16"}).validate()
+    model = decoder.build_decoder(cfg, attention_impl=lambda *a: a[0])
+    assert decoder.keeps_attention_residuals(model, "full_attention")
+    assert not decoder.keeps_attention_residuals(model, "linear_attention")
+    assert decoder.run_remat_policy(model, "linear_attention") is None
+
+
+# --- the share tied to the model ------------------------------------------------
+
+def _heads(leaf, heads, width, axis, part):
+    """The `part`-th half of the heads of a leaf whose `axis` is heads x
+    width."""
+    shape = leaf.shape
+    split = leaf.reshape(*shape[:axis], heads, width, *shape[axis + 1:])
+    half = jnp.take(split, jnp.arange(heads // 2) + part * (heads // 2),
+                    axis=axis)
+    return half.reshape(*shape[:axis], heads // 2 * width, *shape[axis + 1:])
+
+
+def test_the_two_halves_of_the_heads_add_up_to_the_uncut_layer():
+    """What the two chips that divide the heads hold of a Gated-DeltaNet
+    mixer and of the attention layer, each run alone, adds up BEFORE the norm
+    after W_o to what the uncut reference gives for the whole layer: the
+    mixer's per-head states, channels and output rows as they are, the
+    attention's with the QK-norm's mean square taken over the whole width
+    (the one statistic of the mixer itself that would cross the chips). And
+    the program on a half is the reference on that half, each taking the
+    statistic over what it holds."""
+    d, heads, dk, dv, dh, n = 32, 4, 6, 12, 8, 24
+    x = jax.random.normal(jax.random.key(3), (n, d))
+    seg = jnp.ones((1, n), jnp.int32)
+
+    # Gated DeltaNet
+    whole = GatedDeltaMixer(GatedDeltaShape(heads, dk, dv, 4), 1e-6,
+                            jnp.float32)
+    p = moved(jax.jit(whole.init)(jax.random.key(0), x[None], seg))["params"]
+    with jax.default_matmul_precision("highest"):
+        uncut = jax.jit(lambda p: reference.gated_delta_mixer(
+            x, p, 1e-6, key_dim=dk, value_dim=dv, taps=4))(p)
+    taps = p["conv"]["kernel"]
+    q_taps, k_taps, v_taps = jnp.split(taps, [heads * dk, 2 * heads * dk], 1)
+    total = 0.0
+    for part in (0, 1):
+        half = {
+            "wq": {"kernel": _heads(p["wq"]["kernel"], heads, dk, 1, part)},
+            "wk": {"kernel": _heads(p["wk"]["kernel"], heads, dk, 1, part)},
+            "wv": {"kernel": _heads(p["wv"]["kernel"], heads, dv, 1, part)},
+            "wz": {"kernel": _heads(p["wz"]["kernel"], heads, dv, 1, part)},
+            "wo": {"kernel": _heads(p["wo"]["kernel"], heads, dv, 0, part)},
+            "wa": {"kernel": _heads(p["wa"]["kernel"], heads, 1, 1, part)},
+            "wb": {"kernel": _heads(p["wb"]["kernel"], heads, 1, 1, part)},
+            "A_log": {"scale": _heads(p["A_log"]["scale"], heads, 1, 0, part)},
+            "dt_bias": {"bias": _heads(p["dt_bias"]["bias"], heads, 1, 0,
+                                       part)},
+            "conv": {"kernel": jnp.concatenate(
+                [_heads(q_taps, heads, dk, 1, part),
+                 _heads(k_taps, heads, dk, 1, part),
+                 _heads(v_taps, heads, dv, 1, part)], axis=1)},
+            "out_norm": p["out_norm"]}
+        held = GatedDeltaMixer(GatedDeltaShape(heads // 2, dk, dv, 4), 1e-6,
+                               jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            got = jax.jit(held.apply)({"params": half}, x[None], seg)[0]
+            want = jax.jit(lambda p: reference.gated_delta_mixer(
+                x, p, 1e-6, key_dim=dk, value_dim=dv, taps=4))(half)
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+        total = total + got
+    np.testing.assert_allclose(total, uncut, rtol=2e-4, atol=2e-5)
+    assert float(jnp.max(jnp.abs(uncut))) > 0.05
+
+    # the attention layer
+    whole = decoder.DecoderAttention(
+        heads=heads, kv_heads=heads, head_size=dh, window=0, head_gate=False,
+        dtype=jnp.float32, qk_norm=1e-6)
+    p = moved(whole.init(jax.random.key(1), x[None], seg, None))["params"]
+    with jax.default_matmul_precision("highest"):
+        uncut = reference.attention_mixer(x, p, 1e-6, head_dim=dh)
+        of_whole = tuple(jnp.mean(jnp.square(x @ p[w]["kernel"]), axis=-1,
+                                  keepdims=True) for w in ("wq", "wk"))
+    total = 0.0
+    for part in (0, 1):
+        half = {w: {"kernel": _heads(p[w]["kernel"], heads, dh, 1, part)}
+                for w in ("wq", "wk", "wv")}
+        half["wo"] = {"kernel": _heads(p["wo"]["kernel"], heads, dh, 0, part)}
+        for w in ("q_norm", "k_norm"):
+            half[w] = {"scale": _heads(p[w]["scale"], heads, dh, 0, part)}
+        held = decoder.DecoderAttention(
+            heads=heads // 2, kv_heads=heads // 2, head_size=dh, window=0,
+            head_gate=False, dtype=jnp.float32, qk_norm=1e-6)
+        with jax.default_matmul_precision("highest"):
+            # program and reference alike: over what is held
+            np.testing.assert_allclose(
+                held.apply({"params": half}, x[None], seg, None)[0],
+                reference.attention_mixer(x, half, 1e-6, head_dim=dh),
+                rtol=2e-4, atol=2e-5)
+            total = total + reference.attention_mixer(
+                x, half, 1e-6, head_dim=dh, mean_squares=of_whole)
+    np.testing.assert_allclose(total, uncut, rtol=2e-4, atol=2e-5)
+    assert float(jnp.max(jnp.abs(uncut))) > 0.05
+
+
+# --- counts, counters, configuration ----------------------------------------------
+
+def _count(cfg):
+    shapes = jax.eval_shape(
+        lambda: decoder.build_decoder(cfg).init(
+            jax.random.key(0), decoder.sample_documents(cfg, 1), True))
+    return sum(int(np.prod(a.shape)) for a in jax.tree.leaves(shapes))
+
+
+def test_closed_form_parameter_count_and_the_configurations():
+    cfg = Config(**TINY).validate()
+    variables = decoder.build_decoder(cfg).init(
+        jax.random.key(0), decoder.sample_documents(cfg, 1), True)
+    assert sum(a.size for a in jax.tree.leaves(variables)) \
+        == decoder.expected_param_count(cfg)
+    # the configuration of the benchmark's cell, by shapes alone
+    real = Config(**OLMO).validate()
+    assert _count(real) == decoder.expected_param_count(real) == 766_241_946
+    from benchmark import flops_olmo
+    from benchmark import manifest as mf
+    config = mf.Manifest().config("olmo_hybrid_7b_tp2vp8")
+    assert flops_olmo.param_count(config) == config["parameters"] \
+        == 766_241_946
+    built = Config(**mf.Manifest().config_kwargs(config), pack_tokens=4096,
+                   pack_images=5, batch_size=1).validate()
+    for key in OLMO:        # the nested block is the shape above
+        assert getattr(built, key) == getattr(real, key), key
+    # every head of a layer (the published 30): the catalog's 208M a layer,
+    # which a mixer without W_z or with a gate a head would not give
+    whole = Config(**{**OLMO, "kv_heads": 30, "layer_heads": [30] * 4})
+    tables = 2 * 12544 * 3840 + 3840
+    a_layer = (decoder.expected_param_count(whole) - tables) / 4
+    assert 208.0e6 < a_layer < 208.2e6
+    parts = flops_olmo.param_counts_by_part(
+        {**config, "num_attention_heads": 30, "num_key_value_heads": 30,
+         "linear_num_key_heads": 30, "linear_num_value_heads": 30,
+         "source_values": {}})
+    assert round(parts["linear_mixer"] / 1e6, 2) == 88.75
+    assert round(parts["attention_mixer"] / 1e6, 2) == 58.99
+    assert round(parts["mlp"] / 1e6, 2) == 126.81
+
+
+def test_train_step_counters_and_the_first_steps_moments():
+    """Documents of 13, 5, 9 and 20, 7 tokens in two rows of 32, the delta
+    rule's grid one chunk of 32 a row: 54 tokens, 10 of padding, 49 targets;
+    causal pairs 91 + 15 + 45 + 210 + 28; inside a chunk the same pairs (a
+    row is one chunk), both chunks live. And what the benchmark holds the
+    TIMED step to: the gradients read from the optimizer state its first call
+    left (`step_gradients`) are the model's own, with the clip at work."""
+    from benchmark.generators.train_gated_delta_packed import (
+        step_gradients, watched_leaves)
+    from vitax.programs.builder import Geometry, build_program
+    from vitax.train.step import decoder_inputs, decoder_loss
+    cfg = Config(**{**TINY, "warmup_steps": 1, "lr": 2e-3,
+                    "clip_grad_norm": 0.05}).validate()
+    geom = Geometry.assemble(cfg, 100, materialize=True,
+                             devices=jax.devices()[:1])
+    assert geom.model.kda_impl is None
+    state, geom.state = geom.state, None
+    step = build_program("train", geom)
+    batch = make_batch(cfg)
+    want = watched_leaves(jax.jit(jax.grad(
+        lambda v: decoder_loss(geom.model.apply(
+            v, decoder_inputs(batch), True), batch)))(state.params), cfg)
+    losses = []
+    for i in range(4):
+        state, m = step(state, batch, jax.random.key(1))
+        losses.append(float(m["loss"]))
+        if i == 0:
+            norm = float(m["grad_norm"])
+            assert norm > cfg.clip_grad_norm
+            got = step_gradients(state.opt_state, norm, cfg)
+            assert sorted(got) == sorted(want)
+            for name in want:
+                assert reference.relative_gap(got[name], want[name]) < 1e-5
+    got = {k: float(m[k]) for k in (
+        "tokens", "padding_tokens", "images", "targets", "causal_pairs",
+        "kda_pairs", "kda_live_chunks")}
+    assert got == dict(tokens=54, padding_tokens=10, images=5, targets=49,
+                       causal_pairs=389, kda_pairs=389, kda_live_chunks=2)
+    assert np.isfinite(losses).all() and losses[-1] < losses[0]
+    assert "ssd_pairs" not in m
+    from benchmark import flops_olmo
+    # the cell's layout (ISSUE 44) on the counters' fixed grid of 64
+    assert flops_olmo.layout_counts([[1900, 1100, 600, 300, 130]], 4096) \
+        == dict(tokens=4030, documents=5, targets=4025,
+                causal_pairs=2_645_465, kda_pairs=128_577,
+                kda_live_chunks=63, padding_tokens=66)
+
+
+def test_flops_count_the_new_kind_at_its_two_widths():
+    from vitax.telemetry.flops import decoder_flops_per_step
+    cfg = Config(**OLMO).validate()
+    flops = decoder_flops_per_step(cfg, 4030, 4025, 2_645_465, 0, 0, 0.0,
+                                   128_577)
+    # ISSUE 44: about 718M matmul parameters a token, 6 FLOPs each
+    assert 4.1e9 < flops / 4030 < 4.6e9
+    without = decoder_flops_per_step(cfg, 4030, 4025, 2_645_465, 0, 0, 0.0,
+                                     0.0)
+    assert flops - without == 3 * 3 * 15 * (6 * 96 + 4 * 192) * 128_577
+    fewer = decoder_flops_per_step(cfg, 4029, 4025, 2_645_465, 0, 0, 0.0,
+                                   128_577)
+    per_token = (3 * (2 * 3840 * 15 * (2 * 96 + 3 * 192 + 2)
+                      + 6 * 15 * 96 * 192 + 6 * 3840 * 11008)
+                 + 2 * 4 * 3840 * 15 * 128 + 6 * 3840 * 11008)
+    assert flops - fewer == 3 * per_token
+
+
+@pytest.mark.parametrize("change,message", [
+    (dict(gdn_key_size=0), "a linear_attention layer needs"),
+    (dict(gdn_value_size=0), "a linear_attention layer needs"),
+    (dict(gdn_conv_width=0), "a linear_attention layer needs"),
+    (dict(layer_heads=[2, 0, 2, 2]), "a linear_attention layer needs heads"),
+    (dict(layer_heads=[2, 2, 2, 3]), "multiple of --kv_heads"),
+    (dict(layer_kinds=["linear_attention"] * 3 + ["gated_delta"]),
+     "gated_delta"),
+])
+def test_config_refuses_what_is_not_built(change, message):
+    with pytest.raises(AssertionError, match=message):
+        Config(**{**TINY, **change}).validate()
+
+
+def test_a_linear_layers_heads_are_not_held_to_the_kv_heads():
+    cfg = Config(**{**TINY, "layer_heads": [3, 3, 3, 2]}).validate()
+    assert decoder.expected_param_count(cfg) == _count(cfg)
+
+
+def test_the_family_declares_the_new_shape_fields():
+    import os
+    from benchmark import forms
+    from benchmark import manifest as mf
+    olmo = forms.declared_keys(mf.read_json(
+        os.path.join(mf.BENCH_DIR, "shapes", "olmo_hybrid.json")))
+    assert {"gdn_key_size", "gdn_value_size", "gdn_conv_width", "norm_after",
+            "qk_norm"} <= olmo
+    assert not olmo & forms.knob_keys(forms.rules())
+
+
+def test_training_through_the_cli_path(tmp_path, capsys):
+    """`python -m vitax.train --fake_data --model_family decoder` with
+    linear_attention layers in a norm-after block with QK-norm (the flags
+    through `parse_config`, then the loop the entry point calls): a falling
+    loss, the delta rule's counters on the step records, and the start-up
+    line that says which delta rule runs and why; no flag selects a form."""
+    from vitax.train.loop import train
+    cfg = parse_config((
+        "--fake_data", "--model_family", "decoder", "--pack_tokens", "64",
+        "--pack_images", "6", "--embed_dim", "32", "--num_blocks", "4",
+        "--vocab_rows", "48", "--kv_heads", "2", "--head_size", "8",
+        "--layer_kinds",
+        "linear_attention,linear_attention,linear_attention,full_attention",
+        "--layer_heads", "2,2,2,2", "--layer_mlps", "dense,dense,dense,dense",
+        "--ffn_dim", "48", "--norm_eps", "1e-6", "--position_embedding",
+        "nope", "--gdn_key_size", "6", "--gdn_value_size", "12",
+        "--gdn_conv_width", "4", "--norm_after", "--qk_norm",
+        "--batch_size", "8", "--num_epochs", "1", "--steps_per_epoch", "3",
+        "--lr", "3e-3", "--log_step_interval", "1", "--warmup_steps", "1",
+        "--ckpt_dir", str(tmp_path / "ckpt"),
+        "--metrics_dir", str(tmp_path / "metrics")))
+    assert cfg.norm_after and cfg.qk_norm and cfg.gdn_value_size == 12
+    train(cfg)
+    out = capsys.readouterr().out
+    assert "delta rule: plain (no TPU)" in out
+    assert "in linear_attention layers" not in out
+    with open(tmp_path / "metrics" / "metrics.jsonl") as f:
+        steps = [r for r in map(json.loads, f) if "kind" not in r]
+    losses = [r["loss"] for r in steps]
+    assert len(losses) == 3 and np.isfinite(losses).all()
+    assert losses[-1] < losses[0]
+    for r in steps:
+        assert 0.0 <= r["padding_frac"] < 1.0
+        assert 0 < r["kda_pairs"] <= r["causal_pairs"]
+        assert 0 < r["kda_live_chunks"] <= 8 * 64 // 64
+        assert "ssd_pairs" not in r
+    assert (tmp_path / "ckpt" / "epoch_1").exists()
+
+
+def test_the_start_up_line_says_why_the_plain_rule_runs():
+    """On a TPU (here: forced) the 96 x 192 state under one decay a head is
+    none the kernels tile: `plain (<why>)`, and no impl."""
+    from vitax.ops.kda import kda_choice, make_kda_impl
+    cfg = Config(**OLMO).validate()
+    tiling, words = kda_choice(cfg, force_tpu_kernels=True)
+    assert tiling is None and words.startswith("plain (a 96 x 192 state")
+    assert make_kda_impl(cfg, None, force_tpu_kernels=True) is None
+    assert kda_choice(cfg)[1] == "plain (no TPU)"

@@ -19,6 +19,14 @@ variant's distance (`off_float32`) from the plain form in float32 with
 full-precision products, compiled on the chip too. A last line
 holds the inverse alone: max |X (I + A) - I| of the kernel's float32 products
 on the chip, which a single bf16 pass would leave at 1e-3.
+
+`--scalar` adds the rule with ONE decay a head (Gated DeltaNet; the plain form
+only: no kernel tiles it, `vitax/ops/kda.py:kda_choice`) at the Olmo-Hybrid
+cell's shape and layout (benchmark/traffic/packed_1x4096_webmix.json: 15
+heads, a 96 x 192 state, chunks of 64, beta in (0, 2), an unbounded decay),
+over `INVERSE_BASE` of vitax/models/kda.py (the blocks the triangular inverse
+is merged up from): the number a later kernel for that shape starts from,
+beside the per-channel form's `plain` line.
 """
 
 from __future__ import annotations
@@ -36,36 +44,48 @@ HEADS, HEAD_SIZE, GATE_BOUND = 16, 128, -5.0
 NAMES = ("q", "k", "v", "g", "beta")
 
 
-def operands(seed: int = 0):
+SCALAR = dict(traffic="packed_1x4096_webmix", heads=15, key_size=96,
+              value_size=192)
+
+
+def operands(seed: int = 0, traffic: str = "packed_1x4096_tracemix",
+             heads: int = HEADS, key_size: int = 0, value_size: int = 0):
     """(segment ids, (q, k, v, g, beta), a cotangent) as the mixer hands them
-    over at the cell's shape: q and k unit length a head, zero at padding."""
+    over at the cell's shape: q and k unit length a head, zero at padding.
+    With `key_size`: one decay a head, softplus-sized and unbounded, beta in
+    (0, 2), keys of `key_size` and values of `value_size` (`SCALAR`)."""
     import jax
     import jax.numpy as jnp
 
     from vitax.data.packing import document_layout
-    with open("benchmark/traffic/packed_1x4096_tracemix.json") as f:
+    with open(f"benchmark/traffic/{traffic}.json") as f:
         traffic = json.load(f)
     seg = jnp.asarray(document_layout(
         traffic["rows"], traffic["row_tokens"],
         traffic["docs_per_row"])["segment_ids"])
     r, t = seg.shape
     ks = jax.random.split(jax.random.key(seed), 6)
-    shape = (r, t, HEADS, HEAD_SIZE)
+    shape = (r, t, heads, key_size or HEAD_SIZE)
     valid = (seg > 0)[..., None]
 
     def unit(x):
         return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
 
-    q = unit(jax.random.normal(ks[0], shape)) * HEAD_SIZE ** -0.5
+    q = unit(jax.random.normal(ks[0], shape)) * shape[-1] ** -0.5
     k = unit(jax.random.normal(ks[1], shape))
-    v = jax.nn.silu(jax.random.normal(ks[2], shape))
-    g = GATE_BOUND * jax.nn.sigmoid(2.0 * jax.random.normal(ks[3], shape))
-    beta = jnp.where(valid, jax.nn.sigmoid(
-        jax.random.normal(ks[4], shape[:3])), 0.0)
+    wide = shape[:3] + (value_size or HEAD_SIZE,)
+    v = jax.nn.silu(jax.random.normal(ks[2], wide))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3]))
+    if key_size:    # -exp(A_log) * softplus(.): a few tenths, some tokens -16
+        g = -16.0 * jax.nn.sigmoid(jax.random.normal(ks[3], shape[:3]) - 4.0)
+        g, beta = jnp.where(valid, g, 0.0), 2.0 * beta
+    else:
+        g = GATE_BOUND * jax.nn.sigmoid(2.0 * jax.random.normal(ks[3], shape))
+        g = jnp.where(valid[..., None], g, 0.0)
+    beta = jnp.where(valid, beta, 0.0)
     q, k, v = (jnp.where(valid[..., None], x, 0.0).astype(jnp.bfloat16)
                for x in (q, k, v))
-    g = jnp.where(valid[..., None], g, 0.0)
-    return seg, (q, k, v, g, beta), jax.random.normal(ks[5], shape)
+    return seg, (q, k, v, g, beta), jax.random.normal(ks[5], wide)
 
 
 def gap(got, want) -> float:
@@ -109,6 +129,11 @@ def main() -> None:
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--variants", nargs="*", type=int, default=[16, 8, 4, 1],
                     help="HEADS_PER_STEP")
+    ap.add_argument("--scalar", nargs="*", type=int, default=None,
+                    metavar="INVERSE_BASE",
+                    help="also the scalar-decay plain rule at the "
+                         "Olmo-Hybrid cell's shape, over INVERSE_BASE "
+                         "(default: the program's)")
     args = ap.parse_args()
 
     import jax
@@ -123,7 +148,7 @@ def main() -> None:
     chunk, sub = tiling(seg.shape[1], GATE_BOUND)
     dtype = jnp.bfloat16
 
-    def programs(rule):
+    def programs(rule, seg=seg, w=w):
         def forward(*o):
             return rule(*o, seg, chunk, sub, dtype)
 
@@ -132,7 +157,7 @@ def main() -> None:
                                       argnums=tuple(range(5)))(*o)
         return jax.jit(forward), jax.jit(both)
 
-    def ms(fn):
+    def ms(fn, ops=ops):
         best = float("inf")
         for _ in range(3):
             t0 = time.perf_counter()
@@ -194,6 +219,40 @@ def main() -> None:
         report(line)
     report({"inverse_max_residual": inverse_residual(),
             "device": jax.devices()[0].device_kind})
+    if args.scalar is not None:
+        scalar_rule(args.scalar, programs, ms, report)
+
+
+def scalar_rule(bases, programs, ms, report) -> None:
+    """The plain rule with one decay a head at `SCALAR`'s shape, a line an
+    `INVERSE_BASE`: milliseconds and the distance from float32."""
+    import jax
+    import jax.numpy as jnp
+
+    from vitax.models import kda as plain
+    seg, ops, w = operands(**SCALAR)
+    chunk = plain.tiling(seg.shape[1], GATE_BOUND)[0]
+    exact = tuple(a.astype(jnp.float32) for a in ops)
+    with jax.default_matmul_precision("highest"):
+        truth = jax.block_until_ready(jax.jit(jax.value_and_grad(
+            lambda *o: jnp.sum(plain.kda(*o, seg, chunk, chunk,
+                                         jnp.float32) * w),
+            argnums=tuple(range(5))))(*exact))[1]
+    for base in bases or [plain.INVERSE_BASE]:
+        plain.INVERSE_BASE = base
+        forward, both = programs(plain.kda, seg, w)
+        t0 = time.perf_counter()
+        forward, both = (f.lower(*ops).compile() for f in (forward, both))
+        line = {"variant": "plain_scalar", "inverse_base": base,
+                "chunk": chunk, **SCALAR,
+                "trace_lower_compile_s": round(time.perf_counter() - t0, 2),
+                "device": jax.devices()[0].device_kind}
+        (_, grads) = jax.block_until_ready(both(*ops))
+        line.update(fwd_ms=round(ms(forward, ops), 4),
+                    fwd_bwd_ms=round(ms(both, ops), 4))
+        line["off_float32"] = dict(zip(NAMES, (
+            gap(a, b) for a, b in zip(grads, truth))))
+        report(line)
 
 
 if __name__ == "__main__":

@@ -88,9 +88,9 @@ class Config:
     kv_heads: int = 0                   # key/value heads, each serving layer_heads[i] / kv_heads query heads
     head_size: int = 0                  # width of one head (not embed_dim / heads: q is heads * head_size wide)
     # per layer: "full_attention" (or "attention") | "sliding_attention" |
-    # "mamba" | "kda" | "latent_attention"
+    # "mamba" | "kda" | "latent_attention" | "linear_attention"
     layer_kinds: Tuple[str, ...] = ()
-    layer_heads: Tuple[int, ...] = ()   # per layer: query heads, a kda layer's heads (0 in a mamba layer)
+    layer_heads: Tuple[int, ...] = ()   # per layer: query heads, a delta-rule layer's heads (0 in a mamba layer)
     layer_mlps: Tuple[str, ...] = ()    # per layer: "dense" | "sparse"
     window_tokens: int = 0              # keys a sliding layer's query sees, its own position included
     ffn_dim: int = 0                    # width of a dense layer's SwiGLU
@@ -134,6 +134,20 @@ class Config:
     # taps, the log-decay held inside (kda_gate_bound, 0)
     kda_conv_width: int = 0
     kda_gate_bound: float = 0.0         # < 0 in a model with kda layers
+    # A "linear_attention" layer's mixer (Gated DeltaNet: the same delta rule
+    # with ONE unbounded decay a head; vitax/models/kda.py): layer_heads[i]
+    # heads of gdn_key_size keys and gdn_value_size values, a state of
+    # key x value a head, behind one depthwise causal convolution of
+    # gdn_conv_width taps
+    gdn_key_size: int = 0
+    gdn_value_size: int = 0
+    gdn_conv_width: int = 0
+    # The block's form (shapes of the model, not knobs): norm_after puts the
+    # two RMSNorms on what each half of a layer ADDS (Olmo 2's block; a half
+    # reads the raw residual stream); qk_norm RMS-norms q and k of a full or
+    # sliding layer over the whole projected width before the heads are split
+    norm_after: bool = False
+    qk_norm: bool = False
     # A "latent_attention" layer (MLA): keys and values come up from a normed
     # latent of latent_rank; a head's query and key are qk_nope_size +
     # qk_rope_size wide, the rotated part of the key one for all heads; its
@@ -466,7 +480,8 @@ class Config:
             f"{len(self.layer_heads)} and {len(self.layer_mlps)}")
         assert set(self.layer_kinds) <= {
             "full_attention", "attention", "sliding_attention",
-            "mamba", "kda", "latent_attention"}, self.layer_kinds
+            "mamba", "kda", "latent_attention",
+            "linear_attention"}, self.layer_kinds
         assert set(self.layer_mlps) <= {"dense", "sparse"}, self.layer_mlps
         assert self.vocab_rows >= 2 and self.head_size >= 2, (
             f"--vocab_rows {self.vocab_rows} and --head_size "
@@ -474,7 +489,7 @@ class Config:
         assert self.kv_heads >= 1 and all(
             h >= self.kv_heads and h % self.kv_heads == 0
             for h, kind in zip(self.layer_heads, self.layer_kinds)
-            if kind != "mamba"), (
+            if kind not in ("mamba", "linear_attention")), (
             f"every layer's query heads {self.layer_heads} must be a "
             f"multiple of --kv_heads {self.kv_heads}")
         assert self.position_embedding in ("rope", "nope"), (
@@ -505,6 +520,16 @@ class Config:
             assert self.kda_conv_width >= 1 and self.kda_gate_bound < 0, (
                 "a kda layer needs --kda_conv_width >= 1 and "
                 "--kda_gate_bound < 0, the lower bound of its log-decay")
+        if "linear_attention" in self.layer_kinds:
+            assert (self.gdn_key_size >= 1 and self.gdn_value_size >= 1
+                    and self.gdn_conv_width >= 1), (
+                "a linear_attention layer needs --gdn_key_size, "
+                "--gdn_value_size and --gdn_conv_width")
+            assert all(h >= 1 for h, kind in zip(
+                self.layer_heads, self.layer_kinds)
+                if kind == "linear_attention"), (
+                f"a linear_attention layer needs heads, got "
+                f"{self.layer_heads}")
         if "latent_attention" in self.layer_kinds:
             assert (self.latent_rank >= 1 and self.qk_nope_size >= 1
                     and self.qk_rope_size >= 2 and self.v_head_size >= 1), (
@@ -962,7 +987,7 @@ def build_parser() -> argparse.ArgumentParser:
             ("head_size", int, 0, "width of one head"),
             ("layer_kinds", str, "",
              "full_attention (or attention)|sliding_attention|mamba|kda|"
-             "latent_attention a layer"),
+             "latent_attention|linear_attention a layer"),
             ("layer_heads", str, "", "query heads a layer (0 in a mamba one)"),
             ("layer_mlps", str, "", "dense|sparse a layer"),
             ("window_tokens", int, 0, "keys a sliding layer's query sees"),
@@ -998,6 +1023,9 @@ def build_parser() -> argparse.ArgumentParser:
             ("ssm_chunk", int, 0, "tokens a chunk of the mixer's scan"),
             ("kda_conv_width", int, 0, "taps of a kda layer's convolutions"),
             ("kda_gate_bound", float, 0.0, "lower bound of a kda layer's log-decay"),
+            ("gdn_key_size", int, 0, "key width of a linear_attention layer's heads"),
+            ("gdn_value_size", int, 0, "value width of its heads"),
+            ("gdn_conv_width", int, 0, "taps of its convolution"),
             ("latent_rank", int, 0, "width of a latent_attention layer's latent"),
             ("qk_nope_size", int, 0, "unrotated part of its query and key heads"),
             ("qk_rope_size", int, 0, "rotated part (the key's shared by all heads)"),
@@ -1010,6 +1038,10 @@ def build_parser() -> argparse.ArgumentParser:
                      help="nope: attention rotates nothing, in any layer")
     dec.add_argument("--head_gate", action="store_true", dest="head_gate",
                      help="sigmoid gate on the attention output, a head")
+    dec.add_argument("--norm_after", action="store_true", dest="norm_after",
+                     help="the block norms what each half adds, not its input")
+    dec.add_argument("--qk_norm", action="store_true", dest="qk_norm",
+                     help="RMSNorm on q and k over the whole projected width")
     dec.add_argument("--route_bias", action="store_true", dest="route_bias",
                      help="a bias a routed expert on the scores that choose")
     dec.add_argument("--tie_embeddings", action="store_true",

@@ -18,13 +18,22 @@ The second model family (`Config.model_family == "decoder"`), built by
   scores in float32; a key is visible when it is not after the query, in the
   same document and, in a `sliding_attention` layer, fewer than
   `window_tokens` positions back. `g = sigmoid(W_g x)`, one scalar a head
-  (`head_gate`). No biases, no q/k normalisation.
+  (`head_gate`). No biases. `qk_norm`: q and k are RMS-normed over the
+  WHOLE projected width (all the heads held, one weight of that width)
+  before the heads are split, as Olmo 2 and 3 write it (scope `qk_norm`).
 - F: a SwiGLU of `ffn_dim` in a `dense` layer, the routed and shared experts
   of vitax/models/experts.py in a `sparse` one.
 - A `mamba` layer has the state-space mixer of vitax/models/ssm.py in place
   of W_o[g * Attn], a `kda` layer the delta-rule mixer of
   vitax/models/kda.py (`layer_heads[i]` heads of `head_size`; it rotates
-  nothing). `attention` is another word for `full_attention`.
+  nothing), a `linear_attention` layer the Gated-DeltaNet mixer of the same
+  file (`layer_heads[i]` heads of `gdn_key_size` keys and `gdn_value_size`
+  values; the name the published configurations give it in `layer_types`).
+  `attention` is another word for `full_attention`.
+- `norm_after`: Olmo's block. The norm sits on what each half ADDS,
+  `h += RMSNorm(Mix(h)); h += RMSNorm(F(h))` (scope `post_norm`), and a half
+  reads the raw residual stream; the weights keep the names `norm1` and
+  `norm2`.
 - A `latent_attention` layer (MLA, `LatentAttention`): keys and values come
   up from a normed latent of `latent_rank`; a head's query and key are
   `qk_nope_size` + `qk_rope_size` wide, the rotated `qk_rope_size` of the
@@ -57,7 +66,9 @@ import numpy as np
 
 from vitax.config import Config
 from vitax.models.experts import SharedRoutedExperts, SwiGLU, Table
-from vitax.models.kda import KDAMixer, KDAShape, kda_param_count
+from vitax.models.kda import (GatedDeltaMixer, GatedDeltaShape, KDAMixer,
+                              KDAShape, gated_delta_param_count,
+                              kda_param_count)
 from vitax.models.ssm import MixerShape, SSDMixer, mixer_param_count
 from vitax.models.vit import Array, Dtype, default_init
 
@@ -65,6 +76,8 @@ SLIDING = "sliding_attention"
 MAMBA = "mamba"
 KDA = "kda"
 LATENT = "latent_attention"
+GATED_DELTA = "linear_attention"
+RECURRENT = (MAMBA, KDA, GATED_DELTA)   # kinds with no attention kernel
 
 
 # --- rotary position embedding (pure functions) -----------------------------
@@ -186,14 +199,23 @@ class DecoderAttention(nn.Module):
     dtype: Dtype = jnp.bfloat16
     attention_impl: Optional[Callable] = None
     scale: float = 0.0              # on the scores; 0 = head_size ** -0.5
+    qk_norm: float = 0.0            # > 0: the eps of the norm on q and on k
 
     @nn.compact
     def __call__(self, x: Array, segment_ids: Array,
                  rope: Optional[Tuple[Array, Array]]) -> Array:
         r, t, d = x.shape
         h, kv, dh = self.heads, self.kv_heads, self.head_size
-        q = _linear(h * dh, self.dtype, "wq")(x).reshape(r, t, h, dh)
-        k = _linear(kv * dh, self.dtype, "wk")(x).reshape(r, t, kv, dh)
+        def normed(y, name):    # over the whole width, before the split
+            if not self.qk_norm:
+                return y
+            with jax.named_scope("qk_norm"):
+                return RMSNorm(self.qk_norm, self.dtype, name=name)(y)
+
+        q = normed(_linear(h * dh, self.dtype, "wq")(x), "q_norm").reshape(
+            r, t, h, dh)
+        k = normed(_linear(kv * dh, self.dtype, "wk")(x), "k_norm").reshape(
+            r, t, kv, dh)
         v = _linear(kv * dh, self.dtype, "wv")(x).reshape(r, t, kv, dh)
         if rope is not None:
             with jax.named_scope("rope1d"):
@@ -294,11 +316,25 @@ class DecoderBlock(nn.Module):
     kda_impl: Optional[Callable] = None     # ... and its delta rule (None: plain)
     latent: Optional[LatentShape] = None    # a latent_attention layer's
     route: Tuple[int, int, bool] = (0, 0, False)    # groups, kept, bias
+    # a linear_attention layer's key size, value size and taps
+    gated_delta: Optional[Tuple[int, int, int]] = None
+    norm_after: bool = False        # the norms on what a half adds
+    qk_norm: bool = False
 
     def _added(self, y: Array) -> Array:
         if self.residual_multiplier == 1.0:
             return y
         return y * jnp.asarray(self.residual_multiplier, y.dtype)
+
+    def _normed(self, x: Array, name: str, before: bool) -> Array:
+        """A half's norm where it sits: on the half's input in a pre-norm
+        block (`before`), on what it adds in a norm-after one."""
+        if before == self.norm_after:
+            return x
+        if before:
+            return RMSNorm(self.norm_eps, self.dtype, name=name)(x)
+        with jax.named_scope("post_norm"):
+            return RMSNorm(self.norm_eps, self.dtype, name=name)(x)
 
     @nn.compact
     def __call__(self, x: Array, segment_ids: Array, rope_full=None,
@@ -307,7 +343,7 @@ class DecoderBlock(nn.Module):
         sliding = kind == SLIDING
         if self.token_sharding is not None:
             x = jax.lax.with_sharding_constraint(x, self.token_sharding)
-        y = RMSNorm(self.norm_eps, self.dtype, name="norm1")(x)
+        y = self._normed(x, "norm1", True)
         if kind == MAMBA:
             y = SSDMixer(self.mixer, self.norm_eps, self.dtype,
                          scan=self.scan_impl, name="mixer")(y, segment_ids)
@@ -315,6 +351,10 @@ class DecoderBlock(nn.Module):
             y = KDAMixer(KDAShape(heads, self.head_size, *self.kda),
                          self.norm_eps, self.dtype, rule=self.kda_impl,
                          name="mixer")(y, segment_ids)
+        elif kind == GATED_DELTA:
+            y = GatedDeltaMixer(GatedDeltaShape(heads, *self.gated_delta),
+                                self.norm_eps, self.dtype,
+                                name="mixer")(y, segment_ids)
         elif kind == LATENT:
             y = LatentAttention(
                 heads=heads, shape=self.latent, head_gate=self.head_gate,
@@ -327,10 +367,11 @@ class DecoderBlock(nn.Module):
                 window=self.window_tokens if sliding else 0,
                 head_gate=self.head_gate, dtype=self.dtype,
                 attention_impl=self.attention_impl,
-                scale=self.attention_scale, name="attn",
+                scale=self.attention_scale,
+                qk_norm=self.norm_eps if self.qk_norm else 0.0, name="attn",
             )(y, segment_ids, rope_window if sliding else rope_full)
-        x = x + self._added(y)
-        y = RMSNorm(self.norm_eps, self.dtype, name="norm2")(x)
+        x = x + self._added(self._normed(y, "norm1", False))
+        y = self._normed(x, "norm2", True)
         if mlp == "dense":
             y = SwiGLU(self.ffn_dim, x.shape[-1], dtype=self.dtype,
                        name="mlp")(y)
@@ -345,7 +386,7 @@ class DecoderBlock(nn.Module):
                 route_groups=self.route[0], groups_per_token=self.route[1],
                 route_bias=self.route[2], name="moe",
             )(y, segment_ids > 0)
-        return x + self._added(y)
+        return x + self._added(self._normed(y, "norm2", False))
 
 
 class Run(nn.Module):
@@ -428,6 +469,9 @@ class Decoder(nn.Module):
     kda_impl: Optional[Callable] = None
     latent: Optional[LatentShape] = None
     route: Tuple[int, int, bool] = (0, 0, False)
+    gated_delta: Optional[Tuple[int, int, int]] = None
+    norm_after: bool = False
+    qk_norm: bool = False
 
     def runs(self) -> List[Tuple[Tuple[str, int, str], int]]:
         return layer_runs(self.layer_kinds, self.layer_heads, self.layer_mlps)
@@ -482,7 +526,9 @@ class Decoder(nn.Module):
             attention_scale=self.attention_scale,
             residual_multiplier=self.residual_multiplier, mixer=self.mixer,
             scan_impl=self.scan_impl, kda=self.kda, kda_impl=self.kda_impl,
-            latent=self.latent, route=self.route)
+            latent=self.latent, route=self.route,
+            gated_delta=self.gated_delta, norm_after=self.norm_after,
+            qk_norm=self.qk_norm)
         for i, (shape, length) in enumerate(self.runs()):
             x = Run(length=length,
                     block_kwargs=tuple({**block_kwargs,
@@ -521,10 +567,10 @@ def _decoder_attention_saveable(prim, *_, **params):
 def keeps_attention_residuals(model: Decoder, kind: str) -> bool:
     """PR 30's rule (vitax/models/vit.py: keeps_attention_residuals) by the
     span of a run's layers: a full layer's query meets a whole row, a sliding
-    layer's at most `window_tokens` keys. A mamba or kda layer has no
-    attention kernel to keep anything of."""
+    layer's at most `window_tokens` keys. A mamba, kda or linear_attention
+    layer has no attention kernel to keep anything of."""
     from vitax.models.vit import keeps_attention_residuals as rule
-    return kind not in (MAMBA, KDA) and rule(model, span=model.span(kind))
+    return kind not in RECURRENT and rule(model, span=model.span(kind))
 
 
 def run_remat_policy(model: Decoder, kind: str):
@@ -569,7 +615,11 @@ def build_decoder(cfg: Config, attention_impl: Optional[Callable] = None,
              if KDA in cfg.layer_kinds else None),
         kda_impl=kda_impl,
         latent=latent_shape(cfg),
-        route=(cfg.route_groups, cfg.groups_per_token, cfg.route_bias))
+        route=(cfg.route_groups, cfg.groups_per_token, cfg.route_bias),
+        gated_delta=((cfg.gdn_key_size, cfg.gdn_value_size,
+                      cfg.gdn_conv_width)
+                     if GATED_DELTA in cfg.layer_kinds else None),
+        norm_after=cfg.norm_after, qk_norm=cfg.qk_norm)
 
 
 def mixer_shape(cfg: Config) -> Optional[MixerShape]:
@@ -609,6 +659,10 @@ def expected_param_count(cfg: Config) -> int:
         elif kind == KDA:
             total += kda_param_count(KDAShape(
                 heads, dh, cfg.kda_conv_width, cfg.kda_gate_bound), d)
+        elif kind == GATED_DELTA:
+            total += gated_delta_param_count(GatedDeltaShape(
+                heads, cfg.gdn_key_size, cfg.gdn_value_size,
+                cfg.gdn_conv_width), d)
         elif kind == LATENT:
             s = latent_shape(cfg)
             total += (d * heads * (s.nope + s.rope) + d * (s.rank + s.rope)
@@ -618,6 +672,7 @@ def expected_param_count(cfg: Config) -> int:
         else:
             total += 2 * d * heads * dh + 2 * d * cfg.kv_heads * dh
             total += d * heads if cfg.head_gate else 0
+            total += (heads + cfg.kv_heads) * dh if cfg.qk_norm else 0
         if mlp == "dense":
             total += 3 * d * cfg.ffn_dim
         else:

@@ -44,6 +44,16 @@ that owns the incoming state read it; the state a chunk ends with is made of
 the tokens of its last token's document and passes through a chunk only if
 the whole chunk lies in that document.
 
+A Gated-DeltaNet layer (`GatedDeltaMixer`, arXiv:2412.06464) runs the same
+rule with ONE decay a head, g = -exp(A_log) * softplus(W_a u + dt_bias) with
+no lower bound, and a state of key_size x value_size. A scalar decay factors
+out of a (query, key) product as SSD's does: A_ts = b_t (k_t . k_s)
+e^{G_t - G_s}, the exponent a difference taken first and so at most 0,
+whatever g is. `kda` takes that form where `g` comes a head, (R, T, H), and
+not a head and channel: no sub-chunks and no bound, one masked (chunk,
+chunk) decay a head; the solve, the inverse, the state's scan and the
+document rules are the same.
+
 g, G, every exp and every state are float32; the products take operands of
 the model's dtype and accumulate in float32, as `ssd` does. This plain
 `jax.numpy` form is the CPU's path and the tests' oracle; on a TPU, where the
@@ -100,6 +110,26 @@ def kda_param_count(shape: KDAShape, embed_dim: int) -> int:
             + shape.head_size)
 
 
+class GatedDeltaShape(NamedTuple):
+    heads: int
+    key_size: int
+    value_size: int
+    conv_width: int
+
+    @property
+    def inner(self) -> int:         # q, k and v behind one convolution
+        return self.heads * (2 * self.key_size + self.value_size)
+
+
+def gated_delta_param_count(shape: GatedDeltaShape, embed_dim: int) -> int:
+    """W_q, W_k, W_v; the convolution; W_a, W_b, A_log, dt_bias; W_z, the
+    output norm's weight and W_o."""
+    wide = shape.heads * shape.value_size
+    return (embed_dim * shape.inner + shape.conv_width * shape.inner
+            + 2 * embed_dim * shape.heads + 2 * shape.heads
+            + embed_dim * wide + shape.value_size + wide * embed_dim)
+
+
 def tiling(tokens: int, gate_bound: float) -> Tuple[int, int]:
     """(chunk, sub) for rows of `tokens`: the longest chunk up to KDA_CHUNK
     that divides the row, and the longest power-of-two sub-chunk over which
@@ -117,17 +147,52 @@ def count_chunk(tokens: int) -> int:
     return math.gcd(tokens, KDA_COUNT_CHUNK)
 
 
-def unit_lower_inverse(a: Array) -> Array:
+def unit_lower_inverse(a: Array, size: int = 0) -> Array:
     """(I + a)^-1 for `a` (..., c, c) strictly lower triangular, float32:
-    with n = -a nilpotent, (I - n)^-1 = (I + n)(I + n^2)(I + n^4)..."""
+    with n = -a nilpotent, (I - n)^-1 = (I + n)(I + n^2)(I + n^4)...; `size`:
+    n^size = 0 already (diagonal blocks of `size`; 0: the whole c)."""
     c = a.shape[-1]
     hi = jax.lax.Precision.HIGHEST
     power = -a
     out = jnp.eye(c, dtype=a.dtype) + power
-    for _ in range(max(math.ceil(math.log2(c)) - 1, 0)):
+    for _ in range(max(math.ceil(math.log2(size or c)) - 1, 0)):
         power = jnp.matmul(power, power, precision=hi)
         out = out + jnp.matmul(out, power, precision=hi)
     return out
+
+
+INVERSE_BASE = 4        # the blocks `unit_lower_inverse_merged` starts from
+
+
+def unit_lower_inverse_merged(a: Array) -> Array:
+    """(I + a)^-1 as `unit_lower_inverse` gives it, built from the diagonal
+    blocks up: the blocks of INVERSE_BASE by the nilpotent product, then
+    pairs of neighbours merged, [[P, 0], [-Q a21 P, Q]] for the inverses P
+    and Q of a pair, until one block is left. Every intermediate is a block
+    of the inverse itself, so nothing grows that the result does not hold:
+    the nilpotent product's powers a^2, a^4, ... reach binomial sizes
+    (entries near b on keys that repeat give C(c, c / 2) b^(c / 2)) and
+    cancel in float32 only while c is small. Written on the whole (c, c)
+    matrix, X the block-diagonal inverse so far: X <- X - X (a masked to the
+    pairs' lower-left blocks) X; the zeros outside the blocks are exact, and
+    the products keep the shapes the MXU tiles (as many of them as the
+    nilpotent product of the whole chunk takes)."""
+    c = a.shape[-1]
+    s = math.gcd(c, INVERSE_BASE)
+    assert c // s & (c // s - 1) == 0, f"a chunk of {c} is no 2^n x {s}"
+    hi = jax.lax.Precision.HIGHEST
+
+    def same(size):     # rows and columns of one diagonal block of `size`
+        at = jnp.arange(c) // size
+        return at[:, None] == at[None, :]
+
+    inverse = unit_lower_inverse(jnp.where(same(s), a, 0.0), size=s)
+    while s < c:
+        below = jnp.where(same(2 * s) & ~same(s), a, 0.0)
+        inverse = inverse - jnp.matmul(
+            jnp.matmul(inverse, below, precision=hi), inverse, precision=hi)
+        s *= 2
+    return inverse
 
 
 def _chunk_block(per_chunk_bytes: int, chunks: int) -> int:
@@ -139,12 +204,15 @@ def _chunk_block(per_chunk_bytes: int, chunks: int) -> int:
 
 def kda(q: Array, k: Array, v: Array, g: Array, beta: Array,
         segment_ids: Array, chunk: int, sub: int, dtype: Dtype) -> Array:
-    """The delta rule: q and k (R, T, H, K), v (R, T, H, V), g (R, T, H, K)
-    float32 and <= 0, beta (R, T, H) float32 in [0, 1], segment_ids (R, T)
-    with T a multiple of `chunk` and `chunk` of `sub` -> o (R, T, H, V)
-    float32. q, k, v, g and beta are zero at padding, and so is o."""
+    """The delta rule: q and k (R, T, H, K), v (R, T, H, V), g float32 and
+    <= 0, a head and channel (R, T, H, K) or a head (R, T, H), beta (R, T, H)
+    float32 in [0, 2], segment_ids (R, T) with T a multiple of `chunk` and
+    `chunk` of `sub` -> o (R, T, H, V) float32. q, k, v, g and beta are zero
+    at padding, and so is o. The shape of `g` chooses the decay's form: a
+    scalar one needs no sub-chunks and no bound (`sub` plays no part)."""
     r, t, h, dk = q.shape
     dv = v.shape[-1]
+    scalar = g.ndim == 3
     c, nc, a = chunk, t // chunk, chunk // sub
     f32 = jnp.float32
     seg = segment_ids.reshape(r, nc, c)
@@ -199,10 +267,56 @@ def kda(q: Array, k: Array, v: Array, g: Array, beta: Array,
                     (k32 * to_end).astype(dtype).transpose(0, 1, 3, 2, 4),
                     jnp.exp(run[:, :, -1]))                         # R n h k
 
+    @jax.checkpoint
+    def scalar_block(args):
+        """`block` for one decay a head: e^{G_l - G_j} factors out of a
+        (query, key) product, is taken after the difference and is at most
+        1, whatever g is."""
+        seg, owner, q, k, v, g, beta = args         # g (R, n, c, h)
+        with jax.named_scope("kda_chunk"):
+            q32, k32 = q.astype(f32), k.astype(f32)
+            run = jnp.cumsum(g, axis=2)                             # R n l h
+            by_head = run.transpose(0, 1, 3, 2)                     # R n h l
+            see = ((seg[:, :, :, None] == seg[:, :, None, :])
+                   & (seg[:, :, :, None] > 0))[:, :, None]          # R n 1 l j
+            decay = jnp.exp(jnp.where(
+                see & not_after,
+                by_head[..., :, None] - by_head[..., None, :], -jnp.inf))
+
+            def scores(x):          # x_l . k_j: R n h l j
+                return jnp.einsum("rnlhk,rnjhk->rnhlj", x, k,
+                                  preferred_element_type=f32)
+
+            bh = beta.transpose(0, 1, 3, 2)                         # R n h l
+            qk = scores(q) * decay
+            kk = jnp.where(not_after.T, 0.0, scores(k) * decay) \
+                * bh[..., None]
+            # beta reaches 2 here and keys may repeat: built from blocks
+            solve = unit_lower_inverse_merged(kk).astype(dtype)     # R n h l s
+            reads = ((seg == owner[..., None]) & (seg > 0))[..., None]
+            from_start = jnp.where(reads, jnp.exp(run), 0.0)[..., None]
+            b4 = beta[..., None]
+            w = jnp.einsum("rnhls,rnshk->rnhlk", solve,
+                           (k32 * from_start * b4).astype(dtype),
+                           preferred_element_type=f32)
+            u0 = jnp.einsum("rnhls,rnshv->rnhlv", solve,
+                            (v.astype(f32) * b4).astype(dtype),
+                            preferred_element_type=f32)
+            mine = ((seg == seg[:, :, -1:]) & (seg > 0))[..., None]
+            to_end = jnp.exp(jnp.where(
+                mine, run[:, :, -1:] - run, -jnp.inf))[..., None]
+            return (qk.astype(dtype), w.astype(dtype), u0,
+                    (q32 * from_start).astype(dtype).transpose(0, 1, 3, 2, 4),
+                    (k32 * to_end).astype(dtype).transpose(0, 1, 3, 2, 4),
+                    jnp.exp(run[:, :, -1])[..., None])              # R n h 1
+
     def chunks(x):      # (R, T, ...) -> (R, nc, c, ...)
         return x.reshape(r, nc, c, *x.shape[2:])
 
-    cb = _chunk_block(4 * r * a * c * h * dk, nc)
+    if scalar:          # the decay and the two score products of a chunk
+        block, cb = scalar_block, _chunk_block(12 * r * h * c * c, nc)
+    else:
+        cb = _chunk_block(4 * r * a * c * h * dk, nc)
 
     def blocked(x):     # (R, nc, ...) -> (nc / cb, R, cb, ...)
         return jnp.moveaxis(x.reshape(r, nc // cb, cb, *x.shape[2:]), 1, 0)
@@ -222,7 +336,7 @@ def kda(q: Array, k: Array, v: Array, g: Array, beta: Array,
 
     with jax.named_scope("kda_state"):
         through = jnp.where(((last == owner) & (last > 0))[..., None, None],
-                            decay_end, 0.0)                         # R nc h k
+                            decay_end, 0.0)     # R nc h k (h 1: one a head)
 
         @jax.checkpoint
         def carry(state, inputs):
@@ -244,6 +358,11 @@ def kda(q: Array, k: Array, v: Array, g: Array, beta: Array,
                   for x in (qk, w, u0, q_start, k_end, through)))
         # (nc, R, h, c, v) -> (R, T, h, v)
         return o.transpose(1, 0, 3, 2, 4).reshape(r, t, h, dv)
+
+
+def l2norm(x: Array) -> Array:
+    return x * jax.lax.rsqrt(
+        jnp.sum(jnp.square(x), axis=-1, keepdims=True) + L2_EPS)
 
 
 class KDAMixer(nn.Module):
@@ -275,10 +394,6 @@ class KDAMixer(nn.Module):
             q, k, v = (x.reshape(r, t, h, dh)
                        for x in jnp.split(qkv, 3, axis=-1))
 
-            def l2norm(x):
-                return x * jax.lax.rsqrt(
-                    jnp.sum(jnp.square(x), axis=-1, keepdims=True) + L2_EPS)
-
             q = (l2norm(q) * dh ** -0.5).astype(self.dtype)
             k, v = l2norm(k).astype(self.dtype), v.astype(self.dtype)
 
@@ -303,3 +418,73 @@ class KDAMixer(nn.Module):
                 + self.norm_eps)
             o = (o * scale * gate[..., None]).astype(self.dtype)
         return linear(d, "wo")(o.reshape(r, t, s.inner))
+
+
+class GatedDeltaMixer(nn.Module):
+    """Gated DeltaNet (arXiv:2412.06464, as the `fla` library's layer writes
+    it with its gate and short convolutions on): `u` is what the block hands
+    the mixer (the raw residual stream in a norm-after block).
+
+        q, k, v = silu(conv([W_q u; W_k u; W_v u]))     heads of key_size,
+                      key_size and value_size; depthwise, causal, no bias
+        q = l2norm(q) * key_size ** -0.5,   k = l2norm(k)           a head
+        g = -exp(A_log) * softplus(W_a u + dt_bias)     <= 0, ONE a head
+        b = 2 * sigmoid(W_b u)                          in (0, 2), a head
+        S_t = e^{g_t} (I - b_t k_t k_t^T) S_{t-1} + b_t k_t v_t^T
+        o_t = S_t^T q_t                 S (key_size, value_size), float32
+        out = W_o[ RMSNorm(o_t) * w * silu(W_z u_t) ]   normed a head (one
+                      weight of value_size), gated a head AND channel
+
+    The scopes are `KDAMixer`'s, for the same kinds of work."""
+
+    shape: GatedDeltaShape
+    norm_eps: float
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u: Array, segment_ids: Array) -> Array:
+        s = self.shape
+        r, t, d = u.shape
+        h, dk, dv = s.heads, s.key_size, s.value_size
+        f32 = jnp.float32
+
+        def linear(features, name):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                            param_dtype=f32, kernel_init=default_init,
+                            name=name)
+
+        valid = (segment_ids > 0)[..., None]
+        qkv = jnp.concatenate([linear(h * n, name)(u) for name, n in (
+            ("wq", dk), ("wk", dk), ("wv", dv))], axis=-1)
+        with jax.named_scope("kda_conv"):
+            taps = Leaf((s.conv_width, s.inner), conv_init, "kernel",
+                        name="conv")()
+            qkv = causal_conv(qkv, segment_ids, taps, 0.0)
+            qkv = jnp.where(valid, jax.nn.silu(qkv), 0.0)
+            q, k, v = jnp.split(qkv, [h * dk, 2 * h * dk], axis=-1)
+            q = (l2norm(q.reshape(r, t, h, dk)) * dk ** -0.5).astype(
+                self.dtype)
+            k = l2norm(k.reshape(r, t, h, dk)).astype(self.dtype)
+            v = v.reshape(r, t, h, dv).astype(self.dtype)
+
+        with jax.named_scope("kda_gate"):
+            a_log = Leaf((h,), a_log_init, name="A_log")()
+            dt_bias = Leaf((h,), dt_bias_init, "bias", name="dt_bias")()
+            g = -jnp.exp(a_log) * jax.nn.softplus(
+                linear(h, "wa")(u).astype(f32) + dt_bias)
+            g = jnp.where(valid, g, 0.0)
+            beta = jnp.where(valid, 2.0 * jax.nn.sigmoid(
+                linear(h, "wb")(u).astype(f32)), 0.0)
+
+        chunk = math.gcd(t, KDA_CHUNK)
+        o = kda(q, k, v, g, beta, segment_ids, chunk, chunk, self.dtype)
+
+        z = linear(h * dv, "wz")(u)
+        with jax.named_scope("kda_out_norm"):
+            scale = Leaf((dv,), nn.initializers.ones, name="out_norm")()
+            gate = jax.nn.silu(z.astype(f32))
+            o = o * jax.lax.rsqrt(
+                jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+                + self.norm_eps)
+            o = (o * scale * gate.reshape(r, t, h, dv)).astype(self.dtype)
+        return linear(d, "wo")(o.reshape(r, t, h * dv))

@@ -579,10 +579,16 @@ def kda_choice(cfg, force_tpu_kernels: bool = False
     from vitax.models.kda import tiling
     heads = sorted({n for kind, n in zip(cfg.layer_kinds, cfg.layer_heads)
                     if kind == "kda"})
-    if not heads:
+    gated = "linear_attention" in cfg.layer_kinds
+    if not heads and not gated:
         return None, "no kda layer"
     if not (force_tpu_kernels or backend_platform() == "tpu"):
         return None, "plain (no TPU)"
+    if not heads:   # Gated DeltaNet: `GatedDeltaMixer` runs the plain rule
+        return None, (
+            f"plain (a {cfg.gdn_key_size} x {cfg.gdn_value_size} state "
+            f"under one decay a head: the kernels tile a square state of "
+            f"multiples of {LANES} under a decay a channel)")
     chunk, sub = tiling(cfg.pack_tokens, cfg.kda_gate_bound)
     for n in heads:
         hb = kda_tiling(n, cfg.head_size, chunk, sub)

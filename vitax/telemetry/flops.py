@@ -116,7 +116,12 @@ def decoder_flops_per_step(cfg, tokens: float, targets: float,
     ones by `tokens`, its delta rule by `kda_pairs` (the two score products,
     the triangular solve of the corrected keys and values, and the
     intra-chunk output) and by `tokens` (the three products with the
-    state). A latent_attention layer: its projections by `tokens`, QK^T at
+    state). A linear_attention layer (Gated DeltaNet, the same rule at
+    gdn_key_size keys and gdn_value_size values): its projections by
+    `tokens`; by `kda_pairs` the two score products over the key (4K), the
+    solve of the corrected keys and values (2K + 2V) and the intra-chunk
+    output (2V); by `tokens` the three products with the state (6KV). A
+    latent_attention layer: its projections by `tokens`, QK^T at
     qk_nope_size + qk_rope_size and PV at v_head_size by `causal_pairs`.
     Padding, the masked part of a block and sorted rows no held expert owns
     are not counted."""
@@ -135,6 +140,11 @@ def decoder_flops_per_step(cfg, tokens: float, targets: float,
             per_token = 2 * d * heads * (5 * dh + 2)
             per_token += 6 * heads * dh * dh
             fwd += 10 * heads * dh * kda_pairs
+        elif kind == "linear_attention":
+            dk, dv = cfg.gdn_key_size, cfg.gdn_value_size
+            per_token = 2 * d * heads * (2 * dk + 3 * dv + 2)
+            per_token += 6 * heads * dk * dv
+            fwd += heads * (6 * dk + 4 * dv) * kda_pairs
         elif kind == "latent_attention":
             qk, dv = cfg.qk_nope_size + cfg.qk_rope_size, cfg.v_head_size
             per_token = 2 * d * (heads * qk + cfg.latent_rank

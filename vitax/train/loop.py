@@ -98,11 +98,11 @@ def _attention_remat_note(cfg: Config, model, mesh) -> str:
     if model.attention_impl is None or not cfg.grad_ckpt:
         return ""
     if cfg.decoder:   # by the span of each kind of layer
-        from vitax.models.decoder import keeps_attention_residuals as keeps
+        from vitax.models.decoder import RECURRENT, keeps_attention_residuals as keeps
         return "; remat " + ", ".join(
             f"{'keeps o and lse' if keeps(model, kind) else 'runs the forward again'}"
             f" in {kind} layers (span {model.span(kind)})"
-            for kind in sorted(set(cfg.layer_kinds) - {"mamba", "kda"}))
+            for kind in sorted(set(cfg.layer_kinds) - set(RECURRENT)))
     span = attention_span(model)
     if (mesh.shape.get("pp", 1) > 1 or gather_overlap_active(cfg, mesh)
             or cfg.remat_window > 1):
@@ -262,7 +262,7 @@ def train(cfg: Config) -> TrainState:
         from vitax.ops.ssd import scan_choice
         master_print("state-space scan: " + (
             getattr(model.scan_impl, "vitax_name", "") or scan_choice(cfg)[1]))
-    if cfg.decoder and "kda" in cfg.layer_kinds:
+    if cfg.decoder and {"kda", "linear_attention"} & set(cfg.layer_kinds):
         from vitax.ops.kda import kda_choice
         master_print("delta rule: " + (
             getattr(model.kda_impl, "vitax_name", "") or kda_choice(cfg)[1]))

@@ -190,8 +190,8 @@ def decoder_counts(cfg: Config, batch: Dict[str, jax.Array]
     A model with mamba layers (vitax/models/ssm.py) also counts its scan's
     work on the grid of `ssm_chunk` tokens: `ssd_pairs`, the pairs of a
     query and a key not after it in one chunk and one document, and
-    `ssd_live_chunks`, the chunks that hold a valid token; a model with kda
-    layers (vitax/models/kda.py) the same two on a grid of 64 tokens fixed
+    `ssd_live_chunks`; a model with kda or linear_attention layers (the delta
+    rule of vitax/models/kda.py) the same two on a grid of 64 tokens fixed
     for counting (`count_chunk`): `kda_pairs` and `kda_live_chunks`."""
     seg = batch["segment_ids"]
     n = jnp.sum(seg[..., None] == jnp.arange(1, cfg.pack_images + 1),
@@ -210,7 +210,7 @@ def decoder_counts(cfg: Config, batch: Dict[str, jax.Array]
 
     if "mamba" in cfg.layer_kinds:
         ssd["ssd_pairs"], ssd["ssd_live_chunks"] = on_grid(cfg.ssm_chunk)
-    if "kda" in cfg.layer_kinds:
+    if {"kda", "linear_attention"} & set(cfg.layer_kinds):
         from vitax.models.kda import count_chunk
         ssd["kda_pairs"], ssd["kda_live_chunks"] = on_grid(
             count_chunk(cfg.pack_tokens))
