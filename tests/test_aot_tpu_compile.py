@@ -397,6 +397,55 @@ def test_delta_rule_compiles_and_lies_under_its_scope(chip, mosaic):
                                    for c in calls), (calls, kernels)
 
 
+def test_mixer_convolution_compiles_and_lies_under_its_scope(chip, mosaic):
+    """The one-pass convolution (vitax/ops/conv.py) through the state-space
+    mixer at the hybrid cell's shape (1 x 4,096 tokens, 4,352 channels with a
+    bias, no norm): real Mosaic lowering and compile of forward and backward,
+    and both `conv_silu_*` custom calls of the compiled text have `ssm_conv`
+    in their `op_name` path: the join `benchmark/scopes.py:index` makes for
+    `ssm_mixer_busy_pct`. The delta mixers' shapes (6,144 channels with 32
+    heads of 128 normed, 5,760 with 30 heads of 96 normed four to three lane
+    tiles) compile in the Ling and Olmo cells' whole steps below, under
+    `kda_conv`."""
+    import re
+
+    from vitax.config import Config
+    from vitax.models.ssm import MixerShape, SSDMixer
+    from vitax.ops.conv import make_conv_impl
+    one_chip, _ = chip
+    cfg = Config(
+        model_family="decoder", embed_dim=2048, num_blocks=1, vocab_rows=128,
+        kv_heads=8, head_size=64, layer_kinds=["mamba"], layer_heads=[0],
+        layer_mlps=["dense"], ffn_dim=128, ssm_heads=64, ssm_head_size=64,
+        ssm_state_size=128, ssm_conv_width=4, ssm_groups=1, ssm_chunk=256,
+        pack_tokens=4096, pack_images=4, batch_size=1).validate()
+    conv = make_conv_impl(cfg, None, force_tpu_kernels=True)
+    assert conv.vitax_name == ("fused kernel (256 channels a grid step in "
+                               "blocks of 128 tokens)")
+    mixer = SSDMixer(MixerShape(64, 64, 128, 4, 1, 256), 1e-5, jnp.bfloat16,
+                     conv=conv)
+    u = jax.ShapeDtypeStruct((1, 4096, 2048), jnp.bfloat16, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip),
+        jax.eval_shape(mixer.init, jax.random.key(0), u, seg))
+    compiled = jax.jit(jax.grad(lambda p, u, seg: jnp.sum(
+        mixer.apply(p, u, seg).astype(jnp.float32)), argnums=(0, 1))).lower(
+            params, u, seg).compile()
+    kernels = [k for k in _kernel_names(compiled) if "/conv_silu_" in k]
+    assert sorted(k.rsplit("/", 2)[-2] for k in kernels) == \
+        ["conv_silu_bwd", "conv_silu_fwd"], kernels
+    from benchmark import scopes
+    text = compiled.as_text()
+    found = scopes.index(text, ("ssm_conv", "kda_conv"))
+    calls = [re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=", ln).group(1)
+             for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln
+             and "/conv_silu_" in ln]
+    assert len(calls) == 2 and all(found.get(c) == "ssm_conv"
+                                   for c in calls), (calls, kernels)
+
+
 def test_latent_attention_forward_and_vjp_compile(chip, mosaic):
     """The packed causal kernels at the Ling cell's latent layer: one row of
     4,096 tokens, 16 heads each with a key of its own, q and k 192 wide
@@ -429,7 +478,8 @@ def test_the_ling_cells_step_compiles_and_fits(chip, mosaic):
     row of 4,096 tokens), through the cell's own `lower_described`: the
     chip's compiler takes it, it fits the 15.75 GB the compiler allows, the
     latent layer's three kernels, the delta rule's two (a forward a layer's
-    forward and its remat, a backward) and the fused optimizer are in it, and
+    forward and its remat, a backward), the mixers' convolution's two under
+    `kda_conv` and the fused optimizer are in it, and
     the delta rule lies under the scope `kda_roofline` reads (`kda_chunk`:
     the fused form has no `kda_state` of its own)."""
     import re
@@ -462,6 +512,9 @@ def test_the_ling_cells_step_compiles_and_fits(chip, mosaic):
     assert any("fused_adamw" in k for k in kernels)
     for part in ("kda_fwd", "kda_bwd"):
         assert any("kda_chunk" in k and f"/{part}/" in k
+                   for k in kernels), kernels
+    for part in ("conv_silu_fwd", "conv_silu_bwd"):     # vitax/ops/conv.py
+        assert any("kda_conv" in k and f"/{part}/" in k
                    for k in kernels), kernels
     found = set(scopes.index(compiled.as_text(), gen.SCOPES).values())
     assert {"kda_conv", "kda_gate", "kda_chunk", "kda_out_norm",
@@ -500,6 +553,9 @@ def test_the_olmo_hybrid_cells_step_compiles_and_fits(chip, mosaic):
         "flash_causal_dkv", "flash_causal_dq", "flash_causal_fwd"], kernels
     assert any("fused_adamw" in k for k in kernels)
     assert not any("kda_fwd" in k or "kda_bwd" in k for k in kernels)
+    for part in ("conv_silu_fwd", "conv_silu_bwd"):     # vitax/ops/conv.py
+        assert any("kda_conv" in k and f"/{part}/" in k
+                   for k in kernels), kernels
     found = set(scopes.index(compiled.as_text(), gen.SCOPES).values())
     assert {"kda_conv", "kda_gate", "kda_chunk", "kda_state", "kda_out_norm",
             "post_norm", "qk_norm", "lm_head_loss"} <= found, found

@@ -132,6 +132,31 @@ def test_loss_and_every_gradient_leaf_match_the_reference(setup):
     np.testing.assert_allclose(rows[0], first, rtol=2e-4, atol=2e-5)
 
 
+def test_the_model_through_the_convolutions_kernels_equals_the_plain_path():
+    """The mixers' convolution forced to the kernel pair of vitax/ops/conv.py
+    (interpret mode; 12 heads of 8 and two states of 16 make the 128 channels
+    they tile), the scan plain either way: logits, loss and every leaf's
+    gradient are the plain path's."""
+    from tests.test_ssd_kernel import gap
+    from vitax.ops.conv import make_conv_impl
+    from vitax.train.step import decoder_loss
+    cfg = Config(**{**TINY, "ssm_heads": 12}).validate()
+    conv = make_conv_impl(cfg, None, force_tpu_kernels=True)
+    assert conv.vitax_name.startswith("fused kernel (128 channels")
+    models = [decoder.build_decoder(cfg), decoder.build_decoder(
+        cfg, conv_impl=conv)]
+    variables, batch = seeded(models[0], cfg), make_batch(cfg)
+    want, got = (jax.jit(jax.value_and_grad(lambda v, m=m: decoder_loss(
+        m.apply(v, batch, True), batch)))(variables) for m in models)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
+    np.testing.assert_allclose(models[1].apply(variables, batch, True),
+                               models[0].apply(variables, batch, True),
+                               rtol=2e-4, atol=2e-5)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want[1]),
+                            jax.tree.leaves(got[1])):
+        assert gap(b, a) < 2e-4, jax.tree_util.keystr(path)
+
+
 def test_the_vocabulary_slice_is_tied_to_the_model(setup):
     """A chip that holds rows 0-k of the tied table: on ids drawn from the
     slice its logits are those columns of the whole table's (the table is
@@ -342,8 +367,11 @@ def test_training_through_the_cli_path(tmp_path, capsys):
         "--metrics_dir", str(tmp_path / "metrics")))
     assert cfg.tie_embeddings and cfg.ssm_groups == 1
     train(cfg)
-    # which form of the scan runs, beside the attention core's line
-    assert "state-space scan: plain (no TPU)" in capsys.readouterr().out
+    # which form of the scan and of the convolution in front of it runs,
+    # beside the attention core's line
+    out = capsys.readouterr().out
+    assert "state-space scan: plain (no TPU)" in out
+    assert "mixer convolution: plain (no TPU)" in out
     with open(tmp_path / "metrics" / "metrics.jsonl") as f:
         steps = [r for r in map(json.loads, f) if "kind" not in r]
     losses = [r["loss"] for r in steps]

@@ -197,6 +197,36 @@ def test_model_through_the_kernels_equals_the_dense_path():
                                rtol=2e-4, atol=2e-5)
 
 
+def test_the_model_through_the_convolutions_kernels_equals_the_plain_path():
+    """The kda layers' three convolutions, silu and the L2 norms of q and k
+    forced to the kernel pair of vitax/ops/conv.py (interpret mode; heads of
+    128, the width whose norm the kernel takes as its epilogue), the delta
+    rule plain either way: logits, loss and every leaf's gradient are the
+    plain path's."""
+    from tests.test_ssd_kernel import gap
+    from vitax.ops.conv import make_conv_impl
+    from vitax.train.step import decoder_loss
+    cfg = Config(**{**TINY, "head_size": 128,
+                    "qk_rope_size": 64}).validate()
+    conv = make_conv_impl(cfg, None, force_tpu_kernels=True)
+    assert conv.vitax_name == ("fused kernel (384 channels a grid step in "
+                               "blocks of 32 tokens)")
+    models = [decoder.build_decoder(cfg), decoder.build_decoder(
+        cfg, conv_impl=conv)]
+    batch = make_batch(cfg)
+    variables = moved(jax.jit(lambda: models[0].init(
+        jax.random.key(0), decoder.sample_documents(cfg, 1), True))())
+    want, got = (jax.jit(jax.value_and_grad(lambda v, m=m: decoder_loss(
+        m.apply(v, batch, True), batch)))(variables) for m in models)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
+    np.testing.assert_allclose(models[1].apply(variables, batch, True),
+                               models[0].apply(variables, batch, True),
+                               rtol=2e-4, atol=2e-5)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want[1]),
+                            jax.tree.leaves(got[1])):
+        assert gap(b, a) < 2e-4, jax.tree_util.keystr(path)
+
+
 def test_remat_keeps_o_and_lse_of_the_latent_layer_only():
     cfg = Config(**{**TINY, "pack_tokens": 2048,
                     "dtype": "bfloat16"}).validate()

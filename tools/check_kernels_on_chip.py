@@ -149,6 +149,7 @@ def main():
     ok &= check_fused_optimizer()
     ok &= check_dequant_matmul()
     ok &= check_delta_rule()
+    ok &= check_mixer_convolution()
     print("ON-CHIP KERNEL NUMERICS:", "OK" if ok else "FAILED")
     return 0 if ok else 1
 
@@ -317,6 +318,41 @@ def check_delta_rule() -> bool:
     print(f"  delta rule inverse max |X (I + A) - I| {residual:.2e} "
           f"{'ok' if residual < 1e-5 else 'FAIL: not float32 products'}")
     return ok and residual < 1e-5
+
+
+def check_mixer_convolution() -> bool:
+    """The mixers' one-pass convolution (`conv_silu_fwd` / `conv_silu_bwd`,
+    vitax/ops/conv.py) at the three recurrent cells' shapes and layouts
+    against the plain `conv_silu` compiled on the chip, y and the gradients
+    of x, the taps and the bias in bfloat16: the same float32 sums rounded at
+    the same place, so y to a unit in the last place here and there and the
+    gradients to bfloat16's. Operands and distances are tools/bench_conv.py's."""
+    from tools import bench_conv as bench
+    from vitax.models.ssm import conv_silu
+    from vitax.ops import conv as fused
+
+    ok = True
+    for shape in bench.SHAPES:
+        seg, (x, kernel, b), weight, norm = bench.operands(shape)
+        ops = (x, kernel) + (() if b is None else (b,))
+
+        def run(conv):
+            def total(x, kernel, *bias):
+                y = conv(x, seg, kernel, *(bias or (None,)), jnp.bfloat16,
+                         norm)
+                return jnp.sum(y.astype(jnp.float32) * weight), y
+
+            (_, y), grads = jax.jit(jax.value_and_grad(
+                total, argnums=tuple(range(len(ops))), has_aux=True))(*ops)
+            return (y, *grads)
+
+        for tag, got, want in zip(("y",) + bench.NAMES,
+                                  run(fused.conv_silu), run(conv_silu)):
+            err = bench.gap(got, want)
+            print(f"  mixer convolution {shape:8s} {tag:7s} rel-norm-err "
+                  f"{err:.2e} {'ok' if err < 5e-3 else 'FAIL'}")
+            ok &= err < 5e-3
+    return ok
 
 
 if __name__ == "__main__":

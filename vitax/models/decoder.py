@@ -314,6 +314,7 @@ class DecoderBlock(nn.Module):
     scan_impl: Optional[Callable] = None    # ... and its scan (None: plain)
     kda: Optional[Tuple[int, float]] = None     # a kda layer's taps and bound
     kda_impl: Optional[Callable] = None     # ... and its delta rule (None: plain)
+    conv_impl: Optional[Callable] = None    # a recurrent mixer's convolution
     latent: Optional[LatentShape] = None    # a latent_attention layer's
     route: Tuple[int, int, bool] = (0, 0, False)    # groups, kept, bias
     # a linear_attention layer's key size, value size and taps
@@ -346,14 +347,17 @@ class DecoderBlock(nn.Module):
         y = self._normed(x, "norm1", True)
         if kind == MAMBA:
             y = SSDMixer(self.mixer, self.norm_eps, self.dtype,
-                         scan=self.scan_impl, name="mixer")(y, segment_ids)
+                         scan=self.scan_impl, conv=self.conv_impl,
+                         name="mixer")(y, segment_ids)
         elif kind == KDA:
             y = KDAMixer(KDAShape(heads, self.head_size, *self.kda),
                          self.norm_eps, self.dtype, rule=self.kda_impl,
+                         conv=self.conv_impl,
                          name="mixer")(y, segment_ids)
         elif kind == GATED_DELTA:
             y = GatedDeltaMixer(GatedDeltaShape(heads, *self.gated_delta),
                                 self.norm_eps, self.dtype,
+                                conv=self.conv_impl,
                                 name="mixer")(y, segment_ids)
         elif kind == LATENT:
             y = LatentAttention(
@@ -467,6 +471,7 @@ class Decoder(nn.Module):
     scan_impl: Optional[Callable] = None
     kda: Optional[Tuple[int, float]] = None
     kda_impl: Optional[Callable] = None
+    conv_impl: Optional[Callable] = None
     latent: Optional[LatentShape] = None
     route: Tuple[int, int, bool] = (0, 0, False)
     gated_delta: Optional[Tuple[int, int, int]] = None
@@ -526,6 +531,7 @@ class Decoder(nn.Module):
             attention_scale=self.attention_scale,
             residual_multiplier=self.residual_multiplier, mixer=self.mixer,
             scan_impl=self.scan_impl, kda=self.kda, kda_impl=self.kda_impl,
+            conv_impl=self.conv_impl,
             latent=self.latent, route=self.route,
             gated_delta=self.gated_delta, norm_after=self.norm_after,
             qk_norm=self.qk_norm)
@@ -583,7 +589,8 @@ def run_remat_policy(model: Decoder, kind: str):
 def build_decoder(cfg: Config, attention_impl: Optional[Callable] = None,
                   token_sharding=None,
                   scan_impl: Optional[Callable] = None,
-                  kda_impl: Optional[Callable] = None) -> Decoder:
+                  kda_impl: Optional[Callable] = None,
+                  conv_impl: Optional[Callable] = None) -> Decoder:
     return Decoder(
         embed_dim=cfg.embed_dim, vocab_rows=cfg.vocab_rows,
         layer_kinds=cfg.layer_kinds, layer_heads=cfg.layer_heads,
@@ -613,7 +620,7 @@ def build_decoder(cfg: Config, attention_impl: Optional[Callable] = None,
         scan_impl=scan_impl,
         kda=((cfg.kda_conv_width, cfg.kda_gate_bound)
              if KDA in cfg.layer_kinds else None),
-        kda_impl=kda_impl,
+        kda_impl=kda_impl, conv_impl=conv_impl,
         latent=latent_shape(cfg),
         route=(cfg.route_groups, cfg.groups_per_token, cfg.route_bias),
         gated_delta=((cfg.gdn_key_size, cfg.gdn_value_size,

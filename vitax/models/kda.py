@@ -73,7 +73,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from vitax.models.ssm import (Leaf, a_log_init, causal_conv, conv_init,
+from vitax.models.ssm import (Leaf, a_log_init, conv_init, conv_silu,
                               dt_bias_init)
 from vitax.models.vit import Array, Dtype, default_init
 
@@ -85,7 +85,6 @@ KDA_CHUNK = 64          # tokens a chunk, where the row's length allows
 # what the step is said to need.
 KDA_COUNT_CHUNK = 64
 KDA_EXP_RANGE = 40.0    # the largest |exponent| a product's operand may take
-L2_EPS = 1e-6           # q and k: x * rsqrt(sum x^2 + eps)
 # float32 bytes of the (rows, chunks, sub-chunks, chunk, heads, head_size)
 # key-side decay that a block of chunks may hold
 KDA_BLOCK_BYTES = 256 * 2 ** 20
@@ -360,16 +359,12 @@ def kda(q: Array, k: Array, v: Array, g: Array, beta: Array,
         return o.transpose(1, 0, 3, 2, 4).reshape(r, t, h, dv)
 
 
-def l2norm(x: Array) -> Array:
-    return x * jax.lax.rsqrt(
-        jnp.sum(jnp.square(x), axis=-1, keepdims=True) + L2_EPS)
-
-
 class KDAMixer(nn.Module):
     shape: KDAShape
     norm_eps: float
     dtype: Dtype = jnp.bfloat16
     rule: Optional[Callable] = None     # the delta rule (None: the plain `kda`)
+    conv: Optional[Callable] = None     # `conv_silu`'s; None: `conv_silu`
 
     @nn.compact
     def __call__(self, u: Array, segment_ids: Array) -> Array:
@@ -389,13 +384,11 @@ class KDAMixer(nn.Module):
         with jax.named_scope("kda_conv"):
             taps = Leaf((s.conv_width, 3 * s.inner), conv_init, "kernel",
                         name="conv")()
-            qkv = causal_conv(qkv, segment_ids, taps, 0.0)
-            qkv = jnp.where(valid, jax.nn.silu(qkv), 0.0)
+            qkv = (self.conv or conv_silu)(
+                qkv, segment_ids, taps, None, self.dtype,
+                (dh, 2 * s.inner, s.inner))
             q, k, v = (x.reshape(r, t, h, dh)
                        for x in jnp.split(qkv, 3, axis=-1))
-
-            q = (l2norm(q) * dh ** -0.5).astype(self.dtype)
-            k, v = l2norm(k).astype(self.dtype), v.astype(self.dtype)
 
         with jax.named_scope("kda_gate"):
             a_log = Leaf((h,), a_log_init, name="A_log")()
@@ -440,6 +433,7 @@ class GatedDeltaMixer(nn.Module):
     shape: GatedDeltaShape
     norm_eps: float
     dtype: Dtype = jnp.bfloat16
+    conv: Optional[Callable] = None     # as `KDAMixer.conv`
 
     @nn.compact
     def __call__(self, u: Array, segment_ids: Array) -> Array:
@@ -459,13 +453,12 @@ class GatedDeltaMixer(nn.Module):
         with jax.named_scope("kda_conv"):
             taps = Leaf((s.conv_width, s.inner), conv_init, "kernel",
                         name="conv")()
-            qkv = causal_conv(qkv, segment_ids, taps, 0.0)
-            qkv = jnp.where(valid, jax.nn.silu(qkv), 0.0)
+            qkv = (self.conv or conv_silu)(
+                qkv, segment_ids, taps, None, self.dtype,
+                (dk, 2 * h * dk, h * dk))
             q, k, v = jnp.split(qkv, [h * dk, 2 * h * dk], axis=-1)
-            q = (l2norm(q.reshape(r, t, h, dk)) * dk ** -0.5).astype(
-                self.dtype)
-            k = l2norm(k.reshape(r, t, h, dk)).astype(self.dtype)
-            v = v.reshape(r, t, h, dv).astype(self.dtype)
+            q, k = q.reshape(r, t, h, dk), k.reshape(r, t, h, dk)
+            v = v.reshape(r, t, h, dv)
 
         with jax.named_scope("kda_gate"):
             a_log = Leaf((h,), a_log_init, name="A_log")()

@@ -51,7 +51,7 @@ attention core is; `SSDMixer.scan`):
 from __future__ import annotations
 
 import math
-from typing import Callable, NamedTuple, Optional
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -157,6 +157,38 @@ def causal_conv(x: Array, segment_ids: Array, kernel: Array,
     return y + bias
 
 
+L2_EPS = 1e-6           # a delta mixer's q and k: x * rsqrt(sum x^2 + eps)
+
+
+def l2norm(x: Array) -> Array:
+    return x * jax.lax.rsqrt(
+        jnp.sum(jnp.square(x), axis=-1, keepdims=True) + L2_EPS)
+
+
+def conv_silu(x: Array, segment_ids: Array, kernel: Array,
+              bias: Optional[Array], dtype: Dtype,
+              norm: Optional[Tuple[int, int, int]] = None) -> Array:
+    """What a recurrent mixer makes of its projection x (R, T, channels):
+    silu(causal_conv(x)), zero at padding; with `norm` = (head, normed,
+    scaled) the first `normed` channels L2-normed a head of `head` channels
+    and the first `scaled` of them times head ** -0.5 after it (a delta
+    mixer's k and q); float32 up to the one rounding to `dtype`. The plain
+    form: the CPU's path and the oracle of the kernel pair of
+    vitax/ops/conv.py, which takes these arguments (`SSDMixer.conv`,
+    `make_conv_impl`)."""
+    y = causal_conv(x, segment_ids, kernel, 0.0 if bias is None else bias)
+    y = jnp.where((segment_ids > 0)[..., None], jax.nn.silu(y), 0.0)
+    if norm is not None:
+        head, normed, scaled = norm
+        r, t, _ = y.shape
+        q, k, v = jnp.split(y, [scaled, normed], axis=-1)
+        q = l2norm(q.reshape(r, t, -1, head)) * head ** -0.5
+        k = l2norm(k.reshape(r, t, -1, head))
+        y = jnp.concatenate([q.reshape(r, t, scaled),
+                             k.reshape(r, t, normed - scaled), v], axis=-1)
+    return y.astype(dtype)
+
+
 def _chunk_block(r: int, chunks: int, heads: int, chunk: int) -> int:
     """Chunks a block: the most that divide `chunks` within the budget."""
     most = max(SSD_BLOCK_BYTES // (4 * r * heads * chunk * chunk), 1)
@@ -249,6 +281,7 @@ class SSDMixer(nn.Module):
     norm_eps: float
     dtype: Dtype = jnp.bfloat16
     scan: Optional[Callable] = None     # `ssd`'s arguments; None: `ssd`
+    conv: Optional[Callable] = None     # `conv_silu`'s; None: `conv_silu`
 
     @nn.compact
     def __call__(self, u: Array, segment_ids: Array) -> Array:
@@ -264,11 +297,9 @@ class SSDMixer(nn.Module):
 
         z, xbc, dt = jnp.split(linear(s.projected, "in_proj")(u),
                                [s.inner, s.inner + s.conv_channels], axis=-1)
-        valid = (segment_ids > 0)[..., None]
         with jax.named_scope("ssm_conv"):
-            xbc = causal_conv(xbc, segment_ids, *ConvTaps(
-                s.conv_width, s.conv_channels, name="conv")())
-            xbc = jnp.where(valid, jax.nn.silu(xbc), 0.0).astype(self.dtype)
+            xbc = (self.conv or conv_silu)(xbc, segment_ids, *ConvTaps(
+                s.conv_width, s.conv_channels, name="conv")(), self.dtype)
         x, b, c = jnp.split(xbc, [s.inner, s.inner + gn], axis=-1)
 
         a_log = Leaf((s.heads,), a_log_init, name="A_log")()

@@ -43,6 +43,7 @@ from vitax.config import Config
 from vitax.models import build_model
 from vitax.models.decoder import build_decoder
 from vitax.ops.attention import make_attention_impl
+from vitax.ops.conv import make_conv_impl
 from vitax.ops.kda import make_kda_impl
 from vitax.ops.ssd import make_scan_impl
 from vitax.parallel.mesh import Mesh, batch_pspec, build_mesh
@@ -77,7 +78,8 @@ def build_model_for(cfg: Config, mesh: Mesh, force_tpu_kernels: bool = False,
             cfg, attention_impl=attention_impl,
             token_sharding=token_sharding(cfg, mesh),
             scan_impl=make_scan_impl(cfg, mesh, force_tpu_kernels),
-            kda_impl=make_kda_impl(cfg, mesh, force_tpu_kernels))
+            kda_impl=make_kda_impl(cfg, mesh, force_tpu_kernels),
+            conv_impl=make_conv_impl(cfg, mesh, force_tpu_kernels))
     return build_model(
         cfg,
         attention_impl=attention_impl,

@@ -266,6 +266,12 @@ def train(cfg: Config) -> TrainState:
         from vitax.ops.kda import kda_choice
         master_print("delta rule: " + (
             getattr(model.kda_impl, "vitax_name", "") or kda_choice(cfg)[1]))
+    if cfg.decoder and {"mamba", "kda", "linear_attention"} & set(
+            cfg.layer_kinds):
+        from vitax.ops.conv import conv_choice
+        master_print("mixer convolution: " + (
+            getattr(model.conv_impl, "vitax_name", "")
+            or conv_choice(cfg)[1]))
     # the loop owns the state: a restore or a warm start replaces it, every
     # step donates it
     state, geom.state = geom.state, None
