@@ -613,6 +613,10 @@ def test_snapshot_pipeline_exactly_once_under_contention(
     monkeypatch.setattr(orbax_io_mod, "save_state", fake_save)
     state = {"w": jax.device_put(np.arange(8, dtype=np.float32))}
     pipe = snap_mod.SnapshotPipeline(max_buffer_sets=2)
+    # the first stage() reads the tree's layout into the pipeline, from the
+    # loop's thread before any other calls (the class's contract); what is
+    # contended here is the buffer pool and the worker's queue after it
+    pipe.stage(state, epoch=-1).release()
     n_threads, per_thread = 4, 6
     barrier = threading.Barrier(n_threads)
     errors = []

@@ -10,6 +10,7 @@ kernel body once, and that the kernels are found by name under the scopes
 the mixers' metrics read."""
 
 import collections
+import functools
 import itertools
 
 import jax
@@ -50,6 +51,8 @@ def norm_of(channels, name):
     return head, 2 * third, third
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "channels", "taps", "bias", "dtype", "seed"))
 def operands(channels, taps, bias, dtype=jnp.float32, seed=0):
     ks = jax.random.split(jax.random.key(seed + channels + taps), 4)
     x = jax.random.normal(ks[0], SEG.shape + (channels,)).astype(dtype)
@@ -59,14 +62,24 @@ def operands(channels, taps, bias, dtype=jnp.float32, seed=0):
         ks[3], x.shape)
 
 
-def value_and_grads(conv, ops, weight, dtype, norm, seg=SEG):
-    def total(x, kernel, *bias):
+def program(conv, operands, dtype, norm, seg=SEG):
+    """The compiled `(weight, *ops) -> (y, the gradients of sum(y * weight)
+    by each of the `operands` ops)`."""
+    def total(weight, x, kernel, *bias):
         y = conv(x, seg, kernel, *(bias or (None,)), dtype, norm)
         return jnp.sum(y.astype(jnp.float32) * weight), y
 
-    (_, y), grads = jax.jit(jax.value_and_grad(
-        total, argnums=tuple(range(len(ops))), has_aux=True))(*ops)
-    return y, grads
+    both = jax.jit(jax.value_and_grad(
+        total, argnums=tuple(range(1, 1 + operands)), has_aux=True))
+
+    def y_and_grads(weight, *ops):
+        (_, y), grads = both(weight, *ops)
+        return y, grads
+    return y_and_grads
+
+
+def value_and_grads(conv, ops, weight, dtype, norm, seg=SEG):
+    return program(conv, len(ops), dtype, norm, seg)(weight, *ops)
 
 
 @pytest.mark.parametrize("channels,taps,bias,norm", CASES)
@@ -98,16 +111,15 @@ def test_no_tap_crosses_a_documents_first_token(norm):
     ops, weight = operands(384, 4, True)
     first = (np.asarray(SEG) == 1)[..., None]
     other = (jnp.where(first, -ops[0], ops[0]),) + ops[1:]
-    y, _ = value_and_grads(fused.conv_silu, ops, weight, jnp.float32, norm)
-    y_other, _ = value_and_grads(fused.conv_silu, other, weight, jnp.float32,
-                                 norm)
+    run = program(fused.conv_silu, len(ops), jnp.float32, norm)
+    y, _ = run(weight, *ops)
+    y_other, _ = run(weight, *other)
     second = np.asarray(SEG) == 2
     np.testing.assert_array_equal(np.asarray(y)[second],
                                   np.asarray(y_other)[second])
     assert np.abs(np.asarray(y - y_other)[first[..., 0]]).max() > 1e-2
     # only the second document's outputs weigh: nothing reaches the first's x
-    _, grads = value_and_grads(fused.conv_silu, ops,
-                               weight * second[..., None], jnp.float32, norm)
+    _, grads = run(weight * second[..., None], *ops)
     assert float(np.abs(np.asarray(grads[0])[first[..., 0]]).max()) == 0.0
     assert float(np.abs(np.asarray(grads[0])[second]).max()) > 1e-2
 
@@ -303,7 +315,7 @@ def test_the_fused_mixer_equals_the_plain_mixer(name):
     u = jax.random.normal(jax.random.key(1), (1, T, 32))
     w = jax.random.normal(jax.random.key(2), u.shape)
     mixers = [_mixers(forced)[name] for forced in (False, True)]
-    variables = mixers[0].init(jax.random.key(0), u, seg)
+    variables = jax.jit(mixers[0].init)(jax.random.key(0), u, seg)
     want, got = (jax.jit(jax.value_and_grad(lambda v, u, m=m: jnp.sum(
         m.apply(v, u, seg) * w), argnums=(0, 1)))(variables, u)
         for m in mixers)

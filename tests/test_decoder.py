@@ -6,7 +6,6 @@ shares adding up, the kernels in interpret mode against a dense mask, routing
 without drops at its edges, RoPE against closed forms, the step's counters,
 and the loop on fake documents."""
 
-import json
 import math
 
 import jax
@@ -15,7 +14,8 @@ import numpy as np
 import pytest
 
 from benchmark.reference import laguna as reference
-from vitax.config import Config, parse_config
+from tests import decoder_cases as cases
+from vitax.config import Config
 from vitax.data.packing import document_layout, pack_documents
 from vitax.models import decoder
 from vitax.models.experts import SharedRoutedExperts
@@ -53,86 +53,39 @@ def reference_shape(cfg, routed=None):
         experts_routed=routed or cfg.experts_routed)
 
 
-def make_batch(cfg, lengths=LENGTHS, seed=0):
-    lay = document_layout(lengths, cfg.pack_tokens, cfg.pack_images)
-    ids = np.random.default_rng(seed).integers(
-        0, cfg.vocab_rows, lay["segment_ids"].shape).astype(np.int32)
-    return {"tokens": jnp.asarray(ids * (lay["segment_ids"] > 0)),
-            **{k: jnp.asarray(v) for k, v in lay.items()}}
-
-
-def seeded(model, cfg):
-    """Seeded weights with every leaf moved off its initial value, so that a
-    reference that dropped a scale or a gate would not agree."""
-    variables = model.init(jax.random.key(0),
-                           decoder.sample_documents(cfg, 1), True)
-    leaves, tree = jax.tree.flatten(variables)
-    keys = jax.random.split(jax.random.key(2), len(leaves))
-    return jax.tree.unflatten(tree, [
-        a + 0.05 * jax.random.normal(k, a.shape)
-        for a, k in zip(leaves, keys)])
-
-
 @pytest.fixture(scope="module", params=["share", "whole"])
-def setup(request):
+def case(request):
     share = request.param == "share"
     cfg = Config(**{**TINY, **({} if share else dict(
         experts_held=16, expert_first=0))}).validate()
-    model = decoder.build_decoder(cfg)
-    return cfg, model, seeded(model, cfg), make_batch(cfg), (
-        (cfg.expert_first, cfg.experts_held) if share else None)
+    held = (cfg.expert_first, cfg.experts_held) if share else None
+    return cases.DecoderCase(
+        cfg, reference, dict(reference_shape(cfg), experts_held=held),
+        LENGTHS)
 
 
-def documents(batch):
-    return [jnp.asarray(d) for d in reference.unpack(
-        np.asarray(batch["tokens"]), np.asarray(batch["segment_ids"]))]
+def test_logits_match_the_reference(case):
+    case.check_logits(padded=False)
 
 
-def test_logits_match_the_reference(setup):
-    cfg, model, variables, batch, held = setup
-    got = np.asarray(model.apply(variables, batch, True))
-    seg = np.asarray(batch["segment_ids"])
-    with jax.default_matmul_precision("highest"):
-        for r in range(seg.shape[0]):
-            for s in range(1, seg[r].max() + 1):
-                at = np.where(seg[r] == s)[0]
-                want = reference.logits(
-                    variables, batch["tokens"][r, at], experts_held=held,
-                    **reference_shape(cfg))
-                np.testing.assert_allclose(got[r, at], want, rtol=2e-4,
-                                           atol=2e-5)
-
-
-def test_loss_and_every_gradient_leaf_match_the_reference(setup):
-    from vitax.train.step import decoder_loss
-    cfg, model, variables, batch, held = setup
-    want_loss, want = jax.value_and_grad(lambda v: decoder_loss(
-        model.apply(v, batch, True), batch))(variables)
-    docs = documents(batch)
-    ats = [jnp.asarray([0, len(d) - 1]) for d in docs]
-    with jax.default_matmul_precision("highest"):
-        loss, grads, rows = reference.loss_grads_and_logits(
-            variables, docs, ats, experts_held=held, **reference_shape(cfg))
-        norms = reference.leaf_norms(grads)
-        plain = reference.loss(variables, docs, experts_held=held,
-                               **reference_shape(cfg))
+def test_loss_and_every_gradient_leaf_match_the_reference(case):
+    want_loss, want = case.loss_and_grads
+    loss, grads, _ = case.plain
+    norms, want_norms = (jax.jit(reference.leaf_norms)(g)
+                         for g in (grads, want))
     np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
-    np.testing.assert_allclose(plain, want_loss, rtol=1e-5)
-    flat_want = jax.tree_util.tree_leaves_with_path(
-        reference.leaf_norms(want))
+    np.testing.assert_allclose(case.plain_loss, want_loss, rtol=1e-5)
+    flat_want = jax.tree_util.tree_leaves_with_path(want_norms)
     flat_got = jax.tree.leaves(norms)
     assert len(flat_want) == len(flat_got) > 30
     for (path, a), b in zip(flat_want, flat_got):
         np.testing.assert_allclose(b, a, rtol=2e-3, atol=1e-7,
                                    err_msg=jax.tree_util.keystr(path))
-    np.testing.assert_allclose(reference.global_norm(norms),
-                               reference.global_norm(
-                                   reference.leaf_norms(want)), rtol=1e-4)
+    np.testing.assert_allclose(*(jax.jit(reference.global_norm)(n)
+                                 for n in (norms, want_norms)), rtol=1e-4)
     for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(grads)):
         assert reference.relative_gap(b, a) < 2e-3
-    logits = np.asarray(model.apply(variables, batch, True))
-    first = logits[0, [0, LENGTHS[0][0] - 1]]   # row 0's first document
-    np.testing.assert_allclose(rows[0], first, rtol=2e-4, atol=2e-5)
+    case.check_first_rows()
 
 
 def test_the_shares_add_up():
@@ -144,11 +97,16 @@ def test_the_shares_add_up():
     x = jax.random.normal(jax.random.key(1), (2, 24, d), jnp.float32)
     valid = jnp.ones((2, 24), bool)
     p = seeded_layer(whole, x, valid)
-    with jax.default_matmul_precision("highest"):
-        want = reference.routed_and_shared(
-            x.reshape(-1, d), p, top_k=k, routed_scale=2.5,
-            experts_routed=routed, experts_held=None)
-        shared = reference.swiglu(x.reshape(-1, d), p["shared"])
+
+    @jax.jit
+    def plain(p):
+        with jax.default_matmul_precision("highest"):
+            return reference.routed_and_shared(
+                x.reshape(-1, d), p, top_k=k, routed_scale=2.5,
+                experts_routed=routed, experts_held=None), \
+                reference.swiglu(x.reshape(-1, d), p["shared"])
+
+    want, shared = plain(p)
     total = shared
     for first in range(0, routed, held):
         share = SharedRoutedExperts(routed, held, first, k, 32, 32, 2.5,
@@ -156,19 +114,15 @@ def test_the_shares_add_up():
         cut = dict(p, **{
             name: {"kernel": p[name]["kernel"][first:first + held]}
             for name in ("experts_gate", "experts_up", "experts_down")})
-        out = share.apply({"params": cut}, x, valid)
+        out = jax.jit(share.apply)({"params": cut}, x, valid)
         total = total + (out.reshape(-1, d) - shared)
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
     assert float(jnp.max(jnp.abs(want - shared))) > 0.05  # the routed part
 
 
 def seeded_layer(layer, x, valid):
-    p = layer.init(jax.random.key(3), x, valid)["params"]
-    leaves, tree = jax.tree.flatten(p)
-    keys = jax.random.split(jax.random.key(4), len(leaves))
-    return jax.tree.unflatten(tree, [
-        a + 0.2 * jax.random.normal(k, a.shape)
-        for a, k in zip(leaves, keys)])
+    return cases.moved(jax.jit(layer.init)(jax.random.key(3), x, valid)[
+        "params"], key=4, by=0.2)
 
 
 def test_a_token_with_no_held_expert_and_an_expert_with_no_token():
@@ -194,10 +148,10 @@ def test_a_token_with_no_held_expert_and_an_expert_with_no_token():
                               mutable=["intermediates"])
         return jnp.sum(y * y), (y, cols["intermediates"]["expert_load"][0])
 
-    (_, (y, load)), grads = jax.value_and_grad(run, has_aux=True)(p)
+    (_, (y, load)), grads = jax.jit(jax.value_and_grad(run, has_aux=True))(p)
     assert load.tolist() == [10, 0]       # row 0's valid tokens; none
     with jax.default_matmul_precision("highest"):
-        shared = reference.swiglu(x.reshape(-1, d), p["shared"])
+        shared = jax.jit(reference.swiglu)(x.reshape(-1, d), p["shared"])
     np.testing.assert_allclose(y[1], shared.reshape(2, 12, d)[1], rtol=1e-4,
                                atol=1e-6)
     np.testing.assert_allclose(y[0, -2:], shared.reshape(2, 12, d)[0, -2:],
@@ -238,11 +192,13 @@ def test_document_kernels_match_a_dense_mask(group, window, skip):
         return decoder.causal_masked_attention(q, k, v, seg, window,
                                                jnp.float32)
 
-    out = kernel(q, k, v)
-    np.testing.assert_allclose(out, dense(q, k, v), rtol=1e-4, atol=1e-5)
+    out = jax.jit(kernel)(q, k, v)
+    np.testing.assert_allclose(out, jax.jit(dense)(q, k, v), rtol=1e-4,
+                               atol=1e-5)
     assert float(jnp.max(jnp.abs(out * (seg == 0)[..., None, None]))) == 0.0
-    got = jax.grad(lambda *a: jnp.sum(kernel(*a) * w), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2))(q, k, v)
+    got, want = (jax.jit(jax.grad(lambda *a, f=f: jnp.sum(f(*a) * w),
+                                  (0, 1, 2)))(q, k, v)
+                 for f in (kernel, dense))
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=5e-5)
 
@@ -268,16 +224,16 @@ def test_block_tables_skip_what_the_masks_kill():
 
 def test_model_through_the_kernels_equals_the_dense_path():
     cfg = Config(**{**TINY, "pack_tokens": 256}).validate()
-    batch = make_batch(cfg, [[120, 70, 40], [200, 30]])
+    batch = cases.make_batch(cfg, [[120, 70, 40], [200, 30]])
     dense = decoder.build_decoder(cfg)
-    variables = seeded(dense, cfg)
+    variables = cases.seeded(dense, cfg)
     from vitax.ops.attention import make_attention_impl
     impl = make_attention_impl(cfg, None, force_tpu_kernels=True)
     assert "causal" in impl.vitax_name
     through = decoder.build_decoder(cfg, attention_impl=impl)
-    np.testing.assert_allclose(through.apply(variables, batch, True),
-                               dense.apply(variables, batch, True),
-                               rtol=2e-4, atol=2e-5)
+    got, want = (jax.jit(lambda v, m=m: m.apply(v, batch, True))(variables)
+                 for m in (through, dense))
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
 def test_remat_keeps_o_and_lse_by_the_layers_span(monkeypatch):
@@ -316,6 +272,7 @@ def test_plain_rope_against_the_closed_form():
     x = jax.random.normal(jax.random.key(0), (1, 6, 1, 12))
     pos = jnp.asarray([[0, 1, 2, 5, 9, 30]])
     y = decoder.apply_rope(x, *decoder.rope_tables(pos, inv))
+    x, y = np.asarray(x), np.asarray(y)
     for t, p in enumerate(np.asarray(pos[0])):
         for i in range(4):
             a, b = float(x[0, t, 0, i]), float(x[0, t, 0, i + 4])
@@ -360,17 +317,10 @@ def test_train_step_counters_against_a_layout_counted_by_hand():
     """Documents of 30, 12, 9 and 20, 40 tokens in two rows of 64, window 8:
     111 tokens, 17 of padding, 106 targets; causal pairs 465 + 78 + 45 + 210
     + 820; window pairs 36 + (n - 8) * 8 each."""
-    from vitax.programs.builder import Geometry, build_program
     cfg = Config(**{**TINY, "warmup_steps": 1, "lr": 2e-3}).validate()
-    geom = Geometry.assemble(cfg, 100, materialize=True,
-                             devices=jax.devices()[:1])
-    state, geom.state = geom.state, None
-    step = build_program("train", geom)
-    batch = make_batch(cfg)
-    losses = []
-    for _ in range(4):
-        state, m = step(state, batch, jax.random.key(1))
-        losses.append(float(m["loss"]))
+    _, state, step = cases.assembled(cfg)
+    batch = cases.make_batch(cfg, LENGTHS)
+    _, m, losses = cases.take_steps(step, state, batch, 4)
     got = {k: float(m[k]) for k in (
         "tokens", "padding_tokens", "images", "targets", "causal_pairs",
         "window_pairs")}
@@ -461,9 +411,8 @@ def test_training_through_the_cli_path(tmp_path):
     point calls): packed fake documents, `build_program("train")`, a
     checkpoint save, a falling loss and the decoder's counters on the step
     records."""
-    from vitax.train.loop import train
-    cfg = parse_config((
-        "--fake_data", "--model_family", "decoder", "--pack_tokens", "128",
+    _, steps = cases.train_through_the_cli(
+        tmp_path, "--pack_tokens", "128",
         "--pack_images", "6", "--embed_dim", "64", "--num_blocks", "5",
         "--vocab_rows", "96", "--kv_heads", "2", "--head_size", "16",
         "--layer_kinds", ",".join(KINDS), "--layer_heads", "6,8,8,8,6",
@@ -474,20 +423,8 @@ def test_training_through_the_cli_path(tmp_path):
         "--routed_scale", "2.5", "--head_gate", "--rope_theta_full",
         "500000", "--rope_fraction_full", "0.5", "--yarn_factor", "64",
         "--yarn_orig_len", "16", "--yarn_beta_fast", "64",
-        "--yarn_attn_factor", str(ATTN_FACTOR), "--batch_size", "8",
-        "--num_epochs", "1", "--steps_per_epoch", "3", "--lr", "3e-3",
-        "--log_step_interval", "1", "--warmup_steps", "1",
-        "--ckpt_dir", str(tmp_path / "ckpt"),
-        "--metrics_dir", str(tmp_path / "metrics")))
-    train(cfg)
-    with open(tmp_path / "metrics" / "metrics.jsonl") as f:
-        steps = [r for r in map(json.loads, f) if "kind" not in r]
-    losses = [r["loss"] for r in steps]
-    assert len(losses) == 3 and np.isfinite(losses).all()
-    assert losses[-1] < losses[0]
+        "--yarn_attn_factor", str(ATTN_FACTOR))
     for r in steps:
-        assert 0.0 <= r["padding_frac"] < 1.0
         assert r["targets"] > 0 and r["causal_pairs"] >= r["window_pairs"] > 0
         assert np.asarray(r["expert_load"]).shape == (4, 4)
         assert r["expert_slots_here"] == np.asarray(r["expert_load"]).sum()
-    assert (tmp_path / "ckpt" / "epoch_1").exists()

@@ -49,9 +49,10 @@ def test_blocked_grads_match_reference(devices8, n, blk):
     def loss(attn):
         return lambda q, k, v: (attn(q, k, v) ** 2).sum()
 
-    got = jax.grad(loss(lambda q, k, v: blocked_flash_attention(
-        q, k, v, block_q=blk, block_k=blk)), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(q, k, v)
+    got = jax.jit(jax.grad(loss(lambda q, k, v: blocked_flash_attention(
+        q, k, v, block_q=blk, block_k=blk)), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(loss(reference_attention),
+                            argnums=(0, 1, 2)))(q, k, v)
     for g, w in zip(got, want):
         scale = float(jnp.abs(w).max())
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
@@ -112,8 +113,8 @@ def test_blocked_dropout_matches_masked_dense(devices8, n, bq, bk):
     def loss(attn):
         return lambda q, k, v: (attn(q, k, v) ** 2).sum()
 
-    got = jax.grad(loss(stream), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(loss(dense_masked), argnums=(0, 1, 2))(q, k, v)
+    got = jax.jit(jax.grad(loss(stream), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(loss(dense_masked), argnums=(0, 1, 2)))(q, k, v)
     for g, w in zip(got, want):
         scale = float(jnp.abs(w).max())
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),

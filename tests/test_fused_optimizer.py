@@ -162,8 +162,8 @@ class TestClipBranches:
                  (optax.ScaleByAdamState(count=jnp.int32(2), mu=mu, nu=nu),
                   optax.EmptyState(),
                   optax.ScaleByScheduleState(count=jnp.int32(2)))))
-            updates, _ = tx.update(grads, opt_state, params)
-            want = optax.apply_updates(params, updates)
+            want = jax.jit(lambda g, s, p: optax.apply_updates(
+                p, tx.update(g, s, p)[0]))(grads, opt_state, params)
             assert_tree_close(new_p, want, rtol=1e-6, atol=1e-8)
 
 

@@ -7,6 +7,8 @@ chunks, a chunk of padding only, beta at 1.99 on repeated keys and a decay of
 -60 a token; and the per-channel form held to the bit to what it was before
 this form came beside it, on tests/test_kda.py's cases."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,6 +22,7 @@ from vitax.models import kda as K
 H, DK, DV = 2, 6, 12        # K != V, as the 96 x 192 state
 
 
+@functools.partial(jax.jit, static_argnames=("case", "seed"))
 def inputs(seg, case: str, seed=0):
     """q, k unit-length a head (q times DK ** -0.5), v, g <= 0 ONE a head and
     beta in (0, 2), zero at padding, as the mixer hands them over."""
@@ -55,7 +58,8 @@ def _one_document(q, k, v, g, beta):
 
 
 def token_by_token(q, k, v, g, beta, seg):
-    """tests/test_kda.py's, over this file's recurrence (g a head)."""
+    """tests/test_kda.py's, over this file's recurrence (g a head); a caller
+    jits it with the concrete `seg` closed over."""
     seg = np.asarray(seg)
     t = seg.shape[1]
     out = jnp.zeros(v.shape, jnp.float32)
@@ -97,7 +101,7 @@ def test_scalar_decay_chunked_form_equals_the_recurrence(layout, chunk, case):
         # `sub` plays no part in this form: any value gives the same bits
         got = rule(*args, seg, chunk, chunk, jnp.float32)
         other = rule(*args, seg, chunk, 4, jnp.float32)
-    want = token_by_token(*args, seg)
+    want = jax.jit(lambda *a: token_by_token(*a, seg))(*args)
     assert got.dtype == jnp.float32 and np.isfinite(np.asarray(got)).all()
     assert got.shape == seg.shape + (H, DV)
     np.testing.assert_array_equal(got, other)
@@ -117,11 +121,10 @@ def test_scalar_decay_chunked_form_equals_the_recurrence(layout, chunk, case):
 def test_scalar_decay_gradients_equal_the_recurrences(layout, case):
     seg = segment_ids(LAYOUTS[layout])
     args = inputs(seg, case, seed=1)
-    w = jax.random.normal(jax.random.key(9), args[2].shape)
     with jax.default_matmul_precision("highest"):
         got = gradients(K.kda, args, seg, 8, 8, jnp.float32)
-    want = jax.grad(lambda *a: jnp.sum(token_by_token(*a, seg) * w),
-                    range(5))(*args)
+    want = gradients(lambda *a: token_by_token(*a[:6]), args, seg, 8, 8,
+                     jnp.float32)
     for name, a, b in zip("q k v g beta".split(), got, want):
         assert np.isfinite(np.asarray(a)).all(), name
         if float(jnp.max(jnp.abs(b))) < 1e-12:   # e^{-60}: nothing to hold
@@ -263,6 +266,9 @@ def kda_as_pr43(q, k, v, g, beta, segment_ids, chunk, sub, dtype):
 
 
 
+rule_as_pr43 = jax.jit(kda_as_pr43, static_argnums=STATIC)
+
+
 @pytest.mark.parametrize("decay", ["mixed", "at_the_bound"])
 @pytest.mark.parametrize("chunk,sub", [(8, 4), (16, 16)])
 @pytest.mark.parametrize("layout", sorted(LAYOUTS))
@@ -272,8 +278,7 @@ def test_per_channel_form_is_unchanged_to_the_bit(layout, chunk, sub, decay):
     assert args[3].ndim == 4
     np.testing.assert_array_equal(
         rule(*args, seg, chunk, sub, jnp.float32),
-        jax.jit(kda_as_pr43, static_argnums=STATIC)(
-            *args, seg, chunk, sub, jnp.float32))
+        rule_as_pr43(*args, seg, chunk, sub, jnp.float32))
 
 
 def test_per_channel_gradients_are_unchanged_to_the_bit():

@@ -9,7 +9,7 @@ import jax
 import numpy as np
 import pytest
 
-from tests.test_train_smoke import (build_train_objects, random_batch,
+from tests.test_train_smoke import (build_train_objects, fresh, random_batch,
                                     run_steps, tiny_cfg)
 
 
@@ -67,8 +67,11 @@ def test_dropout_deterministic_per_microbatch(devices8):
     the K>1 trajectory is deterministic given the seed, and differs from
     K=1 (different masks — by design, not a bug)."""
     kw = dict(att_dropout=0.1, mlp_dropout=0.1, pos_dropout=0.1)
-    _, a = run_steps(tiny_cfg(grad_accum_steps=2, **kw), n_steps=2)
-    _, b = run_steps(tiny_cfg(grad_accum_steps=2, **kw), n_steps=2)
+    cfg = tiny_cfg(grad_accum_steps=2, **kw)
+    mesh, state, step_fn, eval_fn = build_train_objects(cfg)
+    _, a = run_steps(cfg, n_steps=2,
+                     built=(mesh, fresh(state), step_fn, eval_fn))
+    _, b = run_steps(cfg, n_steps=2, built=(mesh, state, step_fn, eval_fn))
     np.testing.assert_array_equal(a, b)
     _, base = run_steps(tiny_cfg(**kw), n_steps=2)
     assert all(np.isfinite(a))

@@ -6,16 +6,14 @@ whose boundaries fall inside chunks, the vocabulary slice tied to the model,
 the closed-form parameter count, the step's counters, what the float8
 control does to the comparison, the flags and the loop."""
 
-import json
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from benchmark.reference import granite as reference
-from vitax.config import Config, parse_config
-from vitax.data.packing import document_layout
+from tests import decoder_cases as cases
+from vitax.config import Config
 from vitax.models import decoder
 
 KINDS = ["mamba", "mamba", "attention", "mamba"]
@@ -56,67 +54,22 @@ def reference_shape(cfg):
                    n_groups=cfg.ssm_groups, conv_bias=True))
 
 
-def make_batch(cfg, lengths=LENGTHS, seed=0, rows_held=None):
-    lay = document_layout(lengths, cfg.pack_tokens, cfg.pack_images)
-    ids = np.random.default_rng(seed).integers(
-        0, rows_held or cfg.vocab_rows,
-        lay["segment_ids"].shape).astype(np.int32)
-    return {"tokens": jnp.asarray(ids * (lay["segment_ids"] > 0)),
-            **{k: jnp.asarray(v) for k, v in lay.items()}}
-
-
-def seeded(model, cfg):
-    """Seeded weights with every leaf moved off its initial value, so that a
-    reference that dropped a scale, a bias or D would not agree."""
-    variables = model.init(jax.random.key(0),
-                           decoder.sample_documents(cfg, 1), True)
-    leaves, tree = jax.tree.flatten(variables)
-    keys = jax.random.split(jax.random.key(2), len(leaves))
-    return jax.tree.unflatten(tree, [
-        a + 0.05 * jax.random.normal(k, a.shape)
-        for a, k in zip(leaves, keys)])
-
-
 @pytest.fixture(scope="module")
-def setup():
+def case():
     cfg = Config(**TINY).validate()
-    model = decoder.build_decoder(cfg)
-    return cfg, model, seeded(model, cfg), make_batch(cfg)
+    return cases.DecoderCase(cfg, reference, reference_shape(cfg), LENGTHS)
 
 
-def documents(batch):
-    return [jnp.asarray(d) for d in reference.unpack(
-        np.asarray(batch["tokens"]), np.asarray(batch["segment_ids"]))]
+def test_logits_match_the_reference(case):
+    assert np.abs(case.logits).max() > 0.2
+    case.check_logits(padded=False)
 
 
-def test_logits_match_the_reference(setup):
-    cfg, model, variables, batch = setup
-    got = np.asarray(model.apply(variables, batch, True))
-    seg = np.asarray(batch["segment_ids"])
-    assert np.abs(got).max() > 0.2
-    with jax.default_matmul_precision("highest"):
-        for r in range(seg.shape[0]):
-            for s in range(1, seg[r].max() + 1):
-                at = np.where(seg[r] == s)[0]
-                want = reference.logits(variables, batch["tokens"][r, at],
-                                        **reference_shape(cfg))
-                np.testing.assert_allclose(got[r, at], want, rtol=2e-4,
-                                           atol=2e-5)
-
-
-def test_loss_and_every_gradient_leaf_match_the_reference(setup):
-    from vitax.train.step import decoder_loss
-    cfg, model, variables, batch = setup
-    want_loss, want = jax.value_and_grad(lambda v: decoder_loss(
-        model.apply(v, batch, True), batch))(variables)
-    docs = documents(batch)
-    ats = [jnp.asarray([0, len(d) - 1]) for d in docs]
-    with jax.default_matmul_precision("highest"):
-        loss, grads, rows = reference.loss_grads_and_logits(
-            variables, docs, ats, **reference_shape(cfg))
-        plain = reference.loss(variables, docs, **reference_shape(cfg))
+def test_loss_and_every_gradient_leaf_match_the_reference(case):
+    want_loss, want = case.loss_and_grads
+    loss, grads, _ = case.plain
     np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
-    np.testing.assert_allclose(plain, want_loss, rtol=1e-5)
+    np.testing.assert_allclose(case.plain_loss, want_loss, rtol=1e-5)
     flat = jax.tree_util.tree_leaves_with_path(want)
     # embedding (the tied head's too), final norm; a mamba run's 13 leaves
     # twice and the attention run's 9
@@ -124,12 +77,10 @@ def test_loss_and_every_gradient_leaf_match_the_reference(setup):
     for (path, a), b in zip(flat, jax.tree.leaves(grads)):
         assert reference.relative_gap(b, a) < 2e-3, \
             jax.tree_util.keystr(path)
-    np.testing.assert_allclose(
-        reference.global_norm(reference.leaf_norms(grads)),
-        reference.global_norm(reference.leaf_norms(want)), rtol=1e-4)
-    logits = np.asarray(model.apply(variables, batch, True))
-    first = logits[0, [0, LENGTHS[0][0] - 1]]   # row 0's first document
-    np.testing.assert_allclose(rows[0], first, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(*(
+        jax.jit(lambda g: reference.global_norm(reference.leaf_norms(g)))(g)
+        for g in (grads, want)), rtol=1e-4)
+    case.check_first_rows()
 
 
 def test_the_model_through_the_convolutions_kernels_equals_the_plain_path():
@@ -139,49 +90,34 @@ def test_the_model_through_the_convolutions_kernels_equals_the_plain_path():
     gradient are the plain path's."""
     from tests.test_ssd_kernel import gap
     from vitax.ops.conv import make_conv_impl
-    from vitax.train.step import decoder_loss
     cfg = Config(**{**TINY, "ssm_heads": 12}).validate()
     conv = make_conv_impl(cfg, None, force_tpu_kernels=True)
     assert conv.vitax_name.startswith("fused kernel (128 channels")
-    models = [decoder.build_decoder(cfg), decoder.build_decoder(
-        cfg, conv_impl=conv)]
-    variables, batch = seeded(models[0], cfg), make_batch(cfg)
-    want, got = (jax.jit(jax.value_and_grad(lambda v, m=m: decoder_loss(
-        m.apply(v, batch, True), batch)))(variables) for m in models)
-    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
-    np.testing.assert_allclose(models[1].apply(variables, batch, True),
-                               models[0].apply(variables, batch, True),
-                               rtol=2e-4, atol=2e-5)
-    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want[1]),
-                            jax.tree.leaves(got[1])):
-        assert gap(b, a) < 2e-4, jax.tree_util.keystr(path)
+    cases.check_conv_kernels_match_the_plain_path(
+        cfg, conv, cases.make_batch(cfg, LENGTHS), gap)
 
 
-def test_the_vocabulary_slice_is_tied_to_the_model(setup):
+def test_the_vocabulary_slice_is_tied_to_the_model(case):
     """A chip that holds rows 0-k of the tied table: on ids drawn from the
     slice its logits are those columns of the whole table's (the table is
     embedding and head at once, so the slice cuts both)."""
-    cfg, model, variables, _ = setup
-    held = 24
-    batch = make_batch(cfg, seed=5, rows_held=held)
-    whole = model.apply(variables, batch, True)
+    variables, held = case.variables, 24
+    batch = cases.make_batch(case.cfg, LENGTHS, seed=5, rows_held=held)
     cut_cfg = Config(**{**TINY, "vocab_rows": held}).validate()
     cut = jax.tree.map(lambda a: a, variables)
     cut["params"]["embed"]["embedding"] = \
         variables["params"]["embed"]["embedding"][:held]
-    got = decoder.build_decoder(cut_cfg).apply(cut, batch, True)
+    whole, got = (jax.jit(lambda v, m=m: m.apply(v, batch, True))(v)
+                  for m, v in ((case.model, variables),
+                               (decoder.build_decoder(cut_cfg), cut)))
     assert got.shape[-1] == held
     np.testing.assert_allclose(got, whole[..., :held], rtol=1e-5, atol=1e-6)
     assert "lm_head" not in variables["params"]
 
 
-def test_closed_form_parameter_count():
-    cfg = Config(**TINY).validate()
-    model = decoder.build_decoder(cfg)
-    variables = model.init(jax.random.key(0),
-                           decoder.sample_documents(cfg, 1), True)
-    assert sum(a.size for a in jax.tree.leaves(variables)) \
-        == decoder.expected_param_count(cfg)
+def test_closed_form_parameter_count(case):
+    assert sum(a.size for a in jax.tree.leaves(case.variables)) \
+        == decoder.expected_param_count(case.cfg)
     # the configuration of the benchmark's cell, by shapes alone
     real = Config(**GRANITE).validate()
     shapes = jax.eval_shape(
@@ -201,34 +137,16 @@ def test_closed_form_parameter_count():
         "mamba": 76_182_976, "attention": 60_821_504}
 
 
-def test_the_float8_control_is_told_from_the_program(setup):
+def test_the_float8_control_is_told_from_the_program(case):
     """The benchmark's control (weights rounded to float8_e4m3 for the
     program, the reference on the seeded ones) is off the reference by tens
     of times what the program is, gradient by gradient."""
-    from benchmark.generators.train_hybrid_packed import (round_to_float8,
-                                                          watched_leaves)
-    from vitax.train.step import decoder_loss
-    cfg, model, variables, batch = setup
-
-    def grads_of(v):
-        return watched_leaves(jax.grad(lambda v: decoder_loss(
-            model.apply(v, batch, True), batch))(v), cfg)
-
-    docs = documents(batch)
-    with jax.default_matmul_precision("highest"):
-        _, want, _ = reference.loss_grads_and_logits(
-            variables, docs, [jnp.asarray([0])] * len(docs),
-            **reference_shape(cfg))
-    want = watched_leaves(want, cfg)
-    assert sorted(want) == [
+    from benchmark.generators import train_hybrid_packed
+    want = case.check_float8_control(train_hybrid_packed, [
         "attention.wq", "first.conv", "first.in_proj", "last.conv",
-        "last.in_proj", "mamba.A_log", "mamba.dt_bias"]
+        "last.in_proj", "mamba.A_log", "mamba.dt_bias"])
     # A_log and dt_bias of the three mamba layers together
-    assert want["mamba.A_log"].shape == (3, cfg.ssm_heads)
-    sound, control = grads_of(variables), grads_of(round_to_float8(variables))
-    for name in want:
-        assert reference.relative_gap(sound[name], want[name]) < 2e-3, name
-        assert reference.relative_gap(control[name], want[name]) > 2e-2, name
+    assert want["mamba.A_log"].shape == (3, case.cfg.ssm_heads)
 
 
 @pytest.mark.parametrize("clip", [0.05, 1.0])
@@ -237,24 +155,11 @@ def test_the_first_steps_moments_hand_back_its_gradients(clip):
     the optimizer state its first call left (`step_gradients`) are the
     model's own, with the clip at work (the norm here is 0.15) and
     without."""
-    from benchmark.generators.train_hybrid_packed import (step_gradients,
-                                                          watched_leaves)
-    from vitax.programs.builder import Geometry, build_program
-    from vitax.train.step import decoder_inputs, decoder_loss
+    from benchmark.generators import train_hybrid_packed
     cfg = Config(**{**TINY, "clip_grad_norm": clip}).validate()
-    geom = Geometry.assemble(cfg, 100, materialize=True,
-                             devices=jax.devices()[:1])
-    state, geom.state = geom.state, None
-    batch = make_batch(cfg)
-    want = watched_leaves(jax.grad(lambda v: decoder_loss(geom.model.apply(
-        v, decoder_inputs(batch), True), batch))(state.params), cfg)
-    state, m = build_program("train", geom)(state, batch, jax.random.key(1))
-    norm = float(m["grad_norm"])
-    assert (norm > clip) == (clip == 0.05)
-    got = step_gradients(state.opt_state, norm, cfg)
-    assert sorted(got) == sorted(want)
-    for name in want:
-        assert reference.relative_gap(got[name], want[name]) < 1e-5, name
+    cases.check_first_steps_moments(
+        train_hybrid_packed, cfg, cases.make_batch(cfg, LENGTHS),
+        clipped=clip == 0.05)
 
 
 def test_train_step_counters_against_a_layout_counted_by_hand():
@@ -263,17 +168,10 @@ def test_train_step_counters_against_a_layout_counted_by_hand():
     28; row 0's chunks hold 8 | 5 + 3 | 2 + 6 | 3 tokens of one document
     each, row 1's 8 | 8 | 4 + 4 | 3: pairs 36 + 15 + 6 + 3 + 21 + 6, and 36
     + 36 + 10 + 10 + 6; all 8 chunks hold a token."""
-    from vitax.programs.builder import Geometry, build_program
     cfg = Config(**{**TINY, "warmup_steps": 1, "lr": 2e-3}).validate()
-    geom = Geometry.assemble(cfg, 100, materialize=True,
-                             devices=jax.devices()[:1])
-    state, geom.state = geom.state, None
-    step = build_program("train", geom)
-    batch = make_batch(cfg)
-    losses = []
-    for _ in range(4):
-        state, m = step(state, batch, jax.random.key(1))
-        losses.append(float(m["loss"]))
+    _, state, step = cases.assembled(cfg)
+    _, m, losses = cases.take_steps(
+        step, state, cases.make_batch(cfg, LENGTHS), 4)
     got = {k: float(m[k]) for k in (
         "tokens", "padding_tokens", "images", "targets", "causal_pairs",
         "ssd_pairs", "ssd_live_chunks")}
@@ -293,8 +191,8 @@ def test_train_step_counters_against_a_layout_counted_by_hand():
     # a Laguna-shaped model counts no scan
     from tests.test_decoder import TINY as LAGUNA
     from vitax.train.step import decoder_counts
-    assert "ssd_pairs" not in decoder_counts(
-        Config(**LAGUNA).validate(),
+    assert "ssd_pairs" not in jax.eval_shape(
+        lambda batch: decoder_counts(Config(**LAGUNA).validate(), batch),
         {"segment_ids": jnp.ones((2, 64), jnp.int32)})
 
 
@@ -329,17 +227,11 @@ def test_config_refuses_what_is_not_built(change, message):
 
 
 def test_the_family_declares_the_new_shape_fields():
-    import os
-    from benchmark import forms
-    from benchmark import manifest as mf
-    granite = forms.declared_keys(mf.read_json(
-        os.path.join(mf.BENCH_DIR, "shapes", "granite.json")))
     assert {"ssm_heads", "ssm_head_size", "ssm_state_size", "ssm_conv_width",
             "ssm_groups", "ssm_chunk",
             "position_embedding", "tie_embeddings", "embedding_multiplier",
             "residual_multiplier", "attention_multiplier",
-            "logits_scaling"} <= granite
-    assert not granite & forms.knob_keys(forms.rules())
+            "logits_scaling"} <= cases.family_declares("granite")
 
 
 def test_training_through_the_cli_path(tmp_path, capsys):
@@ -349,9 +241,8 @@ def test_training_through_the_cli_path(tmp_path, capsys):
     (`--logits_scaling` 0.05, not the model's 8: fresh random ids every step
     leave nothing to learn below ln(vocabulary rows), which is where a tied
     table of std 0.02 divided by 8 starts.)"""
-    from vitax.train.loop import train
-    cfg = parse_config((
-        "--fake_data", "--model_family", "decoder", "--pack_tokens", "64",
+    cfg, steps = cases.train_through_the_cli(
+        tmp_path, "--pack_tokens", "64",
         "--pack_images", "6", "--embed_dim", "32", "--num_blocks", "4",
         "--vocab_rows", "48", "--kv_heads", "2", "--head_size", "8",
         "--layer_kinds", ",".join(KINDS), "--layer_heads", "0,0,4,0",
@@ -361,25 +252,14 @@ def test_training_through_the_cli_path(tmp_path, capsys):
         "--residual_multiplier", "0.22", "--attention_multiplier", "0.2",
         "--logits_scaling", "0.05", "--ssm_heads", "8", "--ssm_head_size", "8",
         "--ssm_state_size", "16", "--ssm_conv_width", "4", "--ssm_chunk",
-        "8", "--batch_size", "8", "--num_epochs", "1",
-        "--steps_per_epoch", "3", "--lr", "3e-3", "--log_step_interval", "1",
-        "--warmup_steps", "1", "--ckpt_dir", str(tmp_path / "ckpt"),
-        "--metrics_dir", str(tmp_path / "metrics")))
+        "8")
     assert cfg.tie_embeddings and cfg.ssm_groups == 1
-    train(cfg)
     # which form of the scan and of the convolution in front of it runs,
     # beside the attention core's line
     out = capsys.readouterr().out
     assert "state-space scan: plain (no TPU)" in out
     assert "mixer convolution: plain (no TPU)" in out
-    with open(tmp_path / "metrics" / "metrics.jsonl") as f:
-        steps = [r for r in map(json.loads, f) if "kind" not in r]
-    losses = [r["loss"] for r in steps]
-    assert len(losses) == 3 and np.isfinite(losses).all()
-    assert losses[-1] < losses[0]
     for r in steps:
-        assert 0.0 <= r["padding_frac"] < 1.0
         assert 0 < r["ssd_pairs"] <= r["causal_pairs"]
         assert 0 < r["ssd_live_chunks"] <= 8 * 64 // 8
         assert r["expert_slots_here"] == 0 and r["expert_load"] == []
-    assert (tmp_path / "ckpt" / "epoch_1").exists()

@@ -5,6 +5,8 @@ with document boundaries inside chunks, a chunk of padding only, two chunk
 lengths and a decay at the bound; the triangular inverse; the grid the
 program chooses; the mixer against the reference's."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -32,6 +34,7 @@ def segment_ids(lengths, tokens=32):
     return jnp.asarray(np.stack(rows), jnp.int32)
 
 
+@functools.partial(jax.jit, static_argnames=("decay", "seed"))
 def inputs(seg, decay: str, seed=0):
     """q, k unit-length a head (q times DK ** -0.5), v, g in (BOUND, 0) and
     beta in (0, 1), zero at padding, as the mixer hands them over."""
@@ -62,7 +65,8 @@ def _one_document(q, k, v, g, beta):
 def token_by_token(q, k, v, g, beta, seg):
     """The recurrence on each document alone (followed by zeros up to the
     row's length, which no token of it can see, so that one compiled program
-    serves them all), zeros at padding."""
+    serves them all), zeros at padding. `seg` is concrete: a caller jits
+    this with the layout closed over."""
     seg = np.asarray(seg)
     t = seg.shape[1]
     out = jnp.zeros(v.shape, jnp.float32)
@@ -75,16 +79,37 @@ def token_by_token(q, k, v, g, beta, seg):
     return out
 
 
+kda = jax.jit(K.kda, static_argnums=(6, 7, 8))
+
+
+@functools.cache
+def recurrence(layout, decay):
+    """(segment ids, inputs, the recurrence's values), made once for the
+    chunkings that are held to them."""
+    seg = segment_ids(LAYOUTS[layout])
+    args = inputs(seg, decay)
+    return seg, args, jax.jit(lambda *a: token_by_token(*a, seg))(*args)
+
+
+@functools.cache
+def recurrence_gradients(layout, decay):
+    """(segment ids, inputs, a weight, the recurrence's gradients of the
+    weighted sum), likewise."""
+    seg = segment_ids(LAYOUTS[layout])
+    args = inputs(seg, decay, seed=1)
+    w = jax.random.normal(jax.random.key(9), args[2].shape)
+    return seg, args, w, jax.jit(jax.grad(
+        lambda *a: jnp.sum(token_by_token(*a, seg) * w), range(5)))(*args)
+
+
 @pytest.mark.parametrize("decay", ["mild", "mixed", "at_the_bound"])
 @pytest.mark.parametrize("chunk,sub", [(8, 4), (16, 8), (16, 16), (32, 8)])
 @pytest.mark.parametrize("layout", sorted(LAYOUTS))
 def test_chunked_form_equals_the_recurrence(layout, chunk, sub, decay):
-    seg = segment_ids(LAYOUTS[layout])
-    args = inputs(seg, decay)
+    seg, args, want = recurrence(layout, decay)
     if decay == "at_the_bound":
         assert float(jnp.min(args[3])) < BOUND + 1e-6
-    got = K.kda(*args, seg, chunk, sub, jnp.float32)
-    want = token_by_token(*args, seg)
+    got = kda(*args, seg, chunk, sub, jnp.float32)
     assert got.dtype == jnp.float32 and np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-6)
     assert float(jnp.max(jnp.abs(got * (seg == 0)[..., None, None]))) == 0.0
@@ -95,20 +120,16 @@ def test_chunked_form_equals_the_recurrence(layout, chunk, sub, decay):
 @pytest.mark.parametrize("chunk,sub", [(8, 4), (16, 8)])
 @pytest.mark.parametrize("layout", sorted(LAYOUTS))
 def test_gradients_equal_the_recurrences(layout, chunk, sub, decay):
-    seg = segment_ids(LAYOUTS[layout])
-    args = inputs(seg, decay, seed=1)
-    w = jax.random.normal(jax.random.key(9), args[2].shape)
-    got = jax.grad(lambda *a: jnp.sum(K.kda(
-        *a, seg, chunk, sub, jnp.float32) * w), range(5))(*args)
-    want = jax.grad(lambda *a: jnp.sum(token_by_token(*a, seg) * w),
-                    range(5))(*args)
+    seg, args, w, want = recurrence_gradients(layout, decay)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(K.kda(
+        *a, seg, chunk, sub, jnp.float32) * w), range(5)))(*args)
+    pad = np.asarray(seg) == 0
     for name, a, b in zip("q k v g beta".split(), got, want):
         assert np.isfinite(np.asarray(a)).all(), name
         assert reference.relative_gap(a, b) < 2e-4, name
         # nothing reaches padding but the rounding of terms that cancel
         # (the running sum a sub-chunk's exponents are taken from)
-        assert float(jnp.max(jnp.abs(
-            a * (seg == 0).reshape(seg.shape + (1,) * (a.ndim - 2))))) < 1e-6
+        assert np.abs(np.asarray(a)[pad]).max(initial=0.0) < 1e-6
 
 
 def test_blocks_of_chunks_give_what_one_block_gives(monkeypatch):
@@ -120,11 +141,11 @@ def test_blocks_of_chunks_give_what_one_block_gives(monkeypatch):
     def loss(*a):
         return jnp.sum(jnp.sin(K.kda(*a, seg, 8, 4, jnp.float32)))
 
-    whole = jax.value_and_grad(loss, range(5))(*args)
+    whole = jax.jit(jax.value_and_grad(loss, range(5)))(*args)
     per_chunk = 4 * 2 * (8 // 4) * 8 * H * DK
     monkeypatch.setattr(K, "KDA_BLOCK_BYTES", 2 * per_chunk)
     assert K._chunk_block(per_chunk, 4) == 2
-    blocked = jax.value_and_grad(loss, range(5))(*args)
+    blocked = jax.jit(jax.value_and_grad(loss, range(5)))(*args)
     for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(blocked)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
 
@@ -137,16 +158,18 @@ def test_bfloat16_operands_stay_close_and_finite_at_the_bound():
     args = inputs(seg, "at_the_bound", seed=3)
     chunk, sub = K.tiling(64, BOUND)
     assert (chunk, sub) == (64, 16)
-    want = K.kda(*args, seg, chunk, sub, jnp.float32)
+    want = kda(*args, seg, chunk, sub, jnp.float32)
     q, k, v, g, beta = args
-    got = K.kda(q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
-                v.astype(jnp.bfloat16), g, beta, seg, chunk, sub,
-                jnp.bfloat16)
+
+    def rounded(g):
+        return K.kda(q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
+                     v.astype(jnp.bfloat16), g, beta, seg, chunk, sub,
+                     jnp.bfloat16)
+
+    got = jax.jit(rounded)(g)
     assert got.dtype == jnp.float32 and np.isfinite(np.asarray(got)).all()
     assert reference.relative_gap(got, want) < 2e-2
-    grads = jax.grad(lambda g: jnp.sum(K.kda(
-        q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
-        v.astype(jnp.bfloat16), g, beta, seg, chunk, sub, jnp.bfloat16)))(g)
+    grads = jax.jit(jax.grad(lambda g: jnp.sum(rounded(g))))(g)
     assert np.isfinite(np.asarray(grads)).all()
 
 
@@ -155,8 +178,9 @@ def test_unit_lower_inverse(c):
     # entries the size of b (k . k) decay: below 1, about head_size ** -0.5
     a = jnp.tril(jax.random.normal(jax.random.key(c), (3, c, c)), -1) \
         * 0.5 / max(c, 4) ** 0.5
-    want = jnp.linalg.inv(jnp.eye(c) + a)
-    np.testing.assert_allclose(K.unit_lower_inverse(a), want, rtol=1e-4,
+    want = np.linalg.inv(np.eye(c) + np.asarray(a))
+    np.testing.assert_allclose(jax.jit(K.unit_lower_inverse)(a), want,
+                               rtol=1e-4,
                                atol=1e-4 * float(jnp.max(jnp.abs(want))))
 
 
@@ -173,11 +197,16 @@ def test_tiling_follows_the_row_and_the_bound(tokens, bound, want):
 
 def seeded_mixer(shape, u, seg, dtype=jnp.float32):
     mixer = K.KDAMixer(shape, 1e-6, dtype)
-    p = mixer.init(jax.random.key(0), u, seg)["params"]
-    leaves, tree = jax.tree.flatten(p)
-    keys = jax.random.split(jax.random.key(4), len(leaves))
-    return mixer, jax.tree.unflatten(tree, [
-        a + 0.1 * jax.random.normal(k, a.shape) for a, k in zip(leaves, keys)])
+
+    @jax.jit
+    def seeded(u, seg):
+        p = mixer.init(jax.random.key(0), u, seg)["params"]
+        leaves, tree = jax.tree.flatten(p)
+        keys = jax.random.split(jax.random.key(4), len(leaves))
+        return jax.tree.unflatten(tree, [
+            a + 0.1 * jax.random.normal(k, a.shape)
+            for a, k in zip(leaves, keys)])
+    return mixer, seeded(u, seg)
 
 
 def test_mixer_equals_the_references_values_and_gradients():
@@ -202,24 +231,24 @@ def test_mixer_equals_the_references_values_and_gradients():
             u, p, 1e-6, head_dim=8, taps=4, gate_bound=BOUND) * w)
 
     def plain(p):
-        total = 0.0
+        total, rows = 0.0, np.asarray(seg)
         for r in range(2):
-            for s in range(1, int(seg[r].max()) + 1):
-                at = np.where(np.asarray(seg[r]) == s)[0]
+            for s in range(1, rows[r].max() + 1):
+                at = np.where(rows[r] == s)[0]
                 fill = ((0, 32 - len(at)), (0, 0))
                 total += alone(p, jnp.pad(u[r, at], fill),
                                jnp.pad(w[r, at], fill))
         return total
 
     with jax.default_matmul_precision("highest"):
-        want, want_grads = jax.value_and_grad(plain)(p)
-    got, got_grads = jax.value_and_grad(program)(p)
+        want, want_grads = jax.jit(jax.value_and_grad(plain))(p)
+    got, got_grads = jax.jit(jax.value_and_grad(program))(p)
     np.testing.assert_allclose(got, want, rtol=1e-5)
     flat = jax.tree_util.tree_leaves_with_path(got_grads)
     assert len(flat) == 11
     for (path, a), b in zip(flat, jax.tree.leaves(want_grads)):
         assert reference.relative_gap(a, b) < 5e-4, jax.tree_util.keystr(path)
-    out = mixer.apply({"params": p}, u, seg)
+    out = jax.jit(mixer.apply)({"params": p}, u, seg)
     pad = np.asarray(seg) == 0
     # padding receives nothing but what W_o makes of zeros
     assert float(jnp.max(jnp.abs(out[pad]))) == 0.0

@@ -9,6 +9,8 @@ them; the inverse alone and its closed-form cotangent; which form
 `make_kda_impl` chooses, and that the chosen kernels are found by name, under
 the scope a metric reads, with no (chunk, chunk) array left outside them."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -36,6 +38,7 @@ LAYOUTS = {
 HEADS_A_STEP = {"a_boundary_inside_a_chunk": 2}     # the others 1
 
 
+@functools.partial(jax.jit, static_argnames=("decay", "dtype", "seed"))
 def operands(seg, decay="mixed", dtype=jnp.float32, seed=0):
     """q, k unit length a head (q times D ** -0.5), v, g in (BOUND, 0) and
     beta in (0, 1), zero at padding, as the mixer hands them over."""
@@ -105,9 +108,8 @@ def test_kernel_matches_the_token_by_token_recurrence(name, decay):
         assert float(jnp.min(ops[3])) < BOUND + 1e-6
     got_o, got = value_and_grads(fused.kda_fused, seg, ops, weight, chunk,
                                  sub)
-    want_o = token_by_token(*ops, seg)
-    want = jax.grad(lambda *a: jnp.sum(token_by_token(*a, seg) * weight),
-                    range(5))(*ops)
+    want_o, want = value_and_grads(lambda *a: token_by_token(*a[:6]), seg,
+                                   ops, weight, chunk, sub)
     assert np.isfinite(np.asarray(got_o)).all()
     np.testing.assert_allclose(got_o, want_o, rtol=2e-4, atol=2e-6)
     pad = np.asarray(seg) == 0
@@ -150,8 +152,9 @@ def lower_triangles(c, seed=0, n=3):
 @pytest.mark.parametrize("c", [16, 32, 64])
 def test_the_inverse_is_the_plain_forms_to_1e_5(c):
     a = lower_triangles(c, seed=c)
-    want = plain.unit_lower_inverse(a)
-    got = jnp.stack([fused.unit_lower_inverse(m) for m in a])
+    want = jax.jit(plain.unit_lower_inverse)(a)
+    got = jax.jit(lambda a: jnp.stack(
+        [fused.unit_lower_inverse(m) for m in a]))(a)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     eye = np.eye(c)
     for x, m in zip(np.asarray(got, np.float64), np.asarray(a, np.float64)):
@@ -162,9 +165,10 @@ def test_the_inverse_is_the_plain_forms_to_1e_5(c):
 def test_the_inverses_closed_form_cotangent_is_jax_grad_of_the_doublings(c):
     a = lower_triangles(c, seed=c + 1, n=1)[0]
     dx = jax.random.normal(jax.random.key(7), (c, c))
-    want = jax.grad(lambda a: jnp.sum(plain.unit_lower_inverse(a) * dx))(a)
-    x = fused.unit_lower_inverse(a)
-    got = fused.unit_lower_inverse_vjp(x, dx)
+    want = jax.jit(jax.grad(
+        lambda a: jnp.sum(plain.unit_lower_inverse(a) * dx)))(a)
+    got = jax.jit(lambda a: fused.unit_lower_inverse_vjp(
+        fused.unit_lower_inverse(a), dx))(a)
     # JAX's is the cotangent of every entry; the kernel keeps the strictly
     # lower ones, the others being no function of anything
     assert gap(jnp.tril(got, -1), jnp.tril(want, -1)) < 1e-5
@@ -322,7 +326,7 @@ def test_the_fused_mixer_equals_the_plain_mixer():
     w = jax.random.normal(jax.random.key(2), u.shape)
     mixers = [plain.KDAMixer(shape, 1e-5, jnp.float32, rule=rule)
               for rule in (None, fused.make_kda_impl(cfg, None, True))]
-    variables = mixers[0].init(jax.random.key(0), u, seg)
+    variables = jax.jit(mixers[0].init)(jax.random.key(0), u, seg)
     want, got = (jax.jit(jax.value_and_grad(lambda v, u, m=m: jnp.sum(
         m.apply(v, u, seg) * w), argnums=(0, 1)))(variables, u)
         for m in mixers)

@@ -8,16 +8,14 @@ mode, the closed-form parameter count, the step's counters, the
 configuration's sentences, the flags and the loop. The layers one by one,
 the router's choice and the shares: tests/test_latent_layers.py."""
 
-import json
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from benchmark.reference import ling as reference
-from vitax.config import Config, parse_config
-from vitax.data.packing import document_layout
+from tests import decoder_cases as cases
+from vitax.config import Config
 from vitax.models import decoder
 
 # the cell's pattern: a dense kda layer, then one whole period
@@ -61,84 +59,27 @@ def reference_shape(cfg):
                     bias=cfg.route_bias, experts_routed=cfg.experts_routed))
 
 
-def make_batch(cfg, lengths=LENGTHS, seed=0):
-    lay = document_layout(lengths, cfg.pack_tokens, cfg.pack_images)
-    ids = np.random.default_rng(seed).integers(
-        0, cfg.vocab_rows, lay["segment_ids"].shape).astype(np.int32)
-    return {"tokens": jnp.asarray(ids * (lay["segment_ids"] > 0)),
-            **{k: jnp.asarray(v) for k, v in lay.items()}}
-
-
-def moved(tree, key=2, by=0.05):
-    """Every leaf moved off its initial value (the router's bias off zero
-    too), so that a reference that dropped a scale, a gate or the bias would
-    not agree."""
-    leaves, struct = jax.tree.flatten(tree)
-    keys = jax.random.split(jax.random.key(key), len(leaves))
-    return jax.tree.unflatten(struct, [
-        a + by * jax.random.normal(k, a.shape) for a, k in zip(leaves, keys)])
-
-
 @pytest.fixture(scope="module")
-def setup():
+def case():
     cfg = Config(**TINY).validate()
-    model = decoder.build_decoder(cfg)
-    variables = moved(model.init(jax.random.key(0),
-                                 decoder.sample_documents(cfg, 1), True))
-    return cfg, model, variables, make_batch(cfg)
-
-
-@pytest.fixture(scope="module")
-def plain(setup):
-    """The reference's loss, gradients and logits at each document's first
-    and last position, computed once for the tests that hold them."""
-    cfg, _, variables, batch = setup
-    docs = documents(batch)
-    ats = [jnp.asarray([0, len(d) - 1]) for d in docs]
-    with jax.default_matmul_precision("highest"):
-        return reference.loss_grads_and_logits(
-            variables, docs, ats, experts_held=held_of(cfg),
-            **reference_shape(cfg))
-
-
-def documents(batch):
-    return [jnp.asarray(d) for d in reference.unpack(
-        np.asarray(batch["tokens"]), np.asarray(batch["segment_ids"]))]
-
-
-def held_of(cfg):
-    return (cfg.expert_first, cfg.experts_held)
+    return cases.DecoderCase(
+        cfg, reference, dict(reference_shape(cfg), experts_held=(
+            cfg.expert_first, cfg.experts_held)), LENGTHS)
 
 
 # --- the whole model ----------------------------------------------------------
 
-def test_logits_match_the_reference(setup):
-    cfg, model, variables, batch = setup
-    got = np.asarray(model.apply(variables, batch, True))
-    seg = np.asarray(batch["segment_ids"])
+def test_logits_match_the_reference(case):
+    got = case.logits
     assert np.abs(got).max() > 0.2
-    @jax.jit
-    def alone(ids):         # a document followed by zeros it cannot see
-        with jax.default_matmul_precision("highest"):
-            return reference.logits(variables, ids, experts_held=held_of(cfg),
-                                    **reference_shape(cfg))
-
-    for r in range(seg.shape[0]):
-        for s in range(1, seg[r].max() + 1):
-            at = np.where(seg[r] == s)[0]
-            want = alone(jnp.pad(batch["tokens"][r, at],
-                                 (0, seg.shape[1] - len(at))))[:len(at)]
-            np.testing.assert_allclose(got[r, at], want, rtol=2e-4,
-                                       atol=2e-5)
+    case.check_logits(padded=True)
+    seg = np.asarray(case.batch["segment_ids"])
     assert float(np.abs(got[seg == 0]).max()) < 10.0      # finite at padding
 
 
-def test_loss_and_every_gradient_leaf_match_the_reference(setup, plain):
-    from vitax.train.step import decoder_loss
-    cfg, model, variables, batch = setup
-    want_loss, want = jax.jit(jax.value_and_grad(lambda v: decoder_loss(
-        model.apply(v, batch, True), batch)))(variables)
-    loss, grads, rows = plain
+def test_loss_and_every_gradient_leaf_match_the_reference(case):
+    want_loss, want = case.loss_and_grads
+    loss, grads, _ = case.plain
     np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
     flat = jax.tree_util.tree_leaves_with_path(want)
     # embedding, head, final norm; the dense kda run's 16 leaves, the sparse
@@ -151,16 +92,14 @@ def test_loss_and_every_gradient_leaf_match_the_reference(setup, plain):
             assert float(jnp.max(jnp.abs(b))) == 0.0
             continue
         assert reference.relative_gap(b, a) < 2e-3, name
-    np.testing.assert_allclose(
-        reference.global_norm(reference.leaf_norms(grads)),
-        reference.global_norm(reference.leaf_norms(want)), rtol=1e-4)
-    logits = np.asarray(model.apply(variables, batch, True))
-    first = logits[0, [0, LENGTHS[0][0] - 1]]   # row 0's first document
-    np.testing.assert_allclose(rows[0], first, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(*(
+        jax.jit(lambda g: reference.global_norm(reference.leaf_norms(g)))(g)
+        for g in (grads, want)), rtol=1e-4)
+    case.check_first_rows()
 
 
-def test_the_layer_pattern_and_its_runs(setup):
-    cfg, model, variables, _ = setup
+def test_the_layer_pattern_and_its_runs(case):
+    cfg, variables = case.cfg, case.variables
     assert decoder.layer_runs(cfg.layer_kinds, cfg.layer_heads,
                               cfg.layer_mlps) == [
         (("kda", 2, "dense"), 1), (("kda", 2, "sparse"), 4),
@@ -180,21 +119,20 @@ def test_the_layer_pattern_and_its_runs(setup):
 
 # --- through the kernels ---------------------------------------------------------
 
-def test_model_through_the_kernels_equals_the_dense_path():
+def test_model_through_the_kernels_equals_the_dense_path(case):
     cfg = Config(**{**TINY, "pack_tokens": 256}).validate()
-    batch = make_batch(cfg, [[120, 70, 40], [200, 30]])
+    batch = cases.make_batch(cfg, [[120, 70, 40], [200, 30]])
     dense = decoder.build_decoder(cfg)
-    variables = moved(dense.init(jax.random.key(0),
-                                 decoder.sample_documents(cfg, 1), True))
+    variables = case.variables      # no leaf's shape or seed knows the row
     from vitax.ops.attention import make_attention_impl
     impl = make_attention_impl(cfg, None, force_tpu_kernels=True)
     through = decoder.build_decoder(cfg, attention_impl=impl)
-    text = jax.jit(lambda v: through.apply(v, batch, True)).lower(
-        variables).as_text(debug_info=True)
+    programs = [jax.jit(lambda v, m=m: m.apply(v, batch, True))
+                for m in (through, dense)]
+    text = programs[0].lower(variables).as_text(debug_info=True)
     assert "flash_latent_fwd" in text and "mla_latent/" in text
-    np.testing.assert_allclose(through.apply(variables, batch, True),
-                               dense.apply(variables, batch, True),
-                               rtol=2e-4, atol=2e-5)
+    got, want = (program(variables) for program in programs)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
 def test_the_model_through_the_convolutions_kernels_equals_the_plain_path():
@@ -205,26 +143,13 @@ def test_the_model_through_the_convolutions_kernels_equals_the_plain_path():
     plain path's."""
     from tests.test_ssd_kernel import gap
     from vitax.ops.conv import make_conv_impl
-    from vitax.train.step import decoder_loss
     cfg = Config(**{**TINY, "head_size": 128,
                     "qk_rope_size": 64}).validate()
     conv = make_conv_impl(cfg, None, force_tpu_kernels=True)
     assert conv.vitax_name == ("fused kernel (384 channels a grid step in "
                                "blocks of 32 tokens)")
-    models = [decoder.build_decoder(cfg), decoder.build_decoder(
-        cfg, conv_impl=conv)]
-    batch = make_batch(cfg)
-    variables = moved(jax.jit(lambda: models[0].init(
-        jax.random.key(0), decoder.sample_documents(cfg, 1), True))())
-    want, got = (jax.jit(jax.value_and_grad(lambda v, m=m: decoder_loss(
-        m.apply(v, batch, True), batch)))(variables) for m in models)
-    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
-    np.testing.assert_allclose(models[1].apply(variables, batch, True),
-                               models[0].apply(variables, batch, True),
-                               rtol=2e-4, atol=2e-5)
-    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want[1]),
-                            jax.tree.leaves(got[1])):
-        assert gap(b, a) < 2e-4, jax.tree_util.keystr(path)
+    cases.check_conv_kernels_match_the_plain_path(
+        cfg, conv, cases.make_batch(cfg, LENGTHS), gap)
 
 
 def test_remat_keeps_o_and_lse_of_the_latent_layer_only():
@@ -247,13 +172,9 @@ def test_remat_keeps_o_and_lse_of_the_latent_layer_only():
 
 # --- counts, counters, configuration ----------------------------------------------
 
-def test_closed_form_parameter_count_and_the_configurations():
-    cfg = Config(**TINY).validate()
-    model = decoder.build_decoder(cfg)
-    variables = model.init(jax.random.key(0),
-                           decoder.sample_documents(cfg, 1), True)
-    assert sum(a.size for a in jax.tree.leaves(variables)) \
-        == decoder.expected_param_count(cfg)
+def test_closed_form_parameter_count_and_the_configurations(case):
+    assert sum(a.size for a in jax.tree.leaves(case.variables)) \
+        == decoder.expected_param_count(case.cfg)
     # the configuration of the benchmark's cell, by shapes alone
     real = Config(**LING).validate()
     shapes = jax.eval_shape(
@@ -283,18 +204,11 @@ def test_train_step_counters_against_a_layout_counted_by_hand():
     row is one chunk), both chunks live. The slots routed here and the
     tokens that kept the held experts' group, over the six sparse layers."""
     from vitax.models.kda import tiling
-    from vitax.programs.builder import Geometry, build_program
     assert tiling(32, -5.0) == (32, 16)
     cfg = Config(**{**TINY, "warmup_steps": 1, "lr": 2e-3}).validate()
-    geom = Geometry.assemble(cfg, 100, materialize=True,
-                             devices=jax.devices()[:1])
-    state, geom.state = geom.state, None
-    step = build_program("train", geom)
-    batch = make_batch(cfg)
-    losses = []
-    for _ in range(4):
-        state, m = step(state, batch, jax.random.key(1))
-        losses.append(float(m["loss"]))
+    _, state, step = cases.assembled(cfg)
+    _, m, losses = cases.take_steps(
+        step, state, cases.make_batch(cfg, LENGTHS), 4)
     got = {k: float(m[k]) for k in (
         "tokens", "padding_tokens", "images", "targets", "causal_pairs",
         "kda_pairs", "kda_live_chunks")}
@@ -337,10 +251,10 @@ def test_the_delta_rules_counters_do_not_follow_the_programs_chunk(
     from vitax.train.step import decoder_counts
     cfg = Config(**{**TINY, "pack_tokens": 256, "batch_size": 1}).validate()
     lengths = [[150, 56, 28, 6]]
-    batch = make_batch(cfg, lengths)
+    batch = cases.make_batch(cfg, lengths)
 
     def counted():
-        got = decoder_counts(cfg, batch)
+        got = jax.jit(lambda b: decoder_counts(cfg, b))(batch)
         return int(got["kda_pairs"]), int(got["kda_live_chunks"])
 
     want = flops_ling.layout_counts(lengths, 256)
@@ -388,43 +302,23 @@ def test_config_refuses_what_is_not_built(change, message):
 
 
 def test_the_family_declares_the_new_shape_fields():
-    import os
-    from benchmark import forms
-    from benchmark import manifest as mf
-    ling = forms.declared_keys(mf.read_json(
-        os.path.join(mf.BENCH_DIR, "shapes", "ling.json")))
     assert {"kda_conv_width", "kda_gate_bound", "latent_rank",
             "qk_nope_size", "qk_rope_size", "v_head_size", "route_groups",
-            "groups_per_token", "route_bias"} <= ling
-    assert not ling & forms.knob_keys(forms.rules())
+            "groups_per_token", "route_bias"} <= cases.family_declares("ling")
 
 
-def test_the_float8_control_is_told_from_the_program(setup, plain):
+def test_the_float8_control_is_told_from_the_program(case):
     """The benchmark's control (weights rounded to float8_e4m3 for the
     program, the reference on the seeded ones) is off the reference by tens
     of times what the program is, gradient by gradient."""
-    from benchmark.generators.train_latent_packed import (round_to_float8,
-                                                          watched_leaves)
-    from vitax.train.step import decoder_loss
-    cfg, model, variables, batch = setup
-
-    @jax.jit
-    def grads_of(v):
-        return watched_leaves(jax.grad(lambda v: decoder_loss(
-            model.apply(v, batch, True), batch))(v), cfg)
-
-    want = watched_leaves(plain[1], cfg)
-    assert sorted(want) == [
+    from benchmark.generators import train_latent_packed
+    want = case.check_float8_control(train_latent_packed, [
         "kda.A_log", "kda.conv", "kda.dt_bias", "kda.wb", "kda.wf",
         "latent.wkva", "latent.wkvb", "latent.wq", "sparse.experts_gate",
-        "sparse.router"]
+        "sparse.router"])
     # the kda leaves of the six kda layers together
     assert want["kda.A_log"].shape == (6 * 2,)
     assert want["kda.wf"].shape == (6 * 32 * 16,)
-    sound, control = grads_of(variables), grads_of(round_to_float8(variables))
-    for name in want:
-        assert reference.relative_gap(sound[name], want[name]) < 2e-3, name
-        assert reference.relative_gap(control[name], want[name]) > 2e-2, name
 
 
 @pytest.mark.parametrize("clip", [0.05, 100.0])
@@ -432,28 +326,14 @@ def test_the_first_steps_moments_hand_back_its_gradients(clip):
     """What the benchmark holds the TIMED step to: the gradients read from
     the optimizer state its first call left (`step_gradients`) are the
     model's own, with the clip at work and without."""
-    from benchmark.generators.train_latent_packed import (step_gradients,
-                                                          watched_leaves)
-    from vitax.programs.builder import Geometry, build_program
-    from vitax.train.step import decoder_inputs, decoder_loss
+    from benchmark.generators import train_latent_packed
     cfg = Config(**{**TINY, "clip_grad_norm": clip, "num_blocks": 2,
                     "layer_kinds": ["kda", "latent_attention"],
                     "layer_heads": [2, 2],
                     "layer_mlps": ["sparse", "sparse"]}).validate()
-    geom = Geometry.assemble(cfg, 100, materialize=True,
-                             devices=jax.devices()[:1])
-    state, geom.state = geom.state, None
-    batch = make_batch(cfg)
-    want = watched_leaves(jax.jit(jax.grad(
-        lambda v: decoder_loss(geom.model.apply(
-            v, decoder_inputs(batch), True), batch)))(state.params), cfg)
-    state, m = build_program("train", geom)(state, batch, jax.random.key(1))
-    norm = float(m["grad_norm"])
-    assert (norm > clip) == (clip == 0.05)
-    got = step_gradients(state.opt_state, norm, cfg)
-    assert sorted(got) == sorted(want)
-    for name in want:
-        assert reference.relative_gap(got[name], want[name]) < 1e-5, name
+    cases.check_first_steps_moments(
+        train_latent_packed, cfg, cases.make_batch(cfg, LENGTHS),
+        clipped=clip == 0.05)
 
 
 def test_training_through_the_cli_path(tmp_path, capsys):
@@ -462,9 +342,8 @@ def test_training_through_the_cli_path(tmp_path, capsys):
     through `parse_config`, then the loop the entry point calls): a falling
     loss and the new counters on the step records; no flag selects a form of
     the new layers."""
-    from vitax.train.loop import train
-    cfg = parse_config((
-        "--fake_data", "--model_family", "decoder", "--pack_tokens", "64",
+    cfg, steps = cases.train_through_the_cli(
+        tmp_path, "--pack_tokens", "64",
         "--pack_images", "6", "--embed_dim", "32", "--num_blocks", "4",
         "--vocab_rows", "48", "--kv_heads", "2", "--head_size", "8",
         "--layer_kinds", "kda,kda,latent_attention,kda",
@@ -476,24 +355,12 @@ def test_training_through_the_cli_path(tmp_path, capsys):
         "--rope_theta_full", "6000000", "--rope_fraction_full", "0.5",
         "--kda_conv_width", "4", "--kda_gate_bound", "-5", "--latent_rank",
         "12", "--qk_nope_size", "8", "--qk_rope_size", "4", "--v_head_size",
-        "8", "--route_groups", "4", "--groups_per_token", "2", "--route_bias",
-        "--batch_size", "8", "--num_epochs", "1", "--steps_per_epoch", "3",
-        "--lr", "3e-3", "--log_step_interval", "1", "--warmup_steps", "1",
-        "--ckpt_dir", str(tmp_path / "ckpt"),
-        "--metrics_dir", str(tmp_path / "metrics")))
+        "8", "--route_groups", "4", "--groups_per_token", "2", "--route_bias")
     assert cfg.route_bias and cfg.kda_gate_bound == -5.0
-    train(cfg)
     assert "in kda layers" not in capsys.readouterr().out
-    with open(tmp_path / "metrics" / "metrics.jsonl") as f:
-        steps = [r for r in map(json.loads, f) if "kind" not in r]
-    losses = [r["loss"] for r in steps]
-    assert len(losses) == 3 and np.isfinite(losses).all()
-    assert losses[-1] < losses[0]
     for r in steps:
-        assert 0.0 <= r["padding_frac"] < 1.0
         assert 0 < r["kda_pairs"] <= r["causal_pairs"]
         assert 0 < r["kda_live_chunks"] <= 8 * 64 // 64
         assert 0 < r["expert_slots_here"] <= 3 * 8 * 64 * 4
         assert 0 < r["tokens_choosing_held_group"] <= 3 * 8 * 64
         assert len(r["expert_load"]) == 3 and "ssd_pairs" not in r
-    assert (tmp_path / "ckpt" / "epoch_1").exists()

@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 from benchmark.reference import ling as reference
-from tests.test_latent_decoder import LENGTHS, moved
+from tests.decoder_cases import moved
+from tests.test_latent_decoder import LENGTHS
 from vitax.data.packing import document_layout
 from vitax.models import decoder
 from vitax.models.experts import SharedRoutedExperts, choose
@@ -27,13 +28,14 @@ def test_latent_attention_equals_explicit_per_head_keys():
     shape = decoder.LatentShape(rank=12, nope=8, rope=4, value=8)
     layer = decoder.LatentAttention(heads=3, shape=shape, head_gate=True,
                                     norm_eps=1e-6, dtype=jnp.float32)
-    seg = jnp.asarray(document_layout(LENGTHS, 32, 4)["segment_ids"])
     lay = document_layout(LENGTHS, 32, 4)
+    seg = jnp.asarray(lay["segment_ids"])
     u = jax.random.normal(jax.random.key(1), (2, 32, 24))
     w = jax.random.normal(jax.random.key(2), u.shape)
     positions = jnp.asarray(lay["positions"])
     rope = decoder.rope_tables(positions, decoder.rope_inv_freq(4, 6e6))
-    p = moved(layer.init(jax.random.key(0), u, seg, rope)["params"], by=0.1)
+    p = moved(jax.jit(layer.init)(jax.random.key(0), u, seg, rope)["params"],
+              by=0.1)
 
     def program(p):
         return jnp.sum(layer.apply({"params": p}, u, seg, rope) * w)
@@ -46,15 +48,15 @@ def test_latent_attention_equals_explicit_per_head_keys():
     def plain(p):
         total = 0.0
         for r in range(2):
-            for s in range(1, int(seg[r].max()) + 1):
-                at = np.where(np.asarray(seg[r]) == s)[0]
+            for s in range(1, lay["segment_ids"][r].max() + 1):
+                at = np.where(lay["segment_ids"][r] == s)[0]
                 fill = ((0, 32 - len(at)), (0, 0))
                 total += alone(p, jnp.pad(u[r, at], fill),
                                jnp.pad(w[r, at], fill))
         return total
 
     with jax.default_matmul_precision("highest"):
-        want, want_grads = jax.value_and_grad(plain)(p)
+        want, want_grads = jax.jit(jax.value_and_grad(plain))(p)
     got, got_grads = jax.jit(jax.value_and_grad(program))(p)
     np.testing.assert_allclose(got, want, rtol=1e-5)
     flat = jax.tree_util.tree_leaves_with_path(got_grads)
@@ -87,12 +89,14 @@ def test_latent_kernels_match_a_dense_mask(heads, dqk, dv, skip):
     def dense(q, k, v):
         return decoder.causal_masked_attention(q, k, v, seg, 0, jnp.float32)
 
-    out = kernel(q, k, v)
+    out = jax.jit(kernel)(q, k, v)
     assert out.shape == v.shape
-    np.testing.assert_allclose(out, dense(q, k, v), rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(out, jax.jit(dense)(q, k, v), rtol=1e-4,
+                               atol=1e-5)
     assert float(jnp.max(jnp.abs(out * (seg == 0)[..., None, None]))) == 0.0
-    got = jax.grad(lambda *a: jnp.sum(kernel(*a) * w), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2))(q, k, v)
+    got, want = (jax.jit(jax.grad(lambda *a, f=f: jnp.sum(f(*a) * w),
+                                  (0, 1, 2)))(q, k, v)
+                 for f in (kernel, dense))
     for a, b in zip(got, want):
         assert a.shape == b.shape
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=5e-5)
@@ -138,7 +142,8 @@ def test_the_choice_against_a_written_out_loop(groups, kept, k, biased):
     scores = jax.nn.sigmoid(jax.random.normal(jax.random.key(k), (40, e)))
     bias = 0.3 * jax.random.normal(jax.random.key(7), (e,)) if biased \
         else None
-    top, chosen, kept_groups = choose(scores, bias, k, groups, kept)
+    top, chosen, kept_groups = jax.jit(choose, static_argnums=(2, 3, 4))(
+        scores, bias, k, groups, kept)
     want = choice_by_a_loop(np.asarray(scores), None if bias is None
                             else np.asarray(bias), k, groups or 1,
                             kept or 1)
@@ -156,8 +161,9 @@ def test_the_choice_against_a_written_out_loop(groups, kept, k, biased):
     else:
         assert kept_groups is None
     # the reference's own selection agrees
-    ref = reference.chosen_experts(scores, bias, top_k=k, groups=groups or 1,
-                                   groups_kept=kept or 1)
+    ref = jax.jit(lambda s, b: reference.chosen_experts(
+        s, b, top_k=k, groups=groups or 1, groups_kept=kept or 1))(
+            scores, bias)
     np.testing.assert_array_equal(np.sort(np.asarray(ref), axis=-1), want)
 
 
@@ -172,15 +178,17 @@ def test_a_bias_changes_the_choice_and_not_the_weights():
                                 groups_per_token=2, route_bias=True)
     x = jax.random.normal(jax.random.key(1), (1, 24, d))
     valid = jnp.ones((1, 24), bool)
-    p = moved(layer.init(jax.random.key(3), x, valid)["params"], key=4,
-              by=0.2)
+    p = moved(jax.jit(layer.init)(jax.random.key(3), x, valid)["params"],
+              key=4, by=0.2)
     zero = dict(p, router_bias={"bias": jnp.zeros((routed,))})
     pushed = dict(p, router_bias={"bias": jnp.zeros((routed,)).at[
         jnp.asarray([3, 7, 11, 15])].set(5.0)})
 
+    @jax.jit
     def layer_out(p):
         return layer.apply({"params": p}, x, valid)
 
+    @jax.jit
     def plain(p):
         with jax.default_matmul_precision("highest"):
             return reference.routed_and_shared(
@@ -193,13 +201,14 @@ def test_a_bias_changes_the_choice_and_not_the_weights():
                                    rtol=1e-4, atol=1e-5)
     assert float(jnp.max(jnp.abs(layer_out(zero) - layer_out(pushed)))) > 1e-2
     scores = jax.nn.sigmoid(x.reshape(-1, d) @ p["router"]["kernel"])
-    top0, chosen0, _ = choose(scores, zero["router_bias"]["bias"], k, 4, 2)
-    top1, chosen1, _ = choose(scores, pushed["router_bias"]["bias"], k, 4, 2)
+    (top0, chosen0, _), (top1, chosen1, _) = (
+        jax.jit(choose, static_argnums=(2, 3, 4))(
+            scores, q["router_bias"]["bias"], k, 4, 2) for q in (zero, pushed))
     assert (np.sort(chosen0, -1) != np.sort(chosen1, -1)).any()
     # two of the four favoured experts lie in each pair of kept groups
     assert (np.isin(chosen1, [3, 7, 11, 15]).sum(-1) == 2).all()
     assert float(jnp.max(top1)) < 1.0           # scores, not scores + 5
-    grads = jax.grad(lambda p: jnp.sum(layer_out(p) ** 2))(pushed)
+    grads = jax.jit(jax.grad(lambda p: jnp.sum(layer_out(p) ** 2)))(pushed)
     assert float(jnp.max(jnp.abs(grads["router_bias"]["bias"]))) == 0.0
     assert float(jnp.max(jnp.abs(grads["router"]["kernel"]))) > 0.0
 
@@ -212,13 +221,15 @@ def test_zero_groups_and_no_bias_is_the_plain_layer():
     x = jax.random.normal(jax.random.key(1), (1, 12, d))
     valid = jnp.ones((1, 12), bool)
     plain = SharedRoutedExperts(8, 2, 0, 2, 16, 16, 1.0, jnp.float32)
-    p = plain.init(jax.random.key(3), x, valid)
+    p = jax.jit(plain.init)(jax.random.key(3), x, valid)
     assert "router_bias" not in p["params"]
-    _, cols = plain.apply(p, x, valid, mutable=["intermediates"])
+    _, cols = jax.jit(lambda p: plain.apply(
+        p, x, valid, mutable=["intermediates"]))(p)
     assert sorted(cols["intermediates"]) == ["expert_load"]
     grouped = SharedRoutedExperts(8, 2, 0, 2, 16, 16, 1.0, jnp.float32,
                                   route_groups=2, groups_per_token=1)
-    _, cols = grouped.apply(p, x, valid, mutable=["intermediates"])
+    _, cols = jax.jit(lambda p: grouped.apply(
+        p, x, valid, mutable=["intermediates"]))(p)
     assert sorted(cols["intermediates"]) == ["expert_load",
                                              "tokens_choosing_held_group"]
     assert 0 <= int(cols["intermediates"]["tokens_choosing_held_group"][0]) \
@@ -246,23 +257,36 @@ def test_the_expert_and_head_shares_add_up_to_the_uncut_layers():
     whole = SharedRoutedExperts(routed, routed, 0, k, 16, 16, 2.5,
                                 jnp.float32, route_groups=8,
                                 groups_per_token=4, route_bias=True)
-    p = moved(whole.init(jax.random.key(3), x, valid)["params"], key=4,
-              by=0.2)
-    with jax.default_matmul_precision("highest"):
-        want = reference.routed_and_shared(
-            x.reshape(-1, d), p, top_k=k, groups=8, groups_kept=4, scale=2.5,
-            bias=True, experts_routed=routed, experts_held=None)
-        shared = reference.swiglu(x.reshape(-1, d), p["shared"])
-    total, slots, kept = shared, 0, []
-    for first in range(0, routed, held):
+    p = moved(jax.jit(whole.init)(jax.random.key(3), x, valid)["params"],
+              key=4, by=0.2)
+
+    @jax.jit
+    def plain(p):
+        with jax.default_matmul_precision("highest"):
+            return reference.routed_and_shared(
+                x.reshape(-1, d), p, top_k=k, groups=8, groups_kept=4,
+                scale=2.5, bias=True, experts_routed=routed,
+                experts_held=None), \
+                reference.swiglu(x.reshape(-1, d), p["shared"])
+
+    @jax.jit
+    def share_of(p, first):
+        # `first` traced, so that ONE program serves the 64 shares: the layer
+        # only subtracts it from and divides it into what it chose
         share = SharedRoutedExperts(routed, held, first, k, 16, 16, 2.5,
                                     jnp.float32, route_groups=8,
                                     groups_per_token=4, route_bias=True)
         cut = dict(p, **{
-            name: {"kernel": p[name]["kernel"][first:first + held]}
+            name: {"kernel": jax.lax.dynamic_slice_in_dim(
+                p[name]["kernel"], first, held)}
             for name in ("experts_gate", "experts_up", "experts_down")})
-        out, cols = share.apply({"params": cut}, x, valid,
-                                mutable=["intermediates"])
+        return share.apply({"params": cut}, x, valid,
+                           mutable=["intermediates"])
+
+    want, shared = plain(p)
+    total, slots, kept = shared, 0, []
+    for first in range(0, routed, held):
+        out, cols = share_of(p, first)
         total = total + (out.reshape(-1, d) - shared)
         slots += int(jnp.sum(cols["intermediates"]["expert_load"][0]))
         kept.append(int(
@@ -278,11 +302,12 @@ def test_the_expert_and_head_shares_add_up_to_the_uncut_layers():
     # the kda mixer: two shares of 2 of 4 heads
     heads, dh = 4, 8
     mixer = KDAMixer(KDAShape(heads, dh, 4, -5.0), 1e-6, jnp.float32)
-    p = moved(mixer.init(jax.random.key(5), x, seg)["params"], key=6, by=0.1)
+    p = moved(jax.jit(mixer.init)(jax.random.key(5), x, seg)["params"],
+              key=6, by=0.1)
     with jax.default_matmul_precision("highest"):
-        want = jnp.concatenate([reference.kda_mixer(
+        want = jax.jit(lambda p: jnp.concatenate([reference.kda_mixer(
             x[0, a], p, 1e-6, head_dim=dh, taps=4, gate_bound=-5.0)
-            for a in at])
+            for a in at]))(p)
     inner = heads * dh
     total = 0.0
     for first in (0, 2):
@@ -302,7 +327,7 @@ def test_the_expert_and_head_shares_add_up_to_the_uncut_layers():
             "out_norm": p["out_norm"],
             "wo": {"kernel": p["wo"]["kernel"][cols]}}
         share = KDAMixer(KDAShape(2, dh, 4, -5.0), 1e-6, jnp.float32)
-        total = total + share.apply({"params": cut}, x, seg)[0, :21]
+        total = total + jax.jit(share.apply)({"params": cut}, x, seg)[0, :21]
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
 
     # the latent layer: two shares of 2 of 4 heads, the latent counted once
@@ -312,12 +337,12 @@ def test_the_expert_and_head_shares_add_up_to_the_uncut_layers():
                                decoder.rope_inv_freq(4, 6e6))
     layer = decoder.LatentAttention(heads=4, shape=shape, head_gate=True,
                                     norm_eps=1e-6, dtype=jnp.float32)
-    p = moved(layer.init(jax.random.key(7), x, seg, rope)["params"], key=8,
-              by=0.1)
+    p = moved(jax.jit(layer.init)(jax.random.key(7), x, seg, rope)["params"],
+              key=8, by=0.1)
     with jax.default_matmul_precision("highest"):
-        want = jnp.concatenate([reference.latent_mixer(
+        want = jax.jit(lambda p: jnp.concatenate([reference.latent_mixer(
             x[0, a], p, 1e-6, rank=12, nope=8, rope=4, value=8, theta=6e6)
-            for a in at])
+            for a in at]))(p)
     total = 0.0
     for first in (0, 2):
         hs = slice(first, first + 2)
@@ -333,5 +358,6 @@ def test_the_expert_and_head_shares_add_up_to_the_uncut_layers():
         share = decoder.LatentAttention(
             heads=2, shape=shape, head_gate=True, norm_eps=1e-6,
             dtype=jnp.float32)
-        total = total + share.apply({"params": cut}, x, seg, rope)[0, :21]
+        total = total + jax.jit(share.apply)({"params": cut}, x, seg,
+                                             rope)[0, :21]
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)

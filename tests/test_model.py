@@ -23,7 +23,8 @@ def tiny_cfg(**kw):
 def init_params(cfg, rng=0):
     model = build_model(cfg)
     x = jnp.zeros((2, cfg.image_size, cfg.image_size, 3), jnp.float32)
-    return model, model.init(jax.random.key(rng), x, True)
+    return model, jax.jit(model.init, static_argnums=2)(
+        jax.random.key(rng), x, True)
 
 
 def test_param_count_closed_form_10b():
@@ -54,7 +55,7 @@ def test_forward_shape_and_dtype():
     cfg = tiny_cfg()
     model, params = init_params(cfg)
     x = jnp.ones((4, 32, 32, 3), jnp.float32)
-    logits = model.apply(params, x, True)
+    logits = jax.jit(model.apply, static_argnums=2)(params, x, True)
     assert logits.shape == (4, 10)
     assert logits.dtype == jnp.float32
 
@@ -74,8 +75,9 @@ def test_scan_and_unrolled_blocks_agree():
         loop_params[f"blocks_{i}"] = jax.tree.map(lambda a: a[i], stacked)
 
     x = jax.random.normal(jax.random.key(1), (2, 32, 32, 3), jnp.float32)
-    out_s = model_s.apply(params_s, x, True)
-    out_l = model_l.apply({"params": loop_params}, x, True)
+    out_s = jax.jit(model_s.apply, static_argnums=2)(params_s, x, True)
+    out_l = jax.jit(model_l.apply, static_argnums=2)(
+        {"params": loop_params}, x, True)
     np.testing.assert_allclose(np.asarray(out_s), np.asarray(out_l), rtol=1e-5, atol=1e-5)
 
 
@@ -90,14 +92,15 @@ def test_scan_unroll_matches_unroll1():
     def loss(model):
         return lambda p: jnp.sum(model.apply(p, x, True) ** 2)
 
-    l1, g1 = jax.value_and_grad(loss(model1))(params)
+    l1, g1 = jax.jit(jax.value_and_grad(loss(model1)))(params)
     for unroll in (3, 64):  # non-divisor of num_blocks; > num_blocks clamps
         cfgu = tiny_cfg(grad_ckpt=True, num_blocks=5, scan_unroll=unroll)
         modelu = build_model(cfgu)
-        assert jax.tree.structure(
-            modelu.init(jax.random.key(0), x[:1], True)) == jax.tree.structure(
-            params), "scan_unroll must keep the stacked param tree"
-        lu, gu = jax.value_and_grad(loss(modelu))(params)
+        assert jax.tree.structure(jax.eval_shape(
+            lambda: modelu.init(jax.random.key(0), x[:1], True))) \
+            == jax.tree.structure(params), \
+            "scan_unroll must keep the stacked param tree"
+        lu, gu = jax.jit(jax.value_and_grad(loss(modelu)))(params)
         np.testing.assert_allclose(float(l1), float(lu), rtol=1e-5)
         for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(gu)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -117,8 +120,8 @@ def test_remat_matches_no_remat():
             return jnp.sum(model.apply(p, x, True) ** 2)
         return f
 
-    la, ga = jax.value_and_grad(loss_fn(model_a))(params)
-    lb, gb = jax.value_and_grad(loss_fn(model_b))(params)
+    la, ga = jax.jit(jax.value_and_grad(loss_fn(model_a)))(params)
+    lb, gb = jax.jit(jax.value_and_grad(loss_fn(model_b)))(params)
     np.testing.assert_allclose(float(la), float(lb), rtol=1e-5)
     for a, b in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
@@ -151,12 +154,14 @@ def test_dropout_active_in_train_mode():
     cfg = tiny_cfg(pos_dropout=0.5, mlp_dropout=0.5)
     model, params = init_params(cfg)
     x = jnp.ones((2, 32, 32, 3), jnp.float32)
-    out1 = model.apply(params, x, False, rngs={"dropout": jax.random.key(1)})
-    out2 = model.apply(params, x, False, rngs={"dropout": jax.random.key(2)})
+    train = jax.jit(lambda p, key: model.apply(p, x, False,
+                                               rngs={"dropout": key}))
+    out1 = train(params, jax.random.key(1))
+    out2 = train(params, jax.random.key(2))
     assert not np.allclose(np.asarray(out1), np.asarray(out2))
     # deterministic mode is rng-independent
-    out3 = model.apply(params, x, True)
-    out4 = model.apply(params, x, True)
+    out3 = jax.jit(model.apply, static_argnums=2)(params, x, True)
+    out4 = jax.jit(model.apply, static_argnums=2)(params, x, True)
     np.testing.assert_array_equal(np.asarray(out3), np.asarray(out4))
 
 
@@ -190,13 +195,14 @@ def test_windowed_remat_matches_scan_path(devices8):
     params = jax.jit(lambda k: model.init(k, x[:1], True))(jax.random.key(0))
     fwd_w = make_windowed_forward(cfg_w, model)
 
-    ref = model.apply(params, x, True)
+    ref = jax.jit(model.apply, static_argnums=2)(params, x, True)
     got = jax.jit(fwd_w)(params, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
-    g_ref = jax.grad(lambda p: jnp.sum(model.apply(p, x, True) ** 2))(params)
-    g_w = jax.grad(lambda p: jnp.sum(fwd_w(p, x) ** 2))(params)
+    g_ref = jax.jit(jax.grad(
+        lambda p: jnp.sum(model.apply(p, x, True) ** 2)))(params)
+    g_w = jax.jit(jax.grad(lambda p: jnp.sum(fwd_w(p, x) ** 2)))(params)
     for (ka, a), (_, b) in zip(
             jax.tree_util.tree_flatten_with_path(g_ref)[0],
             jax.tree_util.tree_flatten_with_path(g_w)[0]):
@@ -251,9 +257,13 @@ def test_windowed_remat_v2_moe_and_dropout(devices8, variant):
         np.testing.assert_allclose(losses_w, losses_ref, rtol=2e-4)
     else:
         drop = dict(att_dropout=0.2, mlp_dropout=0.1, pos_dropout=0.1)
+        from tests.test_train_smoke import build_train_objects, fresh
         cfg_w = Config(remat_window=2, **kw, **drop).validate()
-        _, l1 = run_steps(cfg_w, n_steps=3)
-        _, l2 = run_steps(cfg_w, n_steps=3)
+        mesh, state, step_fn, eval_fn = build_train_objects(cfg_w)
+        _, l1 = run_steps(cfg_w, n_steps=3,
+                          built=(mesh, fresh(state), step_fn, eval_fn))
+        _, l2 = run_steps(cfg_w, n_steps=3,
+                          built=(mesh, state, step_fn, eval_fn))
         assert all(np.isfinite(l1))
         np.testing.assert_array_equal(l1, l2)  # deterministic given seed
         _, l0 = run_steps(Config(remat_window=2, **kw).validate(), n_steps=3)
@@ -271,7 +281,7 @@ def kernel_model(**kw):
     model = build_model(cfg, attention_impl=impl)
     x = jax.random.normal(jax.random.key(1),
                           (2, cfg.image_size, cfg.image_size, 3), jnp.float32)
-    params = model.init(jax.random.key(0), x, True)
+    params = jax.jit(model.init, static_argnums=2)(jax.random.key(0), x, True)
     return model, params, x, impl.vitax_name
 
 

@@ -33,7 +33,7 @@ def test_single_expert_equals_dense_mlp():
     moe = MoeMlp(num_experts=1, hidden_dim=h, out_dim=d,
                  capacity_factor=1.0, dtype=jnp.float32)
     x = jax.random.normal(jax.random.key(0), (2, n, d), jnp.float32)
-    params = moe.init(jax.random.key(1), x)
+    params = jax.jit(moe.init)(jax.random.key(1), x)
     dense = Mlp(hidden_dim=h, out_dim=d, dtype=jnp.float32)
     dense_params = {"params": {
         "fc1": {"kernel": params["params"]["w1"][0],
@@ -41,8 +41,8 @@ def test_single_expert_equals_dense_mlp():
         "fc2": {"kernel": params["params"]["w2"][0],
                 "bias": params["params"]["b2"][0]},
     }}
-    got = moe.apply(params, x)
-    want = dense.apply(dense_params, x)
+    got = jax.jit(moe.apply)(params, x)
+    want = jax.jit(dense.apply)(dense_params, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
@@ -55,11 +55,11 @@ def test_routing_and_capacity_drop():
     moe = MoeMlp(num_experts=e, hidden_dim=8, out_dim=d,
                  capacity_factor=0.5, dtype=jnp.float32)  # C = ceil(.5*4/2)=1
     x = jax.random.normal(jax.random.key(2), (1, n, d), jnp.float32)
-    params = moe.init(jax.random.key(3), x)
+    params = jax.jit(moe.init)(jax.random.key(3), x)
     # force ALL tokens to expert 0: bias the router hard
     params["params"]["router"]["bias"] = jnp.array([10.0, -10.0])
     params["params"]["router"]["kernel"] = jnp.zeros((d, e))
-    out = moe.apply(params, x)
+    out = jax.jit(moe.apply)(params, x)
     # capacity 1: only the FIRST token gets expert compute; rest are dropped
     assert not np.allclose(np.asarray(out[0, 0]), 0.0)
     np.testing.assert_allclose(np.asarray(out[0, 1:]), 0.0, atol=1e-7)
@@ -73,10 +73,11 @@ def test_aux_loss_uniform_router_is_one():
     d, e = 8, 4
     moe = MoeMlp(num_experts=e, hidden_dim=8, out_dim=d, dtype=jnp.float32)
     x = jax.random.normal(jax.random.key(4), (2, 8, d), jnp.float32)
-    params = moe.init(jax.random.key(5), x)
+    params = jax.jit(moe.init)(jax.random.key(5), x)
     params["params"]["router"]["kernel"] = jnp.zeros((d, e))
     params["params"]["router"]["bias"] = jnp.zeros((e,))
-    _, cols = moe.apply(params, x, mutable=["intermediates"])
+    _, cols = jax.jit(lambda p, x: moe.apply(
+        p, x, mutable=["intermediates"]))(params, x)
     moe_cols = cols["intermediates"]["moe_frac_tokens"], \
         cols["intermediates"]["moe_mean_prob"]
     (frac,), (prob,) = moe_cols
@@ -92,11 +93,11 @@ def test_top2_routing_hand_case():
     moe = MoeMlp(num_experts=e, hidden_dim=4, out_dim=d, top_k=2,
                  capacity_factor=float(n), dtype=jnp.float32)  # C = n: no drops
     x = jax.random.normal(jax.random.key(6), (1, n, d), jnp.float32)
-    params = moe.init(jax.random.key(7), x)
+    params = jax.jit(moe.init)(jax.random.key(7), x)
     # router: token probs fixed at [0.75, 0.25] for every token
     params["params"]["router"]["kernel"] = jnp.zeros((d, e))
     params["params"]["router"]["bias"] = jnp.log(jnp.array([3.0, 1.0]))
-    out = moe.apply(params, x)
+    out = jax.jit(moe.apply)(params, x)
 
     # expected: renormalized gates 0.75/0.25; expert e applies its own MLP
     def expert(i, v):
@@ -124,12 +125,12 @@ def test_top2_second_choice_capacity_queue():
     # token 0 = +e1 basis, token 1 = -e1: router kernel [s, -s] makes token
     # 0's probs [.75, .25] (first choice expert 0) and token 1's [.25, .75]
     x = jnp.zeros((1, n, d)).at[0, 0, 0].set(1.0).at[0, 1, 0].set(-1.0)
-    params = moe.init(jax.random.key(9), x)
+    params = jax.jit(moe.init)(jax.random.key(9), x)
     s = float(np.log(3.0) / 2.0)
     params["params"]["router"]["kernel"] = jnp.zeros((d, e)).at[0, 0].set(
         s).at[0, 1].set(-s)
     params["params"]["router"]["bias"] = jnp.zeros((e,))
-    out = moe.apply(params, x)
+    out = jax.jit(moe.apply)(params, x)
 
     def expert(i, v):
         p = params["params"]
@@ -159,10 +160,10 @@ def test_gather_matches_einsum_oracle(top_k):
     moe_g = MoeMlp(impl="gather", **kw)
     moe_e = MoeMlp(impl="einsum", **kw)
     x = jax.random.normal(jax.random.key(11), (b, n, d), jnp.float32)
-    params = moe_g.init(jax.random.key(12), x)
+    params = jax.jit(moe_g.init)(jax.random.key(12), x)
 
-    out_g = moe_g.apply(params, x)
-    out_e = moe_e.apply(params, x)
+    out_g = jax.jit(moe_g.apply)(params, x)
+    out_e = jax.jit(moe_e.apply)(params, x)
     assert not np.allclose(np.asarray(out_g), 0.0)  # non-degenerate case
     np.testing.assert_allclose(np.asarray(out_g), np.asarray(out_e),
                                rtol=1e-6, atol=1e-6)
@@ -170,8 +171,9 @@ def test_gather_matches_einsum_oracle(top_k):
     def loss(m, p, xx):
         return jnp.sum(jnp.sin(m.apply(p, xx)))
 
-    gp_g, gx_g = jax.grad(lambda p, xx: loss(moe_g, p, xx), (0, 1))(params, x)
-    gp_e, gx_e = jax.grad(lambda p, xx: loss(moe_e, p, xx), (0, 1))(params, x)
+    (gp_g, gx_g), (gp_e, gx_e) = (
+        jax.jit(jax.grad(lambda p, xx, m=m: loss(m, p, xx), (0, 1)))(params, x)
+        for m in (moe_g, moe_e))
     jax.tree.map(
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6), gp_g, gp_e)
@@ -334,7 +336,8 @@ def test_moe_eval_step(devices8):
     }
     correct = int(jax.device_get(eval_step(state, batch)["correct"]))
 
-    logits = model.apply(state.params, batch["image"], True)
+    logits = jax.jit(model.apply, static_argnums=2)(
+        state.params, batch["image"], True)
     want = int(jnp.sum(jnp.argmax(logits, -1) == batch["label"]))
     assert correct == want, (correct, want)
     assert 0 <= correct <= cfg.batch_size

@@ -68,23 +68,26 @@ def make_batch(cfg, rows, seed=1):
 
 
 def init_params(cfg, model, seed=0):
-    params = model.init(jax.random.key(seed), sample_input(cfg, 2), True)
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
-    params = jax.tree.unflatten(tree, [
-        leaf + 0.05 * jax.random.normal(k, leaf.shape, leaf.dtype)
-        for leaf, k in zip(leaves, keys)])
-    # peaked attention: with near-uniform softmax rows neither the RoPE nor
-    # the mask between images would move a logit by much
-    qkv = params["params"]["blocks"]["attn"]["qkv"]
-    qkv["kernel"] = qkv["kernel"] * 6.0
-    return params
+    @jax.jit
+    def seeded():
+        params = model.init(jax.random.key(seed), sample_input(cfg, 2), True)
+        leaves, tree = jax.tree.flatten(params)
+        keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
+        params = jax.tree.unflatten(tree, [
+            leaf + 0.05 * jax.random.normal(k, leaf.shape, leaf.dtype)
+            for leaf, k in zip(leaves, keys)])
+        # peaked attention: with near-uniform softmax rows neither the RoPE
+        # nor the mask between images would move a logit by much
+        qkv = params["params"]["blocks"]["attn"]["qkv"]
+        qkv["kernel"] = qkv["kernel"] * 6.0
+        return params
+    return seeded()
 
 
 def program_logits(model, params, batch):
     """(images, classes): the program's per-image logits in packing order."""
-    out = model.apply(params, packed_inputs(
-        {k: jnp.asarray(v) for k, v in batch.items()}), True)
+    out = jax.jit(lambda p, b: model.apply(p, packed_inputs(b), True))(
+        params, {k: jnp.asarray(v) for k, v in batch.items()})
     return np.asarray(out)[np.asarray(batch["label_mask"]) > 0]
 
 
@@ -103,19 +106,26 @@ def setup():
     return cfg, model, params, batch, images, labels
 
 
-def test_logits_match_the_reference(setup):
+@pytest.fixture(scope="module")
+def want(setup):
+    """The reference's logits, once for the cases held to them."""
+    _, _, params, _, images, _ = setup
+    return jax.jit(lambda p: reference.logits(p, images, **SHAPE))(params)
+
+
+def test_logits_match_the_reference(setup, want):
     cfg, model, params, batch, images, _ = setup
-    want = reference.logits(params, images, **SHAPE)
     assert rel_gap(program_logits(model, params, batch), want) < F32_RTOL
 
 
 def test_loss_and_every_gradient_leaf_match_the_reference(setup):
     cfg, model, params, batch, images, labels = setup
     jb = {k: jnp.asarray(v) for k, v in batch.items()}
-    loss, grads = jax.value_and_grad(lambda p: packed_loss(
-        model.apply(p, packed_inputs(jb), True), jb))(params)
-    ref_loss, (ref_rest, ref_layers) = reference.value_and_grads(
-        params, images, labels, **SHAPE)
+    loss, grads = jax.jit(jax.value_and_grad(lambda p: packed_loss(
+        model.apply(p, packed_inputs(jb), True), jb)))(params)
+    ref_loss, (ref_rest, ref_layers) = jax.jit(
+        lambda p: reference.value_and_grads(p, images, labels, **SHAPE))(
+            params)
     assert abs(float(loss) - float(ref_loss)) < F32_RTOL * float(ref_loss)
     got = grads["params"]
     want = dict(ref_rest, blocks=jax.tree.map(
@@ -144,8 +154,8 @@ def test_train_step_loss_grad_norm_and_counters(setup):
                            schedule=schedule)
     _, metrics = step(state, {k: jnp.asarray(v) for k, v in batch.items()},
                       jax.random.key(1))
-    ref_loss, ref_norm = reference.loss_and_grad_norm(params, images, labels,
-                                                      **SHAPE)
+    ref_loss, ref_norm = jax.jit(lambda p: reference.loss_and_grad_norm(
+        p, images, labels, **SHAPE))(params)
     assert abs(float(metrics["loss"]) - float(ref_loss)) \
         < F32_RTOL * float(ref_loss)
     assert abs(float(metrics["grad_norm"]) - float(ref_norm)) \
@@ -166,17 +176,15 @@ def test_train_step_loss_grad_norm_and_counters(setup):
     assert float(metrics["computed_pairs"]) == live.sum() * 128 * 128 == 32768
 
 
-def test_lower_precision_fails(setup):
+def test_lower_precision_fails(setup, want):
     """The tolerance is tight enough that bfloat16 compute does not pass."""
     cfg, _, params, batch, images, _ = setup
     low = build_model(dataclasses.replace(cfg, dtype="bfloat16"))
-    want = reference.logits(params, images, **SHAPE)
     assert rel_gap(program_logits(low, params, batch), want) > 10 * F32_RTOL
 
 
-def test_dropping_the_rope_fails(setup):
+def test_dropping_the_rope_fails(setup, want):
     cfg, model, params, batch, images, _ = setup
-    want = reference.logits(params, images, **SHAPE)
     flat = dict(batch, positions=np.zeros_like(batch["positions"]))
     # positions also place the position table: keep that part right, so that
     # only the rotation is missing
@@ -186,9 +194,8 @@ def test_dropping_the_rope_fails(setup):
     assert rel_gap(program_logits(model, params, flat), want) > 10 * F32_RTOL
 
 
-def test_attending_across_images_fails(setup, monkeypatch):
+def test_attending_across_images_fails(setup, want, monkeypatch):
     cfg, model, params, batch, images, _ = setup
-    want = reference.logits(params, images, **SHAPE)
 
     def across(q, k, v, segment_ids, dtype):
         merged = (segment_ids > 0).astype(segment_ids.dtype)
@@ -208,6 +215,7 @@ def test_packing_invariance(setup):
     target = (4, 6)
     pixels = rng.integers(0, 256, (24, dim), dtype=np.uint8)
     others = [(8, 8), (2, 10), (4, 4)]
+    apply = jax.jit(lambda p, b: model.apply(p, packed_inputs(b), True))
 
     def logits_of_target(row):
         lay = packing.row_layout([row, []], cfg.pack_tokens, cfg.pack_images)
@@ -218,8 +226,7 @@ def test_packing_invariance(setup):
         patches *= (lay["segment_ids"] > 0)[..., None].astype(np.uint8)
         batch = dict(lay, patches=patches,
                      label=np.zeros((2, cfg.pack_images), np.int32))
-        out = model.apply(params, packed_inputs(
-            {k: jnp.asarray(v) for k, v in batch.items()}), True)
+        out = apply(params, {k: jnp.asarray(v) for k, v in batch.items()})
         return np.asarray(out)[0, row.index(target)]
 
     alone = logits_of_target([target])
@@ -239,9 +246,11 @@ def test_interpolated_table_against_the_two_matrix_form(hw):
     table = jax.random.normal(jax.random.key(0), (8, 8, 16), jnp.float32)
     lay = packing.row_layout([[hw]], h * w + 8, 2)
     token_hw = np.repeat(np.asarray([[hw]]), h * w + 8, axis=1)
-    got = vit.pos_interp(table, jnp.asarray(lay["positions"]),
-                         jnp.asarray(token_hw), jnp.float32)[0, :h * w]
-    want = reference.position_embedding(table, h, w)
+    got = jax.jit(vit.pos_interp, static_argnums=3)(
+        table, jnp.asarray(lay["positions"]), jnp.asarray(token_hw),
+        jnp.float32)[0, :h * w]
+    want = jax.jit(reference.position_embedding, static_argnums=(1, 2))(
+        table, h, w)
     if hw == (8, 8):    # the table's own grid: the table itself, exactly
         np.testing.assert_array_equal(np.asarray(got),
                                       np.asarray(table).reshape(64, 16))
@@ -265,12 +274,15 @@ def test_rope_scores_depend_on_the_offset_only():
     q = jax.random.normal(keys[0], (1, 1, 2, 16))
     k = jax.random.normal(keys[1], (1, 1, 2, 16))
 
-    def score(pq, pk):
-        pos = jnp.asarray([[pq, pk]], jnp.int32)              # (1, 2, 2)
+    @jax.jit
+    def rotated(pos):                                         # (1, 2, 2)
         cos, sin = vit.rope2d_tables(pos, 16, 10000.0)
         rq = vit.apply_rope2d(q, cos[:, :1], sin[:, :1])
         rk = vit.apply_rope2d(k, cos[:, 1:], sin[:, 1:])
-        return np.asarray(jnp.einsum("bqhd,bkhd->h", rq, rk))
+        return jnp.einsum("bqhd,bkhd->h", rq, rk)
+
+    def score(pq, pk):
+        return np.asarray(rotated(np.asarray([[pq, pk]], np.int32)))
 
     base = score((3, 5), (1, 2))
     np.testing.assert_allclose(score((13, 25), (11, 22)), base, atol=1e-5)
@@ -282,9 +294,11 @@ def test_rope_scores_depend_on_the_offset_only():
 def test_rope_matches_the_complex_form():
     x = jax.random.normal(jax.random.key(4), (1, 24, 2, 16))
     lay = packing.row_layout([[(4, 6)]], 24, 1)
-    cos, sin = vit.rope2d_tables(jnp.asarray(lay["positions"]), 16, 10000.0)
-    got = vit.apply_rope2d(x, cos, sin)[0]
-    want = reference.rotate(x[0], reference.rope_cis(4, 6, 16, 10000.0))
+    got = jax.jit(lambda x, pos: vit.apply_rope2d(
+        x, *vit.rope2d_tables(pos, 16, 10000.0)))(
+            x, jnp.asarray(lay["positions"]))[0]
+    want = jax.jit(lambda x: reference.rotate(
+        x, reference.rope_cis(4, 6, 16, 10000.0)))(x[0])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
@@ -311,9 +325,13 @@ def test_packed_kernel_matches_dense_masked_attention(dh, blocks):
     def dense(q, k, v):
         return vit.masked_attention(q, k, v, seg, jnp.float32)
 
-    def run(fn):
-        return fn(q, k, v), jax.grad(
-            lambda q, k, v: jnp.sum(fn(q, k, v) * w), argnums=(0, 1, 2))(q, k, v)
+    def run(fn):         # (output, its three gradients): one program
+        def weighted(q, k, v):
+            out = fn(q, k, v)
+            return jnp.sum(out * w), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            weighted, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return out, grads
 
     want_o, want_g = run(dense)
     outs = {}
@@ -530,8 +548,8 @@ def test_config_rules_of_the_packed_shape():
         with pytest.raises(AssertionError):
             small_cfg(**bad)
     assert vit.expected_param_count(small_cfg()) == vit.count_params(
-        build_model(small_cfg()).init(jax.random.key(0),
-                                      sample_input(small_cfg(), 2), True))
+        jax.jit(lambda: build_model(small_cfg()).init(
+            jax.random.key(0), sample_input(small_cfg(), 2), True))())
 
 
 # --- telemetry and the trainer ----------------------------------------------

@@ -8,16 +8,15 @@ uncut layer, the closed-form parameter count and the catalog's count a
 layer, the step's counters, the configuration's sentences, the flags and the
 loop. The delta rule itself: tests/test_gated_delta.py."""
 
-import json
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from benchmark.reference import olmo_hybrid as reference
-from tests.test_latent_decoder import LENGTHS, documents, make_batch, moved
-from vitax.config import Config, parse_config
+from tests import decoder_cases as cases
+from tests.test_latent_decoder import LENGTHS
+from vitax.config import Config
 from vitax.models import decoder
 from vitax.models.kda import GatedDeltaMixer, GatedDeltaShape
 
@@ -48,55 +47,24 @@ def reference_shape(cfg):
 
 
 @pytest.fixture(scope="module")
-def setup():
+def case():
     cfg = Config(**TINY).validate()
-    model = decoder.build_decoder(cfg)
-    variables = moved(jax.jit(lambda: model.init(
-        jax.random.key(0), decoder.sample_documents(cfg, 1), True))())
-    return cfg, model, variables, make_batch(cfg)
-
-
-@pytest.fixture(scope="module")
-def plain(setup):
-    """The reference's loss, gradients and logits at each document's first
-    and last position, computed once for the tests that hold them."""
-    cfg, _, variables, batch = setup
-    docs = documents(batch)
-    ats = [jnp.asarray([0, len(d) - 1]) for d in docs]
-    with jax.default_matmul_precision("highest"):
-        return reference.loss_grads_and_logits(variables, docs, ats,
-                                               **reference_shape(cfg))
+    return cases.DecoderCase(cfg, reference, reference_shape(cfg), LENGTHS)
 
 
 # --- the whole model ----------------------------------------------------------
 
-def test_logits_match_the_reference(setup):
-    cfg, model, variables, batch = setup
-    got = np.asarray(jax.jit(lambda v: model.apply(v, batch, True))(variables))
-    seg = np.asarray(batch["segment_ids"])
+def test_logits_match_the_reference(case):
+    got = case.logits
     assert np.abs(got).max() > 0.2
-
-    @jax.jit
-    def alone(ids):         # a document followed by zeros it cannot see
-        with jax.default_matmul_precision("highest"):
-            return reference.logits(variables, ids, **reference_shape(cfg))
-
-    for r in range(seg.shape[0]):
-        for s in range(1, seg[r].max() + 1):
-            at = np.where(seg[r] == s)[0]
-            want = alone(jnp.pad(batch["tokens"][r, at],
-                                 (0, seg.shape[1] - len(at))))[:len(at)]
-            np.testing.assert_allclose(got[r, at], want, rtol=2e-4,
-                                       atol=2e-5)
+    case.check_logits(padded=True)
+    seg = np.asarray(case.batch["segment_ids"])
     assert float(np.abs(got[seg == 0]).max()) < 10.0      # finite at padding
 
 
-def test_loss_and_every_gradient_leaf_match_the_reference(setup, plain):
-    from vitax.train.step import decoder_loss
-    cfg, model, variables, batch = setup
-    want_loss, want = jax.jit(jax.value_and_grad(lambda v: decoder_loss(
-        model.apply(v, batch, True), batch)))(variables)
-    loss, grads, rows = plain
+def test_loss_and_every_gradient_leaf_match_the_reference(case):
+    want_loss, want = case.loss_and_grads
+    loss, grads, _ = case.plain
     np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
     flat = jax.tree_util.tree_leaves_with_path(want)
     # embedding, head, final norm; the linear run's 11 mixer leaves, 3 of
@@ -105,16 +73,13 @@ def test_loss_and_every_gradient_leaf_match_the_reference(setup, plain):
     for (path, a), b in zip(flat, jax.tree.leaves(grads)):
         assert float(jnp.max(jnp.abs(a))) > 0.0, path
         assert reference.relative_gap(b, a) < 2e-3, jax.tree_util.keystr(path)
-    np.testing.assert_allclose(
-        reference.global_norm(reference.leaf_norms(grads)),
-        reference.global_norm(reference.leaf_norms(want)), rtol=1e-4)
-    logits = np.asarray(model.apply(variables, batch, True))
-    first = logits[0, [0, LENGTHS[0][0] - 1]]   # row 0's first document
-    np.testing.assert_allclose(rows[0], first, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(*(
+        jax.jit(lambda g: reference.global_norm(reference.leaf_norms(g)))(g)
+        for g in (grads, want)), rtol=1e-4)
+    case.check_first_rows()
 
 
-def test_bfloat16_stays_inside_limits_that_a_float8_control_breaks(setup,
-                                                                   plain):
+def test_bfloat16_stays_inside_limits_that_a_float8_control_breaks(case):
     """The benchmark's control (weights rounded to float8_e4m3 for the
     program, the reference on the seeded ones) against the program in the
     precision the configuration states, gradient by gradient and on the
@@ -122,7 +87,8 @@ def test_bfloat16_stays_inside_limits_that_a_float8_control_breaks(setup,
     from benchmark.generators.train_gated_delta_packed import (
         round_to_float8, watched_leaves)
     from vitax.train.step import decoder_loss
-    cfg, _, variables, batch = setup
+    cfg, variables, batch, plain = (case.cfg, case.variables, case.batch,
+                                    case.plain)
     model = decoder.build_decoder(Config(**{**TINY, "dtype": "bfloat16"}))
 
     @jax.jit
@@ -143,7 +109,7 @@ def test_bfloat16_stays_inside_limits_that_a_float8_control_breaks(setup,
     assert want["attention.q_norm"].shape == (16,)
     (sound, logits), (control, off) = (
         grads_and_logits(variables),
-        grads_and_logits(round_to_float8(variables)))
+        grads_and_logits(jax.jit(round_to_float8)(variables)))
     rows = plain[2]
     at = [0, LENGTHS[0][0] - 1]
     # at 32 wide with every leaf moved by 0.05 bf16 reads 0.07 on the logits
@@ -155,8 +121,8 @@ def test_bfloat16_stays_inside_limits_that_a_float8_control_breaks(setup,
         assert reference.relative_gap(control[name], want[name]) > 0.7, name
 
 
-def test_the_layer_pattern_and_its_runs(setup):
-    cfg, model, variables, _ = setup
+def test_the_layer_pattern_and_its_runs(case):
+    cfg, variables = case.cfg, case.variables
     assert decoder.layer_runs(cfg.layer_kinds, cfg.layer_heads,
                               cfg.layer_mlps) == [
         (("linear_attention", 2, "dense"), 3),
@@ -175,8 +141,8 @@ def test_the_layer_pattern_and_its_runs(setup):
     assert attn["q_norm"]["scale"].shape == (1, 16)       # the whole width
 
 
-def test_the_scopes_a_metric_reads_are_in_the_lowered_program(setup):
-    cfg, model, variables, batch = setup
+def test_the_scopes_a_metric_reads_are_in_the_lowered_program(case):
+    model, variables, batch = case.model, case.variables, case.batch
     text = jax.jit(lambda v: model.apply(v, batch, True)).lower(
         variables).as_text(debug_info=True)
     for scope in ("kda_conv", "kda_gate", "kda_chunk", "kda_state",
@@ -200,26 +166,13 @@ def test_the_model_through_the_convolutions_kernels_equals_the_plain_path():
     plain path's."""
     from tests.test_ssd_kernel import gap
     from vitax.ops.conv import make_conv_impl
-    from vitax.train.step import decoder_loss
     cfg = Config(**{**TINY, "gdn_key_size": 32,
                     "gdn_value_size": 64}).validate()
     conv = make_conv_impl(cfg, None, force_tpu_kernels=True)
     assert conv.vitax_name == ("fused kernel (256 channels a grid step in "
                                "blocks of 32 tokens)")
-    models = [decoder.build_decoder(cfg), decoder.build_decoder(
-        cfg, conv_impl=conv)]
-    batch = make_batch(cfg)
-    variables = moved(jax.jit(lambda: models[0].init(
-        jax.random.key(0), decoder.sample_documents(cfg, 1), True))())
-    want, got = (jax.jit(jax.value_and_grad(lambda v, m=m: decoder_loss(
-        m.apply(v, batch, True), batch)))(variables) for m in models)
-    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
-    np.testing.assert_allclose(models[1].apply(variables, batch, True),
-                               models[0].apply(variables, batch, True),
-                               rtol=2e-4, atol=2e-5)
-    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want[1]),
-                            jax.tree.leaves(got[1])):
-        assert gap(b, a) < 2e-4, jax.tree_util.keystr(path)
+    cases.check_conv_kernels_match_the_plain_path(
+        cfg, conv, cases.make_batch(cfg, LENGTHS), gap)
 
 
 def test_remat_keeps_o_and_lse_of_the_attention_layer_only():
@@ -259,7 +212,8 @@ def test_the_two_halves_of_the_heads_add_up_to_the_uncut_layer():
     # Gated DeltaNet
     whole = GatedDeltaMixer(GatedDeltaShape(heads, dk, dv, 4), 1e-6,
                             jnp.float32)
-    p = moved(jax.jit(whole.init)(jax.random.key(0), x[None], seg))["params"]
+    p = cases.moved(jax.jit(whole.init)(jax.random.key(0), x[None], seg))[
+        "params"]
     with jax.default_matmul_precision("highest"):
         uncut = jax.jit(lambda p: reference.gated_delta_mixer(
             x, p, 1e-6, key_dim=dk, value_dim=dv, taps=4))(p)
@@ -298,11 +252,20 @@ def test_the_two_halves_of_the_heads_add_up_to_the_uncut_layer():
     whole = decoder.DecoderAttention(
         heads=heads, kv_heads=heads, head_size=dh, window=0, head_gate=False,
         dtype=jnp.float32, qk_norm=1e-6)
-    p = moved(whole.init(jax.random.key(1), x[None], seg, None))["params"]
+    p = cases.moved(jax.jit(whole.init)(jax.random.key(1), x[None], seg,
+                                        None))["params"]
+
+    @jax.jit
+    def plain(p, mean_squares=None):
+        with jax.default_matmul_precision("highest"):
+            return reference.attention_mixer(x, p, 1e-6, head_dim=dh,
+                                             mean_squares=mean_squares)
+
     with jax.default_matmul_precision("highest"):
-        uncut = reference.attention_mixer(x, p, 1e-6, head_dim=dh)
-        of_whole = tuple(jnp.mean(jnp.square(x @ p[w]["kernel"]), axis=-1,
-                                  keepdims=True) for w in ("wq", "wk"))
+        uncut = plain(p)
+        of_whole = jax.jit(lambda p: tuple(
+            jnp.mean(jnp.square(x @ p[w]["kernel"]), axis=-1, keepdims=True)
+            for w in ("wq", "wk")))(p)
     total = 0.0
     for part in (0, 1):
         half = {w: {"kernel": _heads(p[w]["kernel"], heads, dh, 1, part)}
@@ -316,11 +279,9 @@ def test_the_two_halves_of_the_heads_add_up_to_the_uncut_layer():
         with jax.default_matmul_precision("highest"):
             # program and reference alike: over what is held
             np.testing.assert_allclose(
-                held.apply({"params": half}, x[None], seg, None)[0],
-                reference.attention_mixer(x, half, 1e-6, head_dim=dh),
-                rtol=2e-4, atol=2e-5)
-            total = total + reference.attention_mixer(
-                x, half, 1e-6, head_dim=dh, mean_squares=of_whole)
+                jax.jit(held.apply)({"params": half}, x[None], seg, None)[0],
+                plain(half), rtol=2e-4, atol=2e-5)
+            total = total + plain(half, of_whole)
     np.testing.assert_allclose(total, uncut, rtol=2e-4, atol=2e-5)
     assert float(jnp.max(jnp.abs(uncut))) > 0.05
 
@@ -334,12 +295,9 @@ def _count(cfg):
     return sum(int(np.prod(a.shape)) for a in jax.tree.leaves(shapes))
 
 
-def test_closed_form_parameter_count_and_the_configurations():
-    cfg = Config(**TINY).validate()
-    variables = decoder.build_decoder(cfg).init(
-        jax.random.key(0), decoder.sample_documents(cfg, 1), True)
-    assert sum(a.size for a in jax.tree.leaves(variables)) \
-        == decoder.expected_param_count(cfg)
+def test_closed_form_parameter_count_and_the_configurations(case):
+    assert sum(a.size for a in jax.tree.leaves(case.variables)) \
+        == decoder.expected_param_count(case.cfg)
     # the configuration of the benchmark's cell, by shapes alone
     real = Config(**OLMO).validate()
     assert _count(real) == decoder.expected_param_count(real) == 766_241_946
@@ -374,32 +332,15 @@ def test_train_step_counters_and_the_first_steps_moments():
     row is one chunk), both chunks live. And what the benchmark holds the
     TIMED step to: the gradients read from the optimizer state its first call
     left (`step_gradients`) are the model's own, with the clip at work."""
-    from benchmark.generators.train_gated_delta_packed import (
-        step_gradients, watched_leaves)
-    from vitax.programs.builder import Geometry, build_program
-    from vitax.train.step import decoder_inputs, decoder_loss
+    from benchmark.generators import train_gated_delta_packed
     cfg = Config(**{**TINY, "warmup_steps": 1, "lr": 2e-3,
                     "clip_grad_norm": 0.05}).validate()
-    geom = Geometry.assemble(cfg, 100, materialize=True,
-                             devices=jax.devices()[:1])
+    batch = cases.make_batch(cfg, LENGTHS)
+    geom, step, state, first = cases.check_first_steps_moments(
+        train_gated_delta_packed, cfg, batch, clipped=True)
     assert geom.model.kda_impl is None
-    state, geom.state = geom.state, None
-    step = build_program("train", geom)
-    batch = make_batch(cfg)
-    want = watched_leaves(jax.jit(jax.grad(
-        lambda v: decoder_loss(geom.model.apply(
-            v, decoder_inputs(batch), True), batch)))(state.params), cfg)
-    losses = []
-    for i in range(4):
-        state, m = step(state, batch, jax.random.key(1))
-        losses.append(float(m["loss"]))
-        if i == 0:
-            norm = float(m["grad_norm"])
-            assert norm > cfg.clip_grad_norm
-            got = step_gradients(state.opt_state, norm, cfg)
-            assert sorted(got) == sorted(want)
-            for name in want:
-                assert reference.relative_gap(got[name], want[name]) < 1e-5
+    _, m, losses = cases.take_steps(step, state, batch, 3)
+    losses.insert(0, float(first["loss"]))
     got = {k: float(m[k]) for k in (
         "tokens", "padding_tokens", "images", "targets", "causal_pairs",
         "kda_pairs", "kda_live_chunks")}
@@ -453,14 +394,8 @@ def test_a_linear_layers_heads_are_not_held_to_the_kv_heads():
 
 
 def test_the_family_declares_the_new_shape_fields():
-    import os
-    from benchmark import forms
-    from benchmark import manifest as mf
-    olmo = forms.declared_keys(mf.read_json(
-        os.path.join(mf.BENCH_DIR, "shapes", "olmo_hybrid.json")))
     assert {"gdn_key_size", "gdn_value_size", "gdn_conv_width", "norm_after",
-            "qk_norm"} <= olmo
-    assert not olmo & forms.knob_keys(forms.rules())
+            "qk_norm"} <= cases.family_declares("olmo_hybrid")
 
 
 def test_training_through_the_cli_path(tmp_path, capsys):
@@ -469,9 +404,8 @@ def test_training_through_the_cli_path(tmp_path, capsys):
     through `parse_config`, then the loop the entry point calls): a falling
     loss, the delta rule's counters on the step records, and the start-up
     line that says which delta rule runs and why; no flag selects a form."""
-    from vitax.train.loop import train
-    cfg = parse_config((
-        "--fake_data", "--model_family", "decoder", "--pack_tokens", "64",
+    cfg, steps = cases.train_through_the_cli(
+        tmp_path, "--pack_tokens", "64",
         "--pack_images", "6", "--embed_dim", "32", "--num_blocks", "4",
         "--vocab_rows", "48", "--kv_heads", "2", "--head_size", "8",
         "--layer_kinds",
@@ -479,28 +413,16 @@ def test_training_through_the_cli_path(tmp_path, capsys):
         "--layer_heads", "2,2,2,2", "--layer_mlps", "dense,dense,dense,dense",
         "--ffn_dim", "48", "--norm_eps", "1e-6", "--position_embedding",
         "nope", "--gdn_key_size", "6", "--gdn_value_size", "12",
-        "--gdn_conv_width", "4", "--norm_after", "--qk_norm",
-        "--batch_size", "8", "--num_epochs", "1", "--steps_per_epoch", "3",
-        "--lr", "3e-3", "--log_step_interval", "1", "--warmup_steps", "1",
-        "--ckpt_dir", str(tmp_path / "ckpt"),
-        "--metrics_dir", str(tmp_path / "metrics")))
+        "--gdn_conv_width", "4", "--norm_after", "--qk_norm")
     assert cfg.norm_after and cfg.qk_norm and cfg.gdn_value_size == 12
-    train(cfg)
     out = capsys.readouterr().out
     assert "delta rule: plain (no TPU)" in out
     assert "mixer convolution: plain (no TPU)" in out
     assert "in linear_attention layers" not in out
-    with open(tmp_path / "metrics" / "metrics.jsonl") as f:
-        steps = [r for r in map(json.loads, f) if "kind" not in r]
-    losses = [r["loss"] for r in steps]
-    assert len(losses) == 3 and np.isfinite(losses).all()
-    assert losses[-1] < losses[0]
     for r in steps:
-        assert 0.0 <= r["padding_frac"] < 1.0
         assert 0 < r["kda_pairs"] <= r["causal_pairs"]
         assert 0 < r["kda_live_chunks"] <= 8 * 64 // 64
         assert "ssd_pairs" not in r
-    assert (tmp_path / "ckpt" / "epoch_1").exists()
 
 
 def test_the_start_up_line_says_why_the_plain_rule_runs():

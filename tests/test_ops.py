@@ -31,8 +31,8 @@ def test_flash_matches_reference_grad(devices8):
     def loss(fn):
         return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
 
-    gf = jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(q, k, v)
+    gf = jax.jit(jax.grad(loss(flash_attention), argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss(reference_attention), argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-3)
 
@@ -47,9 +47,10 @@ def test_model_with_flash_attention_matches_dense(devices8):
     model_d = build_model(cfg, attention_impl=None)
     model_f = build_model(cfg, attention_impl=flash_attention)
     x = jax.random.normal(jax.random.key(2), (2, 32, 32, 3), jnp.float32)
-    params = model_d.init(jax.random.key(0), x, True)
-    out_d = model_d.apply(params, x, True)
-    out_f = model_f.apply(params, x, True)
+    params = jax.jit(model_d.init, static_argnums=2)(jax.random.key(0), x,
+                                                     True)
+    out_d, out_f = (jax.jit(m.apply, static_argnums=2)(params, x, True)
+                    for m in (model_d, model_f))
     np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_d), rtol=2e-3, atol=2e-3)
 
 
@@ -208,8 +209,8 @@ def test_flash_dropout_matches_masked_dense(devices8, family):
         fn = lambda q, k, v: _from_bh(flash_bh_dropout(  # noqa: E731
             _to_bh(q), _to_bh(k), _to_bh(v), seed, scale, rate), q.shape)
 
-    out_k = fn(q, k, v)
-    out_d = _dropout_oracle(q, k, v, seed, rate)
+    out_k = jax.jit(fn)(q, k, v)
+    out_d = jax.jit(_dropout_oracle, static_argnums=4)(q, k, v, seed, rate)
     # sanity: the mask actually dropped something (kernel != no-dropout path)
     assert not np.allclose(np.asarray(out_k),
                            np.asarray(reference_attention(q, k, v)), atol=1e-3)
@@ -219,9 +220,10 @@ def test_flash_dropout_matches_masked_dense(devices8, family):
     def loss(fn):
         return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
 
-    gk = jax.grad(loss(fn), argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(loss(lambda q, k, v: _dropout_oracle(q, k, v, seed, rate)),
-                  argnums=(0, 1, 2))(q, k, v)
+    gk = jax.jit(jax.grad(loss(fn), argnums=(0, 1, 2)))(q, k, v)
+    gd = jax.jit(jax.grad(
+        loss(lambda q, k, v: _dropout_oracle(q, k, v, seed, rate)),
+        argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gk, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-3, atol=1e-3)
@@ -264,22 +266,23 @@ def test_model_train_att_dropout_keeps_kernel_and_is_deterministic(devices8):
     assert getattr(impl, "vitax_dropout", None) is not None
     model = build_model(cfg, attention_impl=impl)
     x = jax.random.normal(jax.random.key(4), (4, 32, 32, 3), jnp.float32)
-    params = model.init(jax.random.key(0), x, True)
+    params = jax.jit(model.init, static_argnums=2)(jax.random.key(0), x, True)
+    train = jax.jit(lambda p, rngs: model.apply(p, x, False, rngs=rngs))
 
     rngs = {"dropout": jax.random.key(9)}
-    out1 = model.apply(params, x, False, rngs=rngs)
-    out2 = model.apply(params, x, False, rngs=rngs)
+    out1 = train(params, rngs)
+    out2 = train(params, rngs)
     np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
-    out3 = model.apply(params, x, False, rngs={"dropout": jax.random.key(10)})
+    out3 = train(params, {"dropout": jax.random.key(10)})
     assert not np.array_equal(np.asarray(out1), np.asarray(out3))
     # eval path (deterministic) unaffected by the dropout hook
-    out_eval = model.apply(params, x, True)
+    out_eval = jax.jit(model.apply, static_argnums=2)(params, x, True)
     assert np.all(np.isfinite(np.asarray(out_eval)))
 
     def loss_fn(p):
         return jnp.sum(model.apply(p, x, False, rngs=rngs) ** 2)
 
-    grads = jax.grad(loss_fn)(params)
+    grads = jax.jit(jax.grad(loss_fn))(params)
     assert all(np.all(np.isfinite(np.asarray(g)))
                for g in jax.tree_util.tree_leaves(grads))
 
@@ -307,8 +310,8 @@ def test_flash4d_matches_reference_grad(devices8):
     def loss(fn):
         return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
 
-    gf = jax.grad(loss(flash_attention_4d), argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(q, k, v)
+    gf = jax.jit(jax.grad(loss(flash_attention_4d), argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss(reference_attention), argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-3, atol=1e-3)
@@ -340,8 +343,8 @@ def _check_flash4d_matches_reference(shape, seed):
     np.testing.assert_allclose(
         np.asarray(flash_attention_4d(q, k, v)),
         np.asarray(reference_attention(q, k, v)), rtol=2e-4, atol=2e-4)
-    gf = jax.grad(loss(flash_attention_4d), argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(q, k, v)
+    gf = jax.jit(jax.grad(loss(flash_attention_4d), argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss(reference_attention), argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-3, atol=1e-3)
@@ -436,8 +439,8 @@ def test_flash_qkv_matches_reference_fwd_and_grad(devices8, shape, hb):
     np.testing.assert_allclose(np.asarray(fused(qkv)),
                                np.asarray(reference(qkv)),
                                rtol=2e-4, atol=2e-4)
-    got = jax.grad(lambda x: jnp.sum(fused(x) * weight))(qkv)
-    want = jax.grad(lambda x: jnp.sum(reference(x) * weight))(qkv)
+    got, want = (jax.jit(jax.grad(lambda x, f=f: jnp.sum(f(x) * weight)))(qkv)
+                 for f in (fused, reference))
     assert got.shape == qkv.shape
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-3, atol=1e-3)

@@ -7,6 +7,7 @@ softmax, on layouts whose image and document edges fall inside a sub-tile,
 on one, and on a block pair with a single live sub-tile.
 """
 
+import functools
 import json
 import os
 
@@ -174,6 +175,19 @@ def dense(q, k, v, see, heads, group):
     return jnp.einsum("bqk,bkd->bqd", p, v), lse
 
 
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def dense_and_grads(q, k, v, do, see, heads, group):
+    """(o, lse, the gradients of sum(o * do) by q, k, v) of the dense softmax:
+    one program a head layout, shared by the cases that differ in `see`."""
+    def weighted(q, k, v):
+        o, lse = dense(q, k, v, see, heads, group)
+        return jnp.sum(o * do), (o, lse)
+
+    (_, (o, lse)), grads = jax.value_and_grad(
+        weighted, (0, 1, 2), has_aux=True)(q, k, v)
+    return o, lse, grads
+
+
 @pytest.mark.parametrize("mask", ["segment", "causal", "window100", "window128"])
 @pytest.mark.parametrize("edges", list(EDGES))
 def test_kernels_walking_sub_tiles_equal_the_every_tile_arm(edges, mask):
@@ -205,9 +219,8 @@ def test_kernels_walking_sub_tiles_equal_the_every_tile_arm(edges, mask):
     for a, b, atol in zip(got, every, (2e-5, 2e-5, 5e-5, 5e-5, 5e-5)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=atol)
     see = dense_mask(seg, causal, window)
-    want_o, want_lse = dense(q, k, v, see, heads, group)
-    want_g = jax.grad(lambda q, k, v: jnp.sum(
-        dense(q, k, v, see, heads, group)[0] * do), (0, 1, 2))(q, k, v)
+    want_o, want_lse, want_g = dense_and_grads(q, k, v, do, see, heads,
+                                               group)
     valid = np.repeat(seg > 0, heads, axis=0)
     np.testing.assert_allclose(got[0], want_o, rtol=1e-4, atol=2e-5)
     np.testing.assert_allclose(np.asarray(got[1])[valid],

@@ -3,6 +3,8 @@ CPU mesh: forward logits parity vs the scan path, full train-step trajectory
 parity vs FSDP, microbatch schedule edge cases, and the pp param sharding —
 mirrors the ring/ulysses suites for the new axis (vitax/parallel/pipeline.py)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,21 +16,20 @@ from vitax.parallel.mesh import build_mesh
 from vitax.parallel.pipeline import make_pp_forward
 
 
-_FSDP8_REF_LOSSES = None
+@functools.cache
+def _fsdp8_losses(moe_experts):
+    from tests.test_train_smoke import run_steps
+    return tuple(run_steps(
+        pp_cfg(pp_size=1, dp_size=1, fsdp_size=-1, grad_ckpt=True,
+               moe_experts=moe_experts), n_steps=4)[1])
 
 
-def fsdp8_reference_losses():
+def fsdp8_reference_losses(moe_experts=0):
     """The plain-fsdp8 4-step trajectory every pp composition is checked
     against — computed once per suite run (six parametrized cases plus three
-    other tests use the byte-identical config)."""
-    global _FSDP8_REF_LOSSES
-    if _FSDP8_REF_LOSSES is None:
-        from tests.test_train_smoke import run_steps
-        _, losses = run_steps(
-            pp_cfg(pp_size=1, dp_size=1, fsdp_size=-1, grad_ckpt=True),
-            n_steps=4)
-        _FSDP8_REF_LOSSES = tuple(losses)
-    return list(_FSDP8_REF_LOSSES)
+    other tests use the byte-identical config; the two MoE compositions
+    theirs, with four experts)."""
+    return list(_fsdp8_losses(moe_experts))
 
 
 def pp_cfg(**kw):
@@ -53,7 +54,7 @@ def test_pp_forward_matches_scan_path(devices8, microbatches):
                           jnp.float32)
     params = jax.jit(lambda k: model.init(k, x[:1], True))(jax.random.key(0))
 
-    ref = model.apply(params, x, True)
+    ref = jax.jit(lambda p, x_: model.apply(p, x_, True))(params, x)
     got = jax.jit(make_pp_forward(cfg, model, mesh))(params, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -75,8 +76,9 @@ def test_pp_grads_match_scan_path(devices8):
     def loss(fwd):
         return lambda p: jnp.sum(fwd(p, x) ** 2)
 
-    g_ref = jax.grad(loss(lambda p, x_: model.apply(p, x_, True)))(params)
-    g_pp = jax.grad(loss(pp_fwd))(params)
+    g_ref = jax.jit(jax.grad(loss(
+        lambda p, x_: model.apply(p, x_, True))))(params)
+    g_pp = jax.jit(jax.grad(loss(pp_fwd)))(params)
     for (ka, a), (_, b) in zip(  # identical treedefs -> identical order
             jax.tree_util.tree_flatten_with_path(g_ref)[0],
             jax.tree_util.tree_flatten_with_path(g_pp)[0]):
@@ -191,9 +193,7 @@ def test_pp_moe_matches_non_pp(devices8):
     moe_kw = dict(moe_experts=4, ep_size=1)
     _, losses_pp = run_steps(
         pp_cfg(pp_size=2, dp_size=4, grad_ckpt=True, **moe_kw), n_steps=4)
-    _, losses_ref = run_steps(
-        pp_cfg(pp_size=1, dp_size=1, fsdp_size=-1, grad_ckpt=True, **moe_kw),
-        n_steps=4)
+    losses_ref = fsdp8_reference_losses(moe_experts=4)
     assert all(np.isfinite(losses_pp))
     np.testing.assert_allclose(losses_pp, losses_ref, rtol=2e-4)
 
@@ -210,9 +210,7 @@ def test_pp_moe_ep_matches_non_pp(devices8):
     _, losses_pp_ep = run_steps(
         pp_cfg(pp_size=2, dp_size=2, ep_size=2, fsdp_size=1, grad_ckpt=True,
                **moe_kw), n_steps=4)
-    _, losses_ref = run_steps(
-        pp_cfg(pp_size=1, dp_size=1, fsdp_size=-1, ep_size=1,
-               grad_ckpt=True, **moe_kw), n_steps=4)
+    losses_ref = fsdp8_reference_losses(moe_experts=4)
     assert all(np.isfinite(losses_pp_ep))
     np.testing.assert_allclose(losses_pp_ep, losses_ref, rtol=2e-4)
 
@@ -270,23 +268,22 @@ def test_pp_dropout_deterministic_and_active(devices8):
     given (seed, step) — same rng twice gives identical losses, a different
     rng different ones — and dropout must actually bite (loss differs from
     the deterministic path)."""
-    from tests.test_train_smoke import build_train_objects, random_batch
+    from tests.test_train_smoke import (build_train_objects, fresh,
+                                        random_batch)
 
     cfg = pp_cfg(pp_size=2, dp_size=4, att_dropout=0.2, mlp_dropout=0.2,
                  pos_dropout=0.1, grad_ckpt=True)
-    mesh, state, step_fn, _ = build_train_objects(cfg)
+    mesh, state, step_fn, _ = build_train_objects(cfg)   # compiled once
     batch = random_batch(cfg, mesh, seed=0)
     rng_a, rng_b = jax.random.key(1), jax.random.key(2)
 
-    _, m1 = step_fn(state, batch, rng_a)
+    _, m1 = step_fn(fresh(state), batch, rng_a)
     l1 = float(jax.device_get(m1["loss"]))
-    mesh2, state2, step_fn2, _ = build_train_objects(cfg)
-    _, m2 = step_fn2(state2, batch, rng_a)
+    _, m2 = step_fn(fresh(state), batch, rng_a)
     l2 = float(jax.device_get(m2["loss"]))
     assert l1 == l2, f"dropout under pp is not deterministic: {l1} vs {l2}"
 
-    mesh3, state3, step_fn3, _ = build_train_objects(cfg)
-    _, m3 = step_fn3(state3, batch, rng_b)
+    _, m3 = step_fn(state, batch, rng_b)
     l3 = float(jax.device_get(m3["loss"]))
     assert l1 != l3, "different step rng produced identical dropout masks"
 
@@ -346,7 +343,7 @@ def test_pp_tp_forward_and_grads_match_scan_path(devices8):
     pp_fwd = make_pp_forward(cfg, model, mesh,
                              block_specs=specs["params"]["blocks"])
 
-    ref = model.apply(params, x, True)
+    ref = jax.jit(lambda p, x_: model.apply(p, x_, True))(params, x)
     got = jax.jit(pp_fwd)(params, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -354,8 +351,9 @@ def test_pp_tp_forward_and_grads_match_scan_path(devices8):
     def loss(fwd):
         return lambda p: jnp.sum(fwd(p, x) ** 2)
 
-    g_ref = jax.grad(loss(lambda p, x_: model.apply(p, x_, True)))(params)
-    g_pp = jax.grad(loss(pp_fwd))(params)
+    g_ref = jax.jit(jax.grad(loss(
+        lambda p, x_: model.apply(p, x_, True))))(params)
+    g_pp = jax.jit(jax.grad(loss(pp_fwd)))(params)
     for (ka, a), (_, b) in zip(
             jax.tree_util.tree_flatten_with_path(g_ref)[0],
             jax.tree_util.tree_flatten_with_path(g_pp)[0]):

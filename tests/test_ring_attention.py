@@ -49,7 +49,8 @@ def test_ring_grad_matches_dense(devices8):
         return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
 
     gr_ring = jax.jit(jax.grad(loss(ring), argnums=(0, 1, 2)))(q, k, v)
-    gr_ref = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(q, k, v)
+    gr_ref = jax.jit(jax.grad(loss(reference_attention),
+                              argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gr_ring, gr_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-3)
 
@@ -73,7 +74,8 @@ def test_ring_kernel_block_matches_dense(devices8):
         return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
 
     gr_ring = jax.jit(jax.grad(loss(ring), argnums=(0, 1, 2)))(q, k, v)
-    gr_ref = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(q, k, v)
+    gr_ref = jax.jit(jax.grad(loss(reference_attention),
+                              argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gr_ring, gr_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-3, atol=1e-3)
@@ -146,7 +148,7 @@ def test_ring_dropout_matches_masked_dense(devices8, use_kernel):
         return jnp.einsum("bhqk,bkhd->bqhd", probs * mask / (1 - rate), v)
 
     out = jax.jit(lambda q, k, v: ring_drop(q, k, v, seed))(q, k, v)
-    want = dense_masked(q, k, v)
+    want = jax.jit(dense_masked)(q, k, v)
     assert not np.allclose(np.asarray(out),
                            np.asarray(reference_attention(q, k, v)),
                            atol=1e-3)  # the mask actually bit
@@ -156,9 +158,9 @@ def test_ring_dropout_matches_masked_dense(devices8, use_kernel):
     def loss(fn):
         return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
 
-    got = jax.grad(loss(lambda q, k, v: ring_drop(q, k, v, seed)),
-                   argnums=(0, 1, 2))(q, k, v)
-    ref = jax.grad(loss(dense_masked), argnums=(0, 1, 2))(q, k, v)
+    got = jax.jit(jax.grad(loss(lambda q, k, v: ring_drop(q, k, v, seed)),
+                           argnums=(0, 1, 2)))(q, k, v)
+    ref = jax.jit(jax.grad(loss(dense_masked), argnums=(0, 1, 2)))(q, k, v)
     for g, w in zip(got, ref):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=2e-3, atol=2e-3)

@@ -12,6 +12,7 @@ from peer shards with ZERO shared-storage checkpoint reads, and pin bitwise
 parameter equality against the uninterrupted run.
 """
 
+import gc
 import json
 import os
 import signal
@@ -746,7 +747,15 @@ def test_loop_zero_stall_pin_and_peer_resume(devices8, tmp_path):
     store and touches shared storage ZERO times (the counter seam)."""
     from vitax.train.loop import train
     common = _loop_common(tmp_path, zero_stall_ckpt=True, replicate_steps=2)
-    state = train(tiny_cfg(num_epochs=2, **common))
+    # the budget below is the staging copy's, not the collector's: a full
+    # collection (of what this process's earlier tests left, and of what
+    # tracing the step leaves) takes over 0.1 s when it lands inside a copy
+    gc.collect()
+    gc.disable()
+    try:
+        state = train(tiny_cfg(num_epochs=2, **common))
+    finally:
+        gc.enable()
     assert int(jax.device_get(state.step)) == 8
 
     steps, events = _read_metrics(tmp_path)

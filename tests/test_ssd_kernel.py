@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from benchmark.reference import granite as reference
+from tests.test_ssm import documents
 from vitax.config import Config
 from vitax.data.packing import document_layout
 from vitax.models.ssm import MixerShape, SSDMixer, ssd
@@ -39,6 +40,9 @@ LAYOUTS = {
 
 
 def operands(lengths, heads, groups, dtype=jnp.float32, head=HEAD, seed=0):
+    """Eager, a dozen small programs: drawn under one jit, delta and A come
+    out a rounding off and the head of 128's gradient of A reads 1.04e-4
+    against the plain form's, over the 1e-4 held (7.6e-5 on these)."""
     seg = jnp.asarray(document_layout(lengths, ROW, 8)["segment_ids"])
     r, t = seg.shape
     ks = jax.random.split(jax.random.key(seed), 7)
@@ -105,19 +109,17 @@ def test_kernel_matches_the_token_by_token_recurrence(name, monkeypatch):
 
     def plain(x, delta, a_head, b, c, d_skip):
         total = 0.0
-        for r in range(seg.shape[0]):
-            for s in range(1, int(seg[r].max()) + 1):
-                at = np.where(np.asarray(seg[r]) == s)[0]
-                y = reference.recurrence(
-                    x[r, at], delta[r, at], a_head,
-                    jnp.repeat(b[r, at], per_group, axis=1),
-                    jnp.repeat(c[r, at], per_group, axis=1))
-                y = y + d_skip[:, None] * x[r, at]
-                total += jnp.sum(y * weight[r, at])
+        for r, at in documents(seg):
+            y = reference.recurrence(
+                x[r, at], delta[r, at], a_head,
+                jnp.repeat(b[r, at], per_group, axis=1),
+                jnp.repeat(c[r, at], per_group, axis=1))
+            y = y + d_skip[:, None] * x[r, at]
+            total += jnp.sum(y * weight[r, at])
         return total
 
     with jax.default_matmul_precision("highest"):
-        want = jax.grad(plain, argnums=tuple(range(6)))(*ops)
+        want = jax.jit(jax.grad(plain, argnums=tuple(range(6))))(*ops)
     valid = np.asarray(seg > 0)
     for leaf, a, b in zip(NAMES, got, want):
         if leaf in ("x", "delta", "B", "C"):    # per token: none at padding

@@ -42,9 +42,11 @@ def test_forward_matches_torch_reference_math(devices8):
     model = build_model(cfg)
     images = np.asarray(jax.random.normal(
         jax.random.key(1), (4, 32, 32, 3), jnp.float32))
-    params = model.init(jax.random.key(0), jnp.asarray(images)[:1], True)
+    params = jax.jit(model.init, static_argnums=2)(
+        jax.random.key(0), jnp.asarray(images)[:1], True)
 
-    got = np.asarray(model.apply(params, jnp.asarray(images), True))
+    got = np.asarray(jax.jit(model.apply, static_argnums=2)(
+        params, jnp.asarray(images), True))
     want = torch_forward(params["params"], images,
                          patch_size=cfg.patch_size, num_heads=cfg.num_heads,
                          num_blocks=cfg.num_blocks)

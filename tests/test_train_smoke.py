@@ -54,8 +54,17 @@ def build_train_objects(cfg, max_iteration=100):
     return mesh, state, step_fn, eval_fn
 
 
-def run_steps(cfg, n_steps=8, seed=0):
-    mesh, state, step_fn, _ = build_train_objects(cfg)
+def fresh(state):
+    """A copy, placed as the original is, that a step may donate."""
+    import jax.numpy as jnp
+    return jax.tree.map(jnp.copy, state)
+
+
+def run_steps(cfg, n_steps=8, seed=0, built=None):
+    """`built`: what `build_train_objects(cfg)` gave, for a caller that runs
+    one configuration more than once and compiles its step once (the state
+    is donated: hand over `fresh(state)` for every run but the last)."""
+    mesh, state, step_fn, _ = built or build_train_objects(cfg)
     rng = jax.random.key(cfg.seed + 1)
     losses = []
     for i in range(n_steps):
@@ -182,18 +191,24 @@ def test_compile_cache_dir_populates(tmp_path):
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     cache = tmp_path / "xla_cache"
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               JAX_COMPILATION_CACHE_DIR=str(cache),
+    # nothing of the caller's JAX or XLA settings reaches the child; one
+    # device: the cache is what is pinned here, and eight virtual devices'
+    # collectives time out at their rendezvous on a busy machine
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith(("JAX_", "XLA_"))}
+    env.update(JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(cache),
                JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    # --eval_max_batches: the closing eval of the whole fake validation
+    # split was 100 of this test's 120 seconds
     r = subprocess.run(
         [sys.executable, "run_vit_training.py", "--fake_data",
          "--image_size", "32", "--patch_size", "8", "--embed_dim", "32",
          "--num_heads", "4", "--num_blocks", "2", "--batch_size", "16",
          "--num_epochs", "1", "--steps_per_epoch", "2",
          "--log_step_interval", "1", "--test_epoch_interval", "10",
+         "--eval_max_batches", "1",
          "--num_workers", "1", "--ckpt_dir", str(tmp_path / "ckpt")],
-        cwd=repo, env=env, capture_output=True, text=True, timeout=1500)
+        cwd=repo, env=env, capture_output=True, text=True, timeout=240)
     assert r.returncode == 0, r.stderr[-2000:]
     assert cache.is_dir() and os.listdir(cache), (
         "compile cache dir was never populated")
