@@ -23,6 +23,7 @@ from benchmark.reference.laguna import relative_gap, unpack
 from vitax.config import parse_config
 from vitax.data.packing import document_layout
 from vitax.models import decoder
+from vitax.programs.kernels import Kernels
 from vitax.train.step import decoder_inputs, decoder_loss
 
 
@@ -175,7 +176,7 @@ def check_conv_kernels_match_the_plain_path(cfg, conv, batch, gap):
     `conv` (interpret mode) against the plain path: logits, loss and every
     leaf's gradient."""
     models = [decoder.build_decoder(cfg),
-              decoder.build_decoder(cfg, conv_impl=conv)]
+              decoder.build_decoder(cfg, kernels=Kernels(conv=conv))]
     variables = seeded(models[0], cfg)
     want, got = (loss_grads_and_logits(m, batch)(variables) for m in models)
     np.testing.assert_allclose(got[0][0], want[0][0], rtol=1e-6)

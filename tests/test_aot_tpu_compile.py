@@ -320,7 +320,7 @@ def test_state_space_scan_compiles_and_lies_under_its_scopes(chip, mosaic):
 
     from vitax.config import Config
     from vitax.models.ssm import MixerShape, SSDMixer
-    from vitax.ops.ssd import make_scan_impl
+    from vitax.programs.kernels import choose_kernels
     one_chip, _ = chip
     cfg = Config(
         model_family="decoder", embed_dim=2048, num_blocks=1, vocab_rows=128,
@@ -328,7 +328,7 @@ def test_state_space_scan_compiles_and_lies_under_its_scopes(chip, mosaic):
         layer_mlps=["dense"], ffn_dim=128, ssm_heads=64, ssm_head_size=64,
         ssm_state_size=128, ssm_conv_width=4, ssm_groups=1, ssm_chunk=256,
         pack_tokens=4096, pack_images=4, batch_size=1).validate()
-    scan = make_scan_impl(cfg, None, force_tpu_kernels=True)
+    scan = choose_kernels(cfg, None, force_tpu_kernels=True).scan
     assert scan.vitax_name == "fused kernel (chunk 256, 16 heads a grid step)"
     mixer = SSDMixer(MixerShape(64, 64, 128, 4, 1, 256), 1e-5, jnp.bfloat16,
                      scan=scan)
@@ -363,7 +363,8 @@ def test_delta_rule_compiles_and_lies_under_its_scope(chip, mosaic):
 
     from vitax.config import Config
     from vitax.models.kda import KDAMixer, KDAShape
-    from vitax.ops.kda import HEADS_PER_STEP, make_kda_impl
+    from vitax.ops.kda import HEADS_PER_STEP
+    from vitax.programs.kernels import choose_kernels
     one_chip, _ = chip
     cfg = Config(
         model_family="decoder", embed_dim=2048, num_blocks=1, vocab_rows=128,
@@ -371,7 +372,7 @@ def test_delta_rule_compiles_and_lies_under_its_scope(chip, mosaic):
         layer_mlps=["dense"], ffn_dim=128, kda_conv_width=4,
         kda_gate_bound=-5.0, pack_tokens=4096, pack_images=4,
         batch_size=1).validate()
-    rule = make_kda_impl(cfg, None, force_tpu_kernels=True)
+    rule = choose_kernels(cfg, None, force_tpu_kernels=True).rule
     assert rule.vitax_name == (f"fused kernel (chunk 64, sub-chunks of 16, "
                                f"{HEADS_PER_STEP} heads a grid step)")
     mixer = KDAMixer(KDAShape(16, 128, 4, -5.0), 1e-6, jnp.bfloat16,
@@ -411,7 +412,7 @@ def test_mixer_convolution_compiles_and_lies_under_its_scope(chip, mosaic):
 
     from vitax.config import Config
     from vitax.models.ssm import MixerShape, SSDMixer
-    from vitax.ops.conv import make_conv_impl
+    from vitax.programs.kernels import choose_kernels
     one_chip, _ = chip
     cfg = Config(
         model_family="decoder", embed_dim=2048, num_blocks=1, vocab_rows=128,
@@ -419,7 +420,7 @@ def test_mixer_convolution_compiles_and_lies_under_its_scope(chip, mosaic):
         layer_mlps=["dense"], ffn_dim=128, ssm_heads=64, ssm_head_size=64,
         ssm_state_size=128, ssm_conv_width=4, ssm_groups=1, ssm_chunk=256,
         pack_tokens=4096, pack_images=4, batch_size=1).validate()
-    conv = make_conv_impl(cfg, None, force_tpu_kernels=True)
+    conv = choose_kernels(cfg, None, force_tpu_kernels=True).conv
     assert conv.vitax_name == ("fused kernel (256 channels a grid step in "
                                "blocks of 128 tokens)")
     mixer = SSDMixer(MixerShape(64, 64, 128, 4, 1, 256), 1e-5, jnp.bfloat16,

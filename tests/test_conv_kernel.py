@@ -5,7 +5,7 @@ silu, the padding's select and `l2norm` a head), which stays the oracle: y
 and the gradients of x, the taps and the bias over rows of two documents and
 padding, with and without a bias and the norm a head, a head of 128 lanes and
 one of 96 (four to three lane tiles); bfloat16 rounded where the plain form
-rounds it; which form `make_conv_impl` chooses, that a program traces each
+rounds it; which form `choose_kernels` chooses, that a program traces each
 kernel body once, and that the kernels are found by name under the scopes
 the mixers' metrics read."""
 
@@ -24,6 +24,8 @@ from tests.test_ssd_kernel import _every_equation, _kernel_name, gap
 from vitax.config import Config
 from vitax.models import ssm as plain
 from vitax.ops import conv as fused
+from vitax.programs import kernels as programs
+from vitax.programs.kernels import Kernels, choose_kernels, kernel_lines
 
 T = 64
 # two rows, each of two documents and padding (segment 0): 9 and 0 tokens
@@ -193,21 +195,26 @@ def test_shapes_the_kernel_cannot_tile_say_why(shape, why):
 
 def test_selection_by_backend_and_by_shape(monkeypatch):
     cfg = Config(**HYBRID).validate()
-    assert fused.make_conv_impl(cfg) is None            # the CPU, unforced
-    assert fused.conv_choice(cfg) == (None, "plain (no TPU)")
-    impl = fused.make_conv_impl(cfg, None, force_tpu_kernels=True)
+    assert choose_kernels(cfg).conv is None             # the CPU, unforced
+    assert kernel_lines(cfg, choose_kernels(cfg))[2] == (
+        "mixer convolution: plain (no TPU)")
+    impl = choose_kernels(cfg, None, force_tpu_kernels=True).conv
     assert impl.vitax_name == ("fused kernel (128 channels a grid step in "
                                "blocks of 32 tokens)")
     # a channel count that is no multiple of 128
     narrow = Config(**{**HYBRID, "ssm_heads": 8}).validate()
-    assert fused.make_conv_impl(narrow, None, force_tpu_kernels=True) is None
-    assert fused.conv_choice(narrow, True) == (
-        None, "plain (96 channels are no multiple of 128)")
+    chosen = choose_kernels(narrow, None, force_tpu_kernels=True)
+    assert chosen.conv is None
+    with monkeypatch.context() as on_the_chip:
+        on_the_chip.setattr(programs, "backend_platform", lambda: "tpu")
+        assert kernel_lines(narrow, chosen)[2] == (
+            "mixer convolution: plain (96 channels are no multiple of 128)")
     # no recurrent layer
     none = Config(**{**HYBRID, "layer_kinds": ["attention"] * 2,
                      "layer_heads": [4, 4]}).validate()
-    assert fused.make_conv_impl(none, None, force_tpu_kernels=True) is None
-    assert fused.conv_choice(none, True) == (None, "no recurrent layer")
+    chosen = choose_kernels(none, None, force_tpu_kernels=True)
+    assert chosen.conv is None
+    assert len(kernel_lines(none, chosen)) == 1         # the attention core's
     # the three cells': the widest lanes that divide the channels and hold
     # whole heads in whole lane tiles, blocks of 128 tokens
     from tests.test_hybrid_decoder import GRANITE
@@ -217,22 +224,25 @@ def test_selection_by_backend_and_by_shape(monkeypatch):
             (GRANITE, "256 channels a grid step in blocks of 128 tokens)"),
             (LING, "512 channels a grid step in blocks of 128 tokens)"),
             (OLMO, "384 channels a grid step in blocks of 128 tokens)")):
-        tilings, said = fused.conv_choice(Config(**cell).validate(), True)
-        assert said == "fused kernel (" + words and len(tilings) == 1
+        said = choose_kernels(Config(**cell).validate(), None,
+                              True).conv.vitax_name
+        assert said == "fused kernel (" + words
     monkeypatch.setattr(fused, "LANE_BLOCK", 256)
     assert fused.conv_tiling(6144, 4096, 4, (128, 4096, 2048)) == (256, 128)
-    assert "heads of 96" in fused.conv_choice(Config(**OLMO).validate(),
-                                               True)[1]
+    olmo = Config(**OLMO).validate()
+    assert choose_kernels(olmo, None, True).conv is None
+    monkeypatch.setattr(programs, "backend_platform", lambda: "tpu")
+    assert "heads of 96" in kernel_lines(olmo, Kernels())[-1]
 
 
 def test_on_a_mesh_the_rows_are_shared_out_and_nothing_else_changes():
-    """`make_conv_impl` on a mesh of two devices: the kernels under
+    """`choose_kernels` on a mesh of two devices: the kernels under
     `shard_map` over the batch axes, a row a device, the taps' gradient summed
     over them; y and every gradient are the unsharded kernels' (the taps' and
     the bias's to a sum's rounding)."""
     from vitax.parallel.mesh import build_mesh
     cfg = Config(**{**LATENT, "batch_size": 2, "pack_tokens": T}).validate()
-    impl = fused.make_conv_impl(cfg, build_mesh(cfg, jax.devices()[:2]), True)
+    impl = choose_kernels(cfg, build_mesh(cfg, jax.devices()[:2]), True).conv
     assert impl.vitax_name.endswith("blocks of 64 tokens) + shard_map")
     norm = (128, 512, 256)
     ops, weight = operands(768, 4, True, seed=3)
@@ -265,7 +275,7 @@ def _mixers(forced: bool):
     from vitax.models.kda import (GatedDeltaMixer, GatedDeltaShape, KDAMixer,
                                   KDAShape)
     hybrid = Config(**HYBRID).validate()
-    conv = fused.make_conv_impl(hybrid, None, True) if forced else None
+    conv = choose_kernels(hybrid, None, True).conv if forced else None
     return {
         "ssm_conv": plain.SSDMixer(
             plain.MixerShape(12, 8, 16, 4, 1, 8), 1e-5, jnp.float32,
@@ -333,7 +343,7 @@ def test_the_plain_mixers_have_no_kernel_and_the_model_the_text_it_had():
     from vitax.programs.builder import build_model_for
     cfg = Config(**HYBRID).validate()
     model = build_model_for(cfg, build_mesh(cfg, jax.devices()[:1]))
-    assert model.conv_impl is None
+    assert model.kernels.conv is None
     batch = decoder.sample_documents(cfg, 1)
     variables = jax.eval_shape(model.init, jax.random.key(0), batch, True)
 
@@ -355,8 +365,8 @@ def test_a_program_traces_each_kernel_body_once(monkeypatch):
     cfg = Config(**{**LATENT, "num_blocks": 3, "layer_mlps": ["dense"] * 3,
                     "layer_kinds": ["kda", "attention", "kda"],
                     "layer_heads": [2, 2, 2]}).validate()
-    model = decoder.build_decoder(
-        cfg, conv_impl=fused.make_conv_impl(cfg, None, True))
+    model = decoder.build_decoder(cfg, kernels=Kernels(
+        conv=choose_kernels(cfg, None, True).conv))
     assert model.grad_ckpt and len(model.runs()) == 3
     ran = collections.Counter()
     for name in ("_fwd_kernel", "_bwd_kernel"):

@@ -19,6 +19,7 @@ from vitax.config import Config
 from vitax.data.packing import document_layout, pack_documents
 from vitax.models import decoder
 from vitax.models.experts import SharedRoutedExperts
+from vitax.programs.kernels import Kernels
 
 KINDS = ["full_attention", "sliding_attention", "sliding_attention",
          "sliding_attention", "full_attention"]
@@ -230,7 +231,7 @@ def test_model_through_the_kernels_equals_the_dense_path():
     from vitax.ops.attention import make_attention_impl
     impl = make_attention_impl(cfg, None, force_tpu_kernels=True)
     assert "causal" in impl.vitax_name
-    through = decoder.build_decoder(cfg, attention_impl=impl)
+    through = decoder.build_decoder(cfg, kernels=Kernels(attention=impl))
     got, want = (jax.jit(lambda v, m=m: m.apply(v, batch, True))(variables)
                  for m in (through, dense))
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
@@ -242,7 +243,8 @@ def test_remat_keeps_o_and_lse_by_the_layers_span(monkeypatch):
     from vitax.models import vit
     cfg = Config(**{**TINY, "pack_tokens": 2048, "window_tokens": 512,
                     "dtype": "bfloat16"}).validate()
-    model = decoder.build_decoder(cfg, attention_impl=lambda *a: a[0])
+    model = decoder.build_decoder(
+        cfg, kernels=Kernels(attention=lambda *a: a[0]))
     assert model.span("full_attention") == 2048
     assert model.span("sliding_attention") == 512
     assert decoder.keeps_attention_residuals(model, "full_attention")

@@ -89,9 +89,9 @@ def test_the_model_through_the_convolutions_kernels_equals_the_plain_path():
     they tile), the scan plain either way: logits, loss and every leaf's
     gradient are the plain path's."""
     from tests.test_ssd_kernel import gap
-    from vitax.ops.conv import make_conv_impl
+    from vitax.programs.kernels import choose_kernels
     cfg = Config(**{**TINY, "ssm_heads": 12}).validate()
-    conv = make_conv_impl(cfg, None, force_tpu_kernels=True)
+    conv = choose_kernels(cfg, None, force_tpu_kernels=True).conv
     assert conv.vitax_name.startswith("fused kernel (128 channels")
     cases.check_conv_kernels_match_the_plain_path(
         cfg, conv, cases.make_batch(cfg, LENGTHS), gap)

@@ -14,6 +14,7 @@ import pytest
 
 from benchmark.reference import ling as reference
 from vitax.models import kda as K
+from vitax.ops.kda import KDA_EXP_RANGE
 
 BOUND = -5.0
 H, DK, DV = 2, 8, 8
@@ -156,7 +157,7 @@ def test_bfloat16_operands_stay_close_and_finite_at_the_bound():
     float32 (and bfloat16's range, which is the same)."""
     seg = segment_ids([[64], [40, 20]], tokens=64)
     args = inputs(seg, "at_the_bound", seed=3)
-    chunk, sub = K.tiling(64, BOUND)
+    chunk, sub = K.chunk_tiling(64, BOUND)
     assert (chunk, sub) == (64, 16)
     want = kda(*args, seg, chunk, sub, jnp.float32)
     q, k, v, g, beta = args
@@ -188,11 +189,11 @@ def test_unit_lower_inverse(c):
     (4096, -5.0, (64, 16)), (32, -5.0, (32, 16)), (96, -5.0, (32, 16)),
     (4096, -1.0, (64, 64)), (4096, -30.0, (64, 2)), (50, -5.0, (2, 2))])
 def test_tiling_follows_the_row_and_the_bound(tokens, bound, want):
-    chunk, sub = K.tiling(tokens, bound)
+    chunk, sub = K.chunk_tiling(tokens, bound)
     assert (chunk, sub) == want
     assert tokens % chunk == 0 and chunk % sub == 0
     # half a sub-chunk at the bound stays inside the range of an exponent
-    assert abs(bound) * sub / 2 <= K.KDA_EXP_RANGE or sub == 1
+    assert abs(bound) * sub / 2 <= KDA_EXP_RANGE or sub == 1
 
 
 def seeded_mixer(shape, u, seg, dtype=jnp.float32):

@@ -6,7 +6,7 @@ the gradients of q, k, v, g and beta, over layouts with a document boundary
 inside a chunk, a chunk wholly of padding, a document over several chunks and
 the gate at its bound; bfloat16 operands rounded where the plain form rounds
 them; the inverse alone and its closed-form cotangent; which form
-`make_kda_impl` chooses, and that the chosen kernels are found by name, under
+`choose_kernels` chooses, and that the chosen kernels are found by name, under
 the scope a metric reads, with no (chunk, chunk) array left outside them."""
 
 import functools
@@ -21,6 +21,8 @@ from tests.test_ssd_kernel import _every_equation, _kernel_name, gap
 from vitax.config import Config
 from vitax.models import kda as plain
 from vitax.ops import kda as fused
+from vitax.programs import kernels as programs
+from vitax.programs.kernels import Kernels, choose_kernels, kernel_lines
 
 BOUND = -5.0
 H, D = 2, 128
@@ -200,42 +202,49 @@ def test_shapes_the_kernel_cannot_tile_say_why(shape, why):
     (dict(kda_gate_bound=-30.0), "sub-chunks of 2"),
     (dict(pack_tokens=72), "sub-chunks of 8 in chunks of 8"),
 ])
-def test_configurations_the_kernel_cannot_tile_fall_back(change, why):
+def test_configurations_the_kernel_cannot_tile_fall_back(change, why,
+                                                         monkeypatch):
     cfg = Config(**{**LATENT, **change}).validate()
-    assert fused.make_kda_impl(cfg, None, force_tpu_kernels=True) is None
-    tiling, words = fused.kda_choice(cfg, force_tpu_kernels=True)
-    assert tiling is None and words.startswith("plain (") and why in words
+    chosen = choose_kernels(cfg, None, force_tpu_kernels=True)
+    assert chosen.rule is None
+    monkeypatch.setattr(programs, "backend_platform", lambda: "tpu")
+    words = kernel_lines(cfg, chosen)[1]     # as on the chip
+    assert words.startswith("delta rule: plain (") and why in words
 
 
 def test_selection_by_backend_and_by_shape(monkeypatch):
     cfg = Config(**LATENT).validate()
-    assert fused.make_kda_impl(cfg) is None             # the CPU, unforced
-    assert fused.kda_choice(cfg) == (None, "plain (no TPU)")
-    impl = fused.make_kda_impl(cfg, None, force_tpu_kernels=True)
+    assert choose_kernels(cfg).rule is None             # the CPU, unforced
+    assert kernel_lines(cfg, choose_kernels(cfg))[1] == (
+        "delta rule: plain (no TPU)")
+    impl = choose_kernels(cfg, None, force_tpu_kernels=True).rule
     assert impl.vitax_name == ("fused kernel (chunk 64, sub-chunks of 16, "
                                "2 heads a grid step)")
     ling = dict(layer_heads=[16, 16], pack_tokens=4096)
-    assert fused.kda_choice(Config(**{**LATENT, **ling}).validate(), True) == (
-        (64, 16, 16), "fused kernel (chunk 64, sub-chunks of 16, 16 heads a "
-        "grid step)")
+    assert fused.chunk_tiling(4096, BOUND) == (64, 16)
+    assert fused.kda_tiling(16, 128, 64, 16) == 16
+    assert choose_kernels(Config(**{**LATENT, **ling}).validate(), None,
+                          True).rule.vitax_name == (
+        "fused kernel (chunk 64, sub-chunks of 16, 16 heads a grid step)")
     # the most heads that divide the layer's, up to HEADS_PER_STEP
     assert fused.kda_tiling(12, 128, 64, 16) == 12
     assert fused.kda_tiling(40, 128, 64, 16) == 10
     monkeypatch.setattr(fused, "HEADS_PER_STEP", 4)
     assert fused.kda_tiling(16, 128, 64, 16) == 4
-    no_kda = dict(layer_kinds=["attention"] * 2, layer_heads=[4, 4],
-                  head_size=8)
-    assert fused.kda_choice(Config(**{**LATENT, **no_kda}).validate(),
-                            True) == (None, "no kda layer")
+    no_kda = Config(**{**LATENT, "layer_kinds": ["attention"] * 2,
+                       "layer_heads": [4, 4], "head_size": 8}).validate()
+    chosen = choose_kernels(no_kda, None, True)
+    assert chosen.rule is None and chosen.conv is None
+    assert len(kernel_lines(no_kda, chosen)) == 1       # the attention core's
 
 
 def test_on_a_mesh_the_rows_are_shared_out_and_nothing_else_changes():
-    """`make_kda_impl` on a mesh of two devices: the kernels under `shard_map`
+    """`choose_kernels` on a mesh of two devices: the kernels under `shard_map`
     over the batch axes, a row a device; o and every gradient are the
     unsharded kernels' to the bit."""
     from vitax.parallel.mesh import build_mesh
     cfg = Config(**{**LATENT, "batch_size": 2}).validate()
-    impl = fused.make_kda_impl(cfg, build_mesh(cfg, jax.devices()[:2]), True)
+    impl = choose_kernels(cfg, build_mesh(cfg, jax.devices()[:2]), True).rule
     assert impl.vitax_name.endswith("2 heads a grid step) + shard_map")
     lengths, tokens, chunk, sub = LAYOUTS["two_rows_chunks_of_64"]
     seg = segment_ids(lengths, tokens)
@@ -275,7 +284,7 @@ def test_the_plain_mixer_has_no_kernel_and_the_text_it_had():
     from vitax.programs.builder import build_model_for
     cfg = Config(**LATENT).validate()
     model = build_model_for(cfg, build_mesh(cfg, jax.devices()[:1]))
-    assert model.kda_impl is None
+    assert model.kernels.rule is None
     batch = decoder.sample_documents(cfg, 1)
     variables = jax.eval_shape(model.init, jax.random.key(0), batch, True)
 
@@ -292,7 +301,7 @@ def test_the_plain_mixer_has_no_kernel_and_the_text_it_had():
 
 def test_the_fused_mixer_keeps_every_chunk_product_inside_its_kernels():
     cfg = Config(**LATENT).validate()
-    grad, variables, u = _mixer(fused.make_kda_impl(cfg, None, True))
+    grad, variables, u = _mixer(choose_kernels(cfg, None, True).rule)
     equations = list(_every_equation(jax.make_jaxpr(grad)(variables, u).jaxpr))
     kernels = sorted({_kernel_name(e) for e, _ in equations
                       if e.primitive.name == "pallas_call"})
@@ -308,7 +317,7 @@ def test_the_scopes_a_metric_reads_are_in_the_lowered_fused_program():
     `kda_state`, which the fused form folds into it): the kernels and the
     cumsum beside them lie under it, forward and backward."""
     cfg = Config(**LATENT).validate()
-    grad, variables, u = _mixer(fused.make_kda_impl(cfg, None, True))
+    grad, variables, u = _mixer(choose_kernels(cfg, None, True).rule)
     text = jax.jit(grad).lower(variables, u).as_text(debug_info=True)
     for scope in ("kda_conv", "kda_gate", "kda_chunk", "kda_out_norm"):
         assert f"{scope}/" in text, scope
@@ -325,7 +334,7 @@ def test_the_fused_mixer_equals_the_plain_mixer():
     u = jax.random.normal(jax.random.key(1), (1, 2 * CHUNK, 32))
     w = jax.random.normal(jax.random.key(2), u.shape)
     mixers = [plain.KDAMixer(shape, 1e-5, jnp.float32, rule=rule)
-              for rule in (None, fused.make_kda_impl(cfg, None, True))]
+              for rule in (None, choose_kernels(cfg, None, True).rule)]
     variables = jax.jit(mixers[0].init)(jax.random.key(0), u, seg)
     want, got = (jax.jit(jax.value_and_grad(lambda v, u, m=m: jnp.sum(
         m.apply(v, u, seg) * w), argnums=(0, 1)))(variables, u)
@@ -350,8 +359,8 @@ def test_a_program_traces_each_kernel_body_once(monkeypatch):
     cfg = Config(**{**LATENT, "num_blocks": 3, "layer_mlps": ["dense"] * 3,
                     "layer_kinds": ["kda", "attention", "kda"],
                     "layer_heads": [2, 2, 2]}).validate()
-    model = decoder.build_decoder(
-        cfg, kda_impl=fused.make_kda_impl(cfg, None, force_tpu_kernels=True))
+    model = decoder.build_decoder(cfg, kernels=Kernels(
+        rule=choose_kernels(cfg, None, force_tpu_kernels=True).rule))
     assert model.grad_ckpt and len(model.runs()) == 3
     ran = collections.Counter()
     for name in ("_fwd_kernel", "_bwd_kernel"):

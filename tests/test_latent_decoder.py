@@ -17,6 +17,7 @@ from benchmark.reference import ling as reference
 from tests import decoder_cases as cases
 from vitax.config import Config
 from vitax.models import decoder
+from vitax.programs.kernels import Kernels
 
 # the cell's pattern: a dense kda layer, then one whole period
 KINDS = ["kda"] * 5 + ["latent_attention", "kda"]
@@ -126,7 +127,7 @@ def test_model_through_the_kernels_equals_the_dense_path(case):
     variables = case.variables      # no leaf's shape or seed knows the row
     from vitax.ops.attention import make_attention_impl
     impl = make_attention_impl(cfg, None, force_tpu_kernels=True)
-    through = decoder.build_decoder(cfg, attention_impl=impl)
+    through = decoder.build_decoder(cfg, kernels=Kernels(attention=impl))
     programs = [jax.jit(lambda v, m=m: m.apply(v, batch, True))
                 for m in (through, dense)]
     text = programs[0].lower(variables).as_text(debug_info=True)
@@ -142,10 +143,10 @@ def test_the_model_through_the_convolutions_kernels_equals_the_plain_path():
     rule plain either way: logits, loss and every leaf's gradient are the
     plain path's."""
     from tests.test_ssd_kernel import gap
-    from vitax.ops.conv import make_conv_impl
+    from vitax.programs.kernels import choose_kernels
     cfg = Config(**{**TINY, "head_size": 128,
                     "qk_rope_size": 64}).validate()
-    conv = make_conv_impl(cfg, None, force_tpu_kernels=True)
+    conv = choose_kernels(cfg, None, force_tpu_kernels=True).conv
     assert conv.vitax_name == ("fused kernel (384 channels a grid step in "
                                "blocks of 32 tokens)")
     cases.check_conv_kernels_match_the_plain_path(
@@ -155,7 +156,8 @@ def test_the_model_through_the_convolutions_kernels_equals_the_plain_path():
 def test_remat_keeps_o_and_lse_of_the_latent_layer_only():
     cfg = Config(**{**TINY, "pack_tokens": 2048,
                     "dtype": "bfloat16"}).validate()
-    model = decoder.build_decoder(cfg, attention_impl=lambda *a: a[0])
+    model = decoder.build_decoder(
+        cfg, kernels=Kernels(attention=lambda *a: a[0]))
     assert model.span("latent_attention") == 2048
     assert decoder.keeps_attention_residuals(model, "latent_attention")
     assert not decoder.keeps_attention_residuals(model, "kda")
@@ -203,8 +205,8 @@ def test_train_step_counters_against_a_layout_counted_by_hand():
     causal pairs 91 + 15 + 45 + 210 + 28; inside a chunk the same pairs (a
     row is one chunk), both chunks live. The slots routed here and the
     tokens that kept the held experts' group, over the six sparse layers."""
-    from vitax.models.kda import tiling
-    assert tiling(32, -5.0) == (32, 16)
+    from vitax.ops.kda import chunk_tiling
+    assert chunk_tiling(32, -5.0) == (32, 16)
     cfg = Config(**{**TINY, "warmup_steps": 1, "lr": 2e-3}).validate()
     _, state, step = cases.assembled(cfg)
     _, m, losses = cases.take_steps(
@@ -230,7 +232,7 @@ def test_train_step_counters_against_a_layout_counted_by_hand():
     # the cell's layout (ISSUE 41) on the counters' fixed grid of 64, which
     # is a constant of its own beside the chunk the program runs
     from vitax.models.kda import count_chunk
-    assert tiling(4096, -5.0) == (64, 16)
+    assert chunk_tiling(4096, -5.0) == (64, 16)
     assert (count_chunk(4096), count_chunk(32), flops_ling.KDA_GRID) \
         == (64, 32, 64)
     assert flops_ling.layout_counts([[2600, 900, 350, 150, 60]], 4096) \
@@ -247,7 +249,7 @@ def test_the_delta_rules_counters_do_not_follow_the_programs_chunk(
     counts on ITS constant (a perf_opt that changes KDA_CHUNK moves the time
     and not the need)."""
     from benchmark import flops_ling
-    from vitax.models import kda
+    from vitax.ops import kda
     from vitax.train.step import decoder_counts
     cfg = Config(**{**TINY, "pack_tokens": 256, "batch_size": 1}).validate()
     lengths = [[150, 56, 28, 6]]
@@ -259,9 +261,9 @@ def test_the_delta_rules_counters_do_not_follow_the_programs_chunk(
 
     want = flops_ling.layout_counts(lengths, 256)
     assert counted() == (want["kda_pairs"], want["kda_live_chunks"])
-    assert kda.tiling(256, -5.0)[0] == 64
+    assert kda.chunk_tiling(256, -5.0)[0] == 64
     monkeypatch.setattr(kda, "KDA_CHUNK", 32)
-    assert kda.tiling(256, -5.0)[0] == 32
+    assert kda.chunk_tiling(256, -5.0)[0] == 32
     assert counted() == (want["kda_pairs"], want["kda_live_chunks"])
     assert want["kda_live_chunks"] == 4 and want["kda_pairs"] < 240 * 65 / 2
 

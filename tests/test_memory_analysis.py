@@ -388,12 +388,7 @@ def test_10b_shape_lowers_under_pipeline_fsdp(devices8):
     stay far below the whole 40.3 GB parameter tensor. Guards the real
     hazard this test caught: XLA LICM hoisting the per-block gathers out of
     the layer scan, materializing the whole stage (28.7 GB vs 12.6 GB
-    temps). The 1F1B schedule is excluded HERE because this test compiles on
-    the CPU backend, where its per-block remat stays disabled (the jax-0.9
-    CPU compiler intermittently aborts on the rematted engine —
-    pipeline_1f1b.py `_remat_blocks`); the TPU-target proof of 1F1B's
-    GPipe-level temps is tools/aot_topology.py --configs 10b_1f1b
-    (AOT_TOPOLOGY.json), compiled against a v5p topology."""
+    temps)."""
     cfg = Config(image_size=224, patch_size=14, embed_dim=5120, num_heads=32,
                  num_blocks=32, num_classes=1000, batch_size=8,
                  warmup_steps=0, pp_size=2, fsdp_size=4, dp_size=1,

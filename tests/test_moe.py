@@ -148,50 +148,6 @@ def test_top2_second_choice_capacity_queue():
                                rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.parametrize("top_k", [1, 2])
-def test_gather_matches_einsum_oracle(top_k):
-    """The gather dispatch/combine (--moe_impl gather, the fast path) must
-    reproduce the GShard one-hot einsum oracle exactly: same outputs, same
-    grads w.r.t. params AND inputs, with real capacity drops in play
-    (capacity_factor 1.0 over a random router forces over-capacity tokens)."""
-    d, e, n, b = 16, 4, 24, 3
-    kw = dict(num_experts=e, hidden_dim=32, out_dim=d, top_k=top_k,
-              capacity_factor=1.0, dtype=jnp.float32)
-    moe_g = MoeMlp(impl="gather", **kw)
-    moe_e = MoeMlp(impl="einsum", **kw)
-    x = jax.random.normal(jax.random.key(11), (b, n, d), jnp.float32)
-    params = jax.jit(moe_g.init)(jax.random.key(12), x)
-
-    out_g = jax.jit(moe_g.apply)(params, x)
-    out_e = jax.jit(moe_e.apply)(params, x)
-    assert not np.allclose(np.asarray(out_g), 0.0)  # non-degenerate case
-    np.testing.assert_allclose(np.asarray(out_g), np.asarray(out_e),
-                               rtol=1e-6, atol=1e-6)
-
-    def loss(m, p, xx):
-        return jnp.sum(jnp.sin(m.apply(p, xx)))
-
-    (gp_g, gx_g), (gp_e, gx_e) = (
-        jax.jit(jax.grad(lambda p, xx, m=m: loss(m, p, xx), (0, 1)))(params, x)
-        for m in (moe_g, moe_e))
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6), gp_g, gp_e)
-    np.testing.assert_allclose(np.asarray(gx_g), np.asarray(gx_e),
-                               rtol=1e-5, atol=1e-6)
-
-
-def test_train_trajectory_gather_matches_einsum(devices8):
-    """Full train-step trajectories must be impl-invariant (the oracle
-    guarantee at the training level, mirroring the ep==dp mesh tests)."""
-    from tests.test_train_smoke import run_steps
-
-    _, losses_g = run_steps(moe_cfg(moe_impl="gather"), n_steps=3)
-    _, losses_e = run_steps(moe_cfg(moe_impl="einsum"), n_steps=3)
-    assert all(np.isfinite(losses_g))
-    np.testing.assert_allclose(losses_g, losses_e, rtol=2e-4)
-
-
 def test_top2_train_step_ep_matches_dp(devices8):
     """Top-2 trajectories must be mesh-invariant too (ep-sharded == dp)."""
     from tests.test_train_smoke import run_steps
@@ -263,13 +219,10 @@ def test_moe_config_validation():
         moe_cfg(moe_experts=0)
     with pytest.raises(AssertionError):  # experts % ep
         moe_cfg(moe_experts=3)
-    with pytest.raises(AssertionError):  # pp x ep needs the einsum impl
-        moe_cfg(ep_size=2, pp_size=2, fsdp_size=1, dp_size=2,
-                moe_impl="gather")
     # moe + pp with ep=1 is supported (v2: aux ingredients ride the pipeline)
     moe_cfg(ep_size=1, pp_size=2, fsdp_size=1, dp_size=4)
-    # moe + pp with ep>1 is supported under the einsum impl (v3: manual
-    # all-to-all dispatch inside the pipeline body)
+    # moe + pp with ep>1 is supported (v3: manual all-to-all dispatch inside
+    # the pipeline body)
     moe_cfg(ep_size=2, pp_size=2, fsdp_size=1, dp_size=2)
 
 

@@ -19,6 +19,7 @@ from tests.test_latent_decoder import LENGTHS
 from vitax.config import Config
 from vitax.models import decoder
 from vitax.models.kda import GatedDeltaMixer, GatedDeltaShape
+from vitax.programs.kernels import Kernels
 
 KINDS = ["linear_attention"] * 3 + ["full_attention"]
 TINY = dict(
@@ -165,10 +166,10 @@ def test_the_model_through_the_convolutions_kernels_equals_the_plain_path():
     four of 96 to three): logits, loss and every leaf's gradient are the
     plain path's."""
     from tests.test_ssd_kernel import gap
-    from vitax.ops.conv import make_conv_impl
+    from vitax.programs.kernels import choose_kernels
     cfg = Config(**{**TINY, "gdn_key_size": 32,
                     "gdn_value_size": 64}).validate()
-    conv = make_conv_impl(cfg, None, force_tpu_kernels=True)
+    conv = choose_kernels(cfg, None, force_tpu_kernels=True).conv
     assert conv.vitax_name == ("fused kernel (256 channels a grid step in "
                                "blocks of 32 tokens)")
     cases.check_conv_kernels_match_the_plain_path(
@@ -178,7 +179,8 @@ def test_the_model_through_the_convolutions_kernels_equals_the_plain_path():
 def test_remat_keeps_o_and_lse_of_the_attention_layer_only():
     cfg = Config(**{**TINY, "pack_tokens": 2048,
                     "dtype": "bfloat16"}).validate()
-    model = decoder.build_decoder(cfg, attention_impl=lambda *a: a[0])
+    model = decoder.build_decoder(
+        cfg, kernels=Kernels(attention=lambda *a: a[0]))
     assert decoder.keeps_attention_residuals(model, "full_attention")
     assert not decoder.keeps_attention_residuals(model, "linear_attention")
     assert decoder.run_remat_policy(model, "linear_attention") is None
@@ -338,7 +340,7 @@ def test_train_step_counters_and_the_first_steps_moments():
     batch = cases.make_batch(cfg, LENGTHS)
     geom, step, state, first = cases.check_first_steps_moments(
         train_gated_delta_packed, cfg, batch, clipped=True)
-    assert geom.model.kda_impl is None
+    assert geom.model.kernels.rule is None
     _, m, losses = cases.take_steps(step, state, batch, 3)
     losses.insert(0, float(first["loss"]))
     got = {k: float(m[k]) for k in (
@@ -425,12 +427,14 @@ def test_training_through_the_cli_path(tmp_path, capsys):
         assert "ssd_pairs" not in r
 
 
-def test_the_start_up_line_says_why_the_plain_rule_runs():
+def test_the_start_up_line_says_why_the_plain_rule_runs(monkeypatch):
     """On a TPU (here: forced) the 96 x 192 state under one decay a head is
     none the kernels tile: `plain (<why>)`, and no impl."""
-    from vitax.ops.kda import kda_choice, make_kda_impl
+    from vitax.programs import kernels as programs
     cfg = Config(**OLMO).validate()
-    tiling, words = kda_choice(cfg, force_tpu_kernels=True)
-    assert tiling is None and words.startswith("plain (a 96 x 192 state")
-    assert make_kda_impl(cfg, None, force_tpu_kernels=True) is None
-    assert kda_choice(cfg)[1] == "plain (no TPU)"
+    chosen = programs.choose_kernels(cfg, None, force_tpu_kernels=True)
+    assert chosen.rule is None
+    assert programs.kernel_lines(cfg, chosen)[1] == "delta rule: plain (no TPU)"
+    monkeypatch.setattr(programs, "backend_platform", lambda: "tpu")
+    assert programs.kernel_lines(cfg, chosen)[1].startswith(
+        "delta rule: plain (a 96 x 192 state")

@@ -294,33 +294,8 @@ def test_pp_dropout_deterministic_and_active(devices8):
     assert abs(l1 - l4) > 1e-7, "dropout under pp had no effect on the loss"
 
 
-@pytest.mark.parametrize("mesh_kw", [
-    dict(pp_size=2, dp_size=4),                 # pure dp x pp
-    dict(pp_size=2, dp_size=2, fsdp_size=2),    # ZeRO-3 inside the schedule
-])
-def test_pp_1f1b_matches_non_pp(devices8, mesh_kw):
-    """The 1F1B interleaved schedule (vitax/parallel/pipeline_1f1b.py) is a
-    hand-built fwd/bwd engine — per-mb loss at the last stage seeds the
-    backward in-tick, grads are assembled from vjp pieces with explicit
-    replica psums. Its trajectory must match the plain fsdp path exactly,
-    composing with ZeRO-3 gathers."""
-    from tests.test_train_smoke import run_steps
-
-    _, losses = run_steps(
-        pp_cfg(pp_schedule="1f1b", grad_ckpt=True, **mesh_kw), n_steps=4)
-    assert all(np.isfinite(losses))
-    np.testing.assert_allclose(losses, fsdp8_reference_losses(), rtol=2e-4)
-
-
-def test_pp_1f1b_validation():
-    with pytest.raises(AssertionError):  # dense/deterministic only (v1)
-        pp_cfg(pp_schedule="1f1b", mlp_dropout=0.1)
-    with pytest.raises(AssertionError):
-        pp_cfg(pp_schedule="1f1b", moe_experts=4, ep_size=1)
-    pp_cfg(pp_schedule="1f1b")  # dense config accepted
-    with pytest.raises(AssertionError):  # tp/sp ride gpipe only
-        pp_cfg(pp_schedule="1f1b", tp_size=2, dp_size=1)
-    with pytest.raises(AssertionError):  # MoE under pp is dp/fsdp-only
+def test_pp_moe_refuses_tp():
+    with pytest.raises(AssertionError):  # MoE under pp is dp/fsdp/ep-only
         pp_cfg(moe_experts=4, ep_size=1, tp_size=2, dp_size=1)
 
 

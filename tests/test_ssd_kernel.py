@@ -5,7 +5,7 @@ token-by-token recurrence of the plain reference (benchmark/reference/
 granite.py), y and the gradients of x, delta, A, B, C and D, over layouts
 whose boundaries fall inside a chunk, on a chunk's edge, over three chunks and
 more, before a padded tail and a chunk of padding only; bfloat16 operands
-rounded where the plain form rounds them; which form `make_scan_impl`
+rounded where the plain form rounds them; which form `choose_kernels`
 chooses, and that the chosen kernels are found by name with no (chunk,
 chunk) array left outside them."""
 
@@ -20,6 +20,8 @@ from vitax.config import Config
 from vitax.data.packing import document_layout
 from vitax.models.ssm import MixerShape, SSDMixer, ssd
 from vitax.ops import ssd as fused
+from vitax.programs import kernels as programs
+from vitax.programs.kernels import choose_kernels, kernel_lines
 
 CHUNK, STATE, HEAD = 128, 128, 64
 ROW = 512
@@ -175,27 +177,33 @@ HYBRID = dict(
     (dict(ssm_heads=512, ssm_groups=1, ssm_head_size=128, ssm_state_size=128),
      "do not fit VMEM"),
 ])
-def test_shapes_the_kernel_cannot_tile_fall_back(change, why):
+def test_shapes_the_kernel_cannot_tile_fall_back(change, why, monkeypatch):
     cfg = Config(**{**HYBRID, **change}).validate()
-    assert fused.make_scan_impl(cfg, None, force_tpu_kernels=True) is None
-    tiling, words = fused.scan_choice(cfg, force_tpu_kernels=True)
-    assert tiling is None and words.startswith("plain (") and why in words
+    chosen = choose_kernels(cfg, None, force_tpu_kernels=True)
+    assert chosen.scan is None
+    monkeypatch.setattr(programs, "backend_platform", lambda: "tpu")
+    words = kernel_lines(cfg, chosen)[1]     # as on the chip
+    assert words.startswith("state-space scan: plain (") and why in words
 
 
 def test_selection_by_backend_and_by_shape():
     cfg = Config(**HYBRID).validate()
-    assert fused.make_scan_impl(cfg) is None            # the CPU, unforced
-    assert fused.scan_choice(cfg) == (None, "plain (no TPU)")
-    impl = fused.make_scan_impl(cfg, None, force_tpu_kernels=True)
+    assert choose_kernels(cfg).scan is None             # the CPU, unforced
+    assert kernel_lines(cfg, choose_kernels(cfg))[1] == (
+        "state-space scan: plain (no TPU)")
+    impl = choose_kernels(cfg, None, force_tpu_kernels=True).scan
     assert impl.vitax_name == "fused kernel (chunk 128, 2 heads a grid step)"
     granite = dict(ssm_heads=64, ssm_head_size=64, ssm_state_size=128,
                    ssm_groups=1, ssm_chunk=256, pack_tokens=4096)
-    assert fused.scan_choice(Config(**{**HYBRID, **granite}).validate(),
-                             True) == (
-        (16, 2), "fused kernel (chunk 256, 16 heads a grid step)")
-    no_mamba = dict(layer_kinds=["attention"] * 2, layer_heads=[4, 4])
-    assert fused.scan_choice(Config(**{**HYBRID, **no_mamba}).validate(),
-                             True) == (None, "no mamba layer")
+    assert fused.scan_tiling(64, 64, 128, 1, 256) == (16, 2)
+    assert choose_kernels(Config(**{**HYBRID, **granite}).validate(), None,
+                          True).scan.vitax_name == (
+        "fused kernel (chunk 256, 16 heads a grid step)")
+    no_mamba = Config(**{**HYBRID, "layer_kinds": ["attention"] * 2,
+                         "layer_heads": [4, 4]}).validate()
+    chosen = choose_kernels(no_mamba, None, True)
+    assert chosen.scan is None and chosen.conv is None
+    assert len(kernel_lines(no_mamba, chosen)) == 1     # the attention core's
 
 
 def _every_equation(jaxpr, inside_kernel=False):
@@ -233,7 +241,7 @@ def test_the_plain_mixer_has_no_kernel_and_the_text_it_had():
     from vitax.programs.builder import build_model_for
     cfg = Config(**HYBRID).validate()
     model = build_model_for(cfg, build_mesh(cfg, jax.devices()[:1]))
-    assert model.scan_impl is None
+    assert model.kernels.scan is None
     batch = decoder.sample_documents(cfg, 1)
     variables = jax.eval_shape(model.init, jax.random.key(0), batch, True)
 
@@ -251,7 +259,7 @@ def test_the_plain_mixer_has_no_kernel_and_the_text_it_had():
 
 def test_the_fused_mixer_keeps_every_chunk_product_inside_its_kernels():
     cfg = Config(**HYBRID).validate()
-    impl = fused.make_scan_impl(cfg, None, force_tpu_kernels=True)
+    impl = choose_kernels(cfg, None, force_tpu_kernels=True).scan
     equations = list(_every_equation(_mixer_grad_jaxpr(impl)))
     kernels = sorted({_kernel_name(e) for e, _ in equations
                       if e.primitive.name == "pallas_call"})

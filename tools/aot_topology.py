@@ -155,15 +155,7 @@ CONFIGS = {
         image_size=224, patch_size=14, embed_dim=5120, num_heads=32,
         num_blocks=32, batch_size=1024, pp_size=2, fsdp_size=-1, dp_size=1,
         remat_policy="none_saveable")),
-    # the rematted 1F1B engine at the 10B shape (pp2 x fsdp4, the round-4
-    # "known scale limit" mesh) — compiling for a TPU target is exactly the
-    # proof the CPU-only abort kept us from having; temps should land at
-    # ~GPipe level, not the ~35 GB gathered-weight residuals
-    "10b_1f1b": ("v5p:2x2x2", dict(
-        image_size=224, patch_size=14, embed_dim=5120, num_heads=32,
-        num_blocks=32, batch_size=64, pp_size=2, fsdp_size=4, dp_size=1,
-        pp_schedule="1f1b", remat_policy="none_saveable")),
-    # GPipe on the same 8-chip topology — the like-for-like comparator
+    # GPipe at the 10B shape on 8 chips (pp2 x fsdp4)
     "10b_pp8": ("v5p:2x2x2", dict(
         image_size=224, patch_size=14, embed_dim=5120, num_heads=32,
         num_blocks=32, batch_size=64, pp_size=2, fsdp_size=4, dp_size=1,
@@ -206,12 +198,6 @@ KERNEL_CONFIGS = {
         image_size=224, patch_size=14, embed_dim=1024, num_heads=16,
         num_blocks=24, batch_size=64, att_dropout=0.1, fsdp_size=-1,
         remat_policy="none_saveable")),
-    # the rematted 1F1B engine with the production kernels in its stage
-    # body (vitax_local_impl) at the 10B shape
-    "10b_1f1b_kernels": ("v5p:2x2x2", dict(
-        image_size=224, patch_size=14, embed_dim=5120, num_heads=32,
-        num_blocks=32, batch_size=64, pp_size=2, fsdp_size=4, dp_size=1,
-        pp_schedule="1f1b", remat_policy="none_saveable")),
 }
 CONFIGS.update(KERNEL_CONFIGS)
 

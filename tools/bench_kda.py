@@ -21,7 +21,7 @@ holds the inverse alone: max |X (I + A) - I| of the kernel's float32 products
 on the chip, which a single bf16 pass would leave at 1e-3.
 
 `--scalar` adds the rule with ONE decay a head (Gated DeltaNet; the plain form
-only: no kernel tiles it, `vitax/ops/kda.py:kda_choice`) at the Olmo-Hybrid
+only: no kernel tiles it, `vitax/programs/kernels.py`) at the Olmo-Hybrid
 cell's shape and layout (benchmark/traffic/packed_1x4096_webmix.json: 15
 heads, a 96 x 192 state, chunks of 64, beta in (0, 2), an unbounded decay),
 over `INVERSE_BASE` of vitax/models/kda.py (the blocks the triangular inverse
@@ -139,13 +139,13 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
 
-    from vitax.models.kda import kda, tiling
+    from vitax.models.kda import kda
     from vitax.ops import kda as fused
 
     # compile_s is the compiler's time, not a read of the machine's cache
     jax.config.update("jax_enable_compilation_cache", False)
     seg, ops, w = operands()
-    chunk, sub = tiling(seg.shape[1], GATE_BOUND)
+    chunk, sub = fused.chunk_tiling(seg.shape[1], GATE_BOUND)
     dtype = jnp.bfloat16
 
     def programs(rule, seg=seg, w=w):
@@ -231,7 +231,7 @@ def scalar_rule(bases, programs, ms, report) -> None:
 
     from vitax.models import kda as plain
     seg, ops, w = operands(**SCALAR)
-    chunk = plain.tiling(seg.shape[1], GATE_BOUND)[0]
+    chunk = plain.chunk_tiling(seg.shape[1], GATE_BOUND)[0]
     exact = tuple(a.astype(jnp.float32) for a in ops)
     with jax.default_matmul_precision("highest"):
         truth = jax.block_until_ready(jax.jit(jax.value_and_grad(

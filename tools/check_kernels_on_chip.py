@@ -289,11 +289,11 @@ def check_delta_rule() -> bool:
     pass would leave 1e-3 from X (I + A) = I. Operands, distances and the
     inverse's kernel are tools/bench_kda.py's."""
     from tools import bench_kda as bench
-    from vitax.models.kda import kda, tiling
-    from vitax.ops.kda import kda_fused
+    from vitax.models.kda import kda
+    from vitax.ops.kda import chunk_tiling, kda_fused
 
     seg, ops, weight = bench.operands()
-    chunk, sub = tiling(seg.shape[1], bench.GATE_BOUND)
+    chunk, sub = chunk_tiling(seg.shape[1], bench.GATE_BOUND)
 
     def run(rule):
         def total(*a):

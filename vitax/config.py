@@ -235,15 +235,11 @@ class Config:
     sp_impl: str = "ring"               # ring (ppermute K/V rotation) | ulysses (all-to-all head<->token)
     pp_size: int = 1                    # pipeline stages (GPipe over the stacked layer axis; composes with dp and fsdp)
     pp_microbatches: int = 0            # GPipe microbatches per step (0 = pp_size; bubble = (S-1)/(M+S-1))
-    pp_schedule: str = "gpipe"          # gpipe (autodiff backward, O(M) live acts) | 1f1b (interleaved
-                                        #   fwd/bwd, O(S) live acts — enables large M)
     ep_size: int = 1                    # expert-parallel axis (also carries batch; experts sharded across it)
     moe_experts: int = 0                # 0 = dense reference MLP; >0 = top-1 MoE in every block
     moe_capacity_factor: float = 1.25   # static expert capacity C = ceil(cf * tokens / experts)
     moe_top_k: int = 1                  # 1 = Switch (top-1); 2 = GShard-style top-2 with renormalized gates
     moe_aux_weight: float = 0.01        # load-balance aux loss weight (Switch Transformer)
-    moe_impl: str = "einsum"            # einsum (GShard one-hot — measured fastest on v5e) | gather
-                                        #   (slot-index scatter + gathers; measured -23%, kept as the A/B arm)
     scan_blocks: bool = True            # lax.scan over stacked block params (one compile for L blocks)
     scan_unroll: int = 1                # blocks per scan step: >1 frees XLA to fuse across blocks
     #   (the scan's per-block dus-stacking constrains wgrad fusion layouts —
@@ -711,36 +707,17 @@ class Config:
             assert self.num_blocks % self.pp_size == 0, (
                 f"--num_blocks {self.num_blocks} not divisible by --pp_size {self.pp_size}")
             assert self.pp_microbatches >= 0
-            assert self.pp_schedule in ("gpipe", "1f1b"), self.pp_schedule
             if self.moe_experts > 0:
-                assert self.ep_size == 1 or self.moe_impl == "einsum", (
-                    "--moe_experts with --ep_size > 1 under --pp_size > 1 "
-                    "runs the manual all-to-all dispatch inside the pipeline "
-                    "body, which only the einsum impl implements "
-                    "(vitax/models/moe.py MoeMlp.ep_axis)")
                 assert self.tp_size == 1 and self.sp_size == 1, (
                     "--moe_experts under --pp_size > 1 composes with "
                     "dp/fsdp/ep only: the MoE dispatch einsums inside the "
                     "pipeline body are not exercised under auto-tp/sp meshes")
-            if self.pp_schedule == "1f1b":
-                assert max(self.pos_dropout, self.att_dropout,
-                           self.mlp_dropout) == 0.0 and self.moe_experts == 0, (
-                    "--pp_schedule 1f1b v1 is dense/deterministic only "
-                    "(dropout and MoE ride the gpipe schedule); the "
-                    "interleaved backward always recomputes the stage "
-                    "forward (none_saveable semantics)")
-                assert self.tp_size == 1 and self.sp_size == 1, (
-                    "--pp_schedule 1f1b runs a fully-manual shard_map "
-                    "engine; tp/sp under pp ride the gpipe schedule "
-                    "(GSPMD-auto axes in the pipeline body)")
         if self.ep_size > 1:
             assert self.moe_experts > 0, "--ep_size > 1 needs --moe_experts"
             assert self.moe_experts % self.ep_size == 0, (
                 f"--moe_experts {self.moe_experts} not divisible by "
                 f"--ep_size {self.ep_size}")
         if self.moe_experts > 0:
-            assert self.moe_impl in ("gather", "einsum"), (
-                f"unknown moe_impl {self.moe_impl!r}")
             assert self.moe_top_k in (1, 2), self.moe_top_k
             assert self.moe_top_k <= self.moe_experts, (
                 f"--moe_top_k {self.moe_top_k} > --moe_experts "
@@ -1128,15 +1105,11 @@ def build_parser() -> argparse.ArgumentParser:
                      choices=["ring", "ulysses"])
     ext.add_argument("--pp_size", type=int, default=1)
     ext.add_argument("--pp_microbatches", type=int, default=0)
-    ext.add_argument("--pp_schedule", type=str, default="gpipe",
-                     choices=["gpipe", "1f1b"])
     ext.add_argument("--ep_size", type=int, default=1)
     ext.add_argument("--moe_experts", type=int, default=0)
     ext.add_argument("--moe_capacity_factor", type=float, default=1.25)
     ext.add_argument("--moe_top_k", type=int, default=1, choices=[1, 2])
     ext.add_argument("--moe_aux_weight", type=float, default=0.01)
-    ext.add_argument("--moe_impl", type=str, default="einsum",
-                     choices=["gather", "einsum"])
     ext.add_argument("--no_scan_blocks", action="store_false", dest="scan_blocks")
     ext.add_argument("--scan_unroll", type=int, default=1)
     ext.add_argument("--remat_window", type=int, default=0)

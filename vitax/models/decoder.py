@@ -48,10 +48,11 @@ The second model family (`Config.model_family == "decoder"`), built by
 Layers differ in shape (heads by kind, dense or sparse), so one stacked
 `lax.scan` cannot hold them: consecutive layers of one shape form a RUN, each
 run is one `nn.scan` over its stacked parameters with per-block remat inside
-(as in vitax/models/vit.py), and the runs follow one another. The attention
-core comes from `make_attention_impl` (vitax/ops/attention.py) as
-`impl(q, k, v, segment_ids, window)`; None selects the dense masked path
-below.
+(as in vitax/models/vit.py), and the runs follow one another. The kernels
+come as ONE record, `kernels` (vitax/programs/kernels.py: `choose_kernels`;
+`attention`, `scan`, `rule`, `conv`), from the model down to its blocks; a
+member that is None, or no record, selects the plain `jax.numpy` form: the
+dense masked path below, `ssd`, `kda`, `conv_silu`.
 """
 
 from __future__ import annotations
@@ -306,15 +307,12 @@ class DecoderBlock(nn.Module):
     experts_per_token: int
     routed_scale: float
     dtype: Dtype = jnp.bfloat16
-    attention_impl: Optional[Callable] = None
+    kernels: Optional[Any] = None   # the Decoder's; None: every form plain
     token_sharding: Optional[Any] = None
     attention_scale: float = 0.0
     residual_multiplier: float = 1.0
     mixer: Optional[MixerShape] = None      # a mamba layer's
-    scan_impl: Optional[Callable] = None    # ... and its scan (None: plain)
     kda: Optional[Tuple[int, float]] = None     # a kda layer's taps and bound
-    kda_impl: Optional[Callable] = None     # ... and its delta rule (None: plain)
-    conv_impl: Optional[Callable] = None    # a recurrent mixer's convolution
     latent: Optional[LatentShape] = None    # a latent_attention layer's
     route: Tuple[int, int, bool] = (0, 0, False)    # groups, kept, bias
     # a linear_attention layer's key size, value size and taps
@@ -342,35 +340,36 @@ class DecoderBlock(nn.Module):
                  rope_window=None):
         kind, heads, mlp = self.shape
         sliding = kind == SLIDING
+        attention = getattr(self.kernels, "attention", None)
+        conv = getattr(self.kernels, "conv", None)
         if self.token_sharding is not None:
             x = jax.lax.with_sharding_constraint(x, self.token_sharding)
         y = self._normed(x, "norm1", True)
         if kind == MAMBA:
             y = SSDMixer(self.mixer, self.norm_eps, self.dtype,
-                         scan=self.scan_impl, conv=self.conv_impl,
+                         scan=getattr(self.kernels, "scan", None), conv=conv,
                          name="mixer")(y, segment_ids)
         elif kind == KDA:
             y = KDAMixer(KDAShape(heads, self.head_size, *self.kda),
-                         self.norm_eps, self.dtype, rule=self.kda_impl,
-                         conv=self.conv_impl,
+                         self.norm_eps, self.dtype,
+                         rule=getattr(self.kernels, "rule", None), conv=conv,
                          name="mixer")(y, segment_ids)
         elif kind == GATED_DELTA:
             y = GatedDeltaMixer(GatedDeltaShape(heads, *self.gated_delta),
-                                self.norm_eps, self.dtype,
-                                conv=self.conv_impl,
+                                self.norm_eps, self.dtype, conv=conv,
                                 name="mixer")(y, segment_ids)
         elif kind == LATENT:
             y = LatentAttention(
                 heads=heads, shape=self.latent, head_gate=self.head_gate,
                 norm_eps=self.norm_eps, dtype=self.dtype,
-                attention_impl=self.attention_impl, name="attn",
+                attention_impl=attention, name="attn",
             )(y, segment_ids, rope_full)
         else:
             y = DecoderAttention(
                 heads=heads, kv_heads=self.kv_heads, head_size=self.head_size,
                 window=self.window_tokens if sliding else 0,
                 head_gate=self.head_gate, dtype=self.dtype,
-                attention_impl=self.attention_impl,
+                attention_impl=attention,
                 scale=self.attention_scale,
                 qk_norm=self.norm_eps if self.qk_norm else 0.0, name="attn",
             )(y, segment_ids, rope_window if sliding else rope_full)
@@ -459,7 +458,7 @@ class Decoder(nn.Module):
     scan_unroll: int = 1
     grad_ckpt: bool = True
     remat_policy: str = "none_saveable"
-    attention_impl: Optional[Callable] = None
+    kernels: Optional[Any] = None   # vitax/programs/kernels.py: Kernels
     token_sharding: Optional[Any] = None
     rope: bool = True               # False: no layer rotates anything (NoPE)
     tie_embeddings: bool = False
@@ -468,15 +467,17 @@ class Decoder(nn.Module):
     attention_scale: float = 0.0    # 0 = head_size ** -0.5
     logits_scaling: float = 1.0
     mixer: Optional[MixerShape] = None
-    scan_impl: Optional[Callable] = None
     kda: Optional[Tuple[int, float]] = None
-    kda_impl: Optional[Callable] = None
-    conv_impl: Optional[Callable] = None
     latent: Optional[LatentShape] = None
     route: Tuple[int, int, bool] = (0, 0, False)
     gated_delta: Optional[Tuple[int, int, int]] = None
     norm_after: bool = False
     qk_norm: bool = False
+
+    @property
+    def attention_impl(self) -> Optional[Callable]:
+        """What the code shared with the ViT reads of a model."""
+        return getattr(self.kernels, "attention", None)
 
     def runs(self) -> List[Tuple[Tuple[str, int, str], int]]:
         return layer_runs(self.layer_kinds, self.layer_heads, self.layer_mlps)
@@ -526,13 +527,10 @@ class Decoder(nn.Module):
             experts_held=self.experts_held, expert_first=self.expert_first,
             experts_per_token=self.experts_per_token,
             routed_scale=self.routed_scale, dtype=self.dtype,
-            attention_impl=self.attention_impl,
-            token_sharding=self.token_sharding,
+            kernels=self.kernels, token_sharding=self.token_sharding,
             attention_scale=self.attention_scale,
             residual_multiplier=self.residual_multiplier, mixer=self.mixer,
-            scan_impl=self.scan_impl, kda=self.kda, kda_impl=self.kda_impl,
-            conv_impl=self.conv_impl,
-            latent=self.latent, route=self.route,
+            kda=self.kda, latent=self.latent, route=self.route,
             gated_delta=self.gated_delta, norm_after=self.norm_after,
             qk_norm=self.qk_norm)
         for i, (shape, length) in enumerate(self.runs()):
@@ -586,11 +584,7 @@ def run_remat_policy(model: Decoder, kind: str):
     return _REMAT_POLICIES[model.remat_policy]
 
 
-def build_decoder(cfg: Config, attention_impl: Optional[Callable] = None,
-                  token_sharding=None,
-                  scan_impl: Optional[Callable] = None,
-                  kda_impl: Optional[Callable] = None,
-                  conv_impl: Optional[Callable] = None) -> Decoder:
+def build_decoder(cfg: Config, kernels=None, token_sharding=None) -> Decoder:
     return Decoder(
         embed_dim=cfg.embed_dim, vocab_rows=cfg.vocab_rows,
         layer_kinds=cfg.layer_kinds, layer_heads=cfg.layer_heads,
@@ -610,17 +604,15 @@ def build_decoder(cfg: Config, attention_impl: Optional[Callable] = None,
         dtype=jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32,
         scan_blocks=cfg.scan_blocks, scan_unroll=cfg.scan_unroll,
         grad_ckpt=cfg.grad_ckpt, remat_policy=cfg.remat_policy,
-        attention_impl=attention_impl, token_sharding=token_sharding,
+        kernels=kernels, token_sharding=token_sharding,
         rope=cfg.position_embedding == "rope",
         tie_embeddings=cfg.tie_embeddings,
         embedding_multiplier=cfg.embedding_multiplier,
         residual_multiplier=cfg.residual_multiplier,
         attention_scale=cfg.attention_multiplier,
         logits_scaling=cfg.logits_scaling, mixer=mixer_shape(cfg),
-        scan_impl=scan_impl,
         kda=((cfg.kda_conv_width, cfg.kda_gate_bound)
              if KDA in cfg.layer_kinds else None),
-        kda_impl=kda_impl, conv_impl=conv_impl,
         latent=latent_shape(cfg),
         route=(cfg.route_groups, cfg.groups_per_token, cfg.route_bias),
         gated_delta=((cfg.gdn_key_size, cfg.gdn_value_size,
@@ -637,6 +629,23 @@ def mixer_shape(cfg: Config) -> Optional[MixerShape]:
         heads=cfg.ssm_heads, head_size=cfg.ssm_head_size,
         state_size=cfg.ssm_state_size, conv_width=cfg.ssm_conv_width,
         groups=cfg.ssm_groups, chunk=cfg.ssm_chunk)
+
+
+def delta_shape(cfg: Config, kind: str, heads: int):
+    """The shape of a kda or a linear_attention layer's mixer."""
+    if kind == KDA:
+        return KDAShape(heads, cfg.head_size, cfg.kda_conv_width,
+                        cfg.kda_gate_bound)
+    return GatedDeltaShape(heads, cfg.gdn_key_size, cfg.gdn_value_size,
+                           cfg.gdn_conv_width)
+
+
+def delta_shapes(cfg: Config) -> list:
+    """One `delta_shape` a kind and number of heads the model has, the kda
+    layers' first."""
+    return [delta_shape(cfg, kind, n) for kind in (KDA, GATED_DELTA)
+            for n in sorted({n for k, n in zip(
+                cfg.layer_kinds, cfg.layer_heads) if k == kind})]
 
 
 def latent_shape(cfg: Config) -> Optional[LatentShape]:
@@ -664,12 +673,9 @@ def expected_param_count(cfg: Config) -> int:
         if kind == MAMBA:
             total += mixer_param_count(mixer_shape(cfg), d)
         elif kind == KDA:
-            total += kda_param_count(KDAShape(
-                heads, dh, cfg.kda_conv_width, cfg.kda_gate_bound), d)
+            total += kda_param_count(delta_shape(cfg, kind, heads), d)
         elif kind == GATED_DELTA:
-            total += gated_delta_param_count(GatedDeltaShape(
-                heads, cfg.gdn_key_size, cfg.gdn_value_size,
-                cfg.gdn_conv_width), d)
+            total += gated_delta_param_count(delta_shape(cfg, kind, heads), d)
         elif kind == LATENT:
             s = latent_shape(cfg)
             total += (d * heads * (s.nope + s.rope) + d * (s.rank + s.rope)

@@ -32,7 +32,7 @@ channels takes e^{G_t - m} on the query's side and e^{m - G_s} on the key's,
 with m the running sum at the middle of the query's SUB-chunk of `sub`
 tokens; both exponents then stay within |L| * sub / 2 (a key of an earlier
 sub-chunk has a negative one). That is why the gate is bounded, and `sub`
-follows from the bound (`tiling`). The unit lower-triangular (I + A) is
+follows from the bound (`chunk_tiling`). The unit lower-triangular (I + A) is
 inverted as the nilpotent product (I - A)(I + A^2)(I + A^4)... in float32:
 matmuls, no scalar loop (scope `kda_chunk`). One float32 state a head goes
 from chunk to chunk in a `lax.scan` that solves U for the chunk, reads S_0
@@ -58,7 +58,7 @@ g, G, every exp and every state are float32; the products take operands of
 the model's dtype and accumulate in float32, as `ssd` does. This plain
 `jax.numpy` form is the CPU's path and the tests' oracle; on a TPU, where the
 shapes tile, `KDAMixer.rule` is the fused kernel pair of vitax/ops/kda.py
-(`make_kda_impl`, through `build_model_for` as the scan of a mamba layer is),
+(`choose_kernels`, through `build_model_for` as the scan of a mamba layer is),
 which keeps a chunk's decayed keys, scores and inverse in VMEM. Here the
 per-chunk part is made `KDA_BLOCK_BYTES` worth of chunks at a time and again
 in the backward (`jax.checkpoint`), as `ssd`'s is.
@@ -76,15 +76,14 @@ import jax.numpy as jnp
 from vitax.models.ssm import (Leaf, a_log_init, conv_init, conv_silu,
                               dt_bias_init)
 from vitax.models.vit import Array, Dtype, default_init
+from vitax.ops.kda import KDA_CHUNK, chunk_tiling
 
-KDA_CHUNK = 64          # tokens a chunk, where the row's length allows
 # The grid the step's counters `kda_pairs` and `kda_live_chunks` count on
 # (vitax/train/step.py: decoder_counts), and so the grid of the delta rule's
 # useful FLOPs (vitax/telemetry/flops.py). A constant of its own and NOT
-# `tiling`'s choice: a change of KDA_CHUNK shows in the step's time, not in
-# what the step is said to need.
+# `chunk_tiling`'s choice: a change of KDA_CHUNK shows in the step's time, not
+# in what the step is said to need.
 KDA_COUNT_CHUNK = 64
-KDA_EXP_RANGE = 40.0    # the largest |exponent| a product's operand may take
 # float32 bytes of the (rows, chunks, sub-chunks, chunk, heads, head_size)
 # key-side decay that a block of chunks may hold
 KDA_BLOCK_BYTES = 256 * 2 ** 20
@@ -99,6 +98,13 @@ class KDAShape(NamedTuple):
     @property
     def inner(self) -> int:
         return self.heads * self.head_size
+
+    @property
+    def conv(self) -> Tuple[int, int, Tuple[int, int, int]]:
+        """The convolution's channels and taps, and the norm behind it: a
+        head's size, the channels normed (q and k), those scaled too (q)."""
+        return (3 * self.inner, self.conv_width,
+                (self.head_size, 2 * self.inner, self.inner))
 
 
 def kda_param_count(shape: KDAShape, embed_dim: int) -> int:
@@ -119,6 +125,12 @@ class GatedDeltaShape(NamedTuple):
     def inner(self) -> int:         # q, k and v behind one convolution
         return self.heads * (2 * self.key_size + self.value_size)
 
+    @property
+    def conv(self) -> Tuple[int, int, Tuple[int, int, int]]:
+        """As `KDAShape.conv`, the norm over a head's key."""
+        wide = self.heads * self.key_size
+        return self.inner, self.conv_width, (self.key_size, 2 * wide, wide)
+
 
 def gated_delta_param_count(shape: GatedDeltaShape, embed_dim: int) -> int:
     """W_q, W_k, W_v; the convolution; W_a, W_b, A_log, dt_bias; W_z, the
@@ -127,18 +139,6 @@ def gated_delta_param_count(shape: GatedDeltaShape, embed_dim: int) -> int:
     return (embed_dim * shape.inner + shape.conv_width * shape.inner
             + 2 * embed_dim * shape.heads + 2 * shape.heads
             + embed_dim * wide + shape.value_size + wide * embed_dim)
-
-
-def tiling(tokens: int, gate_bound: float) -> Tuple[int, int]:
-    """(chunk, sub) for rows of `tokens`: the longest chunk up to KDA_CHUNK
-    that divides the row, and the longest power-of-two sub-chunk over which
-    |gate_bound| * sub / 2 stays within KDA_EXP_RANGE."""
-    chunk = math.gcd(tokens, KDA_CHUNK)
-    sub = 1
-    while (sub * 2 <= chunk and chunk % (sub * 2) == 0
-           and abs(gate_bound) * sub <= KDA_EXP_RANGE):
-        sub *= 2
-    return chunk, sub
 
 
 def count_chunk(tokens: int) -> int:
@@ -385,8 +385,7 @@ class KDAMixer(nn.Module):
             taps = Leaf((s.conv_width, 3 * s.inner), conv_init, "kernel",
                         name="conv")()
             qkv = (self.conv or conv_silu)(
-                qkv, segment_ids, taps, None, self.dtype,
-                (dh, 2 * s.inner, s.inner))
+                qkv, segment_ids, taps, None, self.dtype, s.conv[2])
             q, k, v = (x.reshape(r, t, h, dh)
                        for x in jnp.split(qkv, 3, axis=-1))
 
@@ -401,7 +400,7 @@ class KDAMixer(nn.Module):
                 linear(h, "wb")(u).astype(f32)), 0.0)
 
         o = (self.rule or kda)(q, k, v, g, beta, segment_ids,
-                               *tiling(t, s.gate_bound), self.dtype)
+                               *chunk_tiling(t, s.gate_bound), self.dtype)
 
         with jax.named_scope("kda_out_norm"):
             scale = Leaf((dh,), nn.initializers.ones, name="out_norm")()
@@ -454,8 +453,7 @@ class GatedDeltaMixer(nn.Module):
             taps = Leaf((s.conv_width, s.inner), conv_init, "kernel",
                         name="conv")()
             qkv = (self.conv or conv_silu)(
-                qkv, segment_ids, taps, None, self.dtype,
-                (dk, 2 * h * dk, h * dk))
+                qkv, segment_ids, taps, None, self.dtype, s.conv[2])
             q, k, v = jnp.split(qkv, [h * dk, 2 * h * dk], axis=-1)
             q, k = q.reshape(r, t, h, dk), k.reshape(r, t, h, dk)
             v = v.reshape(r, t, h, dv)

@@ -20,19 +20,13 @@ Design choices (Switch Transformer, arXiv:2101.03961):
   into the "intermediates" collection and added to the CE loss with weight
   --moe_aux_weight (vitax/train/step.py).
 
-Two dispatch/combine implementations (--moe_impl), MEASURED round 5:
-- "einsum" (default): the GShard (B, N, E, C) one-hot form. The round-4
-  profile blamed b16_moe's MFU gap (0.329 vs dense 0.490) on this band, but
-  the gather alternative measured SLOWER on v5e — the one-hot matmuls map
-  onto the MXU; TPU batched row-gathers/scatters do not. Round 5 builds the
-  combine tensor directly in the activation dtype (identical numerics —
-  disjoint top-2 slots never accumulate — at half the HBM bytes).
-- "gather": integer scatter builds a per-slot source-token index (B, E*C),
-  dispatch/combine are take_along_axis gathers, no (B, N, E, C) tensor
-  exists. Measured b16_moe 477-527 img/s vs einsum's 617-650 across two
-  layouts (round 5; ROADMAP A3) — kept as the A/B arm and
-  mutual oracle (tests/test_moe.py asserts gather == einsum on values and
-  grads; trajectory tests pin both).
+Dispatch and combine are the GShard (B, N, E, C) one-hot einsums, the combine
+tensor built directly in the activation dtype (identical numerics: disjoint
+top-2 slots never accumulate; half the HBM bytes). A second arm, an integer
+scatter of slot indices with `take_along_axis` gathers and no (B, N, E, C)
+tensor, measured 477-527 img/s on b16_moe against the einsums' 617-650 on v5e
+(round 5: the one-hot matmuls map onto the MXU, batched row gathers do not)
+and is gone with its `--moe_impl gather` switch (PR 47).
 """
 
 from __future__ import annotations
@@ -57,7 +51,6 @@ class MoeMlp(nn.Module):
     out_dim: int
     capacity_factor: float = 1.25
     top_k: int = 1                  # 1 = Switch; 2 = GShard-style top-2
-    impl: str = "einsum"            # "einsum" (default) | "gather" (A/B arm)
     # manual expert parallelism (the pipeline body, where every batch axis is
     # already manual inside jax.shard_map and GSPMD cannot see the einsums):
     # ep_axis names the mesh axis; expert params are declared at their LOCAL
@@ -133,7 +126,7 @@ class MoeMlp(nn.Module):
 
         if self.top_k == 1:
             slot1, keep1 = slots_of(onehot1, 0.0)
-            choices = [(gate1, keep1, expert1, onehot1, slot1)]
+            choices = [(gate1, keep1, onehot1, slot1)]
         else:
             assert self.top_k == 2, self.top_k
             probs2 = probs * (1.0 - onehot1)          # mask the first choice
@@ -147,8 +140,8 @@ class MoeMlp(nn.Module):
             # second choices queue behind every first choice of that expert
             count1 = jnp.sum(onehot1, axis=1, keepdims=True)    # (B, 1, E)
             slot2, keep2 = slots_of(onehot2, count1)
-            choices = [(g1, keep1, expert1, onehot1, slot1),
-                       (g2, keep2, expert2, onehot2, slot2)]
+            choices = [(g1, keep1, onehot1, slot1),
+                       (g2, keep2, onehot2, slot2)]
 
         manual_ep = self.ep_axis is not None and self.ep_size > 1
         e_p = e // self.ep_size if manual_ep else e  # local expert shard
@@ -157,112 +150,31 @@ class MoeMlp(nn.Module):
         w2 = self.param("w2", default_init, (e_p, self.hidden_dim, self.out_dim), jnp.float32)
         b2 = self.param("b2", nn.initializers.zeros, (e_p, self.out_dim), jnp.float32)
 
-        if self.impl == "gather":
-            assert not manual_ep, (
-                "--moe_impl gather does not implement the manual ep "
-                "all-to-alls (pipeline body); use the einsum default "
-                "(config.validate enforces this)")
-            # the gather path stays in token-major (B, E, C, D) layout end
-            # to end — a physical (E, B, C, D) transpose measured SLOWER
-            # than the einsum oracle it was meant to beat (b16_moe 527 vs
-            # 617 img/s on v5e); the expert einsums batch over B with the
-            # expert dim in the middle instead
-            xe = self._dispatch_gather(x, choices, e, c)        # (B, E, C, D)
-            if self.dispatch_sharding is not None:
-                xe = jax.lax.with_sharding_constraint(
-                    xe, self._becd_sharding())
-            h = jnp.einsum("becd,edh->bech", xe, w1.astype(self.dtype))
-            h = h + b1.astype(self.dtype)[None, :, None, :]
-            h = nn.gelu(h, approximate=False)
-            ye = jnp.einsum("bech,eho->beco", h, w2.astype(self.dtype))
-            ye = ye + b2.astype(self.dtype)[None, :, None, :]   # (B, E, C, D)
-            if self.dispatch_sharding is not None:
-                ye = jax.lax.with_sharding_constraint(
-                    ye, self._becd_sharding())
-            out = self._combine_gather(ye, choices, e, c)
-        else:
-            assert self.impl == "einsum", self.impl
-            combine = sum(combine_of(g, k, oh, s)
-                          for g, k, _, oh, s in choices)        # (B, N, E, C)
-            dispatch = (combine > 0).astype(self.dtype)
-            # dispatch -> per-expert batches (GShard einsum form)
-            xe = jnp.einsum("bnec,bnd->ebcd", dispatch,
-                            x.astype(self.dtype))               # (E, B, C, D)
-            if self.dispatch_sharding is not None:
-                xe = jax.lax.with_sharding_constraint(xe, self.dispatch_sharding)
-            if manual_ep:
-                # each shard dispatched its LOCAL batch to all E experts;
-                # keep this shard's E/ep experts for the whole group's
-                # batches: (E, B, C, D) -> (E/ep, B*ep, C, D)
-                xe = jax.lax.all_to_all(xe, self.ep_axis, 0, 1, tiled=True)
-            h = jnp.einsum("ebcd,edh->ebch", xe, w1.astype(self.dtype))
-            h = h + b1.astype(self.dtype)[:, None, None, :]
-            h = nn.gelu(h, approximate=False)
-            ye = jnp.einsum("ebch,eho->ebco", h, w2.astype(self.dtype))
-            ye = ye + b2.astype(self.dtype)[:, None, None, :]
-            if manual_ep:
-                # inverse exchange: back to (E, B, C, D) in original batch
-                # order (autodiff transposes each a2a into its inverse)
-                ye = jax.lax.all_to_all(ye, self.ep_axis, 1, 0, tiled=True)
-            if self.dispatch_sharding is not None:
-                ye = jax.lax.with_sharding_constraint(ye, self.dispatch_sharding)
-            out = jnp.einsum("bnec,ebcd->bnd", combine, ye)
+        combine = sum(combine_of(g, k, oh, s)
+                      for g, k, oh, s in choices)               # (B, N, E, C)
+        dispatch = (combine > 0).astype(self.dtype)
+        # dispatch -> per-expert batches (GShard einsum form)
+        xe = jnp.einsum("bnec,bnd->ebcd", dispatch,
+                        x.astype(self.dtype))                   # (E, B, C, D)
+        if self.dispatch_sharding is not None:
+            xe = jax.lax.with_sharding_constraint(xe, self.dispatch_sharding)
+        if manual_ep:
+            # each shard dispatched its LOCAL batch to all E experts; keep
+            # this shard's E/ep experts for the whole group's batches:
+            # (E, B, C, D) -> (E/ep, B*ep, C, D)
+            xe = jax.lax.all_to_all(xe, self.ep_axis, 0, 1, tiled=True)
+        h = jnp.einsum("ebcd,edh->ebch", xe, w1.astype(self.dtype))
+        h = h + b1.astype(self.dtype)[:, None, None, :]
+        h = nn.gelu(h, approximate=False)
+        ye = jnp.einsum("ebch,eho->ebco", h, w2.astype(self.dtype))
+        ye = ye + b2.astype(self.dtype)[:, None, None, :]
+        if manual_ep:
+            # inverse exchange: back to (E, B, C, D) in original batch order
+            # (autodiff transposes each a2a into its inverse)
+            ye = jax.lax.all_to_all(ye, self.ep_axis, 1, 0, tiled=True)
+        if self.dispatch_sharding is not None:
+            ye = jax.lax.with_sharding_constraint(ye, self.dispatch_sharding)
+        out = jnp.einsum("bnec,ebcd->bnd", combine, ye)
         if self.token_sharding is not None:
             out = jax.lax.with_sharding_constraint(out, self.token_sharding)
-        return out
-
-    def _becd_sharding(self):
-        """dispatch_sharding is declared for the (E, B, C, D) einsum layout
-        (P("ep"|None, batch, None, None)); the gather path's (B, E, C, D)
-        layout swaps the first two entries."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        ds = self.dispatch_sharding
-        return NamedSharding(ds.mesh, P(ds.spec[1], ds.spec[0],
-                                        *ds.spec[2:]))
-
-    # --- gather-based dispatch/combine ------------------------------------
-    # A token's (expert, slot) pair is unique, so "which token fills slot
-    # (e, c)" is a permutation fragment: scatter token indices (int32, no
-    # feature dim) into a (B, E*C) source map, then move the D-wide data
-    # with gathers. The backward of take_along_axis is a scatter-add over
-    # the same unique indices — no (B, N, E, C) tensor in either direction.
-
-    def _slot_ids(self, choices, e, c, n):
-        """Per-choice flattened slot id (B, N): expert*C + slot for kept
-        tokens; a unique out-of-range sentinel (E*C + token) for dropped
-        ones so scatters can use mode="drop" + unique_indices soundly."""
-        tok = jnp.arange(n, dtype=jnp.int32)[None, :]
-        out = []
-        for gate, keep, expert, _, slot in choices:
-            flat = expert.astype(jnp.int32) * c + slot
-            out.append((jnp.where(keep, flat, e * c + tok), gate, keep))
-        return out
-
-    def _dispatch_gather(self, x, choices, e, c):
-        b, n, d = x.shape
-        bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
-        tok = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None, :], (b, n))
-        src = jnp.full((b, e * c), n, jnp.int32)
-        for flat, _, _ in self._slot_ids(choices, e, c, n):
-            # top-2 first/second choices occupy disjoint slots (the count1
-            # offset), so sequential scatters never collide
-            src = src.at[bidx, flat].set(tok, mode="drop", unique_indices=True)
-        valid = src < n                                         # (B, E*C)
-        xe = jnp.take_along_axis(x.astype(self.dtype),
-                                 jnp.where(valid, src, 0)[:, :, None], axis=1)
-        xe = jnp.where(valid[:, :, None], xe, jnp.zeros((), self.dtype))
-        return xe.reshape(b, e, c, d)                           # (B, E, C, D)
-
-    def _combine_gather(self, ye, choices, e, c):
-        b = ye.shape[0]
-        n = choices[0][0].shape[1]
-        ye_flat = ye.reshape(b, e * c, ye.shape[-1])            # (B, E*C, D)
-        out = jnp.zeros((b, n, ye.shape[-1]), self.dtype)
-        for flat, gate, keep in self._slot_ids(choices, e, c, n):
-            # dropped tokens carry an out-of-range sentinel: clamp the index
-            # and zero the contribution through the keep-masked gate (the
-            # einsum oracle's combine tensor is exactly gate*keep one-hot)
-            y = jnp.take_along_axis(
-                ye_flat, jnp.where(keep, flat, 0)[:, :, None], axis=1)
-            out = out + (gate * keep).astype(self.dtype)[:, :, None] * y
         return out

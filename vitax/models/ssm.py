@@ -32,7 +32,7 @@ delta, A, the running sums and every state are float32; the products over x,
 B and C take operands of the model's dtype and accumulate in float32.
 
 Two forms of the scan, one algorithm, chosen by what the code can see
-(vitax/ops/ssd.py: `make_scan_impl`, through `build_model_for` as the
+(vitax/programs/kernels.py: `choose_kernels`, through `build_model_for` as the
 attention core is; `SSDMixer.scan`):
 
 - the fused kernels `ssd_fwd` / `ssd_bwd` (vitax/ops/ssd.py: `ssd_fused`) on
@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 
 from vitax.models.vit import Array, Dtype, default_init
+from vitax.ops.conv import L2_EPS
 
 # float32 bytes of one (rows, chunks, heads, chunk, chunk) intermediate that a
 # block of chunks may hold; several are alive in a block's backward
@@ -84,6 +85,11 @@ class MixerShape(NamedTuple):
     def projected(self) -> int:
         """Outputs of the in-projection: z, xBC and dt."""
         return self.inner + self.conv_channels + self.heads
+
+    @property
+    def conv(self) -> Tuple[int, int, None]:
+        """The convolution's channels and taps, and no norm behind it."""
+        return self.conv_channels, self.conv_width, None
 
 
 def mixer_param_count(shape: MixerShape, embed_dim: int) -> int:
@@ -157,9 +163,6 @@ def causal_conv(x: Array, segment_ids: Array, kernel: Array,
     return y + bias
 
 
-L2_EPS = 1e-6           # a delta mixer's q and k: x * rsqrt(sum x^2 + eps)
-
-
 def l2norm(x: Array) -> Array:
     return x * jax.lax.rsqrt(
         jnp.sum(jnp.square(x), axis=-1, keepdims=True) + L2_EPS)
@@ -175,7 +178,7 @@ def conv_silu(x: Array, segment_ids: Array, kernel: Array,
     mixer's k and q); float32 up to the one rounding to `dtype`. The plain
     form: the CPU's path and the oracle of the kernel pair of
     vitax/ops/conv.py, which takes these arguments (`SSDMixer.conv`,
-    `make_conv_impl`)."""
+    `choose_kernels`)."""
     y = causal_conv(x, segment_ids, kernel, 0.0 if bias is None else bias)
     y = jnp.where((segment_ids > 0)[..., None], jax.nn.silu(y), 0.0)
     if norm is not None:

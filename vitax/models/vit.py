@@ -440,7 +440,6 @@ class Block(nn.Module):
     moe_experts: int = 0
     moe_capacity_factor: float = 1.25
     moe_top_k: int = 1
-    moe_impl: str = "einsum"
     moe_ep_axis: Optional[str] = None   # manual-ep (pipeline body) only
     moe_ep_size: int = 1
     moe_dispatch_sharding: Optional[Any] = None
@@ -492,7 +491,6 @@ class Block(nn.Module):
                 out_dim=d,
                 capacity_factor=self.moe_capacity_factor,
                 top_k=self.moe_top_k,
-                impl=self.moe_impl,
                 ep_axis=self.moe_ep_axis,
                 ep_size=self.moe_ep_size,
                 dtype=self.dtype,
@@ -563,7 +561,6 @@ class VisionTransformer(nn.Module):
     moe_experts: int = 0
     moe_capacity_factor: float = 1.25
     moe_top_k: int = 1
-    moe_impl: str = "einsum"
     moe_ep_axis: Optional[str] = None   # manual-ep (pipeline body) only
     moe_ep_size: int = 1
     moe_dispatch_sharding: Optional[Any] = None
@@ -597,7 +594,6 @@ class VisionTransformer(nn.Module):
             moe_experts=self.moe_experts,
             moe_capacity_factor=self.moe_capacity_factor,
             moe_top_k=self.moe_top_k,
-            moe_impl=self.moe_impl,
             moe_ep_axis=self.moe_ep_axis,
             moe_ep_size=self.moe_ep_size,
             moe_dispatch_sharding=self.moe_dispatch_sharding,
@@ -1025,7 +1021,6 @@ def build_model(cfg: Config, attention_impl: Optional[Callable] = None,
         moe_experts=cfg.moe_experts,
         moe_capacity_factor=cfg.moe_capacity_factor,
         moe_top_k=cfg.moe_top_k,
-        moe_impl=cfg.moe_impl,
         moe_dispatch_sharding=moe_dispatch_sharding,
         token_sharding=token_sharding,
         quant_matmul=quant_matmul,

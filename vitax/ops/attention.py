@@ -39,23 +39,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
+from vitax.ops.common import interpret as _interpret
 from vitax.parallel.mesh import BATCH_AXES, shard_map
 from vitax.platform import backend_platform
 
 MAX_SEQ_IN_VMEM = 2048  # (N, N) f32 scores: 16 MB at 2048 — VMEM ceiling
-
-
-def _interpret() -> bool:
-    # run the kernels in Pallas interpret mode off-TPU (tests on CPU).
-    # VITAX_FORCE_MOSAIC=1 overrides: emit REAL Mosaic kernels regardless of
-    # the host backend — for AOT compiles against TPU topology targets
-    # (tools/aot_topology.py), where the host is CPU but the compile target
-    # is a TPU and interpret-mode lowering would silently swap the
-    # production kernels out of the program being proven.
-    import os
-    if os.environ.get("VITAX_FORCE_MOSAIC"):
-        return False
-    return backend_platform() != "tpu"
 
 
 def reference_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:

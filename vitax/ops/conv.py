@@ -36,14 +36,12 @@ compare and select, and keeps their float32 intermediates for its backward.
   are sums over the tokens of a grid step, so they stay inside it.
 
 Each `pl.pallas_call` sits under a `jax.jit` of its own and the rules trace
-under the primal's context (`vitax/ops/kda.py:_one_trace_context`): a process
+under the primal's context (`vitax/ops/common.py:one_trace_context`): a process
 traces each body once, however many layers, remats and programs call it.
 
 `conv_tiling` says whether a convolution's shapes tile (channels a multiple of
 128, tokens of 16, whole normed heads in a grid step's whole lane tiles, a
-grid step within `VMEM_BYTES`); `make_conv_impl` chooses this form on a TPU
-(or forced: interpret mode on the CPU) where they do, and the plain form
-otherwise.
+grid step within `VMEM_BYTES`).
 """
 
 from __future__ import annotations
@@ -57,15 +55,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.sharding import Mesh, PartitionSpec as P
 
-from vitax.models.ssm import L2_EPS
-from vitax.ops.attention import _interpret
-from vitax.ops.kda import _one_trace_context, _thirds
-from vitax.ops.ssd import LANES, VMEM_LIMIT, f32
-from vitax.parallel.mesh import BATCH_AXES, shard_map
-from vitax.platform import backend_platform
+from vitax.ops.common import (LANES, compiler_params, f32, interpret,
+                              one_trace_context, thirds)
 
+L2_EPS = 1e-6       # a delta mixer's q and k: x * rsqrt(sum x^2 + eps)
 HALO = 16           # rows a window keeps beside its block: a bfloat16 tile's
 ROW_BLOCK = 128     # tokens through the window at a time, at most
 LANE_BLOCK = 512    # channels a grid step, at most
@@ -103,12 +97,6 @@ def conv_tiling(channels: int, tokens: int, taps: int,
     if not wide:
         return "a grid step's whole token axis does not fit VMEM"
     return max(wide), rows
-
-
-def _params():
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"),
-        vmem_limit_bytes=VMEM_LIMIT)
 
 
 def _fill_masks(seg_ref, masks, segw, taps: int, rows: int):
@@ -163,7 +151,7 @@ def _heads_sum(parts, ind):
     total = None
     for i, p in enumerate(parts):
         held = ind[i * LANES:(i + 1) * LANES, :]
-        for third in _thirds(p):
+        for third in thirds(p):
             part = jnp.dot(third, held, preferred_element_type=f32)
             total = part if total is None else total + part
     return total
@@ -371,7 +359,8 @@ def _conv_forward(x, seg, kernel, bias, lanes, rows, norm, out_dtype,
             out_specs=s["tile"],
             scratch_shapes=_scratch(t, taps, lanes, rows, norm)),
         out_shape=jax.ShapeDtypeStruct(x.shape, out_dtype),
-        compiler_params=_params(), name="conv_silu_fwd", interpret=interpret,
+        compiler_params=compiler_params("parallel", "arbitrary"),
+        name="conv_silu_fwd", interpret=interpret,
     )(*operands)
 
 
@@ -397,7 +386,8 @@ def _conv_backward(x, seg, kernel, bias, dy, lanes, rows, norm, interpret):
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
                    jax.ShapeDtypeStruct((r, taps, c), f32)]
         + [jax.ShapeDtypeStruct((r, 1, c), f32)] * has_bias,
-        compiler_params=_params(), name="conv_silu_bwd", interpret=interpret,
+        compiler_params=compiler_params("parallel", "arbitrary"),
+        name="conv_silu_bwd", interpret=interpret,
     )(*operands)
     return (dx, jnp.sum(dk, axis=0),
             jnp.sum(db[0], axis=0) if has_bias else None)
@@ -405,13 +395,13 @@ def _conv_backward(x, seg, kernel, bias, dy, lanes, rows, norm, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _conv(x, seg, kernel, bias, lanes, rows, norm, out_dtype, interpret):
-    with _one_trace_context():
+    with one_trace_context():
         return _conv_forward(x, seg, kernel, bias, lanes, rows, norm,
                              out_dtype, interpret)
 
 
 def _rule_fwd(x, seg, kernel, bias, lanes, rows, norm, out_dtype, interpret):
-    with _one_trace_context():
+    with one_trace_context():
         y = _conv_forward(x, seg, kernel, bias, lanes, rows, norm, out_dtype,
                           interpret)
     return y, (x, seg, kernel, bias)
@@ -419,7 +409,7 @@ def _rule_fwd(x, seg, kernel, bias, lanes, rows, norm, out_dtype, interpret):
 
 def _rule_bwd(lanes, rows, norm, out_dtype, interpret, res, dy):
     x, seg, kernel, bias = res
-    with _one_trace_context():
+    with one_trace_context():
         dx, dk, db = _conv_backward(x, seg, kernel, bias,
                                     dy.astype(out_dtype), lanes, rows, norm,
                                     interpret)
@@ -444,72 +434,4 @@ def conv_silu(x, segment_ids, kernel, bias, dtype,
     return _conv(x, segment_ids.astype(jnp.int32)[..., None],
                  kernel.astype(f32),
                  None if bias is None else bias.astype(f32)[None], *tiling,
-                 norm, dtype, _interpret())
-
-
-def make_conv_impl(cfg, mesh: Optional[Mesh] = None,
-                   force_tpu_kernels: bool = False):
-    """Choose the recurrent mixers' convolution for this config and mesh, as
-    `make_scan_impl` chooses the mamba layers' scan: `conv_silu` on a TPU
-    (`force_tpu_kernels`: off it too, interpret mode on the CPU) where every
-    recurrent layer's shapes tile, shard_map-wrapped over the batch axes on a
-    mesh of several devices; None (the plain `conv_silu` of
-    vitax/models/ssm.py) otherwise. The start-up line prints the impl's
-    `vitax_name`, or `conv_choice`'s words where it is None."""
-    tilings, words = conv_choice(cfg, force_tpu_kernels)
-    if tilings is None:
-        return None
-    sharded = mesh is not None and mesh.size > 1
-
-    def impl(x, segment_ids, kernel, bias, dtype, norm=None):
-        def kernel_of(x, segment_ids, kernel, *bias):
-            return conv_silu(x, segment_ids, kernel, *(bias or (None,)),
-                             dtype, norm)
-        operands = (x, segment_ids, kernel) + (
-            () if bias is None else (bias,))
-        if sharded:
-            rows = P(BATCH_AXES)
-            kernel_of = shard_map(
-                kernel_of, mesh=mesh,
-                in_specs=(rows, rows) + (P(),) * (len(operands) - 2),
-                out_specs=rows, check_vma=False)
-        return kernel_of(*operands)
-    impl.vitax_name = words + (" + shard_map" if sharded else "")
-    return impl
-
-
-def conv_shapes(cfg):
-    """(channels, taps, norm) of each kind of recurrent layer the config has,
-    as its mixer calls the convolution."""
-    from vitax.models.decoder import mixer_shape
-    mixer = mixer_shape(cfg)
-    shapes = [] if mixer is None else [
-        (mixer.conv_channels, mixer.conv_width, None)]
-    for kind, taps, key, value in (
-            ("kda", cfg.kda_conv_width, cfg.head_size, cfg.head_size),
-            ("linear_attention", cfg.gdn_conv_width, cfg.gdn_key_size,
-             cfg.gdn_value_size)):
-        for n in sorted({n for k, n in zip(cfg.layer_kinds, cfg.layer_heads)
-                         if k == kind}):
-            shapes.append((n * (2 * key + value), taps,
-                           (key, 2 * n * key, n * key)))
-    return shapes
-
-
-def conv_choice(cfg, force_tpu_kernels: bool = False):
-    """(the kernels' tiling a kind of recurrent layer, or None where the
-    plain form runs; the start-up line's words)."""
-    shapes = conv_shapes(cfg)
-    if not shapes:
-        return None, "no recurrent layer"
-    if not (force_tpu_kernels or backend_platform() == "tpu"):
-        return None, "plain (no TPU)"
-    tilings = [conv_tiling(channels, cfg.pack_tokens, taps, norm,
-                           2 if cfg.dtype == "bfloat16" else 4)
-               for channels, taps, norm in shapes]
-    for tiling in tilings:
-        if isinstance(tiling, str):
-            return None, f"plain ({tiling})"
-    return tilings, "fused kernel (" + ", ".join(
-        f"{lanes} channels a grid step in blocks of {rows} tokens"
-        for lanes, rows in tilings) + ")"
+                 norm, dtype, interpret())

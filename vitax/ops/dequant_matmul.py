@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from vitax.ops.attention import _interpret
+from vitax.ops.common import interpret as _interpret
 
 # the pallas_call `name=`: the jaxpr marker VTX-R009 greps for (one
 # occurrence per launch) and the custom call's op_name in compiled HLO

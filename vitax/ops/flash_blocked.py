@@ -33,8 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from vitax.ops.attention import (_from_bh, _interpret, _to_bh,
-                                 dropout_keep_mask)
+from vitax.ops.attention import _from_bh, _to_bh, dropout_keep_mask
+from vitax.ops.common import interpret as _interpret
 
 NEG_INF = -1e30  # large-but-finite: avoids inf-inf=nan in max/exp chains
 

@@ -54,7 +54,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from vitax.ops.attention import _interpret
+from vitax.ops.common import interpret as _interpret
 from vitax.parallel.mesh import shard_map
 
 PyTree = Any

@@ -53,28 +53,26 @@ the jitted layouts cost a run of one layer).
 
 `kda_tiling` says whether a mixer's shapes tile (head size a multiple of 128,
 sub-chunks of whole sublane tiles, all heads' states within
-`STATE_VMEM_BYTES`); `make_kda_impl` chooses this form on a TPU (or forced:
-interpret mode on the CPU) where they do, and the plain form otherwise.
+`STATE_VMEM_BYTES`).
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional, Tuple, Union
+from typing import Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.sharding import Mesh, PartitionSpec as P
 
-from vitax.ops.attention import _interpret
-from vitax.ops.ssd import _NT, _TN, LANES, _params, f32
-from vitax.parallel.mesh import BATCH_AXES, shard_map
-from vitax.platform import backend_platform
+from vitax.ops.common import (LANES, NT, TN, compiler_params, f32, interpret,
+                              one_trace_context, thirds)
 
+KDA_CHUNK = 64          # tokens a chunk, where the row's length allows
+KDA_EXP_RANGE = 40.0    # the largest |exponent| a product's operand may take
 SUBLANES = 16                           # of the model dtype's (16, 128) tile
 # at most; unrolled in the body, in step. tools/bench_kda.py on the chip, the
 # Ling cell's shape, forward / forward + backward: 16 heads 0.88 / 2.13 ms,
@@ -83,6 +81,19 @@ SUBLANES = 16                           # of the model dtype's (16, 128) tile
 HEADS_PER_STEP = 16
 STATE_VMEM_BYTES = 16 * 2 ** 20         # all heads' (K, K) float32 states
 _HI = jax.lax.Precision.HIGHEST
+
+
+def chunk_tiling(tokens: int, gate_bound: float) -> Tuple[int, int]:
+    """(chunk, sub) for rows of `tokens`, of both forms of the rule: the
+    longest chunk up to KDA_CHUNK that divides the row, and the longest
+    power-of-two sub-chunk over which |gate_bound| * sub / 2 stays within
+    KDA_EXP_RANGE."""
+    chunk = math.gcd(tokens, KDA_CHUNK)
+    sub = 1
+    while (sub * 2 <= chunk and chunk % (sub * 2) == 0
+           and abs(gate_bound) * sub <= KDA_EXP_RANGE):
+        sub *= 2
+    return chunk, sub
 
 
 def kda_tiling(heads: int, head_size: int, chunk: int,
@@ -106,14 +117,6 @@ def _dot(a, b, dims=None, precision=None):
                                precision=precision)
 
 
-def _thirds(x):
-    """float32 x as three bfloat16 terms, hi + mid + lo = x to 24 bits."""
-    hi = x.astype(jnp.bfloat16)
-    rest = x - hi.astype(f32)
-    mid = rest.astype(jnp.bfloat16)
-    return hi, mid, (rest - mid.astype(f32)).astype(jnp.bfloat16)
-
-
 def _dot_full(a2, b2):
     """a @ b in float32 with full-precision products, for a (m, c) and b
     (c, c) each given twice along the lanes, [a | a] and [b | b], and so
@@ -122,8 +125,8 @@ def _dot_full(a2, b2):
     contractions 2 c deep, a whole lane tile where c is 64, in place of six c
     deep: [a_hi | a_hi] and [a_mid | a_mid] against [b_hi; b_mid], and
     [a_hi | a_lo] against [b_lo; b_hi]."""
-    a_hi, a_mid, a_lo = _thirds(a2)
-    b_hi, b_mid, b_lo = _thirds(b2)
+    a_hi, a_mid, a_lo = thirds(a2)
+    b_hi, b_mid, b_lo = thirds(b2)
     first = jax.lax.broadcasted_iota(
         jnp.int32, (1, a2.shape[1]), 1) < a2.shape[1] // 2
     high = jnp.concatenate([b_hi, b_mid])
@@ -157,8 +160,8 @@ def unit_lower_inverse_vjps(xs, dxs):
     """The cotangent of each `a` where x = (I + a)^-1 and dx is x's:
     -x^T dx x^T, float32 with full-precision products, the matrices in step;
     the caller keeps the strictly lower part."""
-    firsts = [_dot(x, dx, _TN, _HI) for x, dx in zip(xs, dxs)]
-    return [-_dot(t, x, _NT, _HI) for t, x in zip(firsts, xs)]
+    firsts = [_dot(x, dx, TN, _HI) for x, dx in zip(xs, dxs)]
+    return [-_dot(t, x, NT, _HI) for t, x in zip(firsts, xs)]
 
 
 def unit_lower_inverse_vjp(x, dx):
@@ -207,7 +210,7 @@ class _Chunk:
             self.col.append(col)
             self.keys.append(keys)
         self.subs = subs
-        both = [_dot(jnp.concatenate([self.rq[s], self.rk[s]]), keys, _NT)
+        both = [_dot(jnp.concatenate([self.rq[s], self.rk[s]]), keys, NT)
                 for s, keys in zip(subs, self.keys)]        # (2 sub, c) each
         lower, strict = masks
         self.qk = jnp.where(lower, jnp.concatenate(
@@ -240,7 +243,7 @@ class _Chunk:
     def corrected(self, given, dtype):
         """u (c, V) in the model's dtype, from the transposed state the chunk
         began with, (V, K) in the model's dtype."""
-        return (self.u0 - _dot(self.w.astype(dtype), given, _NT)).astype(dtype)
+        return (self.u0 - _dot(self.w.astype(dtype), given, NT)).astype(dtype)
 
 
 def _head_chunks(refs, hb, width, at, sub, masks):
@@ -286,9 +289,9 @@ def _fwd_kernel(last_ref, owner_ref, live_ref, q_ref, k_ref, v_ref, run_ref,
         began = [state[j, i] for i in range(hb)]            # (V, K) float32
         given = [b.astype(dtype) for b in began]
         u = [ch.corrected(g, dtype) for ch, g in zip(chunks, given)]
-        read = [_dot(ch.q_start, g, _NT) for ch, g in zip(chunks, given)]
+        read = [_dot(ch.q_start, g, NT) for ch, g in zip(chunks, given)]
         within = [_dot(ch.qk.astype(dtype), x) for ch, x in zip(chunks, u)]
-        left = [_dot(x, ch.k_end, _TN) for ch, x in zip(chunks, u)]
+        left = [_dot(x, ch.k_end, TN) for ch, x in zip(chunks, u)]
         for i, ch in enumerate(chunks):
             given_ref[0, 0, i] = began[i]
             o_ref[0, :, i * width:(i + 1) * width] = read[i] + within[i]
@@ -344,23 +347,23 @@ def _bwd_kernel(last_ref, owner_ref, live_ref, q_ref, k_ref, v_ref, run_ref,
         left = [dstate[j, i] for i in heads]                # dS', (V, K)
         left_d = cast(left)
         # o = q_start S + qk u;  S' = through S + k_end^T u
-        du = [_dot(qk[i], do[i], _TN) for i in heads]
-        du = cast(du[i] + _dot(ch[i].k_end, left_d[i], _NT) for i in heads)
-        dqk = [jnp.where(lower, _dot(do[i], u[i], _NT), 0.0) for i in heads]
+        du = [_dot(qk[i], do[i], TN) for i in heads]
+        du = cast(du[i] + _dot(ch[i].k_end, left_d[i], NT) for i in heads)
+        dqk = [jnp.where(lower, _dot(do[i], u[i], NT), 0.0) for i in heads]
         dq_start = [rounded(_dot(do[i], given[i])) for i in heads]  # (c, K)
         dk_end = [rounded(_dot(u[i], left_d[i])) for i in heads]
         # u = u0 - w S
-        dgiven = [_dot(do[i], ch[i].q_start, _TN) for i in heads]
-        dgiven = [rounded(dgiven[i] - _dot(du[i], w[i], _TN)) for i in heads]
+        dgiven = [_dot(do[i], ch[i].q_start, TN) for i in heads]
+        dgiven = [rounded(dgiven[i] - _dot(du[i], w[i], TN)) for i in heads]
         dw = cast(-_dot(du[i], given[i]) for i in heads)            # (c, K)
         for i in heads:
             dstate[j, i] = left[i] * ch[i].through + dgiven[i]
         # w = X kb, u0 = X vb, X = (I + beta kk)^-1
         solve = cast(ch[i].x for i in heads)
-        dx = [_dot(dw[i], ch[i].kb, _NT) for i in heads]
-        dx = [rounded(dx[i] + _dot(du[i], ch[i].vb, _NT)) for i in heads]
-        dkb = [rounded(_dot(solve[i], dw[i], _TN)) for i in heads]
-        dvb = [rounded(_dot(solve[i], du[i], _TN)) for i in heads]
+        dx = [_dot(dw[i], ch[i].kb, NT) for i in heads]
+        dx = [rounded(dx[i] + _dot(du[i], ch[i].vb, NT)) for i in heads]
+        dkb = [rounded(_dot(solve[i], dw[i], TN)) for i in heads]
+        dvb = [rounded(_dot(solve[i], du[i], TN)) for i in heads]
         da = [jnp.where(strict, m, 0.0) for m in unit_lower_inverse_vjps(
             [ch[i].x for i in heads], dx)]
         dkk = cast(da[i] * ch[i].beta for i in heads)
@@ -373,7 +376,7 @@ def _bwd_kernel(last_ref, owner_ref, live_ref, q_ref, k_ref, v_ref, run_ref,
                 drows[i].append(_dot(d[i], ch[i].keys[a]))  # (2 sub, K)
             for i in heads:
                 by_sub[i].append(rounded(_dot(d[i], jnp.concatenate(
-                    [ch[i].rq[s], ch[i].rk[s]]), _TN)) * ch[i].col[a])
+                    [ch[i].rq[s], ch[i].rk[s]]), TN)) * ch[i].col[a])
         for i in heads:
             lanes = slice(i * width, (i + 1) * width)
             h = ch[i]
@@ -466,7 +469,8 @@ def _forward(q, k, v, run, beta, seg, chunk, sub, hb, interpret):
             scratch_shapes=[pltpu.VMEM((h // hb, hb, width, width), f32)]),
         out_shape=[jax.ShapeDtypeStruct((r, t, h * width), f32),
                    jax.ShapeDtypeStruct((r, nc, h, width, width), f32)],
-        compiler_params=_params(), name="kda_fwd", interpret=interpret,
+        compiler_params=compiler_params("parallel", "arbitrary", "arbitrary"),
+        name="kda_fwd", interpret=interpret,
     )(*scalars, q, k, v, run, *extra)
 
 
@@ -491,27 +495,20 @@ def _backward(q, k, v, run, beta, seg, given, do, chunk, sub, hb, interpret):
                    jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct(run.shape, f32),
                    jax.ShapeDtypeStruct((r, h // hb, t, hb), f32)],
-        compiler_params=_params(), name="kda_bwd", interpret=interpret,
+        compiler_params=compiler_params("parallel", "arbitrary", "arbitrary"),
+        name="kda_bwd", interpret=interpret,
     )(*scalars, q, k, v, run, *extra, do, given)
     return dq, dk, dv, drun, dbeta.transpose(0, 2, 1, 3).reshape(r, t, h)
 
 
-def _one_trace_context():
-    """The abstract mesh the call is traced under, set to itself. JAX traces a
-    `custom_vjp`'s rules under an empty abstract mesh where the primal's
-    context has none; the two mean the same and key `jax.jit`'s cache of
-    traces apart, so that a step would trace `_forward`'s body twice."""
-    return jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh())
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
 def _delta_rule(q, k, v, run, beta, seg, chunk, sub, hb, interpret):
-    with jax.named_scope("kda_chunk"), _one_trace_context():
+    with jax.named_scope("kda_chunk"), one_trace_context():
         return _forward(q, k, v, run, beta, seg, chunk, sub, hb, interpret)[0]
 
 
 def _delta_rule_fwd(q, k, v, run, beta, seg, chunk, sub, hb, interpret):
-    with jax.named_scope("kda_chunk"), _one_trace_context():
+    with jax.named_scope("kda_chunk"), one_trace_context():
         o, given = _forward(q, k, v, run, beta, seg, chunk, sub, hb,
                             interpret)
     return o, (q, k, v, run, beta, seg, given)
@@ -519,7 +516,7 @@ def _delta_rule_fwd(q, k, v, run, beta, seg, chunk, sub, hb, interpret):
 
 def _delta_rule_bwd(chunk, sub, hb, interpret, res, do):
     q, k, v, run, beta, seg, given = res
-    with jax.named_scope("kda_chunk"), _one_trace_context():
+    with jax.named_scope("kda_chunk"), one_trace_context():
         grads = _backward(q, k, v, run, beta, seg, given, do, chunk, sub, hb,
                           interpret)
     return (*grads, np.zeros(seg.shape, jax.dtypes.float0))
@@ -543,56 +540,5 @@ def kda_fused(q, k, v, g, beta, segment_ids, chunk: int, sub: int, dtype):
     o = _delta_rule(*(x.reshape(r, t, h * width).astype(dtype)
                       for x in (q, k, v)), run.reshape(r, t, h * width),
                     beta.astype(f32), segment_ids.astype(jnp.int32), chunk,
-                    sub, hb, _interpret())
+                    sub, hb, interpret())
     return o.reshape(r, t, h, width)
-
-
-def make_kda_impl(cfg, mesh: Optional[Mesh] = None,
-                  force_tpu_kernels: bool = False):
-    """Choose the kda layers' delta rule for this config and mesh, as
-    `make_scan_impl` chooses the mamba layers' scan: `kda_fused` on a TPU
-    (`force_tpu_kernels`: off it too, interpret mode on the CPU) where the
-    mixer's shapes tile, shard_map-wrapped over the batch axes on a mesh of
-    several devices; None (the plain `kda`) otherwise. The start-up line
-    prints the impl's `vitax_name`, or `kda_choice`'s words where it is None."""
-    tiling, words = kda_choice(cfg, force_tpu_kernels)
-    if tiling is None:
-        return None
-    sharded = mesh is not None and mesh.size > 1
-
-    def impl(q, k, v, g, beta, segment_ids, chunk, sub, dtype):
-        kernel = functools.partial(kda_fused, chunk=chunk, sub=sub,
-                                   dtype=dtype)
-        if sharded:
-            rows = P(BATCH_AXES)
-            kernel = shard_map(kernel, mesh=mesh, in_specs=(rows,) * 6,
-                               out_specs=rows, check_vma=False)
-        return kernel(q, k, v, g, beta, segment_ids)
-    impl.vitax_name = words + (" + shard_map" if sharded else "")
-    return impl
-
-
-def kda_choice(cfg, force_tpu_kernels: bool = False
-               ) -> Tuple[Optional[Tuple[int, int, int]], str]:
-    """((chunk, sub, heads a grid step) of the kernels, or None where the
-    plain form runs; the start-up line's words)."""
-    from vitax.models.kda import tiling
-    heads = sorted({n for kind, n in zip(cfg.layer_kinds, cfg.layer_heads)
-                    if kind == "kda"})
-    gated = "linear_attention" in cfg.layer_kinds
-    if not heads and not gated:
-        return None, "no kda layer"
-    if not (force_tpu_kernels or backend_platform() == "tpu"):
-        return None, "plain (no TPU)"
-    if not heads:   # Gated DeltaNet: `GatedDeltaMixer` runs the plain rule
-        return None, (
-            f"plain (a {cfg.gdn_key_size} x {cfg.gdn_value_size} state "
-            f"under one decay a head: the kernels tile a square state of "
-            f"multiples of {LANES} under a decay a channel)")
-    chunk, sub = tiling(cfg.pack_tokens, cfg.kda_gate_bound)
-    for n in heads:
-        hb = kda_tiling(n, cfg.head_size, chunk, sub)
-        if isinstance(hb, str):
-            return None, f"plain ({hb})"
-    return (chunk, sub, hb), (f"fused kernel (chunk {chunk}, sub-chunks of "
-                              f"{sub}, {hb} heads a grid step)")

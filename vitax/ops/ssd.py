@@ -39,36 +39,26 @@ accumulate in float32; scores times decay is rounded where `ssd` rounds it.
 
 `scan_tiling` says whether a mixer's shapes tile (chunk and state size
 multiples of 128, head size a divisor or a multiple of 128, the heads of a
-group a multiple of `hp`, all heads' states within `STATE_VMEM_BYTES`);
-`make_scan_impl` chooses this form on a TPU (or forced: interpret mode on the
-CPU) where they do, and the plain form otherwise.
+group a multiple of `hp`, all heads' states within `STATE_VMEM_BYTES`).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple, Union
+from typing import Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.sharding import Mesh, PartitionSpec as P
 
-from vitax.ops.attention import _interpret
-from vitax.parallel.mesh import BATCH_AXES, shard_map
-from vitax.platform import backend_platform
+from vitax.ops.common import (LANES, NT, TN, compiler_params, f32,
+                              interpret)
 
-LANES = 128
 HEADS_PER_STEP = 16                     # at most; unrolled in the body
 ROW_BLOCK = 128     # queries at a time: a block meets only keys not after it
 STATE_VMEM_BYTES = 16 * 2 ** 20         # all heads' (P, N) float32 states
-VMEM_LIMIT = 64 * 1024 * 1024           # of the v5e's 128 MiB
-
-_NT = (((1,), (1,)), ((), ()))          # a @ b^T
-_TN = (((0,), (0,)), ((), ()))          # a^T @ b
-f32 = jnp.float32
 
 
 def scan_tiling(heads: int, head_size: int, state_size: int, groups: int,
@@ -166,7 +156,7 @@ def _passes(segc_ref, segr_ref):
 def _masked_scores(b_ref, c_ref, segc_ref, segr_ref):
     """C B^T where a pair passes, else 0."""
     return jnp.where(_passes(segc_ref, segr_ref),
-                     _dot(c_ref[0], b_ref[0], _NT), 0.0)
+                     _dot(c_ref[0], b_ref[0], NT), 0.0)
 
 
 def _row_blocks(q: int):
@@ -235,13 +225,13 @@ def _fwd_kernel(last_ref, owner_ref, live_ref, x_ref, b_ref, c_ref, rows_ref,
             y = blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks)
             given = state[j, lanes, :]                      # (W, N) float32
             given_ref[0, 0, lanes, :] = given
-            y = y + _dot(c_ref[0], given.astype(dtype), _NT) \
+            y = y + _dot(c_ref[0], given.astype(dtype), NT) \
                 * _spread(from_start, t * hp, hp, lane_head)
             y_ref[0, :, lanes] = y + jnp.where(
                 valid, xf * dlane_ref[:, lanes], 0.0)
             left = _dot((xdt.astype(f32)
                          * _spread(to_end, t * hp, hp, lane_head)
-                         ).astype(dtype), b_ref[0], _TN)    # (W, N)
+                         ).astype(dtype), b_ref[0], TN)    # (W, N)
             state[j, lanes, :] = given * _spread(
                 through, t * hp, hp, row_head) + left
 
@@ -312,8 +302,8 @@ def _bwd_kernel(last_ref, owner_ref, live_ref, x_ref, b_ref, c_ref, rows_ref,
                     m = (scm[queries, keys] * decay).astype(dtype)
                     own = dyb[queries] if hp == 1 else jnp.where(
                         lane_head == i, dyb[queries], jnp.zeros((), dtype))
-                    dsc[queries, keys] += _dot(own, xdt[keys], _NT) * decay
-                    part = _dot(m, dyb[queries], _TN)
+                    dsc[queries, keys] += _dot(own, xdt[keys], NT) * decay
+                    part = _dot(m, dyb[queries], TN)
                     mine = part if i == 0 else jnp.where(lane_head == i, part,
                                                          mine)
                 for kb in range(keys.stop // size):
@@ -325,13 +315,13 @@ def _bwd_kernel(last_ref, owner_ref, live_ref, x_ref, b_ref, c_ref, rows_ref,
             read = (_spread(from_start, t * hp, hp, lane_head) * dy
                     ).astype(dtype)
             dc_acc[...] += _dot(read, given.astype(dtype))
-            dgiven = _dot(read, c_ref[0], _TN)              # (W, N)
+            dgiven = _dot(read, c_ref[0], TN)              # (W, N)
             # the state the chunk leaves
             te = _spread(to_end, t * hp, hp, lane_head)
             dleft = dstate[j, lanes, :]
             dleft_b = dleft.astype(dtype)
             db_acc[...] += _dot((xr * te).astype(dtype), dleft_b)
-            dxdt_end = _dot(b_ref[0], dleft_b, _NT) * te
+            dxdt_end = _dot(b_ref[0], dleft_b, NT) * te
             dxdt = dxdt + dxdt_end
             keep = _spread(through, t * hp, hp, row_head)
             dstate[j, lanes, :] = dleft * keep + dgiven
@@ -367,7 +357,7 @@ def _bwd_kernel(last_ref, owner_ref, live_ref, x_ref, b_ref, c_ref, rows_ref,
             ds = jnp.where(_passes(segc_ref, segr_ref), dsc[...],
                            0.0).astype(dtype)
             dc_acc[...] += _dot(ds, b_ref[0])
-            db_acc[...] += _dot(ds, c_ref[0], _TN)
+            db_acc[...] += _dot(ds, c_ref[0], TN)
         db_ref[0] = db_acc[...].astype(db_ref.dtype)
         dc_ref[0] = dc_acc[...].astype(dc_ref.dtype)
 
@@ -416,12 +406,6 @@ def _specs(h, p, g, n, chunk, hb, chunk_of):
         state=pl.BlockSpec((1, 1, wide, n), at(lambda i, c, j: (i, c, j, 0))))
 
 
-def _params():
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        vmem_limit_bytes=VMEM_LIMIT)
-
-
 def _forward(x, b, c, delta, run, d_skip, seg, chunk, groups, hb, hp,
              interpret):
     """(y (R, T, H * P) float32, the state each chunk began with (R, nc,
@@ -445,7 +429,8 @@ def _forward(x, b, c, delta, run, d_skip, seg, chunk, groups, hb, hp,
                             pltpu.VMEM((LANES, chunk), f32)]),
         out_shape=[jax.ShapeDtypeStruct((r, t, h * p), f32),
                    jax.ShapeDtypeStruct((r, nc, h * p, n), f32)],
-        compiler_params=_params(), name="ssd_fwd", interpret=interpret,
+        compiler_params=compiler_params("parallel", "arbitrary", "arbitrary"),
+        name="ssd_fwd", interpret=interpret,
     )(*scalars, x, b, c, *extra)
 
 
@@ -478,7 +463,8 @@ def _backward(x, b, c, delta, run, d_skip, seg, y, given, dy, chunk, groups,
                    jax.ShapeDtypeStruct(b.shape, b.dtype),
                    jax.ShapeDtypeStruct(c.shape, c.dtype),
                    jax.ShapeDtypeStruct((r, nhb, 3 * hs, t), f32)],
-        compiler_params=_params(), name="ssd_bwd", interpret=interpret,
+        compiler_params=compiler_params("parallel", "arbitrary", "arbitrary"),
+        name="ssd_bwd", interpret=interpret,
     )(*scalars, x, b, c, *extra, y, dy, given)
 
     def tokens(k):      # rows k * hs .. of (R, H / hb, 3 hs, T) -> (R, T, H)
@@ -533,47 +519,5 @@ def ssd_fused(x, delta, a_head, b, c, d_skip, segment_ids, chunk: int, dtype):
     y = _scan(x.reshape(r, t, h * p).astype(dtype),
               b.reshape(r, t, g * n).astype(dtype),
               c.reshape(r, t, g * n).astype(dtype), delta, run, d_skip,
-              segment_ids.astype(jnp.int32), chunk, g, hb, hp, _interpret())
+              segment_ids.astype(jnp.int32), chunk, g, hb, hp, interpret())
     return y.reshape(r, t, h, p)
-
-
-def make_scan_impl(cfg, mesh: Optional[Mesh] = None,
-                   force_tpu_kernels: bool = False):
-    """Choose the mamba layers' scan for this config and mesh, as
-    `make_attention_impl` chooses the attention core: `ssd_fused` on a TPU
-    (`force_tpu_kernels`: off it too, interpret mode on the CPU) where the
-    mixer's shapes tile, shard_map-wrapped over the batch axes on a mesh of
-    several devices; None (the plain `ssd`) otherwise. The start-up line
-    prints the impl's `vitax_name`, or `scan_choice`'s words where it is None."""
-    tiling, words = scan_choice(cfg, force_tpu_kernels)
-    if tiling is None:
-        return None
-    sharded = mesh is not None and mesh.size > 1
-
-    def impl(x, delta, a_head, b, c, d_skip, segment_ids, chunk, dtype):
-        kernel = functools.partial(ssd_fused, chunk=chunk, dtype=dtype)
-        if sharded:
-            rows = P(BATCH_AXES)
-            kernel = shard_map(
-                kernel, mesh=mesh,
-                in_specs=(rows, rows, P(), rows, rows, P(), rows),
-                out_specs=rows, check_vma=False)
-        return kernel(x, delta, a_head, b, c, d_skip, segment_ids)
-    impl.vitax_name = words + (" + shard_map" if sharded else "")
-    return impl
-
-
-def scan_choice(cfg, force_tpu_kernels: bool = False
-                ) -> Tuple[Optional[Tuple[int, int]], str]:
-    """(the kernels' tiling, or None where the plain form runs; the start-up
-    line's words)."""
-    if "mamba" not in cfg.layer_kinds:
-        return None, "no mamba layer"
-    if not (force_tpu_kernels or backend_platform() == "tpu"):
-        return None, "plain (no TPU)"
-    tiling = scan_tiling(cfg.ssm_heads, cfg.ssm_head_size, cfg.ssm_state_size,
-                         cfg.ssm_groups, cfg.ssm_chunk)
-    if isinstance(tiling, str):
-        return None, f"plain ({tiling})"
-    return tiling, (f"fused kernel (chunk {cfg.ssm_chunk}, {tiling[0]} heads "
-                    f"a grid step)")

@@ -52,8 +52,8 @@ def resolve_mesh_shape(cfg: Config, n_devices: Optional[int] = None) -> Tuple[in
     (the reference's pure-DP baseline, run_vit_training.py:171-172). Pipeline
     parallelism (pp > 1) composes with dp, fsdp (ZeRO-3 gathers run
     just-in-time inside the pipeline body), and tp/sp (GSPMD-auto axes
-    inside the body — see vitax/parallel/pipeline.py; the 1F1B schedule
-    and MoE-under-pp remain dense/tp-free, enforced by Config.validate)."""
+    inside the body — see vitax/parallel/pipeline.py; MoE under pp remains
+    tp-free, enforced by Config.validate)."""
     n = n_devices if n_devices is not None else jax.device_count()
     dp, fsdp, tp, sp = cfg.dp_size, cfg.fsdp_size, cfg.tp_size, cfg.sp_size
     pp = getattr(cfg, "pp_size", 1)

@@ -385,8 +385,8 @@ class CommPrecision:
     `cast` downcasts the tree (see `cast_to_compute`); apply it *inside* the
     differentiated function where possible so the convert-vjp upcasts and pins
     the cotangent at the cast boundary. `finalize_grads` is the explicit
-    equivalent for paths that cast outside autodiff (ZeRO-2's step-top gather,
-    the 1f1b hand-assembled backward): it upcasts any bf16 grad leaf to f32,
+    equivalent for the path that casts outside autodiff (ZeRO-2's step-top
+    gather): it upcasts any bf16 grad leaf to f32,
     pinning the reduction dtype the same way. It is a no-op on f32 leaves, so
     applying it unconditionally after any grad path is safe.
     """

@@ -2,7 +2,7 @@
 
 `Geometry.assemble(cfg, ...)` is the only place that writes out
 
-    mesh -> attention core -> model (both activation anchors) ->
+    mesh -> kernels -> model (both activation anchors) ->
     the scenario's optimizer -> train state (abstract or live) -> specs
 
 and `build_program(task, geom)` the only place that turns such a geometry
@@ -42,14 +42,11 @@ from jax.sharding import NamedSharding
 from vitax.config import Config
 from vitax.models import build_model
 from vitax.models.decoder import build_decoder
-from vitax.ops.attention import make_attention_impl
-from vitax.ops.conv import make_conv_impl
-from vitax.ops.kda import make_kda_impl
-from vitax.ops.ssd import make_scan_impl
 from vitax.parallel.mesh import Mesh, batch_pspec, build_mesh
 from vitax.parallel.rules import _leaf_path_names
 from vitax.parallel.sharding import (moe_dispatch_sharding, shardings_of,
                                      token_sharding)
+from vitax.programs.kernels import choose_kernels
 from vitax.programs.registry import Scenario, get_scenario
 from vitax.programs.workloads import load_teacher_params, make_distill_step
 from vitax.train.state import make_train_state
@@ -63,26 +60,22 @@ PROGRAM_KINDS = ("train", "eval", "distill", "serve_bucket")
 
 def build_model_for(cfg: Config, mesh: Mesh, force_tpu_kernels: bool = False,
                     quant_matmul: Optional[Callable] = None):
-    """The model every program runs: the attention core chosen for this
-    config and mesh, and BOTH activation anchors (token sharding on any
-    multi-device mesh, the MoE dispatch sharding iff the model has experts).
-    `force_tpu_kernels` selects the TPU kernels off the TPU (a compile for a
-    described topology; interpret mode on the CPU); `quant_matmul` (serving
-    only) swaps every Dense site for QuantDense. `cfg.model_family` picks
-    the module: the ViT, or the token decoder (vitax/models/decoder.py)."""
-    attention_impl = make_attention_impl(
-        cfg, mesh, force_tpu_kernels=force_tpu_kernels)
+    """The model every program runs: the kernels chosen for this config and
+    mesh (vitax/programs/kernels.py: `choose_kernels`), and BOTH activation
+    anchors (token sharding on any multi-device mesh, the MoE dispatch
+    sharding iff the model has experts). `force_tpu_kernels` selects the TPU
+    kernels off the TPU (a compile for a described topology; interpret mode
+    on the CPU); `quant_matmul` (serving only) swaps every Dense site for
+    QuantDense. `cfg.model_family` picks the module: the ViT, or the token
+    decoder (vitax/models/decoder.py)."""
+    kernels = choose_kernels(cfg, mesh, force_tpu_kernels)
     if cfg.decoder:
         assert quant_matmul is None, "the decoder has no quantized arm"
-        return build_decoder(
-            cfg, attention_impl=attention_impl,
-            token_sharding=token_sharding(cfg, mesh),
-            scan_impl=make_scan_impl(cfg, mesh, force_tpu_kernels),
-            kda_impl=make_kda_impl(cfg, mesh, force_tpu_kernels),
-            conv_impl=make_conv_impl(cfg, mesh, force_tpu_kernels))
+        return build_decoder(cfg, kernels=kernels,
+                             token_sharding=token_sharding(cfg, mesh))
     return build_model(
         cfg,
-        attention_impl=attention_impl,
+        attention_impl=kernels.attention,
         token_sharding=token_sharding(cfg, mesh),
         moe_dispatch_sharding=moe_dispatch_sharding(cfg, mesh),
         quant_matmul=quant_matmul)

@@ -60,6 +60,7 @@ from vitax.models import count_params
 from vitax.parallel.mesh import build_mesh
 from vitax.train.control import ArbiterReporter, ControlPlane
 from vitax.programs.builder import Geometry, build_program
+from vitax.programs.kernels import Kernels, kernel_lines
 from vitax.train.state import TrainState
 from vitax.telemetry import (Watchdog, build_recorder,
                              install_thread_excepthook)
@@ -255,23 +256,11 @@ def train(cfg: Config) -> TrainState:
     geom = Geometry.assemble(cfg, max_iteration,
                              materialize=cfg.resume_epoch <= 0)
     model, schedule = geom.model, geom.schedule
-    master_print("attention core: "
-                 + getattr(model.attention_impl, "vitax_name", "dense jnp")
-                 + _attention_remat_note(cfg, model, geom.mesh))
-    if cfg.decoder and "mamba" in cfg.layer_kinds:
-        from vitax.ops.ssd import scan_choice
-        master_print("state-space scan: " + (
-            getattr(model.scan_impl, "vitax_name", "") or scan_choice(cfg)[1]))
-    if cfg.decoder and {"kda", "linear_attention"} & set(cfg.layer_kinds):
-        from vitax.ops.kda import kda_choice
-        master_print("delta rule: " + (
-            getattr(model.kda_impl, "vitax_name", "") or kda_choice(cfg)[1]))
-    if cfg.decoder and {"mamba", "kda", "linear_attention"} & set(
-            cfg.layer_kinds):
-        from vitax.ops.conv import conv_choice
-        master_print("mixer convolution: " + (
-            getattr(model.conv_impl, "vitax_name", "")
-            or conv_choice(cfg)[1]))
+    lines = kernel_lines(cfg, model.kernels if cfg.decoder else Kernels(
+        attention=model.attention_impl))
+    lines[0] += _attention_remat_note(cfg, model, geom.mesh)
+    for line in lines:
+        master_print(line)
     # the loop owns the state: a restore or a warm start replaces it, every
     # step donates it
     state, geom.state = geom.state, None

@@ -326,9 +326,9 @@ def make_train_step(
       master tree is downcast to bf16 while still sharded, so every FSDP
       param collective moves bf16 bytes. The cast sits INSIDE autodiff for
       the value_and_grad paths (its convert-vjp upcasts cotangents to f32
-      and pins the grad-reduction dtype); the ZeRO-2 step-top gather and the
-      1f1b hand-assembled backward cast outside autodiff and upcast grads
-      explicitly via `finalize_grads`. With the policy off (or
+      and pins the grad-reduction dtype); the ZeRO-2 step-top gather casts
+      outside autodiff and upcasts grads explicitly via `finalize_grads`.
+      With the policy off (or
       --param_gather_dtype float32) the traced program is bit-for-bit the
       pre-policy one.
     """
@@ -388,18 +388,9 @@ def make_train_step(
     gathered_shardings = (
         shardings_of(mesh, gather_over_fsdp(state_specs.params)) if zero2 else None)
 
-    use_1f1b = (getattr(cfg, "pp_schedule", "gpipe") == "1f1b"
-                and cfg.pp_size > 1 and mesh.shape.get("pp", 1) > 1)
-    if use_1f1b:
-        # the interleaved schedule computes the loss INSIDE the pipelined
-        # region (per microbatch, at the last stage) and hand-assembles the
-        # grads — it replaces value_and_grad wholesale
-        from vitax.parallel.pipeline_1f1b import make_1f1b_value_and_grad
-        vag_1f1b = make_1f1b_value_and_grad(cfg, model, mesh, state_specs)
-
     k_steps = int(getattr(cfg, "grad_accum_steps", 1) or 1)
     if k_steps > 1:
-        assert not use_1f1b and getattr(cfg, "pp_size", 1) == 1, (
+        assert getattr(cfg, "pp_size", 1) == 1, (
             "grad accumulation under pipeline parallelism is rejected by "
             "Config.validate()")
         assert cfg.batch_size % k_steps == 0, (cfg.batch_size, k_steps)
@@ -506,19 +497,12 @@ def make_train_step(
             # bf16 bytes and the gathered tree holds half the live memory
             params = state.params if comm is None else comm.cast(state.params)
             params = jax.lax.with_sharding_constraint(params, gathered_shardings)
-        elif use_1f1b and comm is not None:
-            # the 1f1b schedule hand-assembles grads (no value_and_grad), so
-            # the cast sits outside autodiff; finalize_grads upcasts below
-            params = comm.cast(state.params)
         else:
             params = state.params
         expert_load = None
         if cfg.decoder:
             (loss, (expert_load, kept_group)), grads = jax.value_and_grad(
                 decoder_loss_fn, has_aux=True)(params, batch, step_rng)
-        elif use_1f1b:
-            loss, grads = vag_1f1b(params, prepare_images(batch["image"]),
-                                   batch["label"])
         elif k_steps > 1:
             loss, grads = accum_value_and_grad(params, batch, step_rng)
         else:
