@@ -560,3 +560,41 @@ def test_the_olmo_hybrid_cells_step_compiles_and_fits(chip, mosaic):
     found = set(scopes.index(compiled.as_text(), gen.SCOPES).values())
     assert {"kda_conv", "kda_gate", "kda_chunk", "kda_state", "kda_out_norm",
             "post_norm", "qk_norm", "lm_head_loss"} <= found, found
+
+
+def test_the_lfm2_moe_cells_step_compiles_and_fits(chip, mosaic):
+    """The whole train step of `lfm2_24b_a2b_ep8_train_packed8k` at the
+    published widths (five layers, 469.3M parameters with Adam's state, two
+    rows of 8,192 tokens), through the cell's own `lower_described`: the
+    chip's compiler takes it, it fits the 15.75 GiB the compiler allows and
+    fills over 70% of it, the attention layer's three `flash_causal_*`
+    kernels and the fused optimizer are in it and NO `conv_silu_*` (the gated
+    convolution has no activation: the plain form runs), and the mixer, the
+    norm a head and the expert layer lie under the scopes the cell's readers
+    read."""
+    import re
+
+    from benchmark import harness, scopes
+    from benchmark import manifest as mf
+    _, topo = chip
+    man = mf.Manifest()
+    cell = man.cell("lfm2_24b_a2b_ep8_train_packed8k")
+    config, traffic = man.config(cell["config"]), man.traffic(cell["traffic"])
+    gen = mf.generator(traffic["kind"])
+    lowered, what = gen.lower_described(man.config_kwargs(config), traffic,
+                                        list(topo.devices)[:1])
+    assert what == "decoder train step, 2 rows of 8192 tokens"
+    compiled = lowered.compile()
+    step_bytes = harness.program_facts(compiled)["step_bytes"]
+    assert 0.7 * 16.909e9 < step_bytes <= 16.909e9, step_bytes
+    kernels = _kernel_names(compiled)
+    # one attention layer, whose remat keeps o and lse: each kernel once
+    assert sorted(re.search(r"flash_causal_\w+", k).group() for k in kernels
+                  if "flash_" in k) == [
+        "flash_causal_dkv", "flash_causal_dq", "flash_causal_fwd"], kernels
+    assert any("fused_adamw" in k for k in kernels)
+    assert not any("conv_silu" in k for k in kernels)
+    found = set(scopes.index(compiled.as_text(), gen.SCOPES).values())
+    assert {"gconv_in", "gconv", "gconv_out", "qk_norm", "rope1d",
+            "moe_route", "moe_dispatch", "expert_ffn", "moe_combine",
+            "lm_head_loss"} <= found, found

@@ -24,6 +24,9 @@ DOCUMENTS = "pallas streaming, causal / window, grouped KV (packed documents)"
 OLMO_RULE = ("plain (a 96 x 192 state under one decay a head: the kernels "
              "tile a square state of multiples of 128 under a decay a "
              "channel)")
+GATED_CONV = ("plain (a gated convolution, C * conv(B * x) without an "
+              "activation: the kernel pair has a silu behind its taps and no "
+              "gate)")
 
 
 def conv(lanes):
@@ -54,6 +57,9 @@ CELLS = {
         "attention core: " + DOCUMENTS,
         "delta rule: " + OLMO_RULE,
         "mixer convolution: " + conv(384)],
+    "lfm2_24b_a2b_ep8": [       # PR 48: no kernel is asked of the new mixer
+        "attention core: " + DOCUMENTS,
+        "mixer convolution: " + GATED_CONV],
 }
 LINE_OF = {"scan": "state-space scan", "rule": "delta rule",
            "conv": "mixer convolution"}

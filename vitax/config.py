@@ -88,9 +88,9 @@ class Config:
     kv_heads: int = 0                   # key/value heads, each serving layer_heads[i] / kv_heads query heads
     head_size: int = 0                  # width of one head (not embed_dim / heads: q is heads * head_size wide)
     # per layer: "full_attention" (or "attention") | "sliding_attention" |
-    # "mamba" | "kda" | "latent_attention" | "linear_attention"
+    # "mamba" | "kda" | "latent_attention" | "linear_attention" | "conv"
     layer_kinds: Tuple[str, ...] = ()
-    layer_heads: Tuple[int, ...] = ()   # per layer: query heads, a delta-rule layer's heads (0 in a mamba layer)
+    layer_heads: Tuple[int, ...] = ()   # per layer: query heads, a delta-rule layer's heads (0: mamba, conv)
     layer_mlps: Tuple[str, ...] = ()    # per layer: "dense" | "sparse"
     window_tokens: int = 0              # keys a sliding layer's query sees, its own position included
     ffn_dim: int = 0                    # width of a dense layer's SwiGLU
@@ -142,12 +142,21 @@ class Config:
     gdn_key_size: int = 0
     gdn_value_size: int = 0
     gdn_conv_width: int = 0
+    # A "conv" layer's mixer (LFM2's gated short convolution,
+    # vitax/models/gconv.py): W_out[C * conv(B * x)] with B, C and x three
+    # embed_dim-wide parts of one projection, a depthwise causal convolution
+    # of gconv_width taps between the two gates, no activation, no state
+    gconv_width: int = 0
     # The block's form (shapes of the model, not knobs): norm_after puts the
     # two RMSNorms on what each half of a layer ADDS (Olmo 2's block; a half
     # reads the raw residual stream); qk_norm RMS-norms q and k of a full or
-    # sliding layer over the whole projected width before the heads are split
+    # sliding layer over the whole projected width before the heads are
+    # split; head_norm RMS-norms them over each head's head_size channels
+    # (one weight of head_size for q, one for k) after the split and before
+    # the rotation
     norm_after: bool = False
     qk_norm: bool = False
+    head_norm: bool = False
     # A "latent_attention" layer (MLA): keys and values come up from a normed
     # latent of latent_rank; a head's query and key are qk_nope_size +
     # qk_rope_size wide, the rotated part of the key one for all heads; its
@@ -161,10 +170,12 @@ class Config:
     # (biased) scores, groups_per_token groups kept, the experts_per_token
     # best experts inside them; route_bias adds a float32 bias a routed
     # expert to the scores for CHOOSING only (0 groups, no bias: a plain
-    # top-K over all experts)
+    # top-K over all experts); route_weight_eps is what the model adds to
+    # the sum of the chosen scores that normalises the routed weights
     route_groups: int = 0
     groups_per_token: int = 0
     route_bias: bool = False
+    route_weight_eps: float = 0.0
     pos_dropout: float = 0.0
     # NOTE: att_dropout > 0 stays on the fused kernels — every attention path
     # (whole-N, streamed, ring/ulysses sp, and their pipeline bodies at tp=1)
@@ -477,7 +488,7 @@ class Config:
         assert set(self.layer_kinds) <= {
             "full_attention", "attention", "sliding_attention",
             "mamba", "kda", "latent_attention",
-            "linear_attention"}, self.layer_kinds
+            "linear_attention", "conv"}, self.layer_kinds
         assert set(self.layer_mlps) <= {"dense", "sparse"}, self.layer_mlps
         assert self.vocab_rows >= 2 and self.head_size >= 2, (
             f"--vocab_rows {self.vocab_rows} and --head_size "
@@ -485,7 +496,7 @@ class Config:
         assert self.kv_heads >= 1 and all(
             h >= self.kv_heads and h % self.kv_heads == 0
             for h, kind in zip(self.layer_heads, self.layer_kinds)
-            if kind not in ("mamba", "linear_attention")), (
+            if kind not in ("mamba", "linear_attention", "conv")), (
             f"every layer's query heads {self.layer_heads} must be a "
             f"multiple of --kv_heads {self.kv_heads}")
         assert self.position_embedding in ("rope", "nope"), (
@@ -526,6 +537,14 @@ class Config:
                 if kind == "linear_attention"), (
                 f"a linear_attention layer needs heads, got "
                 f"{self.layer_heads}")
+        if "conv" in self.layer_kinds:
+            assert self.gconv_width >= 1, (
+                "a conv layer needs --gconv_width, the taps of its "
+                "convolution")
+        assert not (self.qk_norm and self.head_norm), (
+            "--qk_norm (over the whole projected width) and --head_norm (a "
+            "head) are two forms of one norm: a model has one")
+        assert self.route_weight_eps >= 0, "--route_weight_eps must be >= 0"
         if "latent_attention" in self.layer_kinds:
             assert (self.latent_rank >= 1 and self.qk_nope_size >= 1
                     and self.qk_rope_size >= 2 and self.v_head_size >= 1), (
@@ -964,8 +983,9 @@ def build_parser() -> argparse.ArgumentParser:
             ("head_size", int, 0, "width of one head"),
             ("layer_kinds", str, "",
              "full_attention (or attention)|sliding_attention|mamba|kda|"
-             "latent_attention|linear_attention a layer"),
-            ("layer_heads", str, "", "query heads a layer (0 in a mamba one)"),
+             "latent_attention|linear_attention|conv a layer"),
+            ("layer_heads", str, "",
+             "query heads a layer (0 in a mamba or a conv one)"),
             ("layer_mlps", str, "", "dense|sparse a layer"),
             ("window_tokens", int, 0, "keys a sliding layer's query sees"),
             ("ffn_dim", int, 0, "dense SwiGLU width"),
@@ -1003,12 +1023,15 @@ def build_parser() -> argparse.ArgumentParser:
             ("gdn_key_size", int, 0, "key width of a linear_attention layer's heads"),
             ("gdn_value_size", int, 0, "value width of its heads"),
             ("gdn_conv_width", int, 0, "taps of its convolution"),
+            ("gconv_width", int, 0, "taps of a conv layer's gated convolution"),
             ("latent_rank", int, 0, "width of a latent_attention layer's latent"),
             ("qk_nope_size", int, 0, "unrotated part of its query and key heads"),
             ("qk_rope_size", int, 0, "rotated part (the key's shared by all heads)"),
             ("v_head_size", int, 0, "width of its value heads"),
             ("route_groups", int, 0, "groups the router chooses inside (0 = none)"),
-            ("groups_per_token", int, 0, "groups a token's experts come from")):
+            ("groups_per_token", int, 0, "groups a token's experts come from"),
+            ("route_weight_eps", float, 0.0,
+             "added to the sum that normalises the routed weights")):
         dec.add_argument(f"--{name}", type=kind, default=default, help=text)
     dec.add_argument("--position_embedding", type=str, default="rope",
                      choices=("rope", "nope"),
@@ -1019,6 +1042,9 @@ def build_parser() -> argparse.ArgumentParser:
                      help="the block norms what each half adds, not its input")
     dec.add_argument("--qk_norm", action="store_true", dest="qk_norm",
                      help="RMSNorm on q and k over the whole projected width")
+    dec.add_argument("--head_norm", action="store_true", dest="head_norm",
+                     help="RMSNorm on q and k over each head, before the "
+                          "rotation")
     dec.add_argument("--route_bias", action="store_true", dest="route_bias",
                      help="a bias a routed expert on the scores that choose")
     dec.add_argument("--tie_embeddings", action="store_true",

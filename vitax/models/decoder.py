@@ -21,6 +21,9 @@ The second model family (`Config.model_family == "decoder"`), built by
   (`head_gate`). No biases. `qk_norm`: q and k are RMS-normed over the
   WHOLE projected width (all the heads held, one weight of that width)
   before the heads are split, as Olmo 2 and 3 write it (scope `qk_norm`).
+  `head_norm`: the same norm in its second form, over each head's
+  `head_size` channels (one weight of `head_size` for q, one for k) after
+  the split and before the rotation, as LFM2 writes it (the same scope).
 - F: a SwiGLU of `ffn_dim` in a `dense` layer, the routed and shared experts
   of vitax/models/experts.py in a `sparse` one.
 - A `mamba` layer has the state-space mixer of vitax/models/ssm.py in place
@@ -28,8 +31,10 @@ The second model family (`Config.model_family == "decoder"`), built by
   vitax/models/kda.py (`layer_heads[i]` heads of `head_size`; it rotates
   nothing), a `linear_attention` layer the Gated-DeltaNet mixer of the same
   file (`layer_heads[i]` heads of `gdn_key_size` keys and `gdn_value_size`
-  values; the name the published configurations give it in `layer_types`).
-  `attention` is another word for `full_attention`.
+  values; the name the published configurations give it in `layer_types`),
+  a `conv` layer the gated short convolution of vitax/models/gconv.py (no
+  heads, `layer_heads[i]` 0). `attention` is another word for
+  `full_attention`.
 - `norm_after`: Olmo's block. The norm sits on what each half ADDS,
   `h += RMSNorm(Mix(h)); h += RMSNorm(F(h))` (scope `post_norm`), and a half
   reads the raw residual stream; the weights keep the names `norm1` and
@@ -52,7 +57,8 @@ run is one `nn.scan` over its stacked parameters with per-block remat inside
 come as ONE record, `kernels` (vitax/programs/kernels.py: `choose_kernels`;
 `attention`, `scan`, `rule`, `conv`), from the model down to its blocks; a
 member that is None, or no record, selects the plain `jax.numpy` form: the
-dense masked path below, `ssd`, `kda`, `conv_silu`.
+dense masked path below, `ssd`, `kda`, `conv_silu`. A `conv` layer's mixer
+has the plain form only.
 """
 
 from __future__ import annotations
@@ -67,6 +73,7 @@ import numpy as np
 
 from vitax.config import Config
 from vitax.models.experts import SharedRoutedExperts, SwiGLU, Table
+from vitax.models.gconv import GatedConvMixer, gated_conv_param_count
 from vitax.models.kda import (GatedDeltaMixer, GatedDeltaShape, KDAMixer,
                               KDAShape, gated_delta_param_count,
                               kda_param_count)
@@ -78,7 +85,9 @@ MAMBA = "mamba"
 KDA = "kda"
 LATENT = "latent_attention"
 GATED_DELTA = "linear_attention"
-RECURRENT = (MAMBA, KDA, GATED_DELTA)   # kinds with no attention kernel
+GATED_CONV = "conv"
+# kinds with no attention kernel
+NO_ATTENTION = (MAMBA, KDA, GATED_DELTA, GATED_CONV)
 
 
 # --- rotary position embedding (pure functions) -----------------------------
@@ -201,22 +210,25 @@ class DecoderAttention(nn.Module):
     attention_impl: Optional[Callable] = None
     scale: float = 0.0              # on the scores; 0 = head_size ** -0.5
     qk_norm: float = 0.0            # > 0: the eps of the norm on q and on k
+    head_norm: bool = False         # the norm a head, behind the split
 
     @nn.compact
     def __call__(self, x: Array, segment_ids: Array,
                  rope: Optional[Tuple[Array, Array]]) -> Array:
         r, t, d = x.shape
         h, kv, dh = self.heads, self.kv_heads, self.head_size
-        def normed(y, name):    # over the whole width, before the split
-            if not self.qk_norm:
-                return y
-            with jax.named_scope("qk_norm"):
-                return RMSNorm(self.qk_norm, self.dtype, name=name)(y)
+        def normed(y, name, heads):
+            """Over the whole width before the split, or over each head
+            behind it (`head_norm`: RMSNorm runs over the last axis)."""
+            if self.head_norm:
+                y = y.reshape(r, t, heads, dh)
+            if self.qk_norm:
+                with jax.named_scope("qk_norm"):
+                    y = RMSNorm(self.qk_norm, self.dtype, name=name)(y)
+            return y.reshape(r, t, heads, dh)
 
-        q = normed(_linear(h * dh, self.dtype, "wq")(x), "q_norm").reshape(
-            r, t, h, dh)
-        k = normed(_linear(kv * dh, self.dtype, "wk")(x), "k_norm").reshape(
-            r, t, kv, dh)
+        q = normed(_linear(h * dh, self.dtype, "wq")(x), "q_norm", h)
+        k = normed(_linear(kv * dh, self.dtype, "wk")(x), "k_norm", kv)
         v = _linear(kv * dh, self.dtype, "wv")(x).reshape(r, t, kv, dh)
         if rope is not None:
             with jax.named_scope("rope1d"):
@@ -314,11 +326,14 @@ class DecoderBlock(nn.Module):
     mixer: Optional[MixerShape] = None      # a mamba layer's
     kda: Optional[Tuple[int, float]] = None     # a kda layer's taps and bound
     latent: Optional[LatentShape] = None    # a latent_attention layer's
-    route: Tuple[int, int, bool] = (0, 0, False)    # groups, kept, bias
+    # groups, kept, bias, what the weights' denominator adds
+    route: Tuple[int, int, bool, float] = (0, 0, False, 0.0)
     # a linear_attention layer's key size, value size and taps
     gated_delta: Optional[Tuple[int, int, int]] = None
     norm_after: bool = False        # the norms on what a half adds
     qk_norm: bool = False
+    head_norm: bool = False         # the norm on q and k is one a head
+    gconv_width: int = 0            # a conv layer's taps
 
     def _added(self, y: Array) -> Array:
         if self.residual_multiplier == 1.0:
@@ -358,6 +373,9 @@ class DecoderBlock(nn.Module):
             y = GatedDeltaMixer(GatedDeltaShape(heads, *self.gated_delta),
                                 self.norm_eps, self.dtype, conv=conv,
                                 name="mixer")(y, segment_ids)
+        elif kind == GATED_CONV:
+            y = GatedConvMixer(self.gconv_width, self.dtype,
+                               name="mixer")(y, segment_ids)
         elif kind == LATENT:
             y = LatentAttention(
                 heads=heads, shape=self.latent, head_gate=self.head_gate,
@@ -371,7 +389,9 @@ class DecoderBlock(nn.Module):
                 head_gate=self.head_gate, dtype=self.dtype,
                 attention_impl=attention,
                 scale=self.attention_scale,
-                qk_norm=self.norm_eps if self.qk_norm else 0.0, name="attn",
+                qk_norm=(self.norm_eps if self.qk_norm or self.head_norm
+                         else 0.0),
+                head_norm=self.head_norm, name="attn",
             )(y, segment_ids, rope_window if sliding else rope_full)
         x = x + self._added(self._normed(y, "norm1", False))
         y = self._normed(x, "norm2", True)
@@ -387,7 +407,8 @@ class DecoderBlock(nn.Module):
                 expert_dim=self.expert_dim, shared_dim=self.shared_expert_dim,
                 routed_scale=self.routed_scale, dtype=self.dtype,
                 route_groups=self.route[0], groups_per_token=self.route[1],
-                route_bias=self.route[2], name="moe",
+                route_bias=self.route[2], weight_eps=self.route[3],
+                name="moe",
             )(y, segment_ids > 0)
         return x + self._added(self._normed(y, "norm2", False))
 
@@ -469,10 +490,12 @@ class Decoder(nn.Module):
     mixer: Optional[MixerShape] = None
     kda: Optional[Tuple[int, float]] = None
     latent: Optional[LatentShape] = None
-    route: Tuple[int, int, bool] = (0, 0, False)
+    route: Tuple[int, int, bool, float] = (0, 0, False, 0.0)
     gated_delta: Optional[Tuple[int, int, int]] = None
     norm_after: bool = False
     qk_norm: bool = False
+    head_norm: bool = False
+    gconv_width: int = 0
 
     @property
     def attention_impl(self) -> Optional[Callable]:
@@ -532,7 +555,8 @@ class Decoder(nn.Module):
             residual_multiplier=self.residual_multiplier, mixer=self.mixer,
             kda=self.kda, latent=self.latent, route=self.route,
             gated_delta=self.gated_delta, norm_after=self.norm_after,
-            qk_norm=self.qk_norm)
+            qk_norm=self.qk_norm, head_norm=self.head_norm,
+            gconv_width=self.gconv_width)
         for i, (shape, length) in enumerate(self.runs()):
             x = Run(length=length,
                     block_kwargs=tuple({**block_kwargs,
@@ -571,10 +595,10 @@ def _decoder_attention_saveable(prim, *_, **params):
 def keeps_attention_residuals(model: Decoder, kind: str) -> bool:
     """PR 30's rule (vitax/models/vit.py: keeps_attention_residuals) by the
     span of a run's layers: a full layer's query meets a whole row, a sliding
-    layer's at most `window_tokens` keys. A mamba, kda or linear_attention
-    layer has no attention kernel to keep anything of."""
+    layer's at most `window_tokens` keys. A mamba, kda, linear_attention or
+    conv layer has no attention kernel to keep anything of."""
     from vitax.models.vit import keeps_attention_residuals as rule
-    return kind not in RECURRENT and rule(model, span=model.span(kind))
+    return kind not in NO_ATTENTION and rule(model, span=model.span(kind))
 
 
 def run_remat_policy(model: Decoder, kind: str):
@@ -614,11 +638,13 @@ def build_decoder(cfg: Config, kernels=None, token_sharding=None) -> Decoder:
         kda=((cfg.kda_conv_width, cfg.kda_gate_bound)
              if KDA in cfg.layer_kinds else None),
         latent=latent_shape(cfg),
-        route=(cfg.route_groups, cfg.groups_per_token, cfg.route_bias),
+        route=(cfg.route_groups, cfg.groups_per_token, cfg.route_bias,
+               cfg.route_weight_eps),
         gated_delta=((cfg.gdn_key_size, cfg.gdn_value_size,
                       cfg.gdn_conv_width)
                      if GATED_DELTA in cfg.layer_kinds else None),
-        norm_after=cfg.norm_after, qk_norm=cfg.qk_norm)
+        norm_after=cfg.norm_after, qk_norm=cfg.qk_norm,
+        head_norm=cfg.head_norm, gconv_width=cfg.gconv_width)
 
 
 def mixer_shape(cfg: Config) -> Optional[MixerShape]:
@@ -676,6 +702,8 @@ def expected_param_count(cfg: Config) -> int:
             total += kda_param_count(delta_shape(cfg, kind, heads), d)
         elif kind == GATED_DELTA:
             total += gated_delta_param_count(delta_shape(cfg, kind, heads), d)
+        elif kind == GATED_CONV:
+            total += gated_conv_param_count(d, cfg.gconv_width)
         elif kind == LATENT:
             s = latent_shape(cfg)
             total += (d * heads * (s.nope + s.rope) + d * (s.rank + s.rope)
@@ -686,6 +714,7 @@ def expected_param_count(cfg: Config) -> int:
             total += 2 * d * heads * dh + 2 * d * cfg.kv_heads * dh
             total += d * heads if cfg.head_gate else 0
             total += (heads + cfg.kv_heads) * dh if cfg.qk_norm else 0
+            total += 2 * dh if cfg.head_norm else 0
         if mlp == "dense":
             total += 3 * d * cfg.ffn_dim
         else:

@@ -6,8 +6,9 @@ The feed-forward of a sparse decoder layer (vitax/models/decoder.py):
 
 `s = sigmoid(W_r x)` scores ALL `experts_routed` experts in float32, the K
 best are chosen over all of them (the plain path: `route_groups` 0, no
-bias), `w = routed_scale * s_k / sum_topK s`; every `E_k` and the shared `S`
-is a SwiGLU. The layer is told which experts it holds (`experts_held` from
+bias), `w = routed_scale * s_k / (sum_topK s + weight_eps)` (`weight_eps` is
+the model's: 0, or LFM2's 1e-6); every `E_k` and the shared `S` (`shared_dim`
+0: none) is a SwiGLU. The layer is told which experts it holds (`experts_held` from
 `expert_first` on: one chip's share of a deployment in which several chips
 share each layer) and adds the terms whose expert it holds, and the shared
 expert; what the absent experts would add is left out. With `experts_held
@@ -17,8 +18,10 @@ put the all-to-all pair around `expert_ffn` and is not built.
 
 What groups and a bias change is the CHOICE alone (`choose`, the DeepSeek-V3
 router): with `route_bias` the experts are ranked by s' = s + bias (a
-float32 leaf an expert, which receives no gradient; the balance update that
-moves it in a real job is the trainer's and is not built); with
+float32 leaf an expert, which receives no gradient; the trainer moves it
+once a step from `route_load`, the real tokens that chose each of ALL the
+routed experts, which a layer with a bias sows: vitax/train/step.py
+`balance_router_bias`); with
 `route_groups` G the experts form G equal groups in index order, a group
 scores the sum of its two best s', the `groups_per_token` best groups are
 kept and the K best s' are taken inside them. The weights come from the
@@ -165,6 +168,7 @@ class SharedRoutedExperts(nn.Module):
     route_groups: int = 0           # 0: the K best over all experts
     groups_per_token: int = 0
     route_bias: bool = False
+    weight_eps: float = 0.0         # on the sum that normalises the weights
 
     @nn.compact
     def __call__(self, x: Array, valid: Array) -> Array:
@@ -191,8 +195,16 @@ class SharedRoutedExperts(nn.Module):
                                      dtype=jnp.int32))
             else:
                 top, chosen = jax.lax.top_k(scores, k)            # (N, K)
-            weights = self.routed_scale * top / jnp.sum(
-                top, axis=-1, keepdims=True)
+            scaled = self.routed_scale * top
+            total = jnp.sum(top, axis=-1, keepdims=True)
+            if self.weight_eps:
+                total = total + self.weight_eps
+            weights = scaled / total
+            if self.route_bias:     # what the trainer's balance rule reads
+                self.sow("intermediates", "route_load", jnp.sum(
+                    jax.nn.one_hot(chosen, self.experts_routed,
+                                   dtype=jnp.int32)
+                    * valid.reshape(n, 1, 1), axis=(0, 1)))       # (E,)
             local = chosen - self.expert_first
             here = ((local >= 0) & (local < held)
                     & valid.reshape(n)[:, None])                  # (N, K)

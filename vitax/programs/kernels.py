@@ -23,7 +23,7 @@ from typing import Callable, List, NamedTuple, Optional, Tuple
 from jax.sharding import Mesh, PartitionSpec as P
 
 from vitax.config import Config
-from vitax.models.decoder import delta_shapes, mixer_shape
+from vitax.models.decoder import GATED_CONV, delta_shapes, mixer_shape
 from vitax.models.kda import KDAShape
 from vitax.ops.attention import make_attention_impl
 from vitax.ops.common import LANES
@@ -80,8 +80,10 @@ def _rule_words(cfg: Config) -> Words:
 def _conv_words(cfg: Config) -> Words:
     mixer = mixer_shape(cfg)
     shapes = ([] if mixer is None else [mixer]) + delta_shapes(cfg)
-    if not shapes:
-        return None
+    if not shapes:      # a conv layer's mixer never asks for the member
+        return (False, "plain (a gated convolution, C * conv(B * x) without "
+                "an activation: the kernel pair has a silu behind its taps "
+                "and no gate)") if GATED_CONV in cfg.layer_kinds else None
     tilings = [conv_tiling(channels, cfg.pack_tokens, taps, norm,
                            2 if cfg.dtype == "bfloat16" else 4)
                for channels, taps, norm in (s.conv for s in shapes)]

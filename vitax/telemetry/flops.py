@@ -123,6 +123,8 @@ def decoder_flops_per_step(cfg, tokens: float, targets: float,
     output (2V); by `tokens` the three products with the state (6KV). A
     latent_attention layer: its projections by `tokens`, QK^T at
     qk_nope_size + qk_rope_size and PV at v_head_size by `causal_pairs`.
+    A conv layer (vitax/models/gconv.py): its two projections by `tokens`
+    (the two gates and the taps are no matrix products).
     Padding, the masked part of a block and sorted rows no held expert owns
     are not counted."""
     d, dh = cfg.embed_dim, cfg.head_size
@@ -145,6 +147,8 @@ def decoder_flops_per_step(cfg, tokens: float, targets: float,
             per_token = 2 * d * heads * (2 * dk + 3 * dv + 2)
             per_token += 6 * heads * dk * dv
             fwd += heads * (6 * dk + 4 * dv) * kda_pairs
+        elif kind == "conv":
+            per_token = 2 * d * 3 * d + 2 * d * d
         elif kind == "latent_attention":
             qk, dv = cfg.qk_nope_size + cfg.qk_rope_size, cfg.v_head_size
             per_token = 2 * d * (heads * qk + cfg.latent_rank

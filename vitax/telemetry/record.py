@@ -135,9 +135,10 @@ class Recorder:
         (`targets`, `causal_pairs`, `window_pairs`, `expert_slots_here` in
         place of `token_pairs`; `images` are documents; a scan's `ssd_pairs`
         and `ssd_live_chunks`, a delta rule's `kda_pairs` and
-        `kda_live_chunks`, a grouped router's `tokens_choosing_held_group`)
-        are written into the record as they are, with `expert_load`, its
-        per-layer per-expert load."""
+        `kda_live_chunks`, a grouped router's `tokens_choosing_held_group`,
+        a balanced router's `route_load_max_over_mean`) are written into the
+        record as they are, with `expert_load`, its per-layer per-expert
+        load."""
         images, tokens = self.cfg.batch_size, self.tokens_per_step
         flops_per_step = self.flops_per_step
         if packed_counts is not None:
@@ -184,7 +185,8 @@ class Recorder:
             record.update({k: packed_counts[k] for k in (
                 "targets", "causal_pairs", "window_pairs",
                 "expert_slots_here", "ssd_pairs", "ssd_live_chunks",
-                "kda_pairs", "kda_live_chunks", "tokens_choosing_held_group")
+                "kda_pairs", "kda_live_chunks", "tokens_choosing_held_group",
+                "route_load_max_over_mean")
                 if k in packed_counts}, expert_load=expert_load)
         if grad_norm is not None:
             record["grad_norm"] = float(grad_norm)

@@ -99,11 +99,11 @@ def _attention_remat_note(cfg: Config, model, mesh) -> str:
     if model.attention_impl is None or not cfg.grad_ckpt:
         return ""
     if cfg.decoder:   # by the span of each kind of layer
-        from vitax.models.decoder import RECURRENT, keeps_attention_residuals as keeps
+        from vitax.models.decoder import NO_ATTENTION, keeps_attention_residuals as keeps
         return "; remat " + ", ".join(
             f"{'keeps o and lse' if keeps(model, kind) else 'runs the forward again'}"
             f" in {kind} layers (span {model.span(kind)})"
-            for kind in sorted(set(cfg.layer_kinds) - set(RECURRENT)))
+            for kind in sorted(set(cfg.layer_kinds) - set(NO_ATTENTION)))
     span = attention_span(model)
     if (mesh.shape.get("pp", 1) > 1 or gather_overlap_active(cfg, mesh)
             or cfg.remat_window > 1):
@@ -129,7 +129,9 @@ DECODER_COUNTERS = ("tokens", "padding_tokens", "images", "targets",
                     "ssd_pairs", "ssd_live_chunks",
                     # ... with kda layers, with a router that has groups:
                     "kda_pairs", "kda_live_chunks",
-                    "tokens_choosing_held_group")
+                    "tokens_choosing_held_group",
+                    # ... with a router whose bias the trainer balances:
+                    "route_load_max_over_mean")
 PACKED_COUNTERS = ("tokens", "padding_tokens", "images", "token_pairs",
                    "computed_pairs")
 

@@ -178,6 +178,57 @@ def decoder_loss(logits: jax.Array, batch: Dict[str, jax.Array]) -> jax.Array:
         return jnp.sum(ce * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
+# The step of the router bias's balance rule (auxiliary-loss-free balancing,
+# DeepSeek-V3, arXiv:2412.19437 section 2.1.2: its bias update speed). A
+# constant of the trainer; no configuration names one.
+BALANCE_RATE = 1e-3
+
+
+def balance_router_bias(bias: jax.Array, route_load: jax.Array,
+                        rate: float = BALANCE_RATE) -> jax.Array:
+    """The trainer's rule for a sparse layer's `router_bias`, once a step and
+    outside autodiff: bias_e += rate * sign(mean(load) - load_e), `route_load`
+    the real tokens of the step that chose each of ALL the routed experts
+    (vitax/models/experts.py sows it; trailing axis: experts). An expert
+    chosen less than the mean is ranked higher from the next step on."""
+    load = route_load.astype(jnp.float32)
+    return bias + rate * jnp.sign(
+        jnp.mean(load, axis=-1, keepdims=True) - load).astype(bias.dtype)
+
+
+def route_loads(cols) -> Dict[str, jax.Array]:
+    """{path of a sparse layer's module: its sown `route_load`} from a
+    model's `intermediates`: a scanned run's is stacked (layers, experts)."""
+    out = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cols):
+        keys = [getattr(k, "key", None) for k in path]
+        if "route_load" in keys:
+            out["/".join(keys[1:keys.index("route_load")])] = leaf
+    return out
+
+
+def balance_router_biases(params: PyTree, loads: Dict[str, jax.Array]
+                          ) -> PyTree:
+    """`balance_router_bias` on every `router_bias` leaf of `params`, each
+    from the load its own layer sowed."""
+    def moved(path, leaf):
+        keys = [getattr(k, "key", None) for k in path]
+        if "router_bias" not in keys:
+            return leaf
+        return balance_router_bias(
+            leaf, loads["/".join(keys[1:keys.index("router_bias")])])
+    return jax.tree_util.tree_map_with_path(moved, params)
+
+
+def route_load_max_over_mean(loads: Dict[str, jax.Array]) -> jax.Array:
+    """Of `route_loads`: the fullest routed expert over the mean, the worst
+    sparse layer's: what the balance rule brings towards 1."""
+    load = jnp.concatenate([x.reshape(-1, x.shape[-1])
+                            for x in loads.values()]).astype(jnp.float32)
+    return jnp.max(jnp.max(load, axis=-1)
+                   / jnp.maximum(jnp.mean(load, axis=-1), 1.0))
+
+
 def decoder_counts(cfg: Config, batch: Dict[str, jax.Array]
                    ) -> Dict[str, jax.Array]:
     """What a decoder step's batch held, counted on the device from the
@@ -346,8 +397,9 @@ def make_train_step(
     def decoder_loss_fn(params, batch, rng):
         """(loss, (per-layer per-expert load (sparse layers, held experts),
         the tokens that kept the held experts' group, over the sparse layers;
-        None where the router has no groups)): the expert layers sow both
-        (vitax/models/experts.py)."""
+        None where the router has no groups, the load over ALL routed experts
+        by layer where the router has a bias)): the expert layers sow the
+        three (vitax/models/experts.py)."""
         del rng                      # no dropout arm (Config.validate)
         if comm is not None:
             params = comm.cast(params)
@@ -358,7 +410,7 @@ def make_train_step(
                 if loads else jnp.zeros((0, 0), jnp.int32))
         kept = _select_by_name(cols, "tokens_choosing_held_group")
         kept = sum(jnp.sum(x) for x in kept) if kept else None
-        return decoder_loss(logits, batch), (load, kept)
+        return decoder_loss(logits, batch), (load, kept, route_loads(cols))
 
     def loss_fn(params, batch, rng):
         if comm is not None:
@@ -499,10 +551,11 @@ def make_train_step(
             params = jax.lax.with_sharding_constraint(params, gathered_shardings)
         else:
             params = state.params
-        expert_load = None
+        expert_load, routed = None, {}
         if cfg.decoder:
-            (loss, (expert_load, kept_group)), grads = jax.value_and_grad(
-                decoder_loss_fn, has_aux=True)(params, batch, step_rng)
+            (loss, (expert_load, kept_group, routed)), grads = (
+                jax.value_and_grad(decoder_loss_fn, has_aux=True)(
+                    params, batch, step_rng))
         elif k_steps > 1:
             loss, grads = accum_value_and_grad(params, batch, step_rng)
         else:
@@ -511,6 +564,10 @@ def make_train_step(
             grads = comm.finalize_grads(grads)
         new_params, new_opt_state, grad_norm = update_fn(
             grads, state.opt_state, state.params)
+        if routed:
+            # the router biases take no gradient: the optimizer leaves them
+            # (but for its decay), the balance rule moves them
+            new_params = balance_router_biases(new_params, routed)
         new_state = TrainState(
             step=state.step + 1, params=new_params, opt_state=new_opt_state)
         metrics = {
@@ -528,6 +585,9 @@ def make_train_step(
                            expert_slots_here=jnp.sum(expert_load))
             if kept_group is not None:
                 metrics.update(tokens_choosing_held_group=kept_group)
+            if routed:
+                metrics.update(
+                    route_load_max_over_mean=route_load_max_over_mean(routed))
         elif cfg.packed:
             # what a packed step did is in its batch, not in the config:
             # counted on the device from the segment ids and the label mask
