@@ -339,6 +339,13 @@ def test_train_step_counters_against_a_layout_counted_by_hand():
     load = np.asarray(m["expert_load"])
     assert load.shape == (4, 4)           # sparse layers x held experts
     assert int(m["expert_slots_here"]) == load.sum() <= 111 * 4 * 4
+    # ... and the sorted rows the four layers' loops worked on for them:
+    # whole blocks, under a block a layer more than the slots
+    from vitax.models.experts import block_rows
+    block = block_rows(128 * 4, 4, 16)
+    rows = int(m["expert_rows_computed"])
+    assert rows == sum(-(-int(n) // block) * block for n in load.sum(axis=1))
+    assert load.sum() <= rows < load.sum() + 4 * block
     assert np.isfinite(losses).all() and losses[-1] < losses[0]
     from benchmark import flops_laguna
     assert flops_laguna.layout_counts(LENGTHS, 8) == dict(
@@ -430,3 +437,6 @@ def test_training_through_the_cli_path(tmp_path):
         assert r["targets"] > 0 and r["causal_pairs"] >= r["window_pairs"] > 0
         assert np.asarray(r["expert_load"]).shape == (4, 4)
         assert r["expert_slots_here"] == np.asarray(r["expert_load"]).sum()
+        assert r["expert_slots_here"] <= r["expert_rows_computed"]
+        assert r["expert_rows_over_slots"] == (
+            r["expert_rows_computed"] / r["expert_slots_here"])

@@ -225,12 +225,14 @@ def test_zero_groups_and_no_bias_is_the_plain_layer():
     assert "router_bias" not in p["params"]
     _, cols = jax.jit(lambda p: plain.apply(
         p, x, valid, mutable=["intermediates"]))(p)
-    assert sorted(cols["intermediates"]) == ["expert_load"]
+    assert sorted(cols["intermediates"]) == ["expert_load",
+                                             "expert_rows_computed"]
     grouped = SharedRoutedExperts(8, 2, 0, 2, 16, 16, 1.0, jnp.float32,
                                   route_groups=2, groups_per_token=1)
     _, cols = jax.jit(lambda p: grouped.apply(
         p, x, valid, mutable=["intermediates"]))(p)
     assert sorted(cols["intermediates"]) == ["expert_load",
+                                             "expert_rows_computed",
                                              "tokens_choosing_held_group"]
     assert 0 <= int(cols["intermediates"]["tokens_choosing_held_group"][0]) \
         <= 12

@@ -32,16 +32,43 @@ first held expert.
 
 No token is dropped: the (token, choice) slots whose expert is held are
 sorted by expert and the held experts' three products run as grouped matrix
-products over the sorted rows (`jax.lax.ragged_dot`; on the TPU the compiler
-makes a grouped-matmul kernel of it that skips the rows past the last
-group). The buffer of sorted rows has the static size tokens x K, the most
-that could be routed here; rows past the held slots compute nothing and are
-masked out of values and gradients alike. Dispatch and combine are gathers
-in both directions (each is the other's transpose), so no scatter runs.
+products over the sorted rows (`jax.lax.ragged_dot`, the TPU compiler's
+`ragged-dot*` kernels). The sort's buffer has the static size tokens x K,
+the most that could be routed here, and a chip of a deployment holds an
+eighth or a sixty-fourth of it. On the chip the compiler's kernel does NOT
+skip the rows past the last group: it spares them the MXU and pays for them
+in HBM (PERF.md section 7 (mm): Ling's 72 products a step took 34 ms for a
+need of 2.24), and every gather, mask and `silu * up` around it is as long
+as the buffer too. So the layer bounds its own work (`routed_experts`, one
+`jax.custom_vjp` from tokens to tokens): forward and backward are loops over
+blocks of B consecutive sorted rows, `ceil(slots held / B)` trips read from
+`load` at run time (reverse mode through a loop with a traced trip count
+does not exist, hence the hand-written rules), each trip a gather of B
+tokens, the products on B rows with the block's own group sizes, and B rows
+written. B is a rule of static shapes (`block_rows`). With every expert held
+all tokens x K / B blocks run: the same work as one buffer-long pass. The
+kernels' gradients are the one part a row does not bound: the product that
+contracts the rows writes all held experts' float32 (in, out) whatever the
+rows, and the sum over trips reads and writes that again; the backward's
+blocks therefore only lay out what those gradients are made of, in buffers
+of `BLOCKS_A_CHUNK` blocks, and the three products run once a chunk. A row
+past the last live one is never read as a number: where a product leaves
+something in a dead row of a live block it is selected away, not multiplied
+by its weight of 0. The layer sows `expert_rows_computed` (blocks x B)
+beside `expert_load`: rows worked on over slots held says how far the
+loops' work follows the load.
+
+Still tokens x K rows, by their OUTPUT shape whatever the load: combine's
+forward gather of the sorted rows' results (N, K, D) and dispatch's backward
+gather of their gradients, each the transpose of a B-row gather in the loop
+(no scatter runs: a scatter-add of B rows into the tokens is serial on this
+chip), the zero-fill of the two (tokens x K, D) buffers they read, and the
+two sorts. Later work (ROADMAP A18).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import flax.linen as nn
@@ -83,55 +110,193 @@ class Table(nn.Module):
         return self.param(self.leaf, default_init, self.shape, jnp.float32)
 
 
-@jax.custom_vjp
-def dispatch(x, slot_of_row, row_of_slot):
-    """Sorted rows from tokens: xs[r] = x[slot_of_row[r] // K]. (N, D) ->
-    (M, D). `row_of_slot` (N, K) is the inverse permutation."""
-    return jnp.take(x, slot_of_row // row_of_slot.shape[1], axis=0)
+# blocks of sorted rows: see `block_rows`
+BLOCK_FLOOR, BLOCK_CEILING = 512, 4096
+# ... and the blocks whose rows meet the kernels' gradients in ONE product
+BLOCKS_A_CHUNK = 8
+
+_ROWS_BY_ROWS = jax.lax.RaggedDotDimensionNumbers(       # (B, D) x (B, F)
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
 
-def _dispatch_fwd(x, slot_of_row, row_of_slot):
-    return dispatch(x, slot_of_row, row_of_slot), row_of_slot
+def block_rows(slots: int, held: int, routed: int) -> int:
+    """B, the sorted rows one trip of the layer's loops works on, from static
+    shapes alone: a quarter of the `slots * held / routed` rows a balanced
+    router sends here, as a power of two between 512 and 4,096 (the whole
+    buffer where that is smaller). A quarter, so that the expected load ends
+    INSIDE the fifth block and a seed's few rows more or less do not cross a
+    block's edge."""
+    want = max(slots * held // (4 * routed), 1)
+    block = min(max(1 << want.bit_length() - 1, BLOCK_FLOOR), BLOCK_CEILING)
+    return min(block, slots)
 
 
-def _dispatch_bwd(row_of_slot, dxs):
-    # dx[n] = sum over the token's K slots of dxs[row of that slot]: a gather
-    dx = jnp.sum(jnp.take(dxs, row_of_slot, axis=0).astype(jnp.float32),
-                 axis=1).astype(dxs.dtype)
-    return dx, None, None
+def blocks_of(rows, block: int) -> Array:
+    """The blocks of `block` sorted rows that hold one of `rows` live rows:
+    the trip count of the layer's loops, read from the load."""
+    return (rows + block - 1) // block
 
 
-dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+def _sizes_within(load: Array, first, rows: int) -> Array:
+    """Each held expert's rows inside the sorted rows [first, first + rows)."""
+    ends = jnp.cumsum(load)
+    return (jnp.clip(ends - first, 0, rows)
+            - jnp.clip(ends - load - first, 0, rows))
 
 
-@jax.custom_vjp
-def combine(ys, weights, slot_of_row, row_of_slot):
-    """Tokens from sorted rows: y[n] = sum_k weights[n, k] * ys[row_of_slot[n, k]].
-    (M, D), (N, K) float32 -> (N, D) float32. `weights` is 0 on a slot whose
-    expert is not held here."""
-    del slot_of_row
-    picked = jnp.take(ys, row_of_slot, axis=0).astype(jnp.float32)  # (N, K, D)
-    return jnp.sum(picked * weights[..., None], axis=1)
+def _grouped(rows, kernels, sizes, dims=None):
+    if dims is None:
+        return jax.lax.ragged_dot(rows, kernels, sizes,
+                                  preferred_element_type=jnp.float32)
+    return jax.lax.ragged_dot_general(rows, kernels, sizes, dims,
+                                      preferred_element_type=jnp.float32)
 
 
-def _combine_fwd(ys, weights, slot_of_row, row_of_slot):
-    return (combine(ys, weights, slot_of_row, row_of_slot),
-            (ys, weights, slot_of_row, row_of_slot))
+def _padded(slot_of_row, block: int):
+    """`slot_of_row` to whole blocks: a row past the buffer is a dead row."""
+    short = -slot_of_row.shape[0] % block
+    return jnp.pad(slot_of_row, (0, short)) if short else slot_of_row
 
 
-def _combine_bwd(res, dy):
-    ys, weights, slot_of_row, row_of_slot = res
-    k = weights.shape[1]
-    # each sorted row's token and weight, by the gather that sorted the rows
-    w_of_row = jnp.take(weights.reshape(-1), slot_of_row)
-    dys = (jnp.take(dy, slot_of_row // k, axis=0)
-           * w_of_row[:, None]).astype(ys.dtype)
-    picked = jnp.take(ys, row_of_slot, axis=0).astype(jnp.float32)
-    dw = jnp.sum(picked * dy[:, None, :], axis=-1)
-    return dys, dw, None, None
+def _rows_of_block(xf, slot_of_row, first, total, block: int, k: int):
+    """(the block's slots, which of its rows are live (B, 1), its tokens)."""
+    with jax.named_scope("moe_dispatch"):
+        slots = jax.lax.dynamic_slice_in_dim(slot_of_row, first, block)
+        live = (first + jnp.arange(block) < total)[:, None]
+        xs = jnp.where(live, jnp.take(xf, slots // k, axis=0),
+                       jnp.zeros((), xf.dtype))
+    return slots, live, xs
 
 
-combine.defvjp(_combine_fwd, _combine_bwd)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def routed_experts(xf, weights, slot_of_row, row_of_slot, load,
+                   gate, up, down, block: int):
+    """y[n] = sum_k weights[n, k] * E(x[n]) over the slots whose expert is
+    held: (N, D), (N, K) float32 (0 on a slot whose expert is elsewhere), the
+    sort's two permutations, the held experts' load and their three stacked
+    kernels -> (N, D) float32. Works on `blocks_of(sum(load), block)` blocks
+    of `block` sorted rows, forward and backward."""
+    return _routed_fwd(xf, weights, slot_of_row, row_of_slot, load,
+                       gate, up, down, block)[0]
+
+
+def _routed_fwd(xf, weights, slot_of_row, row_of_slot, load, gate, up, down,
+                block):
+    k, dtype = row_of_slot.shape[1], gate.dtype
+    total = jnp.sum(load)
+    padded = _padded(slot_of_row, block)
+
+    def one_block(b, ys):
+        first = b * block
+        _, live, xs = _rows_of_block(xf, padded, first, total, block, k)
+        with jax.named_scope("expert_ffn"):
+            sizes = _sizes_within(load, first, block)
+            h = nn.silu(_grouped(xs, gate, sizes)) * _grouped(xs, up, sizes)
+            y = _grouped(h.astype(dtype), down, sizes)
+            # a dead row of a live block is whatever the product left there
+            y = jnp.where(live, y, 0.0).astype(dtype)
+            return jax.lax.dynamic_update_slice_in_dim(ys, y, first, 0)
+
+    ys = jax.lax.fori_loop(
+        0, blocks_of(total, block), one_block,
+        jnp.zeros((padded.shape[0], xf.shape[1]), dtype))
+    with jax.named_scope("moe_combine"):
+        # gathered (K, N, D): a token's K rows lie N apart, and no tile is
+        # padded from K rows to its 16 (as (N, K, D) a relayout a gather)
+        picked = jnp.take(ys, row_of_slot.T, axis=0).astype(jnp.float32)
+        y = jnp.sum(picked * weights.T[..., None], axis=0)        # (N, D)
+    return y, (xf, weights, slot_of_row, row_of_slot, load, gate, up, down)
+
+
+def _routed_bwd(block, res, dy):
+    xf, weights, slot_of_row, row_of_slot, load, gate, up, down = res
+    k, dtype = row_of_slot.shape[1], gate.dtype
+    (held, d, f) = gate.shape
+    total = jnp.sum(load)
+    padded = _padded(slot_of_row, block)
+    m = padded.shape[0]
+    chunk = min(block * BLOCKS_A_CHUNK, m)
+    with jax.named_scope("expert_ffn"):
+        # once, out here: the compiler's grouped product contracts a
+        # kernel's middle dimension, and inside a loop it would lay a
+        # kernel out anew every trip
+        gate_t, up_t, down_t = (jnp.swapaxes(w, 1, 2)
+                                for w in (gate, up, down))
+
+    def one_block(first, at, carry):
+        """Rows [first, first + block): `dxs` and the weights' gradients of
+        those rows, and at row `at` of the chunk's five buffers what the
+        kernels' gradients are made of."""
+        dxs, dws, xs_c, dys_c, dg_c, du_c, h_c = carry
+        slots, live, xs = _rows_of_block(xf, padded, first, total, block, k)
+        put = jax.lax.dynamic_update_slice_in_dim
+        with jax.named_scope("moe_combine"):
+            # dy[token of row], and times the weight of the row (0 on a dead
+            # row: its slot's expert is elsewhere)
+            dy_rows = jnp.take(dy, slots // k, axis=0)
+            w_rows = jnp.take(weights.reshape(-1), slots)[:, None]
+            dys = jnp.where(live, dy_rows * w_rows, 0.0).astype(dtype)
+        with jax.named_scope("expert_ffn"):
+            sizes = _sizes_within(load, first, block)
+            # a dead row of a live block is whatever a product left there:
+            # selected away here, so that what follows is 0 on it
+            g, u, dh = (jnp.where(live, _grouped(*of, sizes), 0.0) for of in (
+                (xs, gate), (xs, up), (dy_rows.astype(dtype), down_t)))
+            s = jax.nn.sigmoid(g)
+            h = g * s * u
+            # through `down` once for both: the weight's gradient is the
+            # row's <dy, y> = <dy down^T, h>, the rows' is w * dy down^T
+            dw = jnp.sum(dh * h, axis=-1)
+            dh = dh * w_rows
+            dg = (dh * u * s * (1.0 + g * (1.0 - s))).astype(dtype)
+            du = (dh * g * s).astype(dtype)
+            dx = _grouped(dg, gate_t, sizes) + _grouped(du, up_t, sizes)
+            dx = jnp.where(live, dx, 0.0).astype(dtype)
+        with jax.named_scope("moe_combine"):
+            dws = put(dws, dw, first, 0)
+        return (put(dxs, dx, first, 0), dws, put(xs_c, xs, at, 0),
+                put(dys_c, dys, at, 0), put(dg_c, dg, at, 0),
+                put(du_c, du, at, 0), put(h_c, h.astype(dtype), at, 0))
+
+    def one_chunk(c, carry):
+        first = c * chunk
+        rows = jax.lax.fori_loop(
+            0, blocks_of(jnp.minimum(total - first, chunk), block),
+            lambda b, rows: one_block(first + b * block, b * block, rows),
+            carry[:-1])
+        with jax.named_scope("expert_ffn"):
+            # stale rows of the last chunk lie past every group
+            sizes = _sizes_within(load, first, chunk)
+            xs_c, dys_c, dg_c, du_c, h_c = rows[2:]
+            # the first chunk's IS the sum so far: no pass over zeros
+            kernels = tuple(
+                jax.lax.cond(c == 0, lambda so_far, new: new, jnp.add, so_far,
+                             _grouped(left, right, sizes, _ROWS_BY_ROWS))
+                for so_far, left, right in zip(
+                    carry[-1], (xs_c, xs_c, h_c), (dg_c, du_c, dys_c)))
+        return (*rows, kernels)
+
+    dxs, dws, *_, (dgate, dup, ddown) = jax.lax.fori_loop(
+        0, blocks_of(total, chunk), one_chunk,
+        (jnp.zeros((m, d), dtype), jnp.zeros((m,), jnp.float32),
+         jnp.zeros((chunk, d), dtype), jnp.zeros((chunk, d), dtype),
+         jnp.zeros((chunk, f), dtype), jnp.zeros((chunk, f), dtype),
+         jnp.zeros((chunk, f), dtype),
+         (jnp.zeros((held, d, f), jnp.float32),
+          jnp.zeros((held, d, f), jnp.float32),
+          jnp.zeros((held, f, d), jnp.float32))))
+    with jax.named_scope("moe_dispatch"):
+        # dx[n] = sum over the token's K slots of dxs[row of that slot]
+        dx = jnp.sum(jnp.take(dxs, row_of_slot.T, axis=0).astype(jnp.float32),
+                     axis=0).astype(xf.dtype)
+    with jax.named_scope("moe_combine"):
+        dweights = jnp.take(dws, row_of_slot, axis=0)             # scalars
+    return (dx, dweights, None, None, None, dgate.astype(dtype),
+            dup.astype(dtype), ddown.astype(dtype))
+
+
+routed_experts.defvjp(_routed_fwd, _routed_bwd)
 
 
 def choose(scores: Array, bias, k: int, groups: int, groups_kept: int):
@@ -214,30 +379,22 @@ class SharedRoutedExperts(nn.Module):
             load = jnp.sum(jax.nn.one_hot(group, held, dtype=jnp.int32),
                            axis=0)                                # (held,)
             self.sow("intermediates", "expert_load", load)
+            block = block_rows(n * k, held, self.experts_routed)
+            self.sow("intermediates", "expert_rows_computed",
+                     blocks_of(jnp.sum(load), block) * block)
 
         with jax.named_scope("moe_dispatch"):
             slot_of_row = jnp.argsort(group, stable=True)         # (M,)
             row_of_slot = jnp.argsort(slot_of_row).reshape(n, k)
-            in_a_group = jnp.arange(n * k) < jnp.sum(load)
-            xs = dispatch(xf, slot_of_row, row_of_slot)
-            xs = jnp.where(in_a_group[:, None], xs, jnp.zeros((), xs.dtype))
 
         with jax.named_scope("expert_ffn"):
-            def kernels(name, a, b):
-                return Table((held, a, b), name=name)().astype(self.dtype)
-
-            def grouped(rows, w):
-                return jax.lax.ragged_dot(
-                    rows, w, load, preferred_element_type=jnp.float32)
-
-            h = (nn.silu(grouped(xs, kernels("experts_gate", d, self.expert_dim)))
-                 * grouped(xs, kernels("experts_up", d, self.expert_dim)))
-            ys = grouped(h.astype(self.dtype),
-                         kernels("experts_down", self.expert_dim, d))
-            ys = jnp.where(in_a_group[:, None], ys, 0.0).astype(self.dtype)
-
-        with jax.named_scope("moe_combine"):
-            y = combine(ys, weights, slot_of_row, row_of_slot)
+            gate, up, down = (
+                Table((held, a, b), name=name)().astype(self.dtype)
+                for name, a, b in (("experts_gate", d, self.expert_dim),
+                                   ("experts_up", d, self.expert_dim),
+                                   ("experts_down", self.expert_dim, d)))
+        y = routed_experts(xf, weights, slot_of_row, row_of_slot, load,
+                           gate, up, down, block)
 
         if self.shared_dim:
             with jax.named_scope("shared_expert"):

@@ -66,11 +66,14 @@ REQUIRED_STEP_KEYS = (
 
 # (record key, counter of pairs the mask lets through, counter of pairs the
 # attention kernels compute for them): how much of the kernels' work some
-# query sees, 1.0 at best (vitax/ops/flash_blocked.py: computed_pairs)
+# query sees, 1.0 at best (vitax/ops/flash_blocked.py: computed_pairs); the
+# last, the same of the expert layers: sorted rows their loops worked on over
+# the slots whose expert the chip holds (vitax/models/experts.py: block_rows)
 COMPUTED_OVER_NEEDED = (
     ("attn_computed_over_needed", "token_pairs", "computed_pairs"),
     ("causal_computed_over_needed", "causal_pairs", "causal_computed_pairs"),
     ("window_computed_over_needed", "window_pairs", "window_computed_pairs"),
+    ("expert_rows_over_slots", "expert_slots_here", "expert_rows_computed"),
 )
 
 
@@ -133,8 +136,10 @@ class Recorder:
         the record holds computed / needed: `attn_computed_over_needed`, or
         `causal_` / `window_computed_over_needed`. A decoder step's
         (`targets`, `causal_pairs`, `window_pairs`, `expert_slots_here` in
-        place of `token_pairs`; `images` are documents; a scan's `ssd_pairs`
-        and `ssd_live_chunks`, a delta rule's `kda_pairs` and
+        place of `token_pairs`, and beside it `expert_rows_computed`, the
+        sorted rows the expert layers' loops worked on for those slots, with
+        `expert_rows_over_slots`; `images` are documents; a scan's
+        `ssd_pairs` and `ssd_live_chunks`, a delta rule's `kda_pairs` and
         `kda_live_chunks`, a grouped router's `tokens_choosing_held_group`,
         a balanced router's `route_load_max_over_mean`) are written into the
         record as they are, with `expert_load`, its per-layer per-expert
@@ -184,7 +189,8 @@ class Recorder:
         if packed_counts is not None and self.cfg.decoder:
             record.update({k: packed_counts[k] for k in (
                 "targets", "causal_pairs", "window_pairs",
-                "expert_slots_here", "ssd_pairs", "ssd_live_chunks",
+                "expert_slots_here", "expert_rows_computed", "ssd_pairs",
+                "ssd_live_chunks",
                 "kda_pairs", "kda_live_chunks", "tokens_choosing_held_group",
                 "route_load_max_over_mean")
                 if k in packed_counts}, expert_load=expert_load)

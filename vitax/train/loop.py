@@ -124,9 +124,9 @@ def _attention_remat_note(cfg: Config, model, mesh) -> str:
 # step record carries them
 DECODER_COUNTERS = ("tokens", "padding_tokens", "images", "targets",
                     "causal_pairs", "window_pairs", "causal_computed_pairs",
-                    "window_computed_pairs", "expert_slots_here",
-                    # a model with mamba layers only:
-                    "ssd_pairs", "ssd_live_chunks",
+                    "window_computed_pairs",
+                    "expert_slots_here", "expert_rows_computed",
+                    "ssd_pairs", "ssd_live_chunks",     # mamba layers only
                     # ... with kda layers, with a router that has groups:
                     "kda_pairs", "kda_live_chunks",
                     "tokens_choosing_held_group",
