@@ -598,3 +598,41 @@ def test_the_lfm2_moe_cells_step_compiles_and_fits(chip, mosaic):
     assert {"gconv_in", "gconv", "gconv_out", "qk_norm", "rope1d",
             "moe_route", "moe_dispatch", "expert_ffn", "moe_combine",
             "lm_head_loss"} <= found, found
+
+
+def test_the_four_chip_cells_gradients_leave_through_the_ring(chip, mosaic):
+    """The whole train step of `vit10b_fsdp4_train_b8` (ZeRO-3 over the four
+    described chips, the 10B widths), through the cell's own
+    `lower_described`: the backward scan's body holds NO block-sized
+    synchronous reduce (the parent's four `fusion kind=kCustom
+    calls=%all-reduce-scatter`, which no option of this compiler runs
+    asynchronously: PERF.md, PR 50) and the ring's `fsdp - 1` = 3
+    `collective-permute-start`s for each of the four block matrices and
+    each of the two directions, on a bfloat16 wire; the forward body holds
+    neither; the program fits the
+    15.75 GiB the compiler allows and stays under the 12.6 GB ISSUE 50
+    holds `step_hbm_gb` to."""
+    from benchmark import harness
+    from benchmark import manifest as mf
+    from vitax.analysis import hlo
+    _, topo = chip
+    man = mf.Manifest()
+    cell = man.cell("vit10b_fsdp4_train_b8")
+    config, traffic = man.config(cell["config"]), man.traffic(cell["traffic"])
+    lowered, _ = mf.generator(traffic["kind"]).lower_described(
+        man.config_kwargs(config), traffic, list(topo.devices)[:cell["chips"]])
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    width, fsdp = config["embed_dim"], cell["chips"]
+    verdict = hlo.overlap_verdict(text, min_reduce_numel=width * width // fsdp)
+    forward, backward = list(verdict["ring_permutes_by_body"])
+    assert verdict["sync_block_reduces"] == 0, verdict
+    assert verdict["ring_permutes_by_body"] == {
+        forward: 0, backward: 2 * 4 * (fsdp - 1)}, verdict
+    wires = [ln.split(" = ", 1)[1].split(" collective-permute-start(")[0]
+             for ln in hlo.split_computations(text)[backward]
+             if " collective-permute-start(" in ln]
+    assert len(wires) == 2 * 4 * (fsdp - 1), wires
+    assert all(w.lstrip("(").startswith("bf16[") for w in wires), wires
+    step_bytes = harness.program_facts(compiled)["step_bytes"]
+    assert step_bytes <= 12.6e9 < 16.909e9, step_bytes

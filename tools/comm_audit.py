@@ -54,6 +54,7 @@ from vitax.analysis.hlo import (  # noqa: E402  (sys.path fix must precede)
     split_computations as _split_computations,
     summarize,
 )
+from vitax.parallel.mesh import MESH_AXES, resolve_mesh_shape  # noqa: E402
 
 __all__ = [
     "COLLECTIVE_RE", "DTYPE_BYTES", "collect_collectives", "summarize",
@@ -86,7 +87,9 @@ def audit_config(cfg):
             r for r in rows
             if r["op"] == "all-gather" and r["dtype"] == "f32"
             and r["numel"] >= block_numel],
-        "overlap": overlap_verdict(hlo_text),
+        "overlap": overlap_verdict(
+            hlo_text, min_reduce_numel=block_numel // max(
+                resolve_mesh_shape(cfg)[MESH_AXES.index("fsdp")], 1)),
     }
 
 
@@ -113,7 +116,9 @@ def format_report(report):
         lines.append(
             f"  overlap ({c.get('gather_overlap', '?')}): "
             f"{ov['gathers_in_scan_body']} gathers in scan bodies, "
-            f"{ov['prefetch_slot_gathers']} on the prefetch slot")
+            f"{ov['prefetch_slot_gathers']} on the prefetch slot; "
+            f"{ov['sync_block_reduces']} block-sized synchronous reduces, "
+            f"{ov['ring_permutes']} ring permutes")
     return "\n".join(lines)
 
 

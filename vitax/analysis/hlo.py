@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import collections
 import glob
+import math
 import os
 import re
 import shutil
@@ -124,6 +125,16 @@ TRIVIAL_OPS = frozenset({
 INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*.*?\s([\w\-]+)\((.*)$")
 _OPERAND_RE = re.compile(r"%?([\w.\-]+)")
+_COMMENT_RE = re.compile(r"/\*.*?\*/")
+# `bf16[5120,1280]` in an instruction's type, tuple or not
+_ARRAY_TYPE_RE = re.compile(r"\b([a-z]\w*)\[([\d,]*)\]")
+# the synchronous reduces a scan body can hold: the ops themselves (their
+# `-start` halves are the asynchronous form) and the TPU compiler's fused
+# all-reduce + dynamic-slice, `fusion(...), kind=kCustom,
+# calls=%all-reduce-scatter.N`
+SYNC_REDUCE_OPS = frozenset({"all-reduce", "reduce-scatter"})
+_REDUCE_FUSION_RE = re.compile(r"calls=%?all-reduce-scatter")
+RING_PERMUTE_OPS = frozenset({"collective-permute", "collective-permute-start"})
 
 
 def split_computations(hlo_text: str) -> Dict[str, List[str]]:
@@ -138,7 +149,9 @@ def split_computations(hlo_text: str) -> Dict[str, List[str]]:
     header = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\b[^=]*{\s*$")
     for line in hlo_text.splitlines():
         if name is None:
-            m = header.match(line)
+            # a tuple type of more than five elements prints `/*index=5*/`
+            # marks, whose `=` is no instruction's
+            m = header.match(_COMMENT_RE.sub("", line))
             if m:
                 name, lines = m.group(1), []
         elif line.startswith("}"):
@@ -185,6 +198,39 @@ def parse_instructions(lines: List[str]) -> Tuple[Dict[str, Tuple[str, List[str]
     return instrs, root
 
 
+def instruction_types(lines: List[str]) -> Dict[str, Tuple[str, str]]:
+    """{name: (type text, attribute text)} of one computation's instruction
+    lines: what stands between `=` and the op, and what follows the operand
+    list's opening parenthesis (operands, then `kind=`, `calls=` ...)."""
+    out: Dict[str, Tuple[str, str]] = {}
+    for line in lines:
+        line = _COMMENT_RE.sub("", line)
+        m = INSTR_RE.match(line)
+        if m:
+            head = line[:m.start(2)]
+            out[m.group(1)] = (head[head.index("=") + 1:], m.group(3))
+    return out
+
+
+_CALL_RE = re.compile(r"\scall\(.*\bto_apply=%?([\w.\-]+)")
+
+
+def _with_called(comps: Dict[str, List[str]],
+                 lines: List[str]) -> List[List[str]]:
+    """A computation's lines and, after them, those of every computation a
+    `call` in it applies, transitively."""
+    out, seen, todo = [], set(), [lines]
+    while todo:
+        cur = todo.pop()
+        out.append(cur)
+        for line in cur:
+            m = _CALL_RE.search(line)
+            if m and m.group(1) not in seen and m.group(1) in comps:
+                seen.add(m.group(1))
+                todo.append(comps[m.group(1)])
+    return out
+
+
 def while_body_op_inventory(hlo_text: str) -> Dict[str, Dict[str, int]]:
     """Per while-loop body: {op name: count} over its instructions — the
     cheap structural fingerprint of what a scan iteration executes."""
@@ -201,7 +247,35 @@ def while_body_op_inventory(hlo_text: str) -> Dict[str, Dict[str, int]]:
     return out
 
 
-def overlap_verdict(hlo_text: str) -> dict:
+def matrix_numel(type_text: str) -> int:
+    """Elements of the largest MATRIX in an instruction's type: an array with
+    at least two dimensions above 1 (a bias or a norm scale, stacked or not,
+    is a vector and counts 0)."""
+    best = 0
+    for _, dims in _ARRAY_TYPE_RE.findall(type_text):
+        sizes = [int(d) for d in dims.split(",") if d]
+        if sum(1 for d in sizes if d > 1) >= 2:
+            best = max(best, math.prod(sizes))
+    return best
+
+
+def _is_sync_block_reduce(name: str, op: str, operands: List[str],
+                          types: Dict[str, Tuple[str, str]],
+                          min_numel: int) -> bool:
+    """Whether one instruction is a synchronous reduce (SYNC_REDUCE_OPS, or
+    the fused all-reduce-scatter) of a matrix of at least `min_numel`
+    elements: the larger of its own type's and its operands' (the fused
+    form's result is the shard, its operand the whole matrix)."""
+    own_type, attrs = types[name]
+    if not (op in SYNC_REDUCE_OPS
+            or (op == "fusion" and _REDUCE_FUSION_RE.search(attrs))):
+        return False
+    largest = max([matrix_numel(own_type)]
+                  + [matrix_numel(types[o][0]) for o in operands if o in types])
+    return largest >= max(min_numel, 1)   # a vector (0) never counts
+
+
+def overlap_verdict(hlo_text: str, min_reduce_numel: int = 0) -> dict:
     """Structural check of the --gather_overlap schedule.
 
     Locates every while-loop body in the partitioned module and, per body,
@@ -211,21 +285,44 @@ def overlap_verdict(hlo_text: str) -> dict:
     Use-site gathers — what the plain ZeRO-3 scan has — are consumed by a
     convolution/dot/fusion before any carry, so they never qualify.
 
+    The backward's half (the gradient ring, sharding.ring_weight_grad): per
+    body, `sync_block_reduces` counts the synchronous reduces (SYNC_REDUCE_OPS
+    and the fused all-reduce-scatter) of a matrix of at least
+    `min_reduce_numel` elements; the caller passes a block matrix's shard, so
+    a bias or a norm scale never counts. `ring_permutes` counts the body's
+    collective-permutes (`-start` where the compiler made a pair of it). The
+    plain schedule has one such reduce a block matrix and no permute; the
+    ring fsdp - 1 permutes a matrix and no reduce.
+
     Returns {gathers_in_scan_body, prefetch_slot_gathers,
-    per_iteration_gather_count: {body: count}, prefetch_slot_by_body} — the
-    `--json` overlap verdict the tier-1 suite asserts on (gather count
-    unchanged between off and on; prefetch-slot gathers appear only under
-    on)."""
+    per_iteration_gather_count: {body: count}, prefetch_slot_by_body,
+    sync_block_reduces, sync_block_reduces_by_body, ring_permutes,
+    ring_permutes_by_body} — the `--json` overlap verdict the tier-1 suite
+    asserts on (gather count unchanged between off and on; prefetch-slot
+    gathers appear only under on)."""
     comps = split_computations(hlo_text)
     bodies = while_bodies(hlo_text)
 
     per_body = {}
     slot_by_body = {}
+    reduces_by_body = {}
+    permutes_by_body = {}
     for body in bodies:
         lines = comps.get(body)
         if lines is None:
             continue
         instrs, root = parse_instructions(lines)
+        reduces_by_body[body] = permutes_by_body[body] = 0
+        # the body and what it `call`s: before the backend inlines it, a
+        # shard_map's body is a computation of its own
+        for called in _with_called(comps, lines):
+            c_instrs, _ = parse_instructions(called)
+            types = instruction_types(called)
+            reduces_by_body[body] += sum(
+                _is_sync_block_reduce(n, op, operands, types, min_reduce_numel)
+                for n, (op, operands) in c_instrs.items())
+            permutes_by_body[body] += sum(
+                1 for op, _ in c_instrs.values() if op in RING_PERMUTE_OPS)
         gathers = {n for n, (op, _) in instrs.items()
                    if op in ("all-gather", "all-gather-start")}
         per_body[body] = len(gathers)
@@ -253,6 +350,10 @@ def overlap_verdict(hlo_text: str) -> dict:
         "prefetch_slot_gathers": sum(slot_by_body.values()),
         "per_iteration_gather_count": per_body,
         "prefetch_slot_by_body": slot_by_body,
+        "sync_block_reduces": sum(reduces_by_body.values()),
+        "sync_block_reduces_by_body": reduces_by_body,
+        "ring_permutes": sum(permutes_by_body.values()),
+        "ring_permutes_by_body": permutes_by_body,
     }
 
 
@@ -351,6 +452,13 @@ _MLIR_TYPE_RE = re.compile(
     r"tensor<([x\d]*?)(?:x)?([a-z]+\d+(?:[A-Z][A-Z0-9]*)?|i1)>")
 _MLIR_SHARDING_RE = re.compile(r'mhlo\.sharding\s*=\s*"([^"]*)"')
 _MLIR_DONOR_RE = re.compile(r"tf\.aliasing_output\s*=\s*(\d+)")
+# Shardy's form of the same (jax 0.9 lowers with it): the argument carries
+# `sdy.sharding = #sdy.sharding<@mesh, [{}, {"fsdp"}]>` (one brace group a
+# dimension, the mesh axes that shard it inside) and the module declares
+# `sdy.mesh @mesh = <["dp"=1, "fsdp"=8, ...]>`
+_SDY_SHARDING_RE = re.compile(r"sdy\.sharding\s*=\s*#sdy\.sharding<@\w+,\s*"
+                              r"(\[.*?\])\s*(?:,[^>]*)?>")
+_SDY_MESH_AXIS_RE = re.compile(r'"(\w+)"=(\d+)')
 
 _MLIR_DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8": 1,
@@ -365,7 +473,9 @@ def mlir_main_args(mlir_text: str) -> List[dict]:
 
     Returns [{index, dtype, shape, numel, bytes, sharding, donated_to}] in
     argument order. `sharding` is the raw OpSharding string ("{replicated}",
-    "{devices=[1,8]<=[8]}", ...) or None when unannotated; `donated_to` is
+    "{devices=[1,8]<=[8]}", ...), Shardy's per-dimension axis lists
+    (`[{}, {"fsdp"}]`, axes of one device left out), or None when
+    unannotated; `donated_to` is
     the flat output index the buffer is donated to (`tf.aliasing_output`)
     or None for non-donated args. This is the only artifact where donation
     and sharding are still attached to *arguments* rather than anonymous
@@ -379,6 +489,12 @@ def mlir_main_args(mlir_text: str) -> List[dict]:
     # sidesteps brace-matching the attr dict, whose sharding strings nest
     # braces inside quotes
     parts = re.split(r"%arg(\d+)\s*:", m.group(1))
+    # an axis of one device shards nothing: left out of a Shardy sharding,
+    # so that the string names only what really splits the array
+    mesh = re.search(r"sdy\.mesh\s+@\w+\s*=\s*<\[(.*?)\]>", mlir_text)
+    unit_axes = re.compile("|".join(
+        rf'"{a}",?\s*' for a, n in _SDY_MESH_AXIS_RE.findall(
+            mesh.group(1) if mesh else "") if int(n) == 1) or "$^")
     out = []
     for i in range(1, len(parts) - 1, 2):
         idx = int(parts[i])
@@ -390,6 +506,10 @@ def mlir_main_args(mlir_text: str) -> List[dict]:
             shape = tuple(int(d) for d in tm.group(1).split("x") if d)
             dtype = tm.group(2)
         sm = _MLIR_SHARDING_RE.search(body)
+        sharding = sm.group(1) if sm else None
+        sdy = _SDY_SHARDING_RE.search(body)
+        if sharding is None and sdy:
+            sharding = unit_axes.sub("", sdy.group(1))
         dm = _MLIR_DONOR_RE.search(body)
         numel = 1
         for d in shape:
@@ -398,7 +518,7 @@ def mlir_main_args(mlir_text: str) -> List[dict]:
             "index": idx, "dtype": dtype, "shape": list(shape),
             "numel": numel,
             "bytes": numel * _MLIR_DTYPE_BYTES.get(dtype, 4),
-            "sharding": sm.group(1) if sm else None,
+            "sharding": sharding,
             "donated_to": int(dm.group(1)) if dm else None,
         })
     return out
@@ -413,6 +533,8 @@ def sharding_is_replicated(sharding: Optional[str]) -> bool:
     if sharding is None:
         return True
     s = sharding.strip()
+    if s.startswith("["):   # Shardy's per-dimension axis lists
+        return '"' not in s
     if "replicated" in s or "maximal" in s:
         return "devices=" not in s
     # "{devices=[1,1,8]<=[8] last_tile_dim_replicate}" with ALL non-trailing

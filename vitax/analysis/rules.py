@@ -218,13 +218,19 @@ def _overlap_requested(cfg: Config) -> bool:
       "with --gather_overlap active, every per-iteration forward all-gather "
       "must sit on the scan carry's prefetch slot (reach the while body ROOT "
       "through layout plumbing only) — a use-site gather means the double "
-      "buffering silently degraded to the serial schedule (PR 3)",
+      "buffering silently degraded to the serial schedule (PR 3); and no "
+      "block-sized synchronous reduce in a scan body under --gather_overlap "
+      "— on a mesh that shards over fsdp alone every block matrix's gradient "
+      "leaves through the ring's collective-permutes (PR 50)",
       applies_to=_overlap_requested)
 def check_gather_overlap(program: Program, cfg: Config) -> List[Finding]:
     r = GATHER_OVERLAP
-    if program.mesh_shape.get("fsdp", 1) <= 1:
+    fsdp = program.mesh_shape.get("fsdp", 1)
+    if fsdp <= 1:
         return []  # nothing to overlap on an unsharded fsdp axis
-    verdict = hlo.overlap_verdict(program.partitioned_hlo)
+    verdict = hlo.overlap_verdict(
+        program.partitioned_hlo,
+        min_reduce_numel=cfg.embed_dim * cfg.embed_dim // fsdp)
     per_body = verdict["per_iteration_gather_count"]
     if not per_body:
         return [_finding(r, program,
@@ -246,6 +252,18 @@ def check_gather_overlap(program: Program, cfg: Config) -> List[Finding]:
             f"{n_gathers - on_slot} of {n_gathers} forward in-loop gathers "
             f"are use-site gathers (not on the prefetch slot): the overlap "
             f"schedule regressed to serial gather-then-compute",
+            verdict=verdict)]
+    # the sum over any other axis (dp, tp) is the partitioner's, and a
+    # synchronous reduce of a shard is what it makes of one
+    fsdp_alone = all(size == 1 for axis, size in program.mesh_shape.items()
+                     if axis != "fsdp")
+    if fsdp_alone and verdict["sync_block_reduces"]:
+        return [_finding(
+            r, program,
+            f"{verdict['sync_block_reduces']} block-sized synchronous "
+            f"reduce(s) in a scan body ({verdict['ring_permutes']} ring "
+            f"permutes): a block matrix's gradient left the ring for the "
+            f"compiler's reduce-scatter, which no TPU schedule overlaps",
             verdict=verdict)]
     return []
 

@@ -225,8 +225,11 @@ class Config:
     #   body issues the all-gather (over "fsdp") for block k+1, so the
     #   collective overlaps block k's matmuls instead of serializing in front
     #   of them (XLA's latency-hiding scheduler cannot hoist a gather across a
-    #   lax.scan iteration boundary). auto = enable when ZeRO-3 + scanned
-    #   blocks + per-block remat (none_saveable) are active; off = the exact
+    #   lax.scan iteration boundary). Its backward computes and
+    #   reduce-scatters each block matrix's gradient in one ring over "fsdp"
+    #   (vitax/parallel/sharding.py ring_weight_grad). auto = enable when
+    #   ZeRO-3 + scanned blocks + per-block remat (none_saveable) are active
+    #   (sharding.gather_overlap_active); off = the exact
     #   pre-overlap program; on = require it (validate() rejects configs the
     #   schedule cannot serve: pp, ZeRO-2/DP, unscanned blocks, no-remat).
     gather_overlap: str = "auto"        # auto | off | on

@@ -859,6 +859,17 @@ def make_overlap_forward(cfg: Config, model: "VisionTransformer", mesh,
     direct stacked-tree route, so the carry chain carries no gradient and
     AD never materializes a gathered tree it would have to keep.
 
+    The backward's weight gradients (PR 50): the Blocks are applied under
+    `ring_dense_sites`, so each block matrix's gradient is computed and
+    reduce-scattered over "fsdp" in one ring of chunked products and
+    `ppermute` hops (vitax/parallel/sharding.py:ring_weight_grad) and
+    arrives here already in the stacked tree's layout: the window write
+    below needs no collective, and the whole product + synchronous
+    all-reduce-scatter fusion the TPU compiler made of each of them (a
+    seventh of the four-chip cell's busy time, which no compiler option
+    overlaps) is gone. Biases and norm scales keep the compiler's small
+    reduces. The forward is untouched.
+
     Dropout keys and the MoE aux ingredients thread through exactly like
     make_windowed_forward (same (seed, step) -> same masks; raw frac/prob
     stacks under with_aux == "raw"). pp is excluded (Config.validate)."""
@@ -867,6 +878,7 @@ def make_overlap_forward(cfg: Config, model: "VisionTransformer", mesh,
     w = cfg.remat_window if cfg.remat_window > 1 else 1
     groups = cfg.num_blocks // w
     block = Block(**model.block_kwargs())  # keeps the activation anchors
+    ring_sites = ring_dense_sites(mesh, block_specs)
     policy = _REMAT_POLICIES[cfg.remat_policy]
     dtype = model.dtype
     moe = cfg.moe_experts > 0
@@ -898,20 +910,24 @@ def make_overlap_forward(cfg: Config, model: "VisionTransformer", mesh,
 
         def apply_group(carry, gparams, gkey_data):
             aux = []
-            for i in range(w):
-                layer = jax.tree.map(lambda g: g[i], gparams)
-                rngs = ({"dropout": jax.random.wrap_key_data(gkey_data[i])}
-                        if use_keys else None)
-                if collect_aux:
-                    carry, cols = block.apply(
-                        {"params": layer}, carry, det, rngs=rngs,
-                        mutable=["intermediates"])
-                    m = cols["intermediates"]["moe"]
-                    aux.append((m["moe_frac_tokens"][0],
-                                m["moe_mean_prob"][0]))
-                else:
-                    carry = block.apply({"params": layer}, carry, det,
-                                        rngs=rngs)
+            # every Dense of a block runs with the ring for its kernel's
+            # gradient (ring_dense_sites, at the end of this module)
+            with nn.intercept_methods(ring_sites):
+                for i in range(w):
+                    layer = jax.tree.map(lambda g: g[i], gparams)
+                    rngs = ({"dropout":
+                             jax.random.wrap_key_data(gkey_data[i])}
+                            if use_keys else None)
+                    if collect_aux:
+                        carry, cols = block.apply(
+                            {"params": layer}, carry, det, rngs=rngs,
+                            mutable=["intermediates"])
+                        m = cols["intermediates"]["moe"]
+                        aux.append((m["moe_frac_tokens"][0],
+                                    m["moe_mean_prob"][0]))
+                    else:
+                        carry = block.apply({"params": layer}, carry, det,
+                                            rngs=rngs)
             if not aux:
                 return carry, ()
             return carry, (jnp.stack([a[0] for a in aux]),
@@ -1109,3 +1125,34 @@ def block_remat_policy(model: VisionTransformer):
     if keeps_attention_residuals(model):
         return _attention_kernel_saveable
     return _REMAT_POLICIES[model.remat_policy]  # KeyError on unknown names
+
+
+# --- the ring under the overlap schedule's matmul sites ---------------------
+# (At the end for the reason above: no line a kernel's call stack passes
+# through moves.)
+
+
+def ring_dense_sites(mesh, block_specs):
+    """The Flax method interceptor `make_overlap_forward` applies its Blocks
+    under: every `nn.Dense` of a block (qkv, proj, fc1, fc2; an MoE block's
+    router) runs as itself with `ring_dot_general` for its `dot_general`, so
+    its kernel's gradient is computed and reduce-scattered in one ring
+    (vitax/parallel/sharding.py:ring_weight_grad). The spec is the stacked
+    tree's at the site's own path, less the layer dimension. Parameter paths
+    are the site's own: the stand-in binds to the site's scope. Nothing
+    outside that forward sees it: the plain scan, one-chip programs, serving
+    and QuantDense build the Dense they always built."""
+    from vitax.parallel.sharding import ring_dot_general
+
+    def intercept(next_fun, args, kwargs, context):
+        site = context.module
+        if (type(site) is not nn.Dense or context.method_name != "__call__"
+                or site.dot_general is not None):
+            return next_fun(*args, **kwargs)
+        spec = block_specs
+        for name in site.path:
+            spec = spec[name]
+        ring = ring_dot_general(mesh, P(*spec["kernel"][1:]))
+        return site.clone(parent=site.scope, dot_general=ring)(*args, **kwargs)
+
+    return intercept

@@ -37,7 +37,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from vitax.config import Config
-from vitax.parallel.mesh import BATCH_AXES
+from vitax.parallel.mesh import BATCH_AXES, shard_map
 
 PyTree = Any
 
@@ -276,7 +276,10 @@ def prefetch_gather(stacked: PyTree, start, length: int,
     Composes with the comm-precision cast (cast_to_compute): the cast runs on
     the sharded stacked tree before the forward, so under the bf16 policy the
     prefetched gather moves bf16 bytes (KEEP_F32_PARAMS leaves gather f32,
-    as at the use sites).
+    as at the use sites). The same call re-gathers a group in the schedule's
+    backward; the gradients of what it gathered come back sharded, through
+    `ring_weight_grad` below (the block matrices) and the compiler's small
+    reduces (biases, norm scales).
 
     `block_specs` is the PartitionSpec tree of the stacked block params (the
     `state_specs.params["params"]["blocks"]` subtree); the returned tree holds
@@ -303,6 +306,157 @@ def prefetch_gather(stacked: PyTree, start, length: int,
         return jax.lax.with_sharding_constraint(s, sh_out)
 
     return jax.tree.map(leaf, sharded, gathered, stacked)
+
+
+def ring_order(mesh: Mesh) -> Tuple[int, ...]:
+    """The "fsdp" indices in the order a ring over that axis visits them.
+
+    `build_mesh` lays devices out in `jax.devices()` order, and on a v5e
+    2 x 2 that order is (0,0) (1,0) (0,1) (1,1): a ring in index order makes
+    two of its four hops across the diagonal, two links each. Where the
+    devices say where they sit (`coords`), the ring walks from index 0 to
+    the nearest chip not yet visited (0 1 3 2 on the 2 x 2: every hop one
+    link); where they do not (the CPU's devices), index order."""
+    line = np.moveaxis(mesh.devices, mesh.axis_names.index("fsdp"), 0)
+    line = line.reshape(line.shape[0], -1)[:, 0]
+    coords = [getattr(d, "coords", None) for d in line]
+    if any(c is None for c in coords):
+        return tuple(range(len(line)))
+    order = [0]
+    while len(order) < len(line):
+        here = coords[order[-1]]
+        order.append(min(
+            (i for i in range(len(line)) if i not in order),
+            key=lambda i: sum(abs(a - b) for a, b in zip(here, coords[i]))))
+    return tuple(order)
+
+
+def ring_dim(spec: P, shape: Tuple[int, int], mesh: Mesh) -> Optional[int]:
+    """The dimension of an (in, out) kernel that `ring_weight_grad` cuts in
+    chunks: the one its spec places on "fsdp", if the axis shards and
+    divides it. None: the leaf keeps the plain product."""
+    fsdp = mesh.shape.get("fsdp", 1)
+    dims = [d for d, ax in enumerate(spec) if ax == "fsdp"]
+    if fsdp == 1 or len(dims) != 1 or shape[dims[0]] % fsdp:
+        return None
+    return dims[0]
+
+
+def ring_weight_grad(x: jax.Array, dy: jax.Array, mesh: Mesh, spec: P,
+                     dtype: Any) -> jax.Array:
+    """A matmul site's kernel gradient, x^T dy summed over every chip's rows,
+    computed and reduce-scattered over "fsdp" in one ring ("collective
+    matmul"): the ZeRO-3 backward of --gather_overlap.
+
+    The whole product followed by GSPMD's reduce-scatter lowers on the TPU to
+    a fused all-reduce + dynamic-slice that no compiler option runs
+    asynchronously (PERF.md, PR 50): a seventh of the step's busy time with
+    the MXU idle. Here the dimension `spec` shards on "fsdp" (columns of dy
+    for qkv, proj and fc1; columns of x for fc2) is cut in fsdp chunks. Chip
+    i starts with its partial product of chunk i - 1; each of the fsdp - 1
+    steps after it sends the running sum one chip down the ring
+    (`ppermute`, the collective the TPU compiler does overlap) and adds the
+    partial product of the next chunk up, so chip i ends with chunk i summed
+    over all chips: the shard it owns, in the stacked tree's own layout.
+    Half of every chunk goes round the ring each way (`ring_order` says
+    which chips are neighbours), where a chunk halves. Partial products
+    accumulate in float32 as the MXU gives them and the incoming sum is
+    added in float32; the wire and the result are `dtype` (what the plain
+    schedule reduces in: the gathered kernel's dtype).
+
+    x (B, ..., in) and dy (B, ..., out) are a site's rows with the batch over
+    BATCH_AXES. Manual on "fsdp" alone: a "tp" / "ep" placement and the sum
+    over "dp" stay the partitioner's. Where `ring_dim` finds nothing to cut,
+    or the batch does not split over dp x fsdp, the plain product."""
+    n, dp = mesh.shape.get("fsdp", 1), mesh.shape.get("dp", 1)
+    dim = ring_dim(spec, (x.shape[-1], dy.shape[-1]), mesh)
+    rows = tuple(range(x.ndim - 1))
+    if dim is None or x.shape[0] % (dp * n):
+        return jax.lax.dot_general(x, dy, ((rows, rows), ((), ()))).astype(dtype)
+
+    order = ring_order(mesh)
+    place = np.argsort(order)        # a chip's index -> its place in the ring
+
+    def ring(x, dy):
+        x, dy = x.reshape(-1, x.shape[-1]), dy.reshape(-1, dy.shape[-1])
+        me = jnp.asarray(place)[jax.lax.axis_index("fsdp")]
+        cut = x if dim == 0 else dy
+        width = cut.shape[-1] // n
+        # both directions at once where a chunk halves: a link carries half
+        # the bytes each way (one way left 6.7% of the four-chip cell's
+        # window waiting on a hop, both ways 0.9%: PERF.md, PR 50)
+        ways = (1, -1) if width % 2 == 0 else (1,)
+        part = width // len(ways)
+
+        def partial(owner, lane):
+            chunk = jax.lax.dynamic_slice_in_dim(
+                cut, owner * width + lane * part, part, 1)
+            a, b = (chunk, dy) if dim == 0 else (x, chunk)
+            return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                                       preferred_element_type=jnp.float32)
+
+        sums = []
+        for lane, way in enumerate(ways):
+            hop = [(order[j], order[(j + way) % n]) for j in range(n)]
+            owner = jnp.asarray(order)[(me - way) % n]
+            acc = partial(owner, lane).astype(dtype)
+            for step in range(1, n):
+                arrived = jax.lax.ppermute(acc, "fsdp", hop)
+                owner = jnp.asarray(order)[(me - way * (1 + step)) % n]
+                # a chunk's product waits for the sum it is added to: left
+                # free, the compiler runs every product at the top of the
+                # scan body and keeps them in float32 until their turn
+                # (six [5120, 5120] in the four-chip cell, 0.65 GB, and a
+                # slower step)
+                owner, arrived = jax.lax.optimization_barrier((owner, arrived))
+                acc = (partial(owner, lane)
+                       + arrived.astype(jnp.float32)).astype(dtype)
+            sums.append(acc)
+        return jnp.concatenate(sums, dim)
+
+    # the batch dim as (dp, fsdp, rest): the layout BATCH_AXES gives it
+    # already, so each chip rings the rows it holds and nothing moves
+    split = lambda a: a.reshape(dp, n, a.shape[0] // (dp * n), *a.shape[1:])
+    grad = shard_map(
+        ring, mesh, in_specs=(P(None, "fsdp"), P(None, "fsdp")),
+        out_specs=P(*("fsdp" if d == dim else None for d in range(2))),
+        axis_names={"fsdp"})(split(x), split(dy))
+    return jax.lax.with_sharding_constraint(grad, NamedSharding(mesh, spec))
+
+
+def ring_dot_general(mesh: Mesh, spec: P) -> Callable:
+    """The `dot_general` of an `nn.Dense` site whose (in, out) kernel carries
+    `spec`: forward and the rows' cotangent are `jax.lax.dot_general`'s, the
+    kernel's cotangent comes from `ring_weight_grad`. A kernel with nothing
+    for the ring to cut keeps `jax.lax.dot_general` whole."""
+
+    def dot_general(lhs, rhs, dimension_numbers, precision=None,
+                    preferred_element_type=None):
+        def plain(lhs, rhs):
+            return jax.lax.dot_general(
+                lhs, rhs, dimension_numbers, precision=precision,
+                preferred_element_type=preferred_element_type)
+
+        if ring_dim(spec, rhs.shape, mesh) is None:
+            return plain(lhs, rhs)
+
+        @jax.custom_vjp
+        def dot(lhs, rhs):
+            return plain(lhs, rhs)
+
+        def fwd(lhs, rhs):
+            return plain(lhs, rhs), (lhs, rhs)
+
+        def bwd(res, dy):
+            lhs, rhs = res
+            _, rows_vjp = jax.vjp(lambda l: plain(l, rhs), lhs)
+            return (*rows_vjp(dy),
+                    ring_weight_grad(lhs, dy, mesh, spec, rhs.dtype))
+
+        dot.defvjp(fwd, bwd)
+        return dot(lhs, rhs)
+
+    return dot_general
 
 
 def cast_to_compute(
