@@ -600,6 +600,55 @@ def test_the_lfm2_moe_cells_step_compiles_and_fits(chip, mosaic):
             "lm_head_loss"} <= found, found
 
 
+def test_the_smallthinker_cells_step_compiles_and_fits(chip, mosaic):
+    """The whole train step of `smallthinker_21b_a3b_ep8_train_longrow` at
+    the published widths (four layers, 370.5M parameters with Adam's state,
+    one row of 16,384 tokens), through the cell's own `lower_described`: the
+    chip's compiler takes both attention kernels at 7 query heads a key/value
+    head (28 over 4 of 128) and a window of 4,096, the step fits the 15.75
+    GiB the compiler allows and fills over half of it, the fused optimizer is
+    in it, and the early router, the ReGLU loops and the sliding kind's
+    rotation lie under the scopes the cell's readers read. The full layer's
+    run holds ONE `flash_causal_fwd`. The sliding run of three holds TWO
+    `flash_window_fwd` today, one of them in its backward: PR 30's rule
+    chooses the policy that keeps o and lse (span 4,096), but the policy
+    looks for the kernel's name where this JAX does not put it and keeps
+    nothing; in a run of ONE layer the compiler merges the second forward
+    with the first, which hid it in every cell before this one
+    (tests/test_smallthinker_decoder.py holds the witness; ROADMAP A19)."""
+    import re
+
+    from benchmark import harness, scopes
+    from benchmark import manifest as mf
+    _, topo = chip
+    man = mf.Manifest()
+    cell = man.cell("smallthinker_21b_a3b_ep8_train_longrow")
+    config, traffic = man.config(cell["config"]), man.traffic(cell["traffic"])
+    gen = mf.generator(traffic["kind"])
+    lowered, what = gen.lower_described(man.config_kwargs(config), traffic,
+                                        list(topo.devices)[:1])
+    assert what == "decoder train step, 1 rows of 16384 tokens"
+    compiled = lowered.compile()
+    step_bytes = harness.program_facts(compiled)["step_bytes"]
+    assert 0.5 * 16.909e9 < step_bytes <= 16.909e9, step_bytes
+    kernels = _kernel_names(compiled)
+    attention = [re.search(r"flash_(causal|window)_\w+", k).group()
+                 for k in kernels if "flash_" in k]
+    assert sorted(set(attention)) == [
+        "flash_causal_dkv", "flash_causal_dq", "flash_causal_fwd",
+        "flash_window_dkv", "flash_window_dq", "flash_window_fwd"], kernels
+    assert [attention.count(f"flash_{k}_dq") for k in ("causal", "window")] \
+        == [1, 1] and attention.count("flash_causal_fwd") == 1, kernels
+    assert any("fused_adamw" in k for k in kernels)
+    # 7 query heads a grid step: the kernels' q block holds a key/value
+    # head's whole group
+    text = compiled.as_text()
+    assert "bf16[1,16384,28,128]" in text and "bf16[1,16384,4,128]" in text
+    found = set(scopes.index(text, gen.SCOPES).values())
+    assert {"rope1d", "moe_route", "moe_dispatch", "expert_ffn",
+            "moe_combine", "lm_head_loss"} <= found, found
+
+
 def test_the_four_chip_cells_gradients_leave_through_the_ring(chip, mosaic):
     """The whole train step of `vit10b_fsdp4_train_b8` (ZeRO-3 over the four
     described chips, the 10B widths), through the cell's own

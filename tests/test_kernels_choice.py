@@ -60,6 +60,8 @@ CELLS = {
     "lfm2_24b_a2b_ep8": [       # PR 48: no kernel is asked of the new mixer
         "attention core: " + DOCUMENTS,
         "mixer convolution: " + GATED_CONV],
+    "smallthinker_21b_a3b_ep8": [   # PR 51: both document kernels, no mixer
+        "attention core: " + DOCUMENTS],
 }
 LINE_OF = {"scan": "state-space scan", "rule": "delta rule",
            "conv": "mixer convolution"}

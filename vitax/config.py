@@ -104,14 +104,14 @@ class Config:
     head_gate: bool = False             # sigmoid gate on the attention output, one scalar a head
     norm_eps: float = 1e-6              # RMSNorm epsilon
     rope_theta_full: float = 10000.0    # full layers: RoPE base ...
-    rope_fraction_full: float = 1.0     # ... and the leading share of a head it rotates
+    rope_fraction_full: float = 1.0     # ... and the leading share of a head it rotates (0: full layers rotate nothing)
     yarn_factor: float = 1.0            # full layers: YaRN context extension (1 = plain RoPE) ...
     yarn_orig_len: int = 0              # ... from this many positions ...
     yarn_beta_fast: float = 32.0        # ... between these two rotation counts ...
     yarn_beta_slow: float = 1.0
     yarn_attn_factor: float = 1.0       # ... with this factor on cos and sin
     rope_theta_window: float = 10000.0  # sliding layers: plain RoPE base ...
-    rope_fraction_window: float = 1.0   # ... and rotated share
+    rope_fraction_window: float = 1.0   # ... and rotated share (0: nothing)
     position_embedding: str = "rope"    # "rope" | "nope": nope rotates nothing, in any layer
     tie_embeddings: bool = False        # the head is the embedding table itself (no lm_head leaf)
     embedding_multiplier: float = 1.0   # factor on the embedded tokens
@@ -176,6 +176,14 @@ class Config:
     groups_per_token: int = 0
     route_bias: bool = False
     route_weight_eps: float = 0.0
+    # ... or take the experts_per_token largest LOGITS and a softmax over
+    # those alone (route_form softmax_chosen: no bias, no groups), read the
+    # layer's FIRST norm's output, what its mixer reads (route_early; the
+    # experts still read the second norm's), and gate its experts by relu
+    # in place of silu (expert_activation: a ReGLU). SmallThinker's three
+    route_form: str = "sigmoid"         # "sigmoid" | "softmax_chosen"
+    route_early: bool = False
+    expert_activation: str = "silu"     # "silu" | "relu"
     pos_dropout: float = 0.0
     # NOTE: att_dropout > 0 stays on the fused kernels — every attention path
     # (whole-N, streamed, ring/ulysses sp, and their pipeline bodies at tp=1)
@@ -548,6 +556,16 @@ class Config:
             "--qk_norm (over the whole projected width) and --head_norm (a "
             "head) are two forms of one norm: a model has one")
         assert self.route_weight_eps >= 0, "--route_weight_eps must be >= 0"
+        assert (self.route_form in ("sigmoid", "softmax_chosen")
+                and self.expert_activation in ("silu", "relu")), (
+            f"unknown --route_form {self.route_form!r} (sigmoid | "
+            f"softmax_chosen) or --expert_activation "
+            f"{self.expert_activation!r} (silu | relu)")
+        assert self.route_form == "sigmoid" or not (
+            self.route_groups or self.route_bias or self.route_weight_eps), (
+            "--route_form softmax_chosen takes the largest logits and a "
+            "softmax over them: it has no --route_groups, --route_bias or "
+            "--route_weight_eps")
         if "latent_attention" in self.layer_kinds:
             assert (self.latent_rank >= 1 and self.qk_nope_size >= 1
                     and self.qk_rope_size >= 2 and self.v_head_size >= 1), (
@@ -561,10 +579,13 @@ class Config:
                 f"{self.rope_fraction_full} must equal it")
         for name in ("rope_fraction_full", "rope_fraction_window"):
             rot = self.head_size * getattr(self, name)
-            assert 0 < rot <= self.head_size and rot == int(rot) \
+            assert 0 <= rot <= self.head_size and rot == int(rot) \
                 and int(rot) % 2 == 0, (
                 f"--{name} {getattr(self, name)} must rotate an even number "
-                f"of a head's {self.head_size} dimensions")
+                f"of a head's {self.head_size} dimensions (0: the layers of "
+                f"that kind rotate nothing, beside a kind that does; "
+                f"--position_embedding nope is the model no layer of which "
+                f"rotates)")
         assert self.rope_theta_full > 1 and self.rope_theta_window > 1
         assert self.yarn_factor >= 1 and (
             self.yarn_factor == 1 or self.yarn_orig_len > 0), (
@@ -1036,6 +1057,16 @@ def build_parser() -> argparse.ArgumentParser:
             ("route_weight_eps", float, 0.0,
              "added to the sum that normalises the routed weights")):
         dec.add_argument(f"--{name}", type=kind, default=default, help=text)
+    dec.add_argument("--route_form", type=str, default="sigmoid",
+                     choices=("sigmoid", "softmax_chosen"),
+                     help="softmax_chosen: the largest logits, and a "
+                          "softmax over those alone")
+    dec.add_argument("--expert_activation", type=str, default="silu",
+                     choices=("silu", "relu"),
+                     help="the routed experts' gate: silu (SwiGLU) or relu "
+                          "(ReGLU)")
+    dec.add_argument("--route_early", action="store_true", dest="route_early",
+                     help="the router reads the layer's first norm's output")
     dec.add_argument("--position_embedding", type=str, default="rope",
                      choices=("rope", "nope"),
                      help="nope: attention rotates nothing, in any layer")

@@ -14,7 +14,9 @@ The second model family (`Config.model_family == "decoder"`), built by
 - Attn: `layer_heads[i]` query heads and `kv_heads` key/value heads of
   `head_size`, each key/value head serving heads / kv_heads query heads; RoPE
   in the rotate-half convention on the leading `rope_fraction_*` of a head, by
-  layer kind: plain on sliding layers, YaRN-scaled (`yarn_*`) on full ones;
+  layer kind: plain on sliding layers, YaRN-scaled (`yarn_*`) on full ones,
+  and a kind whose share is 0 rotates nothing (SmallThinker: full layers
+  without positions among sliding layers that rotate the whole head);
   scores in float32; a key is visible when it is not after the query, in the
   same document and, in a `sliding_attention` layer, fewer than
   `window_tokens` positions back. `g = sigmoid(W_g x)`, one scalar a head
@@ -25,7 +27,11 @@ The second model family (`Config.model_family == "decoder"`), built by
   `head_size` channels (one weight of `head_size` for q, one for k) after
   the split and before the rotation, as LFM2 writes it (the same scope).
 - F: a SwiGLU of `ffn_dim` in a `dense` layer, the routed and shared experts
-  of vitax/models/experts.py in a `sparse` one.
+  of vitax/models/experts.py in a `sparse` one: routed by a sigmoid over all
+  the scores or by a softmax over the chosen logits (`route_form`), gated by
+  `silu` or by `relu` (`expert_activation`). `route_early`: the router reads
+  the layer's FIRST norm's output, what the mixer reads, while the experts
+  read the second's; its cotangent then reaches `norm1` beside the mixer's.
 - A `mamba` layer has the state-space mixer of vitax/models/ssm.py in place
   of W_o[g * Attn], a `kda` layer the delta-rule mixer of
   vitax/models/kda.py (`layer_heads[i]` heads of `head_size`; it rotates
@@ -45,7 +51,7 @@ The second model family (`Config.model_family == "decoder"`), built by
   key ONE for all heads (the full layers' RoPE table), its value
   `v_head_size`; every earlier key of the document is visible.
 - What a model may state beside its layers: `position_embedding` nope (no
-  layer rotates anything), `attention_multiplier` on the scores in place of
+  layer of any kind rotates anything), `attention_multiplier` on the scores in place of
   head_size ** -0.5, `embedding_multiplier` on the embedded tokens,
   `residual_multiplier` on what each half of a layer adds, logits divided by
   `logits_scaling`, and `tie_embeddings` (the head is the embedding table).
@@ -334,6 +340,9 @@ class DecoderBlock(nn.Module):
     qk_norm: bool = False
     head_norm: bool = False         # the norm on q and k is one a head
     gconv_width: int = 0            # a conv layer's taps
+    route_form: str = "sigmoid"     # a sparse layer's router, its experts'
+    expert_activation: str = "silu"     # gate, and whether the router
+    route_early: bool = False           # reads norm1's output
 
     def _added(self, y: Array) -> Array:
         if self.residual_multiplier == 1.0:
@@ -360,6 +369,7 @@ class DecoderBlock(nn.Module):
         if self.token_sharding is not None:
             x = jax.lax.with_sharding_constraint(x, self.token_sharding)
         y = self._normed(x, "norm1", True)
+        route_from = y if self.route_early and mlp == "sparse" else None
         if kind == MAMBA:
             y = SSDMixer(self.mixer, self.norm_eps, self.dtype,
                          scan=getattr(self.kernels, "scan", None), conv=conv,
@@ -408,8 +418,9 @@ class DecoderBlock(nn.Module):
                 routed_scale=self.routed_scale, dtype=self.dtype,
                 route_groups=self.route[0], groups_per_token=self.route[1],
                 route_bias=self.route[2], weight_eps=self.route[3],
-                name="moe",
-            )(y, segment_ids > 0)
+                route_form=self.route_form,
+                activation=self.expert_activation, name="moe",
+            )(y, segment_ids > 0, route_from)
         return x + self._added(self._normed(y, "norm2", False))
 
 
@@ -496,6 +507,9 @@ class Decoder(nn.Module):
     qk_norm: bool = False
     head_norm: bool = False
     gconv_width: int = 0
+    route_form: str = "sigmoid"
+    expert_activation: str = "silu"
+    route_early: bool = False
 
     @property
     def attention_impl(self) -> Optional[Callable]:
@@ -511,14 +525,17 @@ class Decoder(nn.Module):
                 else self.pack_tokens)
 
     def _rope(self, positions: Array):
+        """(the full layers' cos and sin, the sliding layers'); None for a
+        kind that rotates nothing (a rotated share of 0)."""
         theta, frac, factor, orig, fast, slow, attn = self.rope_full
         rot = int(self.head_size * frac)
+        theta_w, frac_w = self.rope_window
+        rot_w = int(self.head_size * frac_w)
         full = (rope_inv_freq(rot, theta) if factor == 1.0 else
                 yarn_inv_freq(rot, theta, factor, int(orig), fast, slow))
-        theta_w, frac_w = self.rope_window
-        return (rope_tables(positions, full, attn),
-                rope_tables(positions, rope_inv_freq(
-                    int(self.head_size * frac_w), theta_w)))
+        return (rope_tables(positions, full, attn) if rot else None,
+                rope_tables(positions, rope_inv_freq(rot_w, theta_w))
+                if rot_w else None)
 
     @nn.compact
     def __call__(self, batch, deterministic: bool = True) -> Array:
@@ -556,7 +573,9 @@ class Decoder(nn.Module):
             kda=self.kda, latent=self.latent, route=self.route,
             gated_delta=self.gated_delta, norm_after=self.norm_after,
             qk_norm=self.qk_norm, head_norm=self.head_norm,
-            gconv_width=self.gconv_width)
+            gconv_width=self.gconv_width, route_form=self.route_form,
+            expert_activation=self.expert_activation,
+            route_early=self.route_early)
         for i, (shape, length) in enumerate(self.runs()):
             x = Run(length=length,
                     block_kwargs=tuple({**block_kwargs,
@@ -644,7 +663,9 @@ def build_decoder(cfg: Config, kernels=None, token_sharding=None) -> Decoder:
                       cfg.gdn_conv_width)
                      if GATED_DELTA in cfg.layer_kinds else None),
         norm_after=cfg.norm_after, qk_norm=cfg.qk_norm,
-        head_norm=cfg.head_norm, gconv_width=cfg.gconv_width)
+        head_norm=cfg.head_norm, gconv_width=cfg.gconv_width,
+        route_form=cfg.route_form, expert_activation=cfg.expert_activation,
+        route_early=cfg.route_early)
 
 
 def mixer_shape(cfg: Config) -> Optional[MixerShape]:

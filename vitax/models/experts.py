@@ -4,15 +4,26 @@ The feed-forward of a sparse decoder layer (vitax/models/decoder.py):
 
     y = sum_{k in top-K} w_k E_k(x)  +  S(x)
 
-`s = sigmoid(W_r x)` scores ALL `experts_routed` experts in float32, the K
-best are chosen over all of them (the plain path: `route_groups` 0, no
-bias), `w = routed_scale * s_k / (sum_topK s + weight_eps)` (`weight_eps` is
-the model's: 0, or LFM2's 1e-6); every `E_k` and the shared `S` (`shared_dim`
-0: none) is a SwiGLU. The layer is told which experts it holds (`experts_held` from
-`expert_first` on: one chip's share of a deployment in which several chips
-share each layer) and adds the terms whose expert it holds, and the shared
-expert; what the absent experts would add is left out. With `experts_held
-== experts_routed` it is the whole layer. There is no exchange and nothing
+Two routers, both over ALL `experts_routed` experts in float32 (`route_form`).
+`sigmoid`: `s = sigmoid(W_r x)`, the K best scores are chosen (the plain path:
+`route_groups` 0, no bias), `w = routed_scale * s_k / (sum_topK s +
+weight_eps)` (`weight_eps` is the model's: 0, or LFM2's 1e-6).
+`softmax_chosen` (SmallThinker): the K largest of the LOGITS `W_r x` are
+chosen and `w = routed_scale * softmax` over those K logits alone; no bias,
+no groups. What the router reads may be another tensor than the experts do
+(`route_from`: SmallThinker's router reads the layer's FIRST norm's output,
+what its attention reads, and the experts the second's). Two expert
+activations (`activation`): every `E_k` is `down(act(gate x) * up x)` with
+act `silu` (a SwiGLU) or `relu` (SmallThinker's ReGLU, whose gate leaves a
+hidden unit at exactly 0: the loops count the pairs of a live sorted row and
+a hidden unit whose gate is > 0 and the layer sows them as
+`expert_hidden_live`); the shared `S` (`shared_dim` 0: none) is a SwiGLU.
+
+The layer is told which experts it holds (`experts_held` from `expert_first`
+on: one chip's share of a deployment in which several chips share each
+layer) and adds the terms whose expert it holds, and the shared expert; what
+the absent experts would add is left out. With `experts_held ==
+experts_routed` it is the whole layer. There is no exchange and nothing
 stands in for the absent chips: expert parallelism over a mesh axis would
 put the all-to-all pair around `expert_ffn` and is not built.
 
@@ -69,7 +80,7 @@ two sorts. Later work (ROADMAP A18).
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -169,47 +180,64 @@ def _rows_of_block(xf, slot_of_row, first, total, block: int, k: int):
     return slots, live, xs
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+ACTIVATIONS = ("silu", "relu")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
 def routed_experts(xf, weights, slot_of_row, row_of_slot, load,
-                   gate, up, down, block: int):
+                   gate, up, down, block: int, activation: str = "silu"):
     """y[n] = sum_k weights[n, k] * E(x[n]) over the slots whose expert is
     held: (N, D), (N, K) float32 (0 on a slot whose expert is elsewhere), the
     sort's two permutations, the held experts' load and their three stacked
-    kernels -> (N, D) float32. Works on `blocks_of(sum(load), block)` blocks
-    of `block` sorted rows, forward and backward."""
+    kernels -> ((N, D) float32, hidden units live). Works on
+    `blocks_of(sum(load), block)` blocks of `block` sorted rows, forward and
+    backward. `activation` is E's, `silu` or `relu`; under `relu` the second
+    result is the int32 count of the (live sorted row, hidden unit) pairs
+    whose gate is > 0, under `silu` (no gate is 0) it is None."""
     return _routed_fwd(xf, weights, slot_of_row, row_of_slot, load,
-                       gate, up, down, block)[0]
+                       gate, up, down, block, activation)[0]
 
 
 def _routed_fwd(xf, weights, slot_of_row, row_of_slot, load, gate, up, down,
-                block):
+                block, activation):
     k, dtype = row_of_slot.shape[1], gate.dtype
     total = jnp.sum(load)
     padded = _padded(slot_of_row, block)
+    relu = activation == "relu"
 
-    def one_block(b, ys):
+    def one_block(b, carry):
+        ys, hidden_live = carry
         first = b * block
         _, live, xs = _rows_of_block(xf, padded, first, total, block, k)
         with jax.named_scope("expert_ffn"):
             sizes = _sizes_within(load, first, block)
-            h = nn.silu(_grouped(xs, gate, sizes)) * _grouped(xs, up, sizes)
+            g = _grouped(xs, gate, sizes)
+            if relu:
+                hidden_live += jnp.sum(live & (g > 0), dtype=jnp.int32)
+                h = jnp.maximum(g, 0.0) * _grouped(xs, up, sizes)
+            else:
+                h = nn.silu(g) * _grouped(xs, up, sizes)
             y = _grouped(h.astype(dtype), down, sizes)
             # a dead row of a live block is whatever the product left there
             y = jnp.where(live, y, 0.0).astype(dtype)
-            return jax.lax.dynamic_update_slice_in_dim(ys, y, first, 0)
+            return (jax.lax.dynamic_update_slice_in_dim(ys, y, first, 0),
+                    hidden_live)
 
-    ys = jax.lax.fori_loop(
+    ys, hidden_live = jax.lax.fori_loop(
         0, blocks_of(total, block), one_block,
-        jnp.zeros((padded.shape[0], xf.shape[1]), dtype))
+        (jnp.zeros((padded.shape[0], xf.shape[1]), dtype),
+         jnp.zeros((), jnp.int32) if relu else None))
     with jax.named_scope("moe_combine"):
         # gathered (K, N, D): a token's K rows lie N apart, and no tile is
         # padded from K rows to its 16 (as (N, K, D) a relayout a gather)
         picked = jnp.take(ys, row_of_slot.T, axis=0).astype(jnp.float32)
         y = jnp.sum(picked * weights.T[..., None], axis=0)        # (N, D)
-    return y, (xf, weights, slot_of_row, row_of_slot, load, gate, up, down)
+    return (y, hidden_live), (xf, weights, slot_of_row, row_of_slot, load,
+                              gate, up, down)
 
 
-def _routed_bwd(block, res, dy):
+def _routed_bwd(block, activation, res, cotangents):
+    dy, _ = cotangents              # a count takes no cotangent
     xf, weights, slot_of_row, row_of_slot, load, gate, up, down = res
     k, dtype = row_of_slot.shape[1], gate.dtype
     (held, d, f) = gate.shape
@@ -243,14 +271,20 @@ def _routed_bwd(block, res, dy):
             # selected away here, so that what follows is 0 on it
             g, u, dh = (jnp.where(live, _grouped(*of, sizes), 0.0) for of in (
                 (xs, gate), (xs, up), (dy_rows.astype(dtype), down_t)))
-            s = jax.nn.sigmoid(g)
-            h = g * s * u
+            relu = activation == "relu"
+            s = None if relu else jax.nn.sigmoid(g)
+            a = jnp.maximum(g, 0.0) if relu else g * s          # act(g)
+            h = a * u
             # through `down` once for both: the weight's gradient is the
             # row's <dy, y> = <dy down^T, h>, the rows' is w * dy down^T
             dw = jnp.sum(dh * h, axis=-1)
             dh = dh * w_rows
-            dg = (dh * u * s * (1.0 + g * (1.0 - s))).astype(dtype)
-            du = (dh * g * s).astype(dtype)
+            if relu:                # act'(g) = [g > 0]
+                dg = jnp.where(g > 0, dh * u, 0.0).astype(dtype)
+                du = (dh * a).astype(dtype)
+            else:
+                dg = (dh * u * s * (1.0 + g * (1.0 - s))).astype(dtype)
+                du = (dh * g * s).astype(dtype)
             dx = _grouped(dg, gate_t, sizes) + _grouped(du, up_t, sizes)
             dx = jnp.where(live, dx, 0.0).astype(dtype)
         with jax.named_scope("moe_combine"):
@@ -334,37 +368,52 @@ class SharedRoutedExperts(nn.Module):
     groups_per_token: int = 0
     route_bias: bool = False
     weight_eps: float = 0.0         # on the sum that normalises the weights
+    route_form: str = "sigmoid"     # | "softmax_chosen"
+    activation: str = "silu"        # the routed experts'; | "relu"
 
     @nn.compact
-    def __call__(self, x: Array, valid: Array) -> Array:
+    def __call__(self, x: Array, valid: Array,
+                 route_from: Optional[Array] = None) -> Array:
+        """`route_from` (R, T, D): what the router reads where that is not
+        `x`, which the experts transform."""
         r, t, d = x.shape
         n, k, held = r * t, self.experts_per_token, self.experts_held
         xf = x.reshape(n, d)
+        assert self.activation in ACTIVATIONS, self.activation
 
         with jax.named_scope("moe_route"):
-            scores = jax.nn.sigmoid(nn.Dense(
+            logits = nn.Dense(
                 self.experts_routed, use_bias=False, dtype=jnp.float32,
                 param_dtype=jnp.float32, kernel_init=default_init,
-                name="router")(xf.astype(jnp.float32)))           # (N, E)
-            if self.route_groups or self.route_bias:
-                bias = Leaf((self.experts_routed,), nn.initializers.zeros,
-                            "bias", name="router_bias")() \
-                    if self.route_bias else None
-                top, chosen, kept = choose(scores, bias, k, self.route_groups,
-                                           self.groups_per_token)
-                if kept is not None:
-                    mine = self.expert_first // (
-                        self.experts_routed // self.route_groups)
-                    self.sow("intermediates", "tokens_choosing_held_group",
-                             jnp.sum(kept[:, mine] & valid.reshape(n),
-                                     dtype=jnp.int32))
+                name="router")((xf if route_from is None else
+                                route_from.reshape(n, d)).astype(jnp.float32))
+            if self.route_form == "softmax_chosen":
+                top, chosen = jax.lax.top_k(logits, k)            # (N, K)
+                weights = self.routed_scale * jax.nn.softmax(top, axis=-1)
             else:
-                top, chosen = jax.lax.top_k(scores, k)            # (N, K)
-            scaled = self.routed_scale * top
-            total = jnp.sum(top, axis=-1, keepdims=True)
-            if self.weight_eps:
-                total = total + self.weight_eps
-            weights = scaled / total
+                scores = jax.nn.sigmoid(logits)                   # (N, E)
+                if self.route_groups or self.route_bias:
+                    bias = Leaf((self.experts_routed,),
+                                nn.initializers.zeros, "bias",
+                                name="router_bias")() \
+                        if self.route_bias else None
+                    top, chosen, kept = choose(
+                        scores, bias, k, self.route_groups,
+                        self.groups_per_token)
+                    if kept is not None:
+                        mine = self.expert_first // (
+                            self.experts_routed // self.route_groups)
+                        self.sow("intermediates",
+                                 "tokens_choosing_held_group",
+                                 jnp.sum(kept[:, mine] & valid.reshape(n),
+                                         dtype=jnp.int32))
+                else:
+                    top, chosen = jax.lax.top_k(scores, k)        # (N, K)
+                scaled = self.routed_scale * top
+                total = jnp.sum(top, axis=-1, keepdims=True)
+                if self.weight_eps:
+                    total = total + self.weight_eps
+                weights = scaled / total
             if self.route_bias:     # what the trainer's balance rule reads
                 self.sow("intermediates", "route_load", jnp.sum(
                     jax.nn.one_hot(chosen, self.experts_routed,
@@ -393,8 +442,11 @@ class SharedRoutedExperts(nn.Module):
                 for name, a, b in (("experts_gate", d, self.expert_dim),
                                    ("experts_up", d, self.expert_dim),
                                    ("experts_down", self.expert_dim, d)))
-        y = routed_experts(xf, weights, slot_of_row, row_of_slot, load,
-                           gate, up, down, block)
+        y, hidden_live = routed_experts(
+            xf, weights, slot_of_row, row_of_slot, load, gate, up, down,
+            block, self.activation)
+        if hidden_live is not None:
+            self.sow("intermediates", "expert_hidden_live", hidden_live)
 
         if self.shared_dim:
             with jax.named_scope("shared_expert"):

@@ -124,7 +124,10 @@ def decoder_flops_per_step(cfg, tokens: float, targets: float,
     latent_attention layer: its projections by `tokens`, QK^T at
     qk_nope_size + qk_rope_size and PV at v_head_size by `causal_pairs`.
     A conv layer (vitax/models/gconv.py): its two projections by `tokens`
-    (the two gates and the taps are no matrix products).
+    (the two gates and the taps are no matrix products). An expert gated by
+    relu (a ReGLU) counts as one gated by silu: the three products run over
+    every hidden unit, whatever the gate then leaves at 0; an early router
+    (`route_early`) costs what a late one does.
     Padding, the masked part of a block and sorted rows no held expert owns
     are not counted."""
     d, dh = cfg.embed_dim, cfg.head_size

@@ -141,9 +141,12 @@ class Recorder:
         `expert_rows_over_slots`; `images` are documents; a scan's
         `ssd_pairs` and `ssd_live_chunks`, a delta rule's `kda_pairs` and
         `kda_live_chunks`, a grouped router's `tokens_choosing_held_group`,
-        a balanced router's `route_load_max_over_mean`) are written into the
-        record as they are, with `expert_load`, its per-layer per-expert
-        load."""
+        a balanced router's `route_load_max_over_mean`, ReGLU experts'
+        `expert_hidden_live`) are written into the record as they are, with
+        `expert_load`, its per-layer per-expert load; beside
+        `expert_hidden_live`, the (sorted row, hidden unit) pairs a ReLU gate
+        left above 0, stands `expert_hidden_live_share`, that count over the
+        slots held x `expert_dim`."""
         images, tokens = self.cfg.batch_size, self.tokens_per_step
         flops_per_step = self.flops_per_step
         if packed_counts is not None:
@@ -192,8 +195,12 @@ class Recorder:
                 "expert_slots_here", "expert_rows_computed", "ssd_pairs",
                 "ssd_live_chunks",
                 "kda_pairs", "kda_live_chunks", "tokens_choosing_held_group",
-                "route_load_max_over_mean")
+                "route_load_max_over_mean", "expert_hidden_live")
                 if k in packed_counts}, expert_load=expert_load)
+            units = packed_counts["expert_slots_here"] * self.cfg.expert_dim
+            if "expert_hidden_live" in packed_counts and units:
+                record["expert_hidden_live_share"] = (
+                    packed_counts["expert_hidden_live"] / units)
         if grad_norm is not None:
             record["grad_norm"] = float(grad_norm)
         if loop_marks is not None:

@@ -130,8 +130,8 @@ DECODER_COUNTERS = ("tokens", "padding_tokens", "images", "targets",
                     # ... with kda layers, with a router that has groups:
                     "kda_pairs", "kda_live_chunks",
                     "tokens_choosing_held_group",
-                    # ... with a router whose bias the trainer balances:
-                    "route_load_max_over_mean")
+                    # ... a router bias the trainer balances; ReGLU experts:
+                    "route_load_max_over_mean", "expert_hidden_live")
 PACKED_COUNTERS = ("tokens", "padding_tokens", "images", "token_pairs",
                    "computed_pairs")
 

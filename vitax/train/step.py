@@ -396,10 +396,10 @@ def make_train_step(
 
     def decoder_loss_fn(params, batch, rng):
         """(loss, (per-layer per-expert load (sparse layers, held experts),
-        the sorted rows their loops worked on and the tokens that kept the
-        held experts' group, each over the sparse layers and None where no
-        layer sows it, the load over ALL routed experts by layer where the
-        router has a bias)): sown by vitax/models/experts.py."""
+        the sums over the sparse layers of what they sow under `SOWN_SUMS`,
+        below (None where no layer sows it), the load over ALL routed experts
+        by layer where the router has a bias)): sown by
+        vitax/models/experts.py."""
         del rng                      # no dropout arm (Config.validate)
         if comm is not None:
             params = comm.cast(params)
@@ -408,9 +408,9 @@ def make_train_step(
         loads = _select_by_name(cols, "expert_load")
         load = (jnp.concatenate([x.reshape(-1, x.shape[-1]) for x in loads])
                 if loads else jnp.zeros((0, 0), jnp.int32))
-        sown = (load, _sown_sum(cols, "expert_rows_computed"),
-                _sown_sum(cols, "tokens_choosing_held_group"))
-        return decoder_loss(logits, batch), (*sown, route_loads(cols))
+        sown = (load, *(_sown_sum(cols, name) for name in SOWN_SUMS),
+                route_loads(cols))
+        return decoder_loss(logits, batch), sown
 
     def loss_fn(params, batch, rng):
         if comm is not None:
@@ -553,7 +553,7 @@ def make_train_step(
             params = state.params
         expert_load, routed = None, {}
         if cfg.decoder:
-            (loss, (expert_load, rows, kept_group, routed)), grads = (
+            (loss, (expert_load, *sown_sums, routed)), grads = (
                 jax.value_and_grad(decoder_loss_fn, has_aux=True)(
                     params, batch, step_rng))
         elif k_steps > 1:
@@ -583,10 +583,9 @@ def make_train_step(
             metrics.update(decoder_counts(cfg, batch))
             metrics.update(expert_load=expert_load,
                            expert_slots_here=jnp.sum(expert_load))
-            if rows is not None:    # blocks x B a sparse layer, >= its slots
-                metrics.update(expert_rows_computed=rows)
-            if kept_group is not None:
-                metrics.update(tokens_choosing_held_group=kept_group)
+            # rows: blocks x B a sparse layer, >= its slots
+            metrics.update({name: value for name, value in zip(
+                SOWN_SUMS, sown_sums) if value is not None})
             if routed:
                 metrics.update(
                     route_load_max_over_mean=route_load_max_over_mean(routed))
@@ -675,3 +674,11 @@ def _sown_sum(cols, name: str):
     kernels' Mosaic payloads embed, benchmark/lowered.py.)"""
     sown = _select_by_name(cols, name)
     return sum(jnp.sum(x) for x in sown) if sown else None
+
+
+# What the sparse layers sow that a step sums over them into its metrics
+# under the same name (vitax/models/experts.py): the sorted rows their loops
+# worked on, the tokens that kept the held experts' group (a grouped router),
+# the (live row, hidden unit) pairs a ReLU gate left above 0 (ReGLU experts).
+SOWN_SUMS = ("expert_rows_computed", "tokens_choosing_held_group",
+             "expert_hidden_live")
