@@ -1,7 +1,8 @@
 """What the four decoder-family files share (tests/test_decoder.py,
 test_latent_decoder.py, test_hybrid_decoder.py, test_olmo_decoder.py): the
-packed batch, the seeded weights, and one tiny model against its plain
-float32 reference, built once a module.
+packed batch, the seeded weights, one tiny model against its plain float32
+reference, built once a module, and the equations of a traced program (what
+the remat policy is asked, and where a kernel stands).
 
 The rule of the test tree: a test calls compiled programs. Every `init`,
 `apply`, train step, reference call and gradient here goes through one
@@ -222,6 +223,66 @@ def check_first_steps_moments(generator, cfg, batch, clipped):
     for name in want:
         assert relative_gap(got[name], want[name]) < 1e-5, name
     return geom, step, state, m
+
+
+def equations(jaxpr, path=()):
+    """(the primitives it sits under, the equation) for every equation of a
+    jaxpr and of the jaxprs inside it, in order."""
+    for eqn in jaxpr.eqns:
+        yield path, eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from equations(inner,
+                                         path + (eqn.primitive.name,))
+
+
+def kernel_name(eqn):
+    """A `pallas_call` equation's name, where this JAX puts it."""
+    return eqn.params.get("name") or ""
+
+
+def kernels_traced(fn, *args):
+    """(path, equation) of every `pallas_call` in `fn`'s trace on `args`
+    (shapes do: nothing runs)."""
+    return [(path, eqn)
+            for path, eqn in equations(jax.make_jaxpr(fn)(*args).jaxpr)
+            if eqn.primitive.name == "pallas_call"]
+
+
+def kept(policy, eqn):
+    """What `jax.checkpoint` asks a policy of an equation: whether its
+    outputs may be kept for the backward."""
+    return policy(eqn.primitive, *(v.aval for v in eqn.invars), **eqn.params)
+
+
+def check_the_policy_keeps_by_the_traced_name(model, cfg, policy, name):
+    """The policy on the equations of a TRACED forward of `model` (built
+    through the kernels), not on a hand-made one: it keeps the attention
+    forward kernel `name` where this JAX writes that name, and refuses a
+    `pallas_call` of another name and a `dot_general`."""
+    from jax.experimental import pallas as pl
+    batch = decoder.sample_documents(cfg, 1)
+    shapes = jax.eval_shape(lambda: model.init(jax.random.key(0), batch,
+                                               True))
+    traced = [eqn for _, eqn in equations(jax.make_jaxpr(
+        lambda v: model.apply(v, batch, True))(shapes).jaxpr)]
+    forwards = [eqn for eqn in traced if eqn.primitive.name == "pallas_call"
+                and kernel_name(eqn) == name]
+    assert forwards, name
+    assert all(kept(policy, eqn) for eqn in forwards)
+    dots = [eqn for eqn in traced if eqn.primitive.name == "dot_general"]
+    assert dots and not any(kept(policy, eqn) for eqn in dots)
+
+    def copy(x_ref, o_ref):
+        o_ref[...] = x_ref[...]
+    x = jax.ShapeDtypeStruct((8, 128), jnp.float32)
+    [(_, other)] = kernels_traced(pl.pallas_call(
+        copy, out_shape=x, name="grouped_matmul"), x)
+    assert kernel_name(other) == "grouped_matmul"
+    assert not kept(policy, other)
 
 
 def family_declares(name):

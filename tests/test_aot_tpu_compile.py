@@ -547,7 +547,8 @@ def test_the_olmo_hybrid_cells_step_compiles_and_fits(chip, mosaic):
     step_bytes = harness.program_facts(compiled)["step_bytes"]
     assert 0.25 * 16.909e9 < step_bytes <= 15.75e9, step_bytes
     kernels = _kernel_names(compiled)
-    # one attention layer, whose remat keeps o and lse: each kernel once
+    # one attention layer, a run of one, where the compiler merges the
+    # remat's forward kernel with the first: each kernel once
     import re
     assert sorted(re.search(r"flash_causal_\w+", k).group() for k in kernels
                   if "flash_" in k) == [
@@ -588,7 +589,8 @@ def test_the_lfm2_moe_cells_step_compiles_and_fits(chip, mosaic):
     step_bytes = harness.program_facts(compiled)["step_bytes"]
     assert 0.7 * 16.909e9 < step_bytes <= 16.909e9, step_bytes
     kernels = _kernel_names(compiled)
-    # one attention layer, whose remat keeps o and lse: each kernel once
+    # one attention layer, a run of one, where the compiler merges the
+    # remat's forward kernel with the first: each kernel once
     assert sorted(re.search(r"flash_causal_\w+", k).group() for k in kernels
                   if "flash_" in k) == [
         "flash_causal_dkv", "flash_causal_dq", "flash_causal_fwd"], kernels
@@ -609,13 +611,12 @@ def test_the_smallthinker_cells_step_compiles_and_fits(chip, mosaic):
     GiB the compiler allows and fills over half of it, the fused optimizer is
     in it, and the early router, the ReGLU loops and the sliding kind's
     rotation lie under the scopes the cell's readers read. The full layer's
-    run holds ONE `flash_causal_fwd`. The sliding run of three holds TWO
-    `flash_window_fwd` today, one of them in its backward: PR 30's rule
-    chooses the policy that keeps o and lse (span 4,096), but the policy
-    looks for the kernel's name where this JAX does not put it and keeps
-    nothing; in a run of ONE layer the compiler merges the second forward
-    with the first, which hid it in every cell before this one
-    (tests/test_smallthinker_decoder.py holds the witness; ROADMAP A19)."""
+    run holds ONE `flash_causal_fwd` and the sliding run of three ONE
+    `flash_window_fwd`, so no backward runs a forward kernel again: the
+    remat of the run of three keeps the kernel's o and lse (span 4,096), and
+    in the run of one the compiler merges the second forward with the first
+    (`run_remat_policy`; tests/test_smallthinker_decoder.py holds the trace's
+    side; PR 52, ROADMAP A24)."""
     import re
 
     from benchmark import harness, scopes
@@ -637,8 +638,9 @@ def test_the_smallthinker_cells_step_compiles_and_fits(chip, mosaic):
     assert sorted(set(attention)) == [
         "flash_causal_dkv", "flash_causal_dq", "flash_causal_fwd",
         "flash_window_dkv", "flash_window_dq", "flash_window_fwd"], kernels
-    assert [attention.count(f"flash_{k}_dq") for k in ("causal", "window")] \
-        == [1, 1] and attention.count("flash_causal_fwd") == 1, kernels
+    assert [attention.count(f"flash_{k}_{part}")
+            for k in ("causal", "window") for part in ("fwd", "dq")] \
+        == [1, 1, 1, 1], kernels
     assert any("fused_adamw" in k for k in kernels)
     # 7 query heads a grid step: the kernels' q block holds a key/value
     # head's whole group

@@ -239,26 +239,25 @@ def test_model_through_the_kernels_equals_the_dense_path():
 
 def test_remat_keeps_o_and_lse_by_the_layers_span(monkeypatch):
     """PR 30's rule by run: a full layer's span is the row, a sliding
-    layer's its window; the kept kernels are named."""
+    layer's its window; the kept kernels are named, and the policy finds
+    the name on the `pallas_call` equation of a traced forward."""
     from vitax.models import vit
+    from vitax.ops.attention import make_attention_impl
     cfg = Config(**{**TINY, "pack_tokens": 2048, "window_tokens": 512,
                     "dtype": "bfloat16"}).validate()
-    model = decoder.build_decoder(
-        cfg, kernels=Kernels(attention=lambda *a: a[0]))
+    model = decoder.build_decoder(cfg, kernels=Kernels(
+        attention=make_attention_impl(cfg, None, force_tpu_kernels=True)))
     assert model.span("full_attention") == 2048
     assert model.span("sliding_attention") == 512
     assert decoder.keeps_attention_residuals(model, "full_attention")
     assert not decoder.keeps_attention_residuals(model, "sliding_attention")
-    assert decoder.run_remat_policy(model, "sliding_attention") is None
-    keep = decoder.run_remat_policy(model, "full_attention")
-
-    class Named:
-        def __init__(self, name):
-            self.name = name
-    prim = Named("pallas_call")
-    assert keep(prim, name_and_src_info=Named("flash_causal_fwd"))
-    assert not keep(prim, name_and_src_info=Named("grouped_matmul"))
-    assert not keep(Named("dot_general"))
+    assert decoder.run_remat_policy(model, "sliding_attention", 3) is None
+    # a run of one layer is left to the compiler's merge
+    assert decoder.run_remat_policy(model, "full_attention", 1) \
+        is decoder._decoder_nothing_saveable
+    cases.check_the_policy_keeps_by_the_traced_name(
+        model, cfg, decoder.run_remat_policy(model, "full_attention", 2),
+        "flash_causal_fwd")
     assert [n for _, n in model.runs()] == [1, 3, 1]
     monkeypatch.setattr(vit, "ATTN_KEEP_MIN_SPAN", 4096)
     assert not decoder.keeps_attention_residuals(model, "full_attention")

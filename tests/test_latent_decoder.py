@@ -154,22 +154,20 @@ def test_the_model_through_the_convolutions_kernels_equals_the_plain_path():
 
 
 def test_remat_keeps_o_and_lse_of_the_latent_layer_only():
+    from vitax.ops.attention import make_attention_impl
     cfg = Config(**{**TINY, "pack_tokens": 2048,
                     "dtype": "bfloat16"}).validate()
-    model = decoder.build_decoder(
-        cfg, kernels=Kernels(attention=lambda *a: a[0]))
+    model = decoder.build_decoder(cfg, kernels=Kernels(
+        attention=make_attention_impl(cfg, None, force_tpu_kernels=True)))
     assert model.span("latent_attention") == 2048
     assert decoder.keeps_attention_residuals(model, "latent_attention")
     assert not decoder.keeps_attention_residuals(model, "kda")
-    assert decoder.run_remat_policy(model, "kda") is None
-    keep = decoder.run_remat_policy(model, "latent_attention")
-
-    class Named:
-        def __init__(self, name):
-            self.name = name
-    assert keep(Named("pallas_call"),
-                name_and_src_info=Named("flash_latent_fwd"))
-    assert not keep(Named("dot_general"))
+    assert decoder.run_remat_policy(model, "kda", 3) is None
+    assert decoder.run_remat_policy(model, "latent_attention", 1) \
+        is decoder._decoder_nothing_saveable
+    cases.check_the_policy_keeps_by_the_traced_name(
+        model, cfg, decoder.run_remat_policy(model, "latent_attention", 2),
+        "flash_latent_fwd")
 
 
 # --- counts, counters, configuration ----------------------------------------------

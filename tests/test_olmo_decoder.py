@@ -183,7 +183,7 @@ def test_remat_keeps_o_and_lse_of_the_attention_layer_only():
         cfg, kernels=Kernels(attention=lambda *a: a[0]))
     assert decoder.keeps_attention_residuals(model, "full_attention")
     assert not decoder.keeps_attention_residuals(model, "linear_attention")
-    assert decoder.run_remat_policy(model, "linear_attention") is None
+    assert decoder.run_remat_policy(model, "linear_attention", 3) is None
 
 
 # --- the share tied to the model ------------------------------------------------

@@ -192,47 +192,43 @@ def test_the_scopes_a_metric_reads_are_in_the_lowered_program(case):
 def test_remat_keeps_o_and_lse_in_sliding_runs_at_the_cells_window():
     """A window of 4,096 is a span at which PR 30's rule SELECTS the policy
     that keeps o and lse: the first cell whose sliding runs it selects it for
-    (what that policy then keeps: the witness further down)."""
+    (what that policy then keeps: the witness further down), and the first
+    with a kept run of several layers, the only length the policy is given
+    at (`run_remat_policy`)."""
     from vitax.models.vit import ATTN_KEEP_MIN_SPAN
     from vitax.programs.kernels import Kernels
-    real = decoder.build_decoder(Config(**SMALLTHINKER).validate(),
+    from vitax.train.loop import _attention_remat_note
+    real_cfg = Config(**SMALLTHINKER).validate()
+    real = decoder.build_decoder(real_cfg,
                                  kernels=Kernels(attention=lambda *a: a[0]))
     assert real.span("sliding_attention") == 4096 >= ATTN_KEEP_MIN_SPAN
     assert decoder.keeps_attention_residuals(real, "sliding_attention")
     assert decoder.keeps_attention_residuals(real, "full_attention")
+    # by the run's length too: the full run of one layer is left to the
+    # compiler's merge, and the loop's first line says which is which
+    assert [decoder.run_remat_policy(real, shape[0], n)
+            for shape, n in real.runs()] == [
+        decoder._decoder_nothing_saveable,
+        decoder._decoder_attention_saveable]
+    assert _attention_remat_note(real_cfg, real, None) == (
+        "; remat runs the forward again in full_attention runs of one layer "
+        "(merged with the first when compiled) (span 16384), keeps o and lse "
+        "in sliding_attention runs of several layers (span 4096)")
     short = decoder.build_decoder(
         Config(**{**SMALLTHINKER, "window_tokens": 512}).validate(),
         kernels=Kernels(attention=lambda *a: a[0]))
     assert not decoder.keeps_attention_residuals(short, "sliding_attention")
 
 
-def _equations(jaxpr, path=()):
-    """(the primitives it sits under, the equation) for every equation of a
-    jaxpr and of the jaxprs inside it, in order."""
-    for eqn in jaxpr.eqns:
-        yield path, eqn
-        for value in eqn.params.values():
-            for sub in (value if isinstance(value, (list, tuple))
-                        else [value]):
-                inner = getattr(sub, "jaxpr", sub)
-                if hasattr(inner, "eqns"):
-                    yield from _equations(inner,
-                                          path + (eqn.primitive.name,))
-
-
-@pytest.mark.xfail(strict=True, reason=(
-    "`_decoder_attention_saveable` reads the kernel's name from "
-    "`name_and_src_info`, a parameter this JAX's `pallas_call` does not "
-    "carry (it has `name`), so the policy PR 30's rule selects keeps "
-    "nothing and every kept run's backward runs its forward kernel again. "
-    "Repairing it changes the lowered program of all five accepted decoder "
-    "cells, which ISSUE 51 forbids this PR: ROADMAP A24"))
 def test_a_kept_runs_backward_runs_no_second_forward_kernel():
-    """The witness (trace only, nothing runs): the gradient of a model whose
-    sliding run spans 1,024 keys holds `flash_window_fwd` in the forward scan
-    and NOT in the rematted backward. In a run of one layer the chip's
-    compiler merges the second forward with the first, which hid this in
-    every cell before the first kept run of several layers."""
+    """Trace only, nothing runs: the gradient of a model whose sliding run of
+    three layers spans 1,024 keys holds `flash_window_fwd` in the forward scan
+    and NOT in the rematted backward, because the policy PR 30's rule selects
+    keeps the kernel's o and lse (`_decoder_attention_saveable` reads the
+    name this JAX's `pallas_call` carries). In a run of one layer the chip's
+    compiler merges a second forward with the first; in a run of several it
+    cannot, so this holds what no one-layer cell shows (PR 52: 21 ms of a
+    387 ms step in `smallthinker_21b_a3b_ep8_train_longrow`)."""
     from vitax.ops.attention import make_attention_impl
     from vitax.programs.kernels import Kernels
     from vitax.train.step import decoder_loss
@@ -246,11 +242,9 @@ def test_a_kept_runs_backward_runs_no_second_forward_kernel():
     batch = cases.make_batch(cfg, [[1500, 500]])
     shapes = jax.eval_shape(lambda: model.init(
         jax.random.key(0), decoder.sample_documents(cfg, 1), True))
-    jaxpr = jax.make_jaxpr(jax.grad(lambda v: decoder_loss(
-        model.apply(v, batch, True), batch)))(shapes).jaxpr
-    forwards = [path for path, eqn in _equations(jaxpr)
-                if eqn.primitive.name == "pallas_call"
-                and eqn.params.get("name") == "flash_window_fwd"]
+    forwards = [path for path, eqn in cases.kernels_traced(
+        jax.grad(lambda v: decoder_loss(model.apply(v, batch, True), batch)),
+        shapes) if cases.kernel_name(eqn) == "flash_window_fwd"]
     assert len(forwards) >= 1
     assert not [path for path in forwards if "remat2" in path], forwards
 
@@ -361,7 +355,7 @@ def test_the_silu_rule_is_bit_for_bit_what_it_was():
         argnums=(0, 1)))(p, x).jaxpr
     # every primitive with the shapes and dtypes it writes
     lines = [f"{eqn.primitive.name}:" + ",".join(
-        str(v.aval) for v in eqn.outvars) for _, eqn in _equations(jaxpr)]
+        str(v.aval) for v in eqn.outvars) for _, eqn in cases.equations(jaxpr)]
     assert len(lines) == 454
     assert hashlib.sha256("\n".join(lines).encode()).hexdigest() == (
         "ba58886e837c196263365f0a6edd4b721f923d194f168f1c68f9f5be114ad1d8")

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from benchmark.reference import granite as reference
+from tests.decoder_cases import kernel_name as _kernel_name
 from tests.test_ssm import documents
 from vitax.config import Config
 from vitax.data.packing import document_layout
@@ -226,11 +227,6 @@ def _mixer_grad_jaxpr(scan):
     variables = jax.eval_shape(mixer.init, jax.random.key(0), u, seg)
     return jax.make_jaxpr(jax.grad(
         lambda v, u: jnp.sum(mixer.apply(v, u, seg))))(variables, u).jaxpr
-
-
-def _kernel_name(eqn):
-    return eqn.params.get("name") or getattr(
-        eqn.params.get("name_and_src_info"), "name", "")
 
 
 def test_the_plain_mixer_has_no_kernel_and_the_text_it_had():

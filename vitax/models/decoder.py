@@ -582,7 +582,7 @@ class Decoder(nn.Module):
                                         "shape": shape}.items()),
                     scan_blocks=self.scan_blocks,
                     scan_unroll=self.scan_unroll, remat=self.grad_ckpt,
-                    policy=run_remat_policy(self, shape[0]),
+                    policy=run_remat_policy(self, shape[0], length),
                     name=f"run{i}")(x, seg, *ropes)
 
         x = RMSNorm(self.norm_eps, self.dtype, name="norm")(x)
@@ -605,10 +605,22 @@ class Decoder(nn.Module):
 def _decoder_attention_saveable(prim, *_, **params):
     """`none_saveable` + the attention forward kernel's own outputs (o and
     lse), as vitax/models/vit.py keeps them; by the kernels' names, so that a
-    block that gains another `pallas_call` does not keep that one's too."""
-    name = getattr(params.get("name_and_src_info"), "name", "") or ""
-    return getattr(prim, "name", "") == "pallas_call" and name.startswith(
-        "flash_")
+    block that gains another `pallas_call` does not keep that one's too. The
+    equation carries the name as `name` (tests/decoder_cases.py
+    `check_the_policy_keeps_by_the_traced_name` holds that on a traced
+    forward); the backward kernels never stand under the remat's forward."""
+    return getattr(prim, "name", "") == "pallas_call" and (
+        params.get("name") or "").startswith("flash_")
+
+
+def _decoder_nothing_saveable(*_, **__):
+    """What a kept run of ONE layer is given (`run_remat_policy`). An object
+    of its own, neither `None` nor jax's `nothing_saveable`: jax stages the
+    jitted helpers of runs whose policies are one object as one function,
+    and the step compiled from that text is another schedule (LFM2's ran
+    0.3% slower, PERF.md section 6, PR 52). With this the one-layer cells
+    lower to the text they had."""
+    return False
 
 
 def keeps_attention_residuals(model: Decoder, kind: str) -> bool:
@@ -620,11 +632,18 @@ def keeps_attention_residuals(model: Decoder, kind: str) -> bool:
     return kind not in NO_ATTENTION and rule(model, span=model.span(kind))
 
 
-def run_remat_policy(model: Decoder, kind: str):
+def run_remat_policy(model: Decoder, kind: str, length: int):
+    """The policy of a run's per-block remat. Where PR 30's rule keeps, the
+    kernel's o and lse only in a run of several layers: a scan of one trip is
+    inlined, and the chip's compiler merges the remat's forward kernel with
+    the first there (`prevent_cse` is off), which keeps o and lse without
+    the copy a scan's residual costs (PERF.md section 6, PR 52)."""
     from vitax.models.vit import _REMAT_POLICIES
-    if keeps_attention_residuals(model, kind):
+    if not keeps_attention_residuals(model, kind):
+        return _REMAT_POLICIES[model.remat_policy]
+    if length > 1:
         return _decoder_attention_saveable
-    return _REMAT_POLICIES[model.remat_policy]
+    return _decoder_nothing_saveable
 
 
 def build_decoder(cfg: Config, kernels=None, token_sharding=None) -> Decoder:

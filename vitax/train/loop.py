@@ -87,23 +87,33 @@ def _sharded_param_count(state: TrainState) -> int:
     return total
 
 
+ONE_LAYER = "one layer (merged with the first when compiled)"
+
+
 def _attention_remat_note(cfg: Config, model, mesh) -> str:
     """The `attention core:` line's second half: whether a rematted block
     keeps the forward kernel's o and lse or runs the kernel again in its
-    backward, and why (vitax/models/vit.py: keeps_attention_residuals). The
-    pipeline body and the group forwards (vitax/train/step.py: _forward_fn)
-    checkpoint blocks themselves and always recompute."""
+    backward, and why (vitax/models/vit.py: keeps_attention_residuals;
+    vitax/models/decoder.py: run_remat_policy). The pipeline body and the
+    group forwards (vitax/train/step.py: _forward_fn) checkpoint blocks
+    themselves and always recompute."""
     from vitax.models.vit import (ATTN_KEEP_MIN_SPAN, attention_span,
                                   keeps_attention_residuals)
     from vitax.parallel.sharding import gather_overlap_active
     if model.attention_impl is None or not cfg.grad_ckpt:
         return ""
-    if cfg.decoder:   # by the span of each kind of layer
-        from vitax.models.decoder import NO_ATTENTION, keeps_attention_residuals as keeps
+    if cfg.decoder:   # by each run's kind (its span) and length
+        from vitax.models.decoder import (
+            NO_ATTENTION, _decoder_attention_saveable, run_remat_policy)
+        said = dict.fromkeys(
+            (kind, length > 1,
+             run_remat_policy(model, kind, length) is _decoder_attention_saveable)
+            for (kind, _, _), length in model.runs()
+            if kind not in NO_ATTENTION)
         return "; remat " + ", ".join(
-            f"{'keeps o and lse' if keeps(model, kind) else 'runs the forward again'}"
-            f" in {kind} layers (span {model.span(kind)})"
-            for kind in sorted(set(cfg.layer_kinds) - set(NO_ATTENTION)))
+            f"{'keeps o and lse' if kept else 'runs the forward again'} in "
+            f"{kind} runs of {'several layers' if several else ONE_LAYER}"
+            f" (span {model.span(kind)})" for kind, several, kept in said)
     span = attention_span(model)
     if (mesh.shape.get("pp", 1) > 1 or gather_overlap_active(cfg, mesh)
             or cfg.remat_window > 1):
